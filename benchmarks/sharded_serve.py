@@ -46,9 +46,8 @@ ENGINE_CFG = dict(model="tiny", page_size=8, num_pages=64,
 
 
 def _setup_devices(n: int) -> None:
-    # APPEND the device-count flag when XLA_FLAGS is already set (a bare
-    # setdefault would leave pre-0.5 jax — where jax_num_cpu_devices
-    # doesn't exist — with one device and a misleading tp error)
+    # APPEND the device-count flag when XLA_FLAGS is already set: child
+    # processes take their device count from the environment
     flag = f"--xla_force_host_platform_device_count={n}"
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
@@ -58,7 +57,7 @@ def _setup_devices(n: int) -> None:
     try:
         jax.config.update("jax_platforms", "cpu")
         jax.config.update("jax_num_cpu_devices", n)
-    except (RuntimeError, AttributeError):
+    except RuntimeError:  # a backend already exists: too late, keep it
         pass
 
 

@@ -5,7 +5,9 @@ python/ray/_raylet.pyx); this image has no pybind11, so the C ABI +
 ctypes is the binding (zero build-time Python deps). `ensure_built()`
 compiles csrc/ on first use when a toolchain is present; every native
 feature has a pure-Python fallback, so the framework still works where
-there is no compiler.
+there is no compiler — but a failed build is never silent: it is logged
+once with the compiler's output, `build_error()` returns it, and
+`chip_smoke.py` fails on it.
 """
 
 from __future__ import annotations
@@ -26,6 +28,7 @@ _CSRC = os.path.join(os.path.dirname(os.path.dirname(_HERE)), "csrc")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
+_build_error: Optional[str] = None
 
 
 def _stale() -> bool:
@@ -40,21 +43,49 @@ def _stale() -> bool:
 
 
 def ensure_built() -> bool:
-    """Build librtpu.so if missing/stale. Returns availability."""
-    global _build_failed
-    with _lock:
+    """Build librtpu.so if missing/stale. Returns availability.
+
+    Safe across processes: the check and the build run under an exclusive
+    file lock, and the compiler writes to a temporary name that is renamed
+    into place. A fresh checkout has no .so (it is git-ignored), and the
+    test workers, the worker factory and every cluster worker all come here
+    at once: without the lock one of them loads a half-written library
+    ("file too short")."""
+    global _build_failed, _build_error
+    import fcntl
+
+    with _lock, open(os.path.join(_HERE, ".build.lock"), "w") as lock_file:
+        fcntl.flock(lock_file, fcntl.LOCK_EX)
         if os.path.exists(_SO) and not _stale():
             return True
         if _build_failed:
             return False
+        tmp = f"{_SO}.tmp"
         try:
-            target = (["asan"] if _SO.endswith("_asan.so") else [])
-            subprocess.run(["make", "-C", _CSRC, *target], check=True,
-                           capture_output=True, timeout=120)
+            asan = _SO.endswith("_asan.so")
+            subprocess.run(
+                ["make", "-C", _CSRC, *(["asan"] if asan else []),
+                 f"{'ASAN_OUT' if asan else 'OUT'}={tmp}"],
+                check=True, capture_output=True, timeout=120)
+            os.replace(tmp, _SO)
             return True
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — any failure = pure-Python store
             _build_failed = True
+            out = getattr(e, "stderr", None) or b""
+            _build_error = (f"{e!r}\n{out.decode(errors='replace')}"
+                            .strip())
+            import logging
+
+            logging.getLogger(__name__).warning(
+                "native runtime core did not build; running the "
+                "pure-Python store: %s", _build_error)
             return False
+
+
+def build_error() -> Optional[str]:
+    """The failed build's error and compiler output (None: no build has
+    failed in this process)."""
+    return _build_error
 
 
 def get_lib() -> Optional[ctypes.CDLL]:

@@ -5,7 +5,10 @@ vLLM CUDA kernels; the reference itself ships no attention kernels — see
 SURVEY.md §2.4) implemented TPU-native: a jnp reference implementation that
 XLA fuses well on any backend, and a Pallas flash-attention kernel for TPU
 (ray_tpu/ops/flash_attention.py). GQA (grouped KV heads) is supported
-everywhere; selection is automatic by platform unless forced via `impl`.
+everywhere. Selection follows the backend the process runs on unless forced
+via `impl`: a TPU backend always gets the compiled kernel (wrapped in a
+`shard_map` when a multi-device mesh is active) and never the reference; a
+CPU backend (`JAX_PLATFORMS=cpu`, the tests) gets the reference.
 """
 
 from __future__ import annotations
@@ -83,8 +86,10 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               impl: Optional[str] = None) -> jax.Array:
     """Dispatch to the best backend for this platform.
 
-    impl: None (auto) | "reference" | "flash" (Pallas TPU kernel, runs in
-    interpret mode off-TPU) | "ring" | "ulysses" (sequence-parallel
+    impl: None (flash on a TPU backend, reference elsewhere) | "reference"
+    | "flash" (Pallas TPU kernel; interpret mode on a CPU backend; wrapped
+    in a shard_map over batch and heads when a multi-device mesh is
+    active) | "ring" | "ulysses" (sequence-parallel
     collectives over the ambient mesh's `sp` axis; fall back to the dense
     path when no mesh is active or sp == 1).
 
@@ -107,34 +112,48 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   else ulysses_attention_sharded)
             return fn(q, k, v, mesh, causal=causal, segment_ids=segment_ids,
                       scale=scale)
-        _warn_flash_fallback(
+        _warn_once(
             f"impl={impl!r} requested but no active mesh with sp>1 "
             "(wrap the call in ray_tpu.parallel.mesh.active_mesh); "
             "running dense attention")
         impl = None  # no sp axis active: fall through to dense auto-select
-    auto = impl is None
-    if auto:
+    if impl is None:
         impl = "flash" if jax.default_backend() == "tpu" else "reference"
     if impl == "flash":
-        try:
-            from .flash_attention import flash_attention
-        except ImportError:
-            if not auto:
-                raise  # explicitly requested flash: surface the error
-            _warn_flash_fallback("pallas kernel module unavailable")
-        else:
-            return flash_attention(q, k, v, causal=causal,
-                                   segment_ids=segment_ids, scale=scale)
+        from .flash_attention import flash_attention, flash_attention_sharded
+
+        mesh = _mesh_to_shard_over()
+        if mesh is not None:
+            return flash_attention_sharded(
+                q, k, v, mesh, causal=causal, segment_ids=segment_ids,
+                scale=scale)
+        return flash_attention(q, k, v, causal=causal,
+                               segment_ids=segment_ids, scale=scale)
     return reference_attention(q, k, v, causal=causal,
                                segment_ids=segment_ids, scale=scale)
+
+
+def _mesh_to_shard_over():
+    """The active mesh when the flash kernel has to be wrapped in a
+    shard_map: more than one device, and not already inside a manual
+    region (ulysses calls `attention` from inside its own shard_map, where
+    operands are per-device blocks already)."""
+    from ..parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return mesh
 
 
 _warned = set()
 
 
-def _warn_flash_fallback(reason: str):
-    if reason not in _warned:
-        _warned.add(reason)
+def _warn_once(message: str):
+    if message not in _warned:
+        _warned.add(message)
         import warnings
 
-        warnings.warn(f"falling back to reference attention: {reason}")
+        warnings.warn(message)

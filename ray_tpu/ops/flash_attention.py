@@ -7,7 +7,13 @@ ABSENT").  Here the hot op is owned natively: a blocked online-softmax
 
 - blocked tiling on both query and key axes (512 default, 128 minimum),
 - K/V for one (batch, kv-head) kept resident in VMEM; the inner k-loop is a
-  `fori_loop` of MXU matmuls with f32 accumulation,
+  `fori_loop` of MXU matmuls with f32 accumulation. That residency bounds
+  the kv length the kernel takes. Compile limits on v5e (from a compile
+  for a described v5e chip, jax 0.9.0 — not from a run; guarded by
+  tests/test_chip_compile.py): forward compiles up to 16384 kv rows and
+  is refused at 32768, backward compiles up to 4096 and is refused at
+  8192, at head_dim 64 and 128 alike (`RESOURCE_EXHAUSTED ... vmem`).
+  Longer sequences need K/V tiled over the grid (ROADMAP A3),
 - GQA handled in the BlockSpec index map (q-head h reads kv-head h // n_rep),
   so no materialised `repeat_kv`,
 - causal masking is relative to the *end* of the kv sequence (tril with
@@ -17,14 +23,24 @@ ABSENT").  Here the hot op is owned natively: a blocked online-softmax
 - backward pass as two Pallas kernels (dq; dk/dv) using the saved
   log-sum-exp, flash-2 style.
 
-Interpret mode (`interpret=True`, default off-TPU) runs the same kernels on
-CPU for tests: tests/test_flash_attention.py checks parity with
-`reference_attention` for values and grads.
+Interpret mode runs the same kernels on the CPU for tests
+(tests/test_flash_attention.py checks parity with `reference_attention`
+for values and grads). It is chosen by the caller (`interpret=True`) or,
+when `interpret` is left None, by the process running on a non-TPU backend
+(`JAX_PLATFORMS=cpu`); on a TPU backend None always means the compiled
+kernel.
+
+A Mosaic kernel is a one-device program: GSPMD cannot partition it, so
+under a mesh with more than one device the call must sit inside a
+`shard_map`. `flash_attention_sharded` is that wrapper (batch over
+dp/fsdp, heads over tp); `ops.attention.attention` picks it whenever a
+multi-device mesh is active.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple, Union
 
 import jax
@@ -32,12 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in jax 0.5; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
-BLOCK = 512  # default tile edge: benches fastest fwd+bwd on v5e
+BLOCK = 512  # default tile edge (untuned on the current chip: ROADMAP A3/A4)
 GRAN = 128   # MXU-minimal granularity: short sequences round up to this,
              # not to BLOCK, so small prefills don't pad 4-8x
 
@@ -167,7 +179,7 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(*args)
@@ -318,7 +330,7 @@ def _bwd(q, k, v, q_seg, kv_seg, o, lse, do, causal, sm_scale,
                   vec_blk_spec, qseg_blk, kseg_full],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h, i: (b_, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq_p, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(q, k, v, do, lse, delta, *seg_args)
@@ -344,7 +356,7 @@ def _bwd(q, k, v, q_seg, kv_seg, o, lse, do, causal, sm_scale,
             jax.ShapeDtypeStruct((b, hq, sk_p, d), jnp.float32),
             jax.ShapeDtypeStruct((b, hq, sk_p, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(q, k, v, do, lse, delta, *seg_args)
@@ -448,3 +460,50 @@ def flash_attention(
     o = _flash(qt, kt, vt, q_seg, kv_seg, causal, scale, bq, bk, interpret,
                sq, sk)
     return o[:, :, :sq, :].transpose(0, 2, 1, 3)
+
+
+def flash_attention_sharded(
+    q: jax.Array, k: jax.Array, v: jax.Array, mesh, *,
+    causal: bool = True, segment_ids=None, scale: Optional[float] = None,
+    batch_axes=("dp", "fsdp"), head_axis: str = "tp",
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """`flash_attention` under a multi-device mesh: a `shard_map` over the
+    batch axes (dp, fsdp) and the head axis (tp), so each device runs the
+    one-device kernel on its own [B/n, S, H/tp, D] block. Dense attention
+    needs no collective: every (batch row, head) is independent. Callable
+    from inside a GSPMD-partitioned jit with global [B,S,H,D] operands
+    (the ring_attention_sharded idiom). Axes of the mesh that the specs do
+    not name (pp, sp, ep) see replicated operands.
+
+    Raises when the batch or the kv heads do not divide over the mesh —
+    there is no quiet reference path under a mesh.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    b, hkv = q.shape[0], k.shape[2]
+    batch = tuple(a for a in batch_axes
+                  if a in mesh.axis_names and mesh.shape[a] > 1)
+    n_batch = math.prod(mesh.shape[a] for a in batch)
+    head = (head_axis if head_axis in mesh.axis_names
+            and mesh.shape[head_axis] > 1 else None)
+    n_head = mesh.shape[head] if head else 1
+    if b % n_batch or hkv % n_head:
+        raise ValueError(
+            f"flash attention under mesh {dict(mesh.shape)}: batch {b} must "
+            f"divide over {batch or '()'} ({n_batch}) and kv heads {hkv} "
+            f"over {head!r} ({n_head})")
+    qkv_spec = P(batch or None, None, head, None)
+    seg_spec = P(batch or None, None)
+    pair = isinstance(segment_ids, tuple)
+    segs = (() if segment_ids is None
+            else tuple(segment_ids) if pair else (segment_ids,))
+
+    def local(q, k, v, *segs):
+        seg = None if not segs else (segs if pair else segs[0])
+        return flash_attention(q, k, v, causal=causal, segment_ids=seg,
+                               scale=scale, interpret=interpret)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(qkv_spec,) * 3 + (seg_spec,) * len(segs),
+        out_specs=qkv_spec, check_vma=False)(q, k, v, *segs)

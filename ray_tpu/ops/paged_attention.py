@@ -23,14 +23,15 @@ owned end to end:
   q·k exactly (the V lanes multiply zeros), and the accumulator runs over
   the full ``2*D`` lanes with the V half sliced once at finalize. The
   gather-free design is what moves decode from O(max_pages) HBM traffic
-  (plus a GQA broadcast) to O(used pages) — the difference between ~17 ms
-  and ~3 ms steps on a 1B model (VERDICT round 3, missing #1).
+  (plus a GQA broadcast) to O(used pages).
 - ``paged_prefill_attention`` splits prefill into (1) causal flash
   attention among the new tokens themselves — no page reads at all — and
   (2) segment-masked flash attention over the cached prefix pages, merged
   by log-sum-exp. Rows without a cached prefix mask part (2) entirely.
 - ``paged_attention_reference`` is the jnp gather path: the numerics
-  oracle for kernel parity tests and the off-TPU fallback.
+  oracle for kernel parity tests, the path a CPU backend runs, and the
+  path tensor-parallel engines ask for by argument. A TPU backend never
+  reaches it unasked.
 """
 
 from __future__ import annotations
@@ -44,22 +45,9 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _hbm_space(pltpu):
-    """pltpu.MemorySpace.HBM across jax versions (pre-0.5: the enum is
-    TPUMemorySpace and lacks HBM; ANY is the closest placement)."""
-    space = getattr(pltpu, "MemorySpace", None) \
-        or pltpu.TPUMemorySpace
-    return getattr(space, "HBM", space.ANY)
-
-
 def _fori_no_unroll(lo, hi, body, init):
-    """fori_loop with unrolling pinned OFF. Pre-0.5 jax only accepts the
-    `unroll` kwarg with static bounds (and its default is no-unroll
-    anyway), so fall back to the bare call there."""
-    try:
-        return jax.lax.fori_loop(lo, hi, body, init, unroll=False)
-    except ValueError:
-        return jax.lax.fori_loop(lo, hi, body, init)
+    """fori_loop with unrolling pinned OFF."""
+    return jax.lax.fori_loop(lo, hi, body, init, unroll=False)
 
 
 def make_kv_pages(num_kv_heads: int, num_pages: int, page_size: int,
@@ -300,9 +288,7 @@ def _decode_call(q, kv_pages, block_tables, lengths, *,
             # explicitly HBM (not ANY): the compiler would happily place
             # a small page pool in VMEM, where per-page slices violate
             # tile alignment — and the pool must not eat VMEM anyway.
-            # (pre-0.5 jax calls the enum TPUMemorySpace and has no HBM
-            # member — ANY is the closest it offers)
-            pl.BlockSpec(memory_space=_hbm_space(pltpu)),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
@@ -318,6 +304,22 @@ def _decode_call(q, kv_pages, block_tables, lengths, *,
     return out
 
 
+def decode_kernel_constraint(head_dim: int, page_size: int,
+                             dtype) -> Optional[str]:
+    """Why the compiled decode kernel cannot take this pool layout, or
+    None when it can. Mosaic's slice-alignment contract: the page's lane
+    width 2*head_dim is a multiple of 128 and a page covers whole sublane
+    tiles (16 rows of bfloat16, 8 of float32)."""
+    sublane = 16 if jnp.dtype(dtype) == jnp.bfloat16 else 8
+    if (2 * head_dim) % 128:
+        return (f"2*head_dim must be a multiple of 128 lanes, got "
+                f"head_dim={head_dim}")
+    if page_size % sublane:
+        return (f"page_size must be a multiple of {sublane} rows for "
+                f"{jnp.dtype(dtype).name}, got page_size={page_size}")
+    return None
+
+
 def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
                            block_tables: jax.Array, lengths: jax.Array, *,
                            scale: Optional[float] = None,
@@ -331,28 +333,30 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
     lengths: [B] total tokens per sequence (0 = inactive row -> zero
     output). Returns [B, Hq, D].
 
-    interpret: None = compiled kernel on TPU, jnp reference elsewhere;
-    True forces the kernel in interpreter mode (parity tests).
+    Which implementation runs is the caller's choice or the backend's,
+    never a quiet substitution: `force_reference=True` is the jnp gather
+    path (tensor-parallel engines, which trace under GSPMD);
+    `interpret=True` is the kernel in interpreter mode (parity tests);
+    with both left alone a TPU backend runs the compiled kernel — and
+    raises on a pool layout the kernel cannot take — while a CPU backend
+    (`JAX_PLATFORMS=cpu`) runs the reference.
     """
     d = q.shape[-1]
     scale_f = float(scale if scale is not None else d ** -0.5)
     page = kv_pages.shape[2]
-    # Mosaic slice-alignment contract for the compiled kernel: 2D lanes
-    # multiple of 128 and a page covering whole sublane tiles
-    sublane = 16 if kv_pages.dtype == jnp.bfloat16 else 8
-    kernel_ok = (2 * d) % 128 == 0 and page % sublane == 0
-    if interpret is None:
-        # force_reference: caller traces under GSPMD (tensor-parallel
-        # engine) where the single-device Pallas kernel cannot run
-        if force_reference or jax.default_backend() != "tpu" or not kernel_ok:
-            positions = jnp.maximum(lengths - 1, 0)[:, None]
-            out = paged_attention_reference(
-                q[:, None], kv_pages, block_tables, positions,
-                scale=scale_f)[:, 0]
-            # honor the inactive-row contract (length 0 -> zero output):
-            # the clamped position would otherwise admit kv position 0
-            return jnp.where((lengths > 0)[:, None, None], out, 0)
-        interpret = False
+    on_tpu = jax.default_backend() == "tpu"
+    if force_reference or (interpret is None and not on_tpu):
+        positions = jnp.maximum(lengths - 1, 0)[:, None]
+        out = paged_attention_reference(
+            q[:, None], kv_pages, block_tables, positions,
+            scale=scale_f)[:, 0]
+        # honor the inactive-row contract (length 0 -> zero output):
+        # the clamped position would otherwise admit kv position 0
+        return jnp.where((lengths > 0)[:, None, None], out, 0)
+    if not interpret:
+        why = decode_kernel_constraint(d, page, kv_pages.dtype)
+        if why is not None:
+            raise ValueError(f"paged decode kernel: {why}")
     if pages_per_chunk is None:
         # target ~128 kv rows per work item (one MXU-friendly tile)
         pages_per_chunk = max(1, min(block_tables.shape[1],
@@ -360,17 +364,18 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
     pages_per_chunk = min(pages_per_chunk, block_tables.shape[1])
     return _decode_call(q, kv_pages, block_tables, lengths,
                         scale=scale_f, pages_per_chunk=pages_per_chunk,
-                        interpret=interpret)
+                        interpret=bool(interpret))
 
 
 # --------------------------------------------------------- prefill (+ctx)
 def _attn_lse(q, k, v, *, causal, segment_ids, scale, impl=None):
     """Attention returning (o [B,S,Hq,D], lse [B,S,Hq]).
 
-    impl: None = flash kernel on TPU / jnp reference elsewhere;
-    "flash" forces the Pallas kernel (interpreter mode off-TPU);
-    "reference" forces the jnp path. Both parts of a merged prefill go
-    through the SAME implementation so their lse scales match exactly.
+    impl: None = flash kernel on a TPU backend, jnp reference on a CPU
+    backend (`JAX_PLATFORMS=cpu`); "flash" forces the Pallas kernel
+    (interpreter mode on a CPU backend); "reference" forces the jnp path.
+    Both parts of a merged prefill go through the SAME implementation so
+    their lse scales match exactly.
     """
     if impl == "flash" or (impl is None and jax.default_backend() == "tpu"):
         from .flash_attention import flash_attention

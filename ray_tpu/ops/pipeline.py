@@ -75,12 +75,10 @@ def gpipe(stage_fn: Callable[[Any, jax.Array], jax.Array],
             return (state, outputs), None
 
         # mark the carries as pp-varying (their values differ per rank)
-        from .shard_map_compat import pcast_varying
-
-        init = pcast_varying(
+        init = jax.lax.pcast(
             (jnp.zeros(mb_shape, x_mb.dtype),
              jnp.zeros((M,) + mb_shape, x_mb.dtype)),
-            ("pp",))
+            ("pp",), to="varying")
         (_, outputs), _ = jax.lax.scan(tick, init, jnp.arange(T))
         # broadcast the last stage's outputs to every pp rank (zeros
         # elsewhere, so the psum is exactly the last rank's value).
@@ -125,10 +123,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: jax.Array, *,
         local = jax.tree.map(lambda p: p[0], params)
         return run(local, xs)
 
-    from .shard_map_compat import shard_map
-
     n_spec = len(x_mb.shape) - 1
-    out = shard_map(
+    out = jax.shard_map(
         sharded,
         mesh=mesh,
         in_specs=(P("pp"), P(*([None] * (n_spec + 1)))),

@@ -27,8 +27,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from .shard_map_compat import shard_map  # noqa: F401  (version shim)
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .attention import NEG_INF, _repeat_kv

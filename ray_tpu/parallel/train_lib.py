@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..util.compile_cache import enable_compile_cache
 from . import sharding as shd
 from .mesh import active_mesh, create_mesh, MeshConfig
 
@@ -82,6 +83,7 @@ class ShardedTrainer:
                  rules=shd.DEFAULT_RULES,
                  loss_fn: Optional[Callable] = None,
                  donate_state: bool = True):
+        enable_compile_cache()
         self.model = model
         self.mesh = mesh if mesh is not None else create_mesh(MeshConfig())
         self.tx = optimizer or default_optimizer()
@@ -219,6 +221,17 @@ class ShardedTrainer:
                  for k, v in batch.items()}
         with active_mesh(self.mesh):
             return self._jit_step(state, batch)
+
+    def program_text(self, state: TrainState, batch) -> str:
+        """The lowered (StableHLO) text of the train step for this state
+        and batch — what chip_smoke.py reads to show that the Pallas
+        flash kernel (`tpu_custom_call`) is in the program."""
+        if not isinstance(batch, dict):
+            batch = {"input_ids": batch}
+        if self._jit_step is None:
+            self._build_step(batch)
+        with active_mesh(self.mesh):
+            return self._jit_step.lower(state, batch).as_text()
 
     def eval_loss(self, state: TrainState, batch) -> jax.Array:
         if self._jit_eval is None:

@@ -21,8 +21,8 @@ logger = logging.getLogger(__name__)
 def _apply_platform(platform: Optional[str]) -> None:
     """Pin this WORKER process's JAX backend before first use. RL env
     stepping and small policy nets belong on CPU even when an accelerator
-    is visible — per-step forwards on a remote-tunneled device pay a
-    round-trip each. Never touches the driver process (local mode): that
+    is visible — a per-step forward on an accelerator pays a dispatch and
+    a device sync each. Never touches the driver process (local mode): that
     would silently hide the TPU from the user's own JAX code."""
     if not platform or platform == "default":
         return

@@ -472,14 +472,18 @@ class RpcServer:
             del _local_servers[self.address]
         if self._server is not None:
             self._server.close()
-            try:
-                await self._server.wait_closed()
-            except Exception:  # rtpulint: ignore[RTPU006] — server teardown is best-effort; the listener fd is closed either way
-                pass
+        # connections first: since Python 3.12.1 Server.wait_closed()
+        # waits for every accepted connection to close, so awaiting it
+        # with a client still attached never returns
         for conn in list(self.conns):
             try:
                 conn.writer.close()
             except Exception:  # rtpulint: ignore[RTPU006] — peer may already be gone at stop; nothing to report
+                pass
+        if self._server is not None:
+            try:
+                await self._server.wait_closed()
+            except Exception:  # rtpulint: ignore[RTPU006] — server teardown is best-effort; the listener fd is closed either way
                 pass
 
     async def _on_conn(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):

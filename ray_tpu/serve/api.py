@@ -164,6 +164,16 @@ def run(app: Application, *, name: str = DEFAULT_APP_NAME,
         st = ray_tpu.get(controller.status.remote())["applications"].get(name)
         if st and st["status"] == "RUNNING":
             break
+        if st and st["status"] == "DEPLOY_FAILED":
+            # a replica's constructor keeps raising (e.g. it cannot
+            # initialise its device): surface ITS error now
+            errors = "\n".join(
+                f"{dep}: {d['message']}"
+                for dep, d in st["deployments"].items()
+                if d["status"] == "DEPLOY_FAILED")
+            raise RuntimeError(
+                f"app {name!r} failed to deploy: replica constructor "
+                f"failed {errors}")
         time.sleep(0.05)
     else:
         raise RuntimeError(

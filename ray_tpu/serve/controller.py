@@ -41,6 +41,12 @@ class _ReplicaState:
 
 
 REPLICA_STARTUP_TIMEOUT_S = 600.0
+# A replica whose constructor raises (its actor dies with "creation
+# failed": a device that cannot be initialised, a bad config) is retried
+# this many times; then the deployment is DEPLOY_FAILED and carries the
+# constructor's error, so serve.run raises it instead of waiting out its
+# timeout on a replica that is forever STARTING.
+MAX_REPLICA_START_FAILURES = 3
 
 # cluster prefix-cache registry: poll cadence for replica frontiers and
 # the staleness TTL past which an entry stops influencing routing
@@ -58,6 +64,11 @@ class _DeploymentState:
         self.version = 0
         self.is_ingress = False
         self.name = ""
+        # consecutive constructor failures with no replica reaching READY
+        # in between, and the last one's error (see
+        # MAX_REPLICA_START_FAILURES)
+        self.start_failures = 0
+        self.start_error: Optional[str] = None
         # autoscaling smoothing state
         self._scale_up_since: Optional[float] = None
         self._scale_down_since: Optional[float] = None
@@ -122,6 +133,8 @@ class ServeControllerActor:
                 state.spec_blob = item["spec_blob"]
                 state.config = config
                 state.target_replicas = config.initial_replicas()
+                state.start_failures = 0  # a redeploy gets fresh tries
+                state.start_error = None
                 if not _same_code(old_blob, item["spec_blob"]):
                     self._stop_all_replicas(state)
                 elif old_cfg.user_config != config.user_config:
@@ -186,8 +199,11 @@ class ServeControllerActor:
                 await self._autoscale(state)
                 await self._health_check(state)
                 await self._kv_poll(state)
-                # Scale up
-                while len(state.replicas) < state.target_replicas:
+                # Scale up (not past the start-failure bound: a
+                # constructor that keeps raising is not retried forever)
+                while (len(state.replicas) < state.target_replicas
+                       and state.start_failures
+                       < MAX_REPLICA_START_FAILURES):
                     self._start_replica(state)
                 # Scale down (newest first, like the reference's default)
                 while len(state.replicas) > state.target_replicas:
@@ -295,16 +311,19 @@ class ServeControllerActor:
         for replica_id, rep in list(state.replicas.items()):
             if rep.check_task is not None:
                 if rep.check_task.done():
-                    failed = (rep.check_task.cancelled()
-                              or rep.check_task.exception() is not None)
+                    exc = (None if rep.check_task.cancelled()
+                           else rep.check_task.exception())
+                    failed = rep.check_task.cancelled() or exc is not None
                     rep.check_task = None
                     if not failed:
                         rep.healthy = True
                         if not rep.ready:
                             rep.ready = True
+                            state.start_failures = 0
                             state.version += 1  # newly routable replica
                     else:
-                        self._on_check_failure(state, replica_id, rep, now)
+                        self._on_check_failure(state, replica_id, rep, now,
+                                               exc)
                 elif (now - rep.check_started
                         > state.config.health_check_timeout_s):
                     rep.check_task.cancel()
@@ -324,10 +343,18 @@ class ServeControllerActor:
                     rep.handle.check_health.remote().future()))
 
     def _on_check_failure(self, state: _DeploymentState, replica_id: str,
-                          rep: _ReplicaState, now: float) -> None:
-        if (not rep.ready
-                and now - rep.started_at < REPLICA_STARTUP_TIMEOUT_S):
-            return  # constructor may still be running
+                          rep: _ReplicaState, now: float,
+                          exc: Optional[BaseException] = None) -> None:
+        from ..exceptions import ActorDiedError
+
+        if not rep.ready:
+            if isinstance(exc, ActorDiedError):
+                # the constructor raised (or the worker died under it):
+                # count it and keep the error for status()/serve.run
+                state.start_failures += 1
+                state.start_error = str(exc)
+            elif now - rep.started_at < REPLICA_STARTUP_TIMEOUT_S:
+                return  # constructor may still be running
         rep.healthy = False
         # Replace the dead replica (ref: deployment_state.py replica
         # recovery path).
@@ -570,9 +597,14 @@ class ServeControllerActor:
             for name, state in states.items():
                 n_ready = sum(1 for rep in state.replicas.values()
                               if rep.ready)
+                failed = (n_ready < state.target_replicas
+                          and state.start_failures
+                          >= MAX_REPLICA_START_FAILURES)
                 deployments[name] = {
                     "status": ("HEALTHY" if n_ready >= state.target_replicas
+                               else "DEPLOY_FAILED" if failed
                                else "UPDATING"),
+                    "message": state.start_error if failed else "",
                     "replicas": n_ready,
                     "target_replicas": state.target_replicas,
                     # overload observability: the published brownout EWMA
@@ -580,8 +612,12 @@ class ServeControllerActor:
                 }
             app_ok = all(d["status"] == "HEALTHY"
                          for d in deployments.values())
+            app_failed = any(d["status"] == "DEPLOY_FAILED"
+                             for d in deployments.values())
             out["applications"][app_name] = {
-                "status": "RUNNING" if app_ok else "DEPLOYING",
+                "status": ("RUNNING" if app_ok
+                           else "DEPLOY_FAILED" if app_failed
+                           else "DEPLOYING"),
                 "route_prefix": self._route_prefixes.get(app_name, "/"),
                 "deployments": deployments,
             }

@@ -112,8 +112,10 @@ class _StageWorker:
         import jax.numpy as jnp
 
         from ...models.llama import StageModel, get_config
+        from ...util.compile_cache import enable_compile_cache
         from .sharding import resolve_serve_mesh
 
+        enable_compile_cache()
         self.config = config
         self.stage = int(stage)
         self.pp = int(config.pp)
@@ -132,6 +134,9 @@ class _StageWorker:
         self.sharding = resolve_serve_mesh(None, tp=config.tp)
         if self.sharding is not None:
             self.sharding.validate(self.model_cfg)
+        from .engine import resolve_attention
+
+        resolve_attention(self.model_cfg, config, self.sharding)
         shape = (self.n_layers, config.num_pages,
                  self.model_cfg.num_kv_heads, config.page_size,
                  2 * self.model_cfg.head_dim_)
@@ -365,6 +370,9 @@ class PipelinedEngine(LLMEngine):
         # the driver holds NO device state: stages own the params and
         # the KV pool; the scheduler's page ids are global bookkeeping
         self.sharding = None
+        self._attention = {"decode": "per stage worker",
+                           "prefill": "per stage worker"}
+        self._device = None  # the scheduler process holds no device state
         self.kv_pages = None
         self.slot_ids = None
         self._pp = pp

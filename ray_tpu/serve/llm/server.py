@@ -173,6 +173,8 @@ class EngineDriverMixin:
     def engine_stats(self) -> Dict[str, Any]:
         stats = self.engine.stats()
         self._publish_llm_metrics(stats)
+        # seconds the constructor spent compiling every dispatch shape
+        stats["warmup_s"] = getattr(self, "_warmup_s", None)
         return stats
 
     def kv_frontier(self,
@@ -220,8 +222,11 @@ class LLMServer(EngineDriverMixin):
         # pipeline-parallel stage gang (serve/llm/pp.py); same engine
         # surface, so the driver loop and streaming path are unchanged
         self.engine = make_engine(engine_cfg)
+        self._warmup_s = None
         if llm_config.warmup:
+            t0 = time.monotonic()
             self.engine.warmup()
+            self._warmup_s = round(time.monotonic() - t0, 2)
         self._ids = itertools.count()
         self._init_driver()
 

@@ -28,6 +28,18 @@ class ByteTokenizer:
         return data.decode("utf-8", errors="replace")
 
 
+class TokenIdTokenizer(ByteTokenizer):
+    """For weights made from a seed, where no vocabulary exists: prompts
+    encode as UTF-8 bytes like ByteTokenizer, and completions decode to
+    their token ids in decimal, space-separated — every id of any vocab
+    size is visible in the text, so an HTTP client can check a completion
+    token for token (chip_smoke.py does). ByteTokenizer.decode drops ids
+    >= 256, which for a random 32k-vocab model is nearly all of them."""
+
+    def decode(self, ids: List[int]) -> str:
+        return " ".join(str(int(i)) for i in ids)
+
+
 def get_tokenizer(spec):
     """spec: None -> ByteTokenizer; a string -> HF AutoTokenizer path/name;
     any object with encode/decode -> used as-is."""

@@ -92,11 +92,16 @@ class TrainWorker:
         import time
 
         plat = os.environ.get("RTPU_JAX_PLATFORMS")
-        if plat:
+        distributed = os.environ.get("RTPU_JAX_DISTRIBUTED") == "1"
+        if plat or distributed:
             import jax
 
-            jax.config.update("jax_platforms", plat)
-        if os.environ.get("RTPU_JAX_DISTRIBUTED") != "1":
+            from ..util.compile_cache import enable_compile_cache
+
+            enable_compile_cache()
+            if plat:
+                jax.config.update("jax_platforms", plat)
+        if not distributed:
             return
         num = int(os.environ.get("RTPU_JAX_NUM_PROCESSES",
                                  str(self.world_size)))
@@ -125,8 +130,6 @@ class TrainWorker:
                 time.sleep(0.2)
             if addr is None:
                 raise TimeoutError("jax coordinator address never published")
-        import jax
-
         jax.distributed.initialize(coordinator_address=addr,
                                    num_processes=num,
                                    process_id=self.rank)

@@ -121,13 +121,6 @@ def test_sharded_training_loss_decreases(cpu_mesh_devices):
     assert float(metrics["loss"]) < first
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="sharded-vs-single-device loss parity fails identically at the "
-    "seed on this image's jax 0.4.37 pin (PR 1; reconfirmed at HEAD in "
-    "PR 6) — same GSPMD reduction-order parity family as the "
-    "test_ring_attention train-step parity failure. Not strict: a future "
-    "jax bump may restore parity.")
 @pytest.mark.slow
 def test_sharded_matches_single_device(cpu_mesh_devices):
     """The same seed on a sharded mesh and a single device must produce the

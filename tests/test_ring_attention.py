@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.ops.shard_map_compat import shard_map
+from jax import shard_map
 
 from ray_tpu.ops.attention import reference_attention
 from ray_tpu.ops.ring_attention import (
@@ -141,15 +141,6 @@ def test_ulysses_gqa_segment_ids(sp_mesh):
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="ring-vs-dense train-step loss parity fails identically at the "
-    "seed on this image's jax 0.4.37 pin — the same GSPMD "
-    "reduction-order parity family as test_model_parallel's "
-    "test_sharded_matches_single_device (PR 1/PR 6). The kernel-level "
-    "ring/ulysses parity tests above DO pass; only the end-to-end "
-    "sharded train step differs. Not strict: a future jax bump may "
-    "restore parity.")
 def test_llama_train_step_with_ring_matches_dense(cpu_mesh_devices):
     """End-to-end: one ShardedTrainer step on an sp=2 mesh with ring
     attention produces the same loss as the dense path."""

@@ -209,6 +209,30 @@ def test_replica_failure_recovers(serve_cluster):
     serve.delete("fragile")
 
 
+def test_replica_device_init_failure_surfaces_in_serve_run(serve_cluster):
+    """A replica that cannot initialise its device (injected: the
+    constructor raises what jax raises when no chip can be had) must not
+    sit in STARTING until serve.run's timeout: the caller gets the device
+    error itself, well inside the start-up timeout."""
+    @serve.deployment
+    class NoChip:
+        def __init__(self):
+            raise RuntimeError(
+                "Unable to initialize backend 'tpu': UNAVAILABLE: No TPU "
+                "device found (injected)")
+
+        def __call__(self):
+            return "unreachable"
+
+    t0 = time.time()
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        serve.run(NoChip.bind(), name="nochip", wait_timeout_s=60)
+    assert time.time() - t0 < 30
+    st = serve.status()["applications"]["nochip"]
+    assert st["status"] == "DEPLOY_FAILED"
+    serve.delete("nochip")
+
+
 def test_model_multiplexing(serve_cluster):
     """@serve.multiplexed LRU-caches models per replica; the request's
     model id routes with affinity and is visible via
