@@ -133,23 +133,6 @@ def bench_serve(on_tpu: bool) -> dict:
            "n_requests": n_req, "prompt_len": prompt_len,
            "burst_trials": 3}
 
-    # prefill compute efficiency: synchronous prefill-only MFU on the
-    # engine's compiled shape (VERDICT r4 #7 — TTFT met its target but
-    # carried no visibility into remaining prefill headroom)
-    try:
-        import jax
-
-        out["prefill"] = engine.measure_prefill(
-            seq_len=prompt_len, iters=16 if on_tpu else 3,
-            peak_flops=(_peak_flops(jax.devices()[0]) if on_tpu
-                        else None))
-        if "mfu_compute" in out["prefill"]:
-            # compute-only estimate (engine.measure_prefill separates
-            # the per-dispatch host sync from the chained compute)
-            out["prefill_mfu"] = out["prefill"]["mfu_compute"]
-    except Exception as e:  # noqa: BLE001 — never block the wave tiers
-        out["prefill"] = {"error": repr(e)[:200]}
-
     # sustained Poisson arrivals: ~12 req over ~4s (rate chosen well
     # under the decode capacity so the queue stays bounded)
     if time.perf_counter() - t_bench > 400:

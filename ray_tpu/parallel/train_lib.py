@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..util import tracing
 from ..util.compile_cache import enable_compile_cache
 from . import sharding as shd
 from .mesh import active_mesh, create_mesh, MeshConfig
@@ -96,6 +97,7 @@ class ShardedTrainer:
         self._state_shardings = None
         self._jit_step = None
         self._jit_eval = None
+        self._step_seq = 0
         self._donate = donate_state
 
     # -------------------------------------------------------------- loss
@@ -217,10 +219,16 @@ class ShardedTrainer:
             batch = {"input_ids": batch}
         if self._jit_step is None:
             self._build_step(batch)
-        batch = {k: jax.device_put(v, self._batch_sharding)
-                 for k, v in batch.items()}
-        with active_mesh(self.mesh):
-            return self._jit_step(state, batch)
+        # the host's time to dispatch one step (not the step's): one
+        # `train.step` flight record a call
+        self._step_seq += 1
+        with tracing.region("rtpu.train.step") as r:
+            batch = {k: jax.device_put(v, self._batch_sharding)
+                     for k, v in batch.items()}
+            with active_mesh(self.mesh):
+                out = self._jit_step(state, batch)
+        tracing.record("train.step", (self._step_seq, r.start_ns, r.end_ns))
+        return out
 
     def program_text(self, state: TrainState, batch) -> str:
         """The lowered (StableHLO) text of the train step for this state

@@ -83,9 +83,14 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ...util import tracing
 from .cache import OutOfPages, PageAllocator
 
 WAITING, RUNNING, FINISHED = "WAITING", "RUNNING", "FINISHED"
+# where a step's nanoseconds go (indices into LLMEngine._phase_ns, in the
+# order of the `engine.step` record's fields)
+_INTAKE, _ADMIT, _DISPATCH_PREFILL, _DISPATCH_DECODE, _FETCH, _HARVEST = \
+    range(6)
 
 
 @dataclasses.dataclass
@@ -133,6 +138,17 @@ class Request:
     # device carry is not updated by verify, so no other decode dispatch
     # may touch the slot until the harvest resolves acceptance
     spec_inflight: bool = False
+    # the request's timeline for its `engine.request` flight record, on
+    # the recorder's clock (ray_tpu/util/tracing.py); arrival_t and the
+    # deadline logic stay on time.monotonic()
+    arrival_ns: int = dataclasses.field(default_factory=tracing.now_ns)
+    admitted_ns: Optional[int] = None    # slot and pages granted
+    dispatched_ns: Optional[int] = None  # first prefill dispatch; reset
+                                         # by a preemption as dispatched_t
+    first_token_ns: Optional[int] = None
+    preemptions: int = 0
+    n_folded: int = 0            # output tokens a preemption folded into
+                                 # prompt_ids
 
     @property
     def total_len(self) -> int:
@@ -429,8 +445,21 @@ class LLMEngine:
         # pending-first-decode override: slot -> host-known pending token
         # (set after prefill harvest / injection / re-admission)
         self._slot_override: Dict[int, int] = {}
-        # FIFO of in-flight dispatches awaiting harvest
+        # FIFO of in-flight dispatches awaiting harvest; each dict is the
+        # dispatch's `engine.dispatch` flight record in the making
         self._inflight: List[dict] = []
+        # flight recorder (ray_tpu/util/tracing.py): sequence numbers of
+        # steps and dispatches, the running step's nanoseconds by phase,
+        # and cumulative totals of the same facts for stats()
+        self._step_seq = 0
+        self._dispatch_seq = 0
+        self._phase_ns = [0] * 6
+        self._totals = dict.fromkeys((
+            "steps_total", "prefill_dispatches_total",
+            "decode_dispatches_total", "prefill_tokens_total",
+            "prefill_padded_tokens_total", "decode_rows_total",
+            "decode_ctx_tokens_total", "programs_built_total"), 0)
+        self._queue_wait_ns_total = 0
 
     # ----------------------------------------------------------- intake
 
@@ -500,29 +529,47 @@ class LLMEngine:
           harvest enough dispatches to keep the backlog under the
           pipeline depth, so a running slot's inter-token gap is one
           decode chunk + one prefill chunk instead of one whole prompt.
+
+        Every step leaves one `engine.step` flight record: its start and
+        end and the nanoseconds of each phase (admit, dispatch_prefill,
+        fetch and harvest are timed where they happen, further down).
         """
-        deltas: List[OutputDelta] = list(self._pending_deltas)
-        self._pending_deltas.clear()
-        self._drain_intake(deltas)
-        self._prune_expired_running(deltas)
-        self._prune_expired_waiting(deltas)
-        self._try_admit_injection(deltas)
-        chunked = self.config.prefill_chunk_tokens > 0
-        depth = max(1, int(self.config.pipeline_depth))
-        if not chunked:
-            self._dispatch_prefills()
-        while (len(self._inflight) < depth
-               and (self._dispatch_spec()
-                    or self._dispatch_decode_chunk())):
-            pass
-        if chunked:
-            self._dispatch_prefill_chunks()
-            if self._inflight:
+        self._step_seq += 1
+        self._totals["steps_total"] += 1
+        phase = self._phase_ns
+        phase[:] = (0, 0, 0, 0, 0, 0)
+        with tracing.region("rtpu.engine.step") as whole:
+            deltas: List[OutputDelta] = list(self._pending_deltas)
+            self._pending_deltas.clear()
+            with tracing.region("rtpu.engine.intake") as r:
+                self._drain_intake(deltas)
+                self._prune_expired_running(deltas)
+                self._prune_expired_waiting(deltas)
+                self._try_admit_injection(deltas)
+            # an injection drains the pipeline first: that time is
+            # already under fetch and harvest
+            phase[_INTAKE] = r.ns - phase[_FETCH] - phase[_HARVEST]
+            chunked = self.config.prefill_chunk_tokens > 0
+            depth = max(1, int(self.config.pipeline_depth))
+            if not chunked:
+                self._dispatch_prefills()
+            with tracing.region("rtpu.engine.dispatch_decode") as r:
+                while (len(self._inflight) < depth
+                       and (self._dispatch_spec()
+                            or self._dispatch_decode_chunk())):
+                    pass
+            phase[_DISPATCH_DECODE] = r.ns
+            if chunked:
+                self._dispatch_prefill_chunks()
+                if self._inflight:
+                    self._harvest(self._inflight.pop(0), deltas)
+                while len(self._inflight) >= depth:
+                    self._harvest(self._inflight.pop(0), deltas)
+            elif self._inflight:
                 self._harvest(self._inflight.pop(0), deltas)
-            while len(self._inflight) >= depth:
-                self._harvest(self._inflight.pop(0), deltas)
-        elif self._inflight:
-            self._harvest(self._inflight.pop(0), deltas)
+        tracing.record("engine.step", (
+            self._step_seq, whole.start_ns, whole.end_ns, *phase,
+            len(self.running), len(self.waiting)))
         return deltas
 
     def _drain_pipeline(self, deltas: List[OutputDelta]) -> None:
@@ -594,6 +641,7 @@ class LLMEngine:
                 req.state = FINISHED
                 req.finish_reason = "expired"
                 self.requests.pop(req.request_id, None)
+                self._record_request(req)
                 self._expired_total += 1
                 deltas.append(OutputDelta(req.request_id, [], True,
                                           "expired"))
@@ -693,6 +741,7 @@ class LLMEngine:
             req.state = RUNNING
             req.slot = self._free_slots.pop(0)
             req.planned_out = 0
+            req.admitted_ns = tracing.now_ns()
             self._slot_req[req.slot] = req
             self.running.append(req)
             return req
@@ -711,6 +760,9 @@ class LLMEngine:
         fn = self._jit_cache.get(key)
         if fn is not None:
             return fn
+        self._totals["programs_built_total"] += 1
+        tracing.record("engine.program_built", (
+            kind, shape_key, tracing.now_ns(), self._step_seq))
         model = self.model
         L = self.model_cfg.num_layers
         # sharded engines trace under GSPMD, where the single-device
@@ -911,11 +963,13 @@ class LLMEngine:
         prompt would make TTFT linear in the queue)."""
         admitted = []
         burst_prefixes: set = set()
-        while len(self.running) < self.config.max_batch:
-            req = self._admit_one(burst_prefixes)
-            if req is None:
-                break
-            admitted.append(req)
+        with tracing.region("rtpu.engine.admit") as r:
+            while len(self.running) < self.config.max_batch:
+                req = self._admit_one(burst_prefixes)
+                if req is None:
+                    break
+                admitted.append(req)
+        self._phase_ns[_ADMIT] += r.ns
         if not admitted:
             return
         wave = self._wave_rb
@@ -973,7 +1027,9 @@ class LLMEngine:
         burst_prefixes: set = set()
         while (used < budget and len(rows) < self._wave_rb
                and len(self.running) < self.config.max_batch):
-            req = self._admit_one(burst_prefixes)
+            with tracing.region("rtpu.engine.admit") as r:
+                req = self._admit_one(burst_prefixes)
+            self._phase_ns[_ADMIT] += r.ns
             if req is None:
                 break
             n_new = grant(req, budget - used)
@@ -1018,39 +1074,66 @@ class LLMEngine:
         # length bucket (per-size row buckets would multiply the compile
         # shapes, and an unwarmed shape hit mid-traffic is a
         # multi-second TTFT spike)
-        rb = self._wave_rb
-        ids = np.zeros((rb, sb), np.int32)
-        positions = np.zeros((rb, sb), np.int32)
-        bt = np.zeros((rb, self.max_pages_per_seq), np.int32)
-        total = np.zeros((rb,), np.int32)
-        gather = np.zeros((rb,), np.int32)
-        rows = []
-        for i, (req, n_new) in enumerate(group):
-            start = req.n_prefilled
-            ids[i, :n_new] = req.prompt_ids[start:start + n_new]
-            positions[i] = start + np.arange(sb, dtype=np.int32)
-            bt[i, :len(req.pages)] = req.pages
-            total[i] = start + n_new
-            gather[i] = n_new - 1
-            final = start + n_new >= len(req.prompt_ids)
-            rows.append((req.request_id, req.slot, start + n_new, final))
-        now = time.monotonic()
-        for req, _ in group:
-            if req.dispatched_t is None:
-                req.dispatched_t = now
-        cp = (self.max_pages_per_seq
-              if any(req.n_prefilled for req, _ in group) else 0)
-        temp, topk, keys = self._sampling_arrays(
-            [req for req, _ in group], rb)
-        tokens = self._compute_prefill(sb, rb, cp, bt, total, ids,
-                                       positions, gather, temp, topk, keys)
-        for req, n_new in group:
-            req.n_prefilled += n_new
-            if req.n_prefilled >= len(req.prompt_ids):
-                req.planned_out = 1
+        with tracing.region("rtpu.engine.dispatch_prefill") as r:
+            rb = self._wave_rb
+            ids = np.zeros((rb, sb), np.int32)
+            positions = np.zeros((rb, sb), np.int32)
+            bt = np.zeros((rb, self.max_pages_per_seq), np.int32)
+            total = np.zeros((rb,), np.int32)
+            gather = np.zeros((rb,), np.int32)
+            rows = []
+            facts = []
+            for i, (req, n_new) in enumerate(group):
+                start = req.n_prefilled
+                ids[i, :n_new] = req.prompt_ids[start:start + n_new]
+                positions[i] = start + np.arange(sb, dtype=np.int32)
+                bt[i, :len(req.pages)] = req.pages
+                total[i] = start + n_new
+                gather[i] = n_new - 1
+                final = start + n_new >= len(req.prompt_ids)
+                rows.append((req.request_id, req.slot, start + n_new,
+                             final))
+                facts.append((req.request_id, n_new, start + n_new))
+            now = time.monotonic()
+            for req, _ in group:
+                if req.dispatched_t is None:
+                    req.dispatched_t = now
+                    req.dispatched_ns = r.start_ns
+            cp = (self.max_pages_per_seq
+                  if any(req.n_prefilled for req, _ in group) else 0)
+            temp, topk, keys = self._sampling_arrays(
+                [req for req, _ in group], rb)
+            tokens = self._compute_prefill(sb, rb, cp, bt, total, ids,
+                                           positions, gather, temp, topk,
+                                           keys)
+            for req, n_new in group:
+                req.n_prefilled += n_new
+                if req.n_prefilled >= len(req.prompt_ids):
+                    req.planned_out = 1
+            self._totals["prefill_dispatches_total"] += 1
+            self._totals["prefill_tokens_total"] += sum(
+                n_new for _, n_new in group)
+            self._totals["prefill_padded_tokens_total"] += rb * sb
+            self._enqueue("prefill", tokens, r.start_ns, rb, rb * sb,
+                          facts, group=rows)
+        self._phase_ns[_DISPATCH_PREFILL] += r.ns
+
+    def _enqueue(self, kind: str, toks, dispatch_ns: int, rows_padded: int,
+                 tokens_padded: int, facts: List[tuple], k: int = 1,
+                 **harvest_keys) -> None:
+        """Queue one enqueued program for harvest. The dict is also its
+        `engine.dispatch` flight record in the making: `facts` is one
+        (request_id, q_tokens, ctx_tokens) per real row — the tokens the
+        row computes and the tokens of KV it attends to, cached prefix
+        included (for a k-step decode row: at its first step) —
+        `rows_padded`/`tokens_padded` are what the program computes.
+        _harvest adds the fetch's timestamps and writes the record."""
+        self._dispatch_seq += 1
         self._inflight.append({
-            "kind": "prefill", "toks": tokens, "group": rows,
-        })
+            "kind": kind, "toks": toks, "k": k, "seq": self._dispatch_seq,
+            "step": self._step_seq, "dispatch_ns": dispatch_ns,
+            "rows_padded": rows_padded, "tokens_padded": tokens_padded,
+            "facts": tuple(facts), **harvest_keys})
 
     @staticmethod
     def _prompt_lookup_draft(req: Request, max_len: int) -> List[int]:
@@ -1129,6 +1212,7 @@ class LLMEngine:
         bt = np.zeros((rb, self.max_pages_per_seq), np.int32)
         total_arr = np.zeros((rb,), np.int32)
         recs = []
+        facts = []
         for i, (req, draft) in enumerate(rows):
             total = len(req.prompt_ids) + len(req.output_ids)
             pending = (req.output_ids[-1] if req.output_ids
@@ -1144,10 +1228,12 @@ class LLMEngine:
             total_arr[i] = total + n
             recs.append((req.request_id, req.slot, len(req.output_ids),
                          list(draft)))
+            facts.append((req.request_id, n + 1, total + n))
             req.planned_out += n + 1  # optimistic; rolled back at harvest
             req.spec_inflight = True
             self._spec_drafted_total += n
         fn = self._jit("verify", (sb, rb))
+        dispatch_ns = tracing.now_ns()
         toks, self.kv_pages = fn(
             self.params, self.kv_pages, jnp.asarray(bt),
             jnp.asarray(total_arr), jnp.asarray(ids),
@@ -1156,8 +1242,8 @@ class LLMEngine:
             toks.copy_to_host_async()
         except Exception:  # noqa: BLE001  # rtpulint: ignore[RTPU006] — optional D2H prefetch: CPU backends lack it; harvest blocks on the array either way
             pass
-        self._inflight.append({"kind": "spec", "toks": toks,
-                               "rows": recs})
+        self._enqueue("spec", toks, dispatch_ns, rb, rb * sb, facts,
+                      rows=recs)
         return True
 
     def _decode_eligible(self) -> List[Request]:
@@ -1248,6 +1334,7 @@ class LLMEngine:
         override_mask = np.zeros((S,), bool)
         override_ids = np.zeros((S, 1), np.int32)
         chunk_slots = {}
+        facts = []
         for req in elig:
             s = req.slot
             planned_total = len(req.prompt_ids) + req.planned_out
@@ -1259,6 +1346,7 @@ class LLMEngine:
                 override_mask[s] = True
                 override_ids[s, 0] = self._slot_override.pop(s)
             chunk_slots[s] = (req.request_id, req.planned_out)
+            facts.append((req.request_id, k_steps, planned_total))
         keys_steps = np.zeros((k_steps, S, 2), np.uint32)
         temp = np.zeros((S,), np.float32)
         topk = np.zeros((S,), np.int32)
@@ -1271,83 +1359,102 @@ class LLMEngine:
                 temp, topk = t_k, tk_k
         for req in elig:
             req.planned_out += k_steps
+        dispatch_ns = tracing.now_ns()
         toks = self._compute_decode(k_steps, mp, bt, total, caps,
                                     positions, override_mask,
                                     override_ids, temp, topk, keys_steps)
-        self._inflight.append({
-            "kind": "decode", "toks": toks, "slots": chunk_slots,
-            "k": k_steps,
-        })
+        self._enqueue_decode(toks, dispatch_ns, k_steps, facts,
+                             chunk_slots)
         return True
+
+    def _enqueue_decode(self, toks, dispatch_ns: int, k_steps: int,
+                        facts: List[tuple], chunk_slots: dict) -> None:
+        """_enqueue for a decode chunk over the full slot set, with the
+        stats() totals a decode dispatch moves."""
+        S = self.config.max_batch
+        self._totals["decode_dispatches_total"] += 1
+        self._totals["decode_rows_total"] += len(facts)
+        self._totals["decode_ctx_tokens_total"] += sum(
+            ctx for _, _, ctx in facts)
+        self._enqueue("decode", toks, dispatch_ns, S, S * k_steps, facts,
+                      k=k_steps, slots=chunk_slots)
 
     # ---------------------------------------------------------- harvest
 
     def _harvest(self, rec: dict, deltas: List[OutputDelta]) -> None:
-        toks_np = self._fetch_tokens(rec["toks"])
-        if rec["kind"] == "prefill":
-            for i, (rid, slot, end, final) in enumerate(rec["group"]):
-                req = self.requests.get(rid)
-                if req is None or req.state != RUNNING or req.slot != slot:
-                    continue  # aborted while in flight
-                self._register_full_pages(req, upto=end)
-                if not final:
-                    # intermediate chunk: pages are written; the sampled
-                    # token (mid-prompt continuation) is meaningless
-                    continue
-                token = int(toks_np[i])
-                # the decode chain reads this slot's first input from the
-                # host-side override (the prefill wrote pages, not the
-                # slot carry)
-                self._slot_override[slot] = token
-                req.decode_ready = True
-                self._append_token(req, token, deltas)
-            return
-        if rec["kind"] == "spec":
-            # toks_np is [rb, sb]: g[j] = the model's argmax AFTER input
-            # column j. Accept g[0] (computed from the true pending
-            # token), then each g[j] while draft[j-1] == g[j-1] — the
-            # draft token fed at column j was the model's own choice, so
-            # everything before the first mismatch is exactly what plain
-            # greedy decode would have produced.
-            for i, (rid, slot, start, draft) in enumerate(rec["rows"]):
-                req = self.requests.get(rid)
-                if req is None:
-                    continue
-                req.spec_inflight = False
-                if (req.state != RUNNING or req.slot != slot
-                        or len(req.output_ids) != start):
-                    continue  # finished/aborted while in flight
-                g = toks_np[i]
-                emitted = [int(g[0])]
-                for j in range(1, len(draft) + 1):
-                    if int(draft[j - 1]) != emitted[-1]:
-                        break
-                    emitted.append(int(g[j]))
-                self._spec_accepted_total += len(emitted) - 1
-                for tok in emitted:
-                    if req.state != RUNNING:
-                        break  # EOS/stop/length inside the accepted run
-                    self._append_token(req, tok, deltas)
-                if req.state == RUNNING:
-                    # roll the optimistic plan back to reality and feed
-                    # the next dispatch the last ACCEPTED token (verify
-                    # never touches the device carry); rejected draft
-                    # writes sit beyond total and are rewritten before
-                    # any live request's attention can reach them
-                    req.planned_out = len(req.output_ids)
-                    self._slot_override[req.slot] = req.output_ids[-1]
-            return
-        # decode chunk: toks_np is [K, S]
-        k_steps = rec["k"]
-        for slot, (rid, start) in rec["slots"].items():
-            req = self.requests.get(rid)
-            if (req is None or req.state != RUNNING or req.slot != slot
-                    or len(req.output_ids) != start):
-                continue  # finished/aborted/preempted while in flight
-            for k in range(k_steps):
-                if req.state != RUNNING:
-                    break
-                self._append_token(req, int(toks_np[k, slot]), deltas)
+        with tracing.region("rtpu.engine.fetch") as fetch:
+            toks_np = self._fetch_tokens(rec["toks"])
+        with tracing.region("rtpu.engine.harvest") as r:
+            if rec["kind"] == "prefill":
+                for i, (rid, slot, end, final) in enumerate(rec["group"]):
+                    req = self.requests.get(rid)
+                    if req is None or req.state != RUNNING or req.slot != slot:
+                        continue  # aborted while in flight
+                    self._register_full_pages(req, upto=end)
+                    if not final:
+                        # intermediate chunk: pages are written; the sampled
+                        # token (mid-prompt continuation) is meaningless
+                        continue
+                    token = int(toks_np[i])
+                    # the decode chain reads this slot's first input from the
+                    # host-side override (the prefill wrote pages, not the
+                    # slot carry)
+                    self._slot_override[slot] = token
+                    req.decode_ready = True
+                    self._append_token(req, token, deltas)
+            elif rec["kind"] == "spec":
+                # toks_np is [rb, sb]: g[j] = the model's argmax AFTER input
+                # column j. Accept g[0] (computed from the true pending
+                # token), then each g[j] while draft[j-1] == g[j-1] — the
+                # draft token fed at column j was the model's own choice, so
+                # everything before the first mismatch is exactly what plain
+                # greedy decode would have produced.
+                for i, (rid, slot, start, draft) in enumerate(rec["rows"]):
+                    req = self.requests.get(rid)
+                    if req is None:
+                        continue
+                    req.spec_inflight = False
+                    if (req.state != RUNNING or req.slot != slot
+                            or len(req.output_ids) != start):
+                        continue  # finished/aborted while in flight
+                    g = toks_np[i]
+                    emitted = [int(g[0])]
+                    for j in range(1, len(draft) + 1):
+                        if int(draft[j - 1]) != emitted[-1]:
+                            break
+                        emitted.append(int(g[j]))
+                    self._spec_accepted_total += len(emitted) - 1
+                    for tok in emitted:
+                        if req.state != RUNNING:
+                            break  # EOS/stop/length inside the accepted run
+                        self._append_token(req, tok, deltas)
+                    if req.state == RUNNING:
+                        # roll the optimistic plan back to reality and feed
+                        # the next dispatch the last ACCEPTED token (verify
+                        # never touches the device carry); rejected draft
+                        # writes sit beyond total and are rewritten before
+                        # any live request's attention can reach them
+                        req.planned_out = len(req.output_ids)
+                        self._slot_override[req.slot] = req.output_ids[-1]
+            else:
+                # decode chunk: toks_np is [K, S]
+                k_steps = rec["k"]
+                for slot, (rid, start) in rec["slots"].items():
+                    req = self.requests.get(rid)
+                    if (req is None or req.state != RUNNING or req.slot != slot
+                            or len(req.output_ids) != start):
+                        continue  # finished/aborted/preempted while in flight
+                    for k in range(k_steps):
+                        if req.state != RUNNING:
+                            break
+                        self._append_token(req, int(toks_np[k, slot]), deltas)
+        self._phase_ns[_FETCH] += fetch.ns
+        self._phase_ns[_HARVEST] += r.ns
+        tracing.record("engine.dispatch", (
+            rec["seq"], rec["kind"], rec["step"], self._step_seq,
+            rec["dispatch_ns"], fetch.start_ns, fetch.end_ns,
+            rec["rows_padded"], rec["tokens_padded"], rec["facts"],
+            rec["k"]))
 
     def _preempt(self, req: Request) -> None:
         """Return a running request to the waiting queue, dropping its
@@ -1356,6 +1463,8 @@ class LLMEngine:
         host bookkeeping is authoritative."""
         assert not self._inflight
         self._preempted_total += 1
+        req.preemptions += 1
+        req.n_folded += len(req.output_ids)
         self.running.remove(req)
         self._release_slot(req)
         self.allocator.release(req.pages)
@@ -1370,6 +1479,7 @@ class LLMEngine:
         req.decode_ready = False
         req.spec_inflight = False
         req.dispatched_t = None  # re-prefill measures its own queue wait
+        req.dispatched_ns = None
         req.state = WAITING
         self.waiting.insert(0, req)
 
@@ -1430,6 +1540,8 @@ class LLMEngine:
     def _append_token(self, req: Request, token: int,
                       deltas: List[OutputDelta]) -> None:
         req.output_ids.append(token)
+        if req.first_token_ns is None:
+            req.first_token_ns = tracing.now_ns()
         stop = self._stop_reason(req, token)
         if req.sampling.prefill_only and stop is None:
             # gather-then-release inside the driver thread: the blob is
@@ -1483,6 +1595,19 @@ class LLMEngine:
         # drop the bookkeeping entry: long-lived engines (batch workers,
         # serve replicas) would otherwise accumulate one Request per call
         self.requests.pop(req.request_id, None)
+        self._record_request(req)
+
+    def _record_request(self, req: Request) -> None:
+        """The request's one `engine.request` flight record, written when
+        it leaves the engine (finished, aborted, expired, transferred)."""
+        if req.dispatched_ns is not None:
+            self._queue_wait_ns_total += req.dispatched_ns - req.arrival_ns
+        tracing.record("engine.request", (
+            req.request_id, req.arrival_ns, req.admitted_ns,
+            req.dispatched_ns, req.first_token_ns, tracing.now_ns(),
+            len(req.prompt_ids) - req.n_folded, req.n_cached,
+            len(req.output_ids) + req.n_folded, req.preemptions,
+            req.finish_reason))
 
     # ------------------------------------------- prefill/decode handoff
 
@@ -1616,7 +1741,7 @@ class LLMEngine:
         """Masked operands for one dispatch of the given program, after
         (params, kv_pages[, slot_ids]): total_lens=0 masks every page
         write, so running them leaves engine state untouched. Shared by
-        warmup, measure_prefill and program_text."""
+        warmup and program_text."""
         import jax.numpy as jnp
 
         mp = self.max_pages_per_seq
@@ -1696,78 +1821,6 @@ class LLMEngine:
         np.asarray(toks)
         return n + 1
 
-    def measure_prefill(self, seq_len: Optional[int] = None,
-                        iters: int = 3,
-                        peak_flops: Optional[float] = None
-                        ) -> Dict[str, Any]:
-        """Synchronous prefill-only microbenchmark on the engine's own
-        compiled shape — the serve-side companion of the training
-        bench's MFU (TTFT alone hides how much prefill compute headroom
-        remains; ref contract: own ops/flash_attention.py reaches ~50%
-        in training). Uses the same masked dummy dispatch as warmup()
-        (total_lens=0: page writes masked, engine state untouched), so
-        it can run on a live replica between waves. Requires an idle
-        pipeline. FLOP accounting matches bench_train's convention:
-        fwd = 2*N params + 4*L*H*hd*S attention per token."""
-        import jax
-
-        assert not self._inflight, "measure_prefill requires idle engine"
-        sb = seq_len or max(self.config.prefill_buckets)
-        rb = self._wave_rb
-        fn = self._jit("prefill", (sb, rb, 0))
-        args = self._dummy_args("prefill", (sb, rb, 0))
-
-        def dispatch():
-            toks, self.kv_pages = fn(self.params, self.kv_pages, *args)
-            return toks
-
-        np.asarray(dispatch())  # untimed: compile + page-in
-        # one dispatch + host sync, timed alone, so the chain below can
-        # separate per-dispatch compute from the fixed sync cost (ROADMAP
-        # C4 replaces this estimate with kernel time from a trace)
-        t0 = time.perf_counter()
-        np.asarray(dispatch())
-        single = time.perf_counter() - t0
-        # chained dispatches (kv_pages donation serializes them), ONE
-        # sync at the end: K x compute + 1 sync
-        t0 = time.perf_counter()
-        toks = None
-        for _ in range(iters):
-            toks = dispatch()
-        np.asarray(toks)
-        dt = time.perf_counter() - t0
-
-        cfg = self.model_cfg
-        n_params = sum(x.size for x in jax.tree.leaves(self.params))
-        flops_per_tok = (2 * n_params
-                         + 4 * cfg.num_layers * cfg.num_heads
-                         * cfg.head_dim_ * sb)
-        tokens = rb * sb * iters
-        achieved = tokens / dt * flops_per_tok
-        # compute-only estimate: the single sample = sync + 1 compute,
-        # chain = K computes + sync, so per-dispatch compute
-        # c = (dt-single)/(K-1). Clamped against noisy samples and flagged
-        # unreliable when the chain barely exceeds the single sample — a
-        # fabricated estimate must not be presentable as a physically
-        # impossible >100% MFU.
-        reliable = dt > 1.5 * single
-        c = max((dt - single) / max(iters - 1, 1), dt / iters * 0.05)
-        achieved_compute = (rb * sb * flops_per_tok) / c
-        if peak_flops:
-            achieved_compute = min(achieved_compute, float(peak_flops))
-        out = {"seq_len": sb, "rows": rb, "iters": iters,
-               "single_dispatch_ms": round(single * 1e3, 1),
-               "prefill_tok_s": round(tokens / dt, 1),
-               "achieved_tflops": round(achieved / 1e12, 2),
-               "achieved_tflops_compute": round(
-                   achieved_compute / 1e12, 2),
-               "compute_estimate_reliable": reliable}
-        if peak_flops:
-            out["mfu"] = round(100.0 * achieved / peak_flops, 2)
-            out["mfu_compute"] = round(
-                100.0 * achieved_compute / peak_flops, 2)
-        return out
-
     # ------------------------------------------------------------ stats
 
     def stats(self) -> Dict[str, Any]:
@@ -1785,6 +1838,9 @@ class LLMEngine:
             "attention": self._attention,
             "device": self._device,
             **self.allocator.stats,
+            # the flight recorder's facts, cumulative (rtpu_llm_*_total)
+            **self._totals,
+            "queue_wait_s_total": self._queue_wait_ns_total / 1e9,
         }
         if self.sharding is not None:
             out["sharding"] = self.sharding.page_accounting(

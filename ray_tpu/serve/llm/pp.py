@@ -64,6 +64,7 @@ import numpy as np
 from ... import exceptions
 from ...runtime import faults
 from ...runtime.channel import ChannelClosed
+from ...util import tracing
 from .engine import EngineConfig, LLMEngine, _device_sample
 from .sharding import CHIPS_PER_HOST
 
@@ -502,6 +503,7 @@ class PipelinedEngine(LLMEngine):
         total = np.zeros((S,), np.int32)
         positions = np.zeros((S, 1), np.int32)
         chunk_slots = {}
+        facts = []
         for req in rows:
             s = req.slot
             planned_total = len(req.prompt_ids) + req.planned_out
@@ -516,6 +518,7 @@ class PipelinedEngine(LLMEngine):
             else:
                 ids[s, 0] = req.output_ids[-1]
             chunk_slots[s] = (req.request_id, req.planned_out)
+            facts.append((req.request_id, 1, planned_total))
         temp, topk, keys = self._sampling_arrays(
             rows, S, slot_layout=True, base="planned")
         for req in rows:
@@ -525,9 +528,9 @@ class PipelinedEngine(LLMEngine):
             "positions": positions, "gather": np.zeros((S,), np.int32),
             "temp": temp, "topk": topk, "keys": keys,
         }
+        dispatch_ns = tracing.now_ns()
         ref = self._dag_execute(frame)
-        self._inflight.append({"kind": "decode", "toks": ref,
-                               "slots": chunk_slots, "k": 1})
+        self._enqueue_decode(ref, dispatch_ns, 1, facts, chunk_slots)
         return True
 
     def _fetch_tokens(self, handle) -> np.ndarray:

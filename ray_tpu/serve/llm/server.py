@@ -15,6 +15,7 @@ import itertools
 import time
 from typing import Any, Dict, List, Optional
 
+from ...util import tracing
 from .. import deployment
 from .engine import EngineConfig, LLMEngine, SamplingParams
 from .pp import make_engine
@@ -67,6 +68,21 @@ class LLMConfig:
 
 
 _LLM_METRICS = None
+# engine.stats() totals published as rtpu_llm_<key> counters
+_LLM_WORK_TOTALS = {
+    "steps_total": "engine.step() calls",
+    "prefill_dispatches_total": "prefill programs enqueued",
+    "decode_dispatches_total": "decode programs enqueued",
+    "prefill_tokens_total": "prompt tokens prefilled (real rows)",
+    "prefill_padded_tokens_total":
+        "token positions the prefill programs computed (rows x bucket)",
+    "decode_rows_total": "real rows of the decode programs",
+    "decode_ctx_tokens_total":
+        "tokens of KV the decode programs' real rows attended to",
+    "queue_wait_s_total":
+        "seconds finished requests waited for their first prefill dispatch",
+    "programs_built_total": "programs the engine built (jit cache misses)",
+}
 
 
 def _get_llm_metrics():
@@ -74,10 +90,13 @@ def _get_llm_metrics():
     registered so importing the module costs nothing: queue gauges +
     scheduler counters the continuous-batching bench and dashboards
     read. Counters end ``_total``, gauges do not (RTPU106); the nodelet
-    ships worker-side counters with get_node_info's serve family."""
+    ships worker-side counters with get_node_info's serve family. The
+    work counters are the flight recorder's totals (``engine.stats()``),
+    the three histograms are observed from its finished
+    ``engine.request`` records (ray_tpu/util/tracing.py)."""
     global _LLM_METRICS
     if _LLM_METRICS is None:
-        from ...util.metrics import Counter, Gauge
+        from ...util.metrics import Counter, Gauge, Histogram
 
         _LLM_METRICS = {
             "waiting": Gauge("rtpu_llm_waiting",
@@ -96,7 +115,21 @@ def _get_llm_metrics():
             "spec_accepted": Counter(
                 "rtpu_llm_spec_accepted_total",
                 "speculative draft tokens accepted by verification"),
+            "queue_wait": Histogram(
+                "rtpu_llm_queue_wait_seconds",
+                "arrival to first prefill dispatch, per finished request"),
+            "ttft": Histogram(
+                "rtpu_llm_ttft_seconds",
+                "arrival to first token, per finished request"),
+            "tpot": Histogram(
+                "rtpu_llm_tpot_seconds",
+                "(finish - first token) / (output tokens - 1), per "
+                "finished request",
+                boundaries=(0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5,
+                            1.0, 2.0)),
         }
+        for key, what in _LLM_WORK_TOTALS.items():
+            _LLM_METRICS[key] = Counter(f"rtpu_llm_{key}", what)
     return _LLM_METRICS
 
 
@@ -112,7 +145,8 @@ class EngineDriverMixin:
         self._driver_task: Optional[asyncio.Task] = None
         # last engine counter values already folded into the rtpu_llm_*
         # counters (engine stats are cumulative; metrics take deltas)
-        self._llm_counts: Dict[str, int] = {}
+        self._llm_counts: Dict[str, float] = {}
+        self._llm_requests_seen = tracing.appended("engine.request")
         self._llm_pub_t = 0.0
 
     async def _ensure_driver(self):
@@ -163,12 +197,32 @@ class EngineDriverMixin:
         m["pages_free"].set(stats.get("pages_free", 0))
         for key, mk in (("preempted_total", "preempted"),
                         ("spec_drafted_total", "spec_drafted"),
-                        ("spec_accepted_total", "spec_accepted")):
-            cur = int(stats.get(key, 0))
+                        ("spec_accepted_total", "spec_accepted"),
+                        *((k, k) for k in _LLM_WORK_TOTALS)):
+            cur = stats.get(key, 0)
             delta = cur - self._llm_counts.get(key, 0)
             if delta > 0:
                 m[mk].inc(delta)
             self._llm_counts[key] = cur
+        # latencies of the requests that finished since the last call
+        seen = tracing.appended("engine.request")
+        if seen != self._llm_requests_seen:
+            fields = tracing.FIELDS["engine.request"]
+            for rec in tracing.records("engine.request",
+                                       since=self._llm_requests_seen):
+                r = dict(zip(fields, rec))
+                if r["dispatched_ns"] is not None:
+                    m["queue_wait"].observe(
+                        (r["dispatched_ns"] - r["arrival_ns"]) / 1e9)
+                if r["first_token_ns"] is None:
+                    continue
+                m["ttft"].observe(
+                    (r["first_token_ns"] - r["arrival_ns"]) / 1e9)
+                if r["output_tokens"] > 1:
+                    m["tpot"].observe(
+                        (r["finish_ns"] - r["first_token_ns"]) / 1e9
+                        / (r["output_tokens"] - 1))
+            self._llm_requests_seen = seen
 
     def engine_stats(self) -> Dict[str, Any]:
         stats = self.engine.stats()

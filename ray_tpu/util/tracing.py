@@ -1,23 +1,34 @@
-"""Distributed tracing: spans around task/actor submission and execution.
+"""Tracing: task spans across the cluster, and a flight recorder for the
+loops that are too hot for them.
 
-Parity with the reference's tracing layer (ref:
+Task spans (parity with the reference's tracing layer, ref:
 python/ray/util/tracing/tracing_helper.py — opt-in wrappers around
-submit/execute that propagate an OpenTelemetry context through task specs;
-enabled via ray.init(_tracing_startup_hook=...)). Here tracing is
-self-contained: spans are plain dicts flushed through the task-event
-channel to the controller, with trace/parent ids propagated in task specs,
-and exportable as chrome-trace or OTLP-shaped JSON. Opt-in via
+submit/execute that propagate an OpenTelemetry context through task
+specs): plain dicts flushed through the task-event channel to the
+controller, with trace/parent ids propagated in task specs. Opt-in via
 `tracing.enable()` (no-op overhead when off).
+
+Flight recorder (always on): the serving engine and the trainer write one
+plain tuple per step, dispatch and request into a bounded ring per record
+kind (`record`, read back with `records`); `region(name)` puts the same
+interval into jax's profiler trace under a stable name whenever a profiler
+session runs. No I/O, no uuid and no dict on that path. Every timestamp of
+both tiers is Unix-epoch time (spans in seconds, ring records in
+nanoseconds); jax's profiler reports the same clock counted from the start
+of its session, so one constant per session places a record on a trace.
+`chrome_trace()` renders both tiers for chrome://tracing or Perfetto.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
+import itertools
 import threading
 import time
 import uuid
-from typing import Any, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 _enabled = False
 _lock = threading.Lock()
@@ -117,9 +128,165 @@ def collect() -> List[Dict[str, Any]]:
     return spans
 
 
+# ---------------------------------------------------------------------------
+# Flight recorder: bounded rings of plain tuples, one per record kind.
+# ---------------------------------------------------------------------------
+
+# what each position of a record holds; `*_ns` are Unix-epoch nanoseconds
+# (None where the event never happened), other `*_ns` of engine.step are
+# durations inside the step
+FIELDS: Dict[str, Tuple[str, ...]] = {
+    # one per request, written when it finishes, aborts or expires
+    "engine.request": (
+        "request_id", "arrival_ns", "admitted_ns", "dispatched_ns",
+        "first_token_ns", "finish_ns", "prompt_tokens", "cached_tokens",
+        "output_tokens", "preemptions", "finish_reason"),
+    # one per program enqueued, written when its tokens are harvested;
+    # `rows` is a tuple of (request_id, q_tokens, ctx_tokens) per real row
+    "engine.dispatch": (
+        "seq", "kind", "step_dispatched", "step_harvested", "dispatch_ns",
+        "fetch_start_ns", "fetch_end_ns", "rows_padded", "tokens_padded",
+        "rows", "k"),
+    # one per LLMEngine.step()
+    "engine.step": (
+        "seq", "start_ns", "end_ns", "intake_ns", "admit_ns",
+        "dispatch_prefill_ns", "dispatch_decode_ns", "fetch_ns",
+        "harvest_ns", "running", "waiting"),
+    # one per program the engine builds (a miss of its jit cache)
+    "engine.program_built": ("kind", "shape_key", "ns", "step_seq"),
+    # one per ShardedTrainer.step(): the host's time to dispatch the step
+    "train.step": ("seq", "start_ns", "end_ns"),
+}
+# 65 536 records in all: a 50 s window plus 90 s of grace at five times a
+# 62 ms decode step is 11k steps and as many dispatches
+CAPACITY: Dict[str, int] = {
+    "engine.request": 8192, "engine.dispatch": 16384, "engine.step": 32768,
+    "engine.program_built": 1024, "train.step": 7168,
+}
+
+now_ns = time.time_ns   # the recorder's one clock
+
+
+class _Ring:
+    __slots__ = ("buf", "appended")
+
+    def __init__(self, capacity: int):
+        self.buf: Deque[tuple] = collections.deque(maxlen=capacity)
+        # one writer thread per kind (the engine's driver, the training
+        # loop): a second writer could lose a count here, never a record
+        self.appended = 0
+
+
+_rings: Dict[str, _Ring] = {k: _Ring(n) for k, n in CAPACITY.items()}
+
+
+def record(kind: str, rec: tuple) -> None:
+    """Append one record (a tuple laid out as FIELDS[kind]) to its ring;
+    the oldest record falls out once the ring is full."""
+    ring = _rings[kind]
+    ring.appended += 1
+    ring.buf.append(rec)
+
+
+def appended(kind: str) -> int:
+    """Records of this kind ever written (a cursor for `records`)."""
+    return _rings[kind].appended
+
+
+def dropped(kind: str) -> int:
+    """Records of this kind that have fallen out of the ring."""
+    ring = _rings[kind]
+    return max(0, ring.appended - ring.buf.maxlen)
+
+
+def records(kind: str, since: int = 0) -> List[tuple]:
+    """The ring's records, oldest first; with `since` (an earlier
+    `appended(kind)`) only those written after it that are still held."""
+    ring = _rings[kind]
+    held = len(ring.buf)
+    fresh = min(held, ring.appended - since)
+    if fresh <= 0:
+        return []
+    return list(itertools.islice(ring.buf, held - fresh, held))
+
+
+def reset_ring() -> None:
+    """Empty every ring and its counts (tests)."""
+    for ring in _rings.values():
+        ring.buf.clear()
+        ring.appended = 0
+
+
+_TraceAnnotation = None
+
+
+class region:
+    """`with region("rtpu.engine.fetch") as r:` — the interval as a
+    TraceAnnotation in jax's profiler trace (an atomic load when no
+    profiler session runs) and as `r.start_ns`/`r.end_ns`/`r.ns` for the
+    caller, who folds it into the one record of its step: a region
+    appends nothing by itself."""
+
+    __slots__ = ("_annotation", "start_ns", "end_ns")
+
+    def __init__(self, name: str):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            # on first use, not at import: cluster workers import this
+            # module and never jax
+            from jax.profiler import TraceAnnotation
+
+            _TraceAnnotation = TraceAnnotation
+        self._annotation = _TraceAnnotation(name)
+
+    def __enter__(self) -> "region":
+        self._annotation.__enter__()
+        self.start_ns = now_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end_ns = now_ns()
+        self._annotation.__exit__(*exc)
+        return False
+
+    @property
+    def ns(self) -> int:
+        return self.end_ns - self.start_ns
+
+
+# how a record of each kind is drawn: (event name, field that starts the
+# event, field that ends it)
+_RING_EVENT = {
+    "engine.step": (lambda r: "rtpu.engine.step", "start_ns", "end_ns"),
+    "engine.dispatch": (lambda r: f"{r['kind']} #{r['seq']}", "dispatch_ns",
+                        "fetch_end_ns"),
+    "engine.request": (lambda r: str(r["request_id"]), "arrival_ns",
+                       "finish_ns"),
+    "engine.program_built": (lambda r: f"built {r['kind']}", "ns", "ns"),
+    "train.step": (lambda r: "rtpu.train.step", "start_ns", "end_ns"),
+}
+
+
+def _ring_events() -> List[Dict[str, Any]]:
+    """Ring records as chrome://tracing events, one thread per kind."""
+    out = []
+    for kind, (name, start, end) in _RING_EVENT.items():
+        for rec in records(kind):
+            args = dict(zip(FIELDS[kind], rec))
+            out.append({"ph": "X", "name": name(args), "cat": kind,
+                        "pid": "rtpu.ring", "tid": kind,
+                        "ts": args[start] / 1e3,
+                        "dur": max(args[end] - args[start], 0) / 1e3,
+                        "args": args})
+    return out
+
+
 def chrome_trace(spans: Optional[List[Dict[str, Any]]] = None
                  ) -> List[Dict[str, Any]]:
-    """Spans as chrome://tracing complete events (grouped per trace)."""
+    """Task spans (grouped per trace) and the flight recorder's rings as
+    chrome://tracing events on one clock (microseconds since the epoch):
+    `json.dump(tracing.chrome_trace(), f)` is a file Perfetto loads.
+    Without `spans` this process's finished spans are drained."""
     out = []
     for record in (spans if spans is not None else drain()):
         out.append({
@@ -133,4 +300,4 @@ def chrome_trace(spans: Optional[List[Dict[str, Any]]] = None
             "args": {**record["attributes"], "span_id": record["span_id"],
                      "status": record["status"]},
         })
-    return out
+    return out + _ring_events()

@@ -1,0 +1,341 @@
+"""The flight recorder inside LLMEngine and ShardedTrainer.step
+(ray_tpu/util/tracing.py): what each record kind holds, that the ring is
+bounded and counts what it drops, that a region lies on the profiler's
+clock, and that /metrics and stats() say what the records say.
+
+CPU, tiny engine; no TPU library is loaded at import.
+"""
+
+import json
+
+import numpy as np
+import pytest
+
+from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.util import tracing
+
+ENGINE_CFG = dict(
+    model="tiny", page_size=8, num_pages=64, max_model_len=128,
+    max_batch=4, prefill_buckets=(16, 32, 64, 128), dtype="float32",
+    model_overrides={"vocab_size": 512},
+)
+
+
+def _dicts(kind):
+    return [dict(zip(tracing.FIELDS[kind], rec))
+            for rec in tracing.records(kind)]
+
+
+def _run(engine, max_steps=600):
+    for _ in range(max_steps):
+        if not engine.has_work():
+            return
+        engine.step()
+    raise AssertionError("the engine did not run dry")
+
+
+def _prompts(n, lo=9, step=7, seed=0):
+    rng = np.random.default_rng(seed)
+    return [list(rng.integers(0, 500, lo + step * i)) for i in range(n)]
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """One engine for the cases that only read what it records (its
+    programs compile once); every case starts from an empty ring."""
+    return LLMEngine(EngineConfig(**ENGINE_CFG))
+
+
+@pytest.fixture(autouse=True)
+def empty_ring():
+    tracing.reset_ring()
+    yield
+    tracing.reset_ring()
+
+
+def test_request_timeline_is_ordered_and_every_exit_leaves_one_record(
+        engine):
+    import time
+
+    for i, p in enumerate(_prompts(3, seed=1)):
+        engine.add_request(f"ok{i}", p, SamplingParams(max_tokens=5))
+    engine.add_request("gone", _prompts(1, seed=2)[0],
+                       SamplingParams(max_tokens=50))
+    engine.add_request("late", _prompts(1, seed=3)[0],
+                       SamplingParams(max_tokens=5),
+                       deadline=time.time() - 1.0)
+    engine.step()
+    engine.abort("gone")
+    _run(engine)
+    recs = {r["request_id"]: r for r in _dicts("engine.request")}
+    assert len(tracing.records("engine.request")) == 5 == len(recs)
+    for i in range(3):
+        r = recs[f"ok{i}"]
+        assert (r["arrival_ns"] <= r["admitted_ns"] <= r["dispatched_ns"]
+                <= r["first_token_ns"] <= r["finish_ns"])
+        assert r["finish_reason"] == "length" and r["output_tokens"] == 5
+        assert r["prompt_tokens"] == 9 + 7 * i and r["preemptions"] == 0
+    assert recs["gone"]["finish_reason"] == "aborted"
+    assert recs["late"]["finish_reason"] == "expired"
+    assert recs["late"]["dispatched_ns"] is None
+    assert recs["late"]["arrival_ns"] <= recs["late"]["finish_ns"]
+
+
+def test_prefill_records_count_real_and_padded_tokens(engine):
+    shared = _prompts(1, lo=24, seed=4)[0]
+    prompts = _prompts(3, seed=5) + [shared + [7]]
+    for i, p in enumerate(prompts):
+        engine.add_request(f"a{i}", p, SamplingParams(max_tokens=3))
+    _run(engine)
+    # the same 24 tokens again: three full pages come from the cache
+    engine.add_request("twin", shared + [9], SamplingParams(max_tokens=3))
+    _run(engine)
+    reqs = _dicts("engine.request")
+    prefills = [d for d in _dicts("engine.dispatch")
+                if d["kind"] == "prefill"]
+    assert sum(r["cached_tokens"] for r in reqs) == 24
+    real = sum(q for d in prefills for _, q, _ in d["rows"])
+    assert real == sum(r["prompt_tokens"] - r["cached_tokens"]
+                       for r in reqs)
+    rb = engine._wave_rb
+    for d in prefills:
+        assert d["rows_padded"] == rb
+        assert d["tokens_padded"] % rb == 0
+        bucket = d["tokens_padded"] // rb
+        assert bucket in ENGINE_CFG["prefill_buckets"]
+        assert all(q <= bucket and q <= ctx for _, q, ctx in d["rows"])
+    # the twin's row attends to its cached prefix too
+    (row,) = [r for d in prefills for r in d["rows"] if r[0] == "twin"]
+    assert row[1:] == (1, 25)
+    assert d["step_dispatched"] <= d["step_harvested"]
+    assert d["dispatch_ns"] <= d["fetch_start_ns"] <= d["fetch_end_ns"]
+
+
+def test_decode_rows_carry_the_context_they_attend_to(engine):
+    prompts = _prompts(2, seed=6)
+    for i, p in enumerate(prompts):
+        engine.add_request(f"d{i}", p, SamplingParams(max_tokens=6))
+    _run(engine)
+    plen = {f"d{i}": len(p) for i, p in enumerate(prompts)}
+    seen = {rid: [] for rid in plen}
+    decodes = [d for d in _dicts("engine.dispatch") if d["kind"] == "decode"]
+    assert decodes
+    for d in decodes:
+        assert d["rows_padded"] == ENGINE_CFG["max_batch"]
+        assert d["tokens_padded"] == d["rows_padded"] * d["k"]
+        for rid, q, ctx in d["rows"]:
+            assert q == d["k"] == 1
+            seen[rid].append(ctx)
+    for rid, ctxs in seen.items():
+        # the first decode step attends to the prompt and the token the
+        # prefill sampled; each later one to one token more. A chunk
+        # dispatched ahead of a stop is recorded too, so at least the 5
+        # steps whose tokens were kept
+        assert ctxs[:5] == [plen[rid] + 1 + j for j in range(5)]
+
+
+def test_step_phases_fit_inside_the_step(engine):
+    for i, p in enumerate(_prompts(3, seed=7)):
+        engine.add_request(f"s{i}", p, SamplingParams(max_tokens=4))
+    _run(engine)
+    steps = _dicts("engine.step")
+    assert [s["seq"] for s in steps] == list(
+        range(steps[0]["seq"], steps[0]["seq"] + len(steps)))
+    phases = ("intake_ns", "admit_ns", "dispatch_prefill_ns",
+              "dispatch_decode_ns", "fetch_ns", "harvest_ns")
+    for s in steps:
+        assert all(s[p] >= 0 for p in phases)
+        assert sum(s[p] for p in phases) <= s["end_ns"] - s["start_ns"]
+    assert any(s["dispatch_prefill_ns"] > 0 for s in steps)
+    assert any(s["fetch_ns"] > 0 for s in steps)
+    assert steps[-1]["running"] == 0 and steps[-1]["waiting"] == 0
+
+
+def test_preemption_is_counted_and_gets_a_new_dispatch_time():
+    cfg = dict(ENGINE_CFG, num_pages=12, max_model_len=64, max_batch=2,
+               prefill_buckets=(16, 32, 64))
+    eng = LLMEngine(EngineConfig(**cfg))
+    first_dispatch = {}
+    for i, p in enumerate(_prompts(2, lo=17, step=0, seed=8)):
+        eng.add_request(f"p{i}", p, SamplingParams(max_tokens=40))
+    for _ in range(900):
+        if not eng.has_work():
+            break
+        eng.step()
+        for req in eng.running:
+            first_dispatch.setdefault(req.request_id, req.dispatched_ns)
+    assert eng.stats()["preempted_total"] >= 1
+    recs = {r["request_id"]: r for r in _dicts("engine.request")}
+    assert sum(r["preemptions"] for r in recs.values()) \
+        == eng.stats()["preempted_total"]
+    for rid, r in recs.items():
+        # folded output tokens are not prompt tokens
+        assert r["prompt_tokens"] == 17 and r["output_tokens"] == 40
+        if r["preemptions"]:
+            assert r["dispatched_ns"] > first_dispatch[rid]
+
+
+def test_ring_holds_its_capacity_and_counts_what_it_drops():
+    cap = tracing.CAPACITY["train.step"]
+    assert sum(tracing.CAPACITY.values()) == 65536
+    for i in range(cap + 10):
+        tracing.record("train.step", (i, i, i + 1))
+    held = tracing.records("train.step")
+    assert len(held) == cap and held[0][0] == 10 and held[-1][0] == cap + 9
+    assert tracing.dropped("train.step") == 10
+    assert tracing.dropped("engine.step") == 0
+    assert tracing.appended("train.step") == cap + 10
+    assert [r[0] for r in tracing.records("train.step", since=cap + 7)] \
+        == [cap + 7, cap + 8, cap + 9]
+    assert tracing.records("train.step", since=cap + 10) == []
+
+
+def test_a_region_lies_on_the_profilers_clock(tmp_path):
+    """jax's profiler counts the recorder's clock from the start of its
+    session: annotation start minus region start is one constant."""
+    import glob
+    import time
+
+    import jax
+
+    try:
+        from jax.profiler import ProfileData
+    except ImportError as e:
+        pytest.skip(f"jax.profiler.ProfileData cannot be imported: {e}")
+    starts = []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(3):
+            with tracing.region(f"rtpu.test.region{i}") as r:
+                time.sleep(0.002)
+            starts.append(r.start_ns)
+            time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("rtpu.test.region"):
+                    seen[e.name] = e.start_ns
+    assert sorted(seen) == [f"rtpu.test.region{i}" for i in range(3)]
+    offsets = [starts[i] - seen[f"rtpu.test.region{i}"] for i in range(3)]
+    assert max(offsets) - min(offsets) < 1_000_000, offsets
+
+
+def test_stats_totals_and_llm_metrics_equal_the_records_sums(engine):
+    from ray_tpu.serve.llm.server import EngineDriverMixin
+    from ray_tpu.util import metrics
+
+    before = engine.stats()
+    driver = EngineDriverMixin()
+    driver.engine = engine
+    driver._init_driver()
+    driver._publish_llm_metrics(before)
+    counters0 = metrics.snapshot("rtpu_llm_")
+    for i, p in enumerate(_prompts(3, seed=9)):
+        engine.add_request(f"m{i}", p, SamplingParams(max_tokens=4))
+    _run(engine)
+    after = engine.stats()
+    moved = {k: after[k] - before[k] for k in after if k.endswith("_total")}
+    dispatches, reqs = _dicts("engine.dispatch"), _dicts("engine.request")
+    prefills = [d for d in dispatches if d["kind"] == "prefill"]
+    decodes = [d for d in dispatches if d["kind"] == "decode"]
+    assert moved["steps_total"] == len(tracing.records("engine.step"))
+    assert moved["prefill_dispatches_total"] == len(prefills)
+    assert moved["decode_dispatches_total"] == len(decodes)
+    assert moved["prefill_tokens_total"] == sum(
+        q for d in prefills for _, q, _ in d["rows"])
+    assert moved["prefill_padded_tokens_total"] == sum(
+        d["tokens_padded"] for d in prefills)
+    assert moved["decode_rows_total"] == sum(len(d["rows"]) for d in decodes)
+    assert moved["decode_ctx_tokens_total"] == sum(
+        c for d in decodes for _, _, c in d["rows"])
+    assert moved["queue_wait_s_total"] == pytest.approx(sum(
+        r["dispatched_ns"] - r["arrival_ns"] for r in reqs) / 1e9)
+    assert moved["programs_built_total"] == len(
+        tracing.records("engine.program_built"))
+    driver._publish_llm_metrics(after)
+    counters = metrics.snapshot("rtpu_llm_")
+    for key, delta in moved.items():
+        name = f"rtpu_llm_{key}"
+        if name in counters:
+            assert counters[name] - counters0.get(name, 0) \
+                == pytest.approx(delta), name
+    for hist in ("queue_wait", "ttft", "tpot"):
+        name = f"rtpu_llm_{hist}_seconds_count"
+        assert counters[name] - counters0.get(name, 0) == 3, name
+
+
+def test_a_step_makes_a_bounded_number_of_clock_reads_and_appends(
+        engine, monkeypatch):
+    for i, p in enumerate(_prompts(4, lo=30, step=0, seed=10)):
+        engine.add_request(f"g{i}", p, SamplingParams(max_tokens=30))
+    for _ in range(4):      # past admission and the prefill wave
+        engine.step()
+    assert len(engine.running) == 4 and not engine.waiting
+    reads, appends = [0], [0]
+    clock, record = tracing.now_ns, tracing.record
+
+    def counting_clock():
+        reads[0] += 1
+        return clock()
+
+    def counting_record(kind, rec):
+        appends[0] += 1
+        record(kind, rec)
+
+    monkeypatch.setattr(tracing, "now_ns", counting_clock)
+    monkeypatch.setattr(tracing, "record", counting_record)
+    steps = 10
+    for _ in range(steps):
+        engine.step()
+    monkeypatch.undo()
+    # a decode step: six regions (step, intake, admit, dispatch_decode,
+    # fetch, harvest) of two reads each and one read at the dispatch; one
+    # append for the step and one for the dispatch it harvests
+    assert reads[0] == 13 * steps
+    assert appends[0] == 2 * steps
+    _run(engine)
+
+
+def test_trainer_step_leaves_one_record_a_call():
+    import jax
+
+    from ray_tpu.models.llama import LlamaModel, get_config
+    from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+    from ray_tpu.parallel.train_lib import ShardedTrainer
+
+    cfg = get_config("tiny", scan_layers=True)
+    mesh = create_mesh(MeshConfig(dp=1, fsdp=1, sp=1, tp=1),
+                       devices=jax.devices()[:1])
+    trainer = ShardedTrainer(LlamaModel(cfg), mesh)
+    batch = {"input_ids": np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (2, 17), dtype=np.int32)}
+    state = trainer.init(jax.random.PRNGKey(0), batch)
+    for _ in range(3):
+        state, _ = trainer.step(state, batch)
+    recs = _dicts("train.step")
+    assert [r["seq"] for r in recs] == [1, 2, 3]
+    assert all(r["start_ns"] <= r["end_ns"] for r in recs)
+    assert recs[0]["end_ns"] <= recs[1]["start_ns"]
+
+
+def test_chrome_trace_renders_the_ring(engine):
+    for i, p in enumerate(_prompts(2, seed=11)):
+        engine.add_request(f"c{i}", p, SamplingParams(max_tokens=3))
+    _run(engine)
+    events = tracing.chrome_trace([])
+    json.dumps(events)
+    by_cat = {}
+    for e in events:
+        assert e["ph"] == "X" and e["dur"] >= 0 and e["pid"] == "rtpu.ring"
+        by_cat.setdefault(e["cat"], []).append(e)
+    assert len(by_cat["engine.request"]) == 2
+    assert len(by_cat["engine.step"]) == len(tracing.records("engine.step"))
+    assert len(by_cat["engine.dispatch"]) == len(
+        tracing.records("engine.dispatch"))
+    step = by_cat["engine.step"][0]
+    assert step["ts"] == pytest.approx(step["args"]["start_ns"] / 1e3)
