@@ -402,19 +402,22 @@ def _kernel_parity(cfg, page: int, tol: float = 3e-2) -> Dict[str, float]:
     out = {"flash": err(jax.jit(attention)(q, k, v),
                         jax.jit(reference_attention)(q, k, v))}
 
-    b, mp = 8, 16
-    kv_pages = rnd(keys[3], b * mp, hkv, page, 2 * d)
+    # a layered pool, as the engine holds it: the kernel streams pages of
+    # the layer it is told, the reference reads that layer alone
+    b, mp, layers, layer = 8, 16, 3, 1
+    kv_pages = rnd(keys[3], layers, b * mp, hkv, page, 2 * d)
     tables = jnp.asarray(np.random.default_rng(0).permutation(b * mp)
                          .reshape(b, mp), jnp.int32)
     lengths = jnp.asarray([1, page, page + 1, 3 * page, mp * page - 1,
                            mp * page, 7, 0], jnp.int32)
     qd = rnd(keys[4], b, hq, d)
     ref = paged_attention_reference(
-        qd[:, None], kv_pages, tables,
+        qd[:, None], kv_pages[layer], tables,
         jnp.maximum(lengths - 1, 0)[:, None])[:, 0]
     ref = jnp.where((lengths > 0)[:, None, None], ref, 0)
     out["paged_decode"] = err(
-        jax.jit(paged_attention_decode)(qd, kv_pages, tables, lengths), ref)
+        jax.jit(paged_attention_decode)(qd, kv_pages, tables, lengths,
+                                        layer=jnp.int32(layer)), ref)
     bad = {name: e for name, e in out.items() if not e <= tol}
     if bad:
         raise SmokeFailure(f"kernel disagrees with its reference: {bad}")

@@ -132,18 +132,25 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 @struct.dataclass
 class PagedCache:
-    """Per-layer paged KV state threaded through the model as `kv_caches`.
+    """Paged KV state threaded through the model as `kv_caches`.
 
     The serving engine owns page allocation (ray_tpu/serve/llm/cache.py);
     the model writes new tokens into pages and attends through block tables
-    (ops/paged_attention.py). When scan_layers, every leaf carries a leading
-    [L] axis (block_tables/total_lens are tiled per layer so they can ride
-    the scan's xs axis).
+    (ops/paged_attention.py). A caller hands the model ONE pool for all
+    layers, `kv_pages` [L, P, Hkv, page, 2*D], with block_tables [L, B, MP]
+    and total_lens [L, B] tiled per layer, and gets the same back. Inside
+    the layer scan the pool rides the CARRY whole, never sliced or
+    re-stacked, so the loop updates the donated buffer in place; a layer
+    sees that pool, its own [B, MP] / [B] slices (they ride the scan's xs)
+    and its index in `layer`.
     """
 
-    kv_pages: jax.Array      # [P, Hkv, page, 2*D] (K | V in lanes)
-    block_tables: jax.Array  # [B, MP] int32 page ids
-    total_lens: jax.Array    # [B] int32, length INCLUDING new tokens
+    kv_pages: jax.Array      # [L, P, Hkv, page, 2*D] (K | V in lanes)
+    block_tables: jax.Array  # [B, MP] int32 page ids ([L, B, MP] outside)
+    total_lens: jax.Array    # [B] int32, INCLUDING new tokens ([L, B])
+    # this layer's index into kv_pages, set by the scan; None where
+    # kv_pages has no layer axis (an unscanned layer's own [P, ...] pool)
+    layer: Optional[jax.Array] = None
     # STATIC number of block-table columns a cached prefix may span during
     # prefill (0 = no prefix part compiled in); decode ignores it
     ctx_pages: int = struct.field(pytree_node=False, default=0)
@@ -178,22 +185,25 @@ class Attention(nn.Module):
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
         if isinstance(kv_cache, PagedCache):
-            # Serving path: scatter new K/V into pages, then attend.
-            # Decode (S == 1) streams only the used pages through the
-            # Pallas kernel; prefill attends to itself (causal flash, no
-            # page reads) merged with the cached prefix by log-sum-exp.
+            # Serving path: write new K/V into this layer's pages of the
+            # pool, then attend. Decode (S == 1) streams only the used
+            # pages through the Pallas kernel; prefill attends to itself
+            # (causal flash, no page reads) merged with the cached prefix
+            # by log-sum-exp.
             pc = kv_cache
             kv_pages = paged_write(pc.kv_pages, k, v, pc.block_tables,
-                                   positions, pc.total_lens)
+                                   positions, pc.total_lens, pc.layer)
             if s == 1:
                 out = paged_attention_decode(
                     q[:, 0], kv_pages, pc.block_tables, pc.total_lens,
+                    layer=pc.layer,
                     force_reference=pc.ref_attention)[:, None]
             else:
                 out = paged_prefill_attention(
                     q, k, v, kv_pages, pc.block_tables, positions,
                     pc.total_lens, ctx_pages=pc.ctx_pages,
-                    impl="reference" if pc.ref_attention else None)
+                    impl="reference" if pc.ref_attention else None,
+                    layer=pc.layer)
             new_cache = pc.replace(kv_pages=kv_pages)
         else:
             if kv_cache is not None:
@@ -361,35 +371,55 @@ class DecoderLayer(nn.Module):
 class ScannedLayer(nn.Module):
     """One layer body, scanned over a stacked `layers` param axis.
 
-    The per-layer kv cache rides the scan's xs/ys axis: caches come in
-    stacked [L, ...] and updated caches come out the same way.
+    The paged pool rides the CARRY (None when there is no paged cache: a
+    None leaf adds nothing to the program); `kv_cache` is this layer's
+    slice of the scan's xs: a PagedCache without its pool, or a dense
+    (k, v) pair, whose grown copy goes out through the ys.
     """
     config: LlamaConfig
 
     @nn.compact
     def __call__(self, carry, kv_cache):
-        x, positions, segment_ids = carry
+        x, positions, segment_ids, kv_pages = carry
+        if kv_pages is not None:
+            kv_cache = kv_cache.replace(kv_pages=kv_pages)
         x, new_cache = DecoderLayer(self.config, name="layer")(
             x, positions, segment_ids, kv_cache)
-        return (x, positions, segment_ids), new_cache
+        if kv_pages is not None:
+            kv_pages, new_cache = new_cache.kv_pages, None
+        return (x, positions, segment_ids, kv_pages), new_cache
 
 
-def _scanned_layers(cfg: LlamaConfig, length: int):
-    """The scan-transformed layer stack shared by LlamaModel, LayerStack
-    and StageModel: ONE definition of the scan axes/metadata so every
-    consumer produces the identical "layers" param collection (leaves
-    stacked with a leading [length] axis under PARTITION_NAME "layers")."""
+def _apply_layers(cfg: LlamaConfig, length: int, x, positions, segment_ids,
+                  kv_caches):
+    """Run `length` scanned layers named "layers" under the calling
+    module; returns (x, new_caches). Shared by LlamaModel, LayerStack and
+    StageModel: ONE definition of the scan axes/metadata so every consumer
+    produces the identical "layers" param collection (leaves stacked with
+    a leading [length] axis under PARTITION_NAME "layers").
+
+    A PagedCache's pool goes through the scan's carry, the rest of it (and
+    the layer's index) through the xs, and it comes back as the caller
+    handed it in, with the updated pool."""
     layer_cls = ScannedLayer
     if cfg.remat:
         layer_cls = nn.remat(ScannedLayer, prevent_cse=False,
                              policy=_remat_policy(cfg.remat_policy))
-    return nn.scan(
+    layers = nn.scan(
         layer_cls,
         variable_axes={"params": 0, "losses": 0},
         split_rngs={"params": True},
         length=length,
         metadata_params={nn.PARTITION_NAME: "layers"},
-    )
+    )(cfg, name="layers")
+    paged = isinstance(kv_caches, PagedCache)
+    kv_pages, xs = None, kv_caches
+    if paged:
+        kv_pages = kv_caches.kv_pages
+        xs = kv_caches.replace(kv_pages=None, layer=jnp.arange(length))
+    (x, _, _, kv_pages), ys = layers(
+        (x, positions, segment_ids, kv_pages), xs)
+    return x, kv_caches.replace(kv_pages=kv_pages) if paged else ys
 
 
 class LayerStack(nn.Module):
@@ -405,8 +435,8 @@ class LayerStack(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        (x, _, _), _ = _scanned_layers(self.config, self.layers_per_stage)(
-            self.config, name="layers")((x, positions, None), None)
+        x, _ = _apply_layers(self.config, self.layers_per_stage, x,
+                             positions, None, None)
         return x
 
 
@@ -426,8 +456,8 @@ class StageModel(nn.Module):
     Call signature mirrors the serving path of LlamaModel.__call__:
     `x` is int32 token ids on the first stage (embedded here) and the
     previous stage's hidden states elsewhere; `kv_caches` is this stage's
-    [n_layers]-leading PagedCache slice; returns (hidden-or-logits,
-    new_caches).
+    PagedCache, the pool an [n_layers, P, ...] slice of its own; returns
+    (hidden-or-logits, new_caches).
     """
 
     config: LlamaConfig
@@ -443,8 +473,8 @@ class StageModel(nn.Module):
                 "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
                 (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
             x = embed[x].astype(cfg.dtype)
-        (x, _, _), new_caches = _scanned_layers(cfg, self.n_layers)(
-            cfg, name="layers")((x, positions, None), kv_caches)
+        x, new_caches = _apply_layers(cfg, self.n_layers, x, positions,
+                                      None, kv_caches)
         if self.last:
             x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
             x = nn.DenseGeneral(
@@ -466,7 +496,8 @@ class LlamaModel(nn.Module):
                  kv_caches=None, targets=None):
         """Forward pass.
 
-        kv_caches: None (training / full prefill), or a (k, v) pair stacked
+        kv_caches: None (training / full prefill), a PagedCache (serving:
+        one [L, P, ...] pool, updated in place), or a (k, v) pair stacked
         over layers — k/v shaped [L, B, S_cache, Hkv, D] when scan_layers,
         else a list of L per-layer (k, v) tuples.  When given, returns
         (logits, new_kv_caches); `positions` must then hold the absolute
@@ -483,8 +514,8 @@ class LlamaModel(nn.Module):
         x = embed[input_ids].astype(cfg.dtype)
 
         if cfg.scan_layers:
-            (x, _, _), new_caches = _scanned_layers(cfg, cfg.num_layers)(
-                cfg, name="layers")((x, positions, segment_ids), kv_caches)
+            x, new_caches = _apply_layers(cfg, cfg.num_layers, x, positions,
+                                          segment_ids, kv_caches)
         else:
             layer_cls = DecoderLayer
             if cfg.remat:

@@ -54,6 +54,12 @@ GRAN = 128   # MXU-minimal granularity: short sequences round up to this,
              # not to BLOCK, so small prefills don't pad 4-8x
 
 
+# scoped VMEM a kernel gets without asking (v5e), and what the forward's
+# q / o / lse blocks and its [bq, bk] score tiles take beside K and V
+_VMEM_UNASKED = 16 << 20
+_VMEM_HEADROOM = 8 << 20
+
+
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
@@ -173,14 +179,25 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
         pl.BlockSpec((1, 1, bq, d), lambda b_, h, i: (b_, h, i, 0)),
         pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i: (b_, h, i, 0)),
     ]
+    compiler_params = dict(
+        dimension_semantics=("parallel", "parallel", "parallel"))
+    if have_segs:
+        # K, V and the kv segment ids of one (batch, kv head) stay
+        # resident, double-buffered, and an int32 [sk, 1] column is padded
+        # to 128 lanes: at kv 8k and D 128 that is 18 MB, over the 16 MB a
+        # kernel gets unasked. Such a call compiles only while XLA chooses
+        # to hold the ids in VMEM itself (the engine's [4 x 4096] prefix
+        # prefill did, until the program around it changed), so ask.
+        resident = 2 * sk_p * (2 * d * k.dtype.itemsize + 128 * 4)
+        if resident + _VMEM_HEADROOM > _VMEM_UNASKED:
+            compiler_params["vmem_limit_bytes"] = resident + _VMEM_HEADROOM
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(**compiler_params),
         interpret=interpret,
     )(*args)
     return o, lse

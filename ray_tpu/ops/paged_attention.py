@@ -5,15 +5,23 @@ The reference delegates paged attention entirely to vLLM's CUDA kernels
 wraps the external engine; no kernels in-repo). Here it is TPU-native and
 owned end to end:
 
-- KV lives in fixed-size pages laid out ``[P, Hkv, page, 2*D]`` per layer
-  with K in lanes ``[:D]`` and V in lanes ``[D:]``. Page-major means ONE
-  DMA descriptor moves a page's K and V for EVERY kv head (32 KB
-  contiguous for an 8-head, page-16, D-64 model) — the decode kernel's
-  streaming unit. K/V interleaving also makes the slice's last dim
+- KV lives in fixed-size pages, ONE pool ``[L, P, Hkv, page, 2*D]`` for
+  all layers, with K in lanes ``[:D]`` and V in lanes ``[D:]``. Every op
+  takes the whole pool and a ``layer`` index and touches only
+  ``kv_pages[layer]``: the pool is never sliced, so a layer loop that
+  carries it (models/llama.py) updates it in place. ``layer=None`` means a
+  pool with no layer axis, ``[P, Hkv, page, 2*D]`` (direct callers).
+  Page-major means ONE DMA descriptor moves a page's K and V for EVERY kv
+  head (32 KB contiguous for an 8-head, page-16, D-64 model) — the decode
+  kernel's streaming unit. K/V interleaving also makes the slice's last dim
   ``2*D`` (128 for head_dim-64 models), satisfying Mosaic's 128-lane
   slice alignment, which a split K/V pool with D=64 cannot.
-- ``paged_write`` scatters new tokens into their pages (pure XLA scatter,
-  static shapes, out-of-bounds rows dropped).
+- ``paged_write`` puts new tokens into their pages by whole pages: read
+  the pages a row touches, select the new rows in, scatter the pages back
+  (pure XLA, static shapes, untouched pages dropped). A whole page is the
+  decode kernel's DMA unit, so the scatter keeps the pool in the row-major
+  layout the kernel demands; a per-token scatter makes XLA re-lay the
+  whole pool out around every write.
 - ``paged_attention_decode`` is a Pallas kernel for the single-token step:
   it builds an in-kernel work list of (sequence, page-chunk) items, then
   streams ONLY the used pages HBM->VMEM with double-buffered async copies
@@ -57,36 +65,68 @@ def make_kv_pages(num_kv_heads: int, num_pages: int, page_size: int,
                      dtype)
 
 
+def _layered(kv_pages: jax.Array, layer):
+    """(pool with a layer axis, layer): a pool without one (`layer=None`)
+    is layer 0 of one, by a bitcast."""
+    return (kv_pages[None], 0) if layer is None else (kv_pages, layer)
+
+
 # ------------------------------------------------------------------ write
 def paged_write(kv_pages: jax.Array, k_new: jax.Array, v_new: jax.Array,
                 block_tables: jax.Array, positions: jax.Array,
-                total_lens: jax.Array) -> jax.Array:
-    """Scatter new tokens' K/V into their sequences' pages.
+                total_lens: jax.Array, layer=None) -> jax.Array:
+    """Put new tokens' K/V into their sequences' pages of `layer`.
 
-    kv_pages: [P, Hkv, page, 2*D]; k_new/v_new: [B, S, Hkv, D];
+    kv_pages: [L, P, Hkv, page, 2*D] (layer: traced or static index) or
+    [P, Hkv, page, 2*D] (layer=None); k_new/v_new: [B, S, Hkv, D];
     block_tables: [B, MP] page ids; positions: [B, S] absolute positions
-    of the new tokens; total_lens: [B] sequence length INCLUDING the new
-    tokens. Writes for padding rows (positions >= total_lens) are dropped.
+    of the new tokens, contiguous from positions[:, 0]; total_lens: [B]
+    sequence length INCLUDING the new tokens. Writes for padding rows
+    (positions >= total_lens) are dropped.
+
+    Whole pages move: each row reads the (S + page - 2) // page + 1 pages
+    its span can touch, selects the new rows in and scatters the pages
+    back; pages with no new row go to an out-of-bounds id and are dropped.
+    Pages a row writes are its own (shared prefix pages are full and only
+    read), so the scatter has no duplicate indices.
     """
-    num_pages, _, page_size, _ = kv_pages.shape
-    valid = positions < total_lens[:, None]
-    page_ix = jnp.take_along_axis(block_tables, positions // page_size,
-                                  axis=1)
-    page_ix = jnp.where(valid, page_ix, num_pages)  # OOB -> mode="drop"
-    offset = positions % page_size
+    if layer is None:
+        return paged_write(kv_pages[None], k_new, v_new, block_tables,
+                           positions, total_lens, 0)[0]
+    _, num_pages, hkv, page, d2 = kv_pages.shape
+    b, s = positions.shape
+    mp = block_tables.shape[1]
+    n_pg = (s + page - 2) // page + 1
+    start = positions[:, 0]
+    lp = (start // page)[:, None] + jnp.arange(n_pg)    # [B, n_pg] columns
+    tok = lp[:, :, None] * page + jnp.arange(page)      # absolute positions
+    src = tok - start[:, None, None]                    # index of new token
+    write = ((src >= 0) & (src < s) & (lp < mp)[:, :, None]
+             & (tok < total_lens[:, None, None]))       # [B, n_pg, page]
+    pg = jnp.take_along_axis(block_tables, jnp.minimum(lp, mp - 1), axis=1)
+    pg = jnp.where(write.any(-1), pg, num_pages)        # OOB -> mode="drop"
     kv = jnp.concatenate([k_new, v_new], axis=-1).astype(kv_pages.dtype)
-    # non-adjacent advanced indices (axes 0 and 2) land in FRONT position:
-    # the indexed result is [B, S, Hkv, 2*D] — exactly kv's layout
-    return kv_pages.at[page_ix, :, offset].set(kv, mode="drop")
+    if s == 1:
+        new = kv[:, :, :, None, :]                      # [B, 1, Hkv, 1, 2D]
+    else:
+        new = jnp.take_along_axis(
+            kv, jnp.clip(src, 0, s - 1).reshape(b, -1, 1, 1), axis=1)
+        new = new.reshape(b, n_pg, page, hkv, d2).transpose(0, 1, 3, 2, 4)
+    old = kv_pages[layer, jnp.minimum(pg, num_pages - 1)]
+    pages = jnp.where(write[:, :, None, :, None], new, old)
+    return kv_pages.at[layer, pg].set(pages, mode="drop")
 
 
 # -------------------------------------------------------- gather reference
-def gather_kv(kv_pages: jax.Array,
-              block_tables: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """[P, Hkv, page, 2D] + [B, MP] -> (k, v) each [B, MP*page, Hkv, D]."""
-    _, hkv, page, d2 = kv_pages.shape
+def gather_kv(kv_pages: jax.Array, block_tables: jax.Array,
+              layer=None) -> Tuple[jax.Array, jax.Array]:
+    """[L, P, Hkv, page, 2D] at `layer` (or [P, Hkv, page, 2D]) + [B, MP]
+    -> (k, v) each [B, MP*page, Hkv, D]. One gather, no slice of a layer
+    first."""
+    kv_pages, layer = _layered(kv_pages, layer)
+    hkv, page, d2 = kv_pages.shape[-3:]
     b, mp = block_tables.shape
-    out = kv_pages[block_tables]                  # [B, MP, Hkv, page, 2D]
+    out = kv_pages[layer, block_tables]           # [B, MP, Hkv, page, 2D]
     out = out.transpose(0, 1, 3, 2, 4).reshape(b, mp * page, hkv, d2)
     d = d2 // 2
     return out[..., :d], out[..., d:]
@@ -95,18 +135,20 @@ def gather_kv(kv_pages: jax.Array,
 def paged_attention_reference(q: jax.Array, kv_pages: jax.Array,
                               block_tables: jax.Array,
                               positions: jax.Array,
-                              *, scale: Optional[float] = None) -> jax.Array:
+                              *, scale: Optional[float] = None,
+                              layer=None) -> jax.Array:
     """Attention over paged KV, gather-based. Causal by absolute position:
     query at position p attends to kv positions <= p within its own block
     table. The numerics oracle for the Pallas kernels and the off-TPU path.
 
-    q: [B, S, Hq, D]; kv_pages: [P, Hkv, page, 2D]; block_tables: [B, MP];
-    positions: [B, S]. Returns [B, S, Hq, D].
+    q: [B, S, Hq, D]; kv_pages: [L, P, Hkv, page, 2D] at `layer`, or
+    [P, Hkv, page, 2D]; block_tables: [B, MP]; positions: [B, S].
+    Returns [B, S, Hq, D].
     """
     b, s, hq, d = q.shape
-    _, hkv, page, _ = kv_pages.shape
+    hkv, page = kv_pages.shape[-3:-1]
     mp = block_tables.shape[1]
-    k, v = gather_kv(kv_pages, block_tables)      # [B, K, Hkv, D] each
+    k, v = gather_kv(kv_pages, block_tables, layer)  # [B, K, Hkv, D] each
     rep = hq // hkv
     scale = scale if scale is not None else d ** -0.5
     # GQA without materialising the broadcast: contract per kv-head group
@@ -123,7 +165,7 @@ def paged_attention_reference(q: jax.Array, kv_pages: jax.Array,
 
 
 # ----------------------------------------------------------- decode kernel
-def _decode_kernel(lengths_ref, bt_ref,            # SMEM scalars
+def _decode_kernel(lengths_ref, bt_ref, layer_ref, # SMEM scalars
                    q_ref, kv_hbm,                  # VMEM / HBM
                    o_ref,                          # VMEM out
                    kv_buf, work_b, work_c,         # scratch
@@ -131,7 +173,8 @@ def _decode_kernel(lengths_ref, bt_ref,            # SMEM scalars
                    page: int, chunk: int, scale: float):
     """Single-program decode kernel (grid=()): one flattened work list of
     (sequence, page-chunk) items, double-buffered page DMAs, all kv heads
-    per item.
+    per item. `kv_hbm` is the whole [L, P, Hkv, page, 2D] pool; only pages
+    of layer `layer_ref[0]` are streamed.
 
     A single program (rather than a grid) keeps ONE uninterrupted DMA
     pipeline across every sequence — per-program warm-up latency would
@@ -144,7 +187,8 @@ def _decode_kernel(lengths_ref, bt_ref,            # SMEM scalars
     from jax.experimental.pallas import tpu as pltpu
 
     n_b = lengths_ref.shape[0]
-    hkv = kv_hbm.shape[1]
+    hkv = kv_hbm.shape[2]
+    layer = layer_ref[0]
     bk = chunk * page                              # kv rows per work item
     hq, d2 = q_ref.shape[1], q_ref.shape[2]
     d = d2 // 2
@@ -173,7 +217,7 @@ def _decode_kernel(lengths_ref, bt_ref,            # SMEM scalars
         b, c = work_b[t], work_c[t]
         p = bt_ref[b, c * chunk + j]
         return pltpu.make_async_copy(
-            kv_hbm.at[p], kv_buf.at[slot, j], sems.at[slot])
+            kv_hbm.at[layer, p], kv_buf.at[slot, j], sems.at[slot])
 
     def n_pages_of(t):
         b, c = work_b[t], work_c[t]
@@ -265,13 +309,13 @@ def _decode_kernel(lengths_ref, bt_ref,            # SMEM scalars
 
 @functools.partial(jax.jit, static_argnames=("scale", "pages_per_chunk",
                                              "interpret"))
-def _decode_call(q, kv_pages, block_tables, lengths, *,
+def _decode_call(q, kv_pages, block_tables, lengths, layer, *,
                  scale: float, pages_per_chunk: int, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, hq, d = q.shape
-    _, hkv, page, d2 = kv_pages.shape
+    _, _, hkv, page, d2 = kv_pages.shape
     chunk = pages_per_chunk
     mp = block_tables.shape[1]
     max_chunks = -(-mp // chunk)
@@ -284,6 +328,7 @@ def _decode_call(q, kv_pages, block_tables, lengths, *,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),      # lengths [B]
             pl.BlockSpec(memory_space=pltpu.SMEM),      # block_tables
+            pl.BlockSpec(memory_space=pltpu.SMEM),      # layer [1]
             pl.BlockSpec(memory_space=pltpu.VMEM),      # q (zero-padded)
             # explicitly HBM (not ANY): the compiler would happily place
             # a small page pool in VMEM, where per-page slices violate
@@ -300,7 +345,7 @@ def _decode_call(q, kv_pages, block_tables, lengths, *,
         ],
         interpret=interpret,
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q_pad, kv_pages)
+      jnp.asarray(layer, jnp.int32).reshape(1), q_pad, kv_pages)
     return out
 
 
@@ -322,6 +367,7 @@ def decode_kernel_constraint(head_dim: int, page_size: int,
 
 def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
                            block_tables: jax.Array, lengths: jax.Array, *,
+                           layer=None,
                            scale: Optional[float] = None,
                            pages_per_chunk: Optional[int] = None,
                            interpret: Optional[bool] = None,
@@ -329,9 +375,9 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
     """Single-token decode attention over paged KV (Pallas on TPU).
 
     q: [B, Hq, D] (the newest token per sequence, already written to its
-    page); kv_pages: [P, Hkv, page, 2D]; block_tables: [B, MP];
-    lengths: [B] total tokens per sequence (0 = inactive row -> zero
-    output). Returns [B, Hq, D].
+    page); kv_pages: [L, P, Hkv, page, 2D] at `layer`, or
+    [P, Hkv, page, 2D]; block_tables: [B, MP]; lengths: [B] total tokens
+    per sequence (0 = inactive row -> zero output). Returns [B, Hq, D].
 
     Which implementation runs is the caller's choice or the backend's,
     never a quiet substitution: `force_reference=True` is the jnp gather
@@ -343,13 +389,13 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
     """
     d = q.shape[-1]
     scale_f = float(scale if scale is not None else d ** -0.5)
-    page = kv_pages.shape[2]
+    page = kv_pages.shape[-2]
     on_tpu = jax.default_backend() == "tpu"
     if force_reference or (interpret is None and not on_tpu):
         positions = jnp.maximum(lengths - 1, 0)[:, None]
         out = paged_attention_reference(
             q[:, None], kv_pages, block_tables, positions,
-            scale=scale_f)[:, 0]
+            scale=scale_f, layer=layer)[:, 0]
         # honor the inactive-row contract (length 0 -> zero output):
         # the clamped position would otherwise admit kv position 0
         return jnp.where((lengths > 0)[:, None, None], out, 0)
@@ -362,7 +408,8 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
         pages_per_chunk = max(1, min(block_tables.shape[1],
                                      -(-128 // page)))
     pages_per_chunk = min(pages_per_chunk, block_tables.shape[1])
-    return _decode_call(q, kv_pages, block_tables, lengths,
+    kv_pages, layer = _layered(kv_pages, layer)
+    return _decode_call(q, kv_pages, block_tables, lengths, layer,
                         scale=scale_f, pages_per_chunk=pages_per_chunk,
                         interpret=bool(interpret))
 
@@ -426,7 +473,8 @@ def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
                             positions: jax.Array, total_lens: jax.Array,
                             *, ctx_pages: int = 0,
                             scale: Optional[float] = None,
-                            impl: Optional[str] = None) -> jax.Array:
+                            impl: Optional[str] = None,
+                            layer=None) -> jax.Array:
     """Prefill attention: new tokens attend to themselves (causal) and to
     an optional cached prefix held in pages, merged by log-sum-exp.
 
@@ -435,7 +483,8 @@ def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
     multiple of page_size by the prefix-cache contract). ctx_pages is the
     STATIC number of block-table columns the prefix may span; 0 skips the
     prefix part entirely (no page reads at all). Rows whose prefix is
-    shorter mask the tail; rows with no prefix mask everything.
+    shorter mask the tail; rows with no prefix mask everything. kv_pages
+    is [L, P, Hkv, page, 2D] at `layer`, or [P, Hkv, page, 2D].
     """
     d = q.shape[-1]
     scale_f = float(scale if scale is not None else d ** -0.5)
@@ -443,9 +492,9 @@ def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
                          scale=scale_f, impl=impl)
     if ctx_pages <= 0:
         return o1
-    page = kv_pages.shape[2]
+    page = kv_pages.shape[-2]
     bt = block_tables[:, :ctx_pages]
-    k_ctx, v_ctx = gather_kv(kv_pages, bt)         # [B, CP*page, Hkv, D]
+    k_ctx, v_ctx = gather_kv(kv_pages, bt, layer)  # [B, CP*page, Hkv, D]
     b, sq = q.shape[:2]
     ctx_len = positions[:, 0]                      # [B]
     kv_pos = jnp.arange(ctx_pages * page)

@@ -11,9 +11,12 @@ TPU-first mechanics:
 - all jitted shapes are bucketed (prefill length; decode always runs the
   full `max_batch` slot set) so each bucket compiles once; page buffers are
   donated so the cache updates in place without a copy
-- the KV cache is paged ([L, P, page, Hkv, D]); the model scatters new
-  tokens into pages and attends through block tables
-  (ray_tpu/ops/paged_attention.py)
+- the KV cache is paged, one pool [L, P, Hkv, page, 2*D] for all layers;
+  the model carries it whole through its layer scan, writes new tokens
+  into it by whole pages and attends through block tables
+  (ray_tpu/ops/paged_attention.py), so a program holds the pool once and
+  moves only the pages it touches (tests/test_chip_compile.py reads the
+  compiled programs for that)
 - prefix caching: full pages are refcount-shared across requests keyed by
   rolling content hash (cache.py), so shared system prompts prefill once
 - tensor parallelism (EngineConfig.tp > 1 or an explicit mesh=): params

@@ -128,3 +128,27 @@ def cpu_mesh_devices():
     assert len(devices) >= 8, (
         "tests expect XLA_FLAGS=--xla_force_host_platform_device_count=8")
     return devices
+
+
+@pytest.fixture
+def dense_greedy():
+    """The serving engines' oracle, `run(model, params, prompts, n_tokens)`:
+    greedy continuations of `prompts` by the model run densely with no
+    cache, one causal forward over the zero-padded batch per token (what
+    follows a position cannot reach it, so one compiled width serves)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    def run(model, params, prompts, n_tokens):
+        seqs = [list(p) for p in prompts]
+        width = -(-(max(map(len, seqs)) + n_tokens) // 16) * 16
+        fwd = jax.jit(lambda ids: model.apply({"params": params}, ids))
+        for _ in range(n_tokens):
+            ids = np.zeros((len(seqs), width), np.int32)
+            for i, seq in enumerate(seqs):
+                ids[i, :len(seq)] = seq
+            logits = np.asarray(fwd(jnp.asarray(ids)))
+            for i, seq in enumerate(seqs):
+                seq.append(int(logits[i, len(seq) - 1].argmax()))
+        return [seq[len(p):] for seq, p in zip(seqs, prompts)]
+    return run

@@ -1,16 +1,20 @@
-"""Compile the main path's Pallas kernels for a described (not attached)
-TPU v5e, at real widths.
+"""Compile the main path's Pallas kernels, and the serving engine's own
+programs, for a described (not attached) TPU v5e, at real widths.
 
 Interpret-mode tests cannot see what Mosaic refuses: misaligned slices,
 too much VMEM, a kernel GSPMD cannot partition. The TPU compiler is
 installed here and compiles for a `v5e:2x2` that is only described, so
 each case costs a second or two and no chip time. Nothing runs: a compile
-that passes says nothing about results or speed.
+that passes says nothing about results or speed. What a compiled module
+does say is where the KV pool goes: the engine's programs are read for
+whole-pool copies (`test_engine_program_keeps_the_pool_in_place`).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and under xdist every
 worker imports every test file.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,20 +87,21 @@ def _flash(fn, shape):
         topo.devices[0])))
 
 
-def _paged_decode(b, d, mp, hq=32, hkv=8, page=16):
+def _paged_decode(b, d, mp, hq=32, hkv=8, page=16, layers=4):
     def build(topo):
         one_chip = SingleDeviceSharding(topo.devices[0])
 
         def sds(shape, dt):
             return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-        def fn(q, kv_pages, block_tables, lengths):
-            return _decode_call(q, kv_pages, block_tables, lengths,
+        def fn(q, kv_pages, block_tables, lengths, layer):
+            return _decode_call(q, kv_pages, block_tables, lengths, layer,
                                 scale=d ** -0.5, pages_per_chunk=128 // page,
                                 interpret=False)
         return fn, (sds((b, hq, d), BF16),
-                    sds((b * mp, hkv, page, 2 * d), BF16),
-                    sds((b, mp), jnp.int32), sds((b,), jnp.int32))
+                    sds((layers, b * mp, hkv, page, 2 * d), BF16),
+                    sds((b, mp), jnp.int32), sds((b,), jnp.int32),
+                    sds((), jnp.int32))
     return build
 
 
@@ -140,3 +145,134 @@ def test_kernel_compiles_for_v5e(topo, no_persistent_cache, name):
             lowered.compile()
     else:
         assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+# ------------------------------------------- the engine's own programs
+# Mistral-7B widths. "short": 2 layers of the chat cell's sizes; 2200
+# pages, so that one layer of the pool (144 MB) is more than the chip's
+# 128 MiB of VMEM, as in a deployment: a pool that fits there is
+# prefetched whole, which reads as a copy. "long": the docbatch cell's
+# configuration at its full 16 layers, where the flash kernel's resident
+# K, V and segment ids are largest (kv 8320).
+HKV, PAGE, HEAD_DIM = 8, 16, 128
+ENGINES = {
+    "short": dict(layers=2, pages=2200, max_model_len=2688, buckets=(8, 128)),
+    "long": dict(layers=16, pages=1900, max_model_len=8320, buckets=(4096,)),
+}
+MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice",
+         "slice", "transpose", "concatenate")
+# program -> (engine, kind, shape key given (wave rows, pages per sequence))
+ENGINE_PROGRAMS = {
+    "decode-S1": ("short", "decode", lambda rb, mp: (1, mp)),
+    "prefill-bucket128": ("short", "prefill", lambda rb, mp: (128, rb, 0)),
+    "prefill-bucket128-prefix-hit":
+        ("short", "prefill", lambda rb, mp: (128, rb, mp)),
+    "verify-unaligned-span8": ("short", "verify", lambda rb, mp: (8, rb)),
+    "prefill-bucket4096-prefix-hit-kv8320":
+        ("long", "prefill", lambda rb, mp: (4096, rb, mp)),
+}
+
+
+@pytest.fixture(scope="module")
+def engines():
+    """name -> an `LLMEngine` whose params are shapes only: its `_jit`
+    builds the real `run_decode` / `run_prefill` / `run_verify`, nothing
+    runs. Its own pool is two pages; a program takes the pool's size from
+    its argument, which the test gives at `ENGINES[name]["pages"]`."""
+    import flax.linen as nn
+
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    def build(layers, pages, max_model_len, buckets):
+        eng = LLMEngine(EngineConfig(
+            model="llama3-8b", dtype="bfloat16", page_size=PAGE,
+            num_pages=2, max_model_len=max_model_len, max_batch=8,
+            prefill_buckets=buckets,
+            model_overrides=dict(
+                num_layers=layers, hidden_size=4096, num_heads=32,
+                intermediate_size=14336, num_kv_heads=HKV,
+                head_dim=HEAD_DIM, vocab_size=32768, rope_theta=1e6)),
+            params={})
+        eng.params = jax.eval_shape(lambda: nn.meta.unbox(eng.model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+        return eng
+
+    return {name: build(**sizes) for name, sizes in ENGINES.items()}
+
+
+def _program_args(kind, shape_key, rows, mp, sds):
+    """Shapes of a program's arguments after (params, kv_pages)."""
+    i32, f32 = jnp.int32, jnp.float32
+    if kind == "decode":
+        return (sds((rows, 1), i32), sds((rows, mp), i32), sds((rows,), i32),
+                sds((rows,), i32), sds((rows, 1), i32),
+                sds((rows,), jnp.bool_), sds((rows, 1), i32),
+                sds((rows,), f32), sds((rows,), i32),
+                sds((shape_key[0], rows, 2), jnp.uint32))
+    span = shape_key[0]
+    args = (sds((rows, mp), i32), sds((rows,), i32), sds((rows, span), i32),
+            sds((rows, span), i32))
+    if kind == "verify":
+        return args
+    return args + (sds((rows,), i32), sds((rows,), f32), sds((rows,), i32),
+                   sds((rows, 2), jnp.uint32))
+
+
+def _array_types(type_text):
+    """[(dims, minor-to-major)] of every array in an HLO type."""
+    return [(tuple(map(int, dims.split(","))) if dims else (),
+             tuple(map(int, m2m.split(","))) if m2m else ())
+            for dims, m2m in re.findall(r"\w+\[([\d,]*)\]\{([\d,]*)",
+                                        type_text)]
+
+
+@pytest.mark.parametrize("name", list(ENGINE_PROGRAMS))
+def test_engine_program_keeps_the_pool_in_place(
+        topo, no_persistent_cache, engines, monkeypatch, name):
+    """The donated pool is ONE buffer from argument to result: no
+    instruction that moves data has an output of its size or a layer's,
+    it is row-major wherever it appears (the layout the decode kernel's
+    custom call demands, so nothing re-lays it out), and the argument is
+    aliased to the result."""
+    # the ops choose kernel or reference by the backend, at trace time
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    which, kind, key = ENGINE_PROGRAMS[name]
+    engine = engines[which]
+    mp = engine.max_pages_per_seq
+    rows = engine.config.max_batch if kind == "decode" else engine._wave_rb
+    shape_key = key(rows, mp)
+    pool_dims = (ENGINES[which]["layers"], ENGINES[which]["pages"], HKV,
+                 PAGE, 2 * HEAD_DIM)
+    layer_bytes = 2 * int(np.prod(pool_dims[1:]))
+    pool_bytes = (layer_bytes, pool_dims[0] * layer_bytes)  # in any shape
+    text = engine._jit(kind, shape_key).lower(
+        jax.tree.map(lambda a: sds(a.shape, a.dtype), engine.params),
+        sds(pool_dims, BF16),
+        *_program_args(kind, shape_key, rows, mp, sds)).compile().as_text()
+
+    assert "tpu_custom_call" in text
+    moved, layouts, pool_param = [], set(), None
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        arrays = _array_types(m.group(1))
+        if m.group(2) in MOVES and any(
+                2 * int(np.prod(dims)) in pool_bytes for dims, _ in arrays):
+            moved.append(line.strip()[:160])
+        layouts.update(m2m for dims, m2m in arrays if dims == pool_dims)
+        # the entry computation's parameters are the ones with a sharding
+        entry = re.search(r" parameter\((\d+)\), sharding=", line)
+        if entry and arrays[0][0] == pool_dims:
+            pool_param = int(entry.group(1))
+    assert not moved, "\n".join(moved)
+    assert layouts == {(4, 3, 2, 1, 0)}, layouts
+    header = text.split("\n", 1)[0]
+    assert pool_param is not None
+    assert re.search(r"input_output_alias=\{[^\n]*\(%d, \{\}, (may|must)-alias\)"
+                     % pool_param, header), header[:300]

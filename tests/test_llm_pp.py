@@ -246,12 +246,15 @@ def test_pp_preemption_token_identical(shared_cluster):
         pp.shutdown()
 
 
-def test_pp_zero_control_rpcs_and_bubble_accounting(shared_cluster):
+def test_pp_zero_control_rpcs_and_bubble_accounting(shared_cluster,
+                                                    dense_greedy):
     """Steady-state decode moves ONLY channel frames: across a window
     of pure-decode steps the process's RPC send counters stay flat
     (ambient liveness aside). The same window feeds the measured bubble
     counters: every stage counted reads, pp_bubble_frac in [0, 1], and
-    reset zeroes the window."""
+    reset zeroes the window. Run to the end, the stages' tokens (each
+    stage's pool an [n_layers] slice carried through its own layer scan)
+    are those of the whole model run densely with no cache."""
     from ray_tpu.runtime import rpc
 
     cfg = EngineConfig(pp=2, pp_microbatches=4, **ENGINE_CFG)
@@ -260,12 +263,19 @@ def test_pp_zero_control_rpcs_and_bubble_accounting(shared_cluster):
         # depth raised to cover the fill+drain window
         assert cfg.pipeline_depth >= 4
         rng = np.random.default_rng(7)
-        for i in range(4):
-            pp.add_request(f"r{i}", list(rng.integers(0, 500, 12)),
-                           SamplingParams(max_tokens=30))
+        prompts = {f"r{i}": list(rng.integers(0, 500, 12))
+                   for i in range(4)}
+        got = {rid: [] for rid in prompts}
+
+        def step():
+            for delta in pp.step():
+                got[delta.request_id].extend(delta.new_token_ids)
+
+        for rid, prompt in prompts.items():
+            pp.add_request(rid, prompt, SamplingParams(max_tokens=30))
         # enter steady state: every request prefilled and decoding
         for _ in range(200):
-            pp.step()
+            step()
             if all(r.decode_ready for r in pp.running) \
                     and len(pp.running) == 4:
                 break
@@ -275,7 +285,7 @@ def test_pp_zero_control_rpcs_and_bubble_accounting(shared_cluster):
         ambient = {"heartbeat", "report_metrics", "view_update"}
         before = rpc.transport_sends()
         for _ in range(12):
-            pp.step()
+            step()
         after = rpc.transport_sends()
         delta = {k: after[k] - before.get(k, 0) for k in after
                  if after[k] != before.get(k, 0) and k not in ambient}
@@ -287,5 +297,14 @@ def test_pp_zero_control_rpcs_and_bubble_accounting(shared_cluster):
         assert stats["reads"] > 0
         assert 0.0 <= stats["pp_bubble_frac"] <= 1.0
         assert pp.pp_stats(reset=True)["reads"] >= 0
+
+        for _ in range(600):
+            if not (pp.running or pp.waiting):
+                break
+            step()
+        whole = LLMEngine(EngineConfig(**ENGINE_CFG))  # same seed, unrun
+        want = dense_greedy(whole.model, whole.params,
+                            list(prompts.values()), 30)
+        assert list(got.values()) == want
     finally:
         pp.shutdown()

@@ -127,6 +127,36 @@ def test_prefix_cache_reuse_identical_output():
     assert out_a == out_b
 
 
+def test_engine_matches_dense_greedy_through_prefix_hit_and_preemption(
+        dense_greedy):
+    """Every way the engine's programs touch the pool, in one run: fresh
+    prefill, 32 decode steps, a preemption and its re-prefill, and a
+    prefix-cache hit (the ctx_pages > 0 program). Each request's greedy
+    tokens are those of the same model run densely with no cache."""
+    cfg = dict(ENGINE_CFG)
+    cfg.update(num_pages=12, max_model_len=64, max_batch=2,
+               prefill_buckets=(16, 32, 64))
+    engine = LLMEngine(EngineConfig(**cfg))
+    rng = np.random.default_rng(4)
+    prompts = {f"p{i}": list(rng.integers(0, 500, 17)) for i in range(2)}
+    # two full pages of p0's prompt, then a tail of its own
+    prompts["hit"] = prompts["p0"][:16] + list(rng.integers(0, 500, 5))
+    n = 32
+    # 2 x (17 + 32) tokens need 14 pages and 11 are free: one is preempted
+    for rid in ("p0", "p1"):
+        engine.add_request(rid, prompts[rid], SamplingParams(max_tokens=n))
+    out = _collect(engine, ["p0", "p1"], max_steps=900)
+    assert engine.stats()["preempted_total"] >= 1
+    hits = engine.allocator.stats["cache_hits"]
+    engine.add_request("hit", prompts["hit"], SamplingParams(max_tokens=n))
+    out.update(_collect(engine, ["hit"], max_steps=900))
+    assert engine.allocator.stats["cache_hits"] > hits
+    want = dense_greedy(engine.model, engine.params,
+                        list(prompts.values()), n)
+    for rid, tokens in zip(prompts, want):
+        assert out[rid]["ids"] == tokens, rid
+
+
 def test_page_pressure_queues_and_completes():
     """More requests than the page pool supports at once: engine must queue
     and still complete everything."""

@@ -21,11 +21,24 @@ from ray_tpu.ops.paged_attention import (  # noqa: E402
     paged_attention_reference, paged_prefill_attention, paged_write)
 
 
-def _make_pages(rng, *, b, hkv, d, page, num_pages, mp, lengths):
+N_LAYERS = 3
+# None: a pool with no layer axis; 0 and L-1: the ends of a layered pool
+LAYERS = [None, 0, N_LAYERS - 1]
+
+
+def _make_pages(rng, *, b, hkv, d, page, num_pages, mp, lengths,
+                layer=None):
     """Page pool + per-row block tables holding `lengths` real tokens
     (written via paged_write), plus the dense [B, Smax, Hkv, D] K/V they
-    encode for oracle computation."""
+    encode for oracle computation. With a `layer` the pool is layered
+    [N_LAYERS, P, ...], only that layer is written, and the others hold
+    noise that must come through the write bit-identical (a wrong layer
+    index would corrupt a neighbour silently)."""
     kv_pages = make_kv_pages(hkv, num_pages, page, d, jnp.float32)
+    if layer is not None:
+        noise = jnp.asarray(rng.standard_normal(
+            (N_LAYERS,) + kv_pages.shape), jnp.float32)
+        kv_pages = noise.at[layer].set(kv_pages)
     # distinct pages per row, page 0 reserved as the null page
     perm = rng.permutation(num_pages - 1)[: b * mp] + 1
     bt = jnp.asarray(perm.reshape(b, mp), jnp.int32)
@@ -36,23 +49,95 @@ def _make_pages(rng, *, b, hkv, d, page, num_pages, mp, lengths):
                           jnp.float32)
     positions = jnp.broadcast_to(jnp.arange(smax), (b, smax))
     lens = jnp.asarray(lengths, jnp.int32)
-    kv_pages = paged_write(kv_pages, k_dense, v_dense, bt, positions, lens)
+    before = kv_pages
+    kv_pages = paged_write(kv_pages, k_dense, v_dense, bt, positions, lens,
+                           layer)
+    for other in range(N_LAYERS if layer is not None else 0):
+        if other != layer:
+            np.testing.assert_array_equal(np.asarray(kv_pages[other]),
+                                          np.asarray(before[other]))
     return kv_pages, bt, k_dense, v_dense, lens
 
 
-def test_write_then_gather_roundtrip():
+@pytest.mark.parametrize("layer", LAYERS)
+def test_write_then_gather_roundtrip(layer):
     rng = np.random.default_rng(0)
     b, hkv, d, page, mp = 3, 2, 8, 4, 5
     lengths = [17, 0, 20]
     kv_pages, bt, k_dense, v_dense, lens = _make_pages(
         rng, b=b, hkv=hkv, d=d, page=page, num_pages=32, mp=mp,
-        lengths=lengths)
-    got_k, got_v = gather_kv(kv_pages, bt)
+        lengths=lengths, layer=layer)
+    got_k, got_v = gather_kv(kv_pages, bt, layer)
     for i, n in enumerate(lengths):
         np.testing.assert_allclose(got_k[i, :n], k_dense[i, :n], rtol=1e-6)
         np.testing.assert_allclose(got_v[i, :n], v_dense[i, :n], rtol=1e-6)
         # beyond the row's length nothing was written
         assert not np.any(np.asarray(got_k[i, n:]))
+
+
+def _write_per_token(kv_pages, k_new, v_new, block_tables, positions,
+                     total_lens, layer):
+    """The write as it was before whole pages: one scatter row a token.
+    Kept here as the oracle of `paged_write`: same tokens, same places."""
+    num_pages, page = kv_pages.shape[1], kv_pages.shape[3]
+    valid = positions < total_lens[:, None]
+    page_ix = jnp.take_along_axis(block_tables, positions // page, axis=1)
+    page_ix = jnp.where(valid, page_ix, num_pages)  # OOB -> mode="drop"
+    kv = jnp.concatenate([k_new, v_new], axis=-1).astype(kv_pages.dtype)
+    return kv_pages.at[layer, page_ix, :, positions % page].set(
+        kv, mode="drop")
+
+
+PAGE = 8
+# name -> (first position per row, S, total_lens per row); the block
+# table has 4 columns of PAGE rows, so a sequence holds 32 tokens
+WRITES = {
+    **{f"decode-offset{o}": ([PAGE + o, o, 3 * PAGE + o], 1,
+                             [PAGE + o + 1, o + 1, 3 * PAGE + o + 1])
+       for o in range(PAGE)},
+    # page-aligned prefill with ragged tails (13 and 1 of 16 real)
+    "prefill-aligned-ragged": ([0, PAGE, 0], 2 * PAGE, [13, PAGE + 16, 1]),
+    # unaligned spans crossing one and two page boundaries (verify, chunks)
+    "span-unaligned": ([PAGE - 3, 5, 2 * PAGE - 1], 6, [PAGE + 3, 11, 21]),
+    "span-unaligned-long": ([3, PAGE + 7, 1], PAGE + 4,
+                            [PAGE + 7, 2 * PAGE + 11, PAGE + 5]),
+    # inactive rows write nothing, wherever their positions point
+    "rows-inactive": ([0, 9, 0], 5, [0, 14, 0]),
+    # a row past its cap: frozen at cap - 1 (decode), or a span whose
+    # tail lies beyond total_lens and beyond the block table's last page
+    "decode-at-cap": ([4 * PAGE - 1, 7, 4 * PAGE - 1], 1,
+                      [4 * PAGE, 8, 4 * PAGE]),
+    "span-past-table": ([4 * PAGE - 2, 3 * PAGE + 5, 0], 6,
+                        [4 * PAGE, 4 * PAGE, 6]),
+}
+
+
+@pytest.mark.parametrize("layer", [0, N_LAYERS - 1])
+@pytest.mark.parametrize("case", list(WRITES))
+def test_whole_page_write_equals_per_token_write(case, layer):
+    """`paged_write` moves whole pages; what lands in the pool is, bit for
+    bit, what a per-token scatter puts there, on a pool full of noise."""
+    starts, s, totals = WRITES[case]
+    rng = np.random.default_rng(6)
+    b, hkv, d, mp, num_pages = len(starts), 2, 8, 4, 20
+    pool = jnp.asarray(rng.standard_normal(
+        (N_LAYERS, num_pages, hkv, PAGE, 2 * d)), jnp.bfloat16)
+    bt = jnp.asarray(rng.permutation(num_pages - 1)[:b * mp].reshape(b, mp)
+                     + 1, jnp.int32)
+    for i, total in enumerate(totals):
+        if total == 0:
+            bt = bt.at[i].set(0)        # the engine's padding rows
+    k_new, v_new = (jnp.asarray(rng.standard_normal((b, s, hkv, d)),
+                                jnp.bfloat16) for _ in range(2))
+    positions = jnp.asarray(starts, jnp.int32)[:, None] + jnp.arange(s)
+    totals = jnp.asarray(totals, jnp.int32)
+    got = jax.jit(paged_write)(pool, k_new, v_new, bt, positions, totals,
+                               layer)
+    want = _write_per_token(pool, k_new, v_new, bt, positions, totals,
+                            layer)
+    assert np.any(np.asarray(want != pool)) or not np.any(totals)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
 
 
 def test_reference_matches_dense_attention():
@@ -70,22 +155,24 @@ def test_reference_matches_dense_attention():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("layer", LAYERS)
 @pytest.mark.parametrize("hq,hkv", [(8, 2), (4, 4)])
 @pytest.mark.parametrize("pages_per_chunk", [1, 3, 8])
-def test_decode_kernel_matches_reference(hq, hkv, pages_per_chunk):
+def test_decode_kernel_matches_reference(hq, hkv, pages_per_chunk, layer):
     rng = np.random.default_rng(2)
     b, d, page, mp = 4, 32, 4, 8
     lengths = [1, 13, 0, mp * page]  # incl. inactive + full rows
     kv_pages, bt, _, _, lens = _make_pages(
         rng, b=b, hkv=hkv, d=d, page=page, num_pages=64, mp=mp,
-        lengths=lengths)
+        lengths=lengths, layer=layer)
     q = jnp.asarray(rng.standard_normal((b, hq, d)), jnp.float32)
-    got = paged_attention_decode(q, kv_pages, bt, lens,
+    got = paged_attention_decode(q, kv_pages, bt, lens, layer=layer,
                                  pages_per_chunk=pages_per_chunk,
                                  interpret=True)
     positions = jnp.maximum(lens - 1, 0)[:, None]
-    want = paged_attention_reference(q[:, None], kv_pages, bt,
-                                     positions)[:, 0]
+    # the oracle reads the written layer as a pool of its own
+    own = kv_pages if layer is None else kv_pages[layer]
+    want = paged_attention_reference(q[:, None], own, bt, positions)[:, 0]
     got, want = np.asarray(got), np.asarray(want)
     for i, n in enumerate(lengths):
         if n == 0:
@@ -113,9 +200,10 @@ def test_decode_kernel_bf16():
         rtol=5e-2, atol=5e-2)
 
 
+@pytest.mark.parametrize("layer", LAYERS)
 @pytest.mark.parametrize("impl", [None, "flash"])
 @pytest.mark.parametrize("ctx_lens", [(0, 0), (8, 0), (8, 16)])
-def test_prefill_merge_matches_reference(ctx_lens, impl):
+def test_prefill_merge_matches_reference(ctx_lens, impl, layer):
     """New tokens starting at a (page-aligned) cached-prefix offset must
     attend prefix + themselves exactly like the one-shot gather path."""
     rng = np.random.default_rng(4)
@@ -124,7 +212,7 @@ def test_prefill_merge_matches_reference(ctx_lens, impl):
     lengths = [c + s_new for c in ctx_lens]
     kv_pages, bt, k_dense, v_dense, lens = _make_pages(
         rng, b=b, hkv=hkv, d=d, page=page, num_pages=32, mp=mp,
-        lengths=lengths)
+        lengths=lengths, layer=layer)
     positions = jnp.stack([jnp.arange(c, c + s_new) for c in ctx_lens])
     q = jnp.asarray(rng.standard_normal((b, s_new, hq, d)), jnp.float32)
     k_new = jnp.stack([k_dense[i, c:c + s_new] for i, c in
@@ -132,15 +220,17 @@ def test_prefill_merge_matches_reference(ctx_lens, impl):
     v_new = jnp.stack([v_dense[i, c:c + s_new] for i, c in
                        enumerate(ctx_lens)])
     got = paged_prefill_attention(q, k_new, v_new, kv_pages, bt,
-                                  positions, lens, ctx_pages=mp, impl=impl)
-    want = paged_attention_reference(q, kv_pages, bt, positions)
+                                  positions, lens, ctx_pages=mp, impl=impl,
+                                  layer=layer)
+    want = paged_attention_reference(q, kv_pages, bt, positions,
+                                     layer=layer)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     if max(ctx_lens) == 0:
         # ctx_pages=0 must also work (and read no pages)
         got0 = paged_prefill_attention(q, k_new, v_new, kv_pages, bt,
                                        positions, lens, ctx_pages=0,
-                                       impl=impl)
+                                       impl=impl, layer=layer)
         np.testing.assert_allclose(np.asarray(got0), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
