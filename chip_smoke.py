@@ -79,6 +79,11 @@ class ServeSizes:
         "Name three uses of a paged KV cache in a serving engine. Then "
         "name a fourth.",
     )
+    # the expert model of the engine phase: `tiny-moe` (4 experts, top-2,
+    # 2 layers) at widths the chip's tiling accepts
+    expert_overrides: tuple = (
+        ("hidden_size", 512), ("intermediate_size", 1024), ("num_heads", 4),
+        ("num_kv_heads", 2), ("head_dim", 128), ("vocab_size", 512))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -372,7 +377,66 @@ def phase_engine(sz: ServeSizes = ServeSizes(),
 
     facts["kernel_vs_reference_max_abs_err"] = _kernel_parity(
         engine.model_cfg, sz.page_size)
+    del engine
+    facts["experts"] = _expert_engine(sz)
     return facts
+
+
+def _expert_engine(sz: ServeSizes, tol: float = 3e-2) -> Dict[str, Any]:
+    """A sparse-expert model through the same engine: the dropless expert
+    layer's grouped matmul (ops/grouped_matmul.py) is in the decode
+    program, agrees with a plain loop over the experts on the device, and
+    the engine's routing counters count every real token k x L times."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+    from ray_tpu.serve.llm import LLMEngine
+
+    engine = LLMEngine(_engine_config(
+        sz, model="tiny-moe", model_overrides=dict(sz.expert_overrides)))
+    cfg = engine.model_cfg
+    out = {"kernel": require_kernel(
+        "expert decode", engine.program_text("decode",
+                                             engine._decode_shape_key()))}
+    prompt = _chat_prompt_ids(sz.chats[0])
+    toks = _generate(engine, "experts", prompt, sz.max_tokens)
+    if not toks or not all(0 <= t < cfg.vocab_size for t in toks):
+        raise SmokeFailure(f"expert engine produced bad ids: {toks}")
+    # every prompt token and every fed-back token passes k experts in each
+    # layer; chunks computed past a stop are counted too, so >=
+    least = ((len(prompt) + len(toks) - 1) * cfg.num_experts_per_tok
+             * cfg.num_layers)
+    out["moe_assignments_total"] = engine.stats()["moe_assignments_total"]
+    if out["moe_assignments_total"] < least:
+        raise SmokeFailure(f"routing counters lost tokens: {out} < {least}")
+
+    # the grouped matmul against a loop over the experts, in the engine's
+    # type: a whole [L, E, K, N] stack read by layer, uneven groups, an
+    # empty one, and a tail of rows that belongs to no expert (what the
+    # kernel leaves there is undefined, so only the groups' rows compare)
+    E, h, f = cfg.num_experts, cfg.hidden_size, cfg.intermediate_size
+    dtype = engine.params["embed"].dtype
+    keys = jax.random.split(jax.random.PRNGKey(1), 2)
+    sizes = [37, 0, 150] + [11] * (E - 3)
+    m = sum(sizes) + 58
+    lhs = jax.random.normal(keys[0], (m, h), dtype)
+    rhs = jax.random.normal(keys[1], (2, E, h, 2 * f), dtype) * h ** -0.5
+    got = jax.jit(grouped_matmul)(lhs, rhs, jnp.asarray(sizes),
+                                  jnp.int32(1)).astype(jnp.float32)
+    got = got[:sum(sizes)]
+    want, at = jnp.zeros_like(got), 0
+    with jax.default_matmul_precision("highest"):
+        for e, n in enumerate(sizes):
+            want = want.at[at:at + n].set(
+                lhs[at:at + n].astype(jnp.float32)
+                @ rhs[1, e].astype(jnp.float32))
+            at += n
+    out["grouped_matmul_max_abs_err"] = float(jnp.max(jnp.abs(got - want)))
+    if not out["grouped_matmul_max_abs_err"] <= tol * float(
+            jnp.max(jnp.abs(want))):
+        raise SmokeFailure(f"grouped matmul disagrees with the loop: {out}")
+    return out
 
 
 def _kernel_parity(cfg, page: int, tol: float = 3e-2) -> Dict[str, float]:

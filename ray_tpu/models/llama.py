@@ -68,14 +68,15 @@ class LlamaConfig:
     scan_layers: bool = True
     attention_impl: Optional[str] = None  # None = auto (flash on TPU)
     # MoE (Mixtral-style): 0 = dense MLP. Experts are stacked [E, ...]
-    # params with the "expert" logical axis -> the mesh's ep axis; the
-    # capacity-based einsum dispatch keeps every shape static so XLA turns
-    # the token shuffle into all-to-alls over ICI.
+    # params with the "expert" logical axis -> the mesh's ep axis. Without
+    # an ep axis the layer is dropless (sorted assignments, grouped
+    # matmul); capacity_factor and moe_group_size size the capacity
+    # dispatch that runs only when experts are sharded over ep (MoEMLP).
     num_experts: int = 0
     num_experts_per_tok: int = 2
     capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.02
-    moe_group_size: int = 2048  # dispatch group (bounds routing memory)
+    moe_group_size: int = 2048  # ep dispatch group (bounds routing memory)
 
     @property
     def head_dim_(self) -> int:
@@ -261,48 +262,153 @@ class MLP(nn.Module):
 
 
 class MoEMLP(nn.Module):
-    """Mixtral-style sparse MoE FFN, GShard-style grouped einsum dispatch.
+    """Mixtral's sparse expert FFN (HF `MixtralSparseMoeBlock`):
+    `p = softmax(x W_g)` in float32, the k largest kept and renormalised
+    to sum 1, `y = sum_i p_i * W2_i(silu(W1_i x) * W3_i x)`.
 
-    TPU-first shape discipline: tokens are split into fixed-size groups and
-    routed with a capacity-bounded one-hot dispatch tensor, so every shape
-    is static — XLA lowers the token shuffle to all-to-alls over the ep
-    mesh axis (expert weights carry the "expert" logical axis). The
-    dispatch tensor is [G, g, E, C] with C ~ k*g/E, i.e. linear in total
-    tokens (the per-group capacity bound is what prevents the quadratic
-    [T, E, k*T/E] blowup of ungrouped dispatch).
+    Where experts are not sharded (no ep axis in the ambient mesh: every
+    serving engine and the one-chip trainer) the layer is DROPLESS: the
+    T*k assignments are ordered by expert and go through one grouped
+    matmul each way (ops/grouped_matmul.py), un-ordered, weighted and
+    summed. Every token is served by all k of its experts, whatever its
+    neighbours chose; `capacity_factor` and `moe_group_size` play no part.
+    `token_mask` [B, S] (False = a row or position the caller padded)
+    keeps padding out of every group: it costs no expert work, cannot
+    move a real token's result, gets a zero output and is not counted.
+    Shapes are static (T*k rows; group sizes are data).
 
-    Returns the mixed output; the Switch/GShard load-balancing loss
+    With experts sharded over ep the GShard capacity dispatch below stays:
+    fixed-size groups and a capacity-bounded one-hot dispatch tensor
+    [G, g, E, C], which XLA lowers to all-to-alls over the ep axis, and
+    which DROPS what overflows an expert's capacity.
+
+    Returns the mixed output. Sown, for callers that make the collection
+    mutable: into "losses" the Switch/GShard load-balancing loss
     E * sum_e(frac_tokens_e * frac_probs_e), pre-scaled by
-    router_aux_loss_coef, is sown into the "losses" collection (see the
-    sow call below for the consumer contract).
+    router_aux_loss_coef (see the sow call for the consumer contract);
+    into "routing" `expert_counts`, the [E] int32 count of real assignments
+    per expert (the serving engine's `moe_*` counters).
     """
 
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, token_mask=None, stacked=None):
+        """stacked: None, or (gate_up [L, E, h, 2f], down [L, E, f, h],
+        layer): the whole scanned stack of expert weights and this layer's
+        index in it, for the dropless path's kernel to read in place (see
+        `_stacked_experts`)."""
         cfg = self.config
         E, k = cfg.num_experts, cfg.num_experts_per_tok
         f = cfg.intermediate_size
         b, s, h = x.shape
         T = b * s
-        g = min(cfg.moe_group_size, T)
-        pad = (-T) % g
         xt = x.reshape(T, h)
-        if pad:
-            xt = jnp.pad(xt, ((0, pad), (0, 0)))
-        G = (T + pad) // g
-        xg = xt.reshape(G, g, h)
 
         router = self.param(
             "router", A(nn.initializers.normal(0.02), ("embed", None)),
             (h, E), jnp.float32)
         # routing in fp32 (tiny matmul, numerically load-bearing)
-        logits = jnp.einsum("Gth,he->Gte", xg.astype(jnp.float32), router)
-        probs = jax.nn.softmax(logits, axis=-1)              # [G,g,E]
-        gate, idx = jax.lax.top_k(probs, k)                  # [G,g,k]
+        logits = jnp.einsum("th,he->te", xt.astype(jnp.float32), router)
+        probs = jax.nn.softmax(logits, axis=-1)              # [T,E]
+        gate, idx = jax.lax.top_k(probs, k)                  # [T,k]
         gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
 
+        w_gu = self.param(
+            "experts_gate_up",
+            A(nn.initializers.lecun_normal(), ("expert", "embed", "mlp")),
+            (E, h, 2 * f), cfg.param_dtype)
+        w_dn = self.param(
+            "experts_down",
+            A(nn.initializers.lecun_normal(), ("expert", "mlp", "embed")),
+            (E, f, h), cfg.param_dtype)
+        if _experts_sharded():
+            out = self._capacity_dispatch(xt, gate, idx, w_gu, w_dn)
+        else:
+            layer = None
+            if stacked is not None:
+                w_gu, w_dn, layer = stacked
+            out = self._dropless(xt, gate, idx, w_gu, w_dn, token_mask,
+                                 layer)
+
+        # Switch/GShard load-balancing aux loss
+        onehot = jax.nn.one_hot(idx, E, dtype=jnp.int32)     # [T,k,E]
+        frac_tokens = onehot.sum((0, 1)).astype(jnp.float32) / (T * k)
+        frac_probs = probs.mean(0)
+        aux = E * jnp.sum(frac_tokens * frac_probs)
+        # Sown (not returned) so per-token nll stays pure cross-entropy;
+        # trainers opt in with apply(..., mutable=["losses"]) and add the
+        # (already coefficient-scaled) terms to their loss. sow is a no-op
+        # for callers that don't mutate the collection (e.g. serving).
+        self.sow("losses", "router_aux_scaled",
+                 cfg.router_aux_loss_coef * aux,
+                 reduce_fn=lambda a, b: a + b, init_fn=lambda: 0.0)
+        return out.reshape(b, s, h)
+
+    def _dropless(self, xt, gate, idx, w_gu, w_dn, token_mask, layer):
+        from ..ops.grouped_matmul import grouped_matmul
+
+        cfg = self.config
+        E, k = cfg.num_experts, cfg.num_experts_per_tok
+        T, h = xt.shape
+        M = T * k
+        expert = idx.reshape(M)              # assignment t*k+j: token t
+        if token_mask is not None:
+            # padding joins the trailing group E, which multiplies nothing
+            expert = jnp.where(jnp.repeat(token_mask.reshape(T), k),
+                               expert, E)
+        order = jnp.argsort(expert, stable=True)
+        counts = jnp.zeros((E + 1,), jnp.int32).at[expert].add(1)[:E]
+        self.sow("routing", "expert_counts", counts,
+                 reduce_fn=lambda a, b: a + b,
+                 init_fn=lambda: jnp.zeros((E,), jnp.int32))
+        w_gu, w_dn = w_gu.astype(cfg.dtype), w_dn.astype(cfg.dtype)
+        ends = jnp.cumsum(counts)            # where each expert's rows end
+
+        def experts_on(lo, rows):
+            """The expert FFN on `rows` sorted assignments from `lo` on;
+            rows past the last real assignment come back undefined."""
+            here = jnp.diff(jnp.clip(ends, lo, lo + rows), prepend=lo)
+            at = jax.lax.dynamic_slice(order, (lo,), (rows,))
+            gu = grouped_matmul(xt[at // k], w_gu, here, layer)
+            gate_p, up_p = jnp.split(gu, 2, axis=-1)
+            return grouped_matmul(nn.silu(gate_p) * up_p, w_dn, here, layer)
+
+        # A wave's sorted assignments go through the experts _MOE_ROWS at a
+        # time: the [rows, 2f] intermediate stays small whatever the wave,
+        # and a block that holds padding only (all of a wave's tail, when
+        # 15 of its 16 rows are padding) is skipped, so the elementwise
+        # work between the two matmuls follows the real tokens too.
+        if M <= _MOE_ROWS or M % _MOE_ROWS:
+            y = experts_on(0, M)
+        else:
+            y = jax.lax.map(
+                lambda lo: jax.lax.cond(
+                    lo < ends[-1], lambda: experts_on(lo, _MOE_ROWS),
+                    lambda: jnp.zeros((_MOE_ROWS, h), cfg.dtype)),
+                jnp.arange(0, M, _MOE_ROWS)).reshape(M, h)
+        # back to token order; the k weighted outputs are summed in
+        # float32, in the same order wherever the token sits
+        y = y[jnp.argsort(order)].reshape(T, k, h)
+        out = jnp.einsum("tkh,tk->th", y.astype(jnp.float32),
+                         gate).astype(cfg.dtype)
+        if token_mask is not None:
+            out = jnp.where(token_mask.reshape(T, 1), out, 0)
+        return out
+
+    def _capacity_dispatch(self, xt, gate, idx, w_gu, w_dn):
+        cfg = self.config
+        E, k = cfg.num_experts, cfg.num_experts_per_tok
+        T, h = xt.shape
+        g = min(cfg.moe_group_size, T)
+        pad = (-T) % g
+        G = (T + pad) // g
+
+        def grouped(a):
+            return jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)
+                           ).reshape((G, g) + a.shape[1:])
+
+        xg, gate, idx = grouped(xt), grouped(gate), grouped(idx)
         capacity = max(1, int(cfg.capacity_factor * k * g / E))
         onehot = jax.nn.one_hot(idx, E, dtype=jnp.int32)     # [G,g,k,E]
         assigns = onehot.reshape(G, g * k, E)
@@ -316,44 +422,63 @@ class MoEMLP(nn.Module):
         disp = disp * keep[..., None, None]
         combine = (disp * gate.astype(cfg.dtype)[..., None, None]).sum(2)
         dispatch = disp.sum(2)                               # [G,g,E,C]
-
-        w_gu = self.param(
-            "experts_gate_up",
-            A(nn.initializers.lecun_normal(), ("expert", "embed", "mlp")),
-            (E, h, 2 * f), cfg.param_dtype)
-        w_dn = self.param(
-            "experts_down",
-            A(nn.initializers.lecun_normal(), ("expert", "mlp", "embed")),
-            (E, f, h), cfg.param_dtype)
         ex_in = jnp.einsum("Gtec,Gth->Gech", dispatch, xg)   # [G,E,C,h]
         gu = jnp.einsum("Gech,ehm->Gecm", ex_in, w_gu.astype(cfg.dtype))
         gate_p, up_p = jnp.split(gu, 2, axis=-1)
         y = nn.silu(gate_p) * up_p
         ex_out = jnp.einsum("Gecf,efh->Gech", y, w_dn.astype(cfg.dtype))
         out = jnp.einsum("Gtec,Gech->Gth", combine, ex_out)
-        out = out.reshape(G * g, h)[:T].reshape(b, s, h)
+        return out.reshape(G * g, h)[:T]
 
-        # Switch/GShard load-balancing aux loss over REAL tokens only
-        frac_tokens = onehot.reshape(G * g, k, E)[:T].sum((0, 1)) \
-            .astype(jnp.float32) / (T * k)
-        frac_probs = probs.reshape(G * g, E)[:T].mean(0)
-        aux = E * jnp.sum(frac_tokens * frac_probs)
-        # Sown (not returned) so per-token nll stays pure cross-entropy;
-        # trainers opt in with apply(..., mutable=["losses"]) and add the
-        # (already coefficient-scaled) terms to their loss. sow is a no-op
-        # for callers that don't mutate the collection (e.g. serving).
-        self.sow("losses", "router_aux_scaled",
-                 cfg.router_aux_loss_coef * aux,
-                 reduce_fn=lambda a, b: a + b, init_fn=lambda: 0.0)
-        return out
+
+# sorted assignments per pass through the experts (MoEMLP._dropless): a
+# [4096, 2f] intermediate is 235 MB at Mixtral widths
+_MOE_ROWS = 4096
+
+
+def _stacked_experts(module: nn.Module, cfg: LlamaConfig, kv_caches):
+    """The scanned stack of expert weights, (gate_up [L, E, h, 2f], down
+    [L, E, f, h]), for the paged serving path; None anywhere else.
+
+    Inside the layer scan a layer's weights are a dynamic slice of the
+    stack. XLA fuses that slice into a matmul of its own, but a kernel
+    (the grouped matmul's custom call) needs its operand in memory, so
+    every layer of every step would first COPY its experts (2.8 GB at
+    Mixtral widths). The serving path therefore hands the layers the
+    whole stack, beside the paged pool in the scan's carry, and the kernel
+    picks the layer's experts by index (ops/grouped_matmul.py), as the
+    paged ops do with the pool: on a v5e, 3 Mixtral layers at 18 rows, a
+    decode step takes 12.7 ms so and 37.8 ms sliced (PERF.md section 6, PR
+    29). A program that differentiates (training:
+    no PagedCache) keeps the sliced weights: a carried stack would make
+    every layer's backward add a stack-sized cotangent."""
+    if not (cfg.num_experts and isinstance(kv_caches, PagedCache)
+            and not module.is_initializing() and not _experts_sharded()):
+        return None
+    moe = nn.meta.unbox(module.get_variable("params", "layers"))[
+        "layer"]["moe"]
+    # cast once, outside the scan (nothing where param_dtype is dtype)
+    return (moe["experts_gate_up"].astype(cfg.dtype),
+            moe["experts_down"].astype(cfg.dtype))
+
+
+def _experts_sharded() -> bool:
+    """Whether the ambient mesh (parallel/mesh.py) shards experts."""
+    from ..parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    return mesh is not None and mesh.shape.get("ep", 1) > 1
 
 
 class DecoderLayer(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, positions, segment_ids=None, kv_cache=None):
+    def __call__(self, x, positions, segment_ids=None, kv_cache=None,
+                 token_mask=None, experts=None):
         cfg = self.config
+        if experts is not None:
+            experts += (kv_cache.layer,)
         h, new_cache = Attention(cfg, name="attn")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="attn_norm")(x),
             positions, kv_cache=kv_cache, segment_ids=segment_ids)
@@ -361,7 +486,7 @@ class DecoderLayer(nn.Module):
         x = x + h
         normed = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="mlp_norm")(x)
         if cfg.num_experts:
-            h = MoEMLP(cfg, name="moe")(normed)
+            h = MoEMLP(cfg, name="moe")(normed, token_mask, experts)
         else:
             h = MLP(cfg, name="mlp")(normed)
         h = checkpoint_name(h, "mlp_out")
@@ -372,7 +497,8 @@ class ScannedLayer(nn.Module):
     """One layer body, scanned over a stacked `layers` param axis.
 
     The paged pool rides the CARRY (None when there is no paged cache: a
-    None leaf adds nothing to the program); `kv_cache` is this layer's
+    None leaf adds nothing to the program, and the same goes for the expert
+    layer's `token_mask` and stacked `experts`); `kv_cache` is this layer's
     slice of the scan's xs: a PagedCache without its pool, or a dense
     (k, v) pair, whose grown copy goes out through the ys.
     """
@@ -380,18 +506,19 @@ class ScannedLayer(nn.Module):
 
     @nn.compact
     def __call__(self, carry, kv_cache):
-        x, positions, segment_ids, kv_pages = carry
+        x, positions, segment_ids, kv_pages, token_mask, experts = carry
         if kv_pages is not None:
             kv_cache = kv_cache.replace(kv_pages=kv_pages)
         x, new_cache = DecoderLayer(self.config, name="layer")(
-            x, positions, segment_ids, kv_cache)
+            x, positions, segment_ids, kv_cache, token_mask, experts)
         if kv_pages is not None:
             kv_pages, new_cache = new_cache.kv_pages, None
-        return (x, positions, segment_ids, kv_pages), new_cache
+        return (x, positions, segment_ids, kv_pages, token_mask,
+                experts), new_cache
 
 
 def _apply_layers(cfg: LlamaConfig, length: int, x, positions, segment_ids,
-                  kv_caches):
+                  kv_caches, token_mask=None, experts=None):
     """Run `length` scanned layers named "layers" under the calling
     module; returns (x, new_caches). Shared by LlamaModel, LayerStack and
     StageModel: ONE definition of the scan axes/metadata so every consumer
@@ -407,7 +534,8 @@ def _apply_layers(cfg: LlamaConfig, length: int, x, positions, segment_ids,
                              policy=_remat_policy(cfg.remat_policy))
     layers = nn.scan(
         layer_cls,
-        variable_axes={"params": 0, "losses": 0},
+        variable_axes={"params": 0, "losses": 0, "routing": 0,
+                       "intermediates": 0},
         split_rngs={"params": True},
         length=length,
         metadata_params={nn.PARTITION_NAME: "layers"},
@@ -417,8 +545,8 @@ def _apply_layers(cfg: LlamaConfig, length: int, x, positions, segment_ids,
     if paged:
         kv_pages = kv_caches.kv_pages
         xs = kv_caches.replace(kv_pages=None, layer=jnp.arange(length))
-    (x, _, _, kv_pages), ys = layers(
-        (x, positions, segment_ids, kv_pages), xs)
+    (x, _, _, kv_pages, _, _), ys = layers(
+        (x, positions, segment_ids, kv_pages, token_mask, experts), xs)
     return x, kv_caches.replace(kv_pages=kv_pages) if paged else ys
 
 
@@ -493,8 +621,11 @@ class LlamaModel(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, positions=None, segment_ids=None,
-                 kv_caches=None, targets=None):
+                 kv_caches=None, targets=None, token_mask=None):
         """Forward pass.
+
+        token_mask: [B, S] bool, False where the caller padded a row or a
+        position; only the expert layer reads it (MoEMLP).
 
         kv_caches: None (training / full prefill), a PagedCache (serving:
         one [L, P, ...] pool, updated in place), or a (k, v) pair stacked
@@ -514,8 +645,9 @@ class LlamaModel(nn.Module):
         x = embed[input_ids].astype(cfg.dtype)
 
         if cfg.scan_layers:
-            x, new_caches = _apply_layers(cfg, cfg.num_layers, x, positions,
-                                          segment_ids, kv_caches)
+            x, new_caches = _apply_layers(
+                cfg, cfg.num_layers, x, positions, segment_ids, kv_caches,
+                token_mask, _stacked_experts(self, cfg, kv_caches))
         else:
             layer_cls = DecoderLayer
             if cfg.remat:
@@ -525,7 +657,7 @@ class LlamaModel(nn.Module):
             for i in range(cfg.num_layers):
                 cache_i = kv_caches[i] if kv_caches is not None else None
                 x, new_cache = layer_cls(cfg, name=f"layer_{i}")(
-                    x, positions, segment_ids, cache_i)
+                    x, positions, segment_ids, cache_i, token_mask)
                 if kv_caches is not None:
                     new_caches.append(new_cache)
 

@@ -310,6 +310,11 @@ class LLMEngine:
     (which only touch the locked intake queue); one driver thread calls
     `step()`."""
 
+    # (layers, experts) of an expert model whose programs return routing
+    # counts packed behind their tokens; None for a dense model (and for
+    # the pipelined engine, whose stages return tokens only)
+    _moe_LE: Optional[tuple] = None
+
     def __init__(self, config: EngineConfig, params=None, mesh=None):
         self.config = config
         self._build_compute(params, mesh)
@@ -342,6 +347,9 @@ class LLMEngine:
             param_dtype=dtype, max_seq_len=config.max_model_len,
             **config.model_overrides)
         self.model = LlamaModel(self.model_cfg)
+        if self.model_cfg.num_experts:
+            self._moe_LE = (self.model_cfg.num_layers,
+                            self.model_cfg.num_experts)
         # tensor parallelism: resolve mesh/tp BEFORE any compute so the
         # divisibility contract fails at construction, not first dispatch
         self.sharding = resolve_serve_mesh(mesh, tp=config.tp)
@@ -462,6 +470,9 @@ class LLMEngine:
             "decode_dispatches_total", "prefill_tokens_total",
             "prefill_padded_tokens_total", "decode_rows_total",
             "decode_ctx_tokens_total", "programs_built_total"), 0)
+        if self._moe_LE:
+            self._totals.update(moe_assignments_total=0,
+                                moe_experts_touched_total=0)
         self._queue_wait_ns_total = 0
 
     # ----------------------------------------------------------- intake
@@ -772,6 +783,34 @@ class LLMEngine:
         # Pallas kernels cannot run: pin the reference attention paths
         # via the cache's STATIC field (part of each jit's cache key)
         ref_attn = self.sharding is not None
+        moe = self._moe_LE is not None
+
+        def apply(params, ids, positions, pc, total_lens):
+            """model.apply -> (logits, cache, routing counts). An expert
+            model is told which rows and positions are real (the padding
+            of a wave, idle decode slots: exactly what `paged_write`
+            drops) and hands back its [L, E] int32 count of real
+            assignments per expert; a dense model's call is what it was."""
+            if not moe:
+                logits, new_pc = model.apply(
+                    {"params": params}, ids, positions=positions,
+                    kv_caches=pc)
+                return logits, new_pc, None
+            (logits, new_pc), sown = model.apply(
+                {"params": params}, ids, positions=positions, kv_caches=pc,
+                token_mask=positions < total_lens[:, None],
+                mutable=["routing"])
+            return logits, new_pc, sown["routing"]["layers"]["layer"][
+                "moe"]["expert_counts"]
+
+        def pack(tokens, counts):
+            """The program's host-bound result: the tokens, and for an
+            expert model the counts behind them in ONE int32 array, so
+            the harvest's single fetch brings both (`_split_counts`)."""
+            if counts is None:
+                return tokens
+            return jnp.concatenate([tokens.reshape(-1).astype(jnp.int32),
+                                    counts.reshape(-1)])
 
         if kind == "prefill":
             # ctx_pages buckets to {0, full}: a fresh-prompt wave (the
@@ -790,15 +829,14 @@ class LLMEngine:
                     total_lens=jnp.broadcast_to(total_lens,
                                                 (L,) + total_lens.shape),
                     ctx_pages=cp, ref_attention=ref_attn)
-                logits, new_pc = model.apply({"params": params}, input_ids,
-                                             positions=positions,
-                                             kv_caches=pc)
+                logits, new_pc, counts = apply(params, input_ids, positions,
+                                               pc, total_lens)
                 # sample ON DEVICE: only B int32 tokens cross to the host
                 # per step, never the [B, V] fp32 logits
                 b = logits.shape[0]
                 rows = logits[jnp.arange(b), gather_idx].astype(jnp.float32)
                 tokens = _device_sample(rows, temperature, top_k, rng_keys)
-                return tokens, new_pc.kv_pages
+                return pack(tokens, counts), new_pc.kv_pages
 
             if self.sharding is not None:
                 # explicit shardings: params + pages by their specs,
@@ -833,12 +871,10 @@ class LLMEngine:
                     total_lens=jnp.broadcast_to(
                         total_lens, (L,) + total_lens.shape),
                     ctx_pages=mp, ref_attention=ref_attn)
-                logits, new_pc = model.apply({"params": params},
-                                             input_ids,
-                                             positions=positions,
-                                             kv_caches=pc)
+                logits, new_pc, counts = apply(params, input_ids, positions,
+                                               pc, total_lens)
                 toks = jnp.argmax(logits.astype(jnp.float32), axis=-1)
-                return toks.astype(jnp.int32), new_pc.kv_pages
+                return pack(toks.astype(jnp.int32), counts), new_pc.kv_pages
 
             if self.sharding is not None:
                 repl = self._repl_sharding
@@ -870,9 +906,7 @@ class LLMEngine:
                     kv_pages=kvp, block_tables=bt_b,
                     total_lens=jnp.broadcast_to(tot, (L,) + tot.shape),
                     ref_attention=ref_attn)
-                logits, new_pc = model.apply(
-                    {"params": params}, ids, positions=pos,
-                    kv_caches=pc)
+                logits, new_pc, counts = apply(params, ids, pos, pc, tot)
                 rows = logits[:, 0].astype(jnp.float32)
                 toks = _device_sample(rows, temperature, top_k, keys_k)
                 # caps clamp: past a slot's ceiling, positions freeze at
@@ -888,16 +922,16 @@ class LLMEngine:
                 new_pos = jnp.minimum(pos + 1, caps[:, None] - 1)
                 return ((toks[:, None].astype(jnp.int32), new_pos,
                          new_pc.kv_pages, new_tot),
-                        toks)
+                        (toks, counts))
 
             carry = (ids0, positions, kv_pages, total_lens)
-            (last_ids, _, kvp, _), toks = jax.lax.scan(
+            (last_ids, _, kvp, _), (toks, counts) = jax.lax.scan(
                 body, carry, keys_steps, length=n_steps)
             # carry the last sampled token forward for ACTIVE slots only:
             # dead rows keep their (irrelevant) values instead of being
             # scribbled with garbage samples
             new_slot_ids = jnp.where(active[:, None], last_ids, slot_ids)
-            return toks, new_slot_ids, kvp
+            return pack(toks, counts), new_slot_ids, kvp
 
         if self.sharding is not None:
             repl = self._repl_sharding
@@ -1388,6 +1422,7 @@ class LLMEngine:
         with tracing.region("rtpu.engine.fetch") as fetch:
             toks_np = self._fetch_tokens(rec["toks"])
         with tracing.region("rtpu.engine.harvest") as r:
+            toks_np, moe_facts = self._split_counts(rec, toks_np)
             if rec["kind"] == "prefill":
                 for i, (rid, slot, end, final) in enumerate(rec["group"]):
                     req = self.requests.get(rid)
@@ -1457,7 +1492,24 @@ class LLMEngine:
             rec["seq"], rec["kind"], rec["step"], self._step_seq,
             rec["dispatch_ns"], fetch.start_ns, fetch.end_ns,
             rec["rows_padded"], rec["tokens_padded"], rec["facts"],
-            rec["k"]))
+            rec["k"]) + moe_facts)
+
+    def _split_counts(self, rec: dict, fetched: np.ndarray) -> tuple:
+        """(tokens, the record's `moe_*` fields). An expert model's
+        program returns its tokens and, behind them, the [steps, L, E]
+        count of real assignments per expert (`_jit`: pack); a dense
+        model's returns the tokens and the record gains nothing."""
+        if self._moe_LE is None:
+            return fetched, ()
+        rows = rec["rows_padded"]
+        n = rec["k"] * self._moe_LE[0] * self._moe_LE[1]
+        counts = fetched[-n:].reshape((-1,) + self._moe_LE)
+        tokens = fetched[:-n].reshape({"prefill": (rows,), "spec": (rows, -1),
+                                       "decode": (-1, rows)}[rec["kind"]])
+        assignments, touched = int(counts.sum()), int((counts > 0).sum())
+        self._totals["moe_assignments_total"] += assignments
+        self._totals["moe_experts_touched_total"] += touched
+        return tokens, (assignments, touched, int(counts.max()))
 
     def _preempt(self, req: Request) -> None:
         """Return a running request to the waiting queue, dropping its

@@ -82,6 +82,10 @@ _LLM_WORK_TOTALS = {
     "queue_wait_s_total":
         "seconds finished requests waited for their first prefill dispatch",
     "programs_built_total": "programs the engine built (jit cache misses)",
+    "moe_assignments_total":
+        "real (token, expert) assignments of an expert model, all layers",
+    "moe_experts_touched_total":
+        "experts with at least one real token, summed over layers and steps",
 }
 
 

@@ -142,11 +142,16 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         "first_token_ns", "finish_ns", "prompt_tokens", "cached_tokens",
         "output_tokens", "preemptions", "finish_reason"),
     # one per program enqueued, written when its tokens are harvested;
-    # `rows` is a tuple of (request_id, q_tokens, ctx_tokens) per real row
+    # `rows` is a tuple of (request_id, q_tokens, ctx_tokens) per real row.
+    # The three `moe_*` fields are written for an expert model only (a
+    # dense model's record ends at `k`): real assignments (real tokens x
+    # experts per token x layers), experts with at least one real token
+    # summed over layers and fused steps, the fullest expert's count
     "engine.dispatch": (
         "seq", "kind", "step_dispatched", "step_harvested", "dispatch_ns",
         "fetch_start_ns", "fetch_end_ns", "rows_padded", "tokens_padded",
-        "rows", "k"),
+        "rows", "k", "moe_assignments", "moe_experts_touched",
+        "moe_expert_tokens_max"),
     # one per LLMEngine.step()
     "engine.step": (
         "seq", "start_ns", "end_ns", "intake_ns", "admit_ns",

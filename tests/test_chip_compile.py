@@ -154,11 +154,21 @@ def test_kernel_compiles_for_v5e(topo, no_persistent_cache, name):
 # prefetched whole, which reads as a copy. "long": the docbatch cell's
 # configuration at its full 16 layers, where the flash kernel's resident
 # K, V and segment ids are largest (kv 8320).
+# "mixtral": the `mixtral-chat` cell's configuration, Mixtral-8x7B widths
+# (8 experts of width 14336, top-2) at its 3 layers, 2800 pages and 32
+# slots: the `[32 x 1]` decode program and the `[16 x 2048]` wave.
 HKV, PAGE, HEAD_DIM = 8, 16, 128
+MISTRAL = dict(num_heads=32, num_kv_heads=HKV, head_dim=HEAD_DIM,
+               hidden_size=4096, intermediate_size=14336, vocab_size=32768,
+               rope_theta=1e6)
 ENGINES = {
     "short": dict(layers=2, pages=2200, max_model_len=2688, buckets=(8, 128)),
     "long": dict(layers=16, pages=1900, max_model_len=8320, buckets=(4096,)),
+    "mixtral": dict(layers=3, pages=2800, max_model_len=2688,
+                    buckets=(128, 2048), max_batch=32, model="mixtral-8x7b",
+                    widths={}),
 }
+HBM_GIB = 15.75     # what a program may use of a v5e's 16 GB
 MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice",
          "slice", "transpose", "concatenate")
 # program -> (engine, kind, shape key given (wave rows, pages per sequence))
@@ -170,6 +180,9 @@ ENGINE_PROGRAMS = {
     "verify-unaligned-span8": ("short", "verify", lambda rb, mp: (8, rb)),
     "prefill-bucket4096-prefix-hit-kv8320":
         ("long", "prefill", lambda rb, mp: (4096, rb, mp)),
+    "mixtral-decode-32x1": ("mixtral", "decode", lambda rb, mp: (1, mp)),
+    "mixtral-prefill-16x2048":
+        ("mixtral", "prefill", lambda rb, mp: (2048, rb, 0)),
 }
 
 
@@ -183,15 +196,13 @@ def engines():
 
     from ray_tpu.serve.llm import EngineConfig, LLMEngine
 
-    def build(layers, pages, max_model_len, buckets):
+    def build(layers, pages, max_model_len, buckets, max_batch=8,
+              model="llama3-8b", widths=MISTRAL):
         eng = LLMEngine(EngineConfig(
-            model="llama3-8b", dtype="bfloat16", page_size=PAGE,
-            num_pages=2, max_model_len=max_model_len, max_batch=8,
+            model=model, dtype="bfloat16", page_size=PAGE,
+            num_pages=2, max_model_len=max_model_len, max_batch=max_batch,
             prefill_buckets=buckets,
-            model_overrides=dict(
-                num_layers=layers, hidden_size=4096, num_heads=32,
-                intermediate_size=14336, num_kv_heads=HKV,
-                head_dim=HEAD_DIM, vocab_size=32768, rope_theta=1e6)),
+            model_overrides=dict(num_layers=layers, **widths)),
             params={})
         eng.params = jax.eval_shape(lambda: nn.meta.unbox(eng.model.init(
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
@@ -226,6 +237,24 @@ def _array_types(type_text):
                                         type_text)]
 
 
+def _check_expert_program(cfg, tokens, compiled, text):
+    """An expert model's program fits the chip, runs the grouped matmul
+    kernel, holds no capacity dispatch tensor and copies no layer's stack
+    of expert weights (a slice handed to a kernel would be one)."""
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total <= HBM_GIB * 2 ** 30, total / 2 ** 30
+    assert re.search(r"%_moe_gmm\.\d+ = [^\n]*tpu_custom_call", text)
+    E, k = cfg.num_experts, cfg.num_experts_per_tok
+    group = min(cfg.moe_group_size, tokens)
+    capacity = int(cfg.capacity_factor * k * group / E)
+    h, f = cfg.hidden_size, cfg.intermediate_size
+    for dims, _ in _array_types(text):
+        assert dims[-2:] != (E, capacity), dims      # [G, g, E, C] one-hot
+        assert dims not in ((E, h, 2 * f), (E, f, h)), dims
+
+
 @pytest.mark.parametrize("name", list(ENGINE_PROGRAMS))
 def test_engine_program_keeps_the_pool_in_place(
         topo, no_persistent_cache, engines, monkeypatch, name):
@@ -250,10 +279,14 @@ def test_engine_program_keeps_the_pool_in_place(
                  PAGE, 2 * HEAD_DIM)
     layer_bytes = 2 * int(np.prod(pool_dims[1:]))
     pool_bytes = (layer_bytes, pool_dims[0] * layer_bytes)  # in any shape
-    text = engine._jit(kind, shape_key).lower(
+    compiled = engine._jit(kind, shape_key).lower(
         jax.tree.map(lambda a: sds(a.shape, a.dtype), engine.params),
         sds(pool_dims, BF16),
-        *_program_args(kind, shape_key, rows, mp, sds)).compile().as_text()
+        *_program_args(kind, shape_key, rows, mp, sds)).compile()
+    text = compiled.as_text()
+    if engine.model_cfg.num_experts:
+        _check_expert_program(engine.model_cfg, rows * shape_key[0]
+                              if kind == "prefill" else rows, compiled, text)
 
     assert "tpu_custom_call" in text
     moved, layouts, pool_param = [], set(), None

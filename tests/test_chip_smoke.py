@@ -61,7 +61,8 @@ TINY_SERVE = chip_smoke.ServeSizes(
     page_size=8, num_pages=64, max_model_len=256, max_batch=4,
     prefill_buckets=(64, 128), decode_steps_per_dispatch=2,
     pipeline_depth=2, max_tokens=6, ready_timeout_s=120,
-    chats=("ab", "a much longer question " * 3, "ab c"))
+    chats=("ab", "a much longer question " * 3, "ab c"),
+    expert_overrides=(("vocab_size", 512),))
 TINY_TRAIN = chip_smoke.TrainSizes(
     model="tiny", model_overrides=(("attention_impl", "flash"),),
     batch=4, seq=128, steps=3, lr=1e-2)
@@ -98,6 +99,7 @@ def test_rehearse_one_chip_phases_on_cpu():
     assert not serve["driver_touched_backend"]
     engine = _rehearse("engine", prior={"serve": serve})
     assert engine["completions_match_http"] and engine["logits"]["finite"]
+    assert engine["experts"]["moe_assignments_total"] > 0
     train = _rehearse("train")
     assert train["loss"][-1] < train["loss"][0]
 

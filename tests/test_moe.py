@@ -1,9 +1,14 @@
 """Mixture-of-experts model family + expert parallelism.
 
 The reference ships no in-repo MoE/EP implementation (SURVEY.md §2.4: EP is
-"delegated to engines"), so this is greenfield TPU-native surface: Mixtral-
-style sparse FFN with capacity-based grouped einsum dispatch, expert weights
-sharded over the mesh's ep axis.
+"delegated to engines"), so this is greenfield TPU-native surface: Mixtral's
+sparse FFN, dropless (sorted assignments through a grouped matmul) wherever
+experts are not sharded, and the capacity-based grouped einsum dispatch
+with expert weights sharded over the mesh's ep axis.
+
+The serving tests compare the program in float32 with the plain reference
+`chipbench/references/moe_decoder.py` (the benchmark's own file: one
+source, no drift) on seeded random weights at tiny size.
 """
 
 import flax.linen as nn
@@ -77,15 +82,25 @@ def test_moe_aux_loss_sown_not_folded(tiny_moe):
 
 
 def test_moe_capacity_drops_are_finite(tiny_moe):
-    """With a starved capacity factor most tokens overflow and are
+    """The capacity dispatch, which runs where experts are sharded over
+    ep: with a starved capacity factor most tokens overflow and are
     dropped (identity residual passes them through) — output must stay
-    finite, not NaN."""
+    finite, not NaN, and it differs from the dropless layer's, which the
+    same model computes without an ep axis."""
     cfg, _, params, ids = tiny_moe
     import dataclasses
 
-    tight = dataclasses.replace(cfg, capacity_factor=0.1)
-    logits = LlamaModel(tight).apply({"params": params}, ids)
-    assert np.isfinite(np.asarray(logits, np.float32)).all()
+    from ray_tpu.parallel.mesh import MeshConfig, active_mesh, create_mesh
+
+    tight = LlamaModel(dataclasses.replace(cfg, capacity_factor=0.1))
+    mesh = create_mesh(MeshConfig(dp=1, fsdp=1, sp=1, ep=2, tp=1),
+                       devices=jax.devices("cpu")[:2])
+    with active_mesh(mesh):
+        dropped = np.asarray(tight.apply({"params": params}, ids),
+                             np.float32)
+    assert np.isfinite(dropped).all()
+    dropless = np.asarray(tight.apply({"params": params}, ids), np.float32)
+    assert np.abs(dropped - dropless).max() > 1e-3
 
 
 @pytest.mark.slow
@@ -136,3 +151,338 @@ def test_moe_paged_decode_in_engine(shared_cluster):
         for delta in engine.step():
             got.extend(delta.new_token_ids)
     assert len(got) == 4
+
+
+# ------------------------------------------- the dropless serving path
+# float32 program against the float32 reference. CPU matmuls are exact
+# float32, so what is left is the order of summation: readings are 2e-6
+# on logits whose rms is 1 (largest 3.6). The tolerance is ten times that
+# and 500 times under what bfloat16 matmuls give (1e-2).
+TOL = 2e-5
+
+
+def _published(cfg):
+    return dict(num_attention_heads=cfg.num_heads,
+                num_key_value_heads=cfg.num_kv_heads,
+                hidden_size=cfg.hidden_size, head_dim=cfg.head_dim_,
+                intermediate_size=cfg.intermediate_size,
+                num_experts_per_tok=cfg.num_experts_per_tok,
+                rms_norm_eps=cfg.rms_norm_eps, rope_theta=cfg.rope_theta)
+
+
+@pytest.fixture(scope="module")
+def moe_f32():
+    """tiny-moe in float32 with its parameters, and the reference's view
+    of the same arrays."""
+    from chipbench.references import moe_decoder
+
+    cfg = get_config("tiny-moe", dtype=jnp.float32)
+    model = LlamaModel(cfg)
+    params = nn.meta.unbox(model.init(
+        jax.random.PRNGKey(3), jnp.zeros((1, 8), jnp.int32))["params"])
+    return (cfg, model, params, moe_decoder,
+            moe_decoder.weights_from_program_tree(params), _published(cfg))
+
+
+@pytest.mark.parametrize("shape", [(3, 48), (32, 128)])
+def test_moe_forward_matches_reference(moe_f32, shape):
+    """(a) LlamaModel's full forward against moe_decoder.forward; the
+    larger batch is 8192 assignments, which pass the experts in two
+    blocks with an expert's group across the boundary."""
+    cfg, model, params, ref, weights, pub = moe_f32
+    ids = jnp.asarray(np.random.default_rng(5).integers(
+        0, cfg.vocab_size, shape, dtype=np.int32))
+    want = np.asarray(ref.forward(weights, ids, pub))
+    got = np.asarray(model.apply({"params": params}, ids))
+    assert np.sqrt((want ** 2).mean()) > 0.5      # not a comparison of zeros
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+def _tiny_engine(**over):
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    cfg = dict(model="tiny-moe", dtype="float32", page_size=8, num_pages=64,
+               max_model_len=128, max_batch=4,
+               prefill_buckets=(16, 32, 64, 128), seed=3)
+    cfg.update(over)
+    return LLMEngine(EngineConfig(**cfg))
+
+
+def test_moe_paged_prefill_and_decode_match_reference():
+    """(b) Prefill, then decode token by token through a PagedCache (the
+    benchmark's own routine, which pads the prompts to one width and hands
+    the model no mask), against the reference's full forward at every
+    position."""
+    from chipbench.references import moe_decoder as ref
+    from chipbench.runners.engine import _paged_logits
+
+    engine = _tiny_engine()
+    pub = _published(engine.model_cfg)
+    weights = ref.weights_from_program_tree(engine.params)
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (5, 19, 40)]
+    logits, fed = _paged_logits(engine, prompts, 6)
+    for prompt, toks, got in zip(prompts, fed, logits):
+        seq = prompt + toks[:-1]
+        want = np.asarray(ref.forward(weights, jnp.asarray([seq]), pub))[0]
+        np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+def test_moe_paged_path_casts_the_expert_stack_once_whatever_its_type():
+    """The paged path reads the scanned stack of experts in place
+    (`_stacked_experts`) also where the parameters are kept in another
+    type than the activations: bfloat16 parameters under float32
+    activations (an exact cast) equal the reference on the same values."""
+    import types
+
+    from chipbench.references import moe_decoder as ref
+    from chipbench.runners.engine import _paged_logits
+
+    cfg = get_config("tiny-moe", dtype=jnp.float32, param_dtype=jnp.bfloat16)
+    model = LlamaModel(cfg)
+    params = nn.meta.unbox(model.init(
+        jax.random.PRNGKey(4), jnp.zeros((1, 8), jnp.int32))["params"])
+    engine = types.SimpleNamespace(
+        model=model, params=params, model_cfg=cfg,
+        config=types.SimpleNamespace(page_size=8),
+        kv_pages=jnp.zeros((1,), jnp.float32))
+    prompts = [np.random.default_rng(7).integers(0, 256, 21).tolist()]
+    logits, fed = _paged_logits(engine, prompts, 4)
+    want = np.asarray(ref.forward(
+        ref.weights_from_program_tree(params),
+        jnp.asarray([prompts[0] + fed[0][:-1]]), _published(cfg)))[0]
+    np.testing.assert_allclose(logits[0], want, atol=TOL, rtol=0)
+
+
+@pytest.mark.parametrize("capacity_factor", [0.1, 1.25])
+@pytest.mark.parametrize("slot", [0, 15, 31])
+def test_moe_row_is_independent_of_slot_and_padding(moe_f32, slot,
+                                                   capacity_factor):
+    """(c) A wave of 32 rows through a PagedCache, one real row of 11
+    tokens in `slot` and token 0 everywhere else (31 padded rows, 117
+    padded positions; the second block of its 8192 assignments is padding
+    only and is skipped), masked as the engine masks it: the real row's logits are
+    those of the row alone, unpadded. The capacity dispatch this replaces
+    let identical padding tokens fill their two experts' capacity, so a
+    row in a later slot lost its experts (at capacity_factor 0.1 nearly
+    all of them)."""
+    import dataclasses
+
+    from ray_tpu.models.llama import PagedCache
+
+    cfg, _, params, _, _, _ = moe_f32
+    cfg = dataclasses.replace(cfg, capacity_factor=capacity_factor)
+    model = LlamaModel(cfg)
+    page, n, width, rows = 8, 11, 128, 32
+    row = np.random.default_rng(7).integers(1, cfg.vocab_size, n)
+
+    def run(ids, lens, mask):
+        b, s = ids.shape
+        mp = -(-s // page)
+        pc = PagedCache(
+            kv_pages=jnp.zeros((cfg.num_layers, 1 + b * mp, cfg.num_kv_heads,
+                                page, 2 * cfg.head_dim_), jnp.float32),
+            block_tables=jnp.broadcast_to(
+                jnp.arange(1, 1 + b * mp).reshape(b, mp),
+                (cfg.num_layers, b, mp)),
+            total_lens=jnp.broadcast_to(lens, (cfg.num_layers, b)))
+        positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+        logits, _ = model.apply(
+            {"params": params}, jnp.asarray(ids), positions=positions,
+            kv_caches=pc,
+            token_mask=None if mask is None else positions < lens[:, None])
+        return np.asarray(logits)
+
+    alone = run(row[None], jnp.asarray([n]), None)[0]
+    ids = np.zeros((rows, width), np.int32)
+    ids[slot, :n] = row
+    lens = jnp.zeros((rows,), jnp.int32).at[slot].set(n)
+    padded = run(ids, lens, True)[slot, :n]
+    np.testing.assert_allclose(padded, alone, atol=TOL, rtol=0)
+
+
+@pytest.fixture(scope="module")
+def moe_engine_run():
+    """Every way the engine's programs touch the pool and the experts, in
+    one run (the scenario of test_llm_serve's dense twin): fresh prefill,
+    32 decode steps, a preemption and its re-prefill, a prefix-cache hit."""
+    from ray_tpu.serve.llm.engine import SamplingParams
+    from ray_tpu.util import tracing
+
+    engine = _tiny_engine(num_pages=12, max_model_len=64, max_batch=2,
+                          prefill_buckets=(16, 32, 64))
+    rng = np.random.default_rng(4)
+    prompts = {f"p{i}": rng.integers(0, 256, 17).tolist() for i in range(2)}
+    prompts["hit"] = prompts["p0"][:16] + rng.integers(0, 256, 5).tolist()
+    n = 32
+    out = {rid: [] for rid in prompts}
+    seen = tracing.appended("engine.dispatch")
+
+    def drain(rids):
+        for rid in rids:
+            engine.add_request(rid, prompts[rid],
+                               SamplingParams(max_tokens=n))
+        for _ in range(900):
+            if not engine.has_work():
+                break
+            for delta in engine.step():
+                out[delta.request_id].extend(delta.new_token_ids)
+
+    drain(["p0", "p1"])
+    preempted = engine.stats()["preempted_total"]
+    hits = engine.allocator.stats["cache_hits"]
+    drain(["hit"])
+    fields = tracing.FIELDS["engine.dispatch"]
+    records = [dict(zip(fields, r))
+               for r in tracing.records("engine.dispatch", seen)]
+    return dict(engine=engine, prompts=prompts, out=out, n=n,
+                preempted=preempted,
+                prefix_hit=engine.allocator.stats["cache_hits"] > hits,
+                records=records)
+
+
+def test_moe_engine_matches_reference_greedy_through_prefix_hit_and_preemption(
+        moe_engine_run):
+    """(d) The engine's greedy tokens through add_request/step() are the
+    reference's argmax, token for token, on each request's sequence."""
+    from chipbench.references import moe_decoder as ref
+
+    run = moe_engine_run
+    assert run["preempted"] >= 1 and run["prefix_hit"]
+    engine, n = run["engine"], run["n"]
+    pub = _published(engine.model_cfg)
+    weights = ref.weights_from_program_tree(engine.params)
+    seqs = [list(p) for p in run["prompts"].values()]
+    width = -(-(max(map(len, seqs)) + n) // 16) * 16
+    rows_fn = jax.jit(lambda ids, rows: ref.forward_rows(
+        weights, ids, rows, pub))
+    for _ in range(n):
+        ids = np.zeros((len(seqs), width), np.int32)
+        for i, seq in enumerate(seqs):
+            ids[i, :len(seq)] = seq
+        last = np.asarray([[len(seq) - 1] for seq in seqs], np.int32)
+        logits = np.asarray(rows_fn(jnp.asarray(ids), jnp.asarray(last)))
+        for i, seq in enumerate(seqs):
+            seq.append(int(logits[i, 0].argmax()))
+    for (rid, prompt), seq in zip(run["prompts"].items(), seqs):
+        assert run["out"][rid] == seq[len(prompt):], rid
+
+
+def test_moe_dispatch_records_count_real_assignments(moe_engine_run):
+    """(e) Every `engine.dispatch` record of that run: `moe_assignments` is
+    real tokens x k x L, `moe_experts_touched` is what the REFERENCE's
+    router keeps for those tokens (per fused step and layer, the number of
+    distinct experts over the program's real tokens), and the fullest
+    expert's count lies between the mean and all of a layer's tokens."""
+    from chipbench.references import moe_decoder as ref
+
+    run = moe_engine_run
+    engine = run["engine"]
+    cfg = engine.model_cfg
+    k, L = cfg.num_experts_per_tok, cfg.num_layers
+    weights = ref.weights_from_program_tree(engine.params)
+    chosen = {}     # request -> [L, S, k]: the reference's experts
+    for rid, prompt in run["prompts"].items():
+        seq = prompt + run["out"][rid]
+        chosen[rid] = np.asarray(ref.routing(
+            weights, jnp.asarray([seq]), _published(cfg)))[0]
+    kinds = set()
+    for rec in run["records"]:
+        kinds.add(rec["kind"])
+        tokens = sum(q for _, q, _ in rec["rows"])
+        assert rec["moe_assignments"] == tokens * k * L, rec
+        steps = rec["k"] if rec["kind"] == "decode" else 1
+        touched = 0
+        for j in range(steps):
+            for layer in range(L):
+                experts = set()
+                for rid, q, ctx in rec["rows"]:
+                    at = (slice(ctx - 1 + j, ctx + j)
+                          if rec["kind"] == "decode"
+                          else slice(ctx - q, ctx))
+                    experts.update(chosen[rid][layer, at].ravel().tolist())
+                touched += len(experts)
+        assert rec["moe_experts_touched"] == touched, rec
+        assert (tokens * k / cfg.num_experts / steps
+                <= rec["moe_expert_tokens_max"] <= tokens / steps), rec
+    assert kinds == {"prefill", "decode"}
+    stats = engine.stats()
+    assert stats["moe_assignments_total"] == sum(
+        r["moe_assignments"] for r in run["records"])
+    assert stats["moe_experts_touched_total"] == sum(
+        r["moe_experts_touched"] for r in run["records"])
+    # and both totals reach /metrics as rtpu_llm_<key>
+    from ray_tpu.serve.llm.server import EngineDriverMixin
+    from ray_tpu.util import metrics
+
+    driver = EngineDriverMixin()
+    driver.engine = engine
+    driver._init_driver()
+    before = metrics.snapshot("rtpu_llm_")
+    driver._publish_llm_metrics(stats)
+    after = metrics.snapshot("rtpu_llm_")
+    for key in ("moe_assignments_total", "moe_experts_touched_total"):
+        name = f"rtpu_llm_{key}"
+        assert after[name] - before.get(name, 0) == stats[key], name
+
+
+def test_dense_engine_records_carry_no_moe_fields():
+    """A dense model's programs, records and stats() are what they were."""
+    from ray_tpu.serve.llm.engine import (EngineConfig, LLMEngine,
+                                          SamplingParams)
+    from ray_tpu.util import tracing
+
+    engine = LLMEngine(EngineConfig(
+        model="tiny", dtype="float32", page_size=8, num_pages=32,
+        max_model_len=64, max_batch=2, prefill_buckets=(16,)))
+    seen = tracing.appended("engine.dispatch")
+    engine.add_request("d", [1, 2, 3, 4, 5], SamplingParams(max_tokens=3))
+    while engine.has_work():
+        engine.step()
+    fields = tracing.FIELDS["engine.dispatch"]
+    records = tracing.records("engine.dispatch", seen)
+    assert records and all(
+        len(r) == fields.index("moe_assignments") for r in records)
+    assert not any(key.startswith("moe_") for key in engine.stats())
+
+
+def test_grouped_matmul_kernel_matches_plain_path_forward_and_backward(
+        monkeypatch):
+    """The Pallas grouped matmul (interpret mode here; the compiled kernel
+    on a TPU) against `jax.lax.ragged_dot`, which the CPU tests above run:
+    uneven groups, an empty one and a tail of rows in no group (undefined
+    out of the kernel, with a zero gradient); a whole [L, E, K, N] stack
+    read by layer; and the gradients the one-chip trainer takes through
+    the kernel's own backward (gmm and tgmm)."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    rng = np.random.default_rng(8)
+    m, kdim, n, experts = 300, 256, 384, 4
+    lhs = jnp.asarray(rng.normal(size=(m, kdim)), jnp.float32)
+    stack = jnp.asarray(rng.normal(size=(2, experts, kdim, n)), jnp.float32)
+    sizes = jnp.asarray([70, 0, 130, 50], jnp.int32)
+    real = int(sizes.sum())                                 # 50 rows over
+
+    def loss(lhs, rhs, layer, impl):
+        monkeypatch.setattr(gm, "_impl", lambda: impl)
+        out = gm.grouped_matmul(lhs, rhs, sizes, layer)[:real]
+        return (out ** 2).sum(), out
+
+    with jax.default_matmul_precision("highest"):
+        (_, plain), g_plain = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(lhs, stack[1], None,
+                                                "ragged_dot")
+        for rhs, layer in ((stack[1], None), (stack, jnp.int32(1))):
+            (_, out), grads = jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True)(
+                    lhs, rhs, layer, "megablox_interpret")
+            np.testing.assert_allclose(out, plain, atol=1e-4, rtol=0)
+            assert not np.asarray(grads[0][real:]).any()
+            d_rhs = grads[1] if layer is None else grads[1][1]
+            # gradients reach 1e3 here: float32's summation order
+            np.testing.assert_allclose(grads[0], g_plain[0], rtol=1e-5,
+                                       atol=1e-3)
+            np.testing.assert_allclose(d_rhs, g_plain[1], rtol=1e-5,
+                                       atol=1e-3)
+            if layer is not None:       # the other layer's experts: untouched
+                assert not np.asarray(grads[1][0]).any()
