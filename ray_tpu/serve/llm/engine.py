@@ -38,12 +38,22 @@ on the device without host involvement. Three design rules follow:
 3. results are pushed host-ward with `copy_to_host_async()` at dispatch
    time and harvested FIFO behind a `pipeline_depth`-deep window — the
    blocking `np.asarray` then completes quickly once landed.
-Prefill runs in waves of `prefill_wave_size` rows (one compiled row
-count per length bucket): the waves pipeline on-device, so a burst's
-total prefill compute is unchanged but the first wave's tokens surface
-after only its own share of it. `decode_steps_per_dispatch`,
-`pipeline_depth` and `prefill_wave_size` keep the values an earlier
-deployment chose; ROADMAP C3 re-measures them on the attached chip.
+Prefill runs in waves of at most `prefill_wave_size` rows, and a wave
+computes as many rows as it has requests: the program takes its arrays
+at the wave size (one compiled shape per length bucket, with or without
+a prefix part) and loops over the REAL rows only, one row a pass, so one
+arrival costs one row and not a wave of padding. Every row count from 1
+to the wave size is the same program, so there is nothing more for
+`warmup()` to build than one program per length bucket and prefix
+variant, all before a replica reports READY (a compiled row bucket
+would cost about a second of tracing each on the host, measured; the
+loop costs nothing, and above a few hundred tokens a prefill is bound by
+compute, where rows computed together take as long as rows computed in
+turn). The waves pipeline on-device, so a burst's total prefill compute
+is unchanged but the first wave's tokens surface after only its own
+share of it. `decode_steps_per_dispatch`, `pipeline_depth` and
+`prefill_wave_size` keep the values an earlier deployment chose; ROADMAP
+C3 re-measures them on the attached chip.
 
 Attention implementation: on a TPU backend a single-device engine runs
 the Pallas paged-decode and flash kernels, and a pool layout the decode
@@ -201,11 +211,12 @@ class EngineConfig:
     # tokens/pages computed past a stop are dropped at harvest. 1 =
     # fully synchronous (round-2 behavior).
     pipeline_depth: int = 2
-    # rows per prefill dispatch (and the single compiled row count per
-    # length bucket). A burst larger than this prefills in waves: the
-    # waves pipeline on-device, so total compute is unchanged but the
-    # first wave's tokens surface after only its own share.
-    # None => max_batch // 2.
+    # rows per prefill dispatch AT MOST (and the single compiled row
+    # count per length bucket: a dispatch of fewer requests runs the same
+    # program over its real rows only). A burst larger than this
+    # prefills in waves: the waves pipeline on-device, so total compute
+    # is unchanged but the first wave's tokens surface after only its
+    # own share. None => max_batch // 2.
     prefill_wave_size: Optional[int] = None
     # token-budget scheduling: >0 caps each step's prefill work at this
     # many prompt tokens (rounded up to a page multiple, clamped to the
@@ -442,8 +453,9 @@ class LLMEngine:
         self._head_overtaken: tuple = (None, 0)
         self._jit_cache: Dict[tuple, Any] = {}
         self._pending_deltas: List[OutputDelta] = []
-        # the single compiled prefill row count (and max rows per prefill
-        # dispatch) — one expression, used by dispatch, split and warmup
+        # the single compiled prefill row count (the MOST rows a prefill
+        # dispatch has; it computes its real rows only) — one expression,
+        # used by dispatch, split and warmup
         self._wave_rb: int = (config.prefill_wave_size
                               or max(1, config.max_batch // 2))
         # decode runs ONE compile shape: the full-width block table. The
@@ -819,24 +831,43 @@ class LLMEngine:
             # the full-width variant (two shapes per length bucket)
             cp = shape_key[2]
 
-            def run_prefill(params, kv_pages, block_tables,
+            def run_prefill(params, kv_pages, n_rows, block_tables,
                             total_lens, input_ids, positions, gather_idx,
                             temperature, top_k, rng_keys):
-                pc = PagedCache(
-                    kv_pages=kv_pages,
-                    block_tables=jnp.broadcast_to(
-                        block_tables, (L,) + block_tables.shape),
-                    total_lens=jnp.broadcast_to(total_lens,
-                                                (L,) + total_lens.shape),
-                    ctx_pages=cp, ref_attention=ref_attn)
-                logits, new_pc, counts = apply(params, input_ids, positions,
-                                               pc, total_lens)
+                # the arrays come at the wave size; the first `n_rows` are
+                # requests and only those are computed, one row a pass
+                # (the trip count is data, so every row count is this one
+                # program). The pool rides the loop's carry as it rides
+                # the layer scan's, in place.
+                def row(i, carry):
+                    kvp, rows, counts = carry
+                    bt, tot, ids, pos = (
+                        jax.lax.dynamic_slice_in_dim(a, i, 1)
+                        for a in (block_tables, total_lens, input_ids,
+                                  positions))
+                    pc = PagedCache(
+                        kv_pages=kvp,
+                        block_tables=jnp.broadcast_to(bt, (L,) + bt.shape),
+                        total_lens=jnp.broadcast_to(tot, (L,) + tot.shape),
+                        ctx_pages=cp, ref_attention=ref_attn)
+                    logits, new_pc, c = apply(params, ids, pos, pc, tot)
+                    # keep the row's sampling position only
+                    last = logits[0, gather_idx[i]].astype(jnp.float32)
+                    rows = jax.lax.dynamic_update_slice_in_dim(
+                        rows, last[None], i, 0)
+                    return (new_pc.kv_pages, rows,
+                            None if c is None else counts + c)
+
+                rb = input_ids.shape[0]
+                rows0 = jnp.zeros((rb, self.model_cfg.vocab_size),
+                                  jnp.float32)
+                counts0 = jnp.zeros(self._moe_LE, jnp.int32) if moe else None
+                kvp, rows, counts = jax.lax.fori_loop(
+                    0, n_rows, row, (kv_pages, rows0, counts0))
                 # sample ON DEVICE: only B int32 tokens cross to the host
                 # per step, never the [B, V] fp32 logits
-                b = logits.shape[0]
-                rows = logits[jnp.arange(b), gather_idx].astype(jnp.float32)
                 tokens = _device_sample(rows, temperature, top_k, rng_keys)
-                return pack(tokens, counts), new_pc.kv_pages
+                return pack(tokens, counts), kvp
 
             if self.sharding is not None:
                 # explicit shardings: params + pages by their specs,
@@ -846,7 +877,7 @@ class LLMEngine:
                 fn = jax.jit(
                     run_prefill, donate_argnums=(1,),
                     in_shardings=(self._param_shardings,
-                                  self._kv_sharding) + (repl,) * 8,
+                                  self._kv_sharding) + (repl,) * 9,
                     out_shardings=(repl, self._kv_sharding))
             else:
                 fn = jax.jit(run_prefill, donate_argnums=(1,))
@@ -951,15 +982,22 @@ class LLMEngine:
     # engine (pp.py) overrides these to push frames through the stage
     # DAG and returns CompiledDAGRef handles instead of device arrays.
 
-    def _compute_prefill(self, sb, rb, cp, bt, total, ids, positions,
-                         gather, temp, topk, keys):
-        """One prefill dispatch; returns the sampled-tokens handle the
-        harvest will resolve via _fetch_tokens ([rb] int32)."""
+    # rows a prefill program computes for a dispatch of `n` requests:
+    # run_prefill loops over the real ones. The pipelined engine's stage
+    # programs compute whole frames (pp.py overrides this).
+    def _prefill_rows(self, n: int) -> int:
+        return n
+
+    def _compute_prefill(self, sb, rb, cp, n_rows, bt, total, ids,
+                         positions, gather, temp, topk, keys):
+        """One prefill dispatch over the first `n_rows` of the wave-sized
+        arrays; returns the sampled-tokens handle the harvest will
+        resolve via _fetch_tokens ([rb] int32)."""
         import jax.numpy as jnp
 
         fn = self._jit("prefill", (sb, rb, cp))
         tokens, self.kv_pages = fn(
-            self.params, self.kv_pages, jnp.asarray(bt),
+            self.params, self.kv_pages, np.int32(n_rows), jnp.asarray(bt),
             jnp.asarray(total), jnp.asarray(ids), jnp.asarray(positions),
             jnp.asarray(gather), temp, topk, keys)
         try:
@@ -1107,12 +1145,16 @@ class LLMEngine:
         chunk in token-budget mode. Rows whose start is > 0 attend to
         their earlier pages through the same ctx-merge path prefix-cache
         hits use; only rows whose FINAL chunk this is sample a token."""
-        # rows always pad to the wave size: ONE compiled row count per
-        # length bucket (per-size row buckets would multiply the compile
-        # shapes, and an unwarmed shape hit mid-traffic is a
-        # multi-second TTFT spike)
+        # the arrays always come at the wave size: ONE compiled row count
+        # per length bucket (per-size row buckets would multiply the
+        # programs warmup() has to build, each about a second of tracing
+        # on the host, and an unwarmed shape hit mid-traffic is a
+        # multi-second TTFT spike). The program computes the group's rows
+        # only (run_prefill), so the padding rows below cost an upload
+        # and no compute; `computed` is what the records count
         with tracing.region("rtpu.engine.dispatch_prefill") as r:
             rb = self._wave_rb
+            computed = self._prefill_rows(len(group))
             ids = np.zeros((rb, sb), np.int32)
             positions = np.zeros((rb, sb), np.int32)
             bt = np.zeros((rb, self.max_pages_per_seq), np.int32)
@@ -1140,9 +1182,9 @@ class LLMEngine:
                   if any(req.n_prefilled for req, _ in group) else 0)
             temp, topk, keys = self._sampling_arrays(
                 [req for req, _ in group], rb)
-            tokens = self._compute_prefill(sb, rb, cp, bt, total, ids,
-                                           positions, gather, temp, topk,
-                                           keys)
+            tokens = self._compute_prefill(sb, rb, cp, len(group), bt,
+                                           total, ids, positions, gather,
+                                           temp, topk, keys)
             for req, n_new in group:
                 req.n_prefilled += n_new
                 if req.n_prefilled >= len(req.prompt_ids):
@@ -1150,9 +1192,9 @@ class LLMEngine:
             self._totals["prefill_dispatches_total"] += 1
             self._totals["prefill_tokens_total"] += sum(
                 n_new for _, n_new in group)
-            self._totals["prefill_padded_tokens_total"] += rb * sb
-            self._enqueue("prefill", tokens, r.start_ns, rb, rb * sb,
-                          facts, group=rows)
+            self._totals["prefill_padded_tokens_total"] += computed * sb
+            self._enqueue("prefill", tokens, r.start_ns, computed,
+                          computed * sb, facts, group=rows)
         self._phase_ns[_DISPATCH_PREFILL] += r.ns
 
     def _enqueue(self, kind: str, toks, dispatch_ns: int, rows_padded: int,
@@ -1242,6 +1284,9 @@ class LLMEngine:
                 break
         if not rows:
             return False
+        # verify computes all `_wave_rb` rows, padding included: it is
+        # off by default and no benchmark cell runs it, so run_prefill's
+        # loop over the real rows has not been brought here
         rb = self._wave_rb
         sb = _bucket(L + 1, cfg.prefill_buckets)
         ids = np.zeros((rb, sb), np.int32)
@@ -1504,7 +1549,10 @@ class LLMEngine:
         rows = rec["rows_padded"]
         n = rec["k"] * self._moe_LE[0] * self._moe_LE[1]
         counts = fetched[-n:].reshape((-1,) + self._moe_LE)
-        tokens = fetched[:-n].reshape({"prefill": (rows,), "spec": (rows, -1),
+        # a prefill returns a token per row of the wave-sized arrays and
+        # ONE count over the rows it computed (an expert a wave touches
+        # in two rows counts once: the least a wave has to read)
+        tokens = fetched[:-n].reshape({"prefill": (-1,), "spec": (rows, -1),
                                        "decode": (-1, rows)}[rec["kind"]])
         assignments, touched = int(counts.sum()), int((counts > 0).sum())
         self._totals["moe_assignments_total"] += assignments
@@ -1806,8 +1854,9 @@ class LLMEngine:
 
         if kind == "prefill":
             sb, rb, _cp = shape_key
-            return (z((rb, mp)), z((rb,)), z((rb, sb)), z((rb, sb)),
-                    z((rb,)), np.zeros((rb,), np.float32),
+            # no real row: the program's loop makes no pass
+            return (np.int32(0), z((rb, mp)), z((rb,)), z((rb, sb)),
+                    z((rb, sb)), z((rb,)), np.zeros((rb,), np.float32),
                     np.zeros((rb,), np.int32), np.zeros((rb, 2), np.uint32))
         if kind == "verify":
             sbv, rb = shape_key
@@ -1833,10 +1882,15 @@ class LLMEngine:
             *state, *self._dummy_args(kind, shape_key)).as_text()
 
     def warmup(self, prompt_buckets=None, include_decode=True) -> int:
-        """Compile every dispatch shape traffic can hit — one prefill per
-        length bucket (rows always pad to prefill_wave_size) plus the
-        fused decode chunk — by running masked dummy dispatches
-        (_dummy_args: engine state is untouched). Serve replicas call
+        """Build every dispatch shape traffic can hit — one prefill
+        program per length bucket, with and without a prefix part (rows
+        are no dimension of the set: a program takes the wave size and
+        computes the rows a dispatch gives it, so every group size from
+        one request to a full wave is already here) plus the fused
+        decode chunk — by running masked dummy dispatches (_dummy_args:
+        engine state is untouched; a prefill is given no real row, so
+        its row loop makes no pass and building it costs the trace and
+        the compile or cache fetch, no device time). Serve replicas call
         this before reporting READY: an unwarmed shape compiled under
         live traffic is a multi-second TTFT spike. prompt_buckets=()
         skips prefill shapes (decode-only replicas);

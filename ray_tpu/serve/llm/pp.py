@@ -455,8 +455,16 @@ class PipelinedEngine(LLMEngine):
         self._pp_ticks += 1
         return self._cdag.execute(frame)
 
-    def _compute_prefill(self, sb, rb, cp, bt, total, ids, positions,
-                         gather, temp, topk, keys):
+    def _prefill_rows(self, n: int) -> int:
+        # the stage programs compute whole frames: a frame crosses the
+        # stage channels at ONE shape per length bucket, padding rows
+        # included. The single-process engine's row loop is not brought
+        # here while pp has run on no chip (ROADMAP A5): the hidden
+        # states between stages would have to skip the padding too
+        return self._wave_rb
+
+    def _compute_prefill(self, sb, rb, cp, n_rows, bt, total, ids,
+                         positions, gather, temp, topk, keys):
         frame = {
             "kind": "prefill", "sb": sb, "rb": rb, "cp": cp,
             "ids": np.asarray(ids), "bt": np.asarray(bt),

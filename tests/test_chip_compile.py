@@ -183,6 +183,12 @@ ENGINE_PROGRAMS = {
     "mixtral-decode-32x1": ("mixtral", "decode", lambda rb, mp: (1, mp)),
     "mixtral-prefill-16x2048":
         ("mixtral", "prefill", lambda rb, mp: (2048, rb, 0)),
+    # the programs most of the cells' waves take (a chat prompt's median
+    # is 256 tokens, a document prefills with no prefix): one row a pass
+    "prefill-bucket4096-kv8320":
+        ("long", "prefill", lambda rb, mp: (4096, rb, 0)),
+    "mixtral-prefill-16x128-prefix-hit":
+        ("mixtral", "prefill", lambda rb, mp: (128, rb, mp)),
 }
 
 
@@ -225,8 +231,10 @@ def _program_args(kind, shape_key, rows, mp, sds):
             sds((rows, span), i32))
     if kind == "verify":
         return args
-    return args + (sds((rows,), i32), sds((rows,), f32), sds((rows,), i32),
-                   sds((rows, 2), jnp.uint32))
+    # a prefill takes the number of real rows first: it loops over them
+    return (sds((), i32),) + args + (
+        sds((rows,), i32), sds((rows,), f32), sds((rows,), i32),
+        sds((rows, 2), jnp.uint32))
 
 
 def _array_types(type_text):
@@ -255,35 +263,53 @@ def _check_expert_program(cfg, tokens, compiled, text):
         assert dims not in ((E, h, 2 * f), (E, f, h)), dims
 
 
+@pytest.fixture(scope="module")
+def compiled_programs(topo, no_persistent_cache, engines):
+    """name -> (engine, kind, shape key, rows, pool dims, compiled, its
+    text), each program compiled once for the cases that read it."""
+    done = {}
+
+    def get(name):
+        if name in done:
+            return done[name]
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def sds(shape, dt):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+        which, kind, key = ENGINE_PROGRAMS[name]
+        engine = engines[which]
+        mp = engine.max_pages_per_seq
+        rows = (engine.config.max_batch if kind == "decode"
+                else engine._wave_rb)
+        shape_key = key(rows, mp)
+        pool_dims = (ENGINES[which]["layers"], ENGINES[which]["pages"], HKV,
+                     PAGE, 2 * HEAD_DIM)
+        # the ops choose kernel or reference by the backend, at trace time
+        with pytest.MonkeyPatch.context() as mp_ctx:
+            mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+            compiled = engine._jit(kind, shape_key).lower(
+                jax.tree.map(lambda a: sds(a.shape, a.dtype), engine.params),
+                sds(pool_dims, BF16),
+                *_program_args(kind, shape_key, rows, mp, sds)).compile()
+        done[name] = (engine, kind, shape_key, rows, pool_dims, compiled,
+                      compiled.as_text())
+        return done[name]
+
+    return get
+
+
 @pytest.mark.parametrize("name", list(ENGINE_PROGRAMS))
-def test_engine_program_keeps_the_pool_in_place(
-        topo, no_persistent_cache, engines, monkeypatch, name):
+def test_engine_program_keeps_the_pool_in_place(compiled_programs, name):
     """The donated pool is ONE buffer from argument to result: no
     instruction that moves data has an output of its size or a layer's,
     it is row-major wherever it appears (the layout the decode kernel's
     custom call demands, so nothing re-lays it out), and the argument is
     aliased to the result."""
-    # the ops choose kernel or reference by the backend, at trace time
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one_chip = SingleDeviceSharding(topo.devices[0])
-
-    def sds(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-
-    which, kind, key = ENGINE_PROGRAMS[name]
-    engine = engines[which]
-    mp = engine.max_pages_per_seq
-    rows = engine.config.max_batch if kind == "decode" else engine._wave_rb
-    shape_key = key(rows, mp)
-    pool_dims = (ENGINES[which]["layers"], ENGINES[which]["pages"], HKV,
-                 PAGE, 2 * HEAD_DIM)
+    engine, kind, shape_key, rows, pool_dims, compiled, text = \
+        compiled_programs(name)
     layer_bytes = 2 * int(np.prod(pool_dims[1:]))
     pool_bytes = (layer_bytes, pool_dims[0] * layer_bytes)  # in any shape
-    compiled = engine._jit(kind, shape_key).lower(
-        jax.tree.map(lambda a: sds(a.shape, a.dtype), engine.params),
-        sds(pool_dims, BF16),
-        *_program_args(kind, shape_key, rows, mp, sds)).compile()
-    text = compiled.as_text()
     if engine.model_cfg.num_experts:
         _check_expert_program(engine.model_cfg, rows * shape_key[0]
                               if kind == "prefill" else rows, compiled, text)
@@ -309,3 +335,26 @@ def test_engine_program_keeps_the_pool_in_place(
     assert pool_param is not None
     assert re.search(r"input_output_alias=\{[^\n]*\(%d, \{\}, (may|must)-alias\)"
                      % pool_param, header), header[:300]
+
+
+@pytest.mark.parametrize("name", [n for n, (_, kind, _) in
+                                  ENGINE_PROGRAMS.items()
+                                  if kind == "prefill"])
+def test_prefill_program_computes_one_row_a_pass(compiled_programs, name):
+    """A wave computes its real rows in a loop, one row a pass: the
+    program holds a while loop beside the layer scan's, and no
+    activation at the wave's width (the padded `[16, 2048, 32000]` head
+    and `[16, 2048, 28672]` MLP of the program that padded every wave
+    to its size). Its inputs, `[rows, span]` ids and positions, stay."""
+    engine, _, shape_key, rows, _, _, text = compiled_programs(name)
+    span = shape_key[0]
+    assert rows > 1
+    wide = set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if m:
+            wide.update(dims for dims, _ in _array_types(m.group(1))
+                        if len(dims) > 2 and dims[:2] == (rows, span))
+    assert not wide, wide
+    # the row loop and the layer scan inside it
+    assert len(re.findall(r" while\(", text)) >= 2

@@ -97,9 +97,10 @@ def test_prefill_records_count_real_and_padded_tokens(engine):
     real = sum(q for d in prefills for _, q, _ in d["rows"])
     assert real == sum(r["prompt_tokens"] - r["cached_tokens"]
                        for r in reqs)
-    rb = engine._wave_rb
     for d in prefills:
-        assert d["rows_padded"] == rb
+        # a wave computes its real rows and no other
+        rb = d["rows_padded"]
+        assert rb == len(d["rows"]) <= engine._wave_rb
         assert d["tokens_padded"] % rb == 0
         bucket = d["tokens_padded"] // rb
         assert bucket in ENGINE_CFG["prefill_buckets"]
@@ -109,6 +110,126 @@ def test_prefill_records_count_real_and_padded_tokens(engine):
     assert row[1:] == (1, 25)
     assert d["step_dispatched"] <= d["step_harvested"]
     assert d["dispatch_ns"] <= d["fetch_start_ns"] <= d["fetch_end_ns"]
+
+
+# ------------------------------- a wave computes its real rows only
+WIDE_CFG = dict(ENGINE_CFG, max_batch=8, prefill_buckets=(16, 32))
+
+
+def _waves(n, engine, tag, max_tokens=3, seed=11):
+    """Send n prompts of one length bucket at once and run dry: the
+    (rows the program computed, real rows) of each prefill dispatch,
+    and every request's tokens."""
+    tracing.reset_ring()
+    for i, p in enumerate(_prompts(n, lo=20, step=1, seed=seed)):
+        engine.add_request(f"{tag}-{i}", p,
+                           SamplingParams(max_tokens=max_tokens))
+    out = {}
+    for _ in range(600):
+        if not engine.has_work():
+            break
+        for d in engine.step():
+            out.setdefault(d.request_id, []).extend(d.new_token_ids)
+    return [(d["rows_padded"], len(d["rows"]))
+            for d in _dicts("engine.dispatch")
+            if d["kind"] == "prefill"], out
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """max_batch 8: waves of at most 4 rows."""
+    return LLMEngine(EngineConfig(**WIDE_CFG))
+
+
+@pytest.mark.parametrize("n, waves", [
+    (1, [1]), (2, [2]), (3, [3]), (4, [4]), (5, [4, 1]), (6, [4, 2]),
+    (7, [4, 3]), (8, [4, 4])])
+def test_a_prefill_wave_computes_its_requests_and_no_padding_row(
+        wide, n, waves):
+    """One request computes one row; a burst beyond the wave size goes
+    in waves of the wave size and a remainder of its own size."""
+    assert wide._wave_rb == 4
+    before = wide.stats()
+    # a seed of its own: no page of another case's prompts is cached
+    got, out = _waves(n, wide, f"w{n}", seed=100 + n)
+    assert got == [(w, w) for w in waves]
+    assert len(out) == n and all(len(t) == 3 for t in out.values())
+    after = wide.stats()
+    assert (after["prefill_padded_tokens_total"]
+            - before["prefill_padded_tokens_total"]) == 32 * n
+    assert (after["prefill_tokens_total"]
+            - before["prefill_tokens_total"]) == sum(
+                20 + i for i in range(n))
+    # every row count is ONE program: the wave-sized one of the bucket
+    assert {k for k in wide._jit_cache if k[0] == "prefill"} == {
+        ("prefill", 32, 4, 0)}
+
+
+@pytest.mark.parametrize("chunk", [0, 16])
+def test_greedy_tokens_are_the_same_alone_and_inside_a_full_wave(chunk):
+    """Both schedulers pass through the row loop: a request's tokens do
+    not depend on how many rows shared its wave."""
+    engine = LLMEngine(EngineConfig(**WIDE_CFG, prefill_chunk_tokens=chunk))
+    rows, full = _waves(4, engine, "g", max_tokens=6)
+    # whole prompts go four to a wave; a budget of 16 tokens a step is
+    # shared by up to three rows
+    assert max(real for _, real in rows) == (3 if chunk else 4)
+    # alone, on a fresh engine (nothing cached): one row every dispatch
+    engine = LLMEngine(EngineConfig(**WIDE_CFG, prefill_chunk_tokens=chunk))
+    rows, alone = _waves(1, engine, "g", max_tokens=6)
+    assert set(rows) == {(1, 1)}
+    assert full["g-0"] == alone["g-0"] and len(alone["g-0"]) == 6
+
+
+@pytest.fixture(scope="module")
+def warmed():
+    """A warmed engine, the number warmup() returned, and a count of
+    the programs JAX itself builds from then on."""
+    import jax.monitoring
+
+    engine = LLMEngine(EngineConfig(**WIDE_CFG))
+    n = engine.warmup()
+    compiles = [0]
+
+    def on_duration(name, secs, **_):
+        if name.endswith("/backend_compile_duration"):
+            compiles[0] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return engine, n, compiles
+
+
+def test_warmup_builds_one_program_per_length_bucket_and_prefix_variant(
+        warmed):
+    engine, n, _ = warmed
+    lengths = WIDE_CFG["prefill_buckets"]
+    assert n == len(lengths) * 2 + 1
+    assert set(engine._jit_cache) == {
+        ("prefill", sb, engine._wave_rb, cp) for sb in lengths
+        for cp in (0, engine.max_pages_per_seq)} | {
+            ("decode",) + engine._decode_shape_key()}
+    assert engine.stats()["programs_built_total"] == n
+    # a warm-up dispatch has no real row: nothing was written or counted
+    assert engine.stats()["prefill_padded_tokens_total"] == 0
+
+
+@pytest.mark.parametrize("n", range(1, WIDE_CFG["max_batch"] + 1))
+def test_no_program_is_built_under_traffic_after_warmup(warmed, n):
+    """Every group size from one request to a full batch, fresh prompts
+    and then a prefix hit: no `engine.program_built` record, and JAX
+    compiles nothing."""
+    engine, _, compiles = warmed
+    built = engine.stats()["programs_built_total"]
+    compiled = compiles[0]
+    got, out = _waves(n, engine, f"t{n}", seed=20 + n)
+    assert len(out) == n and sum(real for _, real in got) == n
+    # the same prompts again: their full pages come from the cache, so
+    # the waves take the programs with a prefix part
+    again, _ = _waves(n, engine, f"u{n}", seed=20 + n)
+    assert sum(real for _, real in again) == n
+    assert not tracing.records("engine.program_built")
+    assert engine.stats()["programs_built_total"] == built
+    assert compiles[0] == compiled
 
 
 def test_decode_rows_carry_the_context_they_attend_to(engine):
