@@ -1,12 +1,12 @@
 """Remote-connect client overhead vs in-cluster driver.
 
 Mirrors the reference's Ray Client microbenchmark (ref: python/ray/
-_private/ray_client_microbenchmark.py; BASELINE.md's Ray Client row
+_private/ray_client_microbenchmark.py; the reference's Ray Client row
 shows ~4x overhead vs direct calls). Runs the client in a subprocess
 (client mode owns the process-global core) against an in-process head +
-proxy, and merges `client_*` keys into golden.json.
+proxy, and merges `client_*` keys into the `--out` file.
 
-Run: `python benchmarks/client_overhead.py [--out golden.json]`.
+Run: `python benchmarks/client_overhead.py [--out FILE.json]`.
 """
 
 from __future__ import annotations

@@ -119,7 +119,7 @@ def main() -> int:
     results["data_split_exactly_once"] = True
 
     ray_tpu.shutdown()
-    print(json.dumps(results))  # one line: bench.py scans for it
+    print(json.dumps(results))  # one line
     if args.out:
         with open(args.out, "w") as f:
             f.write(json.dumps(results, indent=2))

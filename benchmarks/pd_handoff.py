@@ -17,7 +17,7 @@ Two modes, same protocol:
 `handoff_speedup` is the ratio (the stable signal on a loaded shared box —
 judge ratios, not absolutes). The bulk child also runs one tiny in-process
 prefill/decode pair end-to-end and reports `pd_ttft_ms` plus the mean TTFT
-breakdown (queue/prefill/handoff), which bench.py surfaces each round.
+breakdown (queue/prefill/handoff).
 
 Run: `python benchmarks/pd_handoff.py [--size-mb 16] [--pulls 3] [--out f]`
 """

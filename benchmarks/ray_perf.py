@@ -2,8 +2,8 @@
 
 Parity with the reference's microbenchmark harness (ref:
 python/ray/_private/ray_perf.py — tasks/s, actor calls/s, put throughput;
-golden numbers ref: release/perf_metrics/microbenchmark.json, duplicated in
-BASELINE.md). Run: `python benchmarks/ray_perf.py [--out golden.json]`.
+golden numbers ref: release/perf_metrics/microbenchmark.json).
+Run: `python benchmarks/ray_perf.py [--out FILE.json]`.
 """
 
 from __future__ import annotations
@@ -23,7 +23,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 def timeit(fn, n: int, warmup: int = 5, chunks: int = 5):
     """(mean_rate, best_chunk_rate). The run splits into `chunks`
     windows; the MEAN over the whole run is the primary number (directly
-    comparable to the reference's mean±std goldens in BASELINE.md), and
+    comparable to the reference's mean±std goldens), and
     the fastest window is reported alongside as the capability bound —
     co-tenant CI load on a shared box only ever subtracts, so the best
     chunk shows what the runtime can do when the box is quiet (VERDICT
@@ -58,7 +58,7 @@ def main():
 
     import ray_tpu
 
-    session = ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
+    ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
     results = {}
 
     # ---- tasks/s (ref: ray_perf.py "multi client tasks async")
@@ -203,22 +203,6 @@ def main():
             mean * nbig * m * (mb << 20) / 1e9, 3)
         results[f"multi_put_gb_per_s_c{m}_best"] = round(
             best * nbig * m * (mb << 20) / 1e9, 3)
-
-    # ---- scheduling plane: spill-path counters + the locality A/B
-    # (multi_locality_gb_s — argument GB/s when large-arg tasks go to
-    # the bytes vs the bytes crossing hosts). LAST: it adds a second
-    # (simulated-host) node, which would change the sections above.
-    try:
-        here = os.path.dirname(os.path.abspath(__file__))
-        if here not in sys.path:
-            sys.path.insert(0, here)
-        from scale import bench_scheduling_plane
-
-        # compact sizing: this rides inside bench.py's runtime budget
-        results.update(bench_scheduling_plane(session, n_tasks=100,
-                                              n_objects=4))
-    except Exception as e:  # noqa: BLE001 — never lose the core keys
-        results["scheduling_plane_error"] = repr(e)[:200]
 
     print(json.dumps(results))
     if args.out:

@@ -28,6 +28,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from ray_tpu.serve.llm.engine import (EngineConfig, LLMEngine,  # noqa: E402
                                       SamplingParams)
+from ray_tpu.serve.llm.stage import serve_model_config  # noqa: E402
 from ray_tpu.util import tracing  # noqa: E402
 
 
@@ -35,6 +36,7 @@ class StubEngine(LLMEngine):
     """The scheduler with no model behind it."""
 
     def _build_compute(self, params, mesh) -> None:
+        self.model_cfg = serve_model_config(self.config)
         self.sharding = None
         self._attention = {"decode": "stub", "prefill": "stub"}
         self._device = {"platform": "none"}

@@ -7,8 +7,7 @@ to the simulated host pull them, and the pull time is clocked INSIDE the
 task around ray_tpu.get. Runs the same protocol twice — bulk stream
 enabled (default) and forced onto the om_read RPC fallback
 (RTPU_bulk_transfer_enabled=0) — so the stream's advantage has its own
-trend line (`object_pull_gb_s` vs `object_pull_gb_s_rpc`; bench.py picks
-these up each round).
+trend line (`object_pull_gb_s` vs `object_pull_gb_s_rpc`).
 
 Run: `python benchmarks/transfer.py [--size-mb 64] [--pulls 4] [--out f]`
 """

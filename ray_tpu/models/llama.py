@@ -520,8 +520,8 @@ class ScannedLayer(nn.Module):
 def _apply_layers(cfg: LlamaConfig, length: int, x, positions, segment_ids,
                   kv_caches, token_mask=None, experts=None):
     """Run `length` scanned layers named "layers" under the calling
-    module; returns (x, new_caches). Shared by LlamaModel, LayerStack and
-    StageModel: ONE definition of the scan axes/metadata so every consumer
+    module; returns (x, new_caches). Shared by LlamaModel and LayerStack:
+    ONE definition of the scan axes/metadata so every consumer
     produces the identical "layers" param collection (leaves stacked with
     a leading [length] axis under PARTITION_NAME "layers").
 
@@ -568,54 +568,17 @@ class LayerStack(nn.Module):
         return x
 
 
-class StageModel(nn.Module):
-    """One SERVING pipeline stage of LlamaModel: an [n_layers] slice of
-    the scanned "layers" collection, plus the embedding table on the
-    first stage and final_norm + lm_head on the last.
-
-    Every param keeps the name it has in the full LlamaModel tree
-    ("embed" / "layers" / "final_norm" / "lm_head"), so stage params are
-    literal slices of a full-model init (serve/llm/pp.py stage_params) —
-    which is what makes the pipelined engine bit-exact against the
-    single-process one: the per-layer math, the embed lookup and the head
-    projection are the same ops on the same values, only partitioned
-    across processes.
-
-    Call signature mirrors the serving path of LlamaModel.__call__:
-    `x` is int32 token ids on the first stage (embedded here) and the
-    previous stage's hidden states elsewhere; `kv_caches` is this stage's
-    PagedCache, the pool an [n_layers, P, ...] slice of its own; returns
-    (hidden-or-logits, new_caches).
-    """
-
-    config: LlamaConfig
-    n_layers: int
-    first: bool = False
-    last: bool = False
-
-    @nn.compact
-    def __call__(self, x, positions, kv_caches):
-        cfg = self.config
-        if self.first:
-            embed = self.param(
-                "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
-                (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-            x = embed[x].astype(cfg.dtype)
-        x, new_caches = _apply_layers(cfg, self.n_layers, x, positions,
-                                      None, kv_caches)
-        if self.last:
-            x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-            x = nn.DenseGeneral(
-                features=cfg.vocab_size, use_bias=False, axis=-1,
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                kernel_init=A(nn.initializers.lecun_normal(),
-                              ("embed", "vocab")),
-                name="lm_head")(x)
-        return x, new_caches
-
-
 class LlamaModel(nn.Module):
+    """The whole decoder, or a contiguous slice of its scanned layers (one
+    SERVING pipeline stage, serve/llm/stage.py): `n_layers` of them (None:
+    all), with the embedding table when `first` and final_norm + lm_head
+    when `last`. A slice keeps every parameter's name, so its params are
+    literal slices of a whole model's (serve/llm/pp.py stage_params) and
+    the same ops run on the same values wherever the layers are cut."""
     config: LlamaConfig
+    n_layers: Optional[int] = None
+    first: bool = True
+    last: bool = True
     # train_lib feature-detects the fused chunked-CE `targets=` path
     supports_fused_loss = True
 
@@ -624,29 +587,36 @@ class LlamaModel(nn.Module):
                  kv_caches=None, targets=None, token_mask=None):
         """Forward pass.
 
+        input_ids: [B, S] token ids; the previous stage's [B, S, h] hidden
+        states when not `first`. Returns logits; hidden states when not
+        `last`.
+
         token_mask: [B, S] bool, False where the caller padded a row or a
         position; only the expert layer reads it (MoEMLP).
 
         kv_caches: None (training / full prefill), a PagedCache (serving:
-        one [L, P, ...] pool, updated in place), or a (k, v) pair stacked
-        over layers — k/v shaped [L, B, S_cache, Hkv, D] when scan_layers,
-        else a list of L per-layer (k, v) tuples.  When given, returns
-        (logits, new_kv_caches); `positions` must then hold the absolute
-        positions of `input_ids` and `segment_ids` (if any) must span the
-        full cache+input kv axis.
+        one [n_layers, P, ...] pool, updated in place), or a (k, v) pair
+        stacked over layers — k/v shaped [L, B, S_cache, Hkv, D] when
+        scan_layers, else a list of L per-layer (k, v) tuples.  When given,
+        returns (logits, new_kv_caches); `positions` must then hold the
+        absolute positions of `input_ids` and `segment_ids` (if any) must
+        span the full cache+input kv axis.
         """
         cfg = self.config
+        n_layers = cfg.num_layers if self.n_layers is None else self.n_layers
         if positions is None:
             positions = jnp.broadcast_to(
-                jnp.arange(input_ids.shape[1]), input_ids.shape)
-        embed = self.param(
-            "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = embed[input_ids].astype(cfg.dtype)
+                jnp.arange(input_ids.shape[1]), input_ids.shape[:2])
+        x = input_ids
+        if self.first:
+            embed = self.param(
+                "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
+                (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
+            x = embed[input_ids].astype(cfg.dtype)
 
         if cfg.scan_layers:
             x, new_caches = _apply_layers(
-                cfg, cfg.num_layers, x, positions, segment_ids, kv_caches,
+                cfg, n_layers, x, positions, segment_ids, kv_caches,
                 token_mask, _stacked_experts(self, cfg, kv_caches))
         else:
             layer_cls = DecoderLayer
@@ -654,13 +624,15 @@ class LlamaModel(nn.Module):
                 layer_cls = nn.remat(DecoderLayer, prevent_cse=False,
                                      policy=_remat_policy(cfg.remat_policy))
             new_caches = [] if kv_caches is not None else None
-            for i in range(cfg.num_layers):
+            for i in range(n_layers):
                 cache_i = kv_caches[i] if kv_caches is not None else None
                 x, new_cache = layer_cls(cfg, name=f"layer_{i}")(
                     x, positions, segment_ids, cache_i, token_mask)
                 if kv_caches is not None:
                     new_caches.append(new_cache)
 
+        if not self.last:
+            return (x, new_caches) if kv_caches is not None else x
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
         head = nn.DenseGeneral(
             features=cfg.vocab_size, use_bias=False, axis=-1,

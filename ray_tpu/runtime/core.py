@@ -1457,7 +1457,10 @@ class CoreWorker:
         if not self._submit_batch_enabled:
             kind, task_id, spec, return_ids, arg_refs, actor_id = entry
             loop = self._loop or EventLoopThread.get().loop
-            if kind == "task":
+            if kind == "register":
+                loop.call_soon_threadsafe(self._send_register_actor,
+                                          actor_id, spec)
+            elif kind == "task":
                 loop.call_soon_threadsafe(
                     self._register_and_submit, task_id, spec, return_ids,
                     arg_refs)
@@ -1510,6 +1513,9 @@ class CoreWorker:
                 except IndexError:
                     break
                 n += 1
+                if kind == "register":
+                    self._send_register_actor(actor_id, spec)
+                    continue
                 self._register_pending(task_id, spec, return_ids,
                                        arg_refs)
                 if kind == "task":
@@ -1956,14 +1962,26 @@ class CoreWorker:
             if self.controller.on_notify_error is None:
                 self.controller.on_notify_error = \
                     self._on_controller_notify_lost
-            self.controller.notify_nowait("register_actor",
-                                          actor_id=actor_id, spec=spec)
+            # through the SUBMISSION queue, not straight onto the loop:
+            # the actor's first calls are staged there, and anything that
+            # drains the queue early (a sync get/wait/cancel) would start
+            # their resolve ahead of a registration travelling beside it
+            # ('unknown actor' right after creation: the first call died
+            # and every later one waited for its sequence number)
+            self._stage_submit(("register", None, spec, None, None,
+                                actor_id))
             return actor_id
         res = self.controller.call("register_actor", actor_id=actor_id, spec=spec)
         if res["status"] == "name_taken":
             raise ValueError(
                 f"actor name {opts.get('name')!r} already taken")
         return res["actor_id"]
+
+    def _send_register_actor(self, actor_id: str, spec: dict):
+        # on the io loop, in submission order: the send is scheduled
+        # ahead of any later call's resolve
+        self.controller.notify_nowait("register_actor", actor_id=actor_id,
+                                      spec=spec)
 
     def _on_controller_notify_lost(self, method: str, kwargs: dict,
                                    exc) -> None:
@@ -2165,6 +2183,7 @@ class CoreWorker:
                     error=f"actor {actor_id} unreachable: {e}")
 
     def kill_actor(self, actor_id: str, no_restart: bool = True):
+        self._flush_staged()  # a kill never overtakes the registration
         self.controller.call("kill_actor", actor_id=actor_id,
                              no_restart=no_restart)
         self._actor_addr.pop(actor_id, None)

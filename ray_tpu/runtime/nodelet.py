@@ -192,7 +192,7 @@ class _TaskQueue:
     Dispatch cost must scale with work DISPATCHED, not work queued: with
     a flat deque, every task completion rescanned the entire backlog
     (100k queued no-ops drained 25x slower at full depth than near-empty
-    — measured by benchmarks/scale.py's chunk_drain_rates). Per-key
+    — measured by the many_tasks stress tier). Per-key
     deques let the dispatch loop touch only keys that have idle workers,
     a bounded look-ahead window per key, and O(1) append/pop."""
 
@@ -338,8 +338,8 @@ class Nodelet:
         self._ctrl_spill_staged: collections.deque = collections.deque()
         self._ctrl_spill_armed = False
         self._dispatch_seq = 0  # stamps pushes so workers dedupe dups
-        # spill-path observability (benchmarks/scale.py + tests assert
-        # the zero-pick_node steady state on these)
+        # spill-path observability (tests assert the zero-pick_node
+        # steady state on these)
         self.sched_counters = {"p2p_spills": 0, "controller_spills": 0,
                                "pick_node_rpcs": 0, "spill_bounces": 0,
                                "spills_received": 0}
@@ -2263,7 +2263,7 @@ class Nodelet:
             # RTPU101 flagged its handler as caller-less)
             "object_bytes": self.object_bytes,
             # scheduling-plane observability: spill-path counters + the
-            # hop histogram (benchmarks/scale.py derives spill_hops_p99)
+            # hop histogram (spill_hops_p99 derives from it)
             "sched": dict(self.sched_counters),
             "spill_hops_hist": dict(self.spill_hops_hist),
             "cluster_view": {nid: v.version

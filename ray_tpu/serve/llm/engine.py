@@ -23,8 +23,14 @@ TPU-first mechanics:
   shard by the train-side logical-axis rules and the page pool splits
   its Hkv axis over the mesh's tp axis; block tables and the decode
   carry stay replicated, so the scheduler/allocator logic below is
-  IDENTICAL in both modes and all sharding lives in __init__ + the
-  in/out_shardings of the two jits (serve/llm/sharding.py)
+  IDENTICAL in both modes and all sharding lives with the device side
+  (serve/llm/stage.py, serve/llm/sharding.py)
+
+This file is the scheduler. Everything that lives on devices (params, the
+pool, the decode carry, the compiled programs) belongs to ONE
+`StageCompute` over all layers (serve/llm/stage.py): the engine is a
+pipeline of one stage, and reaches it through `_compute_prefill`,
+`_compute_verify`, `_compute_decode` and `_fetch_tokens` only.
 
 Host/device contract: a host-blocking fetch (`np.asarray` of a device
 array) costs a device sync — the host waits for every dispatch queued
@@ -89,7 +95,6 @@ Scheduler v2 (token-budget continuous batching), on top of the above:
 from __future__ import annotations
 
 import dataclasses
-import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -98,6 +103,7 @@ import numpy as np
 
 from ...util import tracing
 from .cache import OutOfPages, PageAllocator
+from .stage import _MAX_TOP_K, StageCompute
 
 WAITING, RUNNING, FINISHED = "WAITING", "RUNNING", "FINISHED"
 # where a step's nanoseconds go (indices into LLMEngine._phase_ns, in the
@@ -255,28 +261,6 @@ class EngineConfig:
     pp_fetch_timeout_s: float = 60.0
 
 
-_MAX_TOP_K = 64
-
-
-def _device_sample(rows, temperature, top_k, rng_keys):
-    """Batched in-jit sampler: greedy when temperature == 0, else
-    temperature + (clamped) top-k categorical. rows: [B, V]."""
-    import jax
-    import jax.numpy as jnp
-
-    b = rows.shape[0]
-    greedy = jnp.argmax(rows, axis=-1)
-    scaled = rows / jnp.maximum(temperature, 1e-6)[:, None]
-    topv, _ = jax.lax.top_k(scaled, min(_MAX_TOP_K, rows.shape[-1]))
-    k_idx = jnp.clip(top_k - 1, 0, topv.shape[-1] - 1)
-    kth = topv[jnp.arange(b), k_idx]
-    masked = jnp.where((top_k[:, None] > 0) & (scaled < kth[:, None]),
-                       -jnp.inf, scaled)
-    sampled = jax.vmap(
-        lambda key, lg: jax.random.categorical(key, lg))(rng_keys, masked)
-    return jnp.where(temperature <= 0, greedy, sampled).astype(jnp.int32)
-
-
 def _bucket(n: int, buckets) -> int:
     for b in buckets:
         if n <= b:
@@ -284,47 +268,10 @@ def _bucket(n: int, buckets) -> int:
     raise ValueError(f"{n} exceeds the largest bucket {buckets[-1]}")
 
 
-def resolve_attention(model_cfg, config: "EngineConfig",
-                      sharding) -> Dict[str, str]:
-    """Which attention implementation an engine's (or stage worker's)
-    decode and prefill programs contain — the one place that states the
-    rule the ops layer applies (ops/paged_attention.py): sharded engines
-    ask for the jnp reference, a TPU backend otherwise compiles the Pallas
-    kernels, a CPU backend runs the reference. Called at construction: when
-    the decode step goes to the kernel and the kernel cannot take the page
-    pool, it raises, naming the constraint — never a quiet reference on a
-    TPU."""
-    import jax
-
-    if sharding is not None:
-        impl = "reference (tensor-parallel engine: ref_attention)"
-        return {"decode": impl, "prefill": impl}
-    if jax.default_backend() != "tpu":
-        impl = f"reference ({jax.default_backend()} backend)"
-        return {"decode": impl, "prefill": impl}
-    from ...ops.paged_attention import decode_kernel_constraint
-
-    why = decode_kernel_constraint(
-        model_cfg.head_dim_, config.page_size,
-        "bfloat16" if config.dtype == "bfloat16" else "float32")
-    if why is not None:
-        raise ValueError(
-            f"EngineConfig(model={config.model!r}, page_size="
-            f"{config.page_size}, dtype={config.dtype!r}) cannot run on "
-            f"this TPU: the paged decode kernel needs {why}")
-    return {"decode": "pallas paged_attention_decode",
-            "prefill": "pallas flash_attention (+lse merge)"}
-
-
 class LLMEngine:
     """Single-process engine. Not thread-safe except `add_request`/`abort`
     (which only touch the locked intake queue); one driver thread calls
     `step()`."""
-
-    # (layers, experts) of an expert model whose programs return routing
-    # counts packed behind their tokens; None for a dense model (and for
-    # the pipelined engine, whose stages return tokens only)
-    _moe_LE: Optional[tuple] = None
 
     def __init__(self, config: EngineConfig, params=None, mesh=None):
         self.config = config
@@ -337,90 +284,24 @@ class LLMEngine:
         self._init_host_state()
 
     def _build_compute(self, params, mesh) -> None:
-        """Device-state construction seam: model config, params, the
-        paged KV pool, the decode carry and the sharding context. The
-        pipelined engine (serve/llm/pp.py) overrides this to place each
-        layer slice in its own stage worker process; every host-side
-        scheduler structure built after it (allocator, queues, slots,
-        prefix cache) is backend-agnostic and shared verbatim."""
-        import jax
-        import jax.numpy as jnp
+        """Device-state construction seam: one StageCompute over all
+        layers, in this process. The pipelined engine (serve/llm/pp.py)
+        overrides this to place each layer slice in its own stage worker
+        process; every host-side scheduler structure built after it
+        (allocator, queues, slots, prefix cache) is backend-agnostic and
+        shared verbatim."""
+        self.compute = c = StageCompute(self.config, mesh=mesh,
+                                        params=params)
+        self.model_cfg, self.model, self.sharding = (
+            c.model_cfg, c.model, c.sharding)
+        self._attention, self._device = c.attention, c.device
+        c.step_seq = lambda: self._step_seq
 
-        from ...models.llama import LlamaModel, get_config
-        from ...util.compile_cache import enable_compile_cache
-        from .sharding import resolve_serve_mesh
-
-        enable_compile_cache()
-        config = self.config
-        dtype = jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32
-        self.model_cfg = get_config(
-            config.model, scan_layers=True, remat=False, dtype=dtype,
-            param_dtype=dtype, max_seq_len=config.max_model_len,
-            **config.model_overrides)
-        self.model = LlamaModel(self.model_cfg)
-        if self.model_cfg.num_experts:
-            self._moe_LE = (self.model_cfg.num_layers,
-                            self.model_cfg.num_experts)
-        # tensor parallelism: resolve mesh/tp BEFORE any compute so the
-        # divisibility contract fails at construction, not first dispatch
-        self.sharding = resolve_serve_mesh(mesh, tp=config.tp)
-        if self.sharding is not None:
-            self.sharding.validate(self.model_cfg)
-        self._attention = resolve_attention(self.model_cfg, config,
-                                            self.sharding)
-        dev = jax.devices()[0]
-        self._device = {"platform": dev.platform, "kind": dev.device_kind,
-                        "count": jax.device_count(), "pid": os.getpid()}
-        init_ids = jnp.zeros((1, 8), jnp.int32)
-        if self.sharding is not None:
-            # shardings first (shape-only eval): init and the page pool
-            # below materialize DIRECTLY into their sharded placement —
-            # building them unsharded first would bound the servable
-            # model by ONE chip's HBM, the exact limit tp removes
-            self._param_shardings = self.sharding.param_shardings(
-                self.model, init_ids)
-            self._kv_sharding = self.sharding.kv_pages_sharding()
-            self._repl_sharding = self.sharding.replicated()
-        if params is None:
-            import flax.linen as nn
-
-            def init_params(rng):
-                return nn.meta.unbox(
-                    self.model.init(rng, init_ids)["params"])
-
-            if self.sharding is not None:
-                init_params = jax.jit(
-                    init_params, out_shardings=self._param_shardings)
-            params = init_params(jax.random.PRNGKey(config.seed))
-        elif self.sharding is not None:
-            # provided params (checkpoint leaves): place shard-by-shard
-            params = self.sharding.shard_params(params,
-                                                self._param_shardings)
-        self.params = params
-
-        cfg_m = self.model_cfg
-        L = cfg_m.num_layers
-        # page-major combined layout [L, P, Hkv, page, 2*D]: one decode
-        # DMA per page moves K and V for every head together; the Hkv
-        # axis is the tensor-parallel shard (each tp shard holds Hkv/tp
-        # heads of EVERY page, so block tables stay global + replicated)
-        shape = (L, config.num_pages, cfg_m.num_kv_heads,
-                 config.page_size, 2 * cfg_m.head_dim_)
-        if self.sharding is not None:
-            # zero-fill compiled WITH the sharding: each chip only ever
-            # allocates its Hkv/tp slice of the pool (num_pages is sized
-            # against per-shard HBM — sharding.pages_for_budget)
-            self.kv_pages = jax.jit(
-                lambda: jnp.zeros(shape, dtype),
-                out_shardings=self._kv_sharding)()
-            self.slot_ids = jax.device_put(
-                jnp.zeros((config.max_batch, 1), jnp.int32),
-                self._repl_sharding)
-        else:
-            self.kv_pages = jnp.zeros(shape, dtype)
-            # device-resident last-sampled-token per slot: the decode
-            # chain's carry (design rule 2 in the module docstring)
-            self.slot_ids = jnp.zeros((config.max_batch, 1), jnp.int32)
+    # the whole param tree and the pool, where callers have always found
+    # them (None under pp: the stage workers hold them)
+    compute: Optional[StageCompute] = None
+    params = property(lambda self: self.compute and self.compute.params)
+    kv_pages = property(lambda self: self.compute and self.compute.kv_pages)
 
     def _init_host_state(self) -> None:
         config = self.config
@@ -451,7 +332,6 @@ class LLMEngine:
         # (head request_id, times passed) — bounds prefix-aware
         # skip-ahead unfairness against one page-blocked queue head
         self._head_overtaken: tuple = (None, 0)
-        self._jit_cache: Dict[tuple, Any] = {}
         self._pending_deltas: List[OutputDelta] = []
         # the single compiled prefill row count (the MOST rows a prefill
         # dispatch has; it computes its real rows only) — one expression,
@@ -481,7 +361,12 @@ class LLMEngine:
             "steps_total", "prefill_dispatches_total",
             "decode_dispatches_total", "prefill_tokens_total",
             "prefill_padded_tokens_total", "decode_rows_total",
-            "decode_ctx_tokens_total", "programs_built_total"), 0)
+            "decode_ctx_tokens_total"), 0)
+        # (layers, experts) of an expert model, whose programs return
+        # routing counts packed behind their tokens; None for a dense one
+        cfg_m = self.model_cfg
+        self._moe_LE = ((cfg_m.num_layers, cfg_m.num_experts)
+                        if cfg_m.num_experts else None)
         if self._moe_LE:
             self._totals.update(moe_assignments_total=0,
                                 moe_experts_touched_total=0)
@@ -775,218 +660,20 @@ class LLMEngine:
 
     # ---------------------------------------------------------- compute
 
-    def _jit(self, kind: str, shape_key: tuple):
-        """Build (once per bucketed shape) the jitted prefill/decode fns."""
-        import jax
-        import jax.numpy as jnp
+    # The compute seams + the harvest fetch: everything the scheduler
+    # knows about the compute backend. The base engine enqueues its one
+    # stage's programs (stage.py: OPERANDS names what each takes); the
+    # pipelined engine (pp.py) overrides these to push frames through the
+    # stage DAG and returns CompiledDAGRef handles instead of device
+    # arrays.
 
-        from ...models.llama import PagedCache
-
-        key = (kind,) + shape_key
-        fn = self._jit_cache.get(key)
-        if fn is not None:
-            return fn
-        self._totals["programs_built_total"] += 1
-        tracing.record("engine.program_built", (
-            kind, shape_key, tracing.now_ns(), self._step_seq))
-        model = self.model
-        L = self.model_cfg.num_layers
-        # sharded engines trace under GSPMD, where the single-device
-        # Pallas kernels cannot run: pin the reference attention paths
-        # via the cache's STATIC field (part of each jit's cache key)
-        ref_attn = self.sharding is not None
-        moe = self._moe_LE is not None
-
-        def apply(params, ids, positions, pc, total_lens):
-            """model.apply -> (logits, cache, routing counts). An expert
-            model is told which rows and positions are real (the padding
-            of a wave, idle decode slots: exactly what `paged_write`
-            drops) and hands back its [L, E] int32 count of real
-            assignments per expert; a dense model's call is what it was."""
-            if not moe:
-                logits, new_pc = model.apply(
-                    {"params": params}, ids, positions=positions,
-                    kv_caches=pc)
-                return logits, new_pc, None
-            (logits, new_pc), sown = model.apply(
-                {"params": params}, ids, positions=positions, kv_caches=pc,
-                token_mask=positions < total_lens[:, None],
-                mutable=["routing"])
-            return logits, new_pc, sown["routing"]["layers"]["layer"][
-                "moe"]["expert_counts"]
-
-        def pack(tokens, counts):
-            """The program's host-bound result: the tokens, and for an
-            expert model the counts behind them in ONE int32 array, so
-            the harvest's single fetch brings both (`_split_counts`)."""
-            if counts is None:
-                return tokens
-            return jnp.concatenate([tokens.reshape(-1).astype(jnp.int32),
-                                    counts.reshape(-1)])
-
-        if kind == "prefill":
-            # ctx_pages buckets to {0, full}: a fresh-prompt wave (the
-            # common case) compiles with NO prefix part — zero page
-            # gathers — while any wave containing a prefix-cache hit uses
-            # the full-width variant (two shapes per length bucket)
-            cp = shape_key[2]
-
-            def run_prefill(params, kv_pages, n_rows, block_tables,
-                            total_lens, input_ids, positions, gather_idx,
-                            temperature, top_k, rng_keys):
-                # the arrays come at the wave size; the first `n_rows` are
-                # requests and only those are computed, one row a pass
-                # (the trip count is data, so every row count is this one
-                # program). The pool rides the loop's carry as it rides
-                # the layer scan's, in place.
-                def row(i, carry):
-                    kvp, rows, counts = carry
-                    bt, tot, ids, pos = (
-                        jax.lax.dynamic_slice_in_dim(a, i, 1)
-                        for a in (block_tables, total_lens, input_ids,
-                                  positions))
-                    pc = PagedCache(
-                        kv_pages=kvp,
-                        block_tables=jnp.broadcast_to(bt, (L,) + bt.shape),
-                        total_lens=jnp.broadcast_to(tot, (L,) + tot.shape),
-                        ctx_pages=cp, ref_attention=ref_attn)
-                    logits, new_pc, c = apply(params, ids, pos, pc, tot)
-                    # keep the row's sampling position only
-                    last = logits[0, gather_idx[i]].astype(jnp.float32)
-                    rows = jax.lax.dynamic_update_slice_in_dim(
-                        rows, last[None], i, 0)
-                    return (new_pc.kv_pages, rows,
-                            None if c is None else counts + c)
-
-                rb = input_ids.shape[0]
-                rows0 = jnp.zeros((rb, self.model_cfg.vocab_size),
-                                  jnp.float32)
-                counts0 = jnp.zeros(self._moe_LE, jnp.int32) if moe else None
-                kvp, rows, counts = jax.lax.fori_loop(
-                    0, n_rows, row, (kv_pages, rows0, counts0))
-                # sample ON DEVICE: only B int32 tokens cross to the host
-                # per step, never the [B, V] fp32 logits
-                tokens = _device_sample(rows, temperature, top_k, rng_keys)
-                return pack(tokens, counts), kvp
-
-            if self.sharding is not None:
-                # explicit shardings: params + pages by their specs,
-                # every host-built operand replicated; tokens come back
-                # replicated so the harvest fetch is shard-agnostic
-                repl = self._repl_sharding
-                fn = jax.jit(
-                    run_prefill, donate_argnums=(1,),
-                    in_shardings=(self._param_shardings,
-                                  self._kv_sharding) + (repl,) * 9,
-                    out_shardings=(repl, self._kv_sharding))
-            else:
-                fn = jax.jit(run_prefill, donate_argnums=(1,))
-            self._jit_cache[key] = fn
-            return fn
-
-        if kind == "verify":
-            # speculative verification: prefill-shaped (the draft is a
-            # short "prompt" continuing the sequence, attending to all
-            # earlier pages through the same ctx-merge path), but greedy
-            # tokens come back for EVERY position — the acceptance walk
-            # needs argmax-after-each-draft-token, and comparing argmax
-            # against the draft is what makes acceptance bit-exact
-            mp = self.max_pages_per_seq
-
-            def run_verify(params, kv_pages, block_tables, total_lens,
-                           input_ids, positions):
-                pc = PagedCache(
-                    kv_pages=kv_pages,
-                    block_tables=jnp.broadcast_to(
-                        block_tables, (L,) + block_tables.shape),
-                    total_lens=jnp.broadcast_to(
-                        total_lens, (L,) + total_lens.shape),
-                    ctx_pages=mp, ref_attention=ref_attn)
-                logits, new_pc, counts = apply(params, input_ids, positions,
-                                               pc, total_lens)
-                toks = jnp.argmax(logits.astype(jnp.float32), axis=-1)
-                return pack(toks.astype(jnp.int32), counts), new_pc.kv_pages
-
-            if self.sharding is not None:
-                repl = self._repl_sharding
-                fn = jax.jit(
-                    run_verify, donate_argnums=(1,),
-                    in_shardings=(self._param_shardings,
-                                  self._kv_sharding) + (repl,) * 4,
-                    out_shardings=(repl, self._kv_sharding))
-            else:
-                fn = jax.jit(run_verify, donate_argnums=(1,))
-            self._jit_cache[key] = fn
-            return fn
-
-        # decode: fixed slot-set [S] batch, K fused steps, device-carry ids
-        n_steps = shape_key[0]
-
-        def run_decode(params, kv_pages, slot_ids, block_tables,
-                       total_lens, caps, positions, override_mask,
-                       override_ids, temperature, top_k, keys_steps):
-            bt_b = jnp.broadcast_to(block_tables,
-                                    (L,) + block_tables.shape)
-            active = total_lens > 0
-            ids0 = jnp.where(override_mask[:, None], override_ids,
-                             slot_ids)
-
-            def body(carry, keys_k):
-                ids, pos, kvp, tot = carry
-                pc = PagedCache(
-                    kv_pages=kvp, block_tables=bt_b,
-                    total_lens=jnp.broadcast_to(tot, (L,) + tot.shape),
-                    ref_attention=ref_attn)
-                logits, new_pc, counts = apply(params, ids, pos, pc, tot)
-                rows = logits[:, 0].astype(jnp.float32)
-                toks = _device_sample(rows, temperature, top_k, keys_k)
-                # caps clamp: past a slot's ceiling, positions freeze at
-                # cap-1 and totals at cap, so no block-table index runs
-                # off the allocated range. NOTE the frozen row keeps
-                # re-writing position cap-1 with its (dropped-at-harvest)
-                # samples — safe only because every token a request KEEPS
-                # was appended before its cap was crossed, so no kept
-                # token's attention ever reads a post-cap overwrite.
-                # Inactive slots (total == 0) never write.
-                new_tot = jnp.where(active, jnp.minimum(tot + 1, caps),
-                                    tot)
-                new_pos = jnp.minimum(pos + 1, caps[:, None] - 1)
-                return ((toks[:, None].astype(jnp.int32), new_pos,
-                         new_pc.kv_pages, new_tot),
-                        (toks, counts))
-
-            carry = (ids0, positions, kv_pages, total_lens)
-            (last_ids, _, kvp, _), (toks, counts) = jax.lax.scan(
-                body, carry, keys_steps, length=n_steps)
-            # carry the last sampled token forward for ACTIVE slots only:
-            # dead rows keep their (irrelevant) values instead of being
-            # scribbled with garbage samples
-            new_slot_ids = jnp.where(active[:, None], last_ids, slot_ids)
-            return pack(toks, counts), new_slot_ids, kvp
-
-        if self.sharding is not None:
-            repl = self._repl_sharding
-            fn = jax.jit(
-                run_decode, donate_argnums=(1, 2),
-                in_shardings=(self._param_shardings, self._kv_sharding,
-                              repl) + (repl,) * 9,
-                out_shardings=(repl, repl, self._kv_sharding))
-        else:
-            fn = jax.jit(run_decode, donate_argnums=(1, 2))
-        self._jit_cache[key] = fn
-        return fn
-
-    # The three compute seams + the harvest fetch: everything the
-    # scheduler knows about the compute backend. The base engine runs
-    # in-process jits against self.kv_pages/self.slot_ids; the pipelined
-    # engine (pp.py) overrides these to push frames through the stage
-    # DAG and returns CompiledDAGRef handles instead of device arrays.
-
-    # rows a prefill program computes for a dispatch of `n` requests:
-    # run_prefill loops over the real ones. The pipelined engine's stage
-    # programs compute whole frames (pp.py overrides this).
-    def _prefill_rows(self, n: int) -> int:
-        return n
+    @staticmethod
+    def _to_host_async(tokens):
+        try:
+            tokens.copy_to_host_async()
+        except Exception:  # noqa: BLE001  # rtpulint: ignore[RTPU006] — optional D2H prefetch: CPU backends lack it; harvest blocks on the array either way
+            pass
+        return tokens
 
     def _compute_prefill(self, sb, rb, cp, n_rows, bt, total, ids,
                          positions, gather, temp, topk, keys):
@@ -995,16 +682,19 @@ class LLMEngine:
         resolve via _fetch_tokens ([rb] int32)."""
         import jax.numpy as jnp
 
-        fn = self._jit("prefill", (sb, rb, cp))
-        tokens, self.kv_pages = fn(
-            self.params, self.kv_pages, np.int32(n_rows), jnp.asarray(bt),
+        return self._to_host_async(self.compute.run(
+            "prefill", (sb, rb, cp), np.int32(n_rows), jnp.asarray(bt),
             jnp.asarray(total), jnp.asarray(ids), jnp.asarray(positions),
-            jnp.asarray(gather), temp, topk, keys)
-        try:
-            tokens.copy_to_host_async()
-        except Exception:  # noqa: BLE001  # rtpulint: ignore[RTPU006] — optional D2H prefetch: CPU backends lack it; harvest blocks on the array either way
-            pass
-        return tokens
+            jnp.asarray(gather), temp, topk, keys))
+
+    def _compute_verify(self, sb, rb, n_rows, bt, total, ids, positions):
+        """One speculative verify dispatch over the first `n_rows`;
+        returns the handle of every position's argmax ([rb, sb] int32)."""
+        import jax.numpy as jnp
+
+        return self._to_host_async(self.compute.run(
+            "verify", (sb, rb), np.int32(n_rows), jnp.asarray(bt),
+            jnp.asarray(total), jnp.asarray(ids), jnp.asarray(positions)))
 
     def _compute_decode(self, k_steps, mp, bt, total, caps, positions,
                         override_mask, override_ids, temp, topk,
@@ -1013,18 +703,11 @@ class LLMEngine:
         returns the tokens handle ([K, S] int32 after _fetch_tokens)."""
         import jax.numpy as jnp
 
-        fn = self._jit("decode", (k_steps, mp))
-        toks, self.slot_ids, self.kv_pages = fn(
-            self.params, self.kv_pages, self.slot_ids,
-            jnp.asarray(bt), jnp.asarray(total), jnp.asarray(caps),
-            jnp.asarray(positions), jnp.asarray(override_mask),
-            jnp.asarray(override_ids), temp, topk,
-            jnp.asarray(keys_steps))
-        try:
-            toks.copy_to_host_async()
-        except Exception:  # noqa: BLE001  # rtpulint: ignore[RTPU006] — optional D2H prefetch: CPU backends lack it; harvest blocks on the array either way
-            pass
-        return toks
+        return self._to_host_async(self.compute.run(
+            "decode", (k_steps, mp), jnp.asarray(bt), jnp.asarray(total),
+            jnp.asarray(caps), jnp.asarray(positions),
+            jnp.asarray(override_mask), jnp.asarray(override_ids), temp,
+            topk, jnp.asarray(keys_steps)))
 
     def _fetch_tokens(self, handle) -> np.ndarray:
         """Resolve a compute handle into host tokens (blocks until the
@@ -1150,11 +833,11 @@ class LLMEngine:
         # programs warmup() has to build, each about a second of tracing
         # on the host, and an unwarmed shape hit mid-traffic is a
         # multi-second TTFT spike). The program computes the group's rows
-        # only (run_prefill), so the padding rows below cost an upload
-        # and no compute; `computed` is what the records count
+        # only (stage.py: row_pass), so the padding rows below cost an
+        # upload and no compute; `computed` is what the records count
         with tracing.region("rtpu.engine.dispatch_prefill") as r:
             rb = self._wave_rb
-            computed = self._prefill_rows(len(group))
+            computed = len(group)
             ids = np.zeros((rb, sb), np.int32)
             positions = np.zeros((rb, sb), np.int32)
             bt = np.zeros((rb, self.max_pages_per_seq), np.int32)
@@ -1243,8 +926,6 @@ class LLMEngine:
         in flight (drafting needs the host-known tail of the sequence).
         Returns False when no slot qualifies — the normal fused decode
         then covers everything."""
-        import jax.numpy as jnp
-
         cfg = self.config
         L = int(cfg.spec_lookahead)
         if L <= 0:
@@ -1284,9 +965,8 @@ class LLMEngine:
                 break
         if not rows:
             return False
-        # verify computes all `_wave_rb` rows, padding included: it is
-        # off by default and no benchmark cell runs it, so run_prefill's
-        # loop over the real rows has not been brought here
+        # the arrays come at the wave size and the program computes the
+        # real rows only, as a prefill's does
         rb = self._wave_rb
         sb = _bucket(L + 1, cfg.prefill_buckets)
         ids = np.zeros((rb, sb), np.int32)
@@ -1314,18 +994,11 @@ class LLMEngine:
             req.planned_out += n + 1  # optimistic; rolled back at harvest
             req.spec_inflight = True
             self._spec_drafted_total += n
-        fn = self._jit("verify", (sb, rb))
         dispatch_ns = tracing.now_ns()
-        toks, self.kv_pages = fn(
-            self.params, self.kv_pages, jnp.asarray(bt),
-            jnp.asarray(total_arr), jnp.asarray(ids),
-            jnp.asarray(positions))
-        try:
-            toks.copy_to_host_async()
-        except Exception:  # noqa: BLE001  # rtpulint: ignore[RTPU006] — optional D2H prefetch: CPU backends lack it; harvest blocks on the array either way
-            pass
-        self._enqueue("spec", toks, dispatch_ns, rb, rb * sb, facts,
-                      rows=recs)
+        toks = self._compute_verify(sb, rb, len(rows), bt, total_arr, ids,
+                                    positions)
+        self._enqueue("spec", toks, dispatch_ns, len(rows), len(rows) * sb,
+                      facts, rows=recs)
         return True
 
     def _decode_eligible(self) -> List[Request]:
@@ -1542,18 +1215,19 @@ class LLMEngine:
     def _split_counts(self, rec: dict, fetched: np.ndarray) -> tuple:
         """(tokens, the record's `moe_*` fields). An expert model's
         program returns its tokens and, behind them, the [steps, L, E]
-        count of real assignments per expert (`_jit`: pack); a dense
+        count of real assignments per expert (stage.py: pack); a dense
         model's returns the tokens and the record gains nothing."""
         if self._moe_LE is None:
             return fetched, ()
-        rows = rec["rows_padded"]
         n = rec["k"] * self._moe_LE[0] * self._moe_LE[1]
         counts = fetched[-n:].reshape((-1,) + self._moe_LE)
-        # a prefill returns a token per row of the wave-sized arrays and
-        # ONE count over the rows it computed (an expert a wave touches
-        # in two rows counts once: the least a wave has to read)
-        tokens = fetched[:-n].reshape({"prefill": (-1,), "spec": (rows, -1),
-                                       "decode": (-1, rows)}[rec["kind"]])
+        # a prefill or a verify returns tokens for every row of the
+        # wave-sized arrays and ONE count over the rows it computed (an
+        # expert a wave touches in two rows counts once: the least a wave
+        # has to read)
+        tokens = fetched[:-n].reshape({
+            "prefill": (-1,), "spec": (self._wave_rb, -1),
+            "decode": (-1, self.config.max_batch)}[rec["kind"]])
         assignments, touched = int(counts.sum()), int((counts > 0).sum())
         self._totals["moe_assignments_total"] += assignments
         self._totals["moe_experts_touched_total"] += touched
@@ -1715,14 +1389,13 @@ class LLMEngine:
     # ------------------------------------------- prefill/decode handoff
 
     def _gather_kv(self, req: Request) -> Dict[str, Any]:
-        idx = np.asarray(req.pages, np.int32)
         now = time.monotonic()
         disp = req.dispatched_t if req.dispatched_t is not None \
             else req.arrival_t
         return {
             # [L, n_pages, Hkv, page, 2*D] — page axis 1 in the combined
             # page-major layout; both disagg engines must agree on it
-            "kv": np.asarray(self.kv_pages[:, idx]),
+            "kv": self.compute.read_pages(req.pages),
             "prompt_ids": list(req.prompt_ids),
             "output_ids": list(req.output_ids),
             # TTFT split for the disagg router: time queued before the
@@ -1787,8 +1460,6 @@ class LLMEngine:
         """Admit the oldest queued injection if batch slots + pages allow
         (called from step(), before fresh-prompt admission — transferred
         requests already paid for their prefill)."""
-        import jax.numpy as jnp
-
         with self._intake_lock:
             if not self._injections:
                 return False
@@ -1805,17 +1476,7 @@ class LLMEngine:
         # still in flight must land first or its writes are lost
         self._drain_pipeline(deltas)
         pages = self.allocator.allocate(n)
-        idx = jnp.asarray(np.asarray(pages, np.int32))
-        self.kv_pages = self.kv_pages.at[:, idx].set(
-            jnp.asarray(handoff["kv"], self.kv_pages.dtype))
-        if self.sharding is not None:
-            # the eager scatter may come back with a propagated (not
-            # necessarily Hkv-split) sharding; pin it before the next
-            # donated dispatch
-            import jax
-
-            self.kv_pages = jax.device_put(self.kv_pages,
-                                           self._kv_sharding)
+        self.compute.write_pages(pages, handoff["kv"])
         req = Request(request_id, list(handoff["prompt_ids"]), sampling)
         req.output_ids = list(handoff["output_ids"])
         req.pages = pages
@@ -1840,80 +1501,30 @@ class LLMEngine:
 
     # ----------------------------------------------------------- warmup
 
-    def _dummy_args(self, kind: str, shape_key: tuple) -> tuple:
-        """Masked operands for one dispatch of the given program, after
-        (params, kv_pages[, slot_ids]): total_lens=0 masks every page
-        write, so running them leaves engine state untouched. Shared by
-        warmup and program_text."""
-        import jax.numpy as jnp
-
-        mp = self.max_pages_per_seq
-
-        def z(shape, dtype=np.int32):
-            return jnp.asarray(np.zeros(shape, dtype))
-
-        if kind == "prefill":
-            sb, rb, _cp = shape_key
-            # no real row: the program's loop makes no pass
-            return (np.int32(0), z((rb, mp)), z((rb,)), z((rb, sb)),
-                    z((rb, sb)), z((rb,)), np.zeros((rb,), np.float32),
-                    np.zeros((rb,), np.int32), np.zeros((rb, 2), np.uint32))
-        if kind == "verify":
-            sbv, rb = shape_key
-            return (z((rb, mp)), z((rb,)), z((rb, sbv)), z((rb, sbv)))
-        k_steps, mp = shape_key
-        S = self.config.max_batch
-        return (z((S, mp)), z((S,)), jnp.asarray(np.ones((S,), np.int32)),
-                z((S, 1)), z((S,), bool), z((S, 1)),
-                np.zeros((S,), np.float32), np.zeros((S,), np.int32),
-                z((k_steps, S, 2), np.uint32))
-
     def _decode_shape_key(self) -> tuple:
         return (max(1, int(self.config.decode_steps_per_dispatch)),
                 self.max_pages_per_seq)
 
     def program_text(self, kind: str, shape_key: tuple) -> str:
-        """The lowered (StableHLO) text of one dispatch program — what
-        chip_smoke.py reads to show that a Pallas kernel
-        (`tpu_custom_call`) is really in the program a replica runs."""
-        state = ((self.params, self.kv_pages, self.slot_ids)
-                 if kind == "decode" else (self.params, self.kv_pages))
-        return self._jit(kind, shape_key).lower(
-            *state, *self._dummy_args(kind, shape_key)).as_text()
+        """The lowered (StableHLO) text of one dispatch program."""
+        return self.compute.program_text(kind, shape_key)
 
-    def warmup(self, prompt_buckets=None, include_decode=True) -> int:
-        """Build every dispatch shape traffic can hit — one prefill
-        program per length bucket, with and without a prefix part (rows
-        are no dimension of the set: a program takes the wave size and
-        computes the rows a dispatch gives it, so every group size from
-        one request to a full wave is already here) plus the fused
-        decode chunk — by running masked dummy dispatches (_dummy_args:
-        engine state is untouched; a prefill is given no real row, so
-        its row loop makes no pass and building it costs the trace and
-        the compile or cache fetch, no device time). Serve replicas call
-        this before reporting READY: an unwarmed shape compiled under
-        live traffic is a multi-second TTFT spike. prompt_buckets=()
-        skips prefill shapes (decode-only replicas);
-        include_decode=False skips the decode chunk (prefill-only
-        replicas). Returns the number of shapes compiled. Must be called
-        with an idle pipeline (no traffic yet)."""
-        assert not self._inflight, "warmup requires an idle engine"
-        rb = self._wave_rb
-        n = 0
-        if prompt_buckets is None:
-            prompt_buckets = self.config.prefill_buckets
+    def _warmup_programs(self, prompt_buckets, include_decode) -> list:
+        """(kind, shape key) of every dispatch shape traffic can hit: one
+        prefill program per length bucket, with and without a prefix part
+        (rows are no dimension of the set: a program takes the wave size
+        and computes the rows a dispatch gives it, so every group size
+        from one request to a full wave is already here) plus the
+        decode-phase programs."""
         from itertools import product
 
-        def run(kind, key):
-            toks, self.kv_pages = self._jit(kind, key)(
-                self.params, self.kv_pages, *self._dummy_args(kind, key))
-            np.asarray(toks)
-
-        for sb, cp in product(prompt_buckets, (0, self.max_pages_per_seq)):
-            run("prefill", (sb, rb, cp))
-            n += 1
+        rb = self._wave_rb
+        if prompt_buckets is None:
+            prompt_buckets = self.config.prefill_buckets
+        programs = [("prefill", (sb, rb, cp)) for sb, cp in product(
+            prompt_buckets, (0, self.max_pages_per_seq))]
         if not include_decode:
-            return n
+            return programs
         if self.config.spec_lookahead > 0:
             # the speculative verify dispatch (decode-phase work) has ONE
             # shape: the bucket covering spec_lookahead+1 — padded rows
@@ -1921,14 +1532,23 @@ class LLMEngine:
             sbv = _bucket(min(int(self.config.spec_lookahead),
                               self.config.prefill_buckets[-1] - 1) + 1,
                           self.config.prefill_buckets)
-            run("verify", (sbv, rb))
-            n += 1
-        key = self._decode_shape_key()
-        toks, self.slot_ids, self.kv_pages = self._jit("decode", key)(
-            self.params, self.kv_pages, self.slot_ids,
-            *self._dummy_args("decode", key))
-        np.asarray(toks)
-        return n + 1
+            programs.append(("verify", (sbv, rb)))
+        return programs + [("decode", self._decode_shape_key())]
+
+    def warmup(self, prompt_buckets=None, include_decode=True) -> int:
+        """Build every dispatch shape traffic can hit by running masked
+        dummy dispatches (stage.py: dummy_operands; engine state is
+        untouched; a prefill is given no real row, so its row loop makes
+        no pass and building it costs the trace and the compile or cache
+        fetch, no device time). Serve replicas call this before reporting
+        READY: an unwarmed shape compiled under live traffic is a
+        multi-second TTFT spike. prompt_buckets=() skips prefill shapes
+        (decode-only replicas); include_decode=False skips the decode
+        chunk (prefill-only replicas). Returns the number of shapes
+        compiled. Must be called with an idle pipeline (no traffic yet)."""
+        assert not self._inflight, "warmup requires an idle engine"
+        return self.compute.warmup(
+            self._warmup_programs(prompt_buckets, include_decode))
 
     # ------------------------------------------------------------ stats
 
@@ -1949,6 +1569,8 @@ class LLMEngine:
             **self.allocator.stats,
             # the flight recorder's facts, cumulative (rtpu_llm_*_total)
             **self._totals,
+            "programs_built_total": (self.compute.programs_built
+                                     if self.compute else 0),
             "queue_wait_s_total": self._queue_wait_ns_total / 1e9,
         }
         if self.sharding is not None:

@@ -20,8 +20,10 @@ LLMEngine and overrides only the compute seams:
 
 - ``_build_compute``: spawn stage workers, broadcast the checkpoint down
   the PR-16 replica ladder, compile the stage DAG;
-- ``_compute_prefill`` / ``_dispatch_decode_chunk``: dispatch microbatch
-  FRAMES down the DAG instead of local jits;
+- ``_compute_prefill`` / ``_compute_decode``: dispatch microbatch FRAMES
+  down the DAG instead of enqueueing a local stage's programs (every
+  stage worker runs the SAME programs as the single engine, stage.py),
+  and ``_decode_eligible`` picks one slot group a frame;
 - ``_fetch_tokens``: resolve CompiledDAGRef results, converting a dead
   stage rank into a TYPED ActorDiedError/GetTimeoutError (a SIGKILLed
   rank writes no sentinel, so the fetch would otherwise be an untyped
@@ -57,37 +59,17 @@ stays inside one host (resolve_serve_mesh within the worker).
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 import numpy as np
 
 from ... import exceptions
 from ...runtime import faults
 from ...runtime.channel import ChannelClosed
-from ...util import tracing
-from .engine import EngineConfig, LLMEngine, _device_sample
-from .sharding import CHIPS_PER_HOST
-
-
-def stage_params(full_params: Dict[str, Any], stage: int, pp: int,
-                 num_layers: int) -> Dict[str, Any]:
-    """One stage's slice of a full LlamaModel param tree: a
-    [num_layers/pp]-length slice of every stacked "layers" leaf, plus
-    the embed table on stage 0 and final_norm + lm_head on the last
-    stage. Literal slices — no reshaping, no renaming — which is what
-    makes the pipelined forward bit-exact against the single engine."""
-    import jax
-
-    per = num_layers // pp
-    lo, hi = stage * per, (stage + 1) * per
-    out: Dict[str, Any] = {
-        "layers": jax.tree.map(lambda a: a[lo:hi], full_params["layers"])}
-    if stage == 0:
-        out["embed"] = full_params["embed"]
-    if stage == pp - 1:
-        out["final_norm"] = full_params["final_norm"]
-        out["lm_head"] = full_params["lm_head"]
-    return out
+from .engine import EngineConfig, LLMEngine
+from .sharding import CHIPS_PER_HOST, ServeSharding
+from .stage import (OPERANDS, StageCompute, dummy_operands, init_params,
+                    serve_model_config)
 
 
 def broadcast_params(ref, nodes=None, fanout: int = 0) -> dict:
@@ -103,173 +85,49 @@ def broadcast_params(ref, nodes=None, fanout: int = 0) -> dict:
 
 
 class _StageWorker:
-    """One pipeline stage: an actor process owning a [L/pp]-layer param
-    slice, the matching layer slice of the paged KV pool, and (tp > 1)
-    its own single-host tp mesh. Driven through the compiled DAG —
-    ``tick`` is the per-microbatch frame handler the DAG loop calls; the
-    normal actor methods (ping/dag_stats) stay callable concurrently."""
+    """One pipeline stage: an actor process around the StageCompute of
+    its [L/pp] layers (stage.py: param slice, the matching layer slice of
+    the paged KV pool, and (tp > 1) its own single-host tp mesh). Driven
+    through the compiled DAG — ``tick`` is the per-microbatch frame
+    handler the DAG loop calls; the normal actor methods (ping/dag_stats)
+    stay callable concurrently."""
 
     def __init__(self, config: EngineConfig, stage: int):
-        import jax.numpy as jnp
-
-        from ...models.llama import StageModel, get_config
-        from ...util.compile_cache import enable_compile_cache
-        from .sharding import resolve_serve_mesh
-
-        enable_compile_cache()
-        self.config = config
         self.stage = int(stage)
-        self.pp = int(config.pp)
-        dtype = jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32
-        self.dtype = dtype
-        self.model_cfg = get_config(
-            config.model, scan_layers=True, remat=False, dtype=dtype,
-            param_dtype=dtype, max_seq_len=config.max_model_len,
-            **config.model_overrides)
-        self.n_layers = self.model_cfg.num_layers // self.pp
-        self.first = self.stage == 0
-        self.last = self.stage == self.pp - 1
-        self.model = StageModel(self.model_cfg, n_layers=self.n_layers,
-                                first=self.first, last=self.last)
+        per = serve_model_config(config).num_layers // int(config.pp)
         # tp INSIDE the stage: this worker's own process-local mesh
-        self.sharding = resolve_serve_mesh(None, tp=config.tp)
-        if self.sharding is not None:
-            self.sharding.validate(self.model_cfg)
-        from .engine import resolve_attention
-
-        resolve_attention(self.model_cfg, config, self.sharding)
-        shape = (self.n_layers, config.num_pages,
-                 self.model_cfg.num_kv_heads, config.page_size,
-                 2 * self.model_cfg.head_dim_)
-        if self.sharding is not None:
-            import jax
-
-            self._kv_sharding = self.sharding.kv_pages_sharding()
-            self._repl_sharding = self.sharding.replicated()
-            self.kv_pages = jax.jit(
-                lambda: jnp.zeros(shape, dtype),
-                out_shardings=self._kv_sharding)()
-        else:
-            self.kv_pages = jnp.zeros(shape, dtype)
-        self.params = None
-        self._param_shardings = None
-        self._jit_cache: Dict[tuple, Any] = {}
-        self.max_pages_per_seq = config.max_model_len // config.page_size
-
-    # ------------------------------------------------------------ setup
+        self.compute = StageCompute(config, self.stage * per, per)
 
     def load_params(self, full_params) -> int:
-        """Slice this stage's params out of the full tree (delivered as
-        an ObjectRef arg, resolved from the node-local broadcast
-        replica) and place them on this stage's devices."""
-        import jax
-
-        sliced = stage_params(full_params, self.stage, self.pp,
-                              self.model_cfg.num_layers)
-        cast = jax.tree.map(
-            lambda a: np.asarray(a, dtype=self.dtype), sliced)
-        if self.sharding is not None:
-            self._param_shardings = self._stage_param_shardings()
-            self.params = jax.tree.map(jax.device_put, cast,
-                                       self._param_shardings)
-        else:
-            self.params = jax.tree.map(jax.numpy.asarray, cast)
+        """`full_params` is delivered as an ObjectRef arg, resolved from
+        the node-local broadcast replica."""
+        self.compute.load(full_params)
         return self.stage
 
-    def _stage_param_shardings(self):
-        """NamedShardings for THIS stage's param slice, from the same
-        logical-axis rule table the full engine uses (the stage module
-        reuses the full model's param names/annotations, so the specs
-        line up leaf-for-leaf with the slices)."""
-        import jax.numpy as jnp
-
-        cfg = self.model_cfg
-        if self.first:
-            x0 = jnp.zeros((1, 8), jnp.int32)
-        else:
-            x0 = jnp.zeros((1, 8, cfg.hidden_size), self.dtype)
-        pos0 = jnp.zeros((1, 8), jnp.int32)
-        return self.sharding.module_param_shardings(
-            self.model, x0, pos0, None)
-
-    # ---------------------------------------------------------- compute
-
-    def _jit(self, kind: str, shape_key: tuple):
-        import jax
-        import jax.numpy as jnp
-
-        from ...models.llama import PagedCache
-
-        key = (kind,) + shape_key
-        fn = self._jit_cache.get(key)
-        if fn is not None:
-            return fn
-        model = self.model
-        Ls = self.n_layers
-        last = self.last
-        ref_attn = self.sharding is not None
-        cp = shape_key[2] if kind == "prefill" else 0
-
-        def run(params, kv_pages, block_tables, total_lens, x, positions,
-                gather_idx, temperature, top_k, rng_keys):
-            pc = PagedCache(
-                kv_pages=kv_pages,
-                block_tables=jnp.broadcast_to(
-                    block_tables, (Ls,) + block_tables.shape),
-                total_lens=jnp.broadcast_to(total_lens,
-                                            (Ls,) + total_lens.shape),
-                ctx_pages=cp, ref_attention=ref_attn)
-            out, new_pc = model.apply({"params": params}, x,
-                                      positions, pc)
-            if last:
-                # sample ON the last stage: only int32 tokens ride the
-                # return channel, exactly like the single engine's
-                # device-side sampling keeps logits off the host
-                b = out.shape[0]
-                if kind == "prefill":
-                    rows = out[jnp.arange(b), gather_idx]
-                else:
-                    rows = out[:, 0]
-                out = _device_sample(rows.astype(jnp.float32),
-                                     temperature, top_k, rng_keys)
-            return out, new_pc.kv_pages
-
-        if self.sharding is not None:
-            repl = self._repl_sharding
-            fn = jax.jit(
-                run, donate_argnums=(1,),
-                in_shardings=(self._param_shardings,
-                              self._kv_sharding) + (repl,) * 8,
-                out_shardings=(repl, self._kv_sharding))
-        else:
-            fn = jax.jit(run, donate_argnums=(1,))
-        self._jit_cache[key] = fn
-        return fn
-
     def tick(self, frame: dict) -> dict:
-        """One microbatch through this stage. Prefill frames carry
-        [rb, sb] token ids (stage 0) / hidden states (later stages);
-        decode frames carry the full [S, 1] slot set with only the
-        frame's slot group active (total == 0 rows never write). The
-        last stage samples and returns a slim {kind, toks} frame."""
+        """One microbatch through this stage. A frame is the program's
+        kind, shape key and operands by name (stage.py: OPERANDS), "x"
+        being [rows, span] token ids into the first stage and hidden
+        states after it; decode frames carry the full [S, 1] slot set
+        with only the frame's slot group active (total == 0 rows never
+        write). The last stage samples and returns a slim {kind, toks}
+        frame; an expert model's routing counts ride the frame stage by
+        stage and leave behind the tokens, in layer order."""
         faults.syncpoint("serve.pp_tick")
-        import jax.numpy as jnp
-
         kind = frame["kind"]
-        if kind == "prefill":
-            shape_key = (frame["sb"], frame["rb"], frame["cp"])
-        else:
-            shape_key = (1, self.max_pages_per_seq, 0)
-        fn = self._jit(kind, shape_key)
-        x = frame.pop("ids") if self.first else frame.pop("x")
-        out, self.kv_pages = fn(
-            self.params, self.kv_pages, jnp.asarray(frame["bt"]),
-            jnp.asarray(frame["total"]), jnp.asarray(x),
-            jnp.asarray(frame["positions"]), jnp.asarray(frame["gather"]),
-            jnp.asarray(frame["temp"]), jnp.asarray(frame["topk"]),
-            jnp.asarray(frame["keys"]))
-        if self.last:
-            return {"kind": kind, "toks": np.asarray(out)}
+        out = self.compute.run(kind, frame["key"],
+                               *(frame[name] for name in OPERANDS[kind]))
+        counts = frame.get("counts", [])
+        if self.compute.last:
+            toks = np.asarray(out)
+            if counts:
+                own = (self.compute.n_layers
+                       * self.compute.model_cfg.num_experts)
+                toks = np.concatenate([toks[:-own], *counts, toks[-own:]])
+            return {"kind": kind, "toks": toks}
+        if self.compute.model_cfg.num_experts:
+            out, own = out
+            frame["counts"] = [*counts, np.asarray(own).reshape(-1)]
         frame["x"] = np.asarray(out)
         return frame
 
@@ -318,7 +176,7 @@ class PipelinedEngine(LLMEngine):
         import jax
         import jax.numpy as jnp
 
-        from ...models.llama import LlamaModel, get_config
+        from ...models.llama import LlamaModel
 
         config = self.config
         pp = int(config.pp)
@@ -350,23 +208,14 @@ class PipelinedEngine(LLMEngine):
                 f"tp={config.tp} exceeds the {CHIPS_PER_HOST} chips one "
                 f"host exposes; scale further with pp (stages multiply "
                 f"tp, they do not widen it)")
-        dtype = jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32
-        self.model_cfg = get_config(
-            config.model, scan_layers=True, remat=False, dtype=dtype,
-            param_dtype=dtype, max_seq_len=config.max_model_len,
-            **config.model_overrides)
+        self.model_cfg = serve_model_config(config)
         L = self.model_cfg.num_layers
         if L % pp:
             raise ValueError(
                 f"pp={pp} must divide num_layers={L} (ragged stage "
                 f"splits are not supported)")
         if config.tp > 1:
-            if self.model_cfg.num_kv_heads % config.tp \
-                    or self.model_cfg.num_heads % config.tp:
-                raise ValueError(
-                    f"tp={config.tp} must divide num_kv_heads="
-                    f"{self.model_cfg.num_kv_heads} and num_heads="
-                    f"{self.model_cfg.num_heads}")
+            ServeSharding(mesh=None, tp=config.tp).validate(self.model_cfg)
         self.model = LlamaModel(self.model_cfg)
         # the driver holds NO device state: stages own the params and
         # the KV pool; the scheduler's page ids are global bookkeeping
@@ -374,8 +223,6 @@ class PipelinedEngine(LLMEngine):
         self._attention = {"decode": "per stage worker",
                            "prefill": "per stage worker"}
         self._device = None  # the scheduler process holds no device state
-        self.kv_pages = None
-        self.slot_ids = None
         self._pp = pp
         # decode slot groups = the microbatch supply that fills the
         # pipeline; 2(S-1) is the classic fill+drain bound
@@ -383,6 +230,9 @@ class PipelinedEngine(LLMEngine):
             or max(2, 2 * (pp - 1))
         config.pipeline_depth = max(int(config.pipeline_depth),
                                     self._pp_microbatches, 2 * (pp - 1))
+        # a slot's next id is sampled on the last stage and embedded on
+        # the first: one step a dispatch
+        config.decode_steps_per_dispatch = 1
         self._pp_next_group = 0
         self._pp_ticks = 0
 
@@ -390,14 +240,9 @@ class PipelinedEngine(LLMEngine):
         # (same seed, same module) — the parity anchor. Kept as host
         # numpy only long enough to broadcast + slice.
         if params is None:
-            import flax.linen as nn
-
-            params = nn.meta.unbox(self.model.init(
-                jax.random.PRNGKey(config.seed),
-                jnp.zeros((1, 8), jnp.int32))["params"])
-        params_np = jax.tree.map(np.asarray, params)
-        self.params = None
-        self._spawn_stages(params_np)
+            params = init_params(self.model, jnp.zeros((1, 8), jnp.int32),
+                                 jax.random.PRNGKey(config.seed))
+        self._spawn_stages(jax.tree.map(np.asarray, params))
         self._build_dag()
 
     # ------------------------------------------------------------- gang
@@ -455,91 +300,49 @@ class PipelinedEngine(LLMEngine):
         self._pp_ticks += 1
         return self._cdag.execute(frame)
 
-    def _prefill_rows(self, n: int) -> int:
-        # the stage programs compute whole frames: a frame crosses the
-        # stage channels at ONE shape per length bucket, padding rows
-        # included. The single-process engine's row loop is not brought
-        # here while pp has run on no chip (ROADMAP A5): the hidden
-        # states between stages would have to skip the padding too
-        return self._wave_rb
+    @staticmethod
+    def _frame(kind: str, key: tuple, operands) -> dict:
+        """A program's kind, shape key and operands by name, on the host:
+        what crosses the stage channels."""
+        return dict(zip(OPERANDS[kind], map(np.asarray, operands)),
+                    kind=kind, key=key)
 
-    def _compute_prefill(self, sb, rb, cp, n_rows, bt, total, ids,
-                         positions, gather, temp, topk, keys):
-        frame = {
-            "kind": "prefill", "sb": sb, "rb": rb, "cp": cp,
-            "ids": np.asarray(ids), "bt": np.asarray(bt),
-            "total": np.asarray(total),
-            "positions": np.asarray(positions),
-            "gather": np.asarray(gather), "temp": np.asarray(temp),
-            "topk": np.asarray(topk), "keys": np.asarray(keys),
-        }
-        return self._dag_execute(frame)
+    def _compute_prefill(self, sb, rb, cp, *operands):
+        return self._dag_execute(
+            self._frame("prefill", (sb, rb, cp), operands))
 
-    def _dispatch_decode_chunk(self) -> bool:
-        """Dispatch ONE decode microbatch frame: the next slot group
+    def _compute_decode(self, k_steps, mp, *operands):
+        return self._dag_execute(
+            self._frame("decode", (k_steps, mp), operands))
+
+    def _decode_eligible(self) -> List:
+        """The slots of ONE decode microbatch frame: the next slot group
         (slot % pp_microbatches) with harvested-and-ready slots. A
         slot's next input token is the previous tick's output, so a
         slot is eligible only when nothing of its is in flight
         (planned_out == len(output_ids)); group rotation keeps up to
         pp_microbatches independent frames filling the stage pipeline.
         Frames carry the full [S] slot set (single compile shape, like
-        the base engine) with only the group's slots active."""
-        cfg = self.config
-        S = cfg.max_batch
-        elig = [r for r in self._decode_eligible()
-                if r.planned_out == len(r.output_ids)]
-        if not elig:
-            return False
-        elig = self._reserve_decode_pages(elig, 1)
-        if not elig:
-            return False
+        the base engine) with only the group's slots active. There is no
+        cross-frame device carry under pp, so EVERY tick feeds the
+        host-known last token: the base engine's first-decode override
+        is the steady state here."""
         M = self._pp_microbatches
         groups: Dict[int, List] = {}
-        for r in elig:
-            groups.setdefault(r.slot % M, []).append(r)
+        for r in super()._decode_eligible():
+            if r.planned_out == len(r.output_ids):
+                groups.setdefault(r.slot % M, []).append(r)
         for off in range(M):
             g = (self._pp_next_group + off) % M
             if g in groups:
                 break
         else:
-            return False
+            return []
         self._pp_next_group = (g + 1) % M
-        rows = groups[g]
-        mp = self.max_pages_per_seq
-        ids = np.zeros((S, 1), np.int32)
-        bt = np.zeros((S, mp), np.int32)
-        total = np.zeros((S,), np.int32)
-        positions = np.zeros((S, 1), np.int32)
-        chunk_slots = {}
-        facts = []
-        for req in rows:
-            s = req.slot
-            planned_total = len(req.prompt_ids) + req.planned_out
-            bt[s, :len(req.pages)] = req.pages
-            total[s] = planned_total
-            positions[s, 0] = planned_total - 1
-            # no cross-frame device carry under pp: EVERY tick feeds the
-            # host-known last token (the base engine's override is the
-            # first-decode special case; here it is the steady state)
-            if s in self._slot_override:
-                ids[s, 0] = self._slot_override.pop(s)
-            else:
-                ids[s, 0] = req.output_ids[-1]
-            chunk_slots[s] = (req.request_id, req.planned_out)
-            facts.append((req.request_id, 1, planned_total))
-        temp, topk, keys = self._sampling_arrays(
-            rows, S, slot_layout=True, base="planned")
-        for req in rows:
-            req.planned_out += 1
-        frame = {
-            "kind": "decode", "ids": ids, "bt": bt, "total": total,
-            "positions": positions, "gather": np.zeros((S,), np.int32),
-            "temp": temp, "topk": topk, "keys": keys,
-        }
-        dispatch_ns = tracing.now_ns()
-        ref = self._dag_execute(frame)
-        self._enqueue_decode(ref, dispatch_ns, 1, facts, chunk_slots)
-        return True
+        for r in groups[g]:
+            self._slot_override[r.slot] = (
+                r.output_ids[-1] if r.output_ids else r.prompt_ids[-1])
+        return groups[g]
 
     def _fetch_tokens(self, handle) -> np.ndarray:
         if isinstance(handle, np.ndarray):
@@ -550,11 +353,7 @@ class PipelinedEngine(LLMEngine):
             raise
         except (TimeoutError, ChannelClosed) as err:
             raise self._stage_failure(err) from err
-        toks = frame["toks"]
-        if frame["kind"] == "decode":
-            # base harvest indexes [K, slot]
-            return np.asarray(toks)[None, :]
-        return np.asarray(toks)
+        return np.asarray(frame["toks"])
 
     def _stage_failure(self, err) -> Exception:
         """Classify a wedged fetch into a TYPED error: probe each rank
@@ -585,51 +384,18 @@ class PipelinedEngine(LLMEngine):
 
     def warmup(self, prompt_buckets=None, include_decode=True) -> int:
         """Compile every stage's dispatch shapes by pushing masked dummy
-        frames (total_lens=0: no page write lands) through the DAG —
-        the base engine's warmup touches self.params/self._jit, which a
+        frames (total_lens=0: no page write lands) through the DAG — the
+        base engine's warmup runs its own stage's programs, which a
         pipelined driver does not have. Serially: each frame is fetched
         before the next dispatch, so warmup never trips the in-flight
         bound."""
         assert not self._inflight, "warmup requires an idle engine"
-        S = self.config.max_batch
-        rb = self._wave_rb
-        mp = self.max_pages_per_seq
-        if prompt_buckets is None:
-            prompt_buckets = self.config.prefill_buckets
-        from itertools import product
-
-        n = 0
-        for sb, cp in product(prompt_buckets, (0, mp)):
-            frame = {
-                "kind": "prefill", "sb": sb, "rb": rb, "cp": cp,
-                "ids": np.zeros((rb, sb), np.int32),
-                "bt": np.zeros((rb, mp), np.int32),
-                "total": np.zeros((rb,), np.int32),
-                "positions": np.zeros((rb, sb), np.int32),
-                "gather": np.zeros((rb,), np.int32),
-                "temp": np.zeros((rb,), np.float32),
-                "topk": np.zeros((rb,), np.int32),
-                "keys": np.zeros((rb, 2), np.uint32),
-            }
-            self._dag_execute(frame).get(
-                timeout=self.config.pp_fetch_timeout_s)
-            n += 1
-        if not include_decode:
-            return n
-        frame = {
-            "kind": "decode",
-            "ids": np.zeros((S, 1), np.int32),
-            "bt": np.zeros((S, mp), np.int32),
-            "total": np.zeros((S,), np.int32),
-            "positions": np.zeros((S, 1), np.int32),
-            "gather": np.zeros((S,), np.int32),
-            "temp": np.zeros((S,), np.float32),
-            "topk": np.zeros((S,), np.int32),
-            "keys": np.zeros((S, 2), np.uint32),
-        }
-        self._dag_execute(frame).get(
-            timeout=self.config.pp_fetch_timeout_s)
-        return n + 1
+        programs = self._warmup_programs(prompt_buckets, include_decode)
+        for kind, key in programs:
+            self._dag_execute(self._frame(
+                kind, key, dummy_operands(self.config, kind, key))).get(
+                    timeout=self.config.pp_fetch_timeout_s)
+        return len(programs)
 
     # ------------------------------------------------------------ stats
 

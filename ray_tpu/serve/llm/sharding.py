@@ -81,22 +81,18 @@ class ServeSharding:
 
         return NamedSharding(self.mesh, P())
 
-    def param_shardings(self, model, example_ids):
+    def param_shardings(self, model, example):
         """NamedShardings for the model's (unboxed) param tree, derived
-        from the logical axis annotations via the shared rule table."""
-        return self.module_param_shardings(model, example_ids)
-
-    def module_param_shardings(self, module, *example_args):
-        """`param_shardings` for an arbitrary flax module signature —
-        the pipelined engine's StageModel takes (x, positions,
-        kv_caches), not just ids, but shards by the SAME logical axis
-        annotations (its params keep the full model's names), so one
-        rule-table lowering serves both."""
+        from the logical axis annotations via the shared rule table.
+        `example` is an input to initialise shapes with: token ids, or
+        hidden states for a slice of layers that does not start the
+        model (its params keep the whole model's names and annotations,
+        so one lowering serves every slice)."""
         import flax.linen as nn
         import jax
 
         abstract = jax.eval_shape(
-            lambda: module.init(jax.random.PRNGKey(0), *example_args))
+            lambda: model.init(jax.random.PRNGKey(0), example))
         logical = nn.get_partition_spec(abstract)
         return nn.logical_to_mesh_sharding(
             logical, self.mesh, self._rules())["params"]

@@ -1,0 +1,570 @@
+"""The device side of a contiguous slice of a model's layers.
+
+`StageCompute` is everything about layers [first_layer, first_layer +
+n_layers) that lives on devices: the serving model config, the module,
+the mesh and the attention rule, the parameters' placement, that slice of
+the paged KV pool, the decode carry, the compiled programs and their
+warm-up. `LLMEngine` (engine.py) builds ONE over all layers in its own
+process: an engine is a pipeline of one stage. `_StageWorker` (pp.py)
+builds one for its slice in an actor process. Both run the same three
+program bodies below, which differ by `(first, last)` only: a first stage
+embeds token ids, a later one takes the previous stage's hidden states in
+their place; a last stage samples on the device, an earlier one hands its
+hidden states on.
+
+The operands of a program after its state (params, pool[, carry]) are
+`OPERANDS[kind]`, in order; a pipelined frame (pp.py) is those names in
+a dict. "x" is the stage's input: ids or hidden states.
+"""
+
+from __future__ import annotations
+
+import os
+from typing import Any, Dict, Optional
+
+import numpy as np
+
+from ...util import tracing
+
+OPERANDS = {
+    "prefill": ("n_rows", "bt", "total", "x", "positions", "gather",
+                "temp", "topk", "keys"),
+    "verify": ("n_rows", "bt", "total", "x", "positions"),
+    "decode": ("bt", "total", "caps", "positions", "override_mask", "x",
+               "temp", "topk", "keys"),
+}
+
+_MAX_TOP_K = 64
+
+
+def _device_sample(rows, temperature, top_k, rng_keys):
+    """Batched in-jit sampler: greedy when temperature == 0, else
+    temperature + (clamped) top-k categorical. rows: [B, V]."""
+    import jax
+    import jax.numpy as jnp
+
+    b = rows.shape[0]
+    greedy = jnp.argmax(rows, axis=-1)
+    scaled = rows / jnp.maximum(temperature, 1e-6)[:, None]
+    topv, _ = jax.lax.top_k(scaled, min(_MAX_TOP_K, rows.shape[-1]))
+    k_idx = jnp.clip(top_k - 1, 0, topv.shape[-1] - 1)
+    kth = topv[jnp.arange(b), k_idx]
+    masked = jnp.where((top_k[:, None] > 0) & (scaled < kth[:, None]),
+                       -jnp.inf, scaled)
+    sampled = jax.vmap(
+        lambda key, lg: jax.random.categorical(key, lg))(rng_keys, masked)
+    return jnp.where(temperature <= 0, greedy, sampled).astype(jnp.int32)
+
+
+def serve_dtype(config):
+    import jax.numpy as jnp
+
+    return jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32
+
+
+def serve_model_config(config):
+    """The WHOLE model's config as every serving program sees it."""
+    from ...models.llama import get_config
+
+    dtype = serve_dtype(config)
+    return get_config(
+        config.model, scan_layers=True, remat=False, dtype=dtype,
+        param_dtype=dtype, max_seq_len=config.max_model_len,
+        **config.model_overrides)
+
+
+def pool_shape(config, model_cfg, n_layers: int) -> tuple:
+    """Page-major combined layout [n_layers, P, Hkv, page, 2*D]: one
+    decode DMA per page moves K and V for every head together; the Hkv
+    axis is the tensor-parallel shard (each tp shard holds Hkv/tp heads of
+    EVERY page, so block tables stay global + replicated)."""
+    return (n_layers, config.num_pages, model_cfg.num_kv_heads,
+            config.page_size, 2 * model_cfg.head_dim_)
+
+
+def init_params(model, example, rng):
+    """Fresh parameters of `model`; the whole model's, from the engine's
+    seed, are what every engine and every stage's slice start from."""
+    import flax.linen as nn
+
+    return nn.meta.unbox(model.init(rng, example)["params"])
+
+
+def stage_params(full_params: Dict[str, Any], first_layer: int,
+                 n_layers: int) -> Dict[str, Any]:
+    """One stage's slice of a full LlamaModel param tree: `n_layers` of
+    every stacked "layers" leaf from `first_layer` on, plus the embed
+    table when the slice starts the model and final_norm + lm_head when
+    it ends it. Literal slices — no reshaping, no renaming — which is what
+    makes the pipelined forward bit-exact against the single engine."""
+    import jax
+
+    lo, hi = first_layer, first_layer + n_layers
+    leaves = jax.tree.leaves(full_params["layers"])
+    out: Dict[str, Any] = {
+        "layers": jax.tree.map(lambda a: a[lo:hi], full_params["layers"])}
+    if lo == 0:
+        out["embed"] = full_params["embed"]
+    if hi == leaves[0].shape[0]:
+        out["final_norm"] = full_params["final_norm"]
+        out["lm_head"] = full_params["lm_head"]
+    return out
+
+
+def resolve_attention(model_cfg, config, sharding) -> Dict[str, str]:
+    """Which attention implementation a stage's decode and prefill
+    programs contain — the one place that states the rule the ops layer
+    applies (ops/paged_attention.py): sharded engines ask for the jnp
+    reference, a TPU backend otherwise compiles the Pallas kernels, a CPU
+    backend runs the reference. Called at construction: when the decode
+    step goes to the kernel and the kernel cannot take the page pool, it
+    raises, naming the constraint — never a quiet reference on a TPU."""
+    import jax
+
+    if sharding is not None:
+        impl = "reference (tensor-parallel engine: ref_attention)"
+        return {"decode": impl, "prefill": impl}
+    if jax.default_backend() != "tpu":
+        impl = f"reference ({jax.default_backend()} backend)"
+        return {"decode": impl, "prefill": impl}
+    from ...ops.paged_attention import decode_kernel_constraint
+
+    why = decode_kernel_constraint(
+        model_cfg.head_dim_, config.page_size,
+        "bfloat16" if config.dtype == "bfloat16" else "float32")
+    if why is not None:
+        raise ValueError(
+            f"EngineConfig(model={config.model!r}, page_size="
+            f"{config.page_size}, dtype={config.dtype!r}) cannot run on "
+            f"this TPU: the paged decode kernel needs {why}")
+    return {"decode": "pallas paged_attention_decode",
+            "prefill": "pallas flash_attention (+lse merge)"}
+
+
+def dummy_operands(config, kind: str, shape_key: tuple,
+                   hidden: Optional[tuple] = None) -> tuple:
+    """Masked `OPERANDS[kind]` for one dispatch of a program:
+    total_lens=0 masks every page write and a row pass is given no real
+    row, so running them leaves a stage's state untouched. Shared by
+    warm-up (in process and through the stage DAG) and program_text.
+    `hidden` = (width, dtype) makes "x" a later stage's hidden states."""
+    import jax.numpy as jnp
+
+    mp = config.max_model_len // config.page_size
+
+    def z(shape, dtype=np.int32):
+        return jnp.asarray(np.zeros(shape, dtype))
+
+    def x(rows, span):
+        if hidden is None:
+            return z((rows, span))
+        return jnp.zeros((rows, span, hidden[0]), hidden[1])
+
+    if kind == "decode":
+        k_steps, mp = shape_key
+        S = config.max_batch
+        return (z((S, mp)), z((S,)), jnp.asarray(np.ones((S,), np.int32)),
+                z((S, 1)), z((S,), bool), x(S, 1),
+                np.zeros((S,), np.float32), np.zeros((S,), np.int32),
+                z((k_steps, S, 2), np.uint32))
+    sb, rb = shape_key[:2]
+    # no real row: the program's loop makes no pass
+    rows = (np.int32(0), z((rb, mp)), z((rb,)), x(rb, sb), z((rb, sb)))
+    if kind == "verify":
+        return rows
+    return rows + (z((rb,)), np.zeros((rb,), np.float32),
+                   np.zeros((rb,), np.int32), np.zeros((rb, 2), np.uint32))
+
+
+class StageCompute:
+    """Layers [first_layer, first_layer + n_layers) of `config`'s model
+    on this process's devices (n_layers None: through the last layer).
+    `params`: a tree for exactly this slice, or None: the whole model
+    initialises from the config's seed, a slice waits for `load`."""
+
+    def __init__(self, config, first_layer: int = 0,
+                 n_layers: Optional[int] = None, mesh=None, params=None):
+        import jax
+        import jax.numpy as jnp
+
+        from ...models.llama import LlamaModel
+        from ...util.compile_cache import enable_compile_cache
+        from .sharding import resolve_serve_mesh
+
+        enable_compile_cache()
+        self.config = config
+        self.model_cfg = cfg = serve_model_config(config)
+        self.dtype = dtype = serve_dtype(config)
+        if n_layers is None:
+            n_layers = cfg.num_layers - first_layer
+        self.first_layer, self.n_layers = first_layer, n_layers
+        self.first = first_layer == 0
+        self.last = first_layer + n_layers == cfg.num_layers
+        whole = self.first and self.last
+        self.model = LlamaModel(cfg) if whole else LlamaModel(
+            cfg, n_layers=n_layers, first=self.first, last=self.last)
+        self.max_pages_per_seq = config.max_model_len // config.page_size
+        # tensor parallelism: resolve mesh/tp BEFORE any compute so the
+        # divisibility contract fails at construction, not first dispatch
+        self.sharding = resolve_serve_mesh(mesh, tp=config.tp)
+        if self.sharding is not None:
+            self.sharding.validate(cfg)
+        self.attention = resolve_attention(cfg, config, self.sharding)
+        dev = jax.devices()[0]
+        self.device = {"platform": dev.platform, "kind": dev.device_kind,
+                       "count": jax.device_count(), "pid": os.getpid()}
+        example = (jnp.zeros((1, 8), jnp.int32) if self.first
+                   else jnp.zeros((1, 8, cfg.hidden_size), dtype))
+        if self.sharding is not None:
+            # shardings first (shape-only eval): init and the page pool
+            # below materialize DIRECTLY into their sharded placement —
+            # building them unsharded first would bound the servable
+            # model by ONE chip's HBM, the exact limit tp removes
+            self._param_shardings = self.sharding.param_shardings(
+                self.model, example)
+            self._kv_sharding = self.sharding.kv_pages_sharding()
+            self._repl_sharding = self.sharding.replicated()
+        if params is not None:
+            params = self._place(params)
+        elif whole:
+            def init(rng):
+                return init_params(self.model, example, rng)
+
+            if self.sharding is not None:
+                init = jax.jit(init, out_shardings=self._param_shardings)
+            params = init(jax.random.PRNGKey(config.seed))
+        self.params = params
+
+        shape = pool_shape(config, cfg, n_layers)
+        if self.sharding is not None:
+            # zero-fill compiled WITH the sharding: each chip only ever
+            # allocates its Hkv/tp slice of the pool (num_pages is sized
+            # against per-shard HBM — sharding.pages_for_budget)
+            self.kv_pages = jax.jit(
+                lambda: jnp.zeros(shape, dtype),
+                out_shardings=self._kv_sharding)()
+            self.slot_ids = jax.device_put(
+                jnp.zeros((config.max_batch, 1), jnp.int32),
+                self._repl_sharding)
+        else:
+            self.kv_pages = jnp.zeros(shape, dtype)
+            # device-resident last-sampled-token per slot: the decode
+            # chain's carry (design rule 2 in engine.py's docstring)
+            self.slot_ids = jnp.zeros((config.max_batch, 1), jnp.int32)
+        self.programs: Dict[tuple, Any] = {}
+        self.programs_built = 0
+        # the scheduler step a program is built in, for its record: the
+        # engine points this at its counter, a stage worker has none
+        self.step_seq = lambda: 0
+
+    # ----------------------------------------------------------- params
+
+    def _place(self, tree):
+        """A tree for this slice onto its devices (checkpoint leaves go
+        shard by shard)."""
+        import jax
+
+        if self.sharding is not None:
+            return self.sharding.shard_params(tree, self._param_shardings)
+        return jax.tree.map(jax.numpy.asarray, tree)
+
+    def load(self, full_params) -> None:
+        """Slice this stage's params out of the whole model's tree (under
+        pp: resolved from the node-local broadcast replica) and place
+        them."""
+        import jax
+
+        sliced = stage_params(full_params, self.first_layer, self.n_layers)
+        self.params = self._place(jax.tree.map(
+            lambda a: np.asarray(a, dtype=self.dtype), sliced))
+
+    # ------------------------------------------------------------- pool
+
+    def read_pages(self, pages) -> np.ndarray:
+        """[n_layers, len(pages), Hkv, page, 2*D] on the host. Eager: the
+        caller has drained every dispatch first."""
+        return np.asarray(self.kv_pages[:, np.asarray(pages, np.int32)])
+
+    def write_pages(self, pages, kv) -> None:
+        """Scatter transferred pages into the pool (eager, as above: an
+        eager `.at[].set` forks the buffer, so nothing may be in
+        flight)."""
+        import jax
+        import jax.numpy as jnp
+
+        idx = jnp.asarray(np.asarray(pages, np.int32))
+        self.kv_pages = self.kv_pages.at[:, idx].set(
+            jnp.asarray(kv, self.kv_pages.dtype))
+        if self.sharding is not None:
+            # the eager scatter may come back with a propagated (not
+            # necessarily Hkv-split) sharding; pin it before the next
+            # donated dispatch
+            self.kv_pages = jax.device_put(self.kv_pages,
+                                           self._kv_sharding)
+
+    # --------------------------------------------------------- programs
+
+    def program(self, kind: str, shape_key: tuple):
+        """The jitted program of one bucketed shape, built once.
+        Keys: prefill (sb, rb, cp), verify (sb, rb), decode (K, mp)."""
+        key = (kind,) + tuple(shape_key)
+        fn = self.programs.get(key)
+        if fn is None:
+            self.programs_built += 1
+            tracing.record("engine.program_built", (
+                kind, shape_key, tracing.now_ns(), self.step_seq()))
+            fn = self.programs[key] = self._build(kind, shape_key)
+        return fn
+
+    def _build(self, kind: str, shape_key: tuple):
+        import jax
+        import jax.numpy as jnp
+
+        from ...models.llama import PagedCache
+
+        model, cfg, L = self.model, self.model_cfg, self.n_layers
+        first, last = self.first, self.last
+        # sharded stages trace under GSPMD, where the single-device
+        # Pallas kernels cannot run: pin the reference attention paths
+        # via the cache's STATIC field (part of each jit's cache key)
+        ref_attn = self.sharding is not None
+        # an expert model's programs return its routing counts of this
+        # stage's layers, [L, E] a pass of the model
+        moe_LE = (L, cfg.num_experts) if cfg.num_experts else None
+
+        def apply(params, x, positions, pc, total_lens):
+            """model.apply -> (logits or hidden states, cache, routing
+            counts). An expert model is told which rows and positions are
+            real (the padding of a wave, idle decode slots: exactly what
+            `paged_write` drops) and hands back its [L, E] int32 count of
+            real assignments per expert; a dense model's call is what it
+            was."""
+            if moe_LE is None:
+                out, new_pc = model.apply(
+                    {"params": params}, x, positions=positions,
+                    kv_caches=pc)
+                return out, new_pc, None
+            (out, new_pc), sown = model.apply(
+                {"params": params}, x, positions=positions, kv_caches=pc,
+                token_mask=positions < total_lens[:, None],
+                mutable=["routing"])
+            return out, new_pc, sown["routing"]["layers"]["layer"][
+                "moe"]["expert_counts"]
+
+        def pack(kept, counts):
+            """The program's result: a last stage's tokens are
+            host-bound, and an expert model's counts go behind them in
+            ONE int32 array, so the harvest's single fetch brings both
+            (`LLMEngine._split_counts`); hidden states go to the next
+            stage with the counts beside them."""
+            if counts is None:
+                return kept
+            if not last:
+                return kept, counts
+            return jnp.concatenate([kept.reshape(-1).astype(jnp.int32),
+                                    counts.reshape(-1)])
+
+        if kind in ("prefill", "verify"):
+            sb, rb = shape_key[:2]
+            # a prefill's ctx_pages buckets to {0, full}: a fresh-prompt
+            # wave (the common case) compiles with NO prefix part — zero
+            # page gathers — while any wave containing a prefix-cache hit
+            # uses the full-width variant (two shapes per length bucket).
+            # A verify row always continues a sequence.
+            cp = shape_key[2] if kind == "prefill" else self.max_pages_per_seq
+
+            def row_pass(params, kv_pages, n_rows, block_tables, total_lens,
+                         x, positions, kept, keep):
+                """The arrays come at the wave size; the first `n_rows`
+                are requests and only those are computed, one [1 x sb]
+                pass of the model a row (the trip count is data, so every
+                row count is this one program). `keep(out, i)` is what
+                row i leaves in `kept`. The pool rides the loop's carry as
+                it rides the layer scan's, in place."""
+                def row(i, carry):
+                    kvp, kept, counts = carry
+                    bt, tot, xi, pos = (
+                        jax.lax.dynamic_slice_in_dim(a, i, 1)
+                        for a in (block_tables, total_lens, x, positions))
+                    pc = PagedCache(
+                        kv_pages=kvp,
+                        block_tables=jnp.broadcast_to(bt, (L,) + bt.shape),
+                        total_lens=jnp.broadcast_to(tot, (L,) + tot.shape),
+                        ctx_pages=cp, ref_attention=ref_attn)
+                    out, new_pc, c = apply(params, xi, pos, pc, tot)
+                    kept = jax.lax.dynamic_update_slice_in_dim(
+                        kept, keep(out, i), i, 0)
+                    return (new_pc.kv_pages, kept,
+                            None if c is None else counts + c)
+
+                counts0 = (None if moe_LE is None
+                           else jnp.zeros(moe_LE, jnp.int32))
+                return jax.lax.fori_loop(0, n_rows, row,
+                                         (kv_pages, kept, counts0))
+
+            def hidden_rows(*state_and_rows):
+                """A stage that is not last: every real row's hidden
+                states, for the next stage to read the same rows."""
+                kvp, kept, counts = row_pass(
+                    *state_and_rows,
+                    jnp.zeros((rb, sb, cfg.hidden_size), cfg.dtype),
+                    lambda out, i: out)
+                return pack(kept, counts), kvp
+
+            def run_prefill(params, kv_pages, n_rows, block_tables,
+                            total_lens, x, positions, gather_idx,
+                            temperature, top_k, rng_keys):
+                if not last:
+                    return hidden_rows(params, kv_pages, n_rows,
+                                       block_tables, total_lens, x,
+                                       positions)
+                # keep the row's sampling position only
+                kvp, rows, counts = row_pass(
+                    params, kv_pages, n_rows, block_tables, total_lens, x,
+                    positions, jnp.zeros((rb, cfg.vocab_size), jnp.float32),
+                    lambda out, i: out[0, gather_idx[i]].astype(
+                        jnp.float32)[None])
+                # sample ON DEVICE: only B int32 tokens cross to the host
+                # per step, never the [B, V] fp32 logits
+                tokens = _device_sample(rows, temperature, top_k, rng_keys)
+                return pack(tokens, counts), kvp
+
+            def run_verify(params, kv_pages, n_rows, block_tables,
+                           total_lens, x, positions):
+                # speculative verification: the draft is a short "prompt"
+                # continuing the sequence (attending to all earlier pages
+                # through the same ctx-merge path), but greedy tokens come
+                # back for EVERY position — the acceptance walk needs
+                # argmax-after-each-draft-token, and comparing argmax
+                # against the draft is what makes acceptance bit-exact
+                if not last:
+                    return hidden_rows(params, kv_pages, n_rows,
+                                       block_tables, total_lens, x,
+                                       positions)
+                kvp, toks, counts = row_pass(
+                    params, kv_pages, n_rows, block_tables, total_lens, x,
+                    positions, jnp.zeros((rb, sb), jnp.int32),
+                    lambda out, i: jnp.argmax(
+                        out.astype(jnp.float32), axis=-1).astype(jnp.int32))
+                return pack(toks, counts), kvp
+
+            return self._jit(run_prefill if kind == "prefill"
+                             else run_verify, kind, n_state=2)
+
+        # decode: fixed slot-set [S] batch, K fused steps, device-carry ids
+        n_steps = shape_key[0]
+        whole = first and last
+        if n_steps != 1 and not whole:
+            raise ValueError(
+                "a stage of a pipeline decodes one step a dispatch: the "
+                "next step's ids are sampled on another stage")
+
+        def run_decode(params, kv_pages, slot_ids, block_tables,
+                       total_lens, caps, positions, override_mask,
+                       x, temperature, top_k, keys_steps):
+            # x: the host-known ids of the slots `override_mask` names
+            # (the others' come from the carry); on a later stage, every
+            # slot's hidden states
+            bt_b = jnp.broadcast_to(block_tables,
+                                    (L,) + block_tables.shape)
+            active = total_lens > 0
+            x0 = (jnp.where(override_mask[:, None], x, slot_ids)
+                  if first else x)
+
+            def body(carry, keys_k):
+                x_k, pos, kvp, tot = carry
+                pc = PagedCache(
+                    kv_pages=kvp, block_tables=bt_b,
+                    total_lens=jnp.broadcast_to(tot, (L,) + tot.shape),
+                    ref_attention=ref_attn)
+                out, new_pc, counts = apply(params, x_k, pos, pc, tot)
+                if last:
+                    rows = out[:, 0].astype(jnp.float32)
+                    out = _device_sample(rows, temperature, top_k, keys_k)
+                # caps clamp: past a slot's ceiling, positions freeze at
+                # cap-1 and totals at cap, so no block-table index runs
+                # off the allocated range. NOTE the frozen row keeps
+                # re-writing position cap-1 with its (dropped-at-harvest)
+                # samples — safe only because every token a request KEEPS
+                # was appended before its cap was crossed, so no kept
+                # token's attention ever reads a post-cap overwrite.
+                # Inactive slots (total == 0) never write.
+                new_tot = jnp.where(active, jnp.minimum(tot + 1, caps),
+                                    tot)
+                new_pos = jnp.minimum(pos + 1, caps[:, None] - 1)
+                # the next step's ids; a stage of a pipeline has no next step
+                x_next = out[:, None].astype(jnp.int32) if whole else x_k
+                return ((x_next, new_pos, new_pc.kv_pages, new_tot),
+                        (out, counts))
+
+            carry = (x0, positions, kv_pages, total_lens)
+            (last_ids, _, kvp, _), (outs, counts) = jax.lax.scan(
+                body, carry, keys_steps, length=n_steps)
+            if not whole:
+                # a pipeline's ids come from the host every step: the
+                # carry passes through, the one step's hidden states go on
+                return pack(outs if last else outs[0], counts), slot_ids, kvp
+            # carry the last sampled token forward for ACTIVE slots only:
+            # dead rows keep their (irrelevant) values instead of being
+            # scribbled with garbage samples
+            new_slot_ids = jnp.where(active[:, None], last_ids, slot_ids)
+            return pack(outs, counts), new_slot_ids, kvp
+
+        return self._jit(run_decode, kind, n_state=3)
+
+    def _jit(self, fn, kind: str, n_state: int):
+        """jit with the state after the params donated: the pool (and the
+        decode carry) update in place. Sharded: params + pages by their
+        specs, the carry and every host-built operand replicated; results
+        come back replicated so the harvest fetch is shard-agnostic."""
+        import jax
+
+        donate = tuple(range(1, n_state))
+        if self.sharding is None:
+            return jax.jit(fn, donate_argnums=donate)
+        repl, kv = self._repl_sharding, self._kv_sharding
+        return jax.jit(
+            fn, donate_argnums=donate,
+            in_shardings=(self._param_shardings, kv) + (repl,) * (
+                n_state - 2 + len(OPERANDS[kind])),
+            out_shardings=(repl,) * (n_state - 1) + (kv,))
+
+    def _state(self, kind: str) -> tuple:
+        if kind == "decode":
+            return self.params, self.kv_pages, self.slot_ids
+        return self.params, self.kv_pages
+
+    def run(self, kind: str, shape_key: tuple, *operands):
+        """Enqueue one program over `OPERANDS[kind]`; the pool (and the
+        carry) are rebound to its donated results. Returns the result's
+        handle: tokens ([rb] prefill, [rb, sb] verify, [K, S] decode; an
+        expert model's counts behind them) or hidden states."""
+        out, *state = self.program(kind, shape_key)(
+            *self._state(kind), *operands)
+        self.kv_pages = state[-1]
+        if kind == "decode":
+            self.slot_ids = state[0]
+        return out
+
+    def dummy_args(self, kind: str, shape_key: tuple) -> tuple:
+        return dummy_operands(
+            self.config, kind, shape_key,
+            None if self.first else (self.model_cfg.hidden_size, self.dtype))
+
+    def program_text(self, kind: str, shape_key: tuple) -> str:
+        """The lowered (StableHLO) text of one dispatch program — what
+        chip_smoke.py reads to show that a Pallas kernel
+        (`tpu_custom_call`) is really in the program a replica runs."""
+        return self.program(kind, shape_key).lower(
+            *self._state(kind), *self.dummy_args(kind, shape_key)).as_text()
+
+    def warmup(self, programs) -> int:
+        """Build each (kind, shape key) by running it on masked dummy
+        operands: state is untouched, and a row pass with no real row
+        costs the trace and the compile or cache fetch, no device time."""
+        import jax
+
+        for kind, key in programs:
+            jax.block_until_ready(
+                self.run(kind, key, *self.dummy_args(kind, key)))
+        return len(programs)

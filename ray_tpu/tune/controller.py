@@ -41,6 +41,11 @@ class Trial:
     stopped_by_scheduler: bool = False
     stop_reason: Optional[str] = None
     resume_checkpoint: Optional[Checkpoint] = None
+    # the outstanding `poll` call and when it was issued: a trial whose
+    # actor is still waiting for a CPU answers late, and must not hold
+    # up the polls (and so the stops that free CPUs) of the others
+    poll_ref: Any = None
+    poll_since: float = 0.0
 
     @property
     def last_metrics(self) -> Dict[str, Any]:
@@ -193,6 +198,8 @@ class TuneController:
         return trial
 
     def run(self) -> List[Trial]:
+        import ray_tpu
+
         # restored experiments re-queue their interrupted trials
         pending: List[Trial] = [t for t in self.trials
                                 if t.status == PENDING]
@@ -221,7 +228,18 @@ class TuneController:
                 break
             time.sleep(self.poll_interval)
             changed = False
+            for trial in running:
+                if trial.poll_ref is None:
+                    trial.poll_ref = trial.actor.poll.remote()
+                    trial.poll_since = time.monotonic()
+            ready, _ = ray_tpu.wait(
+                [t.poll_ref for t in running], num_returns=len(running),
+                timeout=self.poll_interval)
+            answered = {t.trial_id for t in running if t.poll_ref in ready}
             for trial in list(running):
+                if (trial.trial_id not in answered
+                        and time.monotonic() - trial.poll_since < 60):
+                    continue
                 done = self._poll_trial(trial)
                 if done:
                     changed = True
@@ -265,8 +283,9 @@ class TuneController:
         """Returns True when the trial left the running set."""
         import ray_tpu
 
+        ref, trial.poll_ref = trial.poll_ref, None
         try:
-            poll = ray_tpu.get(trial.actor.poll.remote(), timeout=60)
+            poll = ray_tpu.get(ref, timeout=10)
         except Exception as e:
             trial.status = ERRORED
             trial.error = f"poll failed: {e!r}"

@@ -168,6 +168,10 @@ ENGINES = {
                     buckets=(128, 2048), max_batch=32, model="mixtral-8x7b",
                     widths={}),
 }
+# a pipeline's stages run the same programs over a slice of the layers
+# (serve/llm/stage.py): name -> (engine, first layer, layers)
+STAGES = {"short-first": ("short", 0, 1), "short-last": ("short", 1, 1),
+          "mixtral-first": ("mixtral", 0, 2)}
 HBM_GIB = 15.75     # what a program may use of a v5e's 16 GB
 MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice",
          "slice", "transpose", "concatenate")
@@ -189,52 +193,75 @@ ENGINE_PROGRAMS = {
         ("long", "prefill", lambda rb, mp: (4096, rb, 0)),
     "mixtral-prefill-16x128-prefix-hit":
         ("mixtral", "prefill", lambda rb, mp: (128, rb, mp)),
+    # stages: hidden states in place of ids going in, or of tokens coming out
+    "decode-S1-first-stage": ("short-first", "decode", lambda rb, mp: (1, mp)),
+    "decode-S1-last-stage": ("short-last", "decode", lambda rb, mp: (1, mp)),
+    "prefill-bucket128-first-stage":
+        ("short-first", "prefill", lambda rb, mp: (128, rb, 0)),
+    "prefill-bucket128-prefix-hit-last-stage":
+        ("short-last", "prefill", lambda rb, mp: (128, rb, mp)),
+    "mixtral-prefill-16x128-first-stage":
+        ("mixtral-first", "prefill", lambda rb, mp: (128, rb, 0)),
 }
 
 
 @pytest.fixture(scope="module")
 def engines():
-    """name -> an `LLMEngine` whose params are shapes only: its `_jit`
-    builds the real `run_decode` / `run_prefill` / `run_verify`, nothing
-    runs. Its own pool is two pages; a program takes the pool's size from
+    """name -> an `LLMEngine` whose params are shapes only: its stage's
+    `program` builds the real `run_decode` / `run_prefill` / `run_verify`,
+    nothing runs. Its own pool is two pages; a program takes the pool's size from
     its argument, which the test gives at `ENGINES[name]["pages"]`."""
-    import flax.linen as nn
-
     from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    from ray_tpu.serve.llm.stage import StageCompute, init_params
 
     def build(layers, pages, max_model_len, buckets, max_batch=8,
-              model="llama3-8b", widths=MISTRAL):
-        eng = LLMEngine(EngineConfig(
+              model="llama3-8b", widths=MISTRAL, stage=None):
+        cfg = EngineConfig(
             model=model, dtype="bfloat16", page_size=PAGE,
             num_pages=2, max_model_len=max_model_len, max_batch=max_batch,
             prefill_buckets=buckets,
-            model_overrides=dict(num_layers=layers, **widths)),
-            params={})
-        eng.params = jax.eval_shape(lambda: nn.meta.unbox(eng.model.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+            model_overrides=dict(num_layers=layers, **widths))
+        eng = LLMEngine(cfg, params={})
+        if stage:
+            eng.compute = StageCompute(cfg, *stage, params={})
+        c = eng.compute
+        x = (jnp.zeros((1, 8), jnp.int32) if c.first
+             else jnp.zeros((1, 8, c.model_cfg.hidden_size), BF16))
+        c.params = jax.eval_shape(
+            lambda: init_params(c.model, x, jax.random.PRNGKey(0)))
         return eng
 
-    return {name: build(**sizes) for name, sizes in ENGINES.items()}
+    out = {name: build(**sizes) for name, sizes in ENGINES.items()}
+    out.update({name: build(**ENGINES[which], stage=(lo, n))
+                for name, (which, lo, n) in STAGES.items()})
+    return out
 
 
-def _program_args(kind, shape_key, rows, mp, sds):
-    """Shapes of a program's arguments after (params, kv_pages)."""
+def _program_args(kind, shape_key, rows, mp, sds, hidden=None):
+    """Shapes of a program's arguments after (params, kv_pages); `hidden`
+    is the width of the hidden states a stage that is not first takes in
+    place of ids."""
     i32, f32 = jnp.int32, jnp.float32
+
+    def x(span):
+        return (sds((rows, span), i32) if hidden is None
+                else sds((rows, span, hidden), BF16))
+
     if kind == "decode":
         return (sds((rows, 1), i32), sds((rows, mp), i32), sds((rows,), i32),
                 sds((rows,), i32), sds((rows, 1), i32),
-                sds((rows,), jnp.bool_), sds((rows, 1), i32),
+                sds((rows,), jnp.bool_), x(1),
                 sds((rows,), f32), sds((rows,), i32),
                 sds((shape_key[0], rows, 2), jnp.uint32))
     span = shape_key[0]
-    args = (sds((rows, mp), i32), sds((rows,), i32), sds((rows, span), i32),
+    # a prefill or a verify takes the number of real rows first: it loops
+    # over them
+    args = (sds((), i32), sds((rows, mp), i32), sds((rows,), i32), x(span),
             sds((rows, span), i32))
     if kind == "verify":
         return args
-    # a prefill takes the number of real rows first: it loops over them
-    return (sds((), i32),) + args + (
-        sds((rows,), i32), sds((rows,), f32), sds((rows,), i32),
-        sds((rows, 2), jnp.uint32))
+    return args + (sds((rows,), i32), sds((rows,), f32), sds((rows,), i32),
+                   sds((rows, 2), jnp.uint32))
 
 
 def _array_types(type_text):
@@ -283,15 +310,19 @@ def compiled_programs(topo, no_persistent_cache, engines):
         rows = (engine.config.max_batch if kind == "decode"
                 else engine._wave_rb)
         shape_key = key(rows, mp)
-        pool_dims = (ENGINES[which]["layers"], ENGINES[which]["pages"], HKV,
-                     PAGE, 2 * HEAD_DIM)
+        stage = engine.compute
+        pool_dims = (stage.n_layers, ENGINES[STAGES.get(
+            which, (which,))[0]]["pages"], HKV, PAGE, 2 * HEAD_DIM)
         # the ops choose kernel or reference by the backend, at trace time
         with pytest.MonkeyPatch.context() as mp_ctx:
             mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
-            compiled = engine._jit(kind, shape_key).lower(
-                jax.tree.map(lambda a: sds(a.shape, a.dtype), engine.params),
+            compiled = stage.program(kind, shape_key).lower(
+                jax.tree.map(lambda a: sds(a.shape, a.dtype), stage.params),
                 sds(pool_dims, BF16),
-                *_program_args(kind, shape_key, rows, mp, sds)).compile()
+                *_program_args(
+                    kind, shape_key, rows, mp, sds,
+                    None if stage.first else stage.model_cfg.hidden_size),
+            ).compile()
         done[name] = (engine, kind, shape_key, rows, pool_dims, compiled,
                       compiled.as_text())
         return done[name]
@@ -339,22 +370,29 @@ def test_engine_program_keeps_the_pool_in_place(compiled_programs, name):
 
 @pytest.mark.parametrize("name", [n for n, (_, kind, _) in
                                   ENGINE_PROGRAMS.items()
-                                  if kind == "prefill"])
+                                  if kind in ("prefill", "verify")])
 def test_prefill_program_computes_one_row_a_pass(compiled_programs, name):
     """A wave computes its real rows in a loop, one row a pass: the
     program holds a while loop beside the layer scan's, and no
     activation at the wave's width (the padded `[16, 2048, 32000]` head
     and `[16, 2048, 28672]` MLP of the program that padded every wave
-    to its size). Its inputs, `[rows, span]` ids and positions, stay."""
+    to its size). Its inputs, `[rows, span]` ids and positions, stay; so
+    do the `[rows, span, hidden]` states between a pipeline's stages,
+    which the loop reads and writes a row at a time. A speculative verify
+    is the same loop."""
     engine, _, shape_key, rows, _, _, text = compiled_programs(name)
     span = shape_key[0]
     assert rows > 1
+    stage = engine.compute
+    between = set() if stage.first and stage.last else {
+        (rows, span, stage.model_cfg.hidden_size)}
     wide = set()
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
         if m:
             wide.update(dims for dims, _ in _array_types(m.group(1))
                         if len(dims) > 2 and dims[:2] == (rows, span))
-    assert not wide, wide
-    # the row loop and the layer scan inside it
-    assert len(re.findall(r" while\(", text)) >= 2
+    assert not wide - between, wide
+    # the row loop and the layer scan inside it (a scan over one layer
+    # is no loop)
+    assert len(re.findall(r" while\(", text)) >= 1 + (stage.n_layers > 1)

@@ -131,31 +131,40 @@ def test_compiled_error_propagates_and_recovers(shared_cluster):
 
 
 def test_compiled_beats_per_call_path(shared_cluster):
-    """The aDAG's reason to exist: channel loops beat task submission."""
+    """The aDAG's reason to exist: a compiled graph's steady state moves
+    channel frames only — ZERO control-plane RPCs an execute, where the
+    per-call path submits a task per hop. (Counted, not timed: two wall
+    times on a shared CPU say little about either path.)"""
+    from ray_tpu.runtime import rpc
+
     a = Adder.remote(1)
     b = Adder.remote(1)
     n = 50
+    ambient = {"heartbeat", "report_metrics", "view_update"}
+
+    def sends_during(fn):
+        before = rpc.transport_sends()
+        out = [fn(i) for i in range(n)]
+        after = rpc.transport_sends()
+        return out, {k: after[k] - before.get(k, 0) for k in after
+                     if after[k] != before.get(k, 0) and k not in ambient}
+
     # warm both paths
     ray_tpu.get(b.add.remote(ray_tpu.get(a.add.remote(0))))
-    t0 = time.perf_counter()
-    for i in range(n):
-        ray_tpu.get(b.add.remote(ray_tpu.get(a.add.remote(i))))
-    per_call = time.perf_counter() - t0
+    want, per_call = sends_during(
+        lambda i: ray_tpu.get(b.add.remote(ray_tpu.get(a.add.remote(i)))))
+    assert per_call.get("actor_call", 0) >= 2 * n, per_call
 
     with InputNode() as inp:
         dag = b.add.bind(a.add.bind(inp))
     cdag = dag.experimental_compile()
     try:
         cdag.execute(0).get()  # warm
-        t0 = time.perf_counter()
-        for i in range(n):
-            cdag.execute(i).get()
-        compiled = time.perf_counter() - t0
+        got, compiled = sends_during(lambda i: cdag.execute(i).get())
     finally:
         cdag.teardown()
-    assert compiled < per_call, (compiled, per_call)
-    print(f"per_call={per_call:.3f}s compiled={compiled:.3f}s "
-          f"speedup={per_call / compiled:.1f}x")
+    assert got == want == [i + 2 for i in range(n)]
+    assert not compiled, f"steady-state execute() issued RPCs: {compiled}"
 
 
 def test_channel_basics(shared_cluster):

@@ -310,6 +310,11 @@ def test_streaming_split_early_exit_consumer_is_not_evicted():
     its = split_iterators(rd.range(120, parallelism=12), 2,
                           consumer_timeout_s=5.0)
     out = {0: [], 1: []}
+    # blocks come off ONE shared queue, whoever asks first: the peer
+    # holds after its first row until the early-exiter has left the
+    # epoch, or on a loaded box it could drain the queue before the
+    # early-exiter's second pull and leave it under its cutoff
+    left = [threading.Event(), threading.Event()]
 
     def run(rank, cutoff):
         for epoch in range(2):
@@ -318,7 +323,11 @@ def test_streaming_split_early_exit_consumer_is_not_evicted():
                 got.append(row["id"])
                 if cutoff and len(got) >= cutoff:
                     break  # early exit mid-epoch
+                if not cutoff and len(got) == 1:
+                    assert left[epoch].wait(timeout=4.0)
             out[rank].append(got)
+            if cutoff:
+                left[epoch].set()
 
     threads = [threading.Thread(target=run, args=(0, 15), daemon=True),
                threading.Thread(target=run, args=(1, 0), daemon=True)]

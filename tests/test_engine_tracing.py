@@ -141,13 +141,26 @@ def wide():
     return LLMEngine(EngineConfig(**WIDE_CFG))
 
 
-@pytest.mark.parametrize("n, waves", [
-    (1, [1]), (2, [2]), (3, [3]), (4, [4]), (5, [4, 1]), (6, [4, 2]),
-    (7, [4, 3]), (8, [4, 4])])
+@pytest.fixture(scope="module")
+def wide_spec():
+    """`wide` with speculative decoding on and a draft for every slot
+    (two tokens the model will not have chosen: all are rejected)."""
+    engine = LLMEngine(EngineConfig(**WIDE_CFG, spec_lookahead=3))
+    engine._prompt_lookup_draft = lambda req, max_len: [511, 510][:max_len]
+    return engine
+
+
+@pytest.mark.parametrize("n, waves, spec", [
+    (1, [1], False), (2, [2], False), (3, [3], False), (4, [4], False),
+    (5, [4, 1], False), (6, [4, 2], False), (7, [4, 3], False),
+    (8, [4, 4], False), (3, [3], True)])
 def test_a_prefill_wave_computes_its_requests_and_no_padding_row(
-        wide, n, waves):
+        wide, wide_spec, n, waves, spec):
     """One request computes one row; a burst beyond the wave size goes
-    in waves of the wave size and a remainder of its own size."""
+    in waves of the wave size and a remainder of its own size. A
+    speculative verify is the same row loop: it computes the slots that
+    have a draft, and the tokens are plain greedy decode's."""
+    wide = wide_spec if spec else wide
     assert wide._wave_rb == 4
     before = wide.stats()
     # a seed of its own: no page of another case's prompts is cached
@@ -161,8 +174,20 @@ def test_a_prefill_wave_computes_its_requests_and_no_padding_row(
             - before["prefill_tokens_total"]) == sum(
                 20 + i for i in range(n))
     # every row count is ONE program: the wave-sized one of the bucket
-    assert {k for k in wide._jit_cache if k[0] == "prefill"} == {
+    assert {k for k in wide.compute.programs if k[0] == "prefill"} == {
         ("prefill", 32, 4, 0)}
+    verifies = [d for d in _dicts("engine.dispatch") if d["kind"] == "spec"]
+    assert bool(verifies) == spec
+    for d in verifies:
+        # draft + pending token = 3 positions, in the bucket of 16
+        assert 1 <= d["rows_padded"] == len(d["rows"]) <= n
+        assert d["tokens_padded"] == d["rows_padded"] * 16
+        assert all(q == 3 for _, q, _ in d["rows"])
+    if spec:
+        assert ("verify", 16, 4) in wide.compute.programs
+        assert after["spec_drafted_total"] > after["spec_accepted_total"] == 0
+        plain = LLMEngine(EngineConfig(**WIDE_CFG))
+        assert _waves(n, plain, f"w{n}", seed=100 + n)[1] == out
 
 
 @pytest.mark.parametrize("chunk", [0, 16])
@@ -204,7 +229,7 @@ def test_warmup_builds_one_program_per_length_bucket_and_prefix_variant(
     engine, n, _ = warmed
     lengths = WIDE_CFG["prefill_buckets"]
     assert n == len(lengths) * 2 + 1
-    assert set(engine._jit_cache) == {
+    assert set(engine.compute.programs) == {
         ("prefill", sb, engine._wave_rb, cp) for sb in lengths
         for cp in (0, engine.max_pages_per_seq)} | {
             ("decode",) + engine._decode_shape_key()}

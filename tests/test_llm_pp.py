@@ -15,7 +15,8 @@ import pytest
 from ray_tpu.serve.llm import (EngineConfig, LLMEngine, PipelinedEngine,
                                SamplingParams, make_engine, pp_bundles,
                                tp_bundles)
-from ray_tpu.serve.llm.pp import broadcast_params, stage_params
+from ray_tpu.serve.llm.pp import broadcast_params
+from ray_tpu.serve.llm.stage import stage_params
 
 pytestmark = pytest.mark.pp
 
@@ -113,8 +114,8 @@ def test_stage_params_are_literal_slices():
 
     full = nn.meta.unbox(LlamaModel(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    s0 = stage_params(full, 0, 2, cfg.num_layers)
-    s1 = stage_params(full, 1, 2, cfg.num_layers)
+    s0 = stage_params(full, 0, 1)
+    s1 = stage_params(full, 1, 1)
     assert "embed" in s0 and "embed" not in s1
     assert "lm_head" in s1 and "lm_head" not in s0
     assert "final_norm" in s1 and "final_norm" not in s0
@@ -216,6 +217,41 @@ def test_pp_bit_exact_greedy_s2_tp2(shared_cluster):
 
 
 @pytest.mark.slow
+def test_pp_expert_model_tokens_and_routing_counts(shared_cluster):
+    """An expert model under pp: each stage counts its own layers' routing
+    and the counts ride the frames, so the driver's `engine.dispatch`
+    records (and greedy tokens) are the single engine's."""
+    from ray_tpu.util import tracing
+
+    cfg = dict(ENGINE_CFG, model="tiny-moe")
+    rng = np.random.default_rng(2)
+    prompts = {f"r{i}": list(rng.integers(0, 500, 11 + 3 * i))
+               for i in range(3)}
+
+    def run(engine):
+        tracing.reset_ring()
+        for rid, p in prompts.items():
+            engine.add_request(rid, p, SamplingParams(max_tokens=5))
+        out = _ids(_collect(engine, list(prompts)))
+        fields = tracing.FIELDS["engine.dispatch"]
+        moe = [(d["kind"], d["rows_padded"], d["moe_assignments"],
+                d["moe_experts_touched"]) for d in (
+                    dict(zip(fields, rec))
+                    for rec in tracing.records("engine.dispatch"))
+               if d["kind"] == "prefill"]
+        assert engine.stats()["moe_assignments_total"] > sum(
+            n for _, _, n, _ in moe)   # decode steps counted too
+        return out, moe
+
+    want = run(LLMEngine(EngineConfig(**cfg)))
+    pp = PipelinedEngine(EngineConfig(pp=2, **cfg))
+    try:
+        assert run(pp) == want
+    finally:
+        pp.shutdown()
+
+
+@pytest.mark.slow
 def test_pp_preemption_token_identical(shared_cluster):
     """OutOfPages mid-decode under pp: preempt -> re-prefill ->
     continue, still token-identical to the uncontended single-engine
@@ -252,11 +288,14 @@ def test_pp_zero_control_rpcs_and_bubble_accounting(shared_cluster,
     of pure-decode steps the process's RPC send counters stay flat
     (ambient liveness aside). The same window feeds the measured bubble
     counters: every stage counted reads, pp_bubble_frac in [0, 1], and
-    reset zeroes the window. Run to the end, the stages' tokens (each
+    reset zeroes the window. `engine.dispatch` prefill records carry
+    `tokens_padded` = real rows x bucket. Run to the end, the stages' tokens (each
     stage's pool an [n_layers] slice carried through its own layer scan)
     are those of the whole model run densely with no cache."""
     from ray_tpu.runtime import rpc
+    from ray_tpu.util import tracing
 
+    tracing.reset_ring()
     cfg = EngineConfig(pp=2, pp_microbatches=4, **ENGINE_CFG)
     pp = PipelinedEngine(cfg)
     try:
@@ -306,5 +345,15 @@ def test_pp_zero_control_rpcs_and_bubble_accounting(shared_cluster,
         want = dense_greedy(whole.model, whole.params,
                             list(prompts.values()), 30)
         assert list(got.values()) == want
+        # the stages ran the single engine's row loop: a prefill frame
+        # computed its requests' rows (12 tokens: the bucket of 16) and
+        # no padding row, and the records say so
+        prefills = [dict(zip(tracing.FIELDS["engine.dispatch"], rec))
+                    for rec in tracing.records("engine.dispatch")
+                    if rec[1] == "prefill"]
+        assert sum(len(d["rows"]) for d in prefills) == 4
+        for d in prefills:
+            assert d["rows_padded"] == len(d["rows"]) <= pp._wave_rb
+            assert d["tokens_padded"] == len(d["rows"]) * 16
     finally:
         pp.shutdown()
