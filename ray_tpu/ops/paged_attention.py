@@ -12,10 +12,11 @@ owned end to end:
   carries it (models/llama.py) updates it in place. ``layer=None`` means a
   pool with no layer axis, ``[P, Hkv, page, 2*D]`` (direct callers).
   Page-major means ONE DMA descriptor moves a page's K and V for EVERY kv
-  head (32 KB contiguous for an 8-head, page-16, D-64 model) — the decode
-  kernel's streaming unit. K/V interleaving also makes the slice's last dim
-  ``2*D`` (128 for head_dim-64 models), satisfying Mosaic's 128-lane
-  slice alignment, which a split K/V pool with D=64 cannot.
+  head (64 KiB contiguous for an 8-head, page-16, D-128 bfloat16 model) —
+  the decode kernel's streaming unit. With K and V side by side a row's
+  lanes are ``2*D``: 128 for head_dim-64 models, which satisfies Mosaic's
+  128-lane slice alignment where a split K/V pool with D=64 cannot; at
+  D=128 K and V are each a whole lane tile of the row.
 - ``paged_write`` puts new tokens into their pages by whole pages: read
   the pages a row touches, select the new rows in, scatter the pages back
   (pure XLA, static shapes, untouched pages dropped). A whole page is the
@@ -25,13 +26,25 @@ owned end to end:
 - ``paged_attention_decode`` is a Pallas kernel for the single-token step:
   it builds an in-kernel work list of (sequence, page-chunk) items, then
   streams ONLY the used pages HBM->VMEM with double-buffered async copies
-  while accumulating a flash-style online softmax across all heads at
-  once. Two tricks keep the vector path free of sub-tile lane slices:
-  queries are zero-padded to ``[Hq, 2*D]`` so ``q_pad @ kv^T`` computes
-  q·k exactly (the V lanes multiply zeros), and the accumulator runs over
-  the full ``2*D`` lanes with the V half sliced once at finalize. The
-  gather-free design is what moves decode from O(max_pages) HBM traffic
-  (plus a GQA broadcast) to O(used pages).
+  while accumulating a flash-style online softmax for all heads at once,
+  q heads grouped by their kv head. The copies set its pace (alone on a
+  v5e, 61 to 89% of the HBM bandwidth; PERF.md section 6, PR 32): an
+  item's compute is a quarter to a half of its copy time. Two things
+  keep it there. q, the softmax state and the output are ``[Hkv, rep,
+  .]``, a head's rows an array of their own on a leading axis: slicing 4
+  rows out of, or concatenating them into, 8-row sublane tiles was
+  eleven twelfths of the kernel's time when the state was ``[Hq, .]``.
+  And nothing touches KV element by element except on a sequence's LAST
+  item, where the rows past the length are cleaned. Two lane forms,
+  chosen from the head size the kernel is given: where ``D`` is a whole
+  number of 128-lane tiles K and V are sliced from a row for free and
+  nothing is padded; below (D=64: a row is ONE tile) queries are
+  zero-padded to ``[.., 2*D]`` so ``q_pad @ kv^T`` computes q.k exactly
+  (the V lanes multiply zeros) and the accumulator runs over ``2*D``
+  lanes with the V half taken at the end, which keeps the vector path
+  free of sub-tile lane slices. The gather-free design is what moves
+  decode from O(max_pages) HBM traffic (plus a GQA broadcast) to O(used
+  pages).
 - ``paged_prefill_attention`` splits prefill into (1) causal flash
   attention among the new tokens themselves — no page reads at all — and
   (2) segment-masked flash attention over the cached prefix pages, merged
@@ -170,11 +183,14 @@ def _decode_kernel(lengths_ref, bt_ref, layer_ref, # SMEM scalars
                    o_ref,                          # VMEM out
                    kv_buf, work_b, work_c,         # scratch
                    sems, *,
-                   page: int, chunk: int, scale: float):
+                   page: int, chunk: int, scale: float,
+                   stream: bool = True, attend: bool = True):
     """Single-program decode kernel (grid=()): one flattened work list of
     (sequence, page-chunk) items, double-buffered page DMAs, all kv heads
     per item. `kv_hbm` is the whole [L, P, Hkv, page, 2D] pool; only pages
-    of layer `layer_ref[0]` are streamed.
+    of layer `layer_ref[0]` are streamed. q and the output are grouped by
+    kv head, [B, Hkv, rep, .]: a group's rows are an array of their own,
+    so the softmax state needs no sublane slice or concatenation.
 
     A single program (rather than a grid) keeps ONE uninterrupted DMA
     pipeline across every sequence — per-program warm-up latency would
@@ -182,17 +198,27 @@ def _decode_kernel(lengths_ref, bt_ref, layer_ref, # SMEM scalars
     there is no grid parallelism to lose. All heads ride one item because
     a page holds every head's K/V contiguously — B*chunks items total,
     not B*chunks*Hkv.
+
+    `stream=False` starts and awaits no copy, `attend=False` skips an
+    item's compute: the two halves of an item, for
+    benchmarks/paged_decode_probe.py to time alone. The engine runs both.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n_b = lengths_ref.shape[0]
-    hkv = kv_hbm.shape[2]
     layer = layer_ref[0]
     bk = chunk * page                              # kv rows per work item
-    hq, d2 = q_ref.shape[1], q_ref.shape[2]
+    hkv, rep, q_lanes = q_ref.shape[1:]
+    d2 = kv_hbm.shape[4]
     d = d2 // 2
-    rep = hq // hkv
+    # Two lane forms, by the head size (see _decode_pallas). q unpadded:
+    # K is lanes [:D] and V lanes [D:] of a row, whole lane tiles both.
+    # q zero-padded to 2D lanes: q_pad @ kv^T == q @ k^T (the V lanes
+    # multiply zeros) and p @ kv carries the K half along to be dropped
+    # at the end; for D = 64 a row is ONE lane tile, so that costs no more
+    # than the sub-tile slice it avoids.
+    k_lanes, v_lanes = ((0, d), (d, d2)) if q_lanes == d else ((0, d2),) * 2
 
     # ---- build the work list: (b, chunk) for every used page-chunk
     def fill_b(b, cnt):
@@ -209,33 +235,40 @@ def _decode_kernel(lengths_ref, bt_ref, layer_ref, # SMEM scalars
 
     # rows not covered by any work item (inactive slots) stay zero
     o_ref[...] = jnp.zeros_like(o_ref)
+    # The buffers never hold a non-finite row: they start as zeros, a
+    # copy brings only pages the sequence owns, and the one page of those
+    # that can hold rows past the length is cleaned below. So the pages of
+    # a short last item that no copy refreshed are old but finite, and a
+    # zero weight times them is zero.
+    kv_buf[...] = jnp.zeros_like(kv_buf)
 
-    def page_dma(t, slot, j):
-        """The j-th page copy of item t into buffer `slot` (descriptors
-        are rebuilt at wait time — the semaphore carries the completion
-        state, not the Python object)."""
+    def live_pages(t):
         b, c = work_b[t], work_c[t]
-        p = bt_ref[b, c * chunk + j]
-        return pltpu.make_async_copy(
-            kv_hbm.at[layer, p], kv_buf.at[slot, j], sems.at[slot])
-
-    def n_pages_of(t):
-        b, c = work_b[t], work_c[t]
-        return pl.cdiv(lengths_ref[b], page) - c * chunk  # pages this item
+        return jnp.minimum(pl.cdiv(lengths_ref[b], page) - c * chunk, chunk)
 
     def start_item(t, slot):
-        live = n_pages_of(t)
-        for j in range(chunk):
-            @pl.when(j < live)
-            def _():
-                page_dma(t, slot, j).start()
+        b, c = work_b[t], work_c[t]
+
+        def start(j, _):
+            pltpu.make_async_copy(
+                kv_hbm.at[layer, bt_ref[b, c * chunk + j]],
+                kv_buf.at[slot, j], sems.at[slot]).start()
+            return _
+
+        if stream:
+            _fori_no_unroll(0, live_pages(t), start, 0)
 
     def wait_item(t, slot):
-        live = n_pages_of(t)
-        for j in range(chunk):
-            @pl.when(j < live)
-            def _():
-                page_dma(t, slot, j).wait()
+        def wait(j, _):
+            # a wait reads the descriptor's size and semaphore only: any
+            # page of the pool stands for the source
+            pltpu.make_async_copy(
+                kv_hbm.at[layer, 0], kv_buf.at[slot, j],
+                sems.at[slot]).wait()
+            return _
+
+        if stream:
+            _fori_no_unroll(0, live_pages(t), wait, 0)
 
     @pl.when(n_items > 0)
     def _():
@@ -251,59 +284,60 @@ def _decode_kernel(lengths_ref, bt_ref, layer_ref, # SMEM scalars
             start_item(t + 1, 1 - slot)
 
         wait_item(t, slot)
+        if not attend:
+            return carry
         length = lengths_ref[b]
-        # zero-padded q: lanes [D:] are 0, so q_pad @ kv^T == q @ k^T
-        # (the V lanes of every kv row multiply zeros)
-        q_pad = q_ref[b]                           # [Hq, 2D]
-        row_pos = c * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-        # stale rows (never DMA'd on a short final chunk) can hold
-        # non-finite garbage; zero them so 0-weighted rows stay 0 in the
-        # accumulator matmul (0 * NaN would poison it)
-        s_heads = []
-        for h in range(hkv):
-            # [chunk, page, 2D] -> [bk, 2D]: page is a whole sublane
-            # tile, so the merge is layout-preserving
-            kv_h = kv_buf[slot, :, h].reshape(bk, d2)
-            kv_h = jnp.where(row_pos < length, kv_h, 0)       # [bk, 2D]
-            s_heads.append((kv_h, jax.lax.dot_general(
-                q_pad[h * rep:(h + 1) * rep], kv_h,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)))          # [rep, bk]
-        s = jnp.concatenate([sh for _, sh in s_heads], axis=0) * scale
-        mask = (row_pos < length).reshape(1, bk)
-        s = jnp.where(mask, s, NEG_INF)            # [Hq, bk]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m - m_new)
-        m = m_new
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jnp.concatenate([
-            jax.lax.dot_general(
-                p[h * rep:(h + 1) * rep].astype(kv_h.dtype), kv_h,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            for h, (kv_h, _) in enumerate(s_heads)], axis=0)   # [Hq, 2D]
-        acc = acc * alpha + pv
-
-        # finalize when the NEXT item is a different sequence
+        # the item is its sequence's last when the NEXT one is another's
         t_next = jnp.minimum(t + 1, work_b.shape[0] - 1)
         is_last = jnp.logical_or(t + 1 >= n_items, work_b[t_next] != b)
 
         @pl.when(is_last)
         def _():
-            # the K half of acc (lanes [:D]) is discarded here — it cost
-            # nothing extra: 2D lanes is one MXU tile for D=64 anyway
-            o_ref[b] = (acc[:, d:] / l).astype(o_ref.dtype)
+            # the tail: rows at or past the length came in with the page
+            # that holds the sequence's end, stale and maybe not finite.
+            # Only here is there any per-element work on KV.
+            j = (length - 1) // page - c * chunk
+            row = (c * chunk + j) * page + jax.lax.broadcasted_iota(
+                jnp.int32, (1, page, 1), 1)
+            kv_buf[slot, j] = jnp.where(row < length, kv_buf[slot, j], 0)
+
+        def head(h, lanes):
+            # [chunk, page, lanes] -> [bk, lanes]: page is a whole sublane
+            # tile, so the merge is layout-preserving
+            lo, hi = lanes
+            return kv_buf[slot, :, h, :, lo:hi].reshape(bk, hi - lo)
+
+        q = q_ref[b]                               # [Hkv, rep, D or 2D]
+        s = jnp.stack([jax.lax.dot_general(
+            q[h], head(h, k_lanes), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) for h in range(hkv)])
+        row_pos = c * bk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+        mask = row_pos < length
+        s = jnp.where(mask, s * scale, NEG_INF)    # [Hkv, rep, bk]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        m = m_new
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        p = p.astype(kv_buf.dtype)
+        pv = jnp.stack([jax.lax.dot_general(
+            p[h], head(h, v_lanes), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) for h in range(hkv)])
+        acc = acc * alpha + pv                     # [Hkv, rep, D or 2D]
+
+        @pl.when(is_last)
+        def _():
+            # V's lanes of the accumulator: all of it, or its upper half
+            o_ref[b] = (acc[:, :, -d:] / l).astype(o_ref.dtype)
 
         m = jnp.where(is_last, jnp.full_like(m, NEG_INF), m)
         l = jnp.where(is_last, jnp.zeros_like(l), l)
         acc = jnp.where(is_last, jnp.zeros_like(acc), acc)
         return m, l, acc
 
-    m0 = jnp.full((hq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((hq, 1), jnp.float32)
-    acc0 = jnp.zeros((hq, d2), jnp.float32)
+    m0 = jnp.full((hkv, rep, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((hkv, rep, 1), jnp.float32)
+    acc0 = jnp.zeros((hkv, rep, v_lanes[1] - v_lanes[0]), jnp.float32)
     _fori_no_unroll(0, n_items, body, (m0, l0, acc0))
 
 
@@ -311,32 +345,41 @@ def _decode_kernel(lengths_ref, bt_ref, layer_ref, # SMEM scalars
                                              "interpret"))
 def _decode_call(q, kv_pages, block_tables, lengths, layer, *,
                  scale: float, pages_per_chunk: int, interpret: bool):
+    return _decode_pallas(_decode_kernel, q, kv_pages, block_tables, lengths,
+                          layer, scale=scale, chunk=pages_per_chunk,
+                          interpret=interpret)
+
+
+def _decode_pallas(kernel, q, kv_pages, block_tables, lengths, layer, *,
+                   scale: float, chunk: int, interpret: bool):
+    """The decode kernel's one `pallas_call`, around `kernel` (the whole
+    `_decode_kernel`, or a half of it for the probe)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, hq, d = q.shape
     _, _, hkv, page, d2 = kv_pages.shape
-    chunk = pages_per_chunk
-    mp = block_tables.shape[1]
-    max_chunks = -(-mp // chunk)
-    q_pad = jnp.pad(q, ((0, 0), (0, 0), (0, d2 - d)))
+    max_chunks = -(-block_tables.shape[1] // chunk)
+    q = q.reshape(b, hkv, hq // hkv, d)
+    if d % 128:
+        # K and V are not whole lane tiles: the padded-q form
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, d2 - d)))
 
-    kernel = functools.partial(
-        _decode_kernel, page=page, chunk=chunk, scale=scale)
+    kernel = functools.partial(kernel, page=page, chunk=chunk, scale=scale)
     out = pl.pallas_call(
         kernel,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),      # lengths [B]
             pl.BlockSpec(memory_space=pltpu.SMEM),      # block_tables
             pl.BlockSpec(memory_space=pltpu.SMEM),      # layer [1]
-            pl.BlockSpec(memory_space=pltpu.VMEM),      # q (zero-padded)
+            pl.BlockSpec(memory_space=pltpu.VMEM),      # q, by kv head
             # explicitly HBM (not ANY): the compiler would happily place
             # a small page pool in VMEM, where per-page slices violate
             # tile alignment — and the pool must not eat VMEM anyway.
             pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, hq // hkv, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((2, chunk, hkv, page, d2), kv_pages.dtype),
             pltpu.SMEM((b * max_chunks,), jnp.int32),
@@ -345,8 +388,8 @@ def _decode_call(q, kv_pages, block_tables, lengths, layer, *,
         ],
         interpret=interpret,
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q_pad, kv_pages)
-    return out
+      jnp.asarray(layer, jnp.int32).reshape(1), q, kv_pages)
+    return out.reshape(b, hq, d)
 
 
 def decode_kernel_constraint(head_dim: int, page_size: int,
@@ -363,6 +406,23 @@ def decode_kernel_constraint(head_dim: int, page_size: int,
         return (f"page_size must be a multiple of {sublane} rows for "
                 f"{jnp.dtype(dtype).name}, got page_size={page_size}")
     return None
+
+
+ITEM_BYTES = 2 << 20
+
+
+def default_pages_per_chunk(kv_pages: jax.Array) -> int:
+    """Pages a work item of the decode kernel holds: as many as 2 MiB take
+    (32 pages = 512 kv rows at Hkv 8, D 128, page 16, bfloat16). The
+    copies set the kernel's pace, and they run closest to the chip's
+    bandwidth with few, large items: on a v5e 32 pages beat 8 and 16 at
+    8k-token and at 400-token rows alike and 48 and 64 bring nothing
+    (PERF.md section 6, PR 32). A short row's dead pages cost nothing:
+    they are never copied, and their products hide behind the next item's
+    copies. Two such buffers and the compute's temporaries sit far inside
+    the 16 MiB of VMEM a kernel may scope."""
+    hkv, page, d2 = kv_pages.shape[-3:]
+    return max(1, ITEM_BYTES // (hkv * page * d2 * kv_pages.dtype.itemsize))
 
 
 def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
@@ -404,9 +464,7 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
         if why is not None:
             raise ValueError(f"paged decode kernel: {why}")
     if pages_per_chunk is None:
-        # target ~128 kv rows per work item (one MXU-friendly tile)
-        pages_per_chunk = max(1, min(block_tables.shape[1],
-                                     -(-128 // page)))
+        pages_per_chunk = default_pages_per_chunk(kv_pages)
     pages_per_chunk = min(pages_per_chunk, block_tables.shape[1])
     kv_pages, layer = _layered(kv_pages, layer)
     return _decode_call(q, kv_pages, block_tables, lengths, layer,

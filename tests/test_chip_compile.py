@@ -25,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops.flash_attention import (flash_attention,
                                          flash_attention_sharded)
-from ray_tpu.ops.paged_attention import _decode_call
+from ray_tpu.ops.paged_attention import paged_attention_decode
 from ray_tpu.parallel.mesh import AXES
 
 BF16 = jnp.bfloat16
@@ -87,7 +87,10 @@ def _flash(fn, shape):
         topo.devices[0])))
 
 
-def _paged_decode(b, d, mp, hq=32, hkv=8, page=16, layers=4):
+def _paged_decode(b, d, mp, hq=32, hkv=8, page=16, layers=4,
+                  pages_per_chunk=None):
+    """The compiled kernel as the engine calls it: `pages_per_chunk=None`
+    is `paged_attention_decode`'s own rule for the item size."""
     def build(topo):
         one_chip = SingleDeviceSharding(topo.devices[0])
 
@@ -95,9 +98,9 @@ def _paged_decode(b, d, mp, hq=32, hkv=8, page=16, layers=4):
             return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
         def fn(q, kv_pages, block_tables, lengths, layer):
-            return _decode_call(q, kv_pages, block_tables, lengths, layer,
-                                scale=d ** -0.5, pages_per_chunk=128 // page,
-                                interpret=False)
+            return paged_attention_decode(
+                q, kv_pages, block_tables, lengths, layer=layer,
+                pages_per_chunk=pages_per_chunk, interpret=False)
         return fn, (sds((b, hq, d), BF16),
                     sds((layers, b * mp, hkv, page, 2 * d), BF16),
                     sds((b, mp), jnp.int32), sds((b,), jnp.int32),
@@ -125,6 +128,7 @@ COMPILES = {
     "fwd-lse-prefill-bucket": _flash(_lse, (4, 512, 32, 8, 64)),
     "decode-llama1b-B8-D64-MP32": _paged_decode(8, 64, 32),
     "decode-8b-B8-D128-MP512": _paged_decode(8, 128, 512),
+    "decode-7b-B32-D128-MP168": _paged_decode(32, 128, 168),
     "fwdbwd-shard_map-2x2-mesh": _flash_on_mesh,
 }
 # The kernel's measured compile limits: K/V of one (batch, kv head) stay
@@ -133,6 +137,11 @@ COMPILES = {
 REFUSED = {
     "fwdbwd-kv8192-refused": _flash(_grads(_fwd), (1, 8192, 32, 8, 64)),
     "fwd-kv32768-refused": _flash(_fwd, (1, 32768, 32, 8, 64)),
+    # the decode kernel's work item: its own rule gives 32 pages (2 MiB a
+    # buffer) at these widths and 64 still compile; 128 are two buffers of
+    # 8 MiB, all the VMEM a kernel may scope
+    "decode-8b-128-pages-an-item-refused": _paged_decode(
+        8, 128, 512, pages_per_chunk=128),
 }
 
 

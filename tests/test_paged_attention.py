@@ -155,17 +155,43 @@ def test_reference_matches_dense_attention():
                                rtol=2e-5, atol=2e-5)
 
 
+# (head_dim, page, dtype): the kernel has two lane forms, chosen from the
+# head size. At 128 K and V are whole lane tiles and nothing is padded;
+# below, q is zero-padded to the 2D lanes of a KV row.
+FORMS = {
+    "d32-page4-f32": (32, 4, jnp.float32),
+    "d128-page16-f32": (128, 16, jnp.float32),
+    "d32-page16-bf16": (32, 16, jnp.bfloat16),
+    "d128-page16-bf16": (128, 16, jnp.bfloat16),
+}
+
+
+def _assert_rows_match(got, want, lengths, dtype):
+    tol = 2e-5 if dtype == jnp.float32 else 5e-2
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.all(np.isfinite(got))
+    for i, n in enumerate(lengths):
+        if n == 0:
+            np.testing.assert_array_equal(got[i], 0.0)
+        else:
+            np.testing.assert_allclose(got[i], want[i], rtol=tol, atol=tol)
+
+
 @pytest.mark.parametrize("layer", LAYERS)
 @pytest.mark.parametrize("hq,hkv", [(8, 2), (4, 4)])
 @pytest.mark.parametrize("pages_per_chunk", [1, 3, 8])
-def test_decode_kernel_matches_reference(hq, hkv, pages_per_chunk, layer):
+@pytest.mark.parametrize("form", list(FORMS))
+def test_decode_kernel_matches_reference(hq, hkv, pages_per_chunk, layer,
+                                         form):
     rng = np.random.default_rng(2)
-    b, d, page, mp = 4, 32, 4, 8
-    lengths = [1, 13, 0, mp * page]  # incl. inactive + full rows
+    d, page, dtype = FORMS[form]
+    b, mp = 4, 8
+    lengths = [1, 3 * page + 1, 0, mp * page]  # incl. inactive + full rows
     kv_pages, bt, _, _, lens = _make_pages(
         rng, b=b, hkv=hkv, d=d, page=page, num_pages=64, mp=mp,
         lengths=lengths, layer=layer)
-    q = jnp.asarray(rng.standard_normal((b, hq, d)), jnp.float32)
+    kv_pages = kv_pages.astype(dtype)
+    q = jnp.asarray(rng.standard_normal((b, hq, d)), dtype)
     got = paged_attention_decode(q, kv_pages, bt, lens, layer=layer,
                                  pages_per_chunk=pages_per_chunk,
                                  interpret=True)
@@ -173,13 +199,40 @@ def test_decode_kernel_matches_reference(hq, hkv, pages_per_chunk, layer):
     # the oracle reads the written layer as a pool of its own
     own = kv_pages if layer is None else kv_pages[layer]
     want = paged_attention_reference(q[:, None], own, bt, positions)[:, 0]
-    got, want = np.asarray(got), np.asarray(want)
+    _assert_rows_match(got, want, lengths, dtype)
+
+
+@pytest.mark.parametrize("pages_per_chunk", [2, 4])
+@pytest.mark.parametrize("form", [f for f in FORMS if "page16" in f])
+def test_decode_kernel_never_reads_past_a_length(form, pages_per_chunk):
+    """A poisoned pool: every row at or past a sequence's length and every
+    page no live row owns is NaN. Sequences end mid-page and mid-item, one
+    row is inactive. The kernel masks a tail only where there is one (a
+    sequence's last item), so this is what holds it to the contract: the
+    outputs are finite and equal the reference on the clean pool."""
+    rng = np.random.default_rng(7)
+    d, page, dtype = FORMS[form]
+    b, hq, hkv, mp, num_pages, layer = 4, 8, 2, 7, 40, 1
+    lengths = [1, 2 * page + 5, 0, 6 * page + 4]   # GQA rep 4
+    bt = rng.permutation(num_pages - 1)[:b * mp].reshape(b, mp) + 1
+    clean = rng.standard_normal(
+        (N_LAYERS, num_pages, hkv, page, 2 * d)).astype(np.float32)
+    live = np.zeros(clean.shape[:2] + (1, page, 1), bool)
     for i, n in enumerate(lengths):
-        if n == 0:
-            np.testing.assert_array_equal(got[i], 0.0)
-        else:
-            np.testing.assert_allclose(got[i], want[i], rtol=2e-5,
-                                       atol=2e-5)
+        for col in range(-(-n // page)):
+            live[layer, bt[i, col], 0, :min(page, n - col * page)] = True
+        bt[i, -(-n // page):] = 0                  # columns past the length
+    poisoned = jnp.asarray(np.where(live, clean, np.nan), dtype)
+    zeroed = jnp.asarray(np.where(live, clean, 0.0), dtype)
+    bt, lens = jnp.asarray(bt, jnp.int32), jnp.asarray(lengths, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((b, hq, d)), dtype)
+    got = paged_attention_decode(q, poisoned, bt, lens, layer=layer,
+                                 pages_per_chunk=pages_per_chunk,
+                                 interpret=True)
+    want = paged_attention_reference(
+        q[:, None], zeroed, bt, jnp.maximum(lens - 1, 0)[:, None],
+        layer=layer)[:, 0]
+    _assert_rows_match(got, want, lengths, dtype)
 
 
 def test_decode_kernel_bf16():
