@@ -53,7 +53,9 @@ class LlamaConfig:
     num_kv_heads: int = 8
     head_dim: Optional[int] = None
     max_seq_len: int = 8192
-    rope_theta: float = 500000.0
+    # None: no rotation (a model whose other layers carry the order,
+    # models/jamba.py)
+    rope_theta: Optional[float] = 500000.0
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -163,6 +165,16 @@ class PagedCache:
     # kernel and reference lowerings never mix within or across engines.
     ref_attention: bool = struct.field(pytree_node=False, default=False)
 
+    # what a serving program carries from dispatch to dispatch, and how a
+    # fused decode step renews it (models/jamba.py: HybridCache has both)
+    @property
+    def pool(self):
+        return self.kv_pages
+
+    def step(self, pool, total_lens):
+        return self.replace(kv_pages=pool, total_lens=jnp.broadcast_to(
+            total_lens, self.block_tables.shape[:1] + total_lens.shape))
+
 
 class Attention(nn.Module):
     config: LlamaConfig
@@ -183,8 +195,9 @@ class Attention(nn.Module):
         q = q.reshape(b, s, nq, hd)
         k = k.reshape(b, s, nkv, hd)
         v = v.reshape(b, s, nkv, hd)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if cfg.rope_theta is not None:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         if isinstance(kv_cache, PagedCache):
             # Serving path: write new K/V into this layer's pages of the
             # pool, then attend. Decode (S == 1) streams only the used
@@ -671,6 +684,42 @@ class LlamaModel(nn.Module):
         if kv_caches is not None:
             return logits, new_caches
         return logits
+
+
+# ----------------------------------------------------------------- serving
+# What serve/llm/stage.py asks of a model family's module (this one and
+# models/jamba.py): get_config, serving_model, pool_spec, serving_cache.
+def serving_model(cfg: LlamaConfig, n_layers=None, first=True, last=True):
+    if first and last:
+        return LlamaModel(cfg)
+    return LlamaModel(cfg, n_layers=n_layers, first=first, last=last)
+
+
+def pool_spec(cfg: LlamaConfig, n_layers: int, num_pages: int,
+              page_size: int, slots: int):
+    """(shape, dtype) of the state a serving engine keeps on the device:
+    one page pool in the page-major combined layout [n_layers, P, Hkv,
+    page, 2*D]: one decode DMA per page moves K and V for every head
+    together; the Hkv axis is the tensor-parallel shard (each tp shard
+    holds Hkv/tp heads of EVERY page, so block tables stay global +
+    replicated). Nothing is kept per decode slot."""
+    return ((n_layers, num_pages, cfg.num_kv_heads, page_size,
+             2 * cfg.head_dim_), cfg.dtype)
+
+
+def serving_cache(cfg: LlamaConfig, pool, block_tables, total_lens=None,
+                  slots=None, **static) -> PagedCache:
+    """The cache one program pass hands the model: block_tables [B, MP]
+    and total_lens [B] (None: `PagedCache.step` brings them) tiled over
+    the pool's layers. `slots` is for models with per-slot state."""
+    tile = pool.shape[:1]
+    return PagedCache(
+        kv_pages=pool,
+        block_tables=jnp.broadcast_to(block_tables,
+                                      tile + block_tables.shape),
+        total_lens=None if total_lens is None else jnp.broadcast_to(
+            total_lens, tile + total_lens.shape),
+        **static)
 
 
 # ---------------------------------------------------------------- registry

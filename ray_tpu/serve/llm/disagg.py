@@ -52,6 +52,7 @@ class PrefillServer(EngineDriverMixin):
     def __init__(self, llm_config: LLMConfig):
         self.config = llm_config
         self.engine = LLMEngine(llm_config.engine)
+        self.engine._refuse_handoff()   # pages only: no recurrent state
         if getattr(llm_config, "warmup", True):
             self.engine.warmup(include_decode=False)
         self._ids = itertools.count()
@@ -131,6 +132,7 @@ class DecodeServer(EngineDriverMixin):
     def __init__(self, llm_config: LLMConfig):
         self.config = llm_config
         self.engine = LLMEngine(llm_config.engine)
+        self.engine._refuse_handoff()   # pages only: no recurrent state
         if getattr(llm_config, "warmup", True):
             # full warmup (not decode-only): page-pressure preemption
             # re-prefills on THIS engine, so prefill shapes are hit in

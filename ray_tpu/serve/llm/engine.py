@@ -90,6 +90,19 @@ Scheduler v2 (token-budget continuous batching), on top of the above:
   bit-exact vs plain greedy decode by construction. Draft page writes
   past the accepted prefix sit beyond the request's total and are
   rewritten by the next dispatch before they ever become visible.
+
+Models with recurrent (state-space) layers (models/jamba.py) keep, beside
+the attention layers' pages, a fixed-size state per DECODE SLOT. The same
+scheduler serves them: a request's slot is assigned at admission, its
+prefill row starts from ZERO state and leaves the row's final state in
+that slot (so a preempted request's re-prefill inherits nothing), and a
+decode dispatch leaves every slot it does not decode bit for bit. What
+that state cannot do yet is refused at construction, by name
+(`_refuse_for_recurrent_state`), never served wrong: a page found by
+content hash carries no state, so prefix reuse is off; state cannot be
+rolled back (speculation), resumed mid-prompt (chunked prefill), split
+(tp), cut by layer slices (pp) or handed to another engine (disaggregated
+prefill).
 """
 
 from __future__ import annotations
@@ -103,7 +116,7 @@ import numpy as np
 
 from ...util import tracing
 from .cache import OutOfPages, PageAllocator
-from .stage import _MAX_TOP_K, StageCompute
+from .stage import _MAX_TOP_K, StageCompute, serve_model_config, ssm_layers
 
 WAITING, RUNNING, FINISHED = "WAITING", "RUNNING", "FINISHED"
 # where a step's nanoseconds go (indices into LLMEngine._phase_ns, in the
@@ -261,6 +274,38 @@ class EngineConfig:
     pp_fetch_timeout_s: float = 60.0
 
 
+def _refuse_for_recurrent_state(config: EngineConfig, model_cfg,
+                                mesh) -> None:
+    """A model with state-space layers keeps per-slot recurrent state; the
+    engine options that would need to copy, split, roll back or resume
+    that state are refused here, each by the mechanism that is missing."""
+    if not ssm_layers(model_cfg):
+        return
+    model = f"model {config.model!r} keeps recurrent state-space state"
+    if config.spec_lookahead > 0:
+        raise NotImplementedError(
+            f"{model}: spec_lookahead={config.spec_lookahead} needs a "
+            f"verify dispatch whose rejected draft tokens can be rolled "
+            f"back, and a state advanced past them cannot be (no state "
+            f"snapshot yet)")
+    if config.prefill_chunk_tokens > 0:
+        raise NotImplementedError(
+            f"{model}: prefill_chunk_tokens={config.prefill_chunk_tokens} "
+            f"needs a prefill that resumes from a slot's state, and a "
+            f"prefill row starts from zero state")
+    if config.tp > 1 or mesh is not None:
+        raise NotImplementedError(
+            f"{model}: tensor parallelism (tp={config.tp}, mesh="
+            f"{'given' if mesh is not None else None}) would have to split "
+            f"the scan's d_inner axis and its per-slot state over the "
+            f"mesh, and nothing does yet")
+    if config.pp > 1:
+        raise NotImplementedError(
+            f"{model}: pipeline parallelism (pp={config.pp}) slices a "
+            f"uniform `layers` axis (stage_params), and this model's "
+            f"layers follow a pattern of two kinds with two kinds of state")
+
+
 def _bucket(n: int, buckets) -> int:
     for b in buckets:
         if n <= b:
@@ -275,6 +320,7 @@ class LLMEngine:
 
     def __init__(self, config: EngineConfig, params=None, mesh=None):
         self.config = config
+        _refuse_for_recurrent_state(config, serve_model_config(config), mesh)
         self._build_compute(params, mesh)
         self.max_pages_per_seq = config.max_model_len // config.page_size
 
@@ -370,6 +416,21 @@ class LLMEngine:
         if self._moe_LE:
             self._totals.update(moe_assignments_total=0,
                                 moe_experts_touched_total=0)
+        # layers with per-slot recurrent state (0: pages are all the state
+        # there is) and what one sequence's state costs to read or write
+        self._ssm_layers = ssm_layers(cfg_m)
+        # the `ssm_*` fields of its `engine.dispatch` records, behind the
+        # `moe_*` positions (None where it has no experts): the layers
+        # that keep per-slot state and what one live row's state costs to
+        # read or write once, so that a reader needs no knowledge of the
+        # model. Nothing for any other model.
+        self._ssm_fields: tuple = ()
+        if self._ssm_layers:
+            self._ssm_fields = (None,) * (0 if self._moe_LE else 3) + (
+                self._ssm_layers, cfg_m.ssm_state_bytes_row())
+            self._totals.update(ssm_scan_tokens_total=0,
+                                ssm_state_updates_total=0,
+                                prefix_reuse_refused_total=0)
         self._queue_wait_ns_total = 0
 
     # ----------------------------------------------------------- intake
@@ -382,6 +443,8 @@ class LLMEngine:
         plane); it is converted to the engine's monotonic domain here so
         queue-time pruning is immune to wall-clock steps."""
         sampling = sampling or SamplingParams()
+        if sampling.prefill_only:
+            self._refuse_handoff()
         if len(prompt_ids) + 1 > self.config.max_model_len:
             raise ValueError(
                 f"prompt of {len(prompt_ids)} tokens exceeds max_model_len "
@@ -597,6 +660,10 @@ class LLMEngine:
         page = self.config.page_size
         legacy = self.config.prefill_chunk_tokens <= 0
         lookahead = 1 if legacy else self._ADMIT_LOOKAHEAD
+        if self._ssm_layers:
+            # a page found by its content hash carries no recurrent state:
+            # no twin is deferred to share a prefix, nothing is matched
+            burst_prefixes = None
         head_id = self.waiting[0].request_id
         if self._head_overtaken[0] != head_id:
             self._head_overtaken = (head_id, 0)
@@ -611,8 +678,12 @@ class LLMEngine:
                     None, req.prompt_ids[:page])
                 if first_hash in burst_prefixes:
                     continue  # wait one step; the prefix cache will hit
-            cached_pages, n_cached = self.allocator.match_prefix(
-                req.prompt_ids)
+            if self._ssm_layers:
+                cached_pages, n_cached = [], 0
+                self._totals["prefix_reuse_refused_total"] += 1
+            else:
+                cached_pages, n_cached = self.allocator.match_prefix(
+                    req.prompt_ids)
             if qi > 0 and not cached_pages:
                 continue  # only prefix-sharers may pass a blocked head
             need = (-(-(len(req.prompt_ids) + 1) // page)
@@ -676,16 +747,18 @@ class LLMEngine:
         return tokens
 
     def _compute_prefill(self, sb, rb, cp, n_rows, bt, total, ids,
-                         positions, gather, temp, topk, keys):
+                         positions, gather, temp, topk, keys, *slots):
         """One prefill dispatch over the first `n_rows` of the wave-sized
         arrays; returns the sampled-tokens handle the harvest will
-        resolve via _fetch_tokens ([rb] int32)."""
+        resolve via _fetch_tokens ([rb] int32). `slots` (a model with
+        per-slot state, and only then): the decode slot of each row."""
         import jax.numpy as jnp
 
         return self._to_host_async(self.compute.run(
             "prefill", (sb, rb, cp), np.int32(n_rows), jnp.asarray(bt),
             jnp.asarray(total), jnp.asarray(ids), jnp.asarray(positions),
-            jnp.asarray(gather), temp, topk, keys))
+            jnp.asarray(gather), temp, topk, keys,
+            *map(jnp.asarray, slots)))
 
     def _compute_verify(self, sb, rb, n_rows, bt, total, ids, positions):
         """One speculative verify dispatch over the first `n_rows`;
@@ -843,10 +916,13 @@ class LLMEngine:
             bt = np.zeros((rb, self.max_pages_per_seq), np.int32)
             total = np.zeros((rb,), np.int32)
             gather = np.zeros((rb,), np.int32)
+            slots = np.zeros((rb,), np.int32) if self._ssm_layers else None
             rows = []
             facts = []
             for i, (req, n_new) in enumerate(group):
                 start = req.n_prefilled
+                if slots is not None:
+                    slots[i] = req.slot
                 ids[i, :n_new] = req.prompt_ids[start:start + n_new]
                 positions[i] = start + np.arange(sb, dtype=np.int32)
                 bt[i, :len(req.pages)] = req.pages
@@ -865,9 +941,9 @@ class LLMEngine:
                   if any(req.n_prefilled for req, _ in group) else 0)
             temp, topk, keys = self._sampling_arrays(
                 [req for req, _ in group], rb)
-            tokens = self._compute_prefill(sb, rb, cp, len(group), bt,
-                                           total, ids, positions, gather,
-                                           temp, topk, keys)
+            tokens = self._compute_prefill(
+                sb, rb, cp, len(group), bt, total, ids, positions, gather,
+                temp, topk, keys, *(() if slots is None else (slots,)))
             for req, n_new in group:
                 req.n_prefilled += n_new
                 if req.n_prefilled >= len(req.prompt_ids):
@@ -876,6 +952,9 @@ class LLMEngine:
             self._totals["prefill_tokens_total"] += sum(
                 n_new for _, n_new in group)
             self._totals["prefill_padded_tokens_total"] += computed * sb
+            if self._ssm_layers:
+                self._totals["ssm_scan_tokens_total"] += self._ssm_layers \
+                    * sum(n_new for _, n_new in group)
             self._enqueue("prefill", tokens, r.start_ns, computed,
                           computed * sb, facts, group=rows)
         self._phase_ns[_DISPATCH_PREFILL] += r.ns
@@ -1131,6 +1210,9 @@ class LLMEngine:
         self._totals["decode_rows_total"] += len(facts)
         self._totals["decode_ctx_tokens_total"] += sum(
             ctx for _, _, ctx in facts)
+        if self._ssm_layers:
+            self._totals["ssm_state_updates_total"] += (
+                len(facts) * k_steps * self._ssm_layers)
         self._enqueue("decode", toks, dispatch_ns, S, S * k_steps, facts,
                       k=k_steps, slots=chunk_slots)
 
@@ -1210,7 +1292,7 @@ class LLMEngine:
             rec["seq"], rec["kind"], rec["step"], self._step_seq,
             rec["dispatch_ns"], fetch.start_ns, fetch.end_ns,
             rec["rows_padded"], rec["tokens_padded"], rec["facts"],
-            rec["k"]) + moe_facts)
+            rec["k"]) + moe_facts + self._ssm_fields)
 
     def _split_counts(self, rec: dict, fetched: np.ndarray) -> tuple:
         """(tokens, the record's `moe_*` fields). An expert model's
@@ -1348,6 +1430,8 @@ class LLMEngine:
         prompt tokens — generated text is rarely shared). ``upto`` bounds
         registration to tokens whose KV has actually been written (a
         chunked prefill registers chunk by chunk as dispatches land)."""
+        if self._ssm_layers:
+            return  # a shared page would carry no recurrent state
         page = self.config.page_size
         n_prompt_full = len(req.prompt_ids) // page
         if upto is not None:
@@ -1388,6 +1472,17 @@ class LLMEngine:
 
     # ------------------------------------------- prefill/decode handoff
 
+    def _refuse_handoff(self) -> None:
+        """The disaggregated prefill -> decode hand-off moves pages only
+        (`_gather_kv`, kv_transfer.py)."""
+        if self._ssm_layers:
+            raise NotImplementedError(
+                f"model {self.config.model!r} keeps recurrent state-space "
+                f"state: the disaggregated prefill/decode hand-off "
+                f"(prefill_only, extract_kv, inject_request) moves KV "
+                f"pages only, and a request's per-slot state would be "
+                f"left behind")
+
     def _gather_kv(self, req: Request) -> Dict[str, Any]:
         now = time.monotonic()
         disp = req.dispatched_t if req.dispatched_t is not None \
@@ -1413,6 +1508,7 @@ class LLMEngine:
         between engines as dense arrays). Synchronous-driver use only;
         concurrent servers use SamplingParams(prefill_only=True) +
         pop_extracted, which gathers inside step()."""
+        self._refuse_handoff()
         self._drain_pipeline(self._pending_deltas)
         req = self.requests.get(request_id)
         if req is None or req.state != RUNNING:
@@ -1452,6 +1548,7 @@ class LLMEngine:
         step() scatters its KV pages and resumes decoding from its
         pending token. Queued (not applied inline) so injections respect
         the same max_batch/page admission control as fresh prompts."""
+        self._refuse_handoff()
         with self._intake_lock:
             self._injections.append(
                 (request_id, handoff, sampling or SamplingParams()))
@@ -1521,8 +1618,12 @@ class LLMEngine:
         rb = self._wave_rb
         if prompt_buckets is None:
             prompt_buckets = self.config.prefill_buckets
+        # a model with recurrent state never prefills behind a cached
+        # prefix: the variant with a prefix part could never run
+        prefix_parts = ((0,) if self._ssm_layers
+                        else (0, self.max_pages_per_seq))
         programs = [("prefill", (sb, rb, cp)) for sb, cp in product(
-            prompt_buckets, (0, self.max_pages_per_seq))]
+            prompt_buckets, prefix_parts)]
         if not include_decode:
             return programs
         if self.config.spec_lookahead > 0:
@@ -1573,6 +1674,10 @@ class LLMEngine:
                                      if self.compute else 0),
             "queue_wait_s_total": self._queue_wait_ns_total / 1e9,
         }
+        if self._ssm_layers and self.compute:
+            sizes = self.compute.pool_bytes()
+            out["ssm_state_pool_bytes"] = sizes["ssm_h"] + sizes["ssm_conv"]
+            out["ssm_slots"] = self.config.max_batch
         if self.sharding is not None:
             out["sharding"] = self.sharding.page_accounting(
                 self.config, self.model_cfg)

@@ -86,6 +86,18 @@ _LLM_WORK_TOTALS = {
         "real (token, expert) assignments of an expert model, all layers",
     "moe_experts_touched_total":
         "experts with at least one real token, summed over layers and steps",
+    "ssm_scan_tokens_total":
+        "real prompt tokens x state-space layers scanned (prefill)",
+    "ssm_state_updates_total":
+        "live rows x fused steps x state-space layers updated (decode)",
+    "prefix_reuse_refused_total":
+        "admissions that skipped the prefix lookup (recurrent state)",
+}
+# engine.stats() sizes published as rtpu_llm_<key> gauges
+_LLM_SIZES = {
+    "ssm_state_pool_bytes":
+        "bytes of the per-slot recurrent state pool (state-space layers)",
+    "ssm_slots": "decode slots of the recurrent state pool",
 }
 
 
@@ -134,6 +146,8 @@ def _get_llm_metrics():
         }
         for key, what in _LLM_WORK_TOTALS.items():
             _LLM_METRICS[key] = Counter(f"rtpu_llm_{key}", what)
+        for key, what in _LLM_SIZES.items():
+            _LLM_METRICS[key] = Gauge(f"rtpu_llm_{key}", what)
     return _LLM_METRICS
 
 
@@ -199,6 +213,9 @@ class EngineDriverMixin:
         m["waiting"].set(stats.get("waiting", 0))
         m["running"].set(stats.get("running", 0))
         m["pages_free"].set(stats.get("pages_free", 0))
+        for key in _LLM_SIZES:
+            if key in stats:
+                m[key].set(stats[key])
         for key, mk in (("preempted_total", "preempted"),
                         ("spec_drafted_total", "spec_drafted"),
                         ("spec_accepted_total", "spec_accepted"),

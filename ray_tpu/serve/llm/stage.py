@@ -15,6 +15,15 @@ hidden states on.
 The operands of a program after its state (params, pool[, carry]) are
 `OPERANDS[kind]`, in order; a pipelined frame (pp.py) is those names in
 a dict. "x" is the stage's input: ids or hidden states.
+
+"The pool" is whatever the model's family keeps on the device between
+dispatches (`model_family(...).pool_spec`): one array of pages for a
+decoder whose every layer is attention, pages AND per-slot arrays for one
+with state-space layers (models/jamba.py). Either way it is ONE donated
+argument, carried whole through the row loop and the step scan, and the
+model's cache object (`serving_cache`) is the only code that looks inside.
+A model with per-slot state gets one more prefill operand, the decode
+slot of each row (`SLOTS_OPERAND`).
 """
 
 from __future__ import annotations
@@ -33,6 +42,10 @@ OPERANDS = {
     "decode": ("bt", "total", "caps", "positions", "override_mask", "x",
                "temp", "topk", "keys"),
 }
+
+# after OPERANDS["prefill"], for a model that keeps per-slot state: the
+# decode slot each row of the wave leaves its state in
+SLOTS_OPERAND = "slots"
 
 _MAX_TOP_K = 64
 
@@ -62,24 +75,33 @@ def serve_dtype(config):
     return jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32
 
 
+def model_family(name: str):
+    """The module that defines the preset `name`: the one place that
+    chooses a model family from `EngineConfig.model`. A family's module
+    has `CONFIGS`, `get_config`, `serving_model`, `pool_spec` and
+    `serving_cache` (models/llama.py, models/jamba.py)."""
+    from ...models import jamba, llama
+
+    for family in (llama, jamba):
+        if name in family.CONFIGS:
+            return family
+    raise KeyError(f"no model preset {name!r}; have "
+                   f"{sorted([*llama.CONFIGS, *jamba.CONFIGS])}")
+
+
 def serve_model_config(config):
     """The WHOLE model's config as every serving program sees it."""
-    from ...models.llama import get_config
-
     dtype = serve_dtype(config)
-    return get_config(
+    return model_family(config.model).get_config(
         config.model, scan_layers=True, remat=False, dtype=dtype,
         param_dtype=dtype, max_seq_len=config.max_model_len,
         **config.model_overrides)
 
 
-def pool_shape(config, model_cfg, n_layers: int) -> tuple:
-    """Page-major combined layout [n_layers, P, Hkv, page, 2*D]: one
-    decode DMA per page moves K and V for every head together; the Hkv
-    axis is the tensor-parallel shard (each tp shard holds Hkv/tp heads of
-    EVERY page, so block tables stay global + replicated)."""
-    return (n_layers, config.num_pages, model_cfg.num_kv_heads,
-            config.page_size, 2 * model_cfg.head_dim_)
+def ssm_layers(model_cfg) -> int:
+    """How many of the model's layers keep recurrent (state-space) state
+    per sequence; 0 for a decoder whose only state is pages."""
+    return getattr(model_cfg, "n_mamba_layers", 0)
 
 
 def init_params(model, example, rng):
@@ -142,12 +164,14 @@ def resolve_attention(model_cfg, config, sharding) -> Dict[str, str]:
 
 
 def dummy_operands(config, kind: str, shape_key: tuple,
-                   hidden: Optional[tuple] = None) -> tuple:
+                   hidden: Optional[tuple] = None,
+                   slots: bool = False) -> tuple:
     """Masked `OPERANDS[kind]` for one dispatch of a program:
     total_lens=0 masks every page write and a row pass is given no real
     row, so running them leaves a stage's state untouched. Shared by
     warm-up (in process and through the stage DAG) and program_text.
-    `hidden` = (width, dtype) makes "x" a later stage's hidden states."""
+    `hidden` = (width, dtype) makes "x" a later stage's hidden states;
+    `slots` adds a prefill's SLOTS_OPERAND."""
     import jax.numpy as jnp
 
     mp = config.max_model_len // config.page_size
@@ -173,7 +197,8 @@ def dummy_operands(config, kind: str, shape_key: tuple,
     if kind == "verify":
         return rows
     return rows + (z((rb,)), np.zeros((rb,), np.float32),
-                   np.zeros((rb,), np.int32), np.zeros((rb, 2), np.uint32))
+                   np.zeros((rb,), np.int32), np.zeros((rb, 2), np.uint32)
+                   ) + ((z((rb,)),) if slots else ())
 
 
 class StageCompute:
@@ -187,7 +212,6 @@ class StageCompute:
         import jax
         import jax.numpy as jnp
 
-        from ...models.llama import LlamaModel
         from ...util.compile_cache import enable_compile_cache
         from .sharding import resolve_serve_mesh
 
@@ -201,8 +225,11 @@ class StageCompute:
         self.first = first_layer == 0
         self.last = first_layer + n_layers == cfg.num_layers
         whole = self.first and self.last
-        self.model = LlamaModel(cfg) if whole else LlamaModel(
-            cfg, n_layers=n_layers, first=self.first, last=self.last)
+        self.family = family = model_family(config.model)
+        self.model = family.serving_model(cfg, n_layers, self.first,
+                                          self.last)
+        # layers of this slice that keep per-slot recurrent state
+        self.ssm_layers = ssm_layers(cfg)
         self.max_pages_per_seq = config.max_model_len // config.page_size
         # tensor parallelism: resolve mesh/tp BEFORE any compute so the
         # divisibility contract fails at construction, not first dispatch
@@ -235,8 +262,10 @@ class StageCompute:
             params = init(jax.random.PRNGKey(config.seed))
         self.params = params
 
-        shape = pool_shape(config, cfg, n_layers)
+        spec = family.pool_spec(cfg, n_layers, config.num_pages,
+                                config.page_size, config.max_batch)
         if self.sharding is not None:
+            shape = spec[0]
             # zero-fill compiled WITH the sharding: each chip only ever
             # allocates its Hkv/tp slice of the pool (num_pages is sized
             # against per-shard HBM — sharding.pages_for_budget)
@@ -247,7 +276,10 @@ class StageCompute:
                 jnp.zeros((config.max_batch, 1), jnp.int32),
                 self._repl_sharding)
         else:
-            self.kv_pages = jnp.zeros(shape, dtype)
+            # one array of pages, or the family's name -> array
+            self.kv_pages = jax.tree.map(
+                lambda sd: jnp.zeros(*sd), spec,
+                is_leaf=lambda sd: isinstance(sd, tuple))
             # device-resident last-sampled-token per slot: the decode
             # chain's carry (design rule 2 in engine.py's docstring)
             self.slot_ids = jnp.zeros((config.max_batch, 1), jnp.int32)
@@ -280,9 +312,19 @@ class StageCompute:
 
     # ------------------------------------------------------------- pool
 
+    def pool_bytes(self) -> Dict[str, int]:
+        """Bytes of each part of the pool ("kv_pages" alone for a model
+        that keeps pages only)."""
+        pool = self.kv_pages
+        if not isinstance(pool, dict):
+            pool = {"kv_pages": pool}
+        return {k: int(a.size) * a.dtype.itemsize for k, a in pool.items()}
+
     def read_pages(self, pages) -> np.ndarray:
         """[n_layers, len(pages), Hkv, page, 2*D] on the host. Eager: the
-        caller has drained every dispatch first."""
+        caller has drained every dispatch first. (Pages only: an engine
+        whose model keeps per-slot state refuses the hand-off that calls
+        this, engine.py.)"""
         return np.asarray(self.kv_pages[:, np.asarray(pages, np.int32)])
 
     def write_pages(self, pages, kv) -> None:
@@ -320,9 +362,8 @@ class StageCompute:
         import jax
         import jax.numpy as jnp
 
-        from ...models.llama import PagedCache
-
         model, cfg, L = self.model, self.model_cfg, self.n_layers
+        serving_cache = self.family.serving_cache
         first, last = self.first, self.last
         # sharded stages trace under GSPMD, where the single-device
         # Pallas kernels cannot run: pin the reference attention paths
@@ -374,27 +415,28 @@ class StageCompute:
             cp = shape_key[2] if kind == "prefill" else self.max_pages_per_seq
 
             def row_pass(params, kv_pages, n_rows, block_tables, total_lens,
-                         x, positions, kept, keep):
+                         x, positions, kept, keep, slots=None):
                 """The arrays come at the wave size; the first `n_rows`
                 are requests and only those are computed, one [1 x sb]
                 pass of the model a row (the trip count is data, so every
                 row count is this one program). `keep(out, i)` is what
                 row i leaves in `kept`. The pool rides the loop's carry as
-                it rides the layer scan's, in place."""
+                it rides the layer scan's, in place. `slots`: the decode
+                slot a row leaves its per-slot state in."""
                 def row(i, carry):
                     kvp, kept, counts = carry
                     bt, tot, xi, pos = (
                         jax.lax.dynamic_slice_in_dim(a, i, 1)
                         for a in (block_tables, total_lens, x, positions))
-                    pc = PagedCache(
-                        kv_pages=kvp,
-                        block_tables=jnp.broadcast_to(bt, (L,) + bt.shape),
-                        total_lens=jnp.broadcast_to(tot, (L,) + tot.shape),
+                    pc = serving_cache(
+                        cfg, kvp, bt, tot,
+                        None if slots is None else
+                        jax.lax.dynamic_slice_in_dim(slots, i, 1),
                         ctx_pages=cp, ref_attention=ref_attn)
                     out, new_pc, c = apply(params, xi, pos, pc, tot)
                     kept = jax.lax.dynamic_update_slice_in_dim(
                         kept, keep(out, i), i, 0)
-                    return (new_pc.kv_pages, kept,
+                    return (new_pc.pool, kept,
                             None if c is None else counts + c)
 
                 counts0 = (None if moe_LE is None
@@ -413,7 +455,7 @@ class StageCompute:
 
             def run_prefill(params, kv_pages, n_rows, block_tables,
                             total_lens, x, positions, gather_idx,
-                            temperature, top_k, rng_keys):
+                            temperature, top_k, rng_keys, slots=None):
                 if not last:
                     return hidden_rows(params, kv_pages, n_rows,
                                        block_tables, total_lens, x,
@@ -423,7 +465,7 @@ class StageCompute:
                     params, kv_pages, n_rows, block_tables, total_lens, x,
                     positions, jnp.zeros((rb, cfg.vocab_size), jnp.float32),
                     lambda out, i: out[0, gather_idx[i]].astype(
-                        jnp.float32)[None])
+                        jnp.float32)[None], slots)
                 # sample ON DEVICE: only B int32 tokens cross to the host
                 # per step, never the [B, V] fp32 logits
                 tokens = _device_sample(rows, temperature, top_k, rng_keys)
@@ -465,19 +507,16 @@ class StageCompute:
             # x: the host-known ids of the slots `override_mask` names
             # (the others' come from the carry); on a later stage, every
             # slot's hidden states
-            bt_b = jnp.broadcast_to(block_tables,
-                                    (L,) + block_tables.shape)
+            cache = serving_cache(cfg, kv_pages, block_tables,
+                                  ref_attention=ref_attn)
             active = total_lens > 0
             x0 = (jnp.where(override_mask[:, None], x, slot_ids)
                   if first else x)
 
             def body(carry, keys_k):
                 x_k, pos, kvp, tot = carry
-                pc = PagedCache(
-                    kv_pages=kvp, block_tables=bt_b,
-                    total_lens=jnp.broadcast_to(tot, (L,) + tot.shape),
-                    ref_attention=ref_attn)
-                out, new_pc, counts = apply(params, x_k, pos, pc, tot)
+                out, new_pc, counts = apply(params, x_k, pos,
+                                            cache.step(kvp, tot), tot)
                 if last:
                     rows = out[:, 0].astype(jnp.float32)
                     out = _device_sample(rows, temperature, top_k, keys_k)
@@ -494,7 +533,7 @@ class StageCompute:
                 new_pos = jnp.minimum(pos + 1, caps[:, None] - 1)
                 # the next step's ids; a stage of a pipeline has no next step
                 x_next = out[:, None].astype(jnp.int32) if whole else x_k
-                return ((x_next, new_pos, new_pc.kv_pages, new_tot),
+                return ((x_next, new_pos, new_pc.pool, new_tot),
                         (out, counts))
 
             carry = (x0, positions, kv_pages, total_lens)
@@ -526,7 +565,7 @@ class StageCompute:
         return jax.jit(
             fn, donate_argnums=donate,
             in_shardings=(self._param_shardings, kv) + (repl,) * (
-                n_state - 2 + len(OPERANDS[kind])),
+                n_state - 2 + len(self.operands(kind))),
             out_shardings=(repl,) * (n_state - 1) + (kv,))
 
     def _state(self, kind: str) -> tuple:
@@ -546,10 +585,17 @@ class StageCompute:
             self.slot_ids = state[0]
         return out
 
+    def operands(self, kind: str) -> tuple:
+        """The names of what `run(kind, ...)` takes after the shape key."""
+        if kind == "prefill" and self.ssm_layers:
+            return OPERANDS[kind] + (SLOTS_OPERAND,)
+        return OPERANDS[kind]
+
     def dummy_args(self, kind: str, shape_key: tuple) -> tuple:
         return dummy_operands(
             self.config, kind, shape_key,
-            None if self.first else (self.model_cfg.hidden_size, self.dtype))
+            None if self.first else (self.model_cfg.hidden_size, self.dtype),
+            slots=kind == "prefill" and bool(self.ssm_layers))
 
     def program_text(self, kind: str, shape_key: tuple) -> str:
         """The lowered (StableHLO) text of one dispatch program — what

@@ -146,12 +146,16 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
     # The three `moe_*` fields are written for an expert model only (a
     # dense model's record ends at `k`): real assignments (real tokens x
     # experts per token x layers), experts with at least one real token
-    # summed over layers and fused steps, the fullest expert's count
+    # summed over layers and fused steps, the fullest expert's count.
+    # The two `ssm_*` fields are written for a model with state-space
+    # layers only (its `moe_*` positions hold None unless it has experts
+    # too): how many layers keep per-slot recurrent state, and the bytes
+    # one live row's state costs to read or write once over all of them
     "engine.dispatch": (
         "seq", "kind", "step_dispatched", "step_harvested", "dispatch_ns",
         "fetch_start_ns", "fetch_end_ns", "rows_padded", "tokens_padded",
         "rows", "k", "moe_assignments", "moe_experts_touched",
-        "moe_expert_tokens_max"),
+        "moe_expert_tokens_max", "ssm_layers", "ssm_state_bytes_row"),
     # one per LLMEngine.step()
     "engine.step": (
         "seq", "start_ns", "end_ns", "intake_ns", "admit_ns",

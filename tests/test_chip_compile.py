@@ -405,3 +405,72 @@ def test_prefill_program_computes_one_row_a_pass(compiled_programs, name):
     # the row loop and the layer scan inside it (a scan over one layer
     # is no loop)
     assert len(re.findall(r" while\(", text)) >= 1 + (stage.n_layers > 1)
+
+
+# ------------------------------- a model with state-space layers, whole
+@pytest.mark.parametrize("kind, span", [("decode", 1), ("prefill", 2048)])
+def test_jamba_program_fits_and_carries_both_pools_in_place(
+        topo, no_persistent_cache, kind, span):
+    """AI21-Jamba2-3B at its published size, all 28 layers, as the cell
+    `jamba2-3b-chat` runs it (64 slots, 10,753 pages): the program fits
+    the chip, holds the selective-scan kernel (prefill) and the paged
+    decode kernel at 20 q heads on 1 kv head (decode), aliases the pages
+    and both state arrays from argument to result, and moves no array as
+    large as a state array or as a run of Mamba layers' weights (the
+    period's slice of a [periods, run, ...] stack was such a copy: 2.7 GB
+    a decode step)."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    from ray_tpu.serve.llm.stage import init_params
+
+    cfg = EngineConfig(
+        model="jamba2-3b", dtype="bfloat16", page_size=PAGE, num_pages=10753,
+        max_model_len=2688, max_batch=64, prefill_buckets=(128, 2048))
+    engine = LLMEngine(cfg, params={})
+    stage = engine.compute
+    stage.params = jax.eval_shape(lambda: init_params(
+        stage.model, jnp.zeros((1, 8), jnp.int32), jax.random.PRNGKey(0)))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(a):
+        a = a if hasattr(a, "shape") else np.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    key = ((1, engine.max_pages_per_seq) if kind == "decode"
+           else (span, engine._wave_rb, 0))
+    assert stage.operands("prefill")[-1] == "slots"
+    with pytest.MonkeyPatch.context() as mp_ctx:
+        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = stage.program(kind, key).lower(*jax.tree.map(
+            sds, (*stage._state(kind), *stage.dummy_args(kind, key)))
+        ).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total <= HBM_GIB * 2 ** 30, total / 2 ** 30
+    kernels = set(re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
+    if kind == "decode":
+        assert any(k.startswith("_decode_call") for k in kernels), kernels
+    else:
+        assert {"_ssm_scan", "_ssm_scan.3"} <= kernels, kernels
+        assert any(k.startswith("attn.") for k in kernels), kernels
+    pools = {name: tuple(a.shape) for name, a in stage.kv_pages.items()}
+    assert (pools["ssm_h"], pools["ssm_conv"]) == ((26, 64, 16, 8, 640),
+                                                   (26, 3, 64, 5120))
+    run_bytes = 2 * 2560 * 10240 * 2          # two layers' W_in
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not m or m.group(2) not in MOVES:
+            continue
+        for dims, _ in _array_types(m.group(1)):
+            # an update in place has the pool's shape: its operand is the
+            # donated buffer, and that it aliases is asserted below
+            if dims in pools.values():
+                if m.group(2) != "dynamic-update-slice":
+                    moved.append(line.strip()[:160])
+            elif int(np.prod(dims)) * 2 >= run_bytes:
+                moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    header = text.split("\n", 1)[0]
+    aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliased) >= (4 if kind == "decode" else 3), header[:400]
