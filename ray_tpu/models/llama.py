@@ -365,6 +365,7 @@ class MoEMLP(nn.Module):
         E, k = cfg.num_experts, cfg.num_experts_per_tok
         T, h = xt.shape
         M = T * k
+        tm, aligned, rows, block = moe_row_layout(T, cfg)
         expert = idx.reshape(M)              # assignment t*k+j: token t
         if token_mask is not None:
             # padding joins the trailing group E, which multiplies nothing
@@ -376,33 +377,54 @@ class MoEMLP(nn.Module):
                  reduce_fn=lambda a, b: a + b,
                  init_fn=lambda: jnp.zeros((E,), jnp.int32))
         w_gu, w_dn = w_gu.astype(cfg.dtype), w_dn.astype(cfg.dtype)
-        ends = jnp.cumsum(counts)            # where each expert's rows end
+        # `order[s]` is the assignment at place s of the packed order (by
+        # expert, padding last). Row p of what the experts multiply holds
+        # the assignment `at[p]`, expert e owns `sizes[e]` rows, and every
+        # assignment comes back `shift[its expert]` rows below its place.
+        sizes, at, shift = counts, order, None
+        if aligned:
+            # every expert's rows start on a tile of the kernel: group e
+            # moves `shift[e]` rows down and is padded to whole tiles (the
+            # padding repeats some real row: multiplied with the tile it
+            # shares either way, never read back); the model's own padding
+            # follows the last group
+            sizes = -(-counts // tm) * tm
+            shift = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                     jnp.cumsum(sizes - counts)])  # [E + 1]
+        ends = jnp.cumsum(sizes)             # where each expert's rows end
+        if aligned:
+            owner = jnp.searchsorted(ends, jnp.arange(rows), side="right")
+            at = order[jnp.clip(jnp.arange(rows) - shift[owner], 0, M - 1)]
 
-        def experts_on(lo, rows):
-            """The expert FFN on `rows` sorted assignments from `lo` on;
-            rows past the last real assignment come back undefined."""
-            here = jnp.diff(jnp.clip(ends, lo, lo + rows), prepend=lo)
-            at = jax.lax.dynamic_slice(order, (lo,), (rows,))
-            gu = grouped_matmul(xt[at // k], w_gu, here, layer)
+        def experts_on(lo, n):
+            """The expert FFN on the `n` rows from `lo` on; rows past the
+            last group's come back undefined."""
+            here = jnp.diff(jnp.clip(ends, lo, lo + n), prepend=lo)
+            rows_at = jax.lax.dynamic_slice(at, (lo,), (n,))
+            gu = grouped_matmul(xt[rows_at // k], w_gu, here, layer, tm)
             gate_p, up_p = jnp.split(gu, 2, axis=-1)
-            return grouped_matmul(nn.silu(gate_p) * up_p, w_dn, here, layer)
+            return grouped_matmul(nn.silu(gate_p) * up_p, w_dn, here, layer,
+                                  tm)
 
-        # A wave's sorted assignments go through the experts _MOE_ROWS at a
-        # time: the [rows, 2f] intermediate stays small whatever the wave,
-        # and a block that holds padding only (all of a wave's tail, when
-        # 15 of its 16 rows are padding) is skipped, so the elementwise
-        # work between the two matmuls follows the real tokens too.
-        if M <= _MOE_ROWS or M % _MOE_ROWS:
-            y = experts_on(0, M)
+        # A wave's rows go through the experts `block` at a time: the
+        # [block, 2f] intermediate stays small whatever the wave, and a
+        # block past the last group (all of a wave's tail, when 15 of its
+        # 16 rows are padding) is skipped, so the elementwise work between
+        # the two matmuls follows the real tokens too.
+        if block == rows:
+            y = experts_on(0, rows)
         else:
             y = jax.lax.map(
                 lambda lo: jax.lax.cond(
-                    lo < ends[-1], lambda: experts_on(lo, _MOE_ROWS),
-                    lambda: jnp.zeros((_MOE_ROWS, h), cfg.dtype)),
-                jnp.arange(0, M, _MOE_ROWS)).reshape(M, h)
+                    lo < ends[-1], lambda: experts_on(lo, block),
+                    lambda: jnp.zeros((block, h), cfg.dtype)),
+                jnp.arange(0, rows, block)).reshape(rows, h)
         # back to token order; the k weighted outputs are summed in
         # float32, in the same order wherever the token sits
-        y = y[jnp.argsort(order)].reshape(T, k, h)
+        row_of = jnp.argsort(order)
+        if aligned:
+            row_of = row_of + shift[expert]
+        y = y[row_of].reshape(T, k, h)
         out = jnp.einsum("tkh,tk->th", y.astype(jnp.float32),
                          gate).astype(cfg.dtype)
         if token_mask is not None:
@@ -447,6 +469,42 @@ class MoEMLP(nn.Module):
 # sorted assignments per pass through the experts (MoEMLP._dropless): a
 # [4096, 2f] intermediate is 235 MB at Mixtral widths
 _MOE_ROWS = 4096
+
+
+def moe_row_layout(tokens: int, cfg: LlamaConfig) -> tuple:
+    """(tm, aligned, rows, block) of the dropless layer's pass over `tokens`
+    tokens: the grouped matmul's m-tile and whether each expert's rows
+    start on one (`ops/grouped_matmul.py: row_tile`, from the tokens * k
+    assignments and the experts alone), the rows that layout takes, and
+    how many of them ONE grouped-matmul call gets (all, or _MOE_ROWS at a
+    time)."""
+    from ..ops.grouped_matmul import aligned_rows, row_tile
+
+    m, e = tokens * cfg.num_experts_per_tok, cfg.num_experts
+    tm, aligned = row_tile(m, e)
+    rows = aligned_rows(m, e, tm) if aligned else m
+    if rows <= _MOE_ROWS or (not aligned and rows % _MOE_ROWS):
+        return tm, aligned, rows, rows
+    return tm, aligned, -(-rows // _MOE_ROWS) * _MOE_ROWS, _MOE_ROWS
+
+
+def moe_tile_rows(counts, tokens: int, cfg: LlamaConfig) -> int:
+    """Rows the grouped-matmul kernel MULTIPLIES (tile visits x m-tile) for
+    passes of `tokens` tokens through dropless layers whose experts got
+    `counts` ([..., E] real assignments, a pass a row of E): a layer's two
+    products run over the same rows and tile, so this counts them once.
+    The real assignments over it are the fill of the tiles. Host
+    arithmetic on counts the engine fetched anyway (a pass cut into
+    _MOE_ROWS blocks counts the same: blocks end on tile boundaries)."""
+    import numpy as np
+
+    from ..ops.grouped_matmul import tile_visits
+
+    tm, aligned = moe_row_layout(tokens, cfg)[:2]
+    counts = np.asarray(counts)
+    if aligned:
+        counts = -(-counts // tm) * tm
+    return tile_visits(counts, tm) * tm
 
 
 def _stacked_experts(module: nn.Module, cfg: LlamaConfig, kv_caches):

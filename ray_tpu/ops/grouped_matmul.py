@@ -30,13 +30,89 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-# (m, k, n) tile of the megablox kernel. One choice for every shape the
-# engine and the trainer compile: 10 MiB of VMEM with double buffering,
-# inside the 16 MiB a kernel gets unasked on v5e. Tuning it per shape is
-# the next perf_opt's (PERF.md section 7).
-TILING = (512, 1024, 1024)
-TILE_M_SMALL = 128   # decode programs: M = max_batch * k rows
+# The tile rule's only constants. VMEM_BYTES: what a kernel gets unasked on
+# v5e. TILE_M_MIN: the MXU's own rows. BALANCE_ROWS: the rows at which one
+# (tile, expert) visit's products take as long as streaming the [K, N]
+# weights it multiplies them by (a visit makes 2 * tm operations a 2-byte
+# weight element; the chip does 197e12 / 819e9 = 240 a byte,
+# chipbench/peaks.json), to the power of two.
+VMEM_BYTES = 16 * 2 ** 20
+TILE_M_MIN, BALANCE_ROWS = 128, 256
+
+
+def row_tile(m: int, e: int) -> tuple:
+    """(tm, aligned) for a call of `m` assignments on `e` experts: the
+    kernel's m-tile, and whether the caller should start every expert's
+    rows on a tile boundary (`aligned_rows`), from the shape alone.
+
+    megablox visits every (m-tile, expert) pair that shares a row,
+    multiplies the WHOLE [tm, K] x [K, N] tile at each visit whatever part
+    of it is that expert's, and streams that expert's [K, N] once a visit.
+    On the chip (benchmarks/moe_gmm_probe.py, PERF.md section 6, PR 34) a
+    visit costs the longer of the two: up to BALANCE_ROWS what counts is
+    the number of visits, past it the rows multiplied (two visits of 256
+    rows cost what one of 512 does, and waste less of a tile that is not
+    full). With groups packed end to end a call makes m / tm visits and
+    one more for every group that starts inside a tile (up to e - 1: 7 of
+    8-13 at Mixtral's prefill shapes); with every group on a tile boundary
+    it makes ceil(rows / tm) an expert, ONE where the tile holds what the
+    expert gets. So: groups on tile boundaries, on BALANCE_ROWS, or on
+    TILE_M_MIN (a visit an eighth cheaper) where that holds an expert's
+    share m / e of a full call with half again for uneven routing.
+    Alignment costs `e` tiles of rows that the elementwise work between
+    the two products passes too, so it is asked for only where the share
+    is half the smallest tile or more; under that (a decode step, the
+    shortest bucket, many small experts on a short call) rows stay packed
+    on the smallest tile."""
+    share = -(-m // e)
+    if 2 * share < TILE_M_MIN:
+        return TILE_M_MIN, False
+    return (TILE_M_MIN if 3 * share <= 2 * TILE_M_MIN else BALANCE_ROWS), True
+
+
+def aligned_rows(m: int, e: int, tm: int) -> int:
+    """Rows that hold `m` assignments with each of `e` groups padded to
+    whole tiles, however they are routed."""
+    return -(-m // tm) * tm + e * tm
+
+
+def tile_for(m: int, e: int, k: int, n: int) -> tuple:
+    """The (tm, tk, tn) tile of the megablox kernel for `m` assignments on
+    `e` experts of [k, n]: a shape in, a tile out. `tm` is `row_tile`'s.
+    The rows are read again for every n-tile, which is tm / tn of the
+    weights' own traffic: `tn` is 8 * tm where the double-buffered blocks
+    and the float32 accumulator fit VMEM_BYTES, halved until they do."""
+    return _tile(row_tile(m, e)[0], k, n)
+
+
+def _tile(tm: int, k: int, n: int) -> tuple:
+    tk, tn = min(1024, k), min(8 * tm, n)
+    while tile_vmem_bytes((tm, tk, tn)) > VMEM_BYTES and tn > 1024:
+        tn //= 2
+    return tm, tk, tn
+
+
+def tile_vmem_bytes(tile: tuple, itemsize: int = 2) -> int:
+    """VMEM the kernel holds at a tile: lhs, rhs and out blocks double
+    buffered, and the float32 accumulator."""
+    tm, tk, tn = tile
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def tile_visits(group_sizes, tm: int) -> int:
+    """How many (m-tile, group) pairs the kernel multiplies for these group
+    sizes (consecutive runs of rows from row 0) at m-tile `tm`: for each
+    non-empty group, the tiles its rows span. Rows multiplied are this
+    times `tm`. Plain numpy, on the host: the probe, the tests and the
+    engine's `moe_tile_rows_total` share it. `group_sizes` may carry leading
+    axes ([steps, L, E]): each row of groups is a call of its own."""
+    sizes = np.asarray(group_sizes, np.int64)
+    ends = np.cumsum(sizes, axis=-1)
+    starts = ends - sizes
+    spans = -(-ends // tm) - starts // tm
+    return int(np.where(sizes > 0, spans, 0).sum())
 
 
 def _impl() -> str:
@@ -46,67 +122,76 @@ def _impl() -> str:
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array,
-                   group_sizes: jax.Array,
-                   layer: jax.Array = None) -> jax.Array:
+                   group_sizes: jax.Array, layer: jax.Array = None,
+                   tm: int = None) -> jax.Array:
     """lhs [M, K] x rhs [E, K, N] -> [M, N] in lhs's type; see the module
     docstring. With `layer` (a traced index), rhs is a whole [L, E, K, N]
     stack and the groups are layer `layer`'s experts: the stack is read in
     place, as L*E groups of which all but E are empty, instead of being
-    sliced (a slice handed to a kernel is a copy)."""
+    sliced (a slice handed to a kernel is a copy). `tm`: the kernel's
+    m-tile, for a caller that laid the groups out by `row_tile`; otherwise
+    `row_tile`'s for M rows (any tile gives the same result)."""
+    if tm is None:
+        tm = row_tile(lhs.shape[0], group_sizes.shape[0])[0]
+    rhs, group_sizes = stacked_groups(rhs, group_sizes, layer)
+    return _moe_gmm(lhs, rhs, group_sizes, tm=tm, impl=_impl())
+
+
+def stacked_groups(rhs, group_sizes, layer):
+    """(rhs [G, K, N], group_sizes [G] int32) as the kernel takes them: a
+    whole [L, E, K, N] stack becomes L * E groups, layer `layer`'s E sizes
+    in their place among zeros."""
     group_sizes = group_sizes.astype(jnp.int32)
-    if layer is not None:
-        n_layers, e = rhs.shape[:2]
-        rhs = rhs.reshape((n_layers * e,) + rhs.shape[2:])
-        group_sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_layers * e,), jnp.int32), group_sizes,
-            (layer * e,))
-    return _moe_gmm(lhs, rhs, group_sizes, impl=_impl())
+    if layer is None:
+        return rhs, group_sizes
+    n_layers, e = rhs.shape[:2]
+    return (rhs.reshape((n_layers * e,) + rhs.shape[2:]),
+            jax.lax.dynamic_update_slice(
+                jnp.zeros((n_layers * e,), jnp.int32), group_sizes,
+                (layer * e,)))
 
 
-@functools.partial(jax.jit, static_argnames=("impl",))
-def _moe_gmm(lhs, rhs, group_sizes, *, impl: str):
+@functools.partial(jax.jit, static_argnames=("tm", "impl"))
+def _moe_gmm(lhs, rhs, group_sizes, *, tm: int, impl: str):
     if impl == "ragged_dot":
         return jax.lax.ragged_dot(lhs, rhs, group_sizes)
     m = lhs.shape[0]
-    tm, tk, tn = TILING
-    if m <= TILE_M_SMALL * 2:
-        tm = TILE_M_SMALL
-    tiling = (tm, min(tk, rhs.shape[1]), min(tn, rhs.shape[2]))
     pad = (-m) % tm   # the kernel wants whole m-tiles
     return _megablox(jnp.pad(lhs, ((0, pad), (0, 0))), rhs, group_sizes,
-                     tiling, impl == "megablox_interpret")[:m]
+                     _tile(tm, *rhs.shape[1:]),
+                     impl == "megablox_interpret")[:m]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _megablox(lhs, rhs, group_sizes, tiling, interpret):
+def _megablox(lhs, rhs, group_sizes, tile, interpret):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
-    return gmm.__wrapped__(lhs, rhs, group_sizes, lhs.dtype, tiling,
+    return gmm.__wrapped__(lhs, rhs, group_sizes, lhs.dtype, tile,
                            interpret=interpret)
 
 
-def _megablox_fwd(lhs, rhs, group_sizes, tiling, interpret):
-    return (_megablox(lhs, rhs, group_sizes, tiling, interpret),
+def _megablox_fwd(lhs, rhs, group_sizes, tile, interpret):
+    return (_megablox(lhs, rhs, group_sizes, tile, interpret),
             (lhs, rhs, group_sizes))
 
 
-def _megablox_bwd(tiling, interpret, res, grad):
-    return (*_moe_gmm_bwd(*res, grad, tiling=tiling, interpret=interpret),
+def _megablox_bwd(tile, interpret, res, grad):
+    return (*_moe_gmm_bwd(*res, grad, tile=tile, interpret=interpret),
             None)
 
 
-@functools.partial(jax.jit, static_argnames=("tiling", "interpret"))
-def _moe_gmm_bwd(lhs, rhs, group_sizes, grad, *, tiling, interpret):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _moe_gmm_bwd(lhs, rhs, group_sizes, grad, *, tile, interpret):
     """d lhs = grad x rhs^T by group; d rhs[e] = lhs[group e]^T x grad."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
-    d_lhs = gmm.__wrapped__(grad, rhs, group_sizes, lhs.dtype, tiling,
+    d_lhs = gmm.__wrapped__(grad, rhs, group_sizes, lhs.dtype, tile,
                             transpose_rhs=True, interpret=interpret)
     # rows in no group: the kernel left their gradient unwritten too
     d_lhs = jnp.where(
         (jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes))[:, None], d_lhs, 0)
     d_rhs = tgmm.__wrapped__(lhs.swapaxes(0, 1), grad, group_sizes,
-                             rhs.dtype, tiling,
+                             rhs.dtype, tile,
                              num_actual_groups=rhs.shape[0],
                              interpret=interpret)
     return d_lhs, d_rhs

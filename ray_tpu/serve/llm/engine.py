@@ -415,7 +415,8 @@ class LLMEngine:
                         if cfg_m.num_experts else None)
         if self._moe_LE:
             self._totals.update(moe_assignments_total=0,
-                                moe_experts_touched_total=0)
+                                moe_experts_touched_total=0,
+                                moe_tile_rows_total=0)
         # layers with per-slot recurrent state (0: pages are all the state
         # there is) and what one sequence's state costs to read or write
         self._ssm_layers = ssm_layers(cfg_m)
@@ -1301,6 +1302,8 @@ class LLMEngine:
         model's returns the tokens and the record gains nothing."""
         if self._moe_LE is None:
             return fetched, ()
+        from ...models.llama import moe_tile_rows
+
         n = rec["k"] * self._moe_LE[0] * self._moe_LE[1]
         counts = fetched[-n:].reshape((-1,) + self._moe_LE)
         # a prefill or a verify returns tokens for every row of the
@@ -1313,6 +1316,14 @@ class LLMEngine:
         assignments, touched = int(counts.sum()), int((counts > 0).sum())
         self._totals["moe_assignments_total"] += assignments
         self._totals["moe_experts_touched_total"] += touched
+        # what the grouped matmul multiplied to serve them: a pass of the
+        # model is the slot set (decode) or one row's length bucket; a wave
+        # of several rows is counted as if their assignments were sorted
+        # together (each row pays boundary visits of its own: a floor)
+        rows = max(rec["rows_padded"], 1)
+        self._totals["moe_tile_rows_total"] += moe_tile_rows(
+            counts, rows if rec["kind"] == "decode"
+            else rec["tokens_padded"] // rows, self.model_cfg)
         return tokens, (assignments, touched, int(counts.max()))
 
     def _preempt(self, req: Request) -> None:

@@ -86,6 +86,9 @@ _LLM_WORK_TOTALS = {
         "real (token, expert) assignments of an expert model, all layers",
     "moe_experts_touched_total":
         "experts with at least one real token, summed over layers and steps",
+    "moe_tile_rows_total":
+        "rows the grouped matmul multiplied (tile visits x m-tile), all "
+        "layers; moe_assignments_total over it is the fill of its tiles",
     "ssm_scan_tokens_total":
         "real prompt tokens x state-space layers scanned (prefill)",
     "ssm_state_updates_total":

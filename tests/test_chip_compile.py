@@ -108,6 +108,36 @@ def _paged_decode(b, d, mp, hq=32, hkv=8, page=16, layers=4,
     return build
 
 
+def _moe_gmm(m, experts=8, h=4096, f=14336, layers=3):
+    """The expert FFN's two grouped matmuls as `MoEMLP._dropless` calls
+    them on a TPU, for `m` assignments at Mixtral's widths in the rows
+    their layout takes (one call's: at most 4096), the weights a [layers,
+    experts, ...] stack read in place: the tile is the rule's
+    (`grouped_matmul.row_tile`), no `vmem_limit_bytes` asked."""
+    def build(topo):
+        from ray_tpu.ops import grouped_matmul as gm
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        tm, aligned = gm.row_tile(m, experts)
+        rows = min(gm.aligned_rows(m, experts, tm) if aligned else m, 4096)
+
+        def sds(shape, dt):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+        def fn(x, w_gu, w_dn, sizes, layer):
+            def product(lhs, stack):
+                return gm._moe_gmm(lhs, *gm.stacked_groups(stack, sizes,
+                                                           layer),
+                                   tm=tm, impl="megablox")
+            gate, up = jnp.split(product(x, w_gu), 2, axis=-1)
+            return product(jax.nn.silu(gate) * up, w_dn)
+        return fn, (sds((rows, h), BF16),
+                    sds((layers, experts, h, 2 * f), BF16),
+                    sds((layers, experts, f, h), BF16),
+                    sds((experts,), jnp.int32), sds((), jnp.int32))
+    return build
+
+
 def _flash_on_mesh(topo):
     """The trainer's path on several chips: GSPMD cannot partition the
     kernel, so it goes through the shard_map wrapper (batch over fsdp,
@@ -130,6 +160,13 @@ COMPILES = {
     "decode-8b-B8-D128-MP512": _paged_decode(8, 128, 512),
     "decode-7b-B32-D128-MP168": _paged_decode(32, 128, 168),
     "fwdbwd-shard_map-2x2-mesh": _flash_on_mesh,
+    # `mixtral-chat`: a decode step's 32 slots x 2 experts, and a prompt's
+    # [1 x bucket] pass at the four buckets whose tile is not decode's
+    "moe-gmm-mixtral-decode-M64": _moe_gmm(64),
+    "moe-gmm-mixtral-bucket256-M512": _moe_gmm(512),
+    "moe-gmm-mixtral-bucket512-M1024": _moe_gmm(1024),
+    "moe-gmm-mixtral-bucket1024-M2048": _moe_gmm(2048),
+    "moe-gmm-mixtral-bucket2048-M4096": _moe_gmm(4096),
 }
 # The kernel's measured compile limits: K/V of one (batch, kv head) stay
 # resident in VMEM, so long kv is refused (bwd passes at 4096, fwd at

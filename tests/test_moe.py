@@ -375,6 +375,7 @@ def test_moe_dispatch_records_count_real_assignments(moe_engine_run):
     distinct experts over the program's real tokens), and the fullest
     expert's count lies between the mean and all of a layer's tokens."""
     from chipbench.references import moe_decoder as ref
+    from ray_tpu.models.llama import moe_tile_rows
 
     run = moe_engine_run
     engine = run["engine"]
@@ -387,22 +388,26 @@ def test_moe_dispatch_records_count_real_assignments(moe_engine_run):
         chosen[rid] = np.asarray(ref.routing(
             weights, jnp.asarray([seq]), _published(cfg)))[0]
     kinds = set()
+    tile_rows = 0
     for rec in run["records"]:
         kinds.add(rec["kind"])
         tokens = sum(q for _, q, _ in rec["rows"])
         assert rec["moe_assignments"] == tokens * k * L, rec
-        steps = rec["k"] if rec["kind"] == "decode" else 1
-        touched = 0
+        decode = rec["kind"] == "decode"
+        steps = rec["k"] if decode else 1
+        counts = np.zeros((steps, L, cfg.num_experts), np.int64)
         for j in range(steps):
             for layer in range(L):
-                experts = set()
                 for rid, q, ctx in rec["rows"]:
-                    at = (slice(ctx - 1 + j, ctx + j)
-                          if rec["kind"] == "decode"
+                    at = (slice(ctx - 1 + j, ctx + j) if decode
                           else slice(ctx - q, ctx))
-                    experts.update(chosen[rid][layer, at].ravel().tolist())
-                touched += len(experts)
-        assert rec["moe_experts_touched"] == touched, rec
+                    np.add.at(counts[j, layer],
+                              chosen[rid][layer, at].ravel(), 1)
+        assert rec["moe_experts_touched"] == (counts > 0).sum(), rec
+        # a pass of the model: the slot set, or one row's length bucket
+        tile_rows += moe_tile_rows(
+            counts, rec["rows_padded"] if decode
+            else rec["tokens_padded"] // rec["rows_padded"], cfg)
         assert (tokens * k / cfg.num_experts / steps
                 <= rec["moe_expert_tokens_max"] <= tokens / steps), rec
     assert kinds == {"prefill", "decode"}
@@ -411,6 +416,11 @@ def test_moe_dispatch_records_count_real_assignments(moe_engine_run):
         r["moe_assignments"] for r in run["records"])
     assert stats["moe_experts_touched_total"] == sum(
         r["moe_experts_touched"] for r in run["records"])
+    # the rows the grouped matmul multiplied for them: `tile_visits` x the
+    # m-tile, on the same counts (a tiny pass fits one tile: every touched
+    # expert is one visit of it)
+    assert stats["moe_tile_rows_total"] == tile_rows
+    assert tile_rows == 128 * stats["moe_experts_touched_total"]
     # and both totals reach /metrics as rtpu_llm_<key>
     from ray_tpu.serve.llm.server import EngineDriverMixin
     from ray_tpu.util import metrics
@@ -421,9 +431,40 @@ def test_moe_dispatch_records_count_real_assignments(moe_engine_run):
     before = metrics.snapshot("rtpu_llm_")
     driver._publish_llm_metrics(stats)
     after = metrics.snapshot("rtpu_llm_")
-    for key in ("moe_assignments_total", "moe_experts_touched_total"):
+    for key in ("moe_assignments_total", "moe_experts_touched_total",
+                "moe_tile_rows_total"):
         name = f"rtpu_llm_{key}"
         assert after[name] - before.get(name, 0) == stats[key], name
+
+
+def test_moe_engine_prefill_with_groups_on_tile_boundaries():
+    """A prompt long enough that `row_tile` aligns its pass's groups (100
+    tokens in the 128 bucket: 256 assignments on 4 experts) through
+    add_request/step(): the reference's greedy tokens, and tile rows that
+    are whole tiles holding every assignment."""
+    from chipbench.references import moe_decoder as ref
+    from ray_tpu.models.llama import moe_row_layout
+    from ray_tpu.serve.llm.engine import SamplingParams
+
+    engine = _tiny_engine()
+    cfg = engine.model_cfg
+    assert moe_row_layout(128, cfg)[:2] == (128, True)
+    prompt = np.random.default_rng(9).integers(0, 256, 100).tolist()
+    engine.add_request("long", prompt, SamplingParams(max_tokens=4))
+    out = []
+    while engine.has_work():
+        for delta in engine.step():
+            out.extend(delta.new_token_ids)
+    weights = ref.weights_from_program_tree(engine.params)
+    seq = list(prompt)
+    for _ in range(4):
+        logits = np.asarray(ref.forward(weights, jnp.asarray([seq]),
+                                        _published(cfg)))
+        seq.append(int(logits[0, -1].argmax()))
+    assert out == seq[len(prompt):]
+    stats = engine.stats()
+    assert stats["moe_tile_rows_total"] % 128 == 0
+    assert stats["moe_assignments_total"] <= stats["moe_tile_rows_total"]
 
 
 def test_dense_engine_records_carry_no_moe_fields():
@@ -446,43 +487,164 @@ def test_dense_engine_records_carry_no_moe_fields():
     assert not any(key.startswith("moe_") for key in engine.stats())
 
 
+# (M, E, K, N) -> the m-tile: every call `mixtral-chat`'s programs make
+# (a decode step's 32 slots x 2; a prompt's [1 x bucket] pass at the five
+# buckets; both products), a model of 64 small experts, and the short calls
+MIXTRAL_GU, MIXTRAL_DN = (4096, 28672), (14336, 4096)
+TILE_CASES = [
+    *((m, 8, *kn, tm) for kn in (MIXTRAL_GU, MIXTRAL_DN)
+      for m, tm in ((64, 128), (256, 128), (512, 128), (1024, 256),
+                    (2048, 256), (4096, 256))),
+    *((m, 64, 2048, 2048, tm) for m, tm in (
+        (64, 128), (128, 128), (256, 128), (4096, 128), (8192, 256))),
+]
+
+
+@pytest.mark.parametrize("m, e, k, n, tm", TILE_CASES)
+def test_grouped_matmul_tile_follows_the_shape(m, e, k, n, tm):
+    """`tile_for`: a shape in, a tile out. M <= 256 keeps the tile every
+    shape had before PR 34 at those sizes (decode's kernel is unchanged);
+    the kernel's double-buffered blocks and accumulator fit the 16 MiB of
+    VMEM it gets unasked; whole tiles divide the weights."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    tile = gm.tile_for(m, e, k, n)
+    assert tile[0] == tm == gm.row_tile(m, e)[0]
+    if m <= 256:
+        assert tile == (128, min(1024, k), min(1024, n))
+        assert not gm.row_tile(m, e)[1]
+    # groups start on tile boundaries where an expert's share of the call
+    # is half the smallest tile or more, and the tile holds that share
+    assert gm.row_tile(m, e)[1] == (m // e >= 64)
+    assert tm >= min(m // e, 256)
+    assert gm.tile_vmem_bytes(tile) <= 16 * 2 ** 20
+    assert k % tile[1] == 0 and n % tile[2] == 0
+    assert all(t % 128 == 0 for t in tile)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_tile_visits_counts_what_the_kernel_multiplies(seed):
+    """`tile_visits` against a count by brute force (every (tile, group)
+    pair that shares a row) and against the kernel's own metadata
+    (`make_group_metadata`: the grid's middle axis), over random group
+    sizes with empty groups, the [L x E] stacked form and a tail in no
+    group; leading axes are calls of their own."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata)
+    from ray_tpu.ops.grouped_matmul import tile_visits
+
+    rng = np.random.default_rng(seed)
+    calls = []
+    for tm in (128, 256, 512):
+        m = tm * int(rng.integers(1, 9))
+        sizes = rng.multinomial(int(m * rng.uniform(0.3, 1.0)),
+                                rng.dirichlet(np.ones(8)))
+        sizes[rng.integers(0, 8)] = 0
+        stacked = np.zeros((24,), np.int64)
+        stacked[8:16] = sizes
+        owner = np.repeat(np.arange(24), stacked)       # row -> group
+        brute = len({(row // tm, g) for row, g in enumerate(owner)})
+        assert tile_visits(stacked, tm) == brute == tile_visits(sizes, tm)
+        _, num_tiles = make_group_metadata(
+            group_sizes=jnp.asarray(stacked, jnp.int32), m=m, tm=tm,
+            start_group=jnp.int32(0), num_nonzero_groups=24,
+            visit_empty_groups=False)
+        assert int(num_tiles) == brute
+        assert m // tm - 1 <= brute <= m // tm + 7
+        calls.append((sizes, tm, brute))
+    tm = 128
+    both = np.stack([c[0] for c in calls[:2]])
+    assert tile_visits(both, tm) == sum(
+        tile_visits(c[0], tm) for c in calls[:2])
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "megablox_interpret"])
+def test_dropless_layer_is_the_same_with_groups_on_tile_boundaries(
+        monkeypatch, impl):
+    """`MoEMLP._dropless` where `row_tile` asks for aligned groups (160
+    tokens x 2 on 4 experts: every expert's rows start on a 128-row tile,
+    padded with repeated rows) against the same layer with its rows packed:
+    the output and the gradients to the input and to every weight, with a
+    third of the tokens masked out as a length bucket's padding is."""
+    from ray_tpu.models.llama import MoEMLP, moe_row_layout
+    from ray_tpu.ops import grouped_matmul as gm
+
+    cfg = get_config("tiny-moe", dtype=jnp.float32, param_dtype=jnp.float32)
+    tokens = 160
+    assert moe_row_layout(tokens, cfg) == (128, True, 384 + 4 * 128, 896)
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(1, tokens, cfg.hidden_size)),
+                    jnp.float32)
+    mask = jnp.asarray(np.arange(tokens) < 107)[None]
+    layer = MoEMLP(cfg)
+    params = nn.meta.unbox(layer.init(jax.random.PRNGKey(1), x)["params"])
+    monkeypatch.setattr(gm, "_impl", lambda: impl)
+
+    def loss(params, x):
+        out = layer.apply({"params": params}, x, token_mask=mask)
+        return (out ** 2).sum(), out
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+                params, x)
+
+    (_, out), grads = run()
+    monkeypatch.setattr(gm, "row_tile", lambda m, e: (128, False))
+    assert moe_row_layout(tokens, cfg) == (128, False, 320, 320)
+    (_, packed), g_packed = run()
+    assert not np.asarray(out[0, 107:]).any()
+    np.testing.assert_allclose(out, packed, atol=1e-5, rtol=1e-5)
+    for got, want in zip(jax.tree_util.tree_leaves(grads),
+                         jax.tree_util.tree_leaves(g_packed)):
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+
+
+# rows of the call -> the m-tile the rule gives it; the groups: uneven, one
+# empty, ends inside tiles, and a tail of rows in no group
+KERNEL_CASES = {128: (300, [70, 0, 130, 50]), 256: (700, [300, 0, 45, 255])}
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["experts", "stack"])
+@pytest.mark.parametrize("tm", list(KERNEL_CASES))
 def test_grouped_matmul_kernel_matches_plain_path_forward_and_backward(
-        monkeypatch):
+        monkeypatch, tm, stacked):
     """The Pallas grouped matmul (interpret mode here; the compiled kernel
-    on a TPU) against `jax.lax.ragged_dot`, which the CPU tests above run:
-    uneven groups, an empty one and a tail of rows in no group (undefined
-    out of the kernel, with a zero gradient); a whole [L, E, K, N] stack
-    read by layer; and the gradients the one-chip trainer takes through
-    the kernel's own backward (gmm and tgmm)."""
+    on a TPU) against `jax.lax.ragged_dot`, which the CPU tests above run,
+    at every m-tile the rule returns: uneven groups that cross tile
+    boundaries, an empty one and a tail of rows in no group (undefined out
+    of the kernel, with a zero gradient); the experts alone or a whole
+    [L, E, K, N] stack read by layer; and the gradients the one-chip
+    trainer takes through the kernel's own backward (gmm and tgmm)."""
     from ray_tpu.ops import grouped_matmul as gm
 
     rng = np.random.default_rng(8)
-    m, kdim, n, experts = 300, 256, 384, 4
+    (m, sizes), kdim, n, experts = KERNEL_CASES[tm], 256, 384, 4
+    assert gm.tile_for(m, experts, kdim, n)[0] == tm
     lhs = jnp.asarray(rng.normal(size=(m, kdim)), jnp.float32)
     stack = jnp.asarray(rng.normal(size=(2, experts, kdim, n)), jnp.float32)
-    sizes = jnp.asarray([70, 0, 130, 50], jnp.int32)
-    real = int(sizes.sum())                                 # 50 rows over
+    sizes = jnp.asarray(sizes, jnp.int32)
+    real = int(sizes.sum())                                 # rows over
+    assert real < m and gm.tile_visits(sizes, tm) > -(-real // tm)
 
     def loss(lhs, rhs, layer, impl):
         monkeypatch.setattr(gm, "_impl", lambda: impl)
         out = gm.grouped_matmul(lhs, rhs, sizes, layer)[:real]
         return (out ** 2).sum(), out
 
+    rhs, layer = (stack, jnp.int32(1)) if stacked else (stack[1], None)
     with jax.default_matmul_precision("highest"):
         (_, plain), g_plain = jax.value_and_grad(
             loss, argnums=(0, 1), has_aux=True)(lhs, stack[1], None,
                                                 "ragged_dot")
-        for rhs, layer in ((stack[1], None), (stack, jnp.int32(1))):
-            (_, out), grads = jax.value_and_grad(
-                loss, argnums=(0, 1), has_aux=True)(
-                    lhs, rhs, layer, "megablox_interpret")
-            np.testing.assert_allclose(out, plain, atol=1e-4, rtol=0)
-            assert not np.asarray(grads[0][real:]).any()
-            d_rhs = grads[1] if layer is None else grads[1][1]
-            # gradients reach 1e3 here: float32's summation order
-            np.testing.assert_allclose(grads[0], g_plain[0], rtol=1e-5,
-                                       atol=1e-3)
-            np.testing.assert_allclose(d_rhs, g_plain[1], rtol=1e-5,
-                                       atol=1e-3)
-            if layer is not None:       # the other layer's experts: untouched
-                assert not np.asarray(grads[1][0]).any()
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(
+                lhs, rhs, layer, "megablox_interpret")
+    np.testing.assert_allclose(out, plain, atol=1e-4, rtol=0)
+    assert not np.asarray(grads[0][real:]).any()
+    d_rhs = grads[1][1] if stacked else grads[1]
+    # gradients reach 1e3 here: float32's summation order
+    np.testing.assert_allclose(grads[0], g_plain[0], rtol=1e-5, atol=3e-3)
+    np.testing.assert_allclose(d_rhs, g_plain[1], rtol=1e-5, atol=3e-3)
+    if stacked:                 # the other layer's experts: untouched
+        assert not np.asarray(grads[1][0]).any()
