@@ -141,6 +141,20 @@ class JambaConfig:
                 + self.n_attn_layers * (attn + mlp)
                 + self.vocab_size * h + h + head)
 
+    # what serve/llm asks of a family whose layers keep per-slot state:
+    # how the engine's refusals word it (engine.py:
+    # _refuse_for_recurrent_state), how many layers, how many bytes a row
+    SLOT_STATE = "recurrent state-space state"
+    SPLIT_BY_TP = "the scan's d_inner axis and its per-slot state"
+    LAYER_KINDS = "follow a pattern of two kinds with two kinds of state"
+
+    @property
+    def n_slot_state_layers(self) -> int:
+        return self.n_mamba_layers
+
+    def slot_state_bytes_row(self) -> int:
+        return self.ssm_state_bytes_row()
+
     def ssm_state_bytes_row(self) -> int:
         """What one sequence's recurrent state costs to read or write
         once, all Mamba layers: h in float32, the conv tail in `dtype`."""
@@ -185,6 +199,11 @@ class HybridCache:
 
 
 # ----------------------------------------------------------------- serving
+# a prefill row starts from ZERO state (scan and conv tail): a prompt is
+# prefilled in one pass (serve/llm/stage.py: model_family)
+RESUMES_PREFILL = False
+
+
 def serving_model(cfg: JambaConfig, n_layers=None, first=True, last=True):
     if not (first and last):
         raise NotImplementedError(

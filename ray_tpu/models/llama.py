@@ -745,8 +745,14 @@ class LlamaModel(nn.Module):
 
 
 # ----------------------------------------------------------------- serving
-# What serve/llm/stage.py asks of a model family's module (this one and
-# models/jamba.py): get_config, serving_model, pool_spec, serving_cache.
+# What serve/llm/stage.py asks of a model family's module (this one,
+# models/jamba.py and models/minicpm_sala.py): get_config, serving_model,
+# pool_spec, serving_cache, RESUMES_PREFILL.
+# pages are all a sequence keeps: a prefill row that starts mid-prompt
+# attends to its earlier pages (the path prefix hits use)
+RESUMES_PREFILL = True
+
+
 def serving_model(cfg: LlamaConfig, n_layers=None, first=True, last=True):
     if first and last:
         return LlamaModel(cfg)

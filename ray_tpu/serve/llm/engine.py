@@ -102,7 +102,21 @@ that state cannot do yet is refused at construction, by name
 content hash carries no state, so prefix reuse is off; state cannot be
 rolled back (speculation), resumed mid-prompt (chunked prefill), split
 (tp), cut by layer slices (pp) or handed to another engine (disaggregated
-prefill).
+prefill). Models with linear-attention and block-sparse layers
+(models/minicpm_sala.py) keep a matrix a head per slot and, beside each
+page, its compressed keys; their prefill rows RESUME from the slot's state,
+so of the list above chunked prefill is served and the rest refused.
+
+A prompt longer than the largest prefill bucket (a preempted request's
+folded prompt too) is prefilled in PASSES of the largest bucket, on the
+default scheduler: every pass is one row of `_dispatch_prefill_batch`
+that starts at the request's `n_prefilled` mark, attends to the pages the
+earlier passes wrote (the path prefix hits use) and, for a model with
+per-slot state, continues from the slot; only the last pass samples. All
+of a prompt's passes are enqueued in the step that admits it, and the
+device runs them in order. A family whose prefill cannot resume
+(`RESUMES_PREFILL` False: models/jamba.py) still raises past its largest
+bucket.
 """
 
 from __future__ import annotations
@@ -116,7 +130,8 @@ import numpy as np
 
 from ...util import tracing
 from .cache import OutOfPages, PageAllocator
-from .stage import _MAX_TOP_K, StageCompute, serve_model_config, ssm_layers
+from .stage import (_MAX_TOP_K, StageCompute, model_family,
+                    serve_model_config, ssm_layers)
 
 WAITING, RUNNING, FINISHED = "WAITING", "RUNNING", "FINISHED"
 # where a step's nanoseconds go (indices into LLMEngine._phase_ns, in the
@@ -181,6 +196,7 @@ class Request:
     preemptions: int = 0
     n_folded: int = 0            # output tokens a preemption folded into
                                  # prompt_ids
+    n_passes: int = 0            # prefill rows dispatched since admission
 
     @property
     def total_len(self) -> int:
@@ -276,19 +292,22 @@ class EngineConfig:
 
 def _refuse_for_recurrent_state(config: EngineConfig, model_cfg,
                                 mesh) -> None:
-    """A model with state-space layers keeps per-slot recurrent state; the
-    engine options that would need to copy, split, roll back or resume
-    that state are refused here, each by the mechanism that is missing."""
+    """A model whose layers keep state a decode slot (state-space layers,
+    linear-attention layers); the engine options that would need to copy,
+    split, roll back or resume that state are refused here, each by the
+    mechanism that is missing. The family's config words what it keeps
+    (`SLOT_STATE`, `SPLIT_BY_TP`, `LAYER_KINDS`)."""
     if not ssm_layers(model_cfg):
         return
-    model = f"model {config.model!r} keeps recurrent state-space state"
+    model = f"model {config.model!r} keeps {model_cfg.SLOT_STATE}"
     if config.spec_lookahead > 0:
         raise NotImplementedError(
             f"{model}: spec_lookahead={config.spec_lookahead} needs a "
             f"verify dispatch whose rejected draft tokens can be rolled "
             f"back, and a state advanced past them cannot be (no state "
             f"snapshot yet)")
-    if config.prefill_chunk_tokens > 0:
+    if (config.prefill_chunk_tokens > 0
+            and not model_family(config.model).RESUMES_PREFILL):
         raise NotImplementedError(
             f"{model}: prefill_chunk_tokens={config.prefill_chunk_tokens} "
             f"needs a prefill that resumes from a slot's state, and a "
@@ -297,13 +316,13 @@ def _refuse_for_recurrent_state(config: EngineConfig, model_cfg,
         raise NotImplementedError(
             f"{model}: tensor parallelism (tp={config.tp}, mesh="
             f"{'given' if mesh is not None else None}) would have to split "
-            f"the scan's d_inner axis and its per-slot state over the "
+            f"{model_cfg.SPLIT_BY_TP} over the "
             f"mesh, and nothing does yet")
     if config.pp > 1:
         raise NotImplementedError(
             f"{model}: pipeline parallelism (pp={config.pp}) slices a "
             f"uniform `layers` axis (stage_params), and this model's "
-            f"layers follow a pattern of two kinds with two kinds of state")
+            f"layers {model_cfg.LAYER_KINDS}")
 
 
 def _bucket(n: int, buckets) -> int:
@@ -407,7 +426,8 @@ class LLMEngine:
             "steps_total", "prefill_dispatches_total",
             "decode_dispatches_total", "prefill_tokens_total",
             "prefill_padded_tokens_total", "decode_rows_total",
-            "decode_ctx_tokens_total"), 0)
+            "decode_ctx_tokens_total", "prefill_passes_total",
+            "prefill_resumed_passes_total"), 0)
         # (layers, experts) of an expert model, whose programs return
         # routing counts packed behind their tokens; None for a dense one
         cfg_m = self.model_cfg
@@ -417,21 +437,44 @@ class LLMEngine:
             self._totals.update(moe_assignments_total=0,
                                 moe_experts_touched_total=0,
                                 moe_tile_rows_total=0)
-        # layers with per-slot recurrent state (0: pages are all the state
-        # there is) and what one sequence's state costs to read or write
+        # layers with per-slot recurrent state of any kind (0: pages are
+        # all the state there is); of them the linear-attention layers,
+        # the rest being state-space layers; and the block-sparse
+        # attention layers with their selection rule
         self._ssm_layers = ssm_layers(cfg_m)
-        # the `ssm_*` fields of its `engine.dispatch` records, behind the
-        # `moe_*` positions (None where it has no experts): the layers
-        # that keep per-slot state and what one live row's state costs to
-        # read or write once, so that a reader needs no knowledge of the
-        # model. Nothing for any other model.
+        self._lin_layers = getattr(cfg_m, "n_lightning_layers", 0)
+        self._scan_layers = self._ssm_layers - self._lin_layers
+        self._sparse_layers = getattr(cfg_m, "n_sparse_layers", 0)
+        self._sparse = cfg_m.sparse if self._sparse_layers else None
+        family = model_family(self.config.model)
+        # a prompt past the largest bucket is prefilled in passes
+        self._resumes = family.RESUMES_PREFILL
+        self._head_at_gather = getattr(family, "HEAD_AT_GATHER", False)
+        # the fields of its `engine.dispatch` records behind `k`, in
+        # `tracing.FIELDS`' order, None where the model has none: the
+        # `moe_*` positions (filled per record for an expert model), then
+        # the state-space layers and what one live row's state costs to
+        # read or write once, then the same for linear-attention layers
+        # and the count of sparse layers, so that a reader needs no
+        # knowledge of the model. Nothing for any other model.
         self._ssm_fields: tuple = ()
         if self._ssm_layers:
-            self._ssm_fields = (None,) * (0 if self._moe_LE else 3) + (
-                self._ssm_layers, cfg_m.ssm_state_bytes_row())
+            self._totals["prefix_reuse_refused_total"] = 0
+            self._ssm_fields = (None,) * (0 if self._moe_LE else 3)
+        if self._scan_layers:
+            self._ssm_fields += (self._scan_layers,
+                                 cfg_m.slot_state_bytes_row())
             self._totals.update(ssm_scan_tokens_total=0,
-                                ssm_state_updates_total=0,
-                                prefix_reuse_refused_total=0)
+                                ssm_state_updates_total=0)
+        if self._lin_layers or self._sparse_layers:
+            self._ssm_fields += (None,) * (0 if self._scan_layers else 2) + (
+                self._lin_layers, cfg_m.slot_state_bytes_row(),
+                self._sparse_layers)
+            self._totals.update(lightning_prefill_tokens_total=0,
+                                lightning_state_updates_total=0,
+                                sparse_blocks_selected_total=0,
+                                sparse_ctx_tokens_total=0,
+                                sparse_dense_rows_total=0)
         self._queue_wait_ns_total = 0
 
     # ----------------------------------------------------------- intake
@@ -724,6 +767,7 @@ class LLMEngine:
             req.state = RUNNING
             req.slot = self._free_slots.pop(0)
             req.planned_out = 0
+            req.n_passes = 0
             req.admitted_ns = tracing.now_ns()
             self._slot_req[req.slot] = req
             self.running.append(req)
@@ -805,9 +849,17 @@ class LLMEngine:
         if not admitted:
             return
         wave = self._wave_rb
+        largest = self.config.prefill_buckets[-1]
         by_bucket: Dict[int, List[tuple]] = {}
         for req in admitted:
             n_new = len(req.prompt_ids) - req.n_prefilled
+            while n_new > largest and self._resumes:
+                # a prompt past the largest bucket: passes of the largest
+                # bucket, each its own dispatch, ahead of the last pass
+                # (which joins the waves below). The device runs them in
+                # the order they are enqueued
+                self._dispatch_prefill_batch(largest, [(req, largest)])
+                n_new -= largest
             sb = _bucket(n_new, self.config.prefill_buckets)
             by_bucket.setdefault(sb, []).append((req, n_new))
         for sb, group in by_bucket.items():
@@ -920,6 +972,7 @@ class LLMEngine:
             slots = np.zeros((rb,), np.int32) if self._ssm_layers else None
             rows = []
             facts = []
+            passes = []
             for i, (req, n_new) in enumerate(group):
                 start = req.n_prefilled
                 if slots is not None:
@@ -928,11 +981,17 @@ class LLMEngine:
                 positions[i] = start + np.arange(sb, dtype=np.int32)
                 bt[i, :len(req.pages)] = req.pages
                 total[i] = start + n_new
-                gather[i] = n_new - 1
                 final = start + n_new >= len(req.prompt_ids)
+                # where the family's model computes the head at this
+                # position only, a pass that is not the last asks for none
+                gather[i] = (n_new - 1 if final or not self._head_at_gather
+                             else -1)
                 rows.append((req.request_id, req.slot, start + n_new,
                              final))
                 facts.append((req.request_id, n_new, start + n_new))
+                passes.append((req.n_passes, final))
+                req.n_passes += 1
+                self._totals["prefill_resumed_passes_total"] += start > 0
             now = time.monotonic()
             for req, _ in group:
                 if req.dispatched_t is None:
@@ -953,11 +1012,14 @@ class LLMEngine:
             self._totals["prefill_tokens_total"] += sum(
                 n_new for _, n_new in group)
             self._totals["prefill_padded_tokens_total"] += computed * sb
-            if self._ssm_layers:
-                self._totals["ssm_scan_tokens_total"] += self._ssm_layers \
+            self._totals["prefill_passes_total"] += computed
+            if self._scan_layers:
+                self._totals["ssm_scan_tokens_total"] += self._scan_layers \
                     * sum(n_new for _, n_new in group)
-            self._enqueue("prefill", tokens, r.start_ns, computed,
-                          computed * sb, facts, group=rows)
+            self._enqueue(
+                "prefill", tokens, r.start_ns, computed, computed * sb,
+                facts, group=rows,
+                tail=self._lin_sparse_facts(facts, 1, passes))
         self._phase_ns[_DISPATCH_PREFILL] += r.ns
 
     def _enqueue(self, kind: str, toks, dispatch_ns: int, rows_padded: int,
@@ -975,7 +1037,55 @@ class LLMEngine:
             "kind": kind, "toks": toks, "k": k, "seq": self._dispatch_seq,
             "step": self._step_seq, "dispatch_ns": dispatch_ns,
             "rows_padded": rows_padded, "tokens_padded": tokens_padded,
-            "facts": tuple(facts), **harvest_keys})
+            "facts": tuple(facts), "tail": (), **harvest_keys})
+
+    def _lin_sparse_facts(self, facts: List[tuple], k_steps: int,
+                          passes=None) -> tuple:
+        """The per-record fields of a model with linear-attention and
+        block-sparse layers, behind `_ssm_fields`: `sparse_tokens_read`
+        (keys the sparse layers attend for the record's real rows, summed
+        over layers, kv-head groups and fused steps), `sparse_kernels_scored`,
+        and a prefill's `pass_index` / `final` a row (`passes`; None: a
+        decode chunk of `k_steps`). `facts` are the record's (request,
+        q_tokens, ctx_tokens) rows: a prefill row's queries sit at the last
+        q_tokens positions under ctx_tokens, a decode row's at ctx_tokens
+        - 1 and the k_steps - 1 after it. The selection's COUNT is a
+        function of the position alone (ops/sparse_attention.py:
+        keys_attended), so nothing is fetched from the device for it.
+        Moves the family's stats() totals too. () for any other model."""
+        if not (self._lin_layers or self._sparse_layers):
+            return ()
+        from ...ops.sparse_attention import keys_attended, kernels_scored
+
+        if passes is None:
+            positions = [ctx - 1 + np.arange(k_steps) for _, _, ctx in facts]
+            prefill_tokens, decode_updates = 0, len(facts) * k_steps
+        else:
+            positions = [np.arange(end - n, end) for _, n, end in facts]
+            prefill_tokens, decode_updates = sum(n for _, n, _ in facts), 0
+        tot = self._totals
+        tot["lightning_prefill_tokens_total"] += (self._lin_layers
+                                                  * prefill_tokens)
+        tot["lightning_state_updates_total"] += (self._lin_layers
+                                                 * decode_updates)
+        read = scored = 0
+        if self._sparse_layers and positions:
+            sp = self._sparse
+            t = np.concatenate(positions)
+            per = self._sparse_layers * self.model_cfg.num_kv_heads
+            keys = keys_attended(t, sp)
+            read = per * int(keys.sum())
+            scored = per * int(kernels_scored(t, sp).sum())
+            tot["sparse_blocks_selected_total"] += per * int(
+                ((keys - t % sp.block - 1) // sp.block + 1).sum())
+            tot["sparse_ctx_tokens_total"] += per * int((t + 1).sum())
+            if passes is None:
+                tot["sparse_dense_rows_total"] += int(
+                    (t < sp.dense_len).sum())
+        if passes is None:
+            return (read, scored, None, None)
+        return (read, scored, tuple(p for p, _ in passes),
+                tuple(f for _, f in passes))
 
     @staticmethod
     def _prompt_lookup_draft(req: Request, max_len: int) -> List[int]:
@@ -1211,11 +1321,12 @@ class LLMEngine:
         self._totals["decode_rows_total"] += len(facts)
         self._totals["decode_ctx_tokens_total"] += sum(
             ctx for _, _, ctx in facts)
-        if self._ssm_layers:
+        if self._scan_layers:
             self._totals["ssm_state_updates_total"] += (
-                len(facts) * k_steps * self._ssm_layers)
-        self._enqueue("decode", toks, dispatch_ns, S, S * k_steps, facts,
-                      k=k_steps, slots=chunk_slots)
+                len(facts) * k_steps * self._scan_layers)
+        self._enqueue(
+            "decode", toks, dispatch_ns, S, S * k_steps, facts, k=k_steps,
+            slots=chunk_slots, tail=self._lin_sparse_facts(facts, k_steps))
 
     # ---------------------------------------------------------- harvest
 
@@ -1293,7 +1404,7 @@ class LLMEngine:
             rec["seq"], rec["kind"], rec["step"], self._step_seq,
             rec["dispatch_ns"], fetch.start_ns, fetch.end_ns,
             rec["rows_padded"], rec["tokens_padded"], rec["facts"],
-            rec["k"]) + moe_facts + self._ssm_fields)
+            rec["k"]) + moe_facts + self._ssm_fields + rec["tail"])
 
     def _split_counts(self, rec: dict, fetched: np.ndarray) -> tuple:
         """(tokens, the record's `moe_*` fields). An expert model's
@@ -1488,8 +1599,9 @@ class LLMEngine:
         (`_gather_kv`, kv_transfer.py)."""
         if self._ssm_layers:
             raise NotImplementedError(
-                f"model {self.config.model!r} keeps recurrent state-space "
-                f"state: the disaggregated prefill/decode hand-off "
+                f"model {self.config.model!r} keeps "
+                f"{self.model_cfg.SLOT_STATE}: the disaggregated "
+                f"prefill/decode hand-off "
                 f"(prefill_only, extract_kv, inject_request) moves KV "
                 f"pages only, and a request's per-slot state would be "
                 f"left behind")
@@ -1629,10 +1741,12 @@ class LLMEngine:
         rb = self._wave_rb
         if prompt_buckets is None:
             prompt_buckets = self.config.prefill_buckets
-        # a model with recurrent state never prefills behind a cached
-        # prefix: the variant with a prefix part could never run
-        prefix_parts = ((0,) if self._ssm_layers
-                        else (0, self.max_pages_per_seq))
+        # the variant with a prefix part runs behind a cached prefix (a
+        # model without per-slot state) and in every pass after a
+        # prompt's first (a family whose prefill resumes); a model with
+        # recurrent state that starts every row from zero never runs it
+        prefix_parts = ((0, self.max_pages_per_seq) if self._resumes
+                        else (0,))
         programs = [("prefill", (sb, rb, cp)) for sb, cp in product(
             prompt_buckets, prefix_parts)]
         if not include_decode:
@@ -1687,8 +1801,13 @@ class LLMEngine:
         }
         if self._ssm_layers and self.compute:
             sizes = self.compute.pool_bytes()
-            out["ssm_state_pool_bytes"] = sizes["ssm_h"] + sizes["ssm_conv"]
-            out["ssm_slots"] = self.config.max_batch
+            if self._scan_layers:
+                out["ssm_state_pool_bytes"] = (sizes["ssm_h"]
+                                               + sizes["ssm_conv"])
+                out["ssm_slots"] = self.config.max_batch
+            if self._lin_layers:
+                out["lin_state_pool_bytes"] = sizes["lin_state"]
+                out["sparse_index_pool_bytes"] = sizes["kc"]
         if self.sharding is not None:
             out["sharding"] = self.sharding.page_accounting(
                 self.config, self.model_cfg)

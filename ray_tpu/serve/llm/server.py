@@ -95,12 +95,30 @@ _LLM_WORK_TOTALS = {
         "live rows x fused steps x state-space layers updated (decode)",
     "prefix_reuse_refused_total":
         "admissions that skipped the prefix lookup (recurrent state)",
+    "prefill_passes_total": "prefill rows dispatched (a prompt past the "
+                            "largest bucket takes several)",
+    "prefill_resumed_passes_total":
+        "prefill rows that started mid-prompt, from pages and slot state",
+    "lightning_prefill_tokens_total":
+        "real prompt tokens x linear-attention layers (prefill)",
+    "lightning_state_updates_total":
+        "live rows x fused steps x linear-attention layers (decode)",
+    "sparse_blocks_selected_total":
+        "blocks the sparse layers attended, over queries, layers, kv heads",
+    "sparse_ctx_tokens_total":
+        "keys a dense layer would have attended for the same queries",
+    "sparse_dense_rows_total":
+        "decode (row, step)s at a position under dense_len (no selection)",
 }
 # engine.stats() sizes published as rtpu_llm_<key> gauges
 _LLM_SIZES = {
     "ssm_state_pool_bytes":
         "bytes of the per-slot recurrent state pool (state-space layers)",
     "ssm_slots": "decode slots of the recurrent state pool",
+    "lin_state_pool_bytes":
+        "bytes of the per-slot linear-attention state pool",
+    "sparse_index_pool_bytes":
+        "bytes of the compressed keys kept beside the pages",
 }
 
 

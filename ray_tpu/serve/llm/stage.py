@@ -19,7 +19,9 @@ a dict. "x" is the stage's input: ids or hidden states.
 "The pool" is whatever the model's family keeps on the device between
 dispatches (`model_family(...).pool_spec`): one array of pages for a
 decoder whose every layer is attention, pages AND per-slot arrays for one
-with state-space layers (models/jamba.py). Either way it is ONE donated
+with state-space layers (models/jamba.py), pages, per-slot matrices AND
+compressed keys indexed by page id for one with linear-attention and
+block-sparse layers (models/minicpm_sala.py). Either way it is ONE donated
 argument, carried whole through the row loop and the step scan, and the
 model's cache object (`serving_cache`) is the only code that looks inside.
 A model with per-slot state gets one more prefill operand, the decode
@@ -77,16 +79,26 @@ def serve_dtype(config):
 
 def model_family(name: str):
     """The module that defines the preset `name`: the one place that
-    chooses a model family from `EngineConfig.model`. A family's module
-    has `CONFIGS`, `get_config`, `serving_model`, `pool_spec` and
-    `serving_cache` (models/llama.py, models/jamba.py)."""
-    from ...models import jamba, llama
+    chooses a model family from `EngineConfig.model`. Three families:
+    models/llama.py (every layer attention, dense or experts),
+    models/jamba.py (state-space layers beside attention) and
+    models/minicpm_sala.py (linear-attention layers beside block-sparse
+    attention). A family's module has `CONFIGS`, `get_config`,
+    `serving_model`, `pool_spec`, `serving_cache`, and two flags:
+    `RESUMES_PREFILL` (a prefill row continues from what its pages and
+    its slot hold, so a prompt may be prefilled in passes) and, where set,
+    `HEAD_AT_GATHER` (`serving_cache` takes the position each row samples
+    from and the model computes the head there only). Its config answers
+    `n_slot_state_layers` / `slot_state_bytes_row()` where layers keep
+    state a decode slot."""
+    from ...models import jamba, llama, minicpm_sala
 
-    for family in (llama, jamba):
+    families = (llama, jamba, minicpm_sala)
+    for family in families:
         if name in family.CONFIGS:
             return family
     raise KeyError(f"no model preset {name!r}; have "
-                   f"{sorted([*llama.CONFIGS, *jamba.CONFIGS])}")
+                   f"{sorted(n for f in families for n in f.CONFIGS)}")
 
 
 def serve_model_config(config):
@@ -99,9 +111,10 @@ def serve_model_config(config):
 
 
 def ssm_layers(model_cfg) -> int:
-    """How many of the model's layers keep recurrent (state-space) state
-    per sequence; 0 for a decoder whose only state is pages."""
-    return getattr(model_cfg, "n_mamba_layers", 0)
+    """How many of the model's layers keep recurrent state a decode slot
+    (state-space layers, linear-attention layers: the family's config
+    says); 0 for a decoder whose only state is pages."""
+    return getattr(model_cfg, "n_slot_state_layers", 0)
 
 
 def init_params(model, example, rng):
@@ -262,24 +275,12 @@ class StageCompute:
             params = init(jax.random.PRNGKey(config.seed))
         self.params = params
 
-        spec = family.pool_spec(cfg, n_layers, config.num_pages,
-                                config.page_size, config.max_batch)
+        self.kv_pages = self.fresh_pool()
         if self.sharding is not None:
-            shape = spec[0]
-            # zero-fill compiled WITH the sharding: each chip only ever
-            # allocates its Hkv/tp slice of the pool (num_pages is sized
-            # against per-shard HBM — sharding.pages_for_budget)
-            self.kv_pages = jax.jit(
-                lambda: jnp.zeros(shape, dtype),
-                out_shardings=self._kv_sharding)()
             self.slot_ids = jax.device_put(
                 jnp.zeros((config.max_batch, 1), jnp.int32),
                 self._repl_sharding)
         else:
-            # one array of pages, or the family's name -> array
-            self.kv_pages = jax.tree.map(
-                lambda sd: jnp.zeros(*sd), spec,
-                is_leaf=lambda sd: isinstance(sd, tuple))
             # device-resident last-sampled-token per slot: the decode
             # chain's carry (design rule 2 in engine.py's docstring)
             self.slot_ids = jnp.zeros((config.max_batch, 1), jnp.int32)
@@ -311,6 +312,27 @@ class StageCompute:
             lambda a: np.asarray(a, dtype=self.dtype), sliced))
 
     # ------------------------------------------------------------- pool
+
+    def fresh_pool(self):
+        """A zeroed pool as the family lays it out: one array of pages, or
+        the family's name -> array. (An idle engine's pool may be dropped
+        and made anew: no request holds a page or a slot.)"""
+        import jax
+        import jax.numpy as jnp
+
+        config = self.config
+        spec = self.family.pool_spec(
+            self.model_cfg, self.n_layers, config.num_pages,
+            config.page_size, config.max_batch)
+        if self.sharding is not None:
+            shape = spec[0]
+            # zero-fill compiled WITH the sharding: each chip only ever
+            # allocates its Hkv/tp slice of the pool (num_pages is sized
+            # against per-shard HBM — sharding.pages_for_budget)
+            return jax.jit(lambda: jnp.zeros(shape, self.dtype),
+                           out_shardings=self._kv_sharding)()
+        return jax.tree.map(lambda sd: jnp.zeros(*sd), spec,
+                            is_leaf=lambda sd: isinstance(sd, tuple))
 
     def pool_bytes(self) -> Dict[str, int]:
         """Bytes of each part of the pool ("kv_pages" alone for a model
@@ -364,6 +386,9 @@ class StageCompute:
 
         model, cfg, L = self.model, self.model_cfg, self.n_layers
         serving_cache = self.family.serving_cache
+        # the family's model computes the head at a row's sampling
+        # position only, told through its cache
+        head_at_gather = getattr(self.family, "HEAD_AT_GATHER", False)
         first, last = self.first, self.last
         # sharded stages trace under GSPMD, where the single-device
         # Pallas kernels cannot run: pin the reference attention paths
@@ -415,14 +440,16 @@ class StageCompute:
             cp = shape_key[2] if kind == "prefill" else self.max_pages_per_seq
 
             def row_pass(params, kv_pages, n_rows, block_tables, total_lens,
-                         x, positions, kept, keep, slots=None):
+                         x, positions, kept, keep, slots=None, gather=None):
                 """The arrays come at the wave size; the first `n_rows`
                 are requests and only those are computed, one [1 x sb]
                 pass of the model a row (the trip count is data, so every
                 row count is this one program). `keep(out, i)` is what
                 row i leaves in `kept`. The pool rides the loop's carry as
                 it rides the layer scan's, in place. `slots`: the decode
-                slot a row leaves its per-slot state in."""
+                slot a row leaves its per-slot state in; `gather`: the
+                position a row samples from, for a family that computes
+                its head there only."""
                 def row(i, carry):
                     kvp, kept, counts = carry
                     bt, tot, xi, pos = (
@@ -432,7 +459,10 @@ class StageCompute:
                         cfg, kvp, bt, tot,
                         None if slots is None else
                         jax.lax.dynamic_slice_in_dim(slots, i, 1),
-                        ctx_pages=cp, ref_attention=ref_attn)
+                        ctx_pages=cp, ref_attention=ref_attn,
+                        **({} if gather is None else {
+                            "gather": jax.lax.dynamic_slice_in_dim(
+                                gather, i, 1)}))
                     out, new_pc, c = apply(params, xi, pos, pc, tot)
                     kept = jax.lax.dynamic_update_slice_in_dim(
                         kept, keep(out, i), i, 0)
@@ -464,8 +494,11 @@ class StageCompute:
                 kvp, rows, counts = row_pass(
                     params, kv_pages, n_rows, block_tables, total_lens, x,
                     positions, jnp.zeros((rb, cfg.vocab_size), jnp.float32),
-                    lambda out, i: out[0, gather_idx[i]].astype(
-                        jnp.float32)[None], slots)
+                    (lambda out, i: out[0].astype(jnp.float32))
+                    if head_at_gather else
+                    (lambda out, i: out[0, gather_idx[i]].astype(
+                        jnp.float32)[None]), slots,
+                    gather_idx if head_at_gather else None)
                 # sample ON DEVICE: only B int32 tokens cross to the host
                 # per step, never the [B, V] fp32 logits
                 tokens = _device_sample(rows, temperature, top_k, rng_keys)

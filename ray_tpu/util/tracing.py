@@ -150,12 +150,23 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
     # The two `ssm_*` fields are written for a model with state-space
     # layers only (its `moe_*` positions hold None unless it has experts
     # too): how many layers keep per-slot recurrent state, and the bytes
-    # one live row's state costs to read or write once over all of them
+    # one live row's state costs to read or write once over all of them.
+    # The seven fields behind them are written for a model with
+    # linear-attention and block-sparse layers only (the positions before
+    # hold None): its linear-attention layers and the bytes one live row's
+    # state costs to read or write once, its sparse layers, the keys they
+    # attended for the record's real rows (summed over layers, kv-head
+    # groups and fused steps), the compressed keys scored the same way,
+    # and for a prefill a tuple a real row of how many passes of its
+    # prompt came before this one and whether it is the last
     "engine.dispatch": (
         "seq", "kind", "step_dispatched", "step_harvested", "dispatch_ns",
         "fetch_start_ns", "fetch_end_ns", "rows_padded", "tokens_padded",
         "rows", "k", "moe_assignments", "moe_experts_touched",
-        "moe_expert_tokens_max", "ssm_layers", "ssm_state_bytes_row"),
+        "moe_expert_tokens_max", "ssm_layers", "ssm_state_bytes_row",
+        "lin_layers", "lin_state_bytes_row", "sparse_layers",
+        "sparse_tokens_read", "sparse_kernels_scored", "pass_index",
+        "final"),
     # one per LLMEngine.step()
     "engine.step": (
         "seq", "start_ns", "end_ns", "intake_ns", "admit_ns",

@@ -511,3 +511,88 @@ def test_jamba_program_fits_and_carries_both_pools_in_place(
     header = text.split("\n", 1)[0]
     aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
     assert len(aliased) >= (4 if kind == "decode" else 3), header[:400]
+
+
+# ------------- a model with lightning and block-sparse layers, at its cut
+@pytest.mark.parametrize("kind, ctx", [("decode", 0), ("prefill", 660),
+                                       ("prefill", 0)])
+def test_sala_program_fits_and_carries_its_pool_in_place(
+        topo, no_persistent_cache, kind, ctx):
+    """MiniCPM-SALA at its published widths, layers 9 to 24, as the cell
+    `minicpm-sala-longdoc` runs it (16 slots, 10,560 pages of 64): the
+    program fits the chip beside its 3.26 GB pool, the decode step reads
+    its selected pages through the paged-decode kernel (one call a run of
+    sparse layers), a pass with nothing cached goes through the flash
+    kernel and a resumed one through plain XLA, all three parts of the
+    pool are aliased from argument to result, and nothing as large as a
+    part of the pool or a layer's FFN weights is copied (the whole-pool
+    view a head a page, `[L, P*G, 1, page, 2D]`, is a bitcast)."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    from ray_tpu.serve.llm.stage import init_params
+
+    cfg = EngineConfig(
+        model="minicpm-sala", dtype="bfloat16", page_size=64, num_pages=64,
+        max_model_len=42240, max_batch=16, prefill_buckets=(512, 4096),
+        model_overrides=dict(num_layers=16, kept_layers=tuple(range(9, 25))))
+    engine = LLMEngine(cfg, params={})
+    stage = engine.compute
+    stage.params = jax.eval_shape(lambda: init_params(
+        stage.model, jnp.zeros((1, 8), jnp.int32), jax.random.PRNGKey(0)))
+    # the cell's pool, as shapes (the engine above holds a small one)
+    stage.kv_pages = {
+        name: jax.ShapeDtypeStruct(shape, dtype) for name, (shape, dtype)
+        in stage.family.pool_spec(stage.model_cfg, 16, 10560, 64, 16).items()}
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(a):
+        a = a if hasattr(a, "shape") else np.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    key = ((1, engine.max_pages_per_seq) if kind == "decode"
+           else (4096, engine._wave_rb, ctx))
+    assert stage.operands("prefill")[-1] == "slots"
+    with pytest.MonkeyPatch.context() as mp_ctx:
+        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = stage.program(kind, key).lower(*jax.tree.map(
+            sds, (*stage._state(kind), *stage.dummy_args(kind, key)),
+            is_leaf=lambda a: isinstance(a, jax.ShapeDtypeStruct))
+        ).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 12.3 * 2 ** 30 <= total <= HBM_GIB * 2 ** 30, total / 2 ** 30
+    kernels = set(re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
+    if kind == "decode":
+        assert len(kernels) == 3 and all(
+            k.startswith("_decode_call") for k in kernels), kernels
+    elif ctx:
+        assert not kernels, kernels
+    else:
+        assert len(kernels) == 3, kernels
+    pools = {name: tuple(a.shape) for name, a in stage.kv_pages.items()}
+    assert pools == {"kv_pages": (4, 10560, 2, 64, 256),
+                     "kc": (4, 10560, 2, 4, 128),
+                     "lin_state": (12, 16, 32, 128, 128)}
+    # a layer's weights sliced from its run's stack are read in place (the
+    # slice sits inside the matmul's fusion); what may not appear is a
+    # COPY of a layer's FFN weights, or any move of a part of the pool but
+    # the update in place. (The one copy there is: the 0.6 GB head, into
+    # the layout its one-row product wants, once a prefill program.)
+    ffn, head = (1, 4096, 32768), (4096, 73448)
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not m or m.group(2) not in MOVES:
+            continue
+        for dims, _ in _array_types(m.group(1)):
+            if dims in pools.values():
+                if m.group(2) != "dynamic-update-slice":
+                    moved.append(line.strip()[:160])
+            elif (m.group(2) in ("copy", "transpose", "concatenate")
+                  and int(np.prod(dims)) >= int(np.prod(ffn))
+                  and dims != head):
+                moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    header = text.split("\n", 1)[0]
+    aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliased) >= (4 if kind == "decode" else 3), header[:400]
