@@ -747,10 +747,21 @@ class LlamaModel(nn.Module):
 # ----------------------------------------------------------------- serving
 # What serve/llm/stage.py asks of a model family's module (this one,
 # models/jamba.py and models/minicpm_sala.py): get_config, serving_model,
-# pool_spec, serving_cache, RESUMES_PREFILL.
+# pool_spec, serving_cache, RESUMES_PREFILL, pass_cost_ratios.
 # pages are all a sequence keeps: a prefill row that starts mid-prompt
 # attends to its earlier pages (the path prefix hits use)
 RESUMES_PREFILL = True
+
+
+def pass_cost_ratios(cfg: LlamaConfig) -> tuple:
+    """What serve/llm/engine.py's `PassCost` asks of a family that resumes,
+    each over the parameters one token multiplies: the weights one prefill
+    pass reads (1 for a dense model; an expert model's pass of a bucket's
+    length reads every expert and a token multiplies
+    `num_experts_per_tok` of them), and the float32 scores a (query, key)
+    pair of its context part makes (one a layer and head)."""
+    active = cfg.active_params()
+    return cfg.num_params() / active, cfg.num_layers * cfg.num_heads / active
 
 
 def serving_model(cfg: LlamaConfig, n_layers=None, first=True, last=True):

@@ -65,6 +65,17 @@ LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
 RESUMES_PREFILL = True
 HEAD_AT_GATHER = True
 
+
+def pass_cost_ratios(cfg) -> tuple:
+    """(weights a prefill pass reads, scores a (query, key) pair of a
+    resumed pass's context makes), each over the parameters a token
+    multiplies (serve/llm/engine.py: PassCost). Every layer is dense; only
+    the sparse layers attend a context, and their masked pass scores a
+    pair for the selection and again for the attention, and sorts: 2.5
+    plain scores on the chip (benchmarks/prefill_split_probe.py)."""
+    return 1.0, 2.5 * cfg.n_sparse_layers * cfg.num_heads / cfg.num_params()
+
+
 _PUBLISHED_MIXERS = tuple(
     SPARSE if i in (0, 9, 16, 17, 22, 29, 30, 31) else LIGHTNING
     for i in range(32))

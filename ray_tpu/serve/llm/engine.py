@@ -107,16 +107,19 @@ prefill). Models with linear-attention and block-sparse layers
 page, its compressed keys; their prefill rows RESUME from the slot's state,
 so of the list above chunked prefill is served and the rest refused.
 
-A prompt longer than the largest prefill bucket (a preempted request's
-folded prompt too) is prefilled in PASSES of the largest bucket, on the
-default scheduler: every pass is one row of `_dispatch_prefill_batch`
+A prompt is prefilled in the PASSES that cost least (`plan_passes`), on
+the default scheduler: full buckets, then the smallest bucket that holds
+what is left, where that computes enough less padding than the one bucket
+that holds the prompt to pay for a pass more; a prompt longer than the
+largest bucket (a preempted request's folded prompt too) starts with
+passes of the largest. Every pass is one row of `_dispatch_prefill_batch`
 that starts at the request's `n_prefilled` mark, attends to the pages the
 earlier passes wrote (the path prefix hits use) and, for a model with
 per-slot state, continues from the slot; only the last pass samples. All
 of a prompt's passes are enqueued in the step that admits it, and the
 device runs them in order. A family whose prefill cannot resume
-(`RESUMES_PREFILL` False: models/jamba.py) still raises past its largest
-bucket.
+(`RESUMES_PREFILL` False: models/jamba.py) prefills every prompt whole and
+still raises past its largest bucket.
 """
 
 from __future__ import annotations
@@ -332,6 +335,85 @@ def _bucket(n: int, buckets) -> int:
     raise ValueError(f"{n} exceeds the largest bucket {buckets[-1]}")
 
 
+# The chip's balance, operations a byte of memory traffic in bf16: 197e12 /
+# 819e9 (TPU v5e; ops/grouped_matmul.py's BALANCE_ROWS is its power of two).
+# It is also the TOKENS at which a prefill pass's products (2 operations a
+# parameter and token) take as long as reading its weights (2 bytes a
+# parameter): a pass of fewer tokens costs the weight read all the same.
+_BALANCE_TOKENS = 240
+
+
+@dataclasses.dataclass(frozen=True)
+class PassCost:
+    """What one prefill pass of `bucket` tokens costs, in tokens' worth of
+    the model's products (benchmarks/prefill_split_probe.py times every
+    term on the chip; PERF.md section 6, PR 36):
+
+    - `max(bucket, floor)`: its products, or the weight read under them.
+      `floor` is the balance x the weights a pass reads over the
+      parameters a token multiplies (1 for a dense model);
+    - its own causal attention, `bucket ** 2 / 2` (query, key) pairs in the
+      flash kernel, at half of `pair` each;
+    - if it RESUMES (it starts mid-prompt): one `floor` more, the bar a
+      split has to clear (the dispatch, a second weight read), and a
+      context part that attends all `width` columns of the block table
+      whatever the context, `bucket * width` pairs at `pair` each. `pair`
+      is the float32 scores a pair makes (one a layer and head, 8 bytes
+      written and read: 4 x the balance in parameters) over the
+      parameters a token multiplies."""
+    floor: float
+    pair: float
+    width: int
+
+    def __call__(self, bucket: int, resumed: bool) -> float:
+        cost = max(bucket, self.floor) + self.pair * bucket * bucket / 4
+        if resumed:
+            cost += self.floor + self.pair * bucket * self.width
+        return cost
+
+
+def plan_passes(n_new: int, buckets, page: int, cost: PassCost,
+                resumed: bool = False) -> List[int]:
+    """The length bucket of each prefill pass for `n_new` prompt tokens
+    (`resumed`: behind a cached prefix, so that the first pass resumes
+    too): every pass but the last is FULL (it prefills exactly its bucket,
+    so where it ends is page-aligned: the context-merge path's contract),
+    the last is the smallest bucket that holds the rest.
+
+    While the rest exceeds the largest bucket it takes passes of the
+    largest. Of the ways to cover what is then left, the one `cost` says
+    is cheapest, the single bucket where nothing is cheaper: one bucket
+    is left for two only where that computes enough less padding to pay
+    for a pass more and its context part. Never more tokens than the
+    single bucket."""
+    largest = buckets[-1]
+    lead = []
+    while n_new > largest:
+        lead.append(largest)
+        n_new -= largest
+    full = [b for b in buckets if b % page == 0]
+    memo: Dict[tuple, tuple] = {}
+
+    def cheapest(rest: int, top: int, resumed: bool) -> tuple:
+        """(cost, buckets) for `rest` tokens, full passes from
+        `full[:top]`, largest first."""
+        got = memo.get((rest, top, resumed))
+        if got is None:
+            last = _bucket(rest, buckets)
+            got = (cost(last, resumed), (last,))
+            for i in reversed(range(top)):
+                if full[i] >= rest:
+                    continue
+                c, tail = cheapest(rest - full[i], i + 1, True)
+                c += cost(full[i], resumed)
+                if c < got[0] and full[i] + sum(tail) <= last:
+                    got = (c, (full[i],) + tail)
+            memo[(rest, top, resumed)] = got
+        return got
+
+    return lead + list(cheapest(n_new, len(full), resumed or bool(lead))[1])
+
+
 class LLMEngine:
     """Single-process engine. Not thread-safe except `add_request`/`abort`
     (which only touch the locked intake queue); one driver thread calls
@@ -427,7 +509,8 @@ class LLMEngine:
             "decode_dispatches_total", "prefill_tokens_total",
             "prefill_padded_tokens_total", "decode_rows_total",
             "decode_ctx_tokens_total", "prefill_passes_total",
-            "prefill_resumed_passes_total"), 0)
+            "prefill_resumed_passes_total", "prefill_split_prompts_total"),
+            0)
         # (layers, experts) of an expert model, whose programs return
         # routing counts packed behind their tokens; None for a dense one
         cfg_m = self.model_cfg
@@ -447,8 +530,17 @@ class LLMEngine:
         self._sparse_layers = getattr(cfg_m, "n_sparse_layers", 0)
         self._sparse = cfg_m.sparse if self._sparse_layers else None
         family = model_family(self.config.model)
-        # a prompt past the largest bucket is prefilled in passes
+        # a prompt is prefilled in the passes that cost least
+        # (`plan_passes`), by the chip's balance and the two ratios the
+        # family answers for its model
         self._resumes = family.RESUMES_PREFILL
+        self._pass_cost = None
+        if self._resumes:
+            weights, scores = family.pass_cost_ratios(cfg_m)
+            self._pass_cost = PassCost(
+                floor=_BALANCE_TOKENS * weights,
+                pair=4 * _BALANCE_TOKENS * scores,
+                width=self.max_pages_per_seq * self.config.page_size)
         self._head_at_gather = getattr(family, "HEAD_AT_GATHER", False)
         # the fields of its `engine.dispatch` records behind `k`, in
         # `tracing.FIELDS`' order, None where the model has none: the
@@ -535,8 +627,9 @@ class LLMEngine:
         """One scheduler iteration. Two scheduling modes share the same
         dispatch/harvest machinery:
 
-        - legacy (prefill_chunk_tokens == 0): admit + prefill whole
-          prompts first (prefill-priority, like vLLM's default), fill
+        - legacy (prefill_chunk_tokens == 0): admit + prefill prompts
+          first, each to its end in the passes that cost least
+          (prefill-priority, like vLLM's default), fill
           the pipeline with fused decode chunks, harvest the oldest
           in-flight dispatch (blocking only when its transfer has not
           landed yet).
@@ -833,10 +926,13 @@ class LLMEngine:
         return np.asarray(handle)
 
     def _dispatch_prefills(self) -> None:
-        """Legacy (prefill-priority) mode: admit as many waiting requests
-        as slots/pages allow and launch one WHOLE-prompt prefill dispatch
-        per length-bucket (single dispatch per bucket: a dispatch per
-        prompt would make TTFT linear in the queue)."""
+        """Prefill-priority mode: admit as many waiting requests as
+        slots/pages allow and prefill each in the passes `plan_passes`
+        gives it. Every pass but a prompt's last is a dispatch of its own,
+        ahead of the last passes, which go out in waves: one dispatch per
+        length bucket (a dispatch per prompt would make TTFT linear in
+        the queue), rows that resume apart from fresh ones (one resuming
+        row gives a whole wave the program with a context part)."""
         admitted = []
         burst_prefixes: set = set()
         with tracing.region("rtpu.engine.admit") as r:
@@ -849,20 +945,22 @@ class LLMEngine:
         if not admitted:
             return
         wave = self._wave_rb
-        largest = self.config.prefill_buckets[-1]
-        by_bucket: Dict[int, List[tuple]] = {}
+        buckets = self.config.prefill_buckets
+        waves: Dict[tuple, List[tuple]] = {}
         for req in admitted:
             n_new = len(req.prompt_ids) - req.n_prefilled
-            while n_new > largest and self._resumes:
-                # a prompt past the largest bucket: passes of the largest
-                # bucket, each its own dispatch, ahead of the last pass
-                # (which joins the waves below). The device runs them in
+            plan = (plan_passes(n_new, buckets, self.config.page_size,
+                                self._pass_cost, req.n_prefilled > 0)
+                    if self._resumes else [_bucket(n_new, buckets)])
+            self._totals["prefill_split_prompts_total"] += (
+                len(plan) > 1 and n_new <= buckets[-1])
+            for sb in plan[:-1]:
+                # a full pass, its own dispatch: the device runs them in
                 # the order they are enqueued
-                self._dispatch_prefill_batch(largest, [(req, largest)])
-                n_new -= largest
-            sb = _bucket(n_new, self.config.prefill_buckets)
-            by_bucket.setdefault(sb, []).append((req, n_new))
-        for sb, group in by_bucket.items():
+                self._dispatch_prefill_batch(sb, [(req, sb)])
+            waves.setdefault((plan[-1], req.n_prefilled > 0), []).append(
+                (req, len(req.prompt_ids) - req.n_prefilled))
+        for (sb, _), group in waves.items():
             for i in range(0, len(group), wave):
                 self._dispatch_prefill_batch(sb, group[i:i + wave])
 
@@ -950,8 +1048,9 @@ class LLMEngine:
                                 group: List[tuple]) -> None:
         """One prefill dispatch. ``group`` rows are (request, n_new):
         each row prefills n_new prompt tokens starting at the request's
-        n_prefilled mark — the whole remaining prompt in legacy mode, one
-        chunk in token-budget mode. Rows whose start is > 0 attend to
+        n_prefilled mark — one pass of its plan in prefill-priority mode
+        (`plan_passes`: often the whole remaining prompt), one chunk in
+        token-budget mode. Rows whose start is > 0 attend to
         their earlier pages through the same ctx-merge path prefix-cache
         hits use; only rows whose FINAL chunk this is sample a token."""
         # the arrays always come at the wave size: ONE compiled row count

@@ -95,10 +95,13 @@ _LLM_WORK_TOTALS = {
         "live rows x fused steps x state-space layers updated (decode)",
     "prefix_reuse_refused_total":
         "admissions that skipped the prefix lookup (recurrent state)",
-    "prefill_passes_total": "prefill rows dispatched (a prompt past the "
-                            "largest bucket takes several)",
+    "prefill_passes_total": "prefill rows dispatched (a prompt takes the "
+                            "passes that cost least: often one)",
     "prefill_resumed_passes_total":
         "prefill rows that started mid-prompt, from pages and slot state",
+    "prefill_split_prompts_total":
+        "prompts that fitted one length bucket and were prefilled in more "
+        "than one pass, because that computed less padding",
     "lightning_prefill_tokens_total":
         "real prompt tokens x linear-attention layers (prefill)",
     "lightning_state_updates_total":

@@ -86,7 +86,10 @@ def model_family(name: str):
     attention). A family's module has `CONFIGS`, `get_config`,
     `serving_model`, `pool_spec`, `serving_cache`, and two flags:
     `RESUMES_PREFILL` (a prefill row continues from what its pages and
-    its slot hold, so a prompt may be prefilled in passes) and, where set,
+    its slot hold, so a prompt may be prefilled in passes; such a family
+    also answers `pass_cost_ratios(cfg)`: the weights a pass reads and
+    the scores its context part makes, over the parameters a token
+    multiplies) and, where set,
     `HEAD_AT_GATHER` (`serving_cache` takes the position each row samples
     from and the model computes the head there only). Its config answers
     `n_slot_state_layers` / `slot_state_bytes_row()` where layers keep
