@@ -5,7 +5,8 @@ seams return numpy arrays at once, so a step is the scheduler's host work
 and nothing else), with the recorder as it ships and with its three entry
 points (`region`, `record`, `now_ns`) replaced by no-ops, every other step;
 the difference of the medians (and the median difference of neighbouring
-steps) is the recorder's own microseconds per step. For
+steps) is the recorder's own microseconds per step; the device stamps a
+harvest computes from them are timed alone. For
 `ShardedTrainer.step` the recorder's part is one region and one record,
 timed alone. Host numbers, whatever machine runs them: no device time.
 
@@ -32,6 +33,20 @@ from ray_tpu.serve.llm.stage import serve_model_config  # noqa: E402
 from ray_tpu.util import tracing  # noqa: E402
 
 
+class Handle:
+    """What a compute seam returns: the tokens, and whether the program
+    behind them had finished when the engine asks (`is_ready`, as a
+    device array answers it)."""
+
+    __slots__ = ("tokens", "ready")
+
+    def __init__(self, tokens: np.ndarray, ready: bool):
+        self.tokens, self.ready = tokens, ready
+
+    def is_ready(self) -> bool:
+        return self.ready
+
+
 class StubEngine(LLMEngine):
     """The scheduler with no model behind it."""
 
@@ -41,14 +56,19 @@ class StubEngine(LLMEngine):
         self._attention = {"decode": "stub", "prefill": "stub"}
         self._device = {"platform": "none"}
 
+    # whether the next handle says its program had finished before the
+    # fetch: False is the rule while the host runs ahead of the device
+    ready = False
+
     def _compute_prefill(self, sb, rb, *_):
-        return np.ones((rb,), np.int32)
+        return Handle(np.ones((rb,), np.int32), self.ready)
 
     def _compute_decode(self, k_steps, *_):
-        return np.ones((k_steps, self.config.max_batch), np.int32)
+        return Handle(np.ones((k_steps, self.config.max_batch), np.int32),
+                      self.ready)
 
     def _fetch_tokens(self, handle):
-        return handle
+        return handle.tokens
 
 
 class _NullRegion:
@@ -114,6 +134,15 @@ def main() -> None:
             pass
         tracing.record("train.step", (i, r.start_ns, r.end_ns))
     train_us = (time.perf_counter() - t0) / n * 1e6
+    # the device stamps of one harvest, alone (they are plain arithmetic on
+    # the engine, so the switch above leaves them on both sides): is the
+    # handle ready, then the record's four fields and the engine's totals
+    handle = eng._compute_decode(1)
+    rec = {"enqueued_ns": tracing.now_ns()}
+    t0 = time.perf_counter()
+    for _ in range(n):
+        eng._device_stamps(rec, eng._handle_ready(handle), 1, 2)
+    stamps_us = (time.perf_counter() - t0) / n * 1e6
     print(json.dumps({
         "rows": args.rows, "steps_each": args.steps,
         "engine_step_us_recorder_on": round(
@@ -126,6 +155,7 @@ def main() -> None:
         "recorder_us_per_engine_step_paired": round(statistics.median(
             a - b for a, b in zip(on, off)) * 1e6, 2),
         "recorder_us_per_train_step": round(train_us, 3),
+        "device_stamps_us_per_dispatch": round(stamps_us, 3),
     }))
 
 

@@ -30,7 +30,8 @@ This file is the scheduler. Everything that lives on devices (params, the
 pool, the decode carry, the compiled programs) belongs to ONE
 `StageCompute` over all layers (serve/llm/stage.py): the engine is a
 pipeline of one stage, and reaches it through `_compute_prefill`,
-`_compute_verify`, `_compute_decode` and `_fetch_tokens` only.
+`_compute_verify`, `_compute_decode`, `_fetch_tokens` and, for the flight
+records' device stamps, `_handle_ready` only.
 
 Host/device contract: a host-blocking fetch (`np.asarray` of a device
 array) costs a device sync — the host waits for every dispatch queued
@@ -196,6 +197,14 @@ class Request:
     dispatched_ns: Optional[int] = None  # first prefill dispatch; reset
                                          # by a preemption as dispatched_t
     first_token_ns: Optional[int] = None
+    # the device's side of `first_token_ns - dispatched_ns`, from the
+    # stamps of the programs that carried the prompt's passes (_harvest):
+    # their device time, the last pass's end, and whether every stamp of
+    # them was exact (else the host came late to one of them and the
+    # parts are bounds); reset by a preemption with dispatched_ns
+    prefill_device_ns: int = 0
+    prefill_end_ns: Optional[int] = None
+    parts_exact: bool = True
     preemptions: int = 0
     n_folded: int = 0            # output tokens a preemption folded into
                                  # prompt_ids
@@ -341,6 +350,8 @@ def _bucket(n: int, buckets) -> int:
 # parameter and token) take as long as reading its weights (2 bytes a
 # parameter): a pass of fewer tokens costs the weight read all the same.
 _BALANCE_TOKENS = 240
+# where an `engine.dispatch` record's device stamps begin (_harvest)
+_STAMPS_AT = tracing.FIELDS["engine.dispatch"].index("enqueued_ns")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -568,6 +579,16 @@ class LLMEngine:
                                 sparse_ctx_tokens_total=0,
                                 sparse_dense_rows_total=0)
         self._queue_wait_ns_total = 0
+        # the device's timeline as the host can stamp it (_device_stamps):
+        # the estimated end of the last program harvested and whether it
+        # is exact, the running step's two facts, the cumulative three
+        self._device_end_ns: Optional[int] = None
+        self._device_end_exact = True
+        self._step_fetch_blocked = 0
+        self._step_device_idle_ns = 0
+        self._device_busy_ns_total = 0
+        self._device_idle_ns_total = 0
+        self._harvests_late_total = 0
 
     # ----------------------------------------------------------- intake
 
@@ -649,6 +670,7 @@ class LLMEngine:
         self._totals["steps_total"] += 1
         phase = self._phase_ns
         phase[:] = (0, 0, 0, 0, 0, 0)
+        self._step_fetch_blocked = self._step_device_idle_ns = 0
         with tracing.region("rtpu.engine.step") as whole:
             deltas: List[OutputDelta] = list(self._pending_deltas)
             self._pending_deltas.clear()
@@ -680,7 +702,8 @@ class LLMEngine:
                 self._harvest(self._inflight.pop(0), deltas)
         tracing.record("engine.step", (
             self._step_seq, whole.start_ns, whole.end_ns, *phase,
-            len(self.running), len(self.waiting)))
+            len(self.running), len(self.waiting), self._step_fetch_blocked,
+            self._step_device_idle_ns))
         return deltas
 
     def _drain_pipeline(self, deltas: List[OutputDelta]) -> None:
@@ -925,6 +948,14 @@ class LLMEngine:
         async D2H copy lands; microseconds once it has)."""
         return np.asarray(handle)
 
+    @staticmethod
+    def _handle_ready(handle) -> Optional[bool]:
+        """Whether the program behind a compute handle has finished,
+        asked just before its fetch (under a microsecond for a device
+        array). None where the handle cannot say (pp.py), and the
+        engine's records then carry no device stamps."""
+        return handle.is_ready()
+
     def _dispatch_prefills(self) -> None:
         """Prefill-priority mode: admit as many waiting requests as
         slots/pages allow and prefill each in the passes `plan_passes`
@@ -1135,6 +1166,7 @@ class LLMEngine:
         self._inflight.append({
             "kind": kind, "toks": toks, "k": k, "seq": self._dispatch_seq,
             "step": self._step_seq, "dispatch_ns": dispatch_ns,
+            "enqueued_ns": tracing.now_ns(),
             "rows_padded": rows_padded, "tokens_padded": tokens_padded,
             "facts": tuple(facts), "tail": (), **harvest_keys})
 
@@ -1429,9 +1461,51 @@ class LLMEngine:
 
     # ---------------------------------------------------------- harvest
 
+    def _device_stamps(self, rec: dict, ready: Optional[bool],
+                       fetch_start_ns: int, fetch_end_ns: int) -> tuple:
+        """(the record's `enqueued_ns`, `device_start_ns`,
+        `device_end_ns`, `end_exact`; whether start and end are both
+        exact). Programs run in dispatch order on one stream and are
+        harvested in that order. A program that had NOT finished when the
+        host came to fetch it (`ready` False: the rule while the host
+        runs ahead of the device) ended as the fetch returned. That is
+        late by the fetch's own lag (the completion's way to the host
+        and the copy of the tokens: 0.6-0.9 ms on a v5e, PERF.md section
+        6, PR 37), the same for every program, so a duration between two
+        such ends carries none of it and a wait that ends at one carries
+        it once. A program that had finished ended at some time before
+        the fetch began: the record says so, and `harvests_late_total`
+        counts it (the host loop is behind the device). A program started
+        when it was enqueued or when the program before it ended,
+        whichever came later: exact unless that end is an upper bound
+        which lies past the enqueue. The gap, where the device had
+        nothing enqueued, is the step's and the engine's idle time."""
+        if ready is None:
+            return (None, None, None, None), False
+        start = rec["enqueued_ns"]
+        prev = self._device_end_ns
+        start_exact = True
+        if prev is not None:
+            if prev > start:
+                start, start_exact = prev, self._device_end_exact
+            else:
+                self._step_device_idle_ns += start - prev
+                self._device_idle_ns_total += start - prev
+        end = fetch_start_ns if ready else fetch_end_ns
+        self._device_end_ns, self._device_end_exact = end, not ready
+        self._device_busy_ns_total += end - start
+        self._step_fetch_blocked += not ready
+        self._harvests_late_total += ready
+        return ((rec["enqueued_ns"], start, end, not ready),
+                start_exact and not ready)
+
     def _harvest(self, rec: dict, deltas: List[OutputDelta]) -> None:
+        ready = self._handle_ready(rec["toks"])
         with tracing.region("rtpu.engine.fetch") as fetch:
             toks_np = self._fetch_tokens(rec["toks"])
+        stamps, exact = self._device_stamps(rec, ready, fetch.start_ns,
+                                            fetch.end_ns)
+        _, device_start_ns, device_end_ns, _ = stamps
         with tracing.region("rtpu.engine.harvest") as r:
             toks_np, moe_facts = self._split_counts(rec, toks_np)
             if rec["kind"] == "prefill":
@@ -1439,6 +1513,13 @@ class LLMEngine:
                     req = self.requests.get(rid)
                     if req is None or req.state != RUNNING or req.slot != slot:
                         continue  # aborted while in flight
+                    if device_end_ns is not None:
+                        # every row of a wave waited for the whole program
+                        req.prefill_device_ns += (device_end_ns
+                                                  - device_start_ns)
+                        req.parts_exact &= exact
+                        if final:
+                            req.prefill_end_ns = device_end_ns
                     self._register_full_pages(req, upto=end)
                     if not final:
                         # intermediate chunk: pages are written; the sampled
@@ -1499,11 +1580,13 @@ class LLMEngine:
                         self._append_token(req, int(toks_np[k, slot]), deltas)
         self._phase_ns[_FETCH] += fetch.ns
         self._phase_ns[_HARVEST] += r.ns
-        tracing.record("engine.dispatch", (
-            rec["seq"], rec["kind"], rec["step"], self._step_seq,
-            rec["dispatch_ns"], fetch.start_ns, fetch.end_ns,
-            rec["rows_padded"], rec["tokens_padded"], rec["facts"],
-            rec["k"]) + moe_facts + self._ssm_fields + rec["tail"])
+        head = (rec["seq"], rec["kind"], rec["step"], self._step_seq,
+                rec["dispatch_ns"], fetch.start_ns, fetch.end_ns,
+                rec["rows_padded"], rec["tokens_padded"], rec["facts"],
+                rec["k"]) + moe_facts + self._ssm_fields + rec["tail"]
+        # the stamps come last, whatever fields the model's family wrote
+        tracing.record("engine.dispatch", head + (None,) * (
+            _STAMPS_AT - len(head)) + stamps)
 
     def _split_counts(self, rec: dict, fetched: np.ndarray) -> tuple:
         """(tokens, the record's `moe_*` fields). An expert model's
@@ -1560,6 +1643,8 @@ class LLMEngine:
         req.spec_inflight = False
         req.dispatched_t = None  # re-prefill measures its own queue wait
         req.dispatched_ns = None
+        req.prefill_device_ns, req.prefill_end_ns = 0, None
+        req.parts_exact = True
         req.state = WAITING
         self.waiting.insert(0, req)
 
@@ -1684,12 +1769,22 @@ class LLMEngine:
         it leaves the engine (finished, aborted, expired, transferred)."""
         if req.dispatched_ns is not None:
             self._queue_wait_ns_total += req.dispatched_ns - req.arrival_ns
+        parts = (None, None, None, None)
+        end = req.prefill_end_ns
+        if (end is not None and req.first_token_ns is not None
+                and req.dispatched_ns is not None):
+            # they sum to first_token_ns - dispatched_ns by construction
+            # (a preempted request keeps its first token and has a later
+            # dispatch: the identity holds, the parts say nothing)
+            parts = (end - req.dispatched_ns - req.prefill_device_ns,
+                     req.prefill_device_ns, req.first_token_ns - end,
+                     req.parts_exact)
         tracing.record("engine.request", (
             req.request_id, req.arrival_ns, req.admitted_ns,
             req.dispatched_ns, req.first_token_ns, tracing.now_ns(),
             len(req.prompt_ids) - req.n_folded, req.n_cached,
             len(req.output_ids) + req.n_folded, req.preemptions,
-            req.finish_reason))
+            req.finish_reason) + parts)
 
     # ------------------------------------------- prefill/decode handoff
 
@@ -1897,6 +1992,10 @@ class LLMEngine:
             "programs_built_total": (self.compute.programs_built
                                      if self.compute else 0),
             "queue_wait_s_total": self._queue_wait_ns_total / 1e9,
+            # the device's timeline by the engine's own stamps
+            "device_busy_s_total": self._device_busy_ns_total / 1e9,
+            "device_idle_s_total": self._device_idle_ns_total / 1e9,
+            "harvests_late_total": self._harvests_late_total,
         }
         if self._ssm_layers and self.compute:
             sizes = self.compute.pool_bytes()

@@ -27,7 +27,8 @@ LLMEngine and overrides only the compute seams:
 - ``_fetch_tokens``: resolve CompiledDAGRef results, converting a dead
   stage rank into a TYPED ActorDiedError/GetTimeoutError (a SIGKILLed
   rank writes no sentinel, so the fetch would otherwise be an untyped
-  timeout).
+  timeout);
+- ``_handle_ready``: None, so its flight records carry no device stamps.
 
 Microbatching: chunked prefills already arrive as token-budget-sized
 frames (prefill_chunk_tokens); decode slots partition into
@@ -343,6 +344,12 @@ class PipelinedEngine(LLMEngine):
             self._slot_override[r.slot] = (
                 r.output_ids[-1] if r.output_ids else r.prompt_ids[-1])
         return groups[g]
+
+    @staticmethod
+    def _handle_ready(handle) -> None:
+        """A frame's programs run in the stage workers: this process
+        cannot say when they ended, and stamps no device timeline."""
+        return None
 
     def _fetch_tokens(self, handle) -> np.ndarray:
         if isinstance(handle, np.ndarray):

@@ -68,6 +68,9 @@ class LLMConfig:
 
 
 _LLM_METRICS = None
+# a token's pace and the three parts of a first token's wait are
+# milliseconds to seconds
+_MS_BOUNDARIES = (0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 1.0, 2.0)
 # engine.stats() totals published as rtpu_llm_<key> counters
 _LLM_WORK_TOTALS = {
     "steps_total": "engine.step() calls",
@@ -82,6 +85,16 @@ _LLM_WORK_TOTALS = {
     "queue_wait_s_total":
         "seconds finished requests waited for their first prefill dispatch",
     "programs_built_total": "programs the engine built (jit cache misses)",
+    "device_busy_s_total":
+        "seconds the device ran the engine's programs, by the engine's own "
+        "stamps (a program's end is its fetch's end when the host waited "
+        "for it)",
+    "device_idle_s_total":
+        "seconds the device had no program enqueued between two programs",
+    "harvests_late_total":
+        "programs that had finished before the host came to fetch them: "
+        "the host loop was behind the device (of prefill_dispatches_total "
+        "+ decode_dispatches_total)",
     "moe_assignments_total":
         "real (token, expert) assignments of an expert model, all layers",
     "moe_experts_touched_total":
@@ -132,7 +145,7 @@ def _get_llm_metrics():
     read. Counters end ``_total``, gauges do not (RTPU106); the nodelet
     ships worker-side counters with get_node_info's serve family. The
     work counters are the flight recorder's totals (``engine.stats()``),
-    the three histograms are observed from its finished
+    the five histograms are observed from its finished
     ``engine.request`` records (ray_tpu/util/tracing.py)."""
     global _LLM_METRICS
     if _LLM_METRICS is None:
@@ -161,12 +174,26 @@ def _get_llm_metrics():
             "ttft": Histogram(
                 "rtpu_llm_ttft_seconds",
                 "arrival to first token, per finished request"),
+            # first token - first dispatch, its two device parts
+            # (engine.request), of the requests whose parts are exact
+            "device_wait": Histogram(
+                "rtpu_llm_device_wait_seconds",
+                "first prefill dispatch to the last pass's end, less the "
+                "request's own programs: other programs ahead of it on the "
+                "device, per finished request whose programs the host "
+                "waited for",
+                boundaries=_MS_BOUNDARIES),
+            "prefill_device": Histogram(
+                "rtpu_llm_prefill_device_seconds",
+                "device time of the programs that carried the prompt's "
+                "passes, per finished request whose programs the host "
+                "waited for",
+                boundaries=_MS_BOUNDARIES),
             "tpot": Histogram(
                 "rtpu_llm_tpot_seconds",
                 "(finish - first token) / (output tokens - 1), per "
                 "finished request",
-                boundaries=(0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5,
-                            1.0, 2.0)),
+                boundaries=_MS_BOUNDARIES),
         }
         for key, what in _LLM_WORK_TOTALS.items():
             _LLM_METRICS[key] = Counter(f"rtpu_llm_{key}", what)
@@ -263,6 +290,9 @@ class EngineDriverMixin:
                     continue
                 m["ttft"].observe(
                     (r["first_token_ns"] - r["arrival_ns"]) / 1e9)
+                if r["parts_exact"] and not r["preemptions"]:
+                    for part in ("device_wait", "prefill_device"):
+                        m[part].observe(r[part + "_ns"] / 1e9)
                 if r["output_tokens"] > 1:
                     m["tpot"].observe(
                         (r["finish_ns"] - r["first_token_ns"]) / 1e9

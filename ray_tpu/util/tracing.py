@@ -136,11 +136,26 @@ def collect() -> List[Dict[str, Any]]:
 # (None where the event never happened), other `*_ns` of engine.step are
 # durations inside the step
 FIELDS: Dict[str, Tuple[str, ...]] = {
-    # one per request, written when it finishes, aborts or expires
+    # one per request, written when it finishes, aborts or expires. The
+    # last four split `first_token_ns - dispatched_ns` by the device's
+    # timeline as the engine stamps it (engine.dispatch, below) and sum to
+    # it to the nanosecond: `prefill_device_ns` the device time of the
+    # programs that carried the prompt's passes, `device_wait_ns` the rest
+    # of the time from the first dispatch to the last pass's stamped end
+    # (other programs ahead of it on the device, the host between its
+    # passes, and the fetch's own lag behind that end), `harvest_host_ns`
+    # the host's code from that stamp to the token's. `parts_exact` is
+    # False where the host came to one of those programs after it had
+    # finished: the host loop was behind the device, the two device parts
+    # are then upper bounds and hold the lag, which `harvest_host_ns`
+    # never shows. None for a request that got no first token, and for
+    # every request of an engine whose handles cannot say (pp)
     "engine.request": (
         "request_id", "arrival_ns", "admitted_ns", "dispatched_ns",
         "first_token_ns", "finish_ns", "prompt_tokens", "cached_tokens",
-        "output_tokens", "preemptions", "finish_reason"),
+        "output_tokens", "preemptions", "finish_reason",
+        "device_wait_ns", "prefill_device_ns", "harvest_host_ns",
+        "parts_exact"),
     # one per program enqueued, written when its tokens are harvested;
     # `rows` is a tuple of (request_id, q_tokens, ctx_tokens) per real row.
     # The three `moe_*` fields are written for an expert model only (a
@@ -158,7 +173,21 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
     # attended for the record's real rows (summed over layers, kv-head
     # groups and fused steps), the compressed keys scored the same way,
     # and for a prefill a tuple a real row of how many passes of its
-    # prompt came before this one and whether it is the last
+    # prompt came before this one and whether it is the last.
+    # The last four are the program on the device's timeline, stamped by
+    # the host with no profiler (programs run in dispatch order on one
+    # stream): `enqueued_ns` when the compute seam returned (`dispatch_ns`
+    # is taken before the host builds the program's arrays),
+    # `device_end_ns` the end of the fetch if the program had not finished
+    # when the host came to fetch it (`end_exact`: the fetch then returns
+    # as the program ends, behind it by the completion's way to the host
+    # and the copy of the tokens, the same lag for every program) and else
+    # the fetch's start, an upper bound,
+    # `device_start_ns` the later of `enqueued_ns` and the previous
+    # program's `device_end_ns`. They come LAST, behind the families'
+    # fields (None for a model without them), because hand-made records
+    # of the benchmark's own tests are laid out by position; None in all
+    # four where the handle cannot say whether it is ready (pp)
     "engine.dispatch": (
         "seq", "kind", "step_dispatched", "step_harvested", "dispatch_ns",
         "fetch_start_ns", "fetch_end_ns", "rows_padded", "tokens_padded",
@@ -166,12 +195,18 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         "moe_expert_tokens_max", "ssm_layers", "ssm_state_bytes_row",
         "lin_layers", "lin_state_bytes_row", "sparse_layers",
         "sparse_tokens_read", "sparse_kernels_scored", "pass_index",
-        "final"),
-    # one per LLMEngine.step()
+        "final", "enqueued_ns", "device_start_ns", "device_end_ns",
+        "end_exact"),
+    # one per LLMEngine.step(); `fetch_blocked`: how many of the step's
+    # harvests found their program unfinished (the step waited for the
+    # device, not the device for the step), `device_idle_ns`: time the
+    # device had nothing enqueued, counted in the step that harvests the
+    # program which ended the gap
     "engine.step": (
         "seq", "start_ns", "end_ns", "intake_ns", "admit_ns",
         "dispatch_prefill_ns", "dispatch_decode_ns", "fetch_ns",
-        "harvest_ns", "running", "waiting"),
+        "harvest_ns", "running", "waiting", "fetch_blocked",
+        "device_idle_ns"),
     # one per program the engine builds (a miss of its jit cache)
     "engine.program_built": ("kind", "shape_key", "ns", "step_seq"),
     # one per ShardedTrainer.step(): the host's time to dispatch the step
@@ -274,27 +309,42 @@ class region:
         return self.end_ns - self.start_ns
 
 
-# how a record of each kind is drawn: (event name, field that starts the
-# event, field that ends it)
-_RING_EVENT = {
-    "engine.step": (lambda r: "rtpu.engine.step", "start_ns", "end_ns"),
-    "engine.dispatch": (lambda r: f"{r['kind']} #{r['seq']}", "dispatch_ns",
-                        "fetch_end_ns"),
-    "engine.request": (lambda r: str(r["request_id"]), "arrival_ns",
-                       "finish_ns"),
-    "engine.program_built": (lambda r: f"built {r['kind']}", "ns", "ns"),
-    "train.step": (lambda r: "rtpu.train.step", "start_ns", "end_ns"),
-}
+def _dispatch_name(r: Dict[str, Any]) -> str:
+    return f"{r['kind']} #{r['seq']}"
+
+
+# how the records are drawn: (lane, record kind, event name, field that
+# starts the event, field that ends it). A dispatch is drawn twice: as the
+# host saw it, and on the lane `rtpu.device` as the device ran it by the
+# engine's stamps, which is the device's timeline with no profiler session
+_RING_EVENT = (
+    ("engine.step", "engine.step", lambda r: "rtpu.engine.step", "start_ns",
+     "end_ns"),
+    ("engine.dispatch", "engine.dispatch", _dispatch_name, "dispatch_ns",
+     "fetch_end_ns"),
+    ("rtpu.device", "engine.dispatch", _dispatch_name, "device_start_ns",
+     "device_end_ns"),
+    ("engine.request", "engine.request", lambda r: str(r["request_id"]),
+     "arrival_ns", "finish_ns"),
+    ("engine.program_built", "engine.program_built",
+     lambda r: f"built {r['kind']}", "ns", "ns"),
+    ("train.step", "train.step", lambda r: "rtpu.train.step", "start_ns",
+     "end_ns"),
+)
 
 
 def _ring_events() -> List[Dict[str, Any]]:
-    """Ring records as chrome://tracing events, one thread per kind."""
+    """Ring records as chrome://tracing events, one thread per lane; a
+    record without a lane's stamps (None, or a record older than the
+    field) is not drawn there."""
     out = []
-    for kind, (name, start, end) in _RING_EVENT.items():
+    for lane, kind, name, start, end in _RING_EVENT:
         for rec in records(kind):
             args = dict(zip(FIELDS[kind], rec))
-            out.append({"ph": "X", "name": name(args), "cat": kind,
-                        "pid": "rtpu.ring", "tid": kind,
+            if args.get(start) is None or args.get(end) is None:
+                continue
+            out.append({"ph": "X", "name": name(args), "cat": lane,
+                        "pid": "rtpu.ring", "tid": lane,
                         "ts": args[start] / 1e3,
                         "dur": max(args[end] - args[start], 0) / 1e3,
                         "args": args})

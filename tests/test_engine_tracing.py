@@ -11,7 +11,9 @@ import json
 import numpy as np
 import pytest
 
+from benchmarks.recorder_cost import StubEngine
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.serve.llm.engine import PassCost
 from ray_tpu.util import tracing
 
 ENGINE_CFG = dict(
@@ -410,9 +412,30 @@ def test_stats_totals_and_llm_metrics_equal_the_records_sums(engine):
         if name in counters:
             assert counters[name] - counters0.get(name, 0) \
                 == pytest.approx(delta), name
-    for hist in ("queue_wait", "ttft", "tpot"):
+    # the two device parts of a first token's wait are observed for the
+    # requests whose programs the host waited for, and no others
+    exact = sum(bool(r["parts_exact"]) for r in reqs)
+    for hist, n in (("queue_wait", 3), ("ttft", 3), ("tpot", 3),
+                    ("device_wait", exact), ("prefill_device", exact)):
         name = f"rtpu_llm_{hist}_seconds_count"
-        assert counters[name] - counters0.get(name, 0) == 3, name
+        assert counters.get(name, 0) - counters0.get(name, 0) == n, name
+    # the device's timeline by the engine's stamps: the totals are the
+    # records' sums, and with the host's part the two histograms' sums
+    # are the wait for the first token that `ttft - queue_wait` is
+    assert moved["device_busy_s_total"] == pytest.approx(sum(
+        d["device_end_ns"] - d["device_start_ns"] for d in dispatches) / 1e9)
+    assert moved["device_idle_s_total"] == pytest.approx(sum(
+        s["device_idle_ns"] for s in _dicts("engine.step")) / 1e9)
+    assert moved["harvests_late_total"] == sum(
+        not d["end_exact"] for d in dispatches)
+    if exact == 3:
+        sums = {h: counters[f"rtpu_llm_{h}_seconds_sum"]
+                - counters0.get(f"rtpu_llm_{h}_seconds_sum", 0)
+                for h in ("queue_wait", "ttft", "device_wait",
+                          "prefill_device")}
+        assert (sums["device_wait"] + sums["prefill_device"] + sum(
+            r["harvest_host_ns"] for r in reqs) / 1e9) == pytest.approx(
+                sums["ttft"] - sums["queue_wait"])
 
 
 def test_a_step_makes_a_bounded_number_of_clock_reads_and_appends(
@@ -440,11 +463,179 @@ def test_a_step_makes_a_bounded_number_of_clock_reads_and_appends(
         engine.step()
     monkeypatch.undo()
     # a decode step: six regions (step, intake, admit, dispatch_decode,
-    # fetch, harvest) of two reads each and one read at the dispatch; one
-    # append for the step and one for the dispatch it harvests
-    assert reads[0] == 13 * steps
+    # fetch, harvest) of two reads each, one read at the dispatch and one
+    # when its program is enqueued (the device stamps are arithmetic on
+    # these and the fetch's two); one append for the step and one for the
+    # dispatch it harvests
+    assert reads[0] == 14 * steps
     assert appends[0] == 2 * steps
     _run(engine)
+
+
+# -------------------- the device's timeline from the engine's own stamps
+STUB_CFG = dict(page_size=8, num_pages=64, max_model_len=128, max_batch=4,
+                prefill_buckets=(16, 32, 64, 128))
+
+
+def _stub(cls=StubEngine, ready=False, **over):
+    """The scheduler over compute seams that return handles whose
+    readiness the case sets (benchmarks/recorder_cost.py), with a floor
+    at which the tiny buckets split (70 tokens = 64 + 16)."""
+    engine = cls(EngineConfig(**{**STUB_CFG, **over}))
+    engine.ready = ready
+    engine._pass_cost = PassCost(4, 0.0, 0)
+    return engine
+
+
+def _by_seq():
+    return sorted(_dicts("engine.dispatch"), key=lambda d: d["seq"])
+
+
+@pytest.mark.parametrize("case, prompts, over, passes", [
+    ("one pass", [20], {}, [1]),
+    ("split by plan_passes", [70], {}, [2]),
+    ("a wave of several rows", [20, 21, 22], {}, [1, 1, 1]),
+    ("a wave and a split prompt", [20, 70, 21], {}, [1, 2, 1]),
+    ("preempted", [17, 17], dict(num_pages=12, max_model_len=64,
+                                 max_batch=2, prefill_buckets=(16, 32, 64)),
+     None)])
+def test_the_three_parts_sum_to_the_wait_for_the_first_token(
+        case, prompts, over, passes):
+    engine = _stub(**over)
+    for i, n in enumerate(prompts):
+        # a token of its own: no page is shared through the prefix cache
+        engine.add_request(f"r{i}", [i + 1] * n,
+                           SamplingParams(max_tokens=40))
+    _run(engine, max_steps=900)
+    reqs = {r["request_id"]: r for r in _dicts("engine.request")}
+    assert len(reqs) == len(prompts)
+    prefills = [d for d in _by_seq() if d["kind"] == "prefill"]
+    for rid, r in reqs.items():
+        assert (r["device_wait_ns"] + r["prefill_device_ns"]
+                + r["harvest_host_ns"]
+                == r["first_token_ns"] - r["dispatched_ns"]), case
+        assert r["parts_exact"] is True
+    if passes is None:
+        assert sum(r["preemptions"] for r in reqs.values()) >= 1
+        return
+    for i, n_passes in enumerate(passes):
+        r = reqs[f"r{i}"]
+        mine = [d for d in prefills
+                if any(row[0] == f"r{i}" for row in d["rows"])]
+        assert len(mine) == n_passes
+        # its own programs, whole: a wave's rows all waited for the wave
+        assert r["prefill_device_ns"] == sum(
+            d["device_end_ns"] - d["device_start_ns"] for d in mine)
+        assert r["device_wait_ns"] >= 0 and r["harvest_host_ns"] >= 0
+        assert (r["dispatched_ns"] + r["device_wait_ns"]
+                + r["prefill_device_ns"]) == mine[-1]["device_end_ns"]
+
+
+@pytest.mark.parametrize("ready", [False, True])
+def test_a_handle_ready_before_its_fetch_gives_an_upper_bound(ready):
+    from ray_tpu.serve.llm.server import EngineDriverMixin
+    from ray_tpu.util import metrics
+
+    engine = _stub(ready=ready)
+    driver = EngineDriverMixin()
+    driver.engine = engine
+    driver._init_driver()
+    driver._publish_llm_metrics(engine.stats())
+    counters0 = metrics.snapshot("rtpu_llm_")
+    engine.add_request("r", [1] * 70, SamplingParams(max_tokens=6))
+    _run(engine)
+    dispatches, steps = _by_seq(), _dicts("engine.step")
+    assert len(dispatches) >= 4
+    for d in dispatches:
+        assert d["end_exact"] is (not ready)
+        assert d["device_end_ns"] == (d["fetch_start_ns"] if ready
+                                      else d["fetch_end_ns"])
+        assert d["dispatch_ns"] <= d["enqueued_ns"] <= d["device_start_ns"]
+    (req,) = _dicts("engine.request")
+    assert req["parts_exact"] is (not ready)
+    blocked = sum(s["fetch_blocked"] for s in steps)
+    assert blocked == (0 if ready else len(dispatches))
+    assert all(s["fetch_blocked"] <= 2 for s in steps)
+    # the host that comes late is counted, whatever the program's kind,
+    # and its request's parts, which are bounds, reach no histogram
+    late = len(dispatches) if ready else 0
+    assert engine.stats()["harvests_late_total"] == late
+    driver._publish_llm_metrics(engine.stats())
+    counters = metrics.snapshot("rtpu_llm_")
+    moved = {k: counters.get(f"rtpu_llm_{k}", 0)
+             - counters0.get(f"rtpu_llm_{k}", 0)
+             for k in ("harvests_late_total", "ttft_seconds_count",
+                       "device_wait_seconds_count",
+                       "prefill_device_seconds_count")}
+    assert moved == {"harvests_late_total": late, "ttft_seconds_count": 1,
+                     "device_wait_seconds_count": 0 if ready else 1,
+                     "prefill_device_seconds_count": 0 if ready else 1}
+
+
+class _EveryThird(StubEngine):
+    """Every third program had finished before the host came for it."""
+    ready = property(lambda self: self._dispatch_seq % 3 == 0,
+                     lambda self, value: None)
+
+
+@pytest.mark.parametrize("cls", [StubEngine, _EveryThird])
+def test_stamped_programs_follow_one_another_and_the_gaps_are_the_idle_time(
+        cls):
+    import time
+
+    engine = _stub(cls)
+    before = engine.stats()
+    for i in range(3):
+        # the pipeline runs dry between two requests: the device idles
+        engine.add_request(f"r{i}", [i + 1] * (20 + 50 * (i % 2)),
+                           SamplingParams(max_tokens=5))
+        _run(engine)
+        time.sleep(0.002)
+    dispatches = _by_seq()
+    gaps = 0
+    for prev, d in zip(dispatches, dispatches[1:]):
+        assert d["device_start_ns"] >= prev["device_end_ns"]
+        assert d["device_start_ns"] == max(d["enqueued_ns"],
+                                           prev["device_end_ns"])
+        assert d["device_end_ns"] >= d["device_start_ns"]
+        gaps += max(0, d["enqueued_ns"] - prev["device_end_ns"])
+    assert gaps >= 2 * 2_000_000
+    assert gaps == sum(s["device_idle_ns"] for s in _dicts("engine.step"))
+    after = engine.stats()
+    assert after["device_idle_s_total"] - before["device_idle_s_total"] \
+        == pytest.approx(gaps / 1e9)
+    assert after["device_busy_s_total"] - before["device_busy_s_total"] \
+        == pytest.approx(sum(d["device_end_ns"] - d["device_start_ns"]
+                             for d in dispatches) / 1e9)
+    if cls is _EveryThird:
+        assert {d["end_exact"] for d in dispatches} == {True, False}
+
+
+def test_an_engine_whose_handles_cannot_say_stamps_nothing():
+    """The pipelined engine's seam: every new field None, nothing drawn
+    on the device's lane, nothing counted."""
+    from ray_tpu.serve.llm.pp import PipelinedEngine
+
+    class NoStamps(StubEngine):
+        _handle_ready = staticmethod(PipelinedEngine._handle_ready)
+
+    assert PipelinedEngine._handle_ready is not LLMEngine._handle_ready
+    engine = _stub(NoStamps)
+    engine.add_request("r", [1] * 70, SamplingParams(max_tokens=6))
+    _run(engine)
+    stamps = ("enqueued_ns", "device_start_ns", "device_end_ns", "end_exact")
+    parts = ("device_wait_ns", "prefill_device_ns", "harvest_host_ns",
+             "parts_exact")
+    dispatches = _dicts("engine.dispatch")
+    assert dispatches and all(d[f] is None for d in dispatches
+                              for f in stamps)
+    (req,) = _dicts("engine.request")
+    assert req["first_token_ns"] and all(req[f] is None for f in parts)
+    assert all(s["fetch_blocked"] == 0 == s["device_idle_ns"]
+               for s in _dicts("engine.step"))
+    assert engine.stats()["device_busy_s_total"] == 0
+    assert not [e for e in tracing.chrome_trace([])
+                if e["cat"] == "rtpu.device"]
 
 
 def test_trainer_step_leaves_one_record_a_call():
@@ -485,3 +676,11 @@ def test_chrome_trace_renders_the_ring(engine):
         tracing.records("engine.dispatch"))
     step = by_cat["engine.step"][0]
     assert step["ts"] == pytest.approx(step["args"]["start_ns"] / 1e3)
+    # the lane of the device: every dispatch again, from its stamped start
+    # to its stamped end, one after another
+    lane = by_cat["rtpu.device"]
+    assert {e["tid"] for e in lane} == {"rtpu.device"}
+    assert [e["name"] for e in lane] == [
+        e["name"] for e in by_cat["engine.dispatch"]]
+    for before, after in zip(lane, lane[1:]):
+        assert before["ts"] + before["dur"] <= after["ts"] + 1.0

@@ -387,6 +387,7 @@ def test_records_and_stats_on_a_known_schedule(params):
 
 
 def test_other_families_carry_none_of_it():
+    fields = tracing.FIELDS["engine.dispatch"]
     for preset, n in (("tiny", 11), ("tiny-jamba", 16)):
         tracing.reset_ring()
         engine = LLMEngine(EngineConfig(model=preset, dtype="float32",
@@ -395,7 +396,11 @@ def test_other_families_carry_none_of_it():
         assert not [k for k in engine.stats()
                     if k.startswith(("lightning_", "sparse_", "lin_"))]
         assert engine.stats()["prefill_passes_total"] == 1
-        assert {len(r) for r in tracing.records("engine.dispatch")} == {n}
+        # None from the family's first position to the device stamps,
+        # which come last
+        recs = tracing.records("engine.dispatch")
+        assert recs and all(set(r[n:fields.index("enqueued_ns")]) == {None}
+                            for r in recs)
 
 
 # -------------------------------------------------------------- lightning
