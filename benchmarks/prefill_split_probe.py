@@ -16,14 +16,18 @@ out.
 `times`: for every length bucket one `[1 x bucket]` pass through
 `LLMEngine._dispatch_prefill_batch` and its harvest (the host's dispatch
 and the fetch included: what a pass adds to a first token's wait), FRESH
-(it starts at 0: the program without a context part) and RESUMED (it starts
-one full pass of the next larger bucket in, and as deep as the model length
-leaves room for: the program whose context part attends the block table's
-whole width). One JSON line a (bucket, start):
-milliseconds, least and median of `--reps`, and the pass in tokens' worth
+(it starts at 0: the program without a context part; from 1024 tokens up
+also with half of the bucket real, the rest padding) and
+RESUMED at three contexts (one full pass of the next larger bucket in, as
+deep as the model length leaves room for, and a quarter of that: ONE
+program, whose context part attends the context a row has). One JSON line a (bucket, start, real): milliseconds, least and
+median of `--reps`, the (query block, key block) visits and the pairs in
+them that the flash calls make a layer and head
+(`ops.paged_attention.prefill_block_visits`), and the pass in tokens' worth
 of the largest fresh pass, beside what the engine's `PassCost` says it
 costs. The last line: the least fresh pass over the largest one's time a
-token (the floor), and what resuming adds to each bucket.
+token (the floor), and for every pass but a bucket's full fresh one the
+milliseconds a million pairs it differs by from that one.
 
 `parity` (a dense Llama-family cell): for each length in `--parity-lens`,
 (a) the logits at every position of the prompt prefilled in the plan's
@@ -70,58 +74,76 @@ def build_engine(cell, seed: int):
     return runner
 
 
-def one_pass(engine, sb: int, start: int, ids) -> float:
+def one_pass(engine, sb: int, start: int, ids, real=None) -> float:
     """Seconds from the dispatch of one `[1 x sb]` pass that starts at
-    `start` to its harvest. The request is the probe's own and never
-    enters the scheduler; its pages are the pool's first."""
+    `start`, `real` of its tokens a prompt's (None: all), to its harvest.
+    The request is the probe's own and never enters the scheduler; its
+    pages are the pool's first."""
     from ray_tpu.serve.llm.engine import RUNNING, Request, SamplingParams
 
     page = engine.config.page_size
-    req = Request("probe", list(ids[:start + sb]),
+    real = sb if real is None else real
+    req = Request("probe", list(ids[:start + real]),
                   SamplingParams(max_tokens=1))
     req.pages = list(range(1, 2 + (start + sb) // page))
     req.slot, req.state, req.n_prefilled = 0, RUNNING, start
     t0 = time.perf_counter()
-    engine._dispatch_prefill_batch(sb, [(req, sb)])
+    engine._dispatch_prefill_batch(sb, [(req, real)])
     engine._harvest(engine._inflight.pop(0), [])
     return time.perf_counter() - t0
 
 
 def job_times(engine, emit, reps: int, seed: int) -> None:
+    from ray_tpu.ops.paged_attention import prefill_block_visits
+
     buckets = list(engine.config.prefill_buckets)
     room = engine.config.max_model_len
+    width = engine.max_pages_per_seq * engine.config.page_size
     ids = np.random.default_rng(seed).integers(
         0, engine.model_cfg.vocab_size, room).tolist()
     rows = []
     for i, sb in enumerate(buckets):
-        # the plan's case (behind one pass of the next bucket up) and the
-        # deepest start the model length leaves room for
+        # fresh whole, fresh with half of its query blocks padding, and
+        # resumed: the plan's case (behind one pass of the next bucket up,
+        # or the largest that leaves room), the deepest start the model
+        # length leaves room for, and a quarter of that
+        cases = [(0, sb)] + ([(0, sb // 2)] if sb >= 1024 else [])
+        deep = (room - sb - 1) // 128 * 128
         fits = [b for b in buckets if b + sb < room]
-        starts = [0] + (sorted({min(buckets[min(i + 1, len(buckets) - 1)],
-                                    fits[-1]), fits[-1]})
-                        if engine._resumes and fits else [])
-        for start in starts:
-            one_pass(engine, sb, start, ids)        # builds the program
-            secs = [one_pass(engine, sb, start, ids) for _ in range(reps)]
-            rows.append({"bucket": sb, "start": start,
+        if engine._resumes and fits:
+            cases += [(start, sb) for start in sorted({
+                min(buckets[min(i + 1, len(buckets) - 1)], fits[-1]),
+                max(128, deep // 512 * 128), deep})]
+        for start, real in cases:
+            one_pass(engine, sb, start, ids, real)   # builds the program
+            secs = [one_pass(engine, sb, start, ids, real)
+                    for _ in range(reps)]
+            visits, pairs = prefill_block_visits(
+                sb, width if start else 0, real, start)
+            rows.append({"bucket": sb, "start": start, "real": real,
                          "ms_min": 1e3 * min(secs),
-                         "ms_median": 1e3 * statistics.median(secs)})
-    fresh = {r["bucket"]: r["ms_min"] for r in rows if r["start"] == 0}
-    per_token = fresh[buckets[-1]] / buckets[-1]
+                         "ms_median": 1e3 * statistics.median(secs),
+                         "visits": visits, "pairs": pairs})
+    fresh = {r["bucket"]: r for r in rows
+             if r["start"] == 0 and r["real"] == r["bucket"]}
+    per_token = fresh[buckets[-1]]["ms_min"] / buckets[-1]
     cost = engine._pass_cost
+    rates = {}
     for r in rows:
         r["tokens_worth"] = r["ms_min"] / per_token
         if cost is not None:
-            r["model_tokens"] = cost(r["bucket"], r["start"] > 0)
+            r["model_tokens"] = cost(r["bucket"], r["real"], r["start"])
+        base = fresh[r["bucket"]]
+        if r["pairs"] != base["pairs"]:
+            rates["%d@%d/%d" % (r["bucket"], r["start"], r["real"])] = (
+                1e6 * (r["ms_min"] - base["ms_min"])
+                / (r["pairs"] - base["pairs"]))
         emit("pass", r)
-    resumed = {r["bucket"]: r["ms_min"] for r in reversed(rows)
-               if r["start"]}       # a bucket's shallowest resumed start
     emit("fit", {
         "ms_a_token_at_largest": per_token,
-        "floor_tokens": min(fresh.values()) / per_token,
+        "floor_tokens": min(r["ms_min"] for r in fresh.values()) / per_token,
         "engine_cost": cost and vars(cost),
-        "resumed_less_fresh_tokens": {
-            b: (resumed[b] - fresh[b]) / per_token for b in resumed}})
+        "ms_a_million_pairs_against_the_fresh_pass": rates})
 
 
 def logits_in_passes(engine, prompt, plan):
@@ -188,7 +210,7 @@ def job_parity(runner, emit, lens, seed: int) -> None:
         # the whole run would find them by their hashes and prefill nothing
         engine.allocator = PageAllocator(engine.config.num_pages,
                                          engine.config.page_size)
-        engine._pass_cost = PassCost(float("inf"), 0.0, 0)   # no split pays
+        engine._pass_cost = PassCost(float("inf"), 0.0)   # no split pays
         toks_whole = _engine_generate(engine, [prompt], 16)[0]
         engine._pass_cost = cost
         assert engine.stats()["prefix_token_hits"] == 0
@@ -262,7 +284,7 @@ def main(argv=None) -> int:
     if args.floor is not None:
         from ray_tpu.serve.llm.engine import PassCost
 
-        engine._pass_cost = PassCost(args.floor, 0.0, 0)
+        engine._pass_cost = PassCost(args.floor, 0.0)
     log(f"engine: buckets {engine.config.prefill_buckets} page "
         f"{engine.config.page_size} {engine._pass_cost}")
     jobs = args.jobs.split(",")

@@ -758,8 +758,8 @@ def pass_cost_ratios(cfg: LlamaConfig) -> tuple:
     each over the parameters one token multiplies: the weights one prefill
     pass reads (1 for a dense model; an expert model's pass of a bucket's
     length reads every expert and a token multiplies
-    `num_experts_per_tok` of them), and the float32 scores a (query, key)
-    pair of its context part makes (one a layer and head)."""
+    `num_experts_per_tok` of them), and the scores a (query, key) pair
+    makes in the flash kernel (one a layer and head)."""
     active = cfg.active_params()
     return cfg.num_params() / active, cfg.num_layers * cfg.num_heads / active
 

@@ -68,12 +68,14 @@ HEAD_AT_GATHER = True
 
 def pass_cost_ratios(cfg) -> tuple:
     """(weights a prefill pass reads, scores a (query, key) pair of a
-    resumed pass's context makes), each over the parameters a token
-    multiplies (serve/llm/engine.py: PassCost). Every layer is dense; only
-    the sparse layers attend a context, and their masked pass scores a
-    pair for the selection and again for the attention, and sorts: 2.5
-    plain scores on the chip (benchmarks/prefill_split_probe.py)."""
-    return 1.0, 2.5 * cfg.n_sparse_layers * cfg.num_heads / cfg.num_params()
+    pass's attention makes), each over the parameters a token multiplies
+    (serve/llm/engine.py: PassCost, which prices a score as the flash
+    kernel makes it). Every layer is dense; only the sparse layers attend
+    a context, and their masked pass is plain XLA that scores a pair for
+    the selection and again for the attention, and sorts: 6.5 of the flash
+    kernel's scores on the chip, 8 at 10k tokens of context and 5 at 40k
+    (benchmarks/prefill_split_probe.py; PERF.md section 6, PR 39)."""
+    return 1.0, 6.5 * cfg.n_sparse_layers * cfg.num_heads / cfg.num_params()
 
 
 _PUBLISHED_MIXERS = tuple(

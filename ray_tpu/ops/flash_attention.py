@@ -13,13 +13,18 @@ ABSENT").  Here the hot op is owned natively: a blocked online-softmax
   tests/test_chip_compile.py): forward compiles up to 16384 kv rows and
   is refused at 32768, backward compiles up to 4096 and is refused at
   8192, at head_dim 64 and 128 alike (`RESOURCE_EXHAUSTED ... vmem`).
-  Longer sequences need K/V tiled over the grid (ROADMAP A3),
+  Longer sequences need K/V tiled over the grid (ROADMAP A6),
 - GQA handled in the BlockSpec index map (q-head h reads kv-head h // n_rep),
   so no materialised `repeat_kv`,
 - causal masking is relative to the *end* of the kv sequence (tril with
   offset sk - sq), which makes the same kernel correct for training
   (sq == sk), chunked prefill and multi-token decode (sq < sk),
 - packed-sequence masking via (q_segment_ids, kv_segment_ids),
+- on the forward-only path (`return_lse=True`: paged prefill) each batch
+  row's TRUE lengths as data (`q_lens`, `kv_lens`, scalar-prefetched): a
+  query block past a row's queries runs no loop and the key loop ends with
+  the row's keys, so a bucket's padding and a block table's unused width
+  cost nothing; the shapes, and so the programs, stay the buckets',
 - backward pass as two Pallas kernels (dq; dk/dv) using the saved
   log-sum-exp, flash-2 style.
 
@@ -83,7 +88,9 @@ def _dummy_spec():
 # =============================================================== forward
 def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
                 sm_scale: float, causal: bool, block_k: int,
-                sq: int, sk: int, have_segs: bool):
+                sq: int, sk: int, have_segs: bool, q_len=None, kv_len=None):
+    """`q_len` / `kv_len`: this batch row's true lengths (traced scalars,
+    `_fwd_kernel_lens`), or None for the shapes' own."""
     qblk = pl.program_id(2)
     bq, d = q_ref.shape[2], q_ref.shape[3]
     q = q_ref[0, 0]  # [bq, d]
@@ -100,6 +107,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
             pl.cdiv((qblk + 1) * bq + offset, block_k), pl.cdiv(sk, block_k))
     else:
         num_kb = pl.cdiv(sk, block_k)
+    if q_len is not None:
+        # real blocks only: the key loop ends with the row's keys, and a
+        # query block of padding runs none of it, which leaves o = 0 and
+        # lse = NEG_INF, what a fully masked row gives (merge_attention
+        # then returns the other part)
+        num_kb = jnp.where(qblk * bq < q_len,
+                           jnp.minimum(num_kb, pl.cdiv(kv_len, block_k)), 0)
 
     def body(kb, carry):
         m, l, acc = carry
@@ -111,6 +125,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
         k_pos = kb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
         mask = k_pos < sk  # kv padding
+        if kv_len is not None:
+            mask = jnp.logical_and(mask, k_pos < kv_len)
         if causal:
             mask = jnp.logical_and(mask, k_pos <= q_pos + offset)
         if have_segs:
@@ -136,13 +152,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
     lse_ref[0, 0] = m + jnp.log(l_safe)  # [bq, 1]
 
 
+def _fwd_kernel_lens(lens_ref, *refs, **static):
+    """`_fwd_kernel` with the lengths of its batch row, from the
+    scalar-prefetched `lens_ref` ([2, B] int32: queries, keys)."""
+    b = pl.program_id(0)
+    _fwd_kernel(*refs, q_len=lens_ref[0, b], kv_len=lens_ref[1, b], **static)
+
+
 def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
-         interpret, sq, sk):
+         interpret, sq, sk, lens=None):
     """q: [B,Hq,Sq_p,D]; k/v: [B,Hkv,Sk_p,D] (padded to block multiples).
 
-    sq/sk are the TRUE lengths: the kernels mask kv padding with
-    `k_pos < sk` and compute the causal offset from true lengths.
-    Returns o [B,Hq,Sq_p,D], lse [B,Hq,Sq_p] (padded lengths).
+    sq/sk are the TRUE lengths of the operands: the kernels mask kv padding
+    with `k_pos < sk` and compute the causal offset from them. `lens`
+    ([2, B] int32, or None) is each batch row's own (queries, keys) within
+    them, as data. Returns o [B,Hq,Sq_p,D], lse [B,Hq,Sq_p] (padded
+    lengths).
     """
     b, hq, sq_p, d = q.shape
     _, hkv, sk_p, _ = k.shape
@@ -152,19 +177,23 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
     grid = (b, hq, sq_p // bq)
 
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, block_k=bk,
+        _fwd_kernel if lens is None else _fwd_kernel_lens,
+        sm_scale=sm_scale, causal=causal, block_k=bk,
         sq=sq, sk=sk, have_segs=have_segs)
 
+    # (`*_`: the scalar-prefetched lengths, where there are any)
     in_specs = [
-        pl.BlockSpec((1, 1, bq, d), lambda b_, h, i: (b_, h, i, 0)),
-        pl.BlockSpec((1, 1, sk_p, d), lambda b_, h, i: (b_, h // n_rep, 0, 0)),
-        pl.BlockSpec((1, 1, sk_p, d), lambda b_, h, i: (b_, h // n_rep, 0, 0)),
+        pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, *_: (b_, h, i, 0)),
+        pl.BlockSpec((1, 1, sk_p, d),
+                     lambda b_, h, i, *_: (b_, h // n_rep, 0, 0)),
+        pl.BlockSpec((1, 1, sk_p, d),
+                     lambda b_, h, i, *_: (b_, h // n_rep, 0, 0)),
     ]
     args = [q, k, v]
     if have_segs:
         in_specs += [
-            pl.BlockSpec((1, bq, 1), lambda b_, h, i: (b_, i, 0)),
-            pl.BlockSpec((1, sk_p, 1), lambda b_, h, i: (b_, 0, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b_, h, i, *_: (b_, i, 0)),
+            pl.BlockSpec((1, sk_p, 1), lambda b_, h, i, *_: (b_, 0, 0)),
         ]
         args += [q_seg, kv_seg]
     else:
@@ -176,8 +205,8 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
         jax.ShapeDtypeStruct((b, hq, sq_p, 1), jnp.float32),
     ]
     out_specs = [
-        pl.BlockSpec((1, 1, bq, d), lambda b_, h, i: (b_, h, i, 0)),
-        pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i: (b_, h, i, 0)),
+        pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, *_: (b_, h, i, 0)),
+        pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i, *_: (b_, h, i, 0)),
     ]
     compiler_params = dict(
         dimension_semantics=("parallel", "parallel", "parallel"))
@@ -191,14 +220,19 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
         resident = 2 * sk_p * (2 * d * k.dtype.itemsize + 128 * 4)
         if resident + _VMEM_HEADROOM > _VMEM_UNASKED:
             compiler_params["vmem_limit_bytes"] = resident + _VMEM_HEADROOM
+    if lens is None:
+        grid_spec = dict(grid=grid, in_specs=in_specs, out_specs=out_specs)
+    else:
+        grid_spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs))
+        args = [lens] + args
     o, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(**compiler_params),
         interpret=interpret,
+        **grid_spec,
     )(*args)
     return o, lse
 
@@ -422,6 +456,8 @@ def flash_attention(
     block_q: int = BLOCK, block_k: int = BLOCK,
     interpret: Optional[bool] = None,
     return_lse: bool = False,
+    q_lens: Optional[jax.Array] = None,
+    kv_lens: Optional[jax.Array] = None,
 ):
     """Flash attention. q: [B,Sq,Hq,D]; k/v: [B,Sk,Hkv,D] -> [B,Sq,Hq,D].
 
@@ -433,6 +469,18 @@ def flash_attention(
     for merging attention partials over disjoint kv sets (paged prefill
     with a cached prefix, ops/paged_attention.py). The lse path is
     forward-only (no custom VJP through the merge).
+
+    q_lens / kv_lens (int32 [B], traced values; with `return_lse` only):
+    how many of a batch row's queries, counted from the first, and of its
+    keys are real; one left None is the operand's whole length. Keys at or
+    past a row's `kv_lens` are masked for it, and the kernel's loops run
+    over real blocks only. A real query row's `o` and
+    `lse` are what the same mask as segment ids gives; a query row at or
+    past `q_lens` is padding and its `o` / `lse` mean nothing (0 /
+    NEG_INF where its whole block is padding). The causal relation stays
+    the operands' (`k_pos <= q_pos + Sk - Sq`): lengths only cut. Both
+    None: the kernel and the program text are what they are without the
+    operand.
     """
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
@@ -468,10 +516,20 @@ def flash_attention(
         q_seg = pad(q_seg.astype(jnp.int32), sq_p, 1)[..., None]
         kv_seg = pad(kv_seg.astype(jnp.int32), sk_p, 1)[..., None]
 
+    lens = None
+    if q_lens is not None or kv_lens is not None:
+        if not return_lse:
+            raise ValueError("q_lens / kv_lens are the forward-only path's "
+                             "(return_lse=True): the backward kernels take "
+                             "no lengths")
+        lens = jnp.stack([
+            jnp.full((b,), n, jnp.int32) if a is None
+            else jnp.asarray(a, jnp.int32) for a, n in
+            ((q_lens, sq), (kv_lens, sk))])
     if return_lse:
         # forward-only: bypass the custom_vjp (no bwd through the merge)
         o, lse = _fwd(qt, kt, vt, q_seg, kv_seg, causal, scale, bq, bk,
-                      interpret, sq, sk)
+                      interpret, sq, sk, lens)
         return (o[:, :, :sq, :].transpose(0, 2, 1, 3),
                 lse[:, :, :sq, 0].transpose(0, 2, 1))
     o = _flash(qt, kt, vt, q_seg, kv_seg, causal, scale, bq, bk, interpret,

@@ -47,8 +47,11 @@ owned end to end:
   pages).
 - ``paged_prefill_attention`` splits prefill into (1) causal flash
   attention among the new tokens themselves — no page reads at all — and
-  (2) segment-masked flash attention over the cached prefix pages, merged
-  by log-sum-exp. Rows without a cached prefix mask part (2) entirely.
+  (2) flash attention over the cached prefix pages, merged by log-sum-exp.
+  Both calls are given each row's true lengths (its real new tokens, its
+  prefix), so the kernel runs over real blocks only: a bucket's padding
+  and the block table's unused width are not computed, and a row without
+  a cached prefix runs none of part (2).
 - ``paged_attention_reference`` is the jnp gather path: the numerics
   oracle for kernel parity tests, the path a CPU backend runs, and the
   path tensor-parallel engines ask for by argument. A TPU backend never
@@ -473,8 +476,12 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
 
 
 # --------------------------------------------------------- prefill (+ctx)
-def _attn_lse(q, k, v, *, causal, segment_ids, scale, impl=None):
-    """Attention returning (o [B,S,Hq,D], lse [B,S,Hq]).
+def _attn_lse(q, k, v, *, causal, scale, q_lens=None, kv_lens=None,
+              impl=None):
+    """Attention returning (o [B,S,Hq,D], lse [B,S,Hq]). kv_lens [B]: the
+    keys a row has (the rest are masked); q_lens [B]: its real queries
+    (the flash kernel computes no block past them; the jnp path computes
+    every row, and a padded one means nothing either way).
 
     impl: None = flash kernel on a TPU backend, jnp reference on a CPU
     backend (`JAX_PLATFORMS=cpu`); "flash" forces the Pallas kernel
@@ -485,9 +492,9 @@ def _attn_lse(q, k, v, *, causal, segment_ids, scale, impl=None):
     if impl == "flash" or (impl is None and jax.default_backend() == "tpu"):
         from .flash_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=causal,
-                               segment_ids=segment_ids, scale=scale,
-                               return_lse=True)
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               return_lse=True, q_lens=q_lens,
+                               kv_lens=kv_lens)
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
     rep = hq // hkv
@@ -497,10 +504,9 @@ def _attn_lse(q, k, v, *, causal, segment_ids, scale, impl=None):
     if causal:
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
         logits = jnp.where(mask[None, None, None], logits, NEG_INF)
-    if segment_ids is not None:
-        q_seg, kv_seg = segment_ids
-        seg = q_seg[:, None, None, :, None] == kv_seg[:, None, None, None, :]
-        logits = jnp.where(seg, logits, NEG_INF)
+    if kv_lens is not None:
+        live = jnp.arange(sk)[None, :] < kv_lens[:, None]
+        logits = jnp.where(live[:, None, None, None, :], logits, NEG_INF)
     m = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.exp(logits - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -546,19 +552,42 @@ def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
     """
     d = q.shape[-1]
     scale_f = float(scale if scale is not None else d ** -0.5)
-    o1, lse1 = _attn_lse(q, k_new, v_new, causal=True, segment_ids=None,
-                         scale=scale_f, impl=impl)
+    ctx_len = positions[:, 0]                      # [B]
+    # the new tokens a row really has: the rest of the bucket is padding
+    # (its writes are dropped, its outputs thrown away), not computed
+    n_new = jnp.clip(total_lens - ctx_len, 0, q.shape[1])
+    o1, lse1 = _attn_lse(q, k_new, v_new, causal=True, scale=scale_f,
+                         q_lens=n_new, impl=impl)
     if ctx_pages <= 0:
         return o1
-    page = kv_pages.shape[-2]
     bt = block_tables[:, :ctx_pages]
     k_ctx, v_ctx = gather_kv(kv_pages, bt, layer)  # [B, CP*page, Hkv, D]
-    b, sq = q.shape[:2]
-    ctx_len = positions[:, 0]                      # [B]
-    kv_pos = jnp.arange(ctx_pages * page)
-    kv_seg = (kv_pos[None, :] < ctx_len[:, None]).astype(jnp.int32)
-    q_seg = jnp.ones((b, sq), jnp.int32)
-    o2, lse2 = _attn_lse(q, k_ctx, v_ctx, causal=False,
-                         segment_ids=(q_seg, kv_seg), scale=scale_f,
-                         impl=impl)
+    # the prefix is a LENGTH of the gathered columns: a row attends the
+    # context it has, not the table's width
+    o2, lse2 = _attn_lse(q, k_ctx, v_ctx, causal=False, scale=scale_f,
+                         q_lens=n_new, kv_lens=ctx_len, impl=impl)
     return merge_attention(o1, lse1, o2, lse2)
+
+
+def prefill_block_visits(s: int, ctx_width: int, n_new=None,
+                         ctx_len=None) -> Tuple[int, int]:
+    """What `paged_prefill_attention`'s two flash calls visit for one row,
+    a layer and head, in plain integers (the engine's counters and its
+    pass cost, on the host): ((query block, key block) visits, the (query,
+    key) pairs in them) of `[s]` new tokens, `n_new` of them real, behind
+    `ctx_len` of `ctx_width` gathered columns: the forward kernel's trip
+    counts summed over its query blocks. Lengths None: what the shapes
+    alone would make. `ctx_width` 0: the program without a context part."""
+    from .flash_attention import BLOCK, _pick_blocks
+
+    bq, bk = _pick_blocks(s, ctx_width or s, BLOCK, BLOCK)
+    n_qb = -(-(s if n_new is None else min(s, n_new)) // bq)
+    # the causal call among the new tokens, in blocks of bq x bq: query
+    # block i runs to the diagonal
+    visits = n_qb * (n_qb + 1) // 2
+    pairs = visits * bq * bq
+    if ctx_width:
+        ctx = n_qb * -(-(ctx_width if ctx_len is None
+                         else min(ctx_width, ctx_len)) // bk)
+        visits, pairs = visits + ctx, pairs + ctx * bq * bk
+    return visits, pairs

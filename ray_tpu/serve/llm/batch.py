@@ -56,6 +56,12 @@ def _get_engine(config: EngineConfig) -> LLMEngine:
     return engine
 
 
+def _drop_engine(engine: LLMEngine) -> None:
+    for key in [k for k, e in _ENGINE_CACHE.items() if e is engine]:
+        del _ENGINE_CACHE[key]
+    engine.close()
+
+
 @dataclasses.dataclass
 class ProcessorConfig:
     """ref: llm/_internal/batch/processor/vllm_engine_proc.py
@@ -150,13 +156,20 @@ class Processor:
                                          else None))
         collected: Dict[str, List[int]] = {rid: [] for rid in by_id}
         finish: Dict[str, str] = {}
-        while engine.has_work():
-            for delta in engine.step():
-                if delta.request_id in collected:
-                    collected[delta.request_id].extend(
-                        delta.new_token_ids)
-                    if delta.finished:
-                        finish[delta.request_id] = delta.finish_reason
+        try:
+            while engine.has_work():
+                for delta in engine.step():
+                    if delta.request_id in collected:
+                        collected[delta.request_id].extend(
+                            delta.new_token_ids)
+                        if delta.finished:
+                            finish[delta.request_id] = delta.finish_reason
+        except BaseException:
+            # a batch that ends early leaves its requests, and dispatches
+            # in flight, in the cached engine: the next batch builds its
+            # own, and this one is left with nothing queued on the device
+            _drop_engine(engine)
+            raise
         tok = get_tokenizer(self.config.tokenizer)
         # per-batch expiry count rides the rows (the engine stage runs in
         # a map_batches worker — driver-side Processor state never sees

@@ -125,9 +125,12 @@ still raises past its largest bucket.
 
 from __future__ import annotations
 
+import atexit
 import dataclasses
+import functools
 import threading
 import time
+import weakref
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -350,54 +353,80 @@ def _bucket(n: int, buckets) -> int:
 # parameter and token) take as long as reading its weights (2 bytes a
 # parameter): a pass of fewer tokens costs the weight read all the same.
 _BALANCE_TOKENS = 240
+# What a (query, key) pair that the flash forward VISITS costs, a layer and
+# head, in parameters a token multiplies: its 4 x head_dim operations at the
+# share of the matmul peak the kernel reaches on the blocks it runs. Timed
+# (benchmarks/prefill_split_probe.py, PERF.md section 6, PR 39): 2.1-2.6e-6
+# ms a pair over Mistral's 16 layers x 32 heads against 0.047 ms a token
+# over its 3.76e9 parameters.
+_PAIR_PARAMS = 375
 # where an `engine.dispatch` record's device stamps begin (_harvest)
 _STAMPS_AT = tracing.FIELDS["engine.dispatch"].index("enqueued_ns")
 
 
+def _attn_visits(bucket: int, width: int, real=None, ctx=None) -> tuple:
+    """`ops.paged_attention.prefill_block_visits`: what a pass's flash
+    calls visit (imported late: this module loads without JAX)."""
+    from ...ops.paged_attention import prefill_block_visits
+
+    return prefill_block_visits(bucket, width, real, ctx)
+
+
 @dataclasses.dataclass(frozen=True)
 class PassCost:
-    """What one prefill pass of `bucket` tokens costs, in tokens' worth of
-    the model's products (benchmarks/prefill_split_probe.py times every
-    term on the chip; PERF.md section 6, PR 36):
+    """What one prefill pass of `bucket` tokens, `real` of them a prompt's
+    and the rest padding, behind `ctx` tokens of context costs, in tokens'
+    worth of the model's products (benchmarks/prefill_split_probe.py times
+    every term on the chip; PERF.md section 6, PR 39):
 
-    - `max(bucket, floor)`: its products, or the weight read under them.
-      `floor` is the balance x the weights a pass reads over the
-      parameters a token multiplies (1 for a dense model);
-    - its own causal attention, `bucket ** 2 / 2` (query, key) pairs in the
-      flash kernel, at half of `pair` each;
-    - if it RESUMES (it starts mid-prompt): one `floor` more, the bar a
-      split has to clear (the dispatch, a second weight read), and a
-      context part that attends all `width` columns of the block table
-      whatever the context, `bucket * width` pairs at `pair` each. `pair`
-      is the float32 scores a pair makes (one a layer and head, 8 bytes
-      written and read: 4 x the balance in parameters) over the
-      parameters a token multiplies."""
+    - `max(bucket, floor)`: its products, padding included, or the weight
+      read under them. `floor` is the balance x the weights a pass reads
+      over the parameters a token multiplies (1 for a dense model);
+    - its attention, `pair` a (query, key) pair the flash kernel VISITS
+      (ops/flash_attention.py), and that is real blocks only: the blocks of
+      `real` queries against their own causal keys and against `ctx`
+      columns of context, whatever the bucket pads and the block table's
+      width. `pair` is the kernel's products a pair (one a layer and head)
+      at the share of the matmul peak it reaches, over the parameters a
+      token multiplies;
+    - if it RESUMES (`ctx` > 0: it starts mid-prompt or behind a cached
+      prefix): one `floor` more, the bar a split has to clear (the
+      dispatch, a second weight read, the gather of the table's width)."""
     floor: float
     pair: float
-    width: int
 
-    def __call__(self, bucket: int, resumed: bool) -> float:
-        cost = max(bucket, self.floor) + self.pair * bucket * bucket / 4
-        if resumed:
-            cost += self.floor + self.pair * bucket * self.width
-        return cost
+    def __call__(self, bucket: int, real: int, ctx: int) -> float:
+        # (any table width that holds the context: the lengths cut the rest)
+        pairs = _attn_visits(bucket, ctx, real, ctx)[1]
+        cost = max(bucket, self.floor) + self.pair * pairs
+        return cost + self.floor if ctx else cost
 
 
 def plan_passes(n_new: int, buckets, page: int, cost: PassCost,
-                resumed: bool = False) -> List[int]:
+                ctx: int = 0) -> List[int]:
     """The length bucket of each prefill pass for `n_new` prompt tokens
-    (`resumed`: behind a cached prefix, so that the first pass resumes
-    too): every pass but the last is FULL (it prefills exactly its bucket,
-    so where it ends is page-aligned: the context-merge path's contract),
-    the last is the smallest bucket that holds the rest.
+    behind `ctx` tokens already in pages (a cached prefix: the first pass
+    resumes too): every pass but the last is FULL (it prefills exactly its
+    bucket, so where it ends is page-aligned: the context-merge path's
+    contract), the last is the smallest bucket that holds the rest.
 
     While the rest exceeds the largest bucket it takes passes of the
     largest. Of the ways to cover what is then left, the one `cost` says
     is cheapest, the single bucket where nothing is cheaper: one bucket
     is left for two only where that computes enough less padding to pay
-    for a pass more and its context part. Never more tokens than the
+    for a pass more and the context it attends. Never more tokens than the
     single bucket."""
+    return list(_plan(n_new, tuple(buckets), page, cost, ctx))
+
+
+@functools.lru_cache(maxsize=4096)
+def _plan(n_new: int, buckets: tuple, page: int, cost: PassCost,
+          ctx: int) -> tuple:
+    """`plan_passes`, kept for the lengths a deployment sees again: the
+    search asks `cost` some fifty times for a long prompt over five
+    buckets, 0.2-0.5 ms of an admission's host time."""
     largest = buckets[-1]
+    end = ctx + n_new
     lead = []
     while n_new > largest:
         lead.append(largest)
@@ -405,24 +434,45 @@ def plan_passes(n_new: int, buckets, page: int, cost: PassCost,
     full = [b for b in buckets if b % page == 0]
     memo: Dict[tuple, tuple] = {}
 
-    def cheapest(rest: int, top: int, resumed: bool) -> tuple:
-        """(cost, buckets) for `rest` tokens, full passes from
-        `full[:top]`, largest first."""
-        got = memo.get((rest, top, resumed))
+    def cheapest(rest: int, top: int) -> tuple:
+        """(cost, buckets) for the last `rest` tokens (so behind `end -
+        rest` of context), full passes from `full[:top]`, largest first."""
+        got = memo.get((rest, top))
         if got is None:
             last = _bucket(rest, buckets)
-            got = (cost(last, resumed), (last,))
+            got = (cost(last, rest, end - rest), (last,))
             for i in reversed(range(top)):
                 if full[i] >= rest:
                     continue
-                c, tail = cheapest(rest - full[i], i + 1, True)
-                c += cost(full[i], resumed)
-                if c < got[0] and full[i] + sum(tail) <= last:
+                c, tail = cheapest(rest - full[i], i + 1)
+                c += cost(full[i], full[i], end - rest)
+                # (cheaper by more than rounding: of two orders of the
+                # same passes the larger bucket goes first)
+                if c + 1e-6 < got[0] and full[i] + sum(tail) <= last:
                     got = (c, (full[i],) + tail)
-            memo[(rest, top, resumed)] = got
+            memo[(rest, top)] = got
         return got
 
-    return lead + list(cheapest(n_new, len(full), resumed or bool(lead))[1])
+    return tuple(lead) + cheapest(n_new, len(full))[1]
+
+
+def _settle(inflight: List[dict], wait) -> None:
+    """What is left of `LLMEngine.close` when the engine itself is gone:
+    `wait` for the tokens of every dispatch in `inflight`, oldest first,
+    and forget them. A program returns its tokens and the donated pool
+    together, so when the last tokens are on the host nothing is queued on
+    the device and no copy to the host is pending. A process that ends
+    otherwise can die in the TPU client's teardown (SIGSEGV in
+    `xla::TpuClient::pending_event_logger()` under
+    `TpuRawBuffer::CopyToLiteralAsync()`: a program ends and its copy
+    starts on a client that is going; after the result line, in 2 of 17
+    benchmark runs of PR 37's tree and 1 of 26 of PR 35's: PERF.md section
+    6, PR 39)."""
+    while inflight:
+        try:
+            wait(inflight.pop(0)["toks"])
+        except Exception:  # noqa: BLE001  # rtpulint: ignore[RTPU006] — the engine is being left: a handle that cannot be fetched has no copy pending
+            pass
 
 
 class LLMEngine:
@@ -465,6 +515,8 @@ class LLMEngine:
         config = self.config
         self._intake: List[Request] = []
         self._intake_lock = threading.Lock()
+        # a step from its first line to its last, against `close`
+        self._step_lock = threading.Lock()
         self._aborted: set = set()
         self._injections: List[tuple] = []
         self.extracted: Dict[str, Dict[str, Any]] = {}
@@ -520,8 +572,9 @@ class LLMEngine:
             "decode_dispatches_total", "prefill_tokens_total",
             "prefill_padded_tokens_total", "decode_rows_total",
             "decode_ctx_tokens_total", "prefill_passes_total",
-            "prefill_resumed_passes_total", "prefill_split_prompts_total"),
-            0)
+            "prefill_resumed_passes_total", "prefill_split_prompts_total",
+            "prefill_attn_blocks_total",
+            "prefill_attn_blocks_skipped_total"), 0)
         # (layers, experts) of an expert model, whose programs return
         # routing counts packed behind their tokens; None for a dense one
         cfg_m = self.model_cfg
@@ -550,8 +603,7 @@ class LLMEngine:
             weights, scores = family.pass_cost_ratios(cfg_m)
             self._pass_cost = PassCost(
                 floor=_BALANCE_TOKENS * weights,
-                pair=4 * _BALANCE_TOKENS * scores,
-                width=self.max_pages_per_seq * self.config.page_size)
+                pair=_PAIR_PARAMS * scores)
         self._head_at_gather = getattr(family, "HEAD_AT_GATHER", False)
         # the fields of its `engine.dispatch` records behind `k`, in
         # `tracing.FIELDS`' order, None where the model has none: the
@@ -589,6 +641,14 @@ class LLMEngine:
         self._device_busy_ns_total = 0
         self._device_idle_ns_total = 0
         self._harvests_late_total = 0
+        # left without being asked (dropped, or the interpreter exits):
+        # `close`'s drain all the same. Registered with atexit HERE, after
+        # JAX's own handlers (the constructor has built the programs), so
+        # that it runs before them, while the client is whole
+        self._closed = False
+        self._leave = weakref.finalize(self, _settle, self._inflight,
+                                       self._await_handle)
+        atexit.register(self._leave)
 
     # ----------------------------------------------------------- intake
 
@@ -642,6 +702,23 @@ class LLMEngine:
         return bool(self.waiting or self.running or self._inflight
                     or self._pending_deltas)
 
+    def close(self) -> None:
+        """Leave the engine: no step dispatches any more (a running one
+        ends first), the tokens of every dispatch in flight are fetched,
+        so that no copy to the host is pending, and the pool is ready:
+        nothing of this engine is queued on the device when the process
+        goes on to exit (`_settle`). Open requests are not finished and
+        get no delta: whoever closes has stopped listening. Idempotent;
+        an engine that is dropped, or alive when the interpreter exits,
+        drains the same way without being asked."""
+        with self._step_lock:
+            self._closed = True
+            self._leave()
+            if self.compute is not None:
+                import jax
+
+                jax.block_until_ready(self.kv_pages)
+
     # ------------------------------------------------------------- step
 
     def step(self) -> List[OutputDelta]:
@@ -666,6 +743,13 @@ class LLMEngine:
         end and the nanoseconds of each phase (admit, dispatch_prefill,
         fetch and harvest are timed where they happen, further down).
         """
+        with self._step_lock:
+            if self._closed:
+                raise RuntimeError("step() on a closed engine")
+            return self._step()
+
+    def _step(self) -> List[OutputDelta]:
+        """`step`, under its lock."""
         self._step_seq += 1
         self._totals["steps_total"] += 1
         phase = self._phase_ns
@@ -948,6 +1032,9 @@ class LLMEngine:
         async D2H copy lands; microseconds once it has)."""
         return np.asarray(handle)
 
+    # the same wait with no engine left to take the tokens (`_settle`)
+    _await_handle = staticmethod(np.asarray)
+
     @staticmethod
     def _handle_ready(handle) -> Optional[bool]:
         """Whether the program behind a compute handle has finished,
@@ -981,7 +1068,7 @@ class LLMEngine:
         for req in admitted:
             n_new = len(req.prompt_ids) - req.n_prefilled
             plan = (plan_passes(n_new, buckets, self.config.page_size,
-                                self._pass_cost, req.n_prefilled > 0)
+                                self._pass_cost, req.n_prefilled)
                     if self._resumes else [_bucket(n_new, buckets)])
             self._totals["prefill_split_prompts_total"] += (
                 len(plan) > 1 and n_new <= buckets[-1])
@@ -1103,6 +1190,16 @@ class LLMEngine:
             rows = []
             facts = []
             passes = []
+            cp = (self.max_pages_per_seq
+                  if any(req.n_prefilled for req, _ in group) else 0)
+            # the passes whose attention is the flash kernel's: not a
+            # block-sparse family's past its dense length or over pages
+            flash = self._sparse is None or (
+                cp == 0 and sb <= self._sparse.dense_len)
+            # (query block, key block) visits a row of the program's
+            # shapes would make; what a row's lengths cut is counted below
+            width = cp * self.config.page_size
+            shapes = _attn_visits(sb, width)[0]
             for i, (req, n_new) in enumerate(group):
                 start = req.n_prefilled
                 if slots is not None:
@@ -1122,13 +1219,15 @@ class LLMEngine:
                 passes.append((req.n_passes, final))
                 req.n_passes += 1
                 self._totals["prefill_resumed_passes_total"] += start > 0
+                if flash:
+                    self._totals["prefill_attn_blocks_total"] += shapes
+                    self._totals["prefill_attn_blocks_skipped_total"] += (
+                        shapes - _attn_visits(sb, width, n_new, start)[0])
             now = time.monotonic()
             for req, _ in group:
                 if req.dispatched_t is None:
                     req.dispatched_t = now
                     req.dispatched_ns = r.start_ns
-            cp = (self.max_pages_per_seq
-                  if any(req.n_prefilled for req, _ in group) else 0)
             temp, topk, keys = self._sampling_arrays(
                 [req for req, _ in group], rb)
             tokens = self._compute_prefill(

@@ -351,6 +351,10 @@ class PipelinedEngine(LLMEngine):
         cannot say when they ended, and stamps no device timeline."""
         return None
 
+    # (a frame's programs and copies are its stage workers': nothing of
+    # this process is pending when the engine is left)
+    _await_handle = staticmethod(lambda handle: None)
+
     def _fetch_tokens(self, handle) -> np.ndarray:
         if isinstance(handle, np.ndarray):
             return handle

@@ -115,6 +115,14 @@ _LLM_WORK_TOTALS = {
     "prefill_split_prompts_total":
         "prompts that fitted one length bucket and were prefilled in more "
         "than one pass, because that computed less padding",
+    "prefill_attn_blocks_total":
+        "(query block, key block) visits the prefill passes' flash calls "
+        "would make by their shapes (bucket x bucket, bucket x block "
+        "table's width), a layer and head",
+    "prefill_attn_blocks_skipped_total":
+        "of prefill_attn_blocks_total, the visits the rows' true lengths "
+        "cut: a bucket's padded query blocks, the table's width past a "
+        "row's context",
     "lightning_prefill_tokens_total":
         "real prompt tokens x linear-attention layers (prefill)",
     "lightning_state_updates_total":
@@ -222,6 +230,16 @@ class EngineDriverMixin:
         if self._driver_task is None or self._driver_task.done():
             self._driver_task = asyncio.get_running_loop().create_task(
                 self._drive())
+
+    async def shutdown(self) -> None:
+        """The replica is being stopped (serve/replica.py calls this once
+        its requests have drained, before the worker is killed): stop the
+        driver and leave the engine with nothing queued on the device
+        (`LLMEngine.close`, which waits for a step that is running)."""
+        if self._driver_task is not None and not self._driver_task.done():
+            self._driver_task.cancel()
+        await asyncio.get_running_loop().run_in_executor(
+            None, self.engine.close)
 
     async def _drive(self):
         loop = asyncio.get_running_loop()

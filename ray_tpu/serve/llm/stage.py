@@ -88,8 +88,8 @@ def model_family(name: str):
     `RESUMES_PREFILL` (a prefill row continues from what its pages and
     its slot hold, so a prompt may be prefilled in passes; such a family
     also answers `pass_cost_ratios(cfg)`: the weights a pass reads and
-    the scores its context part makes, over the parameters a token
-    multiplies) and, where set,
+    the scores a (query, key) pair of its attention makes, over the
+    parameters a token multiplies) and, where set,
     `HEAD_AT_GATHER` (`serving_cache` takes the position each row samples
     from and the model computes the head there only). Its config answers
     `n_slot_state_layers` / `slot_state_bytes_row()` where layers keep

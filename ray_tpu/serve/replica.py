@@ -205,3 +205,10 @@ class ReplicaActor:
         deadline = time.time() + self._config.graceful_shutdown_timeout_s
         while self._ongoing > 0 and time.time() < deadline:
             await asyncio.sleep(0.02)
+        # then the callable's own leave-taking, where it has one (an LLM
+        # server leaves its engine with nothing queued on the device)
+        fn = getattr(self._user_callable, "shutdown", None)
+        if fn is not None:
+            out = fn()
+            if inspect.isawaitable(out):
+                await out

@@ -87,6 +87,25 @@ def _flash(fn, shape):
         topo.devices[0])))
 
 
+def _ctx_lens(sq, sk, hq=32, hkv=8, d=128):
+    """The context call of a resumed prefill pass as
+    `paged_prefill_attention` makes it: `[1 x sq]` queries over `sk`
+    gathered columns, the row's real lengths as data, no segment ids."""
+    def build(topo):
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def fn(q, k, v, q_lens, kv_lens):
+            return flash_attention(q, k, v, causal=False, interpret=False,
+                                   return_lse=True, q_lens=q_lens,
+                                   kv_lens=kv_lens)
+        return fn, tuple(
+            jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+            for shape, dt in [((1, sq, hq, d), BF16), ((1, sk, hkv, d), BF16),
+                              ((1, sk, hkv, d), BF16), ((1,), jnp.int32),
+                              ((1,), jnp.int32)])
+    return build
+
+
 def _paged_decode(b, d, mp, hq=32, hkv=8, page=16, layers=4,
                   pages_per_chunk=None):
     """The compiled kernel as the engine calls it: `pages_per_chunk=None`
@@ -156,6 +175,9 @@ COMPILES = {
     "fwd-d128-4k": _flash(_fwd, (1, 4096, 32, 8, 128)),
     "fwdbwd-d128-4k": _flash(_grads(_fwd), (1, 4096, 32, 8, 128)),
     "fwd-lse-prefill-bucket": _flash(_lse, (4, 512, 32, 8, 64)),
+    # a resumed pass's context part at the two Mistral cells' widths
+    "fwd-lse-lens-ctx-kv2688": _ctx_lens(512, 2688),
+    "fwd-lse-lens-ctx-kv8320": _ctx_lens(2048, 8320),
     "decode-llama1b-B8-D64-MP32": _paged_decode(8, 64, 32),
     "decode-8b-B8-D128-MP512": _paged_decode(8, 128, 512),
     "decode-7b-B32-D128-MP168": _paged_decode(32, 128, 168),
@@ -191,6 +213,11 @@ def test_kernel_compiles_for_v5e(topo, no_persistent_cache, name):
             lowered.compile()
     else:
         assert "tpu_custom_call" in lowered.compile().as_text()
+    if "-lens-" in name:
+        # K and V resident and nothing beside them: inside the VMEM a
+        # kernel gets unasked, where the segment ids it replaces were not
+        # (a raised `vmem_limit_bytes` lowers to `scoped_memory_configs`)
+        assert "scoped_memory_configs" not in lowered.as_text()
 
 
 # ------------------------------------------- the engine's own programs

@@ -6,12 +6,14 @@ clock, and that /metrics and stats() say what the records say.
 CPU, tiny engine; no TPU library is loaded at import.
 """
 
+import gc
 import json
+import os
 
 import numpy as np
 import pytest
 
-from benchmarks.recorder_cost import StubEngine
+from benchmarks.recorder_cost import Handle, StubEngine
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
 from ray_tpu.serve.llm.engine import PassCost
 from ray_tpu.util import tracing
@@ -483,7 +485,7 @@ def _stub(cls=StubEngine, ready=False, **over):
     at which the tiny buckets split (70 tokens = 64 + 16)."""
     engine = cls(EngineConfig(**{**STUB_CFG, **over}))
     engine.ready = ready
-    engine._pass_cost = PassCost(4, 0.0, 0)
+    engine._pass_cost = PassCost(4, 0.0)
     return engine
 
 
@@ -636,6 +638,203 @@ def test_an_engine_whose_handles_cannot_say_stamps_nothing():
     assert engine.stats()["device_busy_s_total"] == 0
     assert not [e for e in tracing.chrome_trace([])
                 if e["cat"] == "rtpu.device"]
+
+
+# ------------------------------------------- an engine that is left
+class _Pending(Handle):
+    """A handle whose copy to the host says when it was waited for, as
+    `np.asarray` of a device array waits."""
+
+    __slots__ = ()
+    fetched: list = []
+    broken: set = set()
+
+    def __array__(self, dtype=None, copy=None):
+        _Pending.fetched.append(self)
+        if len(_Pending.fetched) in _Pending.broken:
+            raise RuntimeError("the buffer was deleted")
+        return self.tokens
+
+
+class _Left(StubEngine):
+    """The stub with the real engine's fetch (`np.asarray`)."""
+
+    _fetch_tokens = LLMEngine._fetch_tokens
+
+    def _compute_prefill(self, sb, rb, *_):
+        return _Pending(np.ones((rb,), np.int32), self.ready)
+
+    def _compute_decode(self, k_steps, *_):
+        return _Pending(np.ones((k_steps, self.config.max_batch), np.int32),
+                        self.ready)
+
+
+def _three_in_flight():
+    """A prompt of two passes and two of one, three steps on: three
+    dispatches in flight, a prefill pass and two decode programs behind
+    it."""
+    _Pending.fetched, _Pending.broken = [], set()
+    engine = _stub(_Left, pipeline_depth=4)
+    for rid, n in (("a", 70), ("b", 20), ("c", 40)):
+        engine.add_request(rid, [1] * n, SamplingParams(max_tokens=6))
+    for _ in range(3):
+        engine.step()
+    handles = [rec["toks"] for rec in engine._inflight]
+    assert len(handles) == 3, [rec["kind"] for rec in engine._inflight]
+    _Pending.fetched = []
+    return engine, handles
+
+
+def _drop(engine):
+    del engine
+    gc.collect()
+
+
+def _close_twice(engine):
+    engine.close()
+    engine.close()
+
+
+def _close_then_drop(engine):
+    engine.close()
+    _drop(engine)
+
+
+def _abort_then_close(engine):
+    engine.abort("a")
+    engine.abort("b")
+    engine.close()
+
+
+@pytest.mark.parametrize("leave", [
+    _drop, LLMEngine.close, _close_twice, _close_then_drop,
+    _abort_then_close, lambda engine: engine._leave()],
+    ids=["dropped", "closed", "closed twice", "closed then dropped",
+         "aborted then closed", "the exit hook"])
+def test_an_engine_that_is_left_fetches_every_dispatch_in_flight(leave):
+    """However it is left, each handle in flight is waited for once,
+    oldest first, and nothing is left pending."""
+    engine, handles = _three_in_flight()
+    inflight = engine._inflight
+    leave(engine)
+    del engine
+    assert _Pending.fetched == handles and not inflight
+
+
+def test_a_closed_engine_dispatches_nothing_more():
+    engine, _ = _three_in_flight()
+    engine.close()
+    assert engine.stats()["inflight"] == 0
+    with pytest.raises(RuntimeError, match="closed engine"):
+        engine.step()
+    assert not _Pending.fetched[3:]
+
+
+def test_a_handle_that_cannot_be_fetched_does_not_stop_the_drain():
+    engine, handles = _three_in_flight()
+    _Pending.broken = {2}
+    engine.close()                      # silent
+    assert _Pending.fetched == handles
+
+
+def test_close_waits_for_the_step_that_is_running():
+    """`close` from another thread (a server's shutdown while the driver's
+    executor is inside `step`) takes its turn behind the step."""
+    import threading
+
+    engine, handles = _three_in_flight()
+    in_step, go = threading.Event(), threading.Event()
+    fetch = engine._fetch_tokens
+
+    def slow_fetch(handle):
+        in_step.set()
+        assert go.wait(10)
+        return fetch(handle)
+
+    engine._fetch_tokens = slow_fetch
+    stepper = threading.Thread(target=engine.step)
+    stepper.start()
+    assert in_step.wait(10)
+    closer = threading.Thread(target=engine.close)
+    closer.start()
+    closer.join(0.2)
+    assert closer.is_alive() and not _Pending.fetched
+    go.set()
+    stepper.join(10)
+    closer.join(10)
+    assert not closer.is_alive() and not engine._inflight
+    assert _Pending.fetched[0] is handles[0]
+
+
+def test_a_servers_shutdown_closes_its_engine():
+    import asyncio
+
+    from ray_tpu.serve.llm.server import EngineDriverMixin
+
+    engine, handles = _three_in_flight()
+    driver = EngineDriverMixin()
+    driver.engine = engine
+    driver._init_driver()
+    asyncio.run(driver.shutdown())
+    assert _Pending.fetched == handles and engine._closed
+
+
+def test_a_replica_that_is_stopped_asks_its_callable_to_leave():
+    import asyncio
+
+    from ray_tpu.serve.replica import ReplicaActor
+
+    class Hosted:
+        left = 0
+
+        async def shutdown(self):
+            Hosted.left += 1
+
+    replica = ReplicaActor.__new__(ReplicaActor)
+    replica._config = type("C", (), {"graceful_shutdown_timeout_s": 1.0})
+    replica._ongoing, replica._user_callable = 0, Hosted()
+    asyncio.run(replica.prepare_for_shutdown())
+    replica._user_callable = object()           # no hook: nothing to ask
+    asyncio.run(replica.prepare_for_shutdown())
+    assert Hosted.left == 1
+
+
+def test_a_batch_that_ends_early_leaves_its_engine():
+    from ray_tpu.serve.llm import batch
+
+    engine, handles = _three_in_flight()
+    batch._ENGINE_CACHE["left"] = engine
+    batch._drop_engine(engine)
+    assert "left" not in batch._ENGINE_CACHE
+    assert _Pending.fetched == handles and engine._closed
+
+
+def test_the_drain_at_exit_runs_before_handlers_registered_earlier():
+    """The interpreter's exit with dispatches in flight: the engine's hook
+    was registered after JAX's own (the constructor imports JAX), so it
+    runs before them. A handler registered before the engine was built
+    stands for JAX's."""
+    import subprocess
+    import sys
+
+    code = r'''
+import atexit, sys
+sys.path.insert(0, "tests"); sys.path.insert(0, ".")
+import jax
+atexit.register(lambda: print("an earlier handler", flush=True))
+import test_engine_tracing as t
+engine, handles = t._three_in_flight()
+t._Pending.__array__ = lambda self, dtype=None, copy=None: (
+    print("fetched", handles.index(self), flush=True), self.tokens)[1]
+'''
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, cwd=os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))),
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split("\n")[:4] == [
+        "fetched 0", "fetched 1", "fetched 2", "an earlier handler"]
 
 
 def test_trainer_step_leaves_one_record_a_call():

@@ -19,6 +19,7 @@ from ray_tpu.ops import sparse_attention as sa
 from ray_tpu.ops.paged_attention import (paged_attention_decode,
                                          paged_write)
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.serve.llm.engine import PassCost
 from ray_tpu.serve.llm.stage import init_params, model_family
 from ray_tpu.util import tracing
 
@@ -264,6 +265,9 @@ def test_a_prompt_past_the_largest_bucket_is_served_in_passes(preset, over):
         whole = LLMEngine(_engine_config(
             model=preset, **{**over, "prefill_buckets": (64, 160),
                              "prefill_chunk_tokens": 0}), params=weights)
+        # whole whatever the plan: a tiny model's attention is dear beside
+        # its products, and 100 tokens would go as 64 + 64
+        whole._pass_cost = PassCost(float("inf"), 0.0)
         return whole, LLMEngine(_engine_config(model=preset, **over),
                                 params=whole.params)
 

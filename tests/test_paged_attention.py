@@ -297,10 +297,93 @@ def test_merge_attention_equals_joint_softmax():
     from ray_tpu.ops.paged_attention import _attn_lse
 
     o1, l1 = _attn_lse(q, k[:, :6], v[:, :6], causal=False,
-                       segment_ids=None, scale=d ** -0.5, impl="flash")
+                       scale=d ** -0.5, impl="flash")
     o2, l2 = _attn_lse(q, k[:, 6:], v[:, 6:], causal=False,
-                       segment_ids=None, scale=d ** -0.5, impl="flash")
+                       scale=d ** -0.5, impl="flash")
     got = merge_attention(o1, l1, o2, l2)
     want = reference_attention(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", LAYERS)
+@pytest.mark.parametrize("impl", [None, "flash"])
+@pytest.mark.parametrize("ctx_lens, n_new", [
+    ((0, 0), (5, 12)), ((8, 0), (12, 1)), ((16, 40), (7, 3)),
+    ((48, 24), (12, 12))])
+def test_prefill_of_a_padded_bucket_attends_real_lengths(ctx_lens, n_new,
+                                                         impl, layer):
+    """Rows of one wave with different prefixes (none, a page, the table's
+    whole width less the bucket) and different real tokens in a bucket of
+    12: every REAL token attends prefix + itself as the one-shot gather
+    path has it, whatever the padded positions' keys and values hold (here
+    1e4: finite, as a padded token's projections are)."""
+    rng = np.random.default_rng(sum(ctx_lens) + sum(n_new))
+    b, hq, hkv, d, page, mp, s = 2, 4, 2, 16, 8, 8, 12
+    lengths = [c + n for c, n in zip(ctx_lens, n_new)]
+    kv_pages, bt, k_dense, v_dense, lens = _make_pages(
+        rng, b=b, hkv=hkv, d=d, page=page, num_pages=32, mp=mp,
+        lengths=lengths, layer=layer)
+    positions = jnp.stack([jnp.arange(c, c + s) for c in ctx_lens])
+    q = jnp.asarray(rng.standard_normal((b, s, hq, d)), jnp.float32)
+    real = jnp.arange(s)[None, :, None, None] < jnp.asarray(
+        n_new)[:, None, None, None]
+    k_new, v_new = (jnp.where(real, jnp.stack(
+        [x[i, c:c + s] for i, c in enumerate(ctx_lens)]), 1e4)
+        for x in (k_dense, v_dense))
+    got = paged_prefill_attention(q, k_new, v_new, kv_pages, bt, positions,
+                                  lens, ctx_pages=mp, impl=impl, layer=layer)
+    want = paged_attention_reference(q, kv_pages, bt, positions, layer=layer)
+    for row, n in enumerate(n_new):
+        np.testing.assert_allclose(np.asarray(got[row, :n]),
+                                   np.asarray(want[row, :n]),
+                                   rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("s, n_new, ctx, width, want", [
+    # the docbatch cycle's passes, in blocks of 512 (ISSUE 39): a fresh
+    # 4096 bucket of 2084 tokens, a resumed 2048 pass of 8 and of 1842
+    # tokens behind 4096 of 8320 columns, a full fresh 8192 pass
+    (4096, 2084, 0, 0, (36, 15, 15 << 18)),
+    (2048, 8, 4096, 8320, (10 + 68, 1 + 8, 9 << 18)),
+    (2048, 1842, 4096, 8320, (78, 10 + 32, 42 << 18)),
+    (8192, 7797, 0, 0, (136, 136, 136 << 18)),
+    # chat: 1326 tokens' resumed 512 pass, 1120's resumed 128 pass (its
+    # query block is 128 rows), a fresh row in a wave's context program
+    (512, 302, 1024, 2688, (1 + 6, 1 + 2, 3 << 18)),
+    (128, 96, 1024, 2688, (7, 3, 128 * 128 + 2 * 128 * 512)),
+    (512, 400, 0, 2688, (7, 1, 1 << 18)),
+    # buckets off the block: 640 tokens are two query blocks of 512 rows,
+    # 1000 columns two key blocks; nothing real at all
+    (640, 513, 700, 1000, (3 + 4, 3 + 4, 7 << 18)),
+    (640, 100, 512, 1000, (7, 1 + 1, 2 << 18)), (128, 0, 0, 0, (1, 0, 0))])
+def test_prefill_block_visits_of_the_benchmarks_passes(s, n_new, ctx, width,
+                                                       want):
+    """`prefill_block_visits` (the kernel's trip counts on the host) by
+    hand for the cells' passes, and against the masks of the two calls:
+    with lengths a block is visited exactly when a real query row of it
+    may attend a real key of it; without, when any row may attend any key
+    of the operands."""
+    from ray_tpu.ops.paged_attention import prefill_block_visits
+
+    assert prefill_block_visits(s, width)[0] == want[0]
+    assert prefill_block_visits(s, width, n_new, ctx) == want[1:]
+
+    def blocks(mask, bq, bk):
+        pad = np.zeros((-(-mask.shape[0] // bq) * bq,
+                        -(-mask.shape[1] // bk) * bk), bool)
+        pad[:mask.shape[0], :mask.shape[1]] = mask
+        return int(pad.reshape(pad.shape[0] // bq, bq, pad.shape[1] // bk,
+                               bk).any((1, 3)).sum())
+
+    qi = np.arange(s)[:, None]
+    bq = min(512, -(-s // 128) * 128)
+    bk = min(512, -(-width // 128) * 128)
+    for q_len, kv_len in ((s, width), (n_new, ctx)):
+        own = (np.arange(s)[None, :] <= qi) & (qi < q_len)
+        over = (np.arange(width)[None, :] < kv_len) & (qi < q_len)
+        n_own, n_ctx = blocks(own, bq, bq), blocks(over, bq, bk) if width else 0
+        assert prefill_block_visits(
+            s, width, *(() if q_len == s and kv_len == width
+                        else (q_len, kv_len))) == (
+            n_own + n_ctx, n_own * bq * bq + n_ctx * bq * bk)

@@ -12,9 +12,9 @@ import pytest
 
 from chipbench import generator
 from ray_tpu.models import jamba, llama, minicpm_sala
-from ray_tpu.serve.llm.engine import (_BALANCE_TOKENS, _bucket, EngineConfig,
-                                      LLMEngine, PassCost, SamplingParams,
-                                      plan_passes)
+from ray_tpu.serve.llm.engine import (_BALANCE_TOKENS, _PAIR_PARAMS, _bucket,
+                                      EngineConfig, LLMEngine, PassCost,
+                                      SamplingParams, plan_passes)
 from ray_tpu.util import tracing
 
 CHIPBENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -29,16 +29,15 @@ def _cycle(mix: str, n: int):
             json.load(f)["prompt_len"], n)]
 
 
-def _cost(width: int, preset: str = "llama3-8b", **over) -> PassCost:
-    """What an engine of `width` tokens of model length gives its plan."""
+def _cost(preset: str = "llama3-8b", **over) -> PassCost:
+    """What an engine gives its plan, whatever its model length."""
     weights, scores = llama.pass_cost_ratios(llama.get_config(preset, **over))
-    return PassCost(_BALANCE_TOKENS * weights, 4 * _BALANCE_TOKENS * scores,
-                    width)
+    return PassCost(_BALANCE_TOKENS * weights, _PAIR_PARAMS * scores)
 
 
 def _bare(floor: float) -> PassCost:
     """The floor alone: no attention term."""
-    return PassCost(floor, 0.0, 0)
+    return PassCost(floor, 0.0)
 
 
 MISTRAL = dict(vocab_size=32768, hidden_size=4096, intermediate_size=14336,
@@ -47,58 +46,92 @@ MISTRAL = dict(vocab_size=32768, hidden_size=4096, intermediate_size=14336,
 
 # ------------------------------------------------------ the plan, alone
 def test_the_cost_is_the_balance_times_what_the_family_answers():
-    mistral = _cost(2688, **MISTRAL)
+    mistral = _cost(**MISTRAL)
     assert mistral.floor == _BALANCE_TOKENS == 240
-    # 7650 (query, key) pairs of a context part cost one token's products
-    assert 7000 < 1 / mistral.pair < 8500
+    # 19600 (query, key) pairs the kernel visits cost one token's products
+    assert 18000 < 1 / mistral.pair < 21000
     # the benchmark's Mixtral (3 layers, all 8 experts) and the whole one
-    assert 740 <= _cost(2688, "mixtral-8x7b", num_layers=3).floor <= 800
-    assert 840 <= _cost(2688, "mixtral-8x7b").floor <= 900
-    weights, scores = minicpm_sala.pass_cost_ratios(
-        minicpm_sala.get_config("minicpm-sala"))
-    assert weights == 1.0 and 0 < scores < llama.pass_cost_ratios(
-        llama.get_config("llama3-8b", **MISTRAL))[1]
+    assert 740 <= _cost("mixtral-8x7b", num_layers=3).floor <= 800
+    assert 840 <= _cost("mixtral-8x7b").floor <= 900
+    sala = minicpm_sala.get_config("minicpm-sala")
+    weights, scores = minicpm_sala.pass_cost_ratios(sala)
+    # 8 of its 32 layers attend a context, in plain XLA: a pair there costs
+    # 6.5 of the flash kernel's, 16000 pairs one token's products
+    assert weights == 1.0 and scores == pytest.approx(
+        6.5 * 8 * 32 / sala.num_params())
+    assert 14000 < 1 / (_PAIR_PARAMS * scores) < 17000
     assert not jamba.RESUMES_PREFILL and not hasattr(jamba,
                                                      "pass_cost_ratios")
 
 
-@pytest.mark.parametrize("bucket, start, ms", [
-    (128, 0, 13.8), (256, 0, 14.9), (512, 0, 26.1), (1024, 0, 49.2),
-    (2048, 0, 98.4), (128, 256, 18.9), (256, 512, 21.6), (512, 1024, 34.4),
-    (1024, 1024, 65.1), (2048, 512, 130.5)])
-def test_the_cost_follows_the_passes_timed_on_the_chip(bucket, start, ms):
-    """`mistral-7b-v0.3-serve`'s passes as benchmarks/prefill_split_probe.py
-    timed them on a TPU v5e (PERF.md section 6, PR 36), dispatch and fetch
-    included, against the model at 0.048 ms a token (a fresh 2048 pass):
-    within 2.5 ms + 12%, and on the dear side for a resumed pass (its
-    extra floor is a bar, not a time)."""
-    got = 0.048 * _cost(2688, **MISTRAL)(bucket, start > 0)
-    assert abs(got - ms) <= 2.5 + 0.12 * ms + (0.048 * 240 if start else 0)
+@pytest.mark.parametrize("bucket, start, real, ms", [
+    # `mistral-7b-v0.3-serve` (a block table of 2688 columns): whole, half
+    # padding, resumed at three contexts
+    (128, 0, 128, 13.89), (128, 256, 128, 14.65), (128, 512, 128, 14.54),
+    (128, 2432, 128, 15.37), (256, 0, 256, 15.45), (256, 512, 256, 16.61),
+    (256, 2304, 256, 17.58), (512, 0, 512, 26.38), (512, 512, 512, 27.93),
+    (512, 1024, 512, 28.15), (512, 2048, 512, 29.38), (1024, 0, 1024, 49.07),
+    (1024, 0, 512, 47.57), (1024, 384, 1024, 52.34),
+    (1024, 1024, 1024, 53.15), (1024, 1536, 1024, 54.17),
+    (2048, 0, 2048, 98.61), (2048, 0, 1024, 93.84), (2048, 128, 2048, 104.38),
+    (2048, 512, 2048, 104.85),
+    # `mistral-7b-v0.3-serve-long` (8320 columns)
+    (2048, 0, 2048, 98.38), (2048, 0, 1024, 93.44),
+    (2048, 1536, 2048, 109.86), (2048, 4096, 2048, 120.84),
+    (2048, 6144, 2048, 129.90), (4096, 0, 4096, 218.48),
+    (4096, 0, 2048, 201.56), (4096, 1024, 4096, 237.06),
+    (4096, 4096, 4096, 263.30), (8192, 0, 8192, 480.48),
+    (8192, 0, 4096, 418.46)])
+def test_the_cost_follows_the_passes_timed_on_the_chip(bucket, start, real,
+                                                       ms):
+    """The two Mistral configurations' passes as
+    benchmarks/prefill_split_probe.py timed them on a TPU v5e with the
+    lengths in the kernel (PERF.md section 6, PR 39), dispatch and fetch
+    included, against the model at 0.047 ms a token: all 31 within 2.5 ms
+    + 12%, and on the dear side for a resumed pass (its extra floor is a
+    bar, not a time)."""
+    got = 0.047 * _cost(**MISTRAL)(bucket, real, start)
+    assert abs(got - ms) <= 2.5 + 0.12 * ms + (0.047 * 240 if start else 0)
     assert not start or got >= ms - 2.5
 
 
-@pytest.mark.parametrize("mix, n, buckets, cost, split", [
-    ("chat", 50, CHAT, _cost(2688, **MISTRAL), {
+@pytest.mark.parametrize("mix, n, buckets, cost, ctx, split", [
+    ("chat", 50, CHAT, _cost(**MISTRAL), 0, {
         536: [512, 128], 573: [512, 128], 616: [512, 128],
+        665: [512, 256], 722: [512, 256],
         1120: [1024, 128], 1326: [1024, 512]}),
-    ("docbatch", 16, DOCS, _cost(8320, **MISTRAL), {
+    ("docbatch", 16, DOCS, _cost(**MISTRAL), 0, {
         n: [4096, 2048] for n in (4104, 4529, 5090, 5938)}),
-    ("chat-mixtral", 238, CHAT, _cost(2688, "mixtral-8x7b", num_layers=3),
-     {}),
-    ("chat-mixtral", 238, CHAT, _cost(2688, "mixtral-8x7b"), {}),
-    # the same chat buckets under a model length of 32k: a resumed pass's
-    # context part attends 32k columns, and only the smallest still pays
-    ("chat", 50, CHAT, _cost(32768, **MISTRAL), {1120: [1024, 128]}),
+    ("chat-mixtral", 238, CHAT, _cost("mixtral-8x7b", num_layers=3), 0, {}),
+    ("chat-mixtral", 238, CHAT, _cost("mixtral-8x7b"), 0, {}),
+    # the same chat cycle behind a cached prefix of 1024 tokens (every
+    # pass resumes and attends the prefix too): the same plans
+    ("chat", 50, CHAT, _cost(**MISTRAL), 1024, {
+        536: [512, 128], 573: [512, 128], 616: [512, 128],
+        665: [512, 256], 722: [512, 256], 1120: [1024, 128],
+        1326: [1024, 512]}),
 ])
-def test_the_plan_over_a_cells_own_cycle(mix, n, buckets, cost, split):
+def test_the_plan_over_a_cells_own_cycle(mix, n, buckets, cost, ctx, split):
     """Which prompts of a cell's fixed schedule are split, and how: the
     two Mistral cells' tails are, nothing of Mixtral's is (a pass of its
-    model reads 3.2 times the weights a token multiplies)."""
+    model reads 3.2 times the weights a token multiplies). The model
+    length is no part of it: a pass attends the context it has."""
     lens = _cycle(mix, n)
-    plans = {x: plan_passes(x, buckets, 16, cost) for x in lens}
+    plans = {x: plan_passes(x, buckets, 16, cost, ctx) for x in lens}
     assert {x: p for x, p in plans.items() if len(p) > 1} == split
     assert all(p == [_bucket(x, buckets)] for x, p in plans.items()
                if x not in split)
+
+
+def _spent(plan, n, cost, ctx):
+    """What `cost` says the plan's passes cost for `n` tokens behind
+    `ctx`: every pass but the last full."""
+    total = 0.0
+    for b in plan:
+        real = min(b, n)
+        total += cost(b, real, ctx)
+        ctx, n = ctx + real, n - real
+    return total
 
 
 @pytest.mark.parametrize("buckets, page", [
@@ -106,11 +139,11 @@ def test_the_plan_over_a_cells_own_cycle(mix, n, buckets, cost, split):
     ((32, 64, 160), 8), ((24, 48, 96), 16)])
 @pytest.mark.parametrize("cost", [
     _bare(0), _bare(4), _bare(60), _bare(240), _bare(765),
-    PassCost(240, 1 / 7650, 2688), PassCost(240, 1 / 7650, 42240),
-    PassCost(4, 1e-3, 256)], ids=str)
-@pytest.mark.parametrize("resumed", [False, True])
+    PassCost(240, 1 / 7650), PassCost(240, 5.9e-5),
+    PassCost(4, 1e-3)], ids=str)
+@pytest.mark.parametrize("ctx", [0, 1024])
 def test_every_plan_covers_the_prompt_in_page_aligned_full_passes(
-        buckets, page, cost, resumed):
+        buckets, page, cost, ctx):
     """Over every length up to past three largest buckets: every pass but
     the last is a full bucket that ends on a page boundary, the last is
     the smallest bucket that holds the rest, and the plan never computes
@@ -119,7 +152,7 @@ def test_every_plan_covers_the_prompt_in_page_aligned_full_passes(
     largest = buckets[-1]
     step = max(1, largest // 97)
     for n in list(range(1, 3 * largest + 40, step)) + list(buckets):
-        plan = plan_passes(n, buckets, page, cost, resumed)
+        plan = plan_passes(n, buckets, page, cost, ctx)
         assert all(b in buckets for b in plan)
         done = sum(plan[:-1])
         assert done < n <= done + plan[-1]
@@ -130,14 +163,38 @@ def test_every_plan_covers_the_prompt_in_page_aligned_full_passes(
         assert plan[:lead] == [largest] * lead
         assert all(b % page == 0 for b in plan[lead:-1])
         assert sum(plan[lead:]) <= _bucket(rest, buckets)
-        tail, first = plan[lead:], resumed or lead > 0
-        spent = cost(tail[0], first) + sum(cost(b, True) for b in tail[1:])
-        whole = cost(_bucket(rest, buckets), first)
+        tail, behind = plan[lead:], ctx + lead * largest
+        spent = _spent(tail, rest, cost, behind)
+        whole = cost(_bucket(rest, buckets), rest, behind)
         assert spent < whole if len(tail) > 1 else spent == whole
 
 
+@pytest.mark.parametrize("bucket, real", [(128, 96), (512, 302),
+                                          (2048, 8), (2048, 1842)])
+def test_a_resumed_pass_costs_by_the_context_it_has(bucket, real):
+    """The context term is the pairs the kernel visits: real query blocks
+    x the context's blocks. More context costs more, and beyond a block
+    edge only; padding of the bucket past a query block costs nothing in
+    attention; the memo of one plan cannot leak into another's context."""
+    cost = _cost(**MISTRAL)
+    at = [cost(bucket, real, ctx) for ctx in (512, 1024, 4096, 8192)]
+    assert at == sorted(at) and at[0] < at[1] < at[2] < at[3]
+    assert cost(bucket, real, 1024) == cost(bucket, real, 520)
+    assert cost(bucket, real, 0) < cost(bucket, real, 16) - cost.floor + 1e-9
+    block = min(bucket, 512)
+    assert cost(bucket, real, 1024) == cost(
+        bucket, -(-real // block) * block, 1024)
+    assert cost(bucket, real, 1024) <= cost(bucket, bucket, 1024)
+    # one plan's memo is its own: the same length behind other contexts
+    for ctx in (0, 1024, 4096):
+        assert plan_passes(1326, CHAT, 16, cost, ctx) == [1024, 512]
+    # (behind 32k tokens a padded query block is 64 key blocks)
+    assert plan_passes(1326, CHAT, 16, cost, 32768) == [1024, 256, 128]
+    assert plan_passes(1326, CHAT, 16, cost) == [1024, 512]
+
+
 def test_three_passes_only_where_they_pay():
-    chat, docs = _cost(2688, **MISTRAL), _cost(8320, **MISTRAL)
+    chat = docs = _cost(**MISTRAL)
     # 1326 as 1024 + 256 + 128 computes 128 tokens fewer than 1024 + 512 and
     # costs a pass more: two passes; at no floor at all, three
     assert plan_passes(1326, CHAT, 16, chat) == [1024, 512]
@@ -149,18 +206,18 @@ def test_three_passes_only_where_they_pay():
     assert plan_passes(7797, DOCS, 16, docs) == [8192]
     # past the largest bucket: passes of it, then the rest by the same
     # rule, and the more readily as every pass there pays a context part
-    sala = PassCost(240, 5.9e-5, 42240)
+    sala = PassCost(240, 5.9e-5)
     assert plan_passes(10283, (512, 1024, 2048, 4096), 64, sala) == [
         4096, 4096, 2048, 512]
     assert plan_passes(8192 + 600, (512, 1024, 2048, 4096), 64, sala) == [
         4096, 4096, 1024]
-    # (on the chip a resumed 4096 pass is 388 ms and two of 2048 are 369)
-    assert plan_passes(8192 + 4100, DOCS, 16, docs) == [8192, 2048, 2048,
-                                                        2048]
-    # behind a cached prefix every pass resumes: padding costs its context
-    # part too, so 665 tokens split there and not from a fresh start
-    assert plan_passes(665, CHAT, 16, chat) == [1024]
-    assert plan_passes(665, CHAT, 16, chat, True) == [512, 256]
+    # (the same 6144 tokens and 189 block visits as three passes of 2048,
+    # at one resumed pass's bar less)
+    assert plan_passes(8192 + 4100, DOCS, 16, docs) == [8192, 4096, 2048]
+    # 665 tokens split since the context part is the context's (PR 39): a
+    # resumed 256 pass behind 512 tokens is one block more, not 2688 columns
+    assert plan_passes(665, CHAT, 16, chat) == [512, 256]
+    assert plan_passes(665, CHAT, 16, chat, 512) == [512, 256]
 
 
 # ------------------------------------------------------------ the engine
@@ -307,3 +364,47 @@ def test_the_plan_adds_no_program(preset, parts):
     _generate(engine, [_prompt(5, 70), _prompt(6, 90), _prompt(7, 12)], 3)
     assert set(engine.compute.programs) <= {
         (kind,) + key for kind, key in engine._warmup_programs(None, True)}
+
+
+def test_the_counters_say_what_the_rows_lengths_cut():
+    """`prefill_attn_blocks_total` / `_skipped_total`: host arithmetic at
+    dispatch over (bucket, real tokens, context, table width), the flash
+    kernel's own trip counts. A fresh 1024 bucket of 500 tokens: of its 3
+    causal visits of 512 x 512 the padded second query block's 2 are cut.
+    700 tokens as 256 + 256 + 256 behind a table of 1024 columns (two key
+    blocks of 512): the first pass 1 visit, each resumed one its own 1
+    and 1 of the 2 context blocks. Published under the same names as
+    `rtpu_llm_*` counters."""
+    from ray_tpu.serve.llm.server import _LLM_WORK_TOTALS
+
+    over = dict(max_model_len=1024, num_pages=300,
+                prefill_buckets=(256, 1024))
+    whole, split = _pair(**over)
+    whole._pass_cost = _bare(float("inf"))      # at this length too
+    keys = ("prefill_attn_blocks_total", "prefill_attn_blocks_skipped_total",
+            "prefill_resumed_passes_total")
+    assert set(keys) <= set(_LLM_WORK_TOTALS)
+    prompts = [_prompt(1, 500)]
+    want = _generate(whole, prompts, 2)
+    st = whole.stats()
+    assert tuple(st[k] for k in keys) == (3, 2, 0)
+    assert plan_passes(700, (256, 1024), 8, split._pass_cost) == [256] * 3
+    _generate(split, [_prompt(2, 700)], 2)
+    st = split.stats()
+    assert tuple(st[k] for k in keys) == (1 + 3 + 3, 2, 2)
+    # the split engine's own 500-token prompt is 256 + 256(244): the same
+    # tokens as the whole bucket's
+    assert _generate(split, prompts, 2) == want
+    st = split.stats()
+    assert tuple(st[k] for k in keys) == (7 + 1 + 3, 2 + 1, 3)
+
+
+def test_a_sparse_familys_resumed_pass_counts_no_flash_blocks():
+    """MiniCPM-SALA attends a context through ops/sparse_attention.py: only
+    its fresh pass under the dense length is the flash kernel's."""
+    whole, split = _pair(model="tiny-sala", page_size=16)
+    _generate(split, [_prompt(3, 70)], 2)
+    st = split.stats()
+    assert st["prefill_resumed_passes_total"] == 1
+    assert (st["prefill_attn_blocks_total"],
+            st["prefill_attn_blocks_skipped_total"]) == (1, 0)
