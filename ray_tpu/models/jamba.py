@@ -92,6 +92,8 @@ class JambaConfig:
     rope_theta: Optional[float] = None
     attention_impl: Optional[str] = None
     num_experts: int = 0
+    qk_norm: bool = False
+    block_causal: int = 0
     # accepted (the engine sets them for every family) and fixed here
     scan_layers: bool = True
     remat: bool = False

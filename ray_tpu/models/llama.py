@@ -27,7 +27,8 @@ from flax import struct
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import attention
-from ..ops.paged_attention import (paged_attention_decode,
+from ..ops.paged_attention import (paged_attention_block,
+                                   paged_attention_decode,
                                    paged_prefill_attention, paged_write)
 
 
@@ -79,10 +80,26 @@ class LlamaConfig:
     capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.02
     moe_group_size: int = 2048  # ep dispatch group (bounds routing memory)
+    # an expert's width where it is not the dense MLP's (None: it is)
+    moe_intermediate_size: Optional[int] = None
+    # the k kept router probabilities are renormalised to sum 1
+    norm_topk_prob: bool = True
+    # RMSNorm with a learned weight over the head dim of q and of k,
+    # before the rotation (Qwen3's layer)
+    qk_norm: bool = False
+    # > 0: attention is causal between blocks of this many positions,
+    # counted from position 0, and bidirectional inside one (query i sees
+    # key j iff j // block_causal <= i // block_causal): a model that
+    # generates by diffusion over blocks (models/sdar.py). 0: causal.
+    block_causal: int = 0
 
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
 
     def num_params(self) -> int:
         h, f, v, l = (self.hidden_size, self.intermediate_size,
@@ -91,9 +108,12 @@ class LlamaConfig:
         attn = h * hd * (self.num_heads + 2 * self.num_kv_heads) \
             + self.num_heads * hd * h
         if self.num_experts:
-            mlp = self.num_experts * 3 * h * f + h * self.num_experts
+            mlp = (self.num_experts * 3 * h * self.expert_width
+                   + h * self.num_experts)
         else:
             mlp = 3 * h * f
+        if self.qk_norm:
+            attn += 2 * hd
         return l * (attn + mlp + 2 * h) + 2 * v * h + h
 
     def active_params(self) -> int:
@@ -101,7 +121,7 @@ class LlamaConfig:
         MFU-relevant count for MoE."""
         if not self.num_experts:
             return self.num_params()
-        h, f, l = self.hidden_size, self.intermediate_size, self.num_layers
+        h, f, l = self.hidden_size, self.expert_width, self.num_layers
         dense = self.num_params() - l * self.num_experts * 3 * h * f
         return dense + l * self.num_experts_per_tok * 3 * h * f
 
@@ -110,10 +130,12 @@ class LlamaConfig:
 class RMSNorm(nn.Module):
     eps: float
     dtype: Any
+    # the logical axis of the weight (a head's norm is no shard of `embed`)
+    axes: tuple = ("embed",)
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", A(nn.initializers.ones, ("embed",)),
+        scale = self.param("scale", A(nn.initializers.ones, self.axes),
                            (x.shape[-1],), jnp.float32)
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
                        keepdims=True)
@@ -164,6 +186,11 @@ class PagedCache:
     # field (not a process flag): each engine's jit cache keys on it, so
     # kernel and reference lowerings never mix within or across engines.
     ref_attention: bool = struct.field(pytree_node=False, default=False)
+    # STATIC: the new tokens are one block of a diffusion model in a
+    # denoising pass (serve/llm/stage.py, kind "block"): they are written
+    # to their pages and ALL attend all of `total_lens`, themselves
+    # included, with no mask between them
+    block_step: bool = struct.field(pytree_node=False, default=False)
 
     # what a serving program carries from dispatch to dispatch, and how a
     # fused decode step renews it (models/jamba.py: HybridCache has both)
@@ -195,6 +222,11 @@ class Attention(nn.Module):
         q = q.reshape(b, s, nq, hd)
         k = k.reshape(b, s, nkv, hd)
         v = v.reshape(b, s, nkv, hd)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, (None,),
+                        name="q_norm")(q)
+            k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, (None,),
+                        name="k_norm")(k)
         if cfg.rope_theta is not None:
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
@@ -207,7 +239,14 @@ class Attention(nn.Module):
             pc = kv_cache
             kv_pages = paged_write(pc.kv_pages, k, v, pc.block_tables,
                                    positions, pc.total_lens, pc.layer)
-            if s == 1:
+            if pc.block_step:
+                # a denoising pass: the block's keys are in its pages (this
+                # pass's; the next overwrites them) and every query of the
+                # block sees all of `total_lens`
+                out = paged_attention_block(
+                    q, kv_pages, pc.block_tables, pc.total_lens,
+                    layer=pc.layer, force_reference=pc.ref_attention)
+            elif s == 1:
                 out = paged_attention_decode(
                     q[:, 0], kv_pages, pc.block_tables, pc.total_lens,
                     layer=pc.layer,
@@ -217,7 +256,7 @@ class Attention(nn.Module):
                     q, k, v, kv_pages, pc.block_tables, positions,
                     pc.total_lens, ctx_pages=pc.ctx_pages,
                     impl="reference" if pc.ref_attention else None,
-                    layer=pc.layer)
+                    layer=pc.layer, block_causal=cfg.block_causal)
             new_cache = pc.replace(kv_pages=kv_pages)
         else:
             if kv_cache is not None:
@@ -241,8 +280,8 @@ class Attention(nn.Module):
             impl = cfg.attention_impl
             if kv_cache is not None and impl in ("ring", "ulysses"):
                 impl = None  # kv-cache decode is dense; sp is for training
-            out = attention(q, k, v, causal=True,
-                            segment_ids=segment_ids, impl=impl)
+            out = attention(q, k, v, causal=True, segment_ids=segment_ids,
+                            impl=impl, block_causal=cfg.block_causal)
             new_cache = (k, v) if kv_cache is not None else None
         out = out.reshape(b, s, nq * hd)
         out = nn.DenseGeneral(
@@ -313,7 +352,7 @@ class MoEMLP(nn.Module):
         `_stacked_experts`)."""
         cfg = self.config
         E, k = cfg.num_experts, cfg.num_experts_per_tok
-        f = cfg.intermediate_size
+        f = cfg.expert_width
         b, s, h = x.shape
         T = b * s
         xt = x.reshape(T, h)
@@ -325,7 +364,8 @@ class MoEMLP(nn.Module):
         logits = jnp.einsum("th,he->te", xt.astype(jnp.float32), router)
         probs = jax.nn.softmax(logits, axis=-1)              # [T,E]
         gate, idx = jax.lax.top_k(probs, k)                  # [T,k]
-        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        if cfg.norm_topk_prob:
+            gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
 
         w_gu = self.param(
             "experts_gate_up",

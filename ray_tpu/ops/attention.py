@@ -51,13 +51,26 @@ def split_segment_ids(segment_ids, sq: int, sk: int):
     return q_seg, kv_seg
 
 
+def causal_mask(sq: int, sk: int, block_causal: int = 0) -> jax.Array:
+    """[sq, sk] bool: query i (the last `sq` of `sk` positions) sees key j
+    iff j <= i; with `block_causal` = B > 0 iff j // B <= i // B, blocks
+    counted from key 0: causal between blocks, bidirectional inside one
+    (a model that generates by diffusion over blocks, models/sdar.py)."""
+    if not block_causal:
+        return jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
+    q_blk = (jnp.arange(sq) + sk - sq) // block_causal
+    return (jnp.arange(sk) // block_causal)[None, :] <= q_blk[:, None]
+
+
 def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         *, causal: bool = True,
                         segment_ids=None,
-                        scale: Optional[float] = None) -> jax.Array:
+                        scale: Optional[float] = None,
+                        block_causal: int = 0) -> jax.Array:
     """Plain softmax attention. Shapes: q [B,Sq,Hq,D], k/v [B,Sk,Hkv,D].
 
     segment_ids: None | [B,S] array | (q_seg [B,Sq], kv_seg [B,Sk]) tuple.
+    block_causal: see `causal_mask`.
     """
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
@@ -69,8 +82,8 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
-        mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
-        logits = jnp.where(mask[None, None], logits, NEG_INF)
+        logits = jnp.where(causal_mask(sq, sk, block_causal)[None, None],
+                           logits, NEG_INF)
     q_seg, kv_seg = split_segment_ids(segment_ids, sq, sk)
     if q_seg is not None:
         seg_mask = q_seg[:, None, :, None] == kv_seg[:, None, None, :]
@@ -83,8 +96,12 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True,
               segment_ids=None,
               scale: Optional[float] = None,
-              impl: Optional[str] = None) -> jax.Array:
+              impl: Optional[str] = None,
+              block_causal: int = 0) -> jax.Array:
     """Dispatch to the best backend for this platform.
+
+    block_causal > 0 (`causal_mask`; dense forward only: the flash
+    backward and the sequence-parallel forms know no such mask).
 
     impl: None (flash on a TPU backend, reference elsewhere) | "reference"
     | "flash" (Pallas TPU kernel; interpret mode on a CPU backend; wrapped
@@ -96,6 +113,22 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     segment_ids: None | [B,S] array | (q_seg, kv_seg) tuple (see
     reference_attention).
     """
+    if block_causal:
+        if not causal or impl in ("ring", "ulysses"):
+            raise NotImplementedError(
+                f"block_causal={block_causal} is a dense causal forward's "
+                f"(got causal={causal}, impl={impl!r})")
+        if impl == "flash" or (impl is None
+                               and jax.default_backend() == "tpu"):
+            from .flash_attention import flash_attention
+
+            # the forward-only path: its kernel takes the mask
+            return flash_attention(
+                q, k, v, causal=True, segment_ids=segment_ids, scale=scale,
+                return_lse=True, block_causal=block_causal)[0]
+        return reference_attention(q, k, v, causal=True,
+                                   segment_ids=segment_ids, scale=scale,
+                                   block_causal=block_causal)
     if impl in ("ring", "ulysses"):
         from ..parallel.mesh import current_mesh
 

@@ -88,14 +88,22 @@ def _dummy_spec():
 # =============================================================== forward
 def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
                 sm_scale: float, causal: bool, block_k: int,
-                sq: int, sk: int, have_segs: bool, q_len=None, kv_len=None):
+                sq: int, sk: int, have_segs: bool, q_len=None, kv_len=None,
+                block_causal: int = 0):
     """`q_len` / `kv_len`: this batch row's true lengths (traced scalars,
-    `_fwd_kernel_lens`), or None for the shapes' own."""
+    `_fwd_kernel_lens`), or None for the shapes' own. `block_causal` = B >
+    0: the causal relation is between blocks of B positions and a block
+    sees itself whole (`ops.attention.causal_mask`); B divides the query
+    block and `sk - sq`, so the key blocks a query block walks are
+    causal's."""
     qblk = pl.program_id(2)
     bq, d = q_ref.shape[2], q_ref.shape[3]
     q = q_ref[0, 0]  # [bq, d]
     q_pos = qblk * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     offset = sk - sq
+    if block_causal:
+        # the last key a query row sees: the end of its block
+        k_last = ((q_pos + offset) // block_causal + 1) * block_causal - 1
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
@@ -128,7 +136,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
         if kv_len is not None:
             mask = jnp.logical_and(mask, k_pos < kv_len)
         if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos + offset)
+            mask = jnp.logical_and(mask, k_pos <= (
+                k_last if block_causal else q_pos + offset))
         if have_segs:
             qs = qseg_ref[0]  # [bq, 1]
             ks = kseg_ref[0, pl.ds(kb * block_k, block_k), :].reshape(
@@ -160,7 +169,7 @@ def _fwd_kernel_lens(lens_ref, *refs, **static):
 
 
 def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
-         interpret, sq, sk, lens=None):
+         interpret, sq, sk, lens=None, block_causal=0):
     """q: [B,Hq,Sq_p,D]; k/v: [B,Hkv,Sk_p,D] (padded to block multiples).
 
     sq/sk are the TRUE lengths of the operands: the kernels mask kv padding
@@ -179,7 +188,7 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
     kernel = functools.partial(
         _fwd_kernel if lens is None else _fwd_kernel_lens,
         sm_scale=sm_scale, causal=causal, block_k=bk,
-        sq=sq, sk=sk, have_segs=have_segs)
+        sq=sq, sk=sk, have_segs=have_segs, block_causal=block_causal)
 
     # (`*_`: the scalar-prefetched lengths, where there are any)
     in_specs = [
@@ -458,6 +467,7 @@ def flash_attention(
     return_lse: bool = False,
     q_lens: Optional[jax.Array] = None,
     kv_lens: Optional[jax.Array] = None,
+    block_causal: int = 0,
 ):
     """Flash attention. q: [B,Sq,Hq,D]; k/v: [B,Sk,Hkv,D] -> [B,Sq,Hq,D].
 
@@ -481,6 +491,12 @@ def flash_attention(
     the operands' (`k_pos <= q_pos + Sk - Sq`): lengths only cut. Both
     None: the kernel and the program text are what they are without the
     operand.
+
+    block_causal = B > 0 (static; with `causal` and `return_lse` only):
+    query i sees key j iff j // B <= (i + Sk - Sq) // B, causal between
+    blocks of B positions and whole inside one. B must divide the query
+    block (128 and up) and Sk - Sq. 0: the kernel and the jaxpr are what
+    they are without the argument.
     """
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
@@ -499,6 +515,12 @@ def flash_attention(
     # padded q rows are sliced off below, so padding needs no sentinel segs
     bq, bk = _pick_blocks(sq, sk, block_q, block_k)
     sq_p, sk_p = _round_up(sq, bq), _round_up(sk, bk)
+    if block_causal and not (causal and return_lse and bq % block_causal == 0
+                             and (sk - sq) % block_causal == 0):
+        raise ValueError(
+            f"block_causal={block_causal} needs causal=True, return_lse="
+            f"True (forward only) and blocks that divide the query block "
+            f"{bq} and Sk - Sq = {sk - sq}")
 
     def pad(x, s_p, axis):
         pad_n = s_p - x.shape[axis]
@@ -529,7 +551,7 @@ def flash_attention(
     if return_lse:
         # forward-only: bypass the custom_vjp (no bwd through the merge)
         o, lse = _fwd(qt, kt, vt, q_seg, kv_seg, causal, scale, bq, bk,
-                      interpret, sq, sk, lens)
+                      interpret, sq, sk, lens, block_causal)
         return (o[:, :, :sq, :].transpose(0, 2, 1, 3),
                 lse[:, :, :sq, 0].transpose(0, 2, 1))
     o = _flash(qt, kt, vt, q_seg, kv_seg, causal, scale, bq, bk, interpret,

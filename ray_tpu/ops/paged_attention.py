@@ -475,9 +475,36 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
                         interpret=bool(interpret))
 
 
+def paged_attention_block(q: jax.Array, kv_pages: jax.Array,
+                          block_tables: jax.Array, lengths: jax.Array, *,
+                          layer=None, scale: Optional[float] = None,
+                          interpret: Optional[bool] = None,
+                          force_reference: bool = False) -> jax.Array:
+    """One block of query tokens a row, all of which see ALL `lengths`
+    keys of their row (the block's own included: already written to its
+    pages), with no mask between them: a denoising pass of a model that
+    generates by diffusion over blocks.
+
+    q: [B, T, Hq, D]; lengths: [B] (0 = inactive row -> zero output).
+    Returns [B, T, Hq, D]. It is `paged_attention_decode` with the T
+    tokens x rep query heads of a kv head as that head's group of query
+    rows: one read of a row's pages serves every token of its block. The
+    implementation is chosen as that call chooses it."""
+    b, t, hq, d = q.shape
+    hkv = kv_pages.shape[-3]
+    rep = hq // hkv
+    grouped = q.reshape(b, t, hkv, rep, d).transpose(0, 2, 1, 3, 4)
+    out = paged_attention_decode(
+        grouped.reshape(b, hkv * t * rep, d), kv_pages, block_tables,
+        lengths, layer=layer, scale=scale, interpret=interpret,
+        force_reference=force_reference)
+    return out.reshape(b, hkv, t, rep, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, t, hq, d)
+
+
 # --------------------------------------------------------- prefill (+ctx)
 def _attn_lse(q, k, v, *, causal, scale, q_lens=None, kv_lens=None,
-              impl=None):
+              impl=None, block_causal=0):
     """Attention returning (o [B,S,Hq,D], lse [B,S,Hq]). kv_lens [B]: the
     keys a row has (the rest are masked); q_lens [B]: its real queries
     (the flash kernel computes no block past them; the jnp path computes
@@ -492,9 +519,9 @@ def _attn_lse(q, k, v, *, causal, scale, q_lens=None, kv_lens=None,
     if impl == "flash" or (impl is None and jax.default_backend() == "tpu"):
         from .flash_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               return_lse=True, q_lens=q_lens,
-                               kv_lens=kv_lens)
+        return flash_attention(
+            q, k, v, causal=causal, scale=scale, return_lse=True,
+            q_lens=q_lens, kv_lens=kv_lens, block_causal=block_causal)
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
     rep = hq // hkv
@@ -502,8 +529,10 @@ def _attn_lse(q, k, v, *, causal, scale, q_lens=None, kv_lens=None,
     logits = jnp.einsum("bshrd,bkhd->bhrsk", qg, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
-        mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
-        logits = jnp.where(mask[None, None, None], logits, NEG_INF)
+        from .attention import causal_mask
+
+        logits = jnp.where(causal_mask(sq, sk, block_causal)[
+            None, None, None], logits, NEG_INF)
     if kv_lens is not None:
         live = jnp.arange(sk)[None, :] < kv_lens[:, None]
         logits = jnp.where(live[:, None, None, None, :], logits, NEG_INF)
@@ -538,9 +567,14 @@ def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
                             *, ctx_pages: int = 0,
                             scale: Optional[float] = None,
                             impl: Optional[str] = None,
-                            layer=None) -> jax.Array:
+                            layer=None,
+                            block_causal: int = 0) -> jax.Array:
     """Prefill attention: new tokens attend to themselves (causal) and to
     an optional cached prefix held in pages, merged by log-sum-exp.
+    `block_causal` = B > 0: among themselves by blocks of B
+    (`ops.attention.causal_mask`); a row starts on a block boundary (B
+    divides the page or the engine aligns its passes), so the part over
+    the prefix is what it was.
 
     q/k_new/v_new: [B, S, H*, D] — the new tokens, contiguous from each
     row's first position positions[:, 0] (the cached-prefix length, a
@@ -557,7 +591,7 @@ def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
     # (its writes are dropped, its outputs thrown away), not computed
     n_new = jnp.clip(total_lens - ctx_len, 0, q.shape[1])
     o1, lse1 = _attn_lse(q, k_new, v_new, causal=True, scale=scale_f,
-                         q_lens=n_new, impl=impl)
+                         q_lens=n_new, impl=impl, block_causal=block_causal)
     if ctx_pages <= 0:
         return o1
     bt = block_tables[:, :ctx_pages]

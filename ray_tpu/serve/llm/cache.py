@@ -20,6 +20,20 @@ class OutOfPages(Exception):
     pass
 
 
+def prefix_reuse_unsound(page_size: int, block_length: int) -> Optional[str]:
+    """Why a page found by its content hash may NOT be reused by a model
+    whose attention is bidirectional inside blocks of `block_length`
+    tokens (models/sdar.py), or None where it may: a token's keys depend
+    on its block's LATER tokens, so a page's hash (its tokens and
+    everything before them) says what its keys are only if no block of
+    the page reaches into the next."""
+    if page_size % block_length == 0:
+        return None
+    return (f"page_size {page_size} holds no whole number of blocks of "
+            f"{block_length} tokens: a page's keys depend on tokens its "
+            f"hash does not cover")
+
+
 class PageAllocator:
     """Page 0 is reserved as the dummy page (padding block-table slots).
 

@@ -128,6 +128,7 @@ from __future__ import annotations
 import atexit
 import dataclasses
 import functools
+import math
 import threading
 import time
 import weakref
@@ -136,7 +137,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ...util import tracing
-from .cache import OutOfPages, PageAllocator
+from .cache import OutOfPages, PageAllocator, prefix_reuse_unsound
 from .stage import (_MAX_TOP_K, StageCompute, model_family,
                     serve_model_config, ssm_layers)
 
@@ -232,6 +233,10 @@ class OutputDelta:
     new_token_ids: List[int]
     finished: bool
     finish_reason: Optional[str] = None
+    # a model that generates by diffusion over blocks: the denoising pass
+    # that fixed each of `new_token_ids` (one block's tokens, in position
+    # order). None for every other model.
+    fixed_pass: Optional[List[int]] = None
 
 
 @dataclasses.dataclass
@@ -340,6 +345,43 @@ def _refuse_for_recurrent_state(config: EngineConfig, model_cfg,
             f"layers {model_cfg.LAYER_KINDS}")
 
 
+def _refuse_for_block_diffusion(config: EngineConfig, model_cfg,
+                                mesh) -> None:
+    """A model that generates by diffusion over blocks (its config has a
+    `block_length`: models/sdar.py). A generation step fixes a block of a
+    row's tokens in passes that rewrite the block's keys; the engine
+    options built on one token a step are refused here, each by the
+    mechanism that is missing."""
+    block = getattr(model_cfg, "block_length", 0)
+    if not block:
+        return
+    model = (f"model {config.model!r} generates by diffusion over blocks "
+             f"of {block} tokens")
+    if config.max_model_len % block:
+        raise ValueError(
+            f"{model}: max_model_len={config.max_model_len} must hold "
+            f"whole blocks (a block is written whole)")
+    if config.spec_lookahead > 0:
+        raise NotImplementedError(
+            f"{model}: spec_lookahead={config.spec_lookahead} verifies a "
+            f"draft under a causal mask one token after the other, and a "
+            f"block's tokens are fixed in the order the model's confidence "
+            f"chooses (no draft-and-verify use of the block program yet)")
+    if config.tp > 1 or mesh is not None:
+        raise NotImplementedError(
+            f"{model}: tensor parallelism (tp={config.tp}, mesh="
+            f"{'given' if mesh is not None else None}) runs the jnp "
+            f"attention paths under GSPMD, and the block step's attention "
+            f"(every query of a block on the row's pages) has no sharded "
+            f"form that was ever run")
+    if config.pp > 1:
+        raise NotImplementedError(
+            f"{model}: pipeline parallelism (pp={config.pp}) samples on "
+            f"the last stage and feeds the first, and a block's passes "
+            f"are one program's loop: the next pass's ids are chosen "
+            f"where the head is")
+
+
 def _bucket(n: int, buckets) -> int:
     for b in buckets:
         if n <= b:
@@ -362,6 +404,8 @@ _BALANCE_TOKENS = 240
 _PAIR_PARAMS = 375
 # where an `engine.dispatch` record's device stamps begin (_harvest)
 _STAMPS_AT = tracing.FIELDS["engine.dispatch"].index("enqueued_ns")
+# and where a block program's three fields sit, behind every family's
+_BLOCK_AT = tracing.FIELDS["engine.dispatch"].index("block_passes")
 
 
 def _attn_visits(bucket: int, width: int, real=None, ctx=None) -> tuple:
@@ -482,7 +526,9 @@ class LLMEngine:
 
     def __init__(self, config: EngineConfig, params=None, mesh=None):
         self.config = config
-        _refuse_for_recurrent_state(config, serve_model_config(config), mesh)
+        model_cfg = serve_model_config(config)
+        _refuse_for_recurrent_state(config, model_cfg, mesh)
+        _refuse_for_block_diffusion(config, model_cfg, mesh)
         self._build_compute(params, mesh)
         self.max_pages_per_seq = config.max_model_len // config.page_size
 
@@ -575,6 +621,24 @@ class LLMEngine:
             "prefill_resumed_passes_total", "prefill_split_prompts_total",
             "prefill_attn_blocks_total",
             "prefill_attn_blocks_skipped_total"), 0)
+        # a model that generates by diffusion over blocks: its block
+        # length (0: one token a row and step), and where a prefill pass
+        # may start (a page boundary that is a block boundary too)
+        self._block = getattr(self.model_cfg, "block_length", 0)
+        self._pass_align = config.page_size
+        # why a page found by its content hash may not be reused (None:
+        # it may)
+        self._prefix_off = None
+        if self._block:
+            self._pass_align = math.lcm(config.page_size, self._block)
+            self._prefix_off = prefix_reuse_unsound(config.page_size,
+                                                    self._block)
+            self._totals.update(dict.fromkeys((
+                "block_dispatches_total", "block_passes_total",
+                "block_tokens_total", "block_rows_total",
+                "block_early_exits_total", "prefill_tokenless_total"), 0))
+            if self._prefix_off:
+                self._totals["prefix_reuse_refused_total"] = 0
         # (layers, experts) of an expert model, whose programs return
         # routing counts packed behind their tokens; None for a dense one
         cfg_m = self.model_cfg
@@ -614,6 +678,8 @@ class LLMEngine:
         # knowledge of the model. Nothing for any other model.
         self._ssm_fields: tuple = ()
         if self._ssm_layers:
+            self._prefix_off = ("a page found by its content hash carries "
+                                "no recurrent state")
             self._totals["prefix_reuse_refused_total"] = 0
             self._ssm_fields = (None,) * (0 if self._moe_LE else 3)
         if self._scan_layers:
@@ -904,9 +970,10 @@ class LLMEngine:
         page = self.config.page_size
         legacy = self.config.prefill_chunk_tokens <= 0
         lookahead = 1 if legacy else self._ADMIT_LOOKAHEAD
-        if self._ssm_layers:
-            # a page found by its content hash carries no recurrent state:
-            # no twin is deferred to share a prefix, nothing is matched
+        if self._prefix_off:
+            # a page found by its content hash cannot be reused
+            # (`_prefix_off` says why): no twin is deferred to share a
+            # prefix, nothing is matched
             burst_prefixes = None
         head_id = self.waiting[0].request_id
         if self._head_overtaken[0] != head_id:
@@ -922,7 +989,7 @@ class LLMEngine:
                     None, req.prompt_ids[:page])
                 if first_hash in burst_prefixes:
                     continue  # wait one step; the prefix cache will hit
-            if self._ssm_layers:
+            if self._prefix_off:
                 cached_pages, n_cached = [], 0
                 self._totals["prefix_reuse_refused_total"] += 1
             else:
@@ -930,8 +997,11 @@ class LLMEngine:
                     req.prompt_ids)
             if qi > 0 and not cached_pages:
                 continue  # only prefix-sharers may pass a blocked head
-            need = (-(-(len(req.prompt_ids) + 1) // page)
-                    - len(cached_pages))
+            # the prompt and the first token's place (a block model: its
+            # first block's)
+            first_end = (self._block_start(req) + self._block if self._block
+                         else len(req.prompt_ids) + 1)
+            need = -(-first_end // page) - len(cached_pages)
             if self.allocator.num_free() < need:
                 self.allocator.release(cached_pages)
                 self.allocator.stats["cache_hits"] -= len(cached_pages)
@@ -971,8 +1041,26 @@ class LLMEngine:
             req.admitted_ns = tracing.now_ns()
             self._slot_req[req.slot] = req
             self.running.append(req)
+            # nothing to prefill (a block model's prompt shorter than a
+            # block, or cached to its last whole block): its first block
+            # may go at once
+            req.decode_ready = req.n_prefilled >= self._prefill_end(req)
             return req
         return None
+
+    def _prefill_end(self, req: Request) -> int:
+        """The prompt tokens a prefill writes keys for: all of them, and
+        the last pass samples the first token; for a block model the
+        prompt's WHOLE blocks, and no pass samples (the ragged tail opens
+        the first block, `_dispatch_block`)."""
+        n = len(req.prompt_ids)
+        return n // self._block * self._block if self._block else n
+
+    def _block_start(self, req: Request) -> int:
+        """Where the next block of a block model's request begins: every
+        token planned so far is settled or on its way."""
+        return ((len(req.prompt_ids) + req.planned_out)
+                // self._block * self._block)
 
     # ---------------------------------------------------------- compute
 
@@ -1027,6 +1115,17 @@ class LLMEngine:
             jnp.asarray(override_mask), jnp.asarray(override_ids), temp,
             topk, jnp.asarray(keys_steps)))
 
+    def _compute_block(self, key, bt, total, ids, masked, temp, topk,
+                       keys_steps):
+        """One block program over the full slot set (stage.py:
+        `_block_program`); returns the handle of its packed result."""
+        import jax.numpy as jnp
+
+        return self._to_host_async(self.compute.run(
+            "block", key, jnp.asarray(bt), jnp.asarray(total),
+            jnp.asarray(ids), jnp.asarray(masked), temp, topk,
+            jnp.asarray(keys_steps)))
+
     def _fetch_tokens(self, handle) -> np.ndarray:
         """Resolve a compute handle into host tokens (blocks until the
         async D2H copy lands; microseconds once it has)."""
@@ -1066,8 +1165,10 @@ class LLMEngine:
         buckets = self.config.prefill_buckets
         waves: Dict[tuple, List[tuple]] = {}
         for req in admitted:
-            n_new = len(req.prompt_ids) - req.n_prefilled
-            plan = (plan_passes(n_new, buckets, self.config.page_size,
+            n_new = self._prefill_end(req) - req.n_prefilled
+            if n_new <= 0:
+                continue
+            plan = (plan_passes(n_new, buckets, self._pass_align,
                                 self._pass_cost, req.n_prefilled)
                     if self._resumes else [_bucket(n_new, buckets)])
             self._totals["prefill_split_prompts_total"] += (
@@ -1077,7 +1178,7 @@ class LLMEngine:
                 # the order they are enqueued
                 self._dispatch_prefill_batch(sb, [(req, sb)])
             waves.setdefault((plan[-1], req.n_prefilled > 0), []).append(
-                (req, len(req.prompt_ids) - req.n_prefilled))
+                (req, self._prefill_end(req) - req.n_prefilled))
         for (sb, _), group in waves.items():
             for i in range(0, len(group), wave):
                 self._dispatch_prefill_batch(sb, group[i:i + wave])
@@ -1087,7 +1188,7 @@ class LLMEngine:
         boundaries stay page-aligned so every completed chunk's full
         pages enter the prefix cache) and clamped to the largest length
         bucket (a chunk must fit one compiled prefill shape)."""
-        page = self.config.page_size
+        page = self._pass_align
         c = max(1, int(self.config.prefill_chunk_tokens))
         return max(page, min(-(-c // page) * page,
                              self.config.prefill_buckets[-1]))
@@ -1109,7 +1210,7 @@ class LLMEngine:
         requests (page-aligned shares) so concurrent long prompts
         advance together instead of strictly FIFO."""
         budget = self._chunk_tokens()
-        page = self.config.page_size
+        page = self._pass_align
 
         def grant(req: Request, tokens: int) -> int:
             """Tokens this row may prefill now: a FINAL chunk takes its
@@ -1117,7 +1218,7 @@ class LLMEngine:
             multiple so every chunk boundary stays page-aligned (full
             pages enter the prefix cache; the ctx-merge path only ever
             sees the page-multiple starts prefix-cache hits produce)."""
-            remaining = len(req.prompt_ids) - req.n_prefilled
+            remaining = self._prefill_end(req) - req.n_prefilled
             if remaining <= tokens:
                 return remaining
             return tokens // page * page
@@ -1140,7 +1241,7 @@ class LLMEngine:
             # holds its slot/pages and continues in the next step's wave
         continuing = [r for r in self.running
                       if r.state == RUNNING and not r.decode_ready
-                      and 0 < len(r.prompt_ids) - r.n_prefilled
+                      and 0 < self._prefill_end(r) - r.n_prefilled
                       and all(r is not q for q, _ in rows)]
         if continuing and used < budget:
             # even, page-aligned shares; the division remainder goes to
@@ -1208,7 +1309,7 @@ class LLMEngine:
                 positions[i] = start + np.arange(sb, dtype=np.int32)
                 bt[i, :len(req.pages)] = req.pages
                 total[i] = start + n_new
-                final = start + n_new >= len(req.prompt_ids)
+                final = start + n_new >= self._prefill_end(req)
                 # where the family's model computes the head at this
                 # position only, a pass that is not the last asks for none
                 gather[i] = (n_new - 1 if final or not self._head_at_gather
@@ -1235,8 +1336,14 @@ class LLMEngine:
                 temp, topk, keys, *(() if slots is None else (slots,)))
             for req, n_new in group:
                 req.n_prefilled += n_new
-                if req.n_prefilled >= len(req.prompt_ids):
-                    req.planned_out = 1
+                if req.n_prefilled >= self._prefill_end(req):
+                    req.planned_out = 0 if self._block else 1
+                    if self._block:
+                        # this prefill yields no token the first block
+                        # would wait for, and programs run in dispatch
+                        # order on one stream: the block may be enqueued
+                        # right behind the pass that writes its context
+                        req.decode_ready = True
             self._totals["prefill_dispatches_total"] += 1
             self._totals["prefill_tokens_total"] += sum(
                 n_new for _, n_new in group)
@@ -1457,9 +1564,12 @@ class LLMEngine:
         for req in sorted(elig, key=lambda r: r.arrival_t):
             cap = _cap_total(req, cfg.max_model_len)
             # last position this chunk writes: the pending token sits at
-            # total-1 and each of the K steps advances one, clamped
-            last_pos = min(len(req.prompt_ids) + req.planned_out - 1
-                           + (k_steps - 1), cap - 1)
+            # total-1 and each of the K steps advances one, clamped (a
+            # block model: the end of the request's next block)
+            last_pos = (self._block_start(req) + self._block - 1
+                        if self._block else
+                        min(len(req.prompt_ids) + req.planned_out - 1
+                            + (k_steps - 1), cap - 1))
             required = min(last_pos // page + 1, self.max_pages_per_seq)
             while (req in self.running and req.state == RUNNING
                    and len(req.pages) < required):
@@ -1489,6 +1599,8 @@ class LLMEngine:
         reading last tokens from the device-resident carry. Returns False
         when there is nothing safe to decode (no eligible slot, or a page
         shortfall that needs the pipeline drained first)."""
+        if self._block:
+            return self._dispatch_block()
         cfg = self.config
         k_steps = self._decode_shape_key()[0]
         S = cfg.max_batch
@@ -1540,6 +1652,63 @@ class LLMEngine:
                                     override_ids, temp, topk, keys_steps)
         self._enqueue_decode(toks, dispatch_ns, k_steps, facts,
                              chunk_slots)
+        return True
+
+    def _dispatch_block(self) -> bool:
+        """A block model's generation step: ONE program that denoises the
+        next block of every slot that has one to generate and settles it
+        (stage.py: `_block_program`). A request's first block opens with
+        its prompt's ragged tail; every later one is all masks, which is
+        why the host needs no token of block n to dispatch block n + 1
+        (the pipeline runs ahead as a decode chain does; what a stop token
+        makes stale is dropped at harvest). Returns False when no slot has
+        a block to generate or pages fall short."""
+        cfg = self.config
+        B, S = self._block, cfg.max_batch
+        elig = [req for req in self.running
+                if req.slot >= 0 and req.decode_ready
+                and req.planned_out < req.sampling.max_tokens
+                and self._block_start(req) + B <= cfg.max_model_len]
+        if not elig:
+            return False
+        elig = self._reserve_decode_pages(elig, B)
+        if not elig:
+            return False
+        with tracing.region("rtpu.engine.dispatch_block") as r:
+            key = self._block_shape_key()
+            steps = key[1]
+            bt = np.zeros((S, self.max_pages_per_seq), np.int32)
+            total = np.zeros((S,), np.int32)
+            ids = np.full((S, B), self.model_cfg.mask_token_id, np.int32)
+            masked = np.zeros((S, B), bool)
+            block_slots, facts = {}, []
+            now = time.monotonic()
+            for req in elig:
+                s, start = req.slot, self._block_start(req)
+                # the block's known tokens: a first block's prompt tail
+                tail = req.prompt_ids[start:] if req.planned_out == 0 else []
+                ids[s, :len(tail)] = tail
+                masked[s, len(tail):] = True
+                bt[s, :len(req.pages)] = req.pages
+                total[s] = start + B
+                block_slots[s] = (req.request_id, req.planned_out, len(tail))
+                facts.append((req.request_id, B, start + B))
+                if req.dispatched_t is None:
+                    # a prompt with no whole block had no prefill
+                    req.dispatched_t, req.dispatched_ns = now, r.start_ns
+            keys_steps = np.zeros((steps, S, 2), np.uint32)
+            for k in range(steps):
+                temp, topk, keys_steps[k] = self._sampling_arrays(
+                    elig, S, counter_offset=k, slot_layout=True,
+                    base="planned")
+            for req in elig:
+                req.planned_out += B - block_slots[req.slot][2]
+            toks = self._compute_block(key, bt, total, ids, masked, temp,
+                                       topk, keys_steps)
+            self._totals["block_dispatches_total"] += 1
+            self._totals["block_rows_total"] += len(facts)
+            self._enqueue("block", toks, r.start_ns, S, S * B, facts,
+                          k=steps + 1, slots=block_slots)
         return True
 
     def _enqueue_decode(self, toks, dispatch_ns: int, k_steps: int,
@@ -1624,6 +1793,13 @@ class LLMEngine:
                         # intermediate chunk: pages are written; the sampled
                         # token (mid-prompt continuation) is meaningless
                         continue
+                    if self._block:
+                        # a block model's prefill yields no token: the
+                        # prompt's whole blocks are in pages, its first
+                        # block may go
+                        req.decode_ready = True
+                        self._totals["prefill_tokenless_total"] += 1
+                        continue
                     token = int(toks_np[i])
                     # the decode chain reads this slot's first input from the
                     # host-side override (the prefill wrote pages, not the
@@ -1665,6 +1841,9 @@ class LLMEngine:
                         # any live request's attention can reach them
                         req.planned_out = len(req.output_ids)
                         self._slot_override[req.slot] = req.output_ids[-1]
+            elif rec["kind"] == "block":
+                self._harvest_block(rec, toks_np, deltas, (
+                    device_start_ns, device_end_ns, exact))
             else:
                 # decode chunk: toks_np is [K, S]
                 k_steps = rec["k"]
@@ -1683,9 +1862,64 @@ class LLMEngine:
                 rec["dispatch_ns"], fetch.start_ns, fetch.end_ns,
                 rec["rows_padded"], rec["tokens_padded"], rec["facts"],
                 rec["k"]) + moe_facts + self._ssm_fields + rec["tail"]
+        if "block" in rec:
+            head += (None,) * (_BLOCK_AT - len(head)) + rec["block"]
         # the stamps come last, whatever fields the model's family wrote
         tracing.record("engine.dispatch", head + (None,) * (
             _STAMPS_AT - len(head)) + stamps)
+
+    def _harvest_block(self, rec: dict, fetched: np.ndarray,
+                       deltas: List[OutputDelta], stamps: tuple) -> None:
+        """A block program's result (stage.py: `_block_program`): each
+        live row's tokens go out in position order as ONE delta with the
+        pass that fixed each; the record gains its three `block_*` fields,
+        behind every other family's."""
+        B, S = self._block, self.config.max_batch
+        ids = fetched[:S * B].reshape(S, B)
+        fixed_at = fetched[S * B:2 * S * B].reshape(S, B)
+        passes = int(fetched[2 * S * B])
+        device_start_ns, device_end_ns, exact = stamps
+        emitted = 0
+        for slot, (rid, start, known) in rec["slots"].items():
+            req = self.requests.get(rid)
+            if (req is None or req.state != RUNNING or req.slot != slot
+                    or len(req.output_ids) != start):
+                continue  # finished/aborted/preempted while in flight
+            if req.first_token_ns is None and device_end_ns is not None:
+                # the first block's program is one of the request's OWN:
+                # its device time is the first token's, not a wait
+                req.prefill_device_ns += device_end_ns - device_start_ns
+                req.parts_exact &= exact
+                req.prefill_end_ns = device_end_ns
+            emitted += self._append_block(
+                req, ids[slot, known:], fixed_at[slot, known:], deltas)
+        tot = self._totals
+        tot["block_passes_total"] += passes
+        tot["block_tokens_total"] += emitted
+        tot["block_early_exits_total"] += passes < rec["k"]
+        rec["block"] = (passes, emitted, B)
+
+    def _append_block(self, req: Request, tokens, fixed_at,
+                      deltas: List[OutputDelta]) -> int:
+        """`_append_token` for a block's tokens, in position order: the
+        first stop token ends the request and what lies right of it in
+        the block is dropped, `max_tokens` cuts a last block. One delta."""
+        new: List[int] = []
+        stop = None
+        for tok in tokens:
+            new.append(int(tok))
+            req.output_ids.append(new[-1])
+            stop = self._stop_reason(req, new[-1])
+            if stop:
+                break
+        if req.first_token_ns is None:
+            req.first_token_ns = tracing.now_ns()
+        if stop:
+            self._finish(req, stop)
+        deltas.append(OutputDelta(
+            req.request_id, new, bool(stop), stop,
+            fixed_pass=[int(p) for p in fixed_at[:len(new)]]))
+        return len(new)
 
     def _split_counts(self, rec: dict, fetched: np.ndarray) -> tuple:
         """(tokens, the record's `moe_*` fields). An expert model's
@@ -1703,7 +1937,7 @@ class LLMEngine:
         # expert a wave touches in two rows counts once: the least a wave
         # has to read)
         tokens = fetched[:-n].reshape({
-            "prefill": (-1,), "spec": (self._wave_rb, -1),
+            "prefill": (-1,), "spec": (self._wave_rb, -1), "block": (-1,),
             "decode": (-1, self.config.max_batch)}[rec["kind"]])
         assignments, touched = int(counts.sum()), int((counts > 0).sum())
         self._totals["moe_assignments_total"] += assignments
@@ -1714,8 +1948,8 @@ class LLMEngine:
         # together (each row pays boundary visits of its own: a floor)
         rows = max(rec["rows_padded"], 1)
         self._totals["moe_tile_rows_total"] += moe_tile_rows(
-            counts, rows if rec["kind"] == "decode"
-            else rec["tokens_padded"] // rows, self.model_cfg)
+            counts, {"decode": rows, "block": rec["tokens_padded"]}.get(
+                rec["kind"], rec["tokens_padded"] // rows), self.model_cfg)
         return tokens, (assignments, touched, int(counts.max()))
 
     def _preempt(self, req: Request) -> None:
@@ -1835,8 +2069,8 @@ class LLMEngine:
         prompt tokens — generated text is rarely shared). ``upto`` bounds
         registration to tokens whose KV has actually been written (a
         chunked prefill registers chunk by chunk as dispatches land)."""
-        if self._ssm_layers:
-            return  # a shared page would carry no recurrent state
+        if self._prefix_off:
+            return  # a shared page could not be reused
         page = self.config.page_size
         n_prompt_full = len(req.prompt_ids) // page
         if upto is not None:
@@ -1890,6 +2124,15 @@ class LLMEngine:
     def _refuse_handoff(self) -> None:
         """The disaggregated prefill -> decode hand-off moves pages only
         (`_gather_kv`, kv_transfer.py)."""
+        if self._block:
+            raise NotImplementedError(
+                f"model {self.config.model!r} generates by diffusion over "
+                f"blocks of {self._block} tokens: the disaggregated "
+                f"prefill/decode hand-off (prefill_only, extract_kv, "
+                f"inject_request) moves KV pages and ONE pending token, "
+                f"and this model's prefill yields no token: what it would "
+                f"hand over is a block's state (ids and which of them are "
+                f"masked), which the blob has no place for")
         if self._ssm_layers:
             raise NotImplementedError(
                 f"model {self.config.model!r} keeps "
@@ -2014,6 +2257,10 @@ class LLMEngine:
 
     # ----------------------------------------------------------- warmup
 
+    def _block_shape_key(self) -> tuple:
+        return (self._block, self.model_cfg.denoising_steps,
+                self.max_pages_per_seq)
+
     def _decode_shape_key(self) -> tuple:
         return (max(1, int(self.config.decode_steps_per_dispatch)),
                 self.max_pages_per_seq)
@@ -2052,6 +2299,8 @@ class LLMEngine:
                               self.config.prefill_buckets[-1] - 1) + 1,
                           self.config.prefill_buckets)
             programs.append(("verify", (sbv, rb)))
+        if self._block:
+            return programs + [("block", self._block_shape_key())]
         return programs + [("decode", self._decode_shape_key())]
 
     def warmup(self, prompt_buckets=None, include_decode=True) -> int:
@@ -2105,6 +2354,8 @@ class LLMEngine:
             if self._lin_layers:
                 out["lin_state_pool_bytes"] = sizes["lin_state"]
                 out["sparse_index_pool_bytes"] = sizes["kc"]
+        if self._block and self._prefix_off:
+            out["prefix_reuse_refused_why"] = self._prefix_off
         if self.sharding is not None:
             out["sharding"] = self.sharding.page_accounting(
                 self.config, self.model_cfg)

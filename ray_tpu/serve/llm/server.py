@@ -107,7 +107,23 @@ _LLM_WORK_TOTALS = {
     "ssm_state_updates_total":
         "live rows x fused steps x state-space layers updated (decode)",
     "prefix_reuse_refused_total":
-        "admissions that skipped the prefix lookup (recurrent state)",
+        "admissions that skipped the prefix lookup (recurrent state, or "
+        "pages that hold no whole number of a block model's blocks)",
+    "block_dispatches_total":
+        "block programs enqueued (a model that generates by diffusion over "
+        "blocks: every denoising pass of a block and the settling one)",
+    "block_passes_total":
+        "forward passes the block programs ran, the settling ones counted",
+    "block_tokens_total":
+        "tokens the block programs' real rows emitted; over "
+        "block_passes_total it is what an operator trades against quality",
+    "block_rows_total": "real rows of the block programs",
+    "block_early_exits_total":
+        "block programs that left before denoising_steps passes: every "
+        "live row's block was settled",
+    "prefill_tokenless_total":
+        "prompts whose prefill ended without a token (a block model's: its "
+        "first token comes out of its first block)",
     "prefill_passes_total": "prefill rows dispatched (a prompt takes the "
                             "passes that cost least: often one)",
     "prefill_resumed_passes_total":
@@ -381,8 +397,16 @@ class LLMServer(EngineDriverMixin):
                        prompt_ids: Optional[List[int]] = None,
                        max_tokens: int = 64, temperature: float = 0.0,
                        top_k: int = 0, seed: Optional[int] = None,
-                       deadline: Optional[float] = None) -> Dict[str, Any]:
+                       deadline: Optional[float] = None,
+                       stream: bool = False) -> Dict[str, Any]:
         """Generate to completion; returns text + token ids + usage.
+        ``stream``: also ``chunks``, one per engine delta that brought
+        tokens, in the order they came: ``token_ids``, their ``text`` and,
+        for a model that generates by diffusion over blocks, the
+        ``fixed_pass`` of each (such a model's delta is one block's tokens
+        in position order; every other model's is one token). A replica
+        materializes a stream (serve/replica.py), so the chunks travel
+        with the result.
         ``deadline`` (absolute, time.time() domain) defaults to the
         Serve request deadline propagated into this replica; the engine
         prunes the request from its WAITING queue if it expires before
@@ -404,6 +428,7 @@ class LLMServer(EngineDriverMixin):
                                 deadline=deadline)
         await self._ensure_driver()
         out_ids: List[int] = []
+        chunks: List[Dict[str, Any]] = []
         finish_reason = None
         ttft = None
         try:
@@ -412,6 +437,11 @@ class LLMServer(EngineDriverMixin):
                 if ttft is None and delta.new_token_ids:
                     ttft = time.time() - t0
                 out_ids.extend(delta.new_token_ids)
+                if stream and delta.new_token_ids:
+                    chunks.append({
+                        "token_ids": list(delta.new_token_ids),
+                        "text": self.tokenizer.decode(delta.new_token_ids),
+                        "fixed_pass": delta.fixed_pass})
                 if delta.finished:
                     finish_reason = delta.finish_reason
                     break
@@ -438,6 +468,7 @@ class LLMServer(EngineDriverMixin):
                       "completion_tokens": len(out_ids),
                       "total_tokens": len(prompt_ids) + len(out_ids)},
             "ttft_s": ttft,
+            **({"chunks": chunks} if stream else {}),
         }
 
     async def check_health(self) -> bool:
@@ -496,6 +527,9 @@ class OpenAIIngress:
             temperature=float(body.get("temperature", 0.0)),
             seed=(int(body["seed"]) if body.get("seed") is not None
                   else None))
+        stream = bool(body.get("stream"))
+        if stream:
+            call_kwargs["stream"] = True
         prefix_hashes = None
         if (self._tokenizer is not None
                 and getattr(self.config, "prefix_routing", True)):
@@ -513,6 +547,9 @@ class OpenAIIngress:
                 method_name="generate", routing_key=prefix_key).remote(
                 prompt, **call_kwargs)
         created = int(time.time())
+        if stream:
+            return self._chunks(out, kind, created,
+                                body.get("model", self.model_id))
         if kind == "chat.completion":
             choice = {"index": 0, "finish_reason": out["finish_reason"],
                       "message": {"role": "assistant",
@@ -528,6 +565,31 @@ class OpenAIIngress:
             "choices": [choice],
             "usage": out["usage"],
         }
+
+
+    def _chunks(self, out: Dict[str, Any], kind: str, created: int,
+                model: str) -> List[Dict[str, Any]]:
+        """`stream: true`: the response as its chunks, one per delta of
+        the engine (a token; a model that generates by diffusion over
+        blocks: one block's tokens, in position order, with the denoising
+        pass that fixed each beside them), the last carrying the finish
+        reason."""
+        cid = f"cmpl-{next(self._ids)}"
+        events = []
+        for i, chunk in enumerate(out["chunks"]):
+            last = i == len(out["chunks"]) - 1
+            choice = {"index": 0,
+                      "finish_reason": out["finish_reason"] if last else None}
+            if kind == "chat.completion":
+                choice["delta"] = {"content": chunk["text"]}
+            else:
+                choice["text"] = chunk["text"]
+            events.append({
+                "id": cid, "object": f"{kind}.chunk", "created": created,
+                "model": model, "choices": [choice],
+                "token_ids": chunk["token_ids"],
+                "fixed_pass": chunk["fixed_pass"]})
+        return events
 
 
 def placement_options(llm_config: LLMConfig) -> Dict[str, Any]:

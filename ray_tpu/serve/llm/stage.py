@@ -43,6 +43,10 @@ OPERANDS = {
     "verify": ("n_rows", "bt", "total", "x", "positions"),
     "decode": ("bt", "total", "caps", "positions", "override_mask", "x",
                "temp", "topk", "keys"),
+    # a model that generates by diffusion over blocks (models/sdar.py):
+    # "total" a row's settled tokens plus its block (0: no row), "x" the
+    # block's ids, "masked" which of them are still to be fixed
+    "block": ("bt", "total", "x", "masked", "temp", "topk", "keys"),
 }
 
 # after OPERANDS["prefill"], for a model that keeps per-slot state: the
@@ -79,11 +83,14 @@ def serve_dtype(config):
 
 def model_family(name: str):
     """The module that defines the preset `name`: the one place that
-    chooses a model family from `EngineConfig.model`. Three families:
+    chooses a model family from `EngineConfig.model`. Four families:
     models/llama.py (every layer attention, dense or experts),
-    models/jamba.py (state-space layers beside attention) and
+    models/jamba.py (state-space layers beside attention),
     models/minicpm_sala.py (linear-attention layers beside block-sparse
-    attention). A family's module has `CONFIGS`, `get_config`,
+    attention) and models/sdar.py (llama's layers under a block mask,
+    generating by diffusion over blocks: its config has `block_length`
+    and the engine steps it with the "block" program). A family's module
+    has `CONFIGS`, `get_config`,
     `serving_model`, `pool_spec`, `serving_cache`, and two flags:
     `RESUMES_PREFILL` (a prefill row continues from what its pages and
     its slot hold, so a prompt may be prefilled in passes; such a family
@@ -94,9 +101,9 @@ def model_family(name: str):
     from and the model computes the head there only). Its config answers
     `n_slot_state_layers` / `slot_state_bytes_row()` where layers keep
     state a decode slot."""
-    from ...models import jamba, llama, minicpm_sala
+    from ...models import jamba, llama, minicpm_sala, sdar
 
-    families = (llama, jamba, minicpm_sala)
+    families = (llama, jamba, minicpm_sala, sdar)
     for family in families:
         if name in family.CONFIGS:
             return family
@@ -200,6 +207,14 @@ def dummy_operands(config, kind: str, shape_key: tuple,
             return z((rows, span))
         return jnp.zeros((rows, span, hidden[0]), hidden[1])
 
+    if kind == "block":
+        block, steps, mp = shape_key
+        S = config.max_batch
+        # no live row: the loop makes no pass and the settling one writes
+        # nothing
+        return (z((S, mp)), z((S,)), z((S, block)), z((S, block), bool),
+                np.zeros((S,), np.float32), np.zeros((S,), np.int32),
+                z((steps, S, 2), np.uint32))
     if kind == "decode":
         k_steps, mp = shape_key
         S = config.max_batch
@@ -373,7 +388,8 @@ class StageCompute:
 
     def program(self, kind: str, shape_key: tuple):
         """The jitted program of one bucketed shape, built once.
-        Keys: prefill (sb, rb, cp), verify (sb, rb), decode (K, mp)."""
+        Keys: prefill (sb, rb, cp), verify (sb, rb), decode (K, mp), block
+        (block_length, denoising_steps, mp)."""
         key = (kind,) + tuple(shape_key)
         fn = self.programs.get(key)
         if fn is None:
@@ -529,9 +545,17 @@ class StageCompute:
             return self._jit(run_prefill if kind == "prefill"
                              else run_verify, kind, n_state=2)
 
+        whole = first and last
+        if kind == "block":
+            if not whole:
+                raise ValueError(
+                    "a block's denoising passes run in one program: the "
+                    "next pass's ids are chosen where the head is")
+            return self._jit(self._block_program(apply, pack, ref_attn),
+                             kind, n_state=2)
+
         # decode: fixed slot-set [S] batch, K fused steps, device-carry ids
         n_steps = shape_key[0]
-        whole = first and last
         if n_steps != 1 and not whole:
             raise ValueError(
                 "a stage of a pipeline decodes one step a dispatch: the "
@@ -586,6 +610,77 @@ class StageCompute:
             return pack(outs, counts), new_slot_ids, kvp
 
         return self._jit(run_decode, kind, n_state=3)
+
+    def _block_program(self, apply, pack, ref_attn):
+        """`run_block`: ALL of one block's forward passes for the full slot
+        set of a model that generates by diffusion over blocks. Every pass
+        writes the block's keys and values to its pages as the block
+        stands (the positions are fixed; a later pass overwrites them) and
+        attends the row's settled context and the whole block
+        (`PagedCache.block_step`); `models/sdar.py: denoise` fixes some
+        masked positions from the logits. The loop leaves when no live row
+        has a masked position (at most `denoising_steps` passes); one more
+        pass over the settled blocks leaves their final keys and values.
+        The block's state between passes is the loop's carry: a block
+        starts from what the host knows (a prompt's tail, or masks) and
+        ends settled, so nothing is carried from program to program but
+        the pool.
+
+        Returns (int32 [S*B tokens | S*B the pass that fixed each, -1
+        where it came fixed | passes run, the settling one counted |
+        an expert model's [steps + 1, L, E] counts, a pass a row], pool).
+        """
+        import jax
+        import jax.numpy as jnp
+
+        from ...models.sdar import denoise
+
+        cfg = self.model_cfg
+        B, steps = cfg.block_length, cfg.denoising_steps
+        serving_cache = self.family.serving_cache
+        moe = cfg.num_experts > 0
+
+        def run_block(params, kv_pages, block_tables, total_lens, x, masked,
+                      temperature, top_k, keys_steps):
+            cache = serving_cache(cfg, kv_pages, block_tables,
+                                  ref_attention=ref_attn, block_step=True)
+            live = (total_lens > 0)[:, None]
+            positions = (jnp.maximum(total_lens - B, 0)[:, None]
+                         + jnp.arange(B, dtype=jnp.int32))
+
+            def forward(ids, kvp):
+                out, new_pc, counts = apply(
+                    params, ids, positions, cache.step(kvp, total_lens),
+                    total_lens)
+                return out, new_pc.pool, counts
+
+            def put(counts, c, at):
+                return (counts if c is None else
+                        jax.lax.dynamic_update_slice_in_dim(
+                            counts, c[None], at, 0))
+
+            def body(carry):
+                step, ids, masked, fixed_at, kvp, counts = carry
+                logits, kvp, c = forward(ids, kvp)
+                ids, masked, fixed = denoise(
+                    logits, ids, masked, step, cfg, temperature, top_k,
+                    keys_steps[step])
+                fixed_at = jnp.where(fixed, step, fixed_at)
+                return (step + 1, ids, masked, fixed_at, kvp,
+                        put(counts, c, step))
+
+            step, ids, _, fixed_at, kvp, counts = jax.lax.while_loop(
+                lambda c: (c[0] < steps) & jnp.any(c[2]), body,
+                (jnp.int32(0), x, masked & live,
+                 jnp.full(x.shape, -1, jnp.int32), kv_pages,
+                 jnp.zeros((steps + 1, self.n_layers, cfg.num_experts),
+                           jnp.int32) if moe else None))
+            _, kvp, c = forward(ids, kvp)
+            kept = jnp.concatenate([ids.reshape(-1), fixed_at.reshape(-1),
+                                    (step + 1)[None]])
+            return pack(kept, put(counts, c, step)), kvp
+
+        return run_block
 
     def _jit(self, fn, kind: str, n_state: int):
         """jit with the state after the params donated: the pool (and the

@@ -174,6 +174,12 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
     # groups and fused steps), the compressed keys scored the same way,
     # and for a prefill a tuple a real row of how many passes of its
     # prompt came before this one and whether it is the last.
+    # The three `block_*` fields are written for a program of kind
+    # "block" only (a model that generates by diffusion over blocks; its
+    # `rows` are (request_id, block_len, ctx_tokens) with the block
+    # counted in the context, its `moe_*` as a decode's, the positions
+    # between hold None): the forward passes the program ran, the settling
+    # one counted; the tokens its real rows emitted; the block length.
     # The last four are the program on the device's timeline, stamped by
     # the host with no profiler (programs run in dispatch order on one
     # stream): `enqueued_ns` when the compute seam returned (`dispatch_ns`
@@ -195,8 +201,8 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         "moe_expert_tokens_max", "ssm_layers", "ssm_state_bytes_row",
         "lin_layers", "lin_state_bytes_row", "sparse_layers",
         "sparse_tokens_read", "sparse_kernels_scored", "pass_index",
-        "final", "enqueued_ns", "device_start_ns", "device_end_ns",
-        "end_exact"),
+        "final", "block_passes", "block_tokens_fixed", "block_len",
+        "enqueued_ns", "device_start_ns", "device_end_ns", "end_exact"),
     # one per LLMEngine.step(); `fetch_blocked`: how many of the step's
     # harvests found their program unfinished (the step waited for the
     # device, not the device for the step), `device_idle_ns`: time the

@@ -623,3 +623,69 @@ def test_sala_program_fits_and_carries_its_pool_in_place(
     header = text.split("\n", 1)[0]
     aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
     assert len(aliased) >= (4 if kind == "decode" else 3), header[:400]
+
+
+# --------- a model that generates by diffusion over blocks, at its cut
+@pytest.mark.parametrize("kind, key", [
+    ("block", None), ("prefill", (2048, 0)), ("prefill", (2048, 200)),
+    ("prefill", (128, 0))])
+def test_sdar_program_fits_and_carries_its_pool_in_place(
+        topo, no_persistent_cache, kind, key):
+    """SDAR-30B-A3B at its published widths, 6 layers with all 128
+    experts, as the cell `sdar-30b-a3b-chat` runs it (64 slots, 12,800
+    pages of 16): the program fits the chip beside its 2.5 GB pool; the
+    block program's attention is the paged-decode kernel given 4 tokens x
+    8 heads a kv head (one call in the loop's pass, one in the settling
+    pass) and its experts the grouped matmul at E = 128; a prefill's is
+    the flash kernel under the block mask; the pool is aliased from
+    argument to result."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    from ray_tpu.serve.llm.stage import init_params
+
+    cfg = EngineConfig(
+        model="sdar-30b-a3b", dtype="bfloat16", page_size=16, num_pages=64,
+        max_model_len=3200, max_batch=64,
+        prefill_buckets=(128, 256, 512, 1024, 2048),
+        model_overrides=dict(num_layers=6,
+                             remasking="low_confidence_static"))
+    engine = LLMEngine(cfg, params={})
+    stage = engine.compute
+    stage.params = jax.eval_shape(lambda: init_params(
+        stage.model, jnp.zeros((1, 8), jnp.int32), jax.random.PRNGKey(0)))
+    shape, dtype = stage.family.pool_spec(stage.model_cfg, 6, 12800, 16, 64)
+    stage.kv_pages = jax.ShapeDtypeStruct(shape, dtype)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(a):
+        a = a if hasattr(a, "shape") else np.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    key = (engine._block_shape_key() if kind == "block"
+           else (key[0], engine._wave_rb, key[1]))
+    assert key == ((4, 4, 200) if kind == "block" else key)
+    with pytest.MonkeyPatch.context() as mp_ctx:
+        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = stage.program(kind, key).lower(*jax.tree.map(
+            sds, (*stage._state(kind), *stage.dummy_args(kind, key)),
+            is_leaf=lambda a: isinstance(a, jax.ShapeDtypeStruct))
+        ).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # 8.72 GB of weights + 2.52 GB of pages, under the chip's 15.75 GiB
+    assert 10.4 * 2 ** 30 <= total <= HBM_GIB * 2 ** 30, total / 2 ** 30
+    kernels = set(re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
+    names = sorted({k.split(".")[0] for k in kernels})
+    if kind == "block":
+        assert names == ["_decode_call", "_moe_gmm"], kernels
+        assert sum(k.startswith("_decode_call") for k in kernels) == 2
+        assert sum(k.startswith("_moe_gmm") for k in kernels) == 4
+    else:
+        assert names == ["_moe_gmm", "attn"], kernels
+        # a resumed pass: the own-tokens part and the part over its pages
+        assert sum(k.startswith("attn") for k in kernels) == (
+            2 if key[2] else 1)
+    header = text.split("\n", 1)[0]
+    aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliased) >= 1, header[:400]
+    assert tuple(stage.kv_pages.shape) == (6, 12800, 4, 16, 256)

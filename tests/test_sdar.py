@@ -1,0 +1,595 @@
+"""SDAR (models/sdar.py): the Llama family's layers under a block mask,
+generation by diffusion over blocks through the paged engine, against the
+plain reference (chipbench/references/sdar_decoder.py): seeded weights in
+float32, tiny sizes, on the CPU. The tiny preset has 16 experts of which 4
+a token, an expert width that is not the dense one's, head-dim norms on q
+and k, blocks of 4."""
+
+import hashlib
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import weights
+from chipbench.references import sdar_decoder as reference
+from ray_tpu.models import sdar
+from ray_tpu.ops.attention import reference_attention
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.paged_attention import (paged_attention_block,
+                                         paged_attention_reference)
+from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.serve.llm.stage import init_params, model_family
+from ray_tpu.util import tracing
+
+B = 4
+CFG = dict(model="tiny-sdar", page_size=16, num_pages=64, max_model_len=256,
+           max_batch=4, prefill_buckets=(32, 64, 128), dtype="float32")
+RULES = ("low_confidence_static", "sequential", "low_confidence_dynamic")
+# remainders 3, 0, 1, 2, 3 by blocks of 4; the first is shorter than a block
+PROMPT_LENS = (3, 8, 41, 42, 43)
+
+
+def _model_cfg(**over):
+    return sdar.get_config("tiny-sdar", scan_layers=True, remat=False,
+                           dtype=jnp.float32, param_dtype=jnp.float32, **over)
+
+
+def _params(seed=7, head_gain=1.0):
+    """The benchmark's seeded weights in the tiny preset's tree. The head
+    times `head_gain`: at 200 every position's softmax is peaked (a
+    "confident" model)."""
+    cfg = _model_cfg()
+    probe = jax.eval_shape(lambda: init_params(
+        sdar.serving_model(cfg), jnp.zeros((1, 8), jnp.int32),
+        jax.random.PRNGKey(0)))
+    params = weights.make_params(probe, seed)
+    params["lm_head"]["kernel"] = params["lm_head"]["kernel"] * head_gain
+    return params
+
+
+def _ref_cfg(model_cfg):
+    c = model_cfg
+    return dict(
+        num_attention_heads=c.num_heads, num_key_value_heads=c.num_kv_heads,
+        head_dim=c.head_dim_, hidden_size=c.hidden_size,
+        moe_intermediate_size=c.moe_intermediate_size,
+        num_experts_per_tok=c.num_experts_per_tok,
+        rms_norm_eps=c.rms_norm_eps, rope_theta=c.rope_theta,
+        block_length=c.block_length, denoising_steps=c.denoising_steps,
+        remasking=c.remasking, confidence_threshold=c.confidence_threshold,
+        mask_token_id=c.mask_token_id)
+
+
+def _engine(rule="low_confidence_static", head_gain=1.0, **over):
+    cfg = {**CFG, **over}
+    cfg["model_overrides"] = {"remasking": rule,
+                              **over.get("model_overrides", {})}
+    return LLMEngine(EngineConfig(**cfg), params=_params(
+        head_gain=head_gain))
+
+
+def _run(engine, max_steps=2000):
+    deltas = []
+    for _ in range(max_steps):
+        if not engine.has_work():
+            return deltas
+        deltas.extend(engine.step())
+    raise AssertionError("the engine did not finish")
+
+
+def _by_request(deltas):
+    out = {}
+    for d in deltas:
+        got = out.setdefault(d.request_id, dict(tokens=[], passes=[],
+                                                blocks=[], reason=None))
+        if d.new_token_ids:
+            got["tokens"] += d.new_token_ids
+            got["passes"] += d.fixed_pass
+            got["blocks"].append(list(d.new_token_ids))
+        if d.finished:
+            got["reason"] = d.finish_reason
+    return out
+
+
+def _prompts(lens=PROMPT_LENS, seed=3):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 255, n).tolist() for n in lens]
+
+
+def _reference_generate(engine, prompt, max_tokens, stop_ids=()):
+    w = reference.weights_from_program_tree(engine.params)
+    cfg = _ref_cfg(engine.model_cfg)
+    return reference.generate(w, prompt, cfg, max_tokens, stop_ids)
+
+
+# ------------------------------------------------------------------ model
+@pytest.mark.parametrize("s", [16, 50])
+def test_forward_under_the_block_mask_is_the_references(s):
+    cfg, params = _model_cfg(), _params()
+    ids = jnp.asarray(np.random.default_rng(s).integers(0, 255, (2, s)),
+                      jnp.int32)
+    got = sdar.serving_model(cfg).apply({"params": params}, ids)
+    want = reference.forward(reference.weights_from_program_tree(params),
+                             ids, _ref_cfg(cfg))
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+    # and it is not the causal function: a block's first token sees its
+    # last
+    causal = sdar.serving_model(_model_cfg(block_length=1)).apply(
+        {"params": params}, ids)
+    assert float(jnp.abs(causal - got).max()) > 1e-2
+
+
+def test_the_family_is_found_by_its_presets_and_keeps_llamas_layers():
+    assert model_family("tiny-sdar") is sdar
+    assert model_family("sdar-30b-a3b") is sdar
+    cfg = sdar.get_config("sdar-30b-a3b")
+    assert (cfg.block_causal, cfg.expert_width, cfg.qk_norm) == (4, 768,
+                                                                 True)
+    # 48 layers of 128 x 3 x 2048 x 768 experts: 30.5 B, of which 3.35 B a
+    # token
+    assert round(cfg.num_params() / 1e9, 1) == 30.5
+    assert round(cfg.active_params() / 1e9, 2) == 3.35
+    assert sdar.transfer_schedule(4, 4) == (1, 1, 1, 1)
+    assert sdar.transfer_schedule(8, 3) == (3, 3, 2)
+    with pytest.raises(ValueError, match="remasking"):
+        sdar.get_config("tiny-sdar", remasking="random")
+
+
+# ----------------------------------------------------------------- kernels
+def _qkv(sq, sk, seed=0, b=2, hq=4, hkv=2, d=64):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (b, sq, hq, d), jnp.float32),
+            jax.random.normal(ks[1], (b, sk, hkv, d), jnp.float32),
+            jax.random.normal(ks[2], (b, sk, hkv, d), jnp.float32))
+
+
+@pytest.mark.parametrize("sq, sk, block, lens", [
+    (128, 128, 4, None), (256, 256, 16, None), (128, 384, 4, None),
+    (256, 256, 4, ([130, 256], None))])
+def test_flash_forward_block_mask_in_interpret_mode(sq, sk, block, lens):
+    q, k, v = _qkv(sq, sk)
+    kw = {}
+    if lens:
+        kw["q_lens"] = jnp.asarray(lens[0], jnp.int32)
+    o, lse = flash_attention(q, k, v, causal=True, return_lse=True,
+                             block_causal=block, interpret=True,
+                             block_q=128, block_k=128, **kw)
+    want = reference_attention(q, k, v, causal=True, block_causal=block)
+    for i in range(q.shape[0]):
+        n = lens[0][i] if lens else sq
+        np.testing.assert_allclose(o[i, :n], want[i, :n], atol=2e-5,
+                                   rtol=2e-5)
+    # a block's first query sees the block's last key: not causal
+    plain = reference_attention(q, k, v, causal=True)
+    assert float(jnp.abs(plain - want).max()) > 1e-2
+
+
+def test_block_causal_zero_is_the_call_it_was_and_misuse_is_refused():
+    q, k, v = _qkv(128, 128)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, return_lse=True, interpret=False, **kw))(
+                q, k, v))
+
+    assert text() == text(block_causal=0)
+    assert text() != text(block_causal=4)
+    with pytest.raises(ValueError, match="block_causal"):
+        flash_attention(q, k, v, causal=True, block_causal=4)  # backward
+    with pytest.raises(ValueError, match="block_causal"):
+        flash_attention(q, k, v, causal=True, return_lse=True,
+                        block_causal=48)                       # 128 % 48
+
+
+@pytest.mark.parametrize("impl", ["reference", "kernel (interpret)"])
+def test_block_step_attention_sees_every_key_of_the_row(impl):
+    """[S, B] queries against `lengths` keys, the block's own included,
+    no mask between them, against plain softmax over the gathered keys."""
+    rng = np.random.default_rng(1)
+    hq, hkv, d, page, mp, rows = 4, 2, 64, 16, 4, 3
+    pool = jnp.asarray(rng.normal(size=(2, 1 + rows * mp, hkv, page, 2 * d)),
+                       jnp.float32)
+    bt = jnp.asarray(1 + np.arange(rows * mp).reshape(rows, mp), jnp.int32)
+    lengths = jnp.asarray([B, 37, 0], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(rows, B, hq, d)), jnp.float32)
+    got = paged_attention_block(
+        q, pool, bt, lengths, layer=1,
+        **({"interpret": True} if "kernel" in impl
+           else {"force_reference": True}))
+    # every query sits at the row's last position for the oracle
+    want = paged_attention_reference(
+        q, pool, bt, jnp.broadcast_to(jnp.maximum(lengths - 1, 0)[:, None],
+                                      (rows, B)), layer=1)
+    np.testing.assert_allclose(got[:2], want[:2], atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got[2]).any()       # an inactive row: zeros
+
+
+# ------------------------------------------------------------------ engine
+@pytest.fixture(scope="module")
+def generated():
+    """{rule: (engine's outputs by request, the reference's)} for
+    PROMPT_LENS, all requests of a rule in one batch. `dynamic` runs on a
+    confident head (x200): several tokens a pass and an early exit."""
+    out = {}
+    for rule in RULES:
+        gain = 200.0 if rule == "low_confidence_dynamic" else 1.0
+        engine = _engine(rule, head_gain=gain)
+        engine.warmup()
+        built = engine.stats()["programs_built_total"]
+        for i, p in enumerate(_prompts()):
+            engine.add_request(f"r{i}", p, SamplingParams(max_tokens=13))
+        got = _by_request(_run(engine))
+        want = [_reference_generate(engine, p, 13) for p in _prompts()]
+        out[rule] = (got, want, engine.stats(), built)
+        engine.close()
+    return out
+
+
+@pytest.mark.parametrize("n", PROMPT_LENS)
+@pytest.mark.parametrize("rule", RULES)
+def test_engine_emits_the_references_tokens_in_its_order(generated, rule, n):
+    got, want, _, _ = generated[rule]
+    i = PROMPT_LENS.index(n)
+    tokens, passes = want[i]
+    assert got[f"r{i}"]["tokens"] == tokens
+    assert got[f"r{i}"]["passes"] == passes
+    assert got[f"r{i}"]["reason"] == "length"
+    assert len(tokens) == 13
+    # a block's tokens are one delta, in position order: the first block
+    # holds what the prompt's tail left of it
+    first = B - n % B
+    assert [len(b) for b in got[f"r{i}"]["blocks"]][:2] == [first, B]
+
+
+@pytest.mark.parametrize("rule", RULES)
+def test_rules_differ_in_the_order_and_dynamic_leaves_early(generated, rule):
+    got, _, stats, built = generated[rule]
+    passes = [p for r in got.values() for p in r["passes"]]
+    assert stats["programs_built_total"] == built     # nothing under traffic
+    if rule == "sequential":
+        # left to right: a block's passes ascend
+        assert all(sorted(b) == b for b in (
+            got["r1"]["passes"][:4], got["r1"]["passes"][4:8]))
+    if rule == "low_confidence_static":
+        assert any(sorted(got[r]["passes"][4:8]) != got[r]["passes"][4:8]
+                   for r in got)                      # confidence's order
+    # the seeded model does not repeat itself: order errors would show
+    assert len({t for r in got.values() for t in r["tokens"]}) > 10
+    per_pass = stats["block_tokens_total"] / stats["block_passes_total"]
+    if rule == "low_confidence_dynamic":
+        # a confident head fixes most of a block in one pass and the
+        # program leaves before `denoising_steps`
+        assert passes.count(0) > len(passes) // 2 and max(passes) < 3
+        assert stats["block_early_exits_total"] == \
+            stats["block_dispatches_total"]
+        static = generated["low_confidence_static"][2]
+        assert per_pass > 1.5 * (static["block_tokens_total"]
+                                 / static["block_passes_total"])
+    else:
+        # a token a row and pass, and a settling pass a block
+        assert stats["block_early_exits_total"] <= 1
+        assert set(passes) == {0, 1, 2, 3}
+
+
+@pytest.mark.parametrize("max_tokens", [1, 5, 6, 7])
+def test_max_tokens_cuts_a_last_block(max_tokens):
+    engine = _engine()
+    prompt = _prompts((42,))[0]
+    engine.add_request("r", prompt, SamplingParams(max_tokens=max_tokens))
+    got = _by_request(_run(engine))["r"]
+    want, _ = _reference_generate(engine, prompt, max_tokens)
+    assert got["tokens"] == want and len(want) == max_tokens
+    assert got["reason"] == "length"
+    engine.close()
+
+
+def test_the_first_block_is_enqueued_right_behind_its_prefill():
+    """The prefill yields no token the first block would wait for, and
+    programs run in dispatch order: the step that enqueues a prompt's last
+    pass enqueues its first block too (no harvest between them)."""
+    engine = _engine()
+    prompt = _prompts((42,))[0]
+    engine.add_request("r", prompt, SamplingParams(max_tokens=8))
+    engine.step()
+    st = engine.stats()
+    assert (st["prefill_dispatches_total"],
+            st["block_dispatches_total"]) == (1, 1)
+    got = _by_request(_run(engine))["r"]
+    assert got["tokens"] == _reference_generate(engine, prompt, 8)[0]
+    assert engine.stats()["prefill_tokenless_total"] == 1
+    engine.close()
+
+
+@pytest.mark.parametrize("which", [1, 5, 8])
+def test_a_stop_token_inside_a_block_ends_the_request_there(which):
+    """The `which`-th token of the free run is made a stop token: it and
+    what lies left of it in its block are emitted, the rest dropped."""
+    engine = _engine()
+    prompt = _prompts((41,))[0]
+    free, _ = _reference_generate(engine, prompt, 12)
+    stop = free[which]
+    cut = free.index(stop) + 1
+    engine.add_request("r", prompt, SamplingParams(
+        max_tokens=12, stop_token_ids=(stop,)))
+    got = _by_request(_run(engine))["r"]
+    assert got["tokens"] == free[:cut]
+    assert got["reason"] == "stop"
+    want, _ = _reference_generate(engine, prompt, 12, (stop,))
+    assert got["tokens"] == want
+    assert engine.stats()["free_pages"] == CFG["num_pages"] - 1
+    engine.close()
+
+
+def test_a_preempted_request_refills_and_goes_on_to_the_same_tokens():
+    """Pages for two 40-token answers do not fit: one request is preempted
+    mid-answer, its output folded into its prompt (whole blocks from
+    position 0), and ends with the tokens it would have had alone."""
+    engine = _engine(num_pages=8, max_model_len=96, max_batch=2,
+                     prefill_buckets=(32, 64))
+    prompts = _prompts((30, 33), seed=5)
+    for i, p in enumerate(prompts):
+        engine.add_request(f"r{i}", p, SamplingParams(max_tokens=40))
+    got = _by_request(_run(engine))
+    assert engine.stats()["preempted_total"] >= 1
+    for i, p in enumerate(prompts):
+        assert got[f"r{i}"]["tokens"] == _reference_generate(
+            engine, p, 40)[0], i
+    reqs = [dict(zip(tracing.FIELDS["engine.request"], r))
+            for r in tracing.records("engine.request")[-2:]]
+    assert sorted(r["output_tokens"] for r in reqs) == [40, 40]
+    engine.close()
+
+
+def test_prefix_pages_are_reused_where_a_page_holds_whole_blocks():
+    engine = _engine()
+    shared = _prompts((48,), seed=11)[0]
+    tails = _prompts((5, 7), seed=12)
+    outs = []
+    for i, tail in enumerate(tails):
+        engine.add_request(f"r{i}", shared + tail,
+                           SamplingParams(max_tokens=8))
+        outs.append(_by_request(_run(engine))[f"r{i}"]["tokens"])
+    st = engine.stats()
+    assert st["prefix_token_hits"] == 48          # three pages of 16
+    assert "prefix_reuse_refused_total" not in st
+    for tail, got in zip(tails, outs):
+        assert got == _reference_generate(engine, shared + tail, 8)[0]
+    engine.close()
+
+
+def test_prefix_reuse_is_refused_by_name_where_a_page_cuts_a_block():
+    engine = _engine(page_size=6, max_model_len=96,
+                     prefill_buckets=(12, 24, 48, 96))
+    shared = _prompts((48,), seed=11)[0]
+    for i, tail in enumerate(_prompts((5, 7), seed=12)):
+        engine.add_request(f"r{i}", shared + tail,
+                           SamplingParams(max_tokens=8))
+        got = _by_request(_run(engine))[f"r{i}"]["tokens"]
+        assert got == _reference_generate(engine, shared + tail, 8)[0]
+    st = engine.stats()
+    assert st["prefix_token_hits"] == 0
+    assert st["prefix_reuse_refused_total"] == 2
+    assert "page_size 6 holds no whole number of blocks of 4" in st[
+        "prefix_reuse_refused_why"]
+    engine.close()
+
+
+def test_a_split_prompt_starts_its_passes_on_block_boundaries():
+    """plan_passes' full passes are multiples of page and block: a prompt
+    split in two (PassCost forced) gives the reference's tokens."""
+    from ray_tpu.serve.llm.engine import PassCost
+
+    engine = _engine()
+    engine._pass_cost = PassCost(4, 0.0)
+    prompt = _prompts((70,), seed=9)[0]
+    engine.add_request("r", prompt, SamplingParams(max_tokens=6))
+    got = _by_request(_run(engine))["r"]["tokens"]
+    assert engine.stats()["prefill_split_prompts_total"] == 1
+    assert got == _reference_generate(engine, prompt, 6)[0]
+    engine.close()
+
+
+@pytest.mark.parametrize("over, what", [
+    (dict(spec_lookahead=4), "spec_lookahead=4"),
+    (dict(tp=2), "tensor parallelism"),
+    (dict(pp=2), "pipeline parallelism"),
+    (dict(max_model_len=250), "whole blocks")])
+def test_options_built_on_one_token_a_step_are_refused_by_name(over, what):
+    with pytest.raises((NotImplementedError, ValueError), match=what) as e:
+        LLMEngine(EngineConfig(**{**CFG, **over}))
+    assert "diffusion over blocks of 4" in str(e.value)
+
+
+def test_the_hand_off_is_refused_by_name():
+    engine = _engine()
+    for call in (
+            lambda: engine.add_request("r", [1, 2, 3], SamplingParams(
+                prefill_only=True)),
+            lambda: engine.extract_kv("r"),
+            lambda: engine.inject_request("r", {})):
+        with pytest.raises(NotImplementedError, match="hand-off") as e:
+            call()
+        assert "a block's state" in str(e.value)
+    engine.close()
+
+
+def test_counters_records_and_the_first_tokens_three_parts():
+    engine = _engine()
+    n0 = {k: tracing.appended(k) for k in ("engine.dispatch",
+                                           "engine.request")}
+    before = engine.stats()
+    for i, p in enumerate(_prompts((3, 41, 64))):
+        engine.add_request(f"c{i}", p, SamplingParams(max_tokens=9))
+    got = _by_request(_run(engine))
+    after = engine.stats()
+    moved = {k: after[k] - before[k] for k in after if k.endswith("_total")}
+    fields = tracing.FIELDS["engine.dispatch"]
+    recs = [dict(zip(fields, r)) for r in tracing.records(
+        "engine.dispatch", since=n0["engine.dispatch"])]
+    blocks = [r for r in recs if r["kind"] == "block"]
+    assert {r["kind"] for r in recs} == {"prefill", "block"}
+    assert moved["block_dispatches_total"] == len(blocks)
+    assert moved["block_passes_total"] == sum(
+        r["block_passes"] for r in blocks)
+    assert moved["block_tokens_total"] == sum(
+        r["block_tokens_fixed"] for r in blocks) == 27
+    assert moved["block_rows_total"] == sum(len(r["rows"]) for r in blocks)
+    # the 3-token prompt has no whole block: two prefills end tokenless
+    assert moved["prefill_tokenless_total"] == 2
+    assert moved["decode_dispatches_total"] == 0
+    for r in blocks:
+        assert r["block_len"] == B and r["k"] == 5
+        assert 2 <= r["block_passes"] <= 5
+        assert all(q == B and ctx % B == 0 for _, q, ctx in r["rows"])
+        # experts: every real token of every pass, 4 experts in 2 layers
+        assert r["moe_assignments"] == (
+            r["block_passes"] * len(r["rows"]) * B * 4 * 2)
+        assert r["enqueued_ns"] <= r["device_start_ns"] <= r["device_end_ns"]
+    prefills = [r for r in recs if r["kind"] == "prefill"]
+    assert all(r["block_passes"] is None for r in prefills)
+    assert fields.index("block_passes") == fields.index("final") + 1
+    assert fields.index("enqueued_ns") == fields.index("block_len") + 1
+    reqs = {r[0]: dict(zip(tracing.FIELDS["engine.request"], r))
+            for r in tracing.records("engine.request",
+                                     since=n0["engine.request"])}
+    for i in range(3):
+        r = reqs[f"c{i}"]
+        assert r["output_tokens"] == 9 == len(got[f"c{i}"]["tokens"])
+        assert (r["device_wait_ns"] + r["prefill_device_ns"]
+                + r["harvest_host_ns"]
+                == r["first_token_ns"] - r["dispatched_ns"])
+        # its own programs: its prefill (none for c0) and its first block
+        mine = [d for d in recs if any(row[0] == f"c{i}"
+                                       for row in d["rows"])]
+        own = [d for d in mine if d["kind"] == "prefill"] + [
+            d for d in mine if d["kind"] == "block"][:1]
+        assert r["prefill_device_ns"] == sum(
+            d["device_end_ns"] - d["device_start_ns"] for d in own)
+    # the span lies inside the step record's dispatch_decode
+    from ray_tpu.serve.llm import server
+
+    assert "block_tokens_total" in server._LLM_WORK_TOTALS
+    engine.close()
+
+
+def test_sampled_rows_are_seeded_and_greedy_rows_unmoved_beside_them():
+    prompt = _prompts((41,))[0]
+    outs = []
+    for _ in range(2):
+        engine = _engine()
+        engine.add_request("g", prompt, SamplingParams(max_tokens=8))
+        engine.add_request("s", prompt, SamplingParams(
+            max_tokens=8, temperature=1.0, top_k=8, seed=5))
+        outs.append(_by_request(_run(engine)))
+        greedy = _reference_generate(engine, prompt, 8)[0]
+        engine.close()
+    assert outs[0]["g"]["tokens"] == outs[1]["g"]["tokens"] == greedy
+    assert outs[0]["s"]["tokens"] == outs[1]["s"]["tokens"] != greedy
+
+
+# ---------------------------------------------------------------- the server
+def test_the_openai_stream_sends_a_block_as_one_chunk():
+    """The ingress over an in-process server with the tiny preset:
+    `stream: true` gives one chunk an engine delta, and a block model's
+    delta is a block's tokens in position order."""
+    import asyncio
+
+    from ray_tpu.serve.llm import LLMConfig
+    from ray_tpu.serve.llm.server import LLMServer, OpenAIIngress
+    from ray_tpu.serve.replica import Request
+
+    config = LLMConfig(model_id="tiny-sdar", engine=EngineConfig(**{
+        **CFG, "model_overrides": {"vocab_size": 512,
+                                   "mask_token_id": 511}}))
+    server = LLMServer.func_or_class(config)
+
+    class Handle:
+        def options(self, **_):
+            return self
+
+        def remote(self, *args, **kw):
+            return server.generate(*args, **kw)
+
+    ingress = OpenAIIngress.func_or_class(Handle(), "tiny-sdar", config)
+
+    async def ask(stream):
+        body = json.dumps({
+            "model": "tiny-sdar", "max_tokens": 10, "stream": stream,
+            "messages": [{"role": "user", "content": "hello there"}],
+        }).encode()
+        return await ingress(Request(
+            method="POST", path="/v1/chat/completions", body=body))
+
+    async def both():
+        try:
+            return await ask(False), await ask(True)
+        finally:
+            await server.shutdown()
+
+    whole, chunks = asyncio.run(both())
+    assert whole["usage"]["completion_tokens"] == 10
+    assert all(c["object"] == "chat.completion.chunk" for c in chunks)
+    sizes = [len(c["token_ids"]) for c in chunks]
+    assert sum(sizes) == 10 and max(sizes) == B and len(chunks) <= 4
+    assert [c["choices"][0]["finish_reason"] for c in chunks] == (
+        [None] * (len(chunks) - 1) + ["length"])
+    assert "".join(c["choices"][0]["delta"]["content"] for c in chunks) \
+        == whole["choices"][0]["message"]["content"]
+    assert all(len(c["fixed_pass"]) == len(c["token_ids"]) for c in chunks)
+
+
+# --------------------------------------------- every other family, unchanged
+# read on the parent commit (PR 39's tree): the lowered text of every
+# serving program of the four tiny presets, float32, CPU
+PROGRAM_SHAS = {
+    "tiny:prefill:(32, 2, 0)": "076203e0a6010b27",
+    "tiny:prefill:(32, 2, 16)": "560013caed9231dc",
+    "tiny:prefill:(64, 2, 0)": "fef477afc2926dce",
+    "tiny:prefill:(64, 2, 16)": "5262db6ff2c4172a",
+    "tiny:prefill:(128, 2, 0)": "2a5312ba57cbfe13",
+    "tiny:prefill:(128, 2, 16)": "908416dc83f08d65",
+    "tiny:decode:(1, 16)": "ae36d16f69da070c",
+    "tiny:verify:(32, 2)": "bbfb32dae0dd51a5",
+    "tiny-moe:prefill:(32, 2, 0)": "74bbdbf02c06f17c",
+    "tiny-moe:prefill:(32, 2, 16)": "03e9b23ef88c6ee1",
+    "tiny-moe:prefill:(64, 2, 0)": "e645fc06b5f1493e",
+    "tiny-moe:prefill:(64, 2, 16)": "3503bbfef472b65e",
+    "tiny-moe:prefill:(128, 2, 0)": "7b6cd19a4e346713",
+    "tiny-moe:prefill:(128, 2, 16)": "339bc0411046ad03",
+    "tiny-moe:decode:(1, 16)": "79d32007067191b6",
+    "tiny-moe:verify:(32, 2)": "166f34b8e25449e5",
+    "tiny-jamba:prefill:(32, 2, 0)": "1416eab2013c1e6e",
+    "tiny-jamba:prefill:(64, 2, 0)": "91210e061dcc755d",
+    "tiny-jamba:prefill:(128, 2, 0)": "743c37d954de7ca1",
+    "tiny-jamba:decode:(1, 16)": "84c93c353816d183",
+    "tiny-sala:prefill:(32, 2, 0)": "bb8c5778b4a573d8",
+    "tiny-sala:prefill:(32, 2, 16)": "c91019f36e645b5f",
+    "tiny-sala:prefill:(64, 2, 0)": "de8d4133ca69e415",
+    "tiny-sala:prefill:(64, 2, 16)": "0b3f1ae7ee03a273",
+    "tiny-sala:prefill:(128, 2, 0)": "626d3e7028d88025",
+    "tiny-sala:decode:(1, 16)": "89289881804dbbd2",
+}
+
+
+@pytest.fixture(scope="module")
+def program_shas():
+    out = {}
+    for preset in ("tiny", "tiny-moe", "tiny-jamba", "tiny-sala"):
+        engine = LLMEngine(EngineConfig(**{**CFG, "model": preset}))
+        programs = engine._warmup_programs(None, True)
+        if preset in ("tiny", "tiny-moe"):
+            programs.append(("verify", (32, engine._wave_rb)))
+        for kind, key in programs:
+            out[f"{preset}:{kind}:{key}"] = hashlib.sha256(
+                engine.program_text(kind, key).encode()).hexdigest()[:16]
+        engine.close()
+    return out
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAM_SHAS))
+def test_other_families_programs_keep_their_lowered_text(program_shas,
+                                                         program):
+    assert program_shas[program] == PROGRAM_SHAS[program]
