@@ -189,7 +189,8 @@ class PagedCache:
     # STATIC: the new tokens are one block of a diffusion model in a
     # denoising pass (serve/llm/stage.py, kind "block"): they are written
     # to their pages and ALL attend all of `total_lens`, themselves
-    # included, with no mask between them
+    # included, with no mask between them; or two blocks, the row's
+    # pending one first (`_block_step`), and the logits are the last's
     block_step: bool = struct.field(pytree_node=False, default=False)
 
     # what a serving program carries from dispatch to dispatch, and how a
@@ -201,6 +202,34 @@ class PagedCache:
     def step(self, pool, total_lens):
         return self.replace(kv_pages=pool, total_lens=jnp.broadcast_to(
             total_lens, self.block_tables.shape[:1] + total_lens.shape))
+
+
+def _block_step(q, k, v, positions, pc: PagedCache, block: int):
+    """A denoising pass's write and attention (`PagedCache.block_step`):
+    the new tokens are whole blocks of `block`, contiguous each. A block's
+    keys go to its pages (this pass's; the next overwrites them) and its
+    queries see their row up to the block's own end, with no mask inside
+    it. One block (every pass but a program's first, and the benchmark's
+    check): it ends the row and sees all of `total_lens`. Two (the pass
+    that opens a block, serve/llm/stage.py `_block_program`): the row's
+    pending block beside the new one; one that lies past `total_lens` is
+    padding, written nowhere and given no key. One read of a row's pages
+    a block: the kernel takes one length a row.
+    -> (out [B, S, Hq, D], the pool)"""
+    s = q.shape[1]
+    kv_pages, outs = pc.kv_pages, []
+    for cut in (slice(lo, lo + block) for lo in range(0, s, block)):
+        kv_pages = paged_write(kv_pages, k[:, cut], v[:, cut],
+                               pc.block_tables, positions[:, cut],
+                               pc.total_lens, pc.layer)
+        lengths = pc.total_lens
+        if cut.stop < s:
+            end = positions[:, cut.stop - 1] + 1
+            lengths = jnp.where(end <= lengths, end, 0)
+        outs.append(paged_attention_block(
+            q[:, cut], kv_pages, pc.block_tables, lengths, layer=pc.layer,
+            force_reference=pc.ref_attention))
+    return jnp.concatenate(outs, axis=1), kv_pages
 
 
 class Attention(nn.Module):
@@ -237,26 +266,23 @@ class Attention(nn.Module):
             # (causal flash, no page reads) merged with the cached prefix
             # by log-sum-exp.
             pc = kv_cache
-            kv_pages = paged_write(pc.kv_pages, k, v, pc.block_tables,
-                                   positions, pc.total_lens, pc.layer)
             if pc.block_step:
-                # a denoising pass: the block's keys are in its pages (this
-                # pass's; the next overwrites them) and every query of the
-                # block sees all of `total_lens`
-                out = paged_attention_block(
-                    q, kv_pages, pc.block_tables, pc.total_lens,
-                    layer=pc.layer, force_reference=pc.ref_attention)
-            elif s == 1:
-                out = paged_attention_decode(
-                    q[:, 0], kv_pages, pc.block_tables, pc.total_lens,
-                    layer=pc.layer,
-                    force_reference=pc.ref_attention)[:, None]
+                out, kv_pages = _block_step(q, k, v, positions, pc,
+                                            cfg.block_causal or s)
             else:
-                out = paged_prefill_attention(
-                    q, k, v, kv_pages, pc.block_tables, positions,
-                    pc.total_lens, ctx_pages=pc.ctx_pages,
-                    impl="reference" if pc.ref_attention else None,
-                    layer=pc.layer, block_causal=cfg.block_causal)
+                kv_pages = paged_write(pc.kv_pages, k, v, pc.block_tables,
+                                       positions, pc.total_lens, pc.layer)
+                if s == 1:
+                    out = paged_attention_decode(
+                        q[:, 0], kv_pages, pc.block_tables, pc.total_lens,
+                        layer=pc.layer,
+                        force_reference=pc.ref_attention)[:, None]
+                else:
+                    out = paged_prefill_attention(
+                        q, k, v, kv_pages, pc.block_tables, positions,
+                        pc.total_lens, ctx_pages=pc.ctx_pages,
+                        impl="reference" if pc.ref_attention else None,
+                        layer=pc.layer, block_causal=cfg.block_causal)
             new_cache = pc.replace(kv_pages=kv_pages)
         else:
             if kv_cache is not None:
@@ -744,6 +770,10 @@ class LlamaModel(nn.Module):
 
         if not self.last:
             return (x, new_caches) if kv_caches is not None else x
+        if isinstance(kv_caches, PagedCache) and kv_caches.block_step:
+            # a denoising pass decides the row's LAST block only: a pending
+            # block left of it (`_block_step`) is there for its keys
+            x = x[:, -(cfg.block_causal or x.shape[1]):]
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
         head = nn.DenseGeneral(
             features=cfg.vocab_size, use_bias=False, axis=-1,

@@ -8,11 +8,14 @@ position 0, and a token attends every token of its own and of earlier
 blocks (`LlamaConfig.block_causal`). What is new is how it generates
 (the family's `block_diffusion_generate`): a block starts as [MASK] ids
 and is denoised in at most `denoising_steps` forward passes, each of which
-FIXES the masked positions the model is surest of; then one more pass
-writes the settled block's keys and values and the next block begins. The
-logits at a masked position predict that position's OWN token (no shift by
-one). A prompt's whole blocks are prefilled and yield no token; its ragged
-tail opens the first block.
+FIXES the masked positions the model is surest of; the settled block's keys
+and values are then written and the next block begins. The family's script
+spends a forward of its own on that; the serving program writes them in the
+pass that opens the next block, beside it (serve/llm/stage.py
+`_block_program`: the same ids at the same positions over the same
+context, so the same keys). The logits at a masked position predict that
+position's OWN token (no shift by one). A prompt's whole blocks are
+prefilled and yield no token; its ragged tail opens the first block.
 
 This module holds what the method adds to the Llama family: the config's
 generation settings (they are the MODEL's, no scheduling knob), the presets,

@@ -193,6 +193,13 @@ class Request:
     # device carry is not updated by verify, so no other decode dispatch
     # may touch the slot until the harvest resolves acceptance
     spec_inflight: bool = False
+    # a model that generates by diffusion over blocks. `block_pending`: a
+    # block of this request went out from its slot (the slot's carry holds
+    # or will hold it: the next block's program opens over it);
+    # `block_unsettled`: the newest block HARVESTED has no harvested
+    # successor yet. Both end with the slot (`_release_slot`)
+    block_pending: bool = False
+    block_unsettled: bool = False
     # the request's timeline for its `engine.request` flight record, on
     # the recorder's clock (ray_tpu/util/tracing.py); arrival_t and the
     # deadline logic stay on time.monotonic()
@@ -636,7 +643,9 @@ class LLMEngine:
             self._totals.update(dict.fromkeys((
                 "block_dispatches_total", "block_passes_total",
                 "block_tokens_total", "block_rows_total",
-                "block_early_exits_total", "prefill_tokenless_total"), 0))
+                "block_early_exits_total", "block_settles_folded_total",
+                "block_unsettled_dropped_total",
+                "prefill_tokenless_total"), 0))
             if self._prefix_off:
                 self._totals["prefix_reuse_refused_total"] = 0
         # (layers, experts) of an expert model, whose programs return
@@ -1115,16 +1124,16 @@ class LLMEngine:
             jnp.asarray(override_mask), jnp.asarray(override_ids), temp,
             topk, jnp.asarray(keys_steps)))
 
-    def _compute_block(self, key, bt, total, ids, masked, temp, topk,
-                       keys_steps):
+    def _compute_block(self, key, bt, total, ids, masked, pending, temp,
+                       topk, keys_steps):
         """One block program over the full slot set (stage.py:
         `_block_program`); returns the handle of its packed result."""
         import jax.numpy as jnp
 
         return self._to_host_async(self.compute.run(
             "block", key, jnp.asarray(bt), jnp.asarray(total),
-            jnp.asarray(ids), jnp.asarray(masked), temp, topk,
-            jnp.asarray(keys_steps)))
+            jnp.asarray(ids), jnp.asarray(masked), jnp.asarray(pending),
+            temp, topk, jnp.asarray(keys_steps)))
 
     def _fetch_tokens(self, handle) -> np.ndarray:
         """Resolve a compute handle into host tokens (blocks until the
@@ -1656,13 +1665,19 @@ class LLMEngine:
 
     def _dispatch_block(self) -> bool:
         """A block model's generation step: ONE program that denoises the
-        next block of every slot that has one to generate and settles it
-        (stage.py: `_block_program`). A request's first block opens with
-        its prompt's ragged tail; every later one is all masks, which is
-        why the host needs no token of block n to dispatch block n + 1
-        (the pipeline runs ahead as a decode chain does; what a stop token
-        makes stale is dropped at harvest). Returns False when no slot has
-        a block to generate or pages fall short."""
+        next block of every slot that has one to generate, in
+        `denoising_steps` forwards at most (stage.py: `_block_program`).
+        A request's first block opens with its prompt's ragged tail; every
+        later one is all masks AND opens over the block before it, whose
+        settled ids the device kept: `pending` says which slots have one
+        (a block of the same request went out from the slot since its last
+        prefill pass; the prompt's whole blocks, a refilled request's
+        folded output among them, are final from the prefill). So the host
+        needs no token of block n to dispatch block n + 1 (the pipeline
+        runs ahead as a decode chain does; what a stop token makes stale
+        is dropped at harvest), and a row that sits a round out finds its
+        pending block in the carry when it next goes. Returns False when
+        no slot has a block to generate or pages fall short."""
         cfg = self.config
         B, S = self._block, cfg.max_batch
         elig = [req for req in self.running
@@ -1681,6 +1696,7 @@ class LLMEngine:
             total = np.zeros((S,), np.int32)
             ids = np.full((S, B), self.model_cfg.mask_token_id, np.int32)
             masked = np.zeros((S, B), bool)
+            pending = np.zeros((S,), bool)
             block_slots, facts = {}, []
             now = time.monotonic()
             for req in elig:
@@ -1691,7 +1707,9 @@ class LLMEngine:
                 masked[s, len(tail):] = True
                 bt[s, :len(req.pages)] = req.pages
                 total[s] = start + B
-                block_slots[s] = (req.request_id, req.planned_out, len(tail))
+                pending[s] = req.block_pending
+                block_slots[s] = (req.request_id, req.planned_out, len(tail),
+                                  req.block_pending)
                 facts.append((req.request_id, B, start + B))
                 if req.dispatched_t is None:
                     # a prompt with no whole block had no prefill
@@ -1703,12 +1721,13 @@ class LLMEngine:
                     base="planned")
             for req in elig:
                 req.planned_out += B - block_slots[req.slot][2]
-            toks = self._compute_block(key, bt, total, ids, masked, temp,
-                                       topk, keys_steps)
+                req.block_pending = True
+            toks = self._compute_block(key, bt, total, ids, masked, pending,
+                                       temp, topk, keys_steps)
             self._totals["block_dispatches_total"] += 1
             self._totals["block_rows_total"] += len(facts)
             self._enqueue("block", toks, r.start_ns, S, S * B, facts,
-                          k=steps + 1, slots=block_slots)
+                          k=steps, slots=block_slots)
         return True
 
     def _enqueue_decode(self, toks, dispatch_ns: int, k_steps: int,
@@ -1873,14 +1892,16 @@ class LLMEngine:
         """A block program's result (stage.py: `_block_program`): each
         live row's tokens go out in position order as ONE delta with the
         pass that fixed each; the record gains its three `block_*` fields,
-        behind every other family's."""
+        behind every other family's. A row that opened over its pending
+        block settled it: that block's keys are final now."""
         B, S = self._block, self.config.max_batch
         ids = fetched[:S * B].reshape(S, B)
         fixed_at = fetched[S * B:2 * S * B].reshape(S, B)
         passes = int(fetched[2 * S * B])
         device_start_ns, device_end_ns, exact = stamps
+        tot = self._totals
         emitted = 0
-        for slot, (rid, start, known) in rec["slots"].items():
+        for slot, (rid, start, known, pending) in rec["slots"].items():
             req = self.requests.get(rid)
             if (req is None or req.state != RUNNING or req.slot != slot
                     or len(req.output_ids) != start):
@@ -1891,9 +1912,10 @@ class LLMEngine:
                 req.prefill_device_ns += device_end_ns - device_start_ns
                 req.parts_exact &= exact
                 req.prefill_end_ns = device_end_ns
+            tot["block_settles_folded_total"] += pending
+            req.block_unsettled = True
             emitted += self._append_block(
                 req, ids[slot, known:], fixed_at[slot, known:], deltas)
-        tot = self._totals
         tot["block_passes_total"] += passes
         tot["block_tokens_total"] += emitted
         tot["block_early_exits_total"] += passes < rec["k"]
@@ -1947,9 +1969,13 @@ class LLMEngine:
         # of several rows is counted as if their assignments were sorted
         # together (each row pays boundary visits of its own: a floor)
         rows = max(rec["rows_padded"], 1)
+        per_pass = {"decode": rows, "block": rec["tokens_padded"]}.get(
+            rec["kind"], rec["tokens_padded"] // rows)
+        # the pass that opens a block is two blocks wide a row
+        opening = int(rec["kind"] == "block")
         self._totals["moe_tile_rows_total"] += moe_tile_rows(
-            counts, {"decode": rows, "block": rec["tokens_padded"]}.get(
-                rec["kind"], rec["tokens_padded"] // rows), self.model_cfg)
+            counts[:opening], 2 * per_pass, self.model_cfg) + moe_tile_rows(
+                counts[opening:], per_pass, self.model_cfg)
         return tokens, (assignments, touched, int(counts.max()))
 
     def _preempt(self, req: Request) -> None:
@@ -1982,6 +2008,13 @@ class LLMEngine:
         self.waiting.insert(0, req)
 
     def _release_slot(self, req: Request) -> None:
+        if req.block_unsettled:
+            # a request's LAST block in a slot is never settled, and no
+            # output depends on it: nothing reads its keys (prompt pages
+            # alone enter the prefix cache, a preemption refills from the
+            # tokens, a finish releases the pages, the hand-off is refused)
+            self._totals["block_unsettled_dropped_total"] += 1
+        req.block_pending = req.block_unsettled = False
         if req.slot >= 0:
             self._slot_req.pop(req.slot, None)
             self._slot_override.pop(req.slot, None)

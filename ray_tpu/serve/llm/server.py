@@ -111,16 +111,25 @@ _LLM_WORK_TOTALS = {
         "pages that hold no whole number of a block model's blocks)",
     "block_dispatches_total":
         "block programs enqueued (a model that generates by diffusion over "
-        "blocks: every denoising pass of a block and the settling one)",
+        "blocks: every denoising pass of a block, the first of them two "
+        "blocks wide where it settles the slot's pending block)",
     "block_passes_total":
-        "forward passes the block programs ran, the settling ones counted",
+        "forward passes the block programs ran",
     "block_tokens_total":
         "tokens the block programs' real rows emitted; over "
         "block_passes_total it is what an operator trades against quality",
     "block_rows_total": "real rows of the block programs",
     "block_early_exits_total":
         "block programs that left before denoising_steps passes: every "
-        "live row's block was settled",
+        "live row's block was fixed",
+    "block_settles_folded_total":
+        "pending blocks whose final keys the next block's opening pass "
+        "wrote; over block_dispatches_total x rows it is how full the "
+        "opening passes' second half runs",
+    "block_unsettled_dropped_total":
+        "last blocks of a request in its slot, which no pass settles: "
+        "nothing reads their keys; with block_settles_folded_total, the "
+        "blocks generated",
     "prefill_tokenless_total":
         "prompts whose prefill ended without a token (a block model's: its "
         "first token comes out of its first block)",

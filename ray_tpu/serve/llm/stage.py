@@ -44,10 +44,18 @@ OPERANDS = {
     "decode": ("bt", "total", "caps", "positions", "override_mask", "x",
                "temp", "topk", "keys"),
     # a model that generates by diffusion over blocks (models/sdar.py):
-    # "total" a row's settled tokens plus its block (0: no row), "x" the
-    # block's ids, "masked" which of them are still to be fixed
-    "block": ("bt", "total", "x", "masked", "temp", "topk", "keys"),
+    # "total" a row's earlier tokens plus its block (0: no row), "x" the
+    # block's ids, "masked" which of them are still to be fixed, "pending"
+    # whether the block before it is this slot's own last one, denoised
+    # and not yet settled (its ids are in the program's carry)
+    "block": ("bt", "total", "x", "masked", "pending", "temp", "topk",
+              "keys"),
 }
+
+# the programs that carry ids from dispatch to dispatch on the device, and
+# where `StageCompute` keeps them: [S, 1] the token each slot sampled last,
+# [S, B] the block each slot denoised last
+_CARRY = {"decode": "slot_ids", "block": "block_ids"}
 
 # after OPERANDS["prefill"], for a model that keeps per-slot state: the
 # decode slot each row of the wave leaves its state in
@@ -210,9 +218,10 @@ def dummy_operands(config, kind: str, shape_key: tuple,
     if kind == "block":
         block, steps, mp = shape_key
         S = config.max_batch
-        # no live row: the loop makes no pass and the settling one writes
-        # nothing
+        # no live row: the opening pass writes nothing, the loop makes no
+        # pass and the carry stays
         return (z((S, mp)), z((S,)), z((S, block)), z((S, block), bool),
+                z((S,), bool),
                 np.zeros((S,), np.float32), np.zeros((S,), np.int32),
                 z((steps, S, 2), np.uint32))
     if kind == "decode":
@@ -302,6 +311,11 @@ class StageCompute:
             # device-resident last-sampled-token per slot: the decode
             # chain's carry (design rule 2 in engine.py's docstring)
             self.slot_ids = jnp.zeros((config.max_batch, 1), jnp.int32)
+        if getattr(cfg, "block_length", 0):
+            # a model that generates by diffusion over blocks: the block
+            # each slot denoised last, the block program's carry
+            self.block_ids = jnp.zeros(
+                (config.max_batch, cfg.block_length), jnp.int32)
         self.programs: Dict[tuple, Any] = {}
         self.programs_built = 0
         # the scheduler step a program is built in, for its record: the
@@ -552,7 +566,7 @@ class StageCompute:
                     "a block's denoising passes run in one program: the "
                     "next pass's ids are chosen where the head is")
             return self._jit(self._block_program(apply, pack, ref_attn),
-                             kind, n_state=2)
+                             kind, n_state=3)
 
         # decode: fixed slot-set [S] batch, K fused steps, device-carry ids
         n_steps = shape_key[0]
@@ -616,20 +630,29 @@ class StageCompute:
         set of a model that generates by diffusion over blocks. Every pass
         writes the block's keys and values to its pages as the block
         stands (the positions are fixed; a later pass overwrites them) and
-        attends the row's settled context and the whole block
+        attends the row's earlier tokens and the whole block
         (`PagedCache.block_step`); `models/sdar.py: denoise` fixes some
         masked positions from the logits. The loop leaves when no live row
-        has a masked position (at most `denoising_steps` passes); one more
-        pass over the settled blocks leaves their final keys and values.
-        The block's state between passes is the loop's carry: a block
-        starts from what the host knows (a prompt's tail, or masks) and
-        ends settled, so nothing is carried from program to program but
-        the pool.
+        has a masked position (at most `denoising_steps` passes in all).
+
+        A denoised block is PENDING: its pages hold the keys of its last
+        pass's inputs, not of its settled ids. No pass of its own settles
+        it. Its ids stay on the device, in the carry [S, B] (the host
+        dispatches block n + 1 before it has harvested block n), and the
+        FIRST pass of the slot's next program is two blocks wide: the
+        pending block at its positions beside the new one, both written,
+        the pending one's queries attending up to their own block. That is
+        what a settling pass computes, on a read of the weights that the
+        new block pays for anyway. The head is over the new block only.
+        Where `pending` is False (a request's first block; the slot's
+        carry is another request's) the left half sits past the row's
+        length, where every rule drops it: no write, no expert, no key.
+        A request's LAST block is never settled: nothing reads its keys
+        (engine.py: `_release_slot`).
 
         Returns (int32 [S*B tokens | S*B the pass that fixed each, -1
-        where it came fixed | passes run, the settling one counted |
-        an expert model's [steps + 1, L, E] counts, a pass a row], pool).
-        """
+        where it came fixed | forward passes run | an expert model's
+        [steps, L, E] counts, a pass a row], carry, pool)."""
         import jax
         import jax.numpy as jnp
 
@@ -640,15 +663,19 @@ class StageCompute:
         serving_cache = self.family.serving_cache
         moe = cfg.num_experts > 0
 
-        def run_block(params, kv_pages, block_tables, total_lens, x, masked,
-                      temperature, top_k, keys_steps):
+        def run_block(params, kv_pages, block_ids, block_tables, total_lens,
+                      x, masked, pending, temperature, top_k, keys_steps):
             cache = serving_cache(cfg, kv_pages, block_tables,
                                   ref_attention=ref_attn, block_step=True)
-            live = (total_lens > 0)[:, None]
-            positions = (jnp.maximum(total_lens - B, 0)[:, None]
-                         + jnp.arange(B, dtype=jnp.int32))
+            live = total_lens > 0
+            start = jnp.maximum(total_lens - B, 0)
+            span = jnp.arange(B, dtype=jnp.int32)
+            positions = start[:, None] + span
+            # the pending block's place, or past the row's end
+            left = jnp.where(pending & live, start - B,
+                             total_lens)[:, None] + span
 
-            def forward(ids, kvp):
+            def forward(ids, positions, kvp):
                 out, new_pc, counts = apply(
                     params, ids, positions, cache.step(kvp, total_lens),
                     total_lens)
@@ -659,9 +686,8 @@ class StageCompute:
                         jax.lax.dynamic_update_slice_in_dim(
                             counts, c[None], at, 0))
 
-            def body(carry):
-                step, ids, masked, fixed_at, kvp, counts = carry
-                logits, kvp, c = forward(ids, kvp)
+            def fix(carry, logits, kvp, c):
+                step, ids, masked, fixed_at, _, counts = carry
                 ids, masked, fixed = denoise(
                     logits, ids, masked, step, cfg, temperature, top_k,
                     keys_steps[step])
@@ -669,16 +695,23 @@ class StageCompute:
                 return (step + 1, ids, masked, fixed_at, kvp,
                         put(counts, c, step))
 
+            def body(carry):
+                return fix(carry, *forward(carry[1], positions, carry[4]))
+
+            carry = (jnp.int32(0), x, masked & live[:, None],
+                     jnp.full(x.shape, -1, jnp.int32), kv_pages,
+                     jnp.zeros((steps, self.n_layers, cfg.num_experts),
+                               jnp.int32) if moe else None)
+            # the opening pass: [pending block | new block]
+            carry = fix(carry, *forward(
+                jnp.concatenate([block_ids, x], axis=1),
+                jnp.concatenate([left, positions], axis=1), kv_pages))
             step, ids, _, fixed_at, kvp, counts = jax.lax.while_loop(
-                lambda c: (c[0] < steps) & jnp.any(c[2]), body,
-                (jnp.int32(0), x, masked & live,
-                 jnp.full(x.shape, -1, jnp.int32), kv_pages,
-                 jnp.zeros((steps + 1, self.n_layers, cfg.num_experts),
-                           jnp.int32) if moe else None))
-            _, kvp, c = forward(ids, kvp)
+                lambda c: (c[0] < steps) & jnp.any(c[2]), body, carry)
             kept = jnp.concatenate([ids.reshape(-1), fixed_at.reshape(-1),
-                                    (step + 1)[None]])
-            return pack(kept, put(counts, c, step)), kvp
+                                    step[None]])
+            return (pack(kept, counts),
+                    jnp.where(live[:, None], ids, block_ids), kvp)
 
         return run_block
 
@@ -700,9 +733,9 @@ class StageCompute:
             out_shardings=(repl,) * (n_state - 1) + (kv,))
 
     def _state(self, kind: str) -> tuple:
-        if kind == "decode":
-            return self.params, self.kv_pages, self.slot_ids
-        return self.params, self.kv_pages
+        carry = _CARRY.get(kind)
+        return (self.params, self.kv_pages) + (
+            (getattr(self, carry),) if carry else ())
 
     def run(self, kind: str, shape_key: tuple, *operands):
         """Enqueue one program over `OPERANDS[kind]`; the pool (and the
@@ -712,8 +745,8 @@ class StageCompute:
         out, *state = self.program(kind, shape_key)(
             *self._state(kind), *operands)
         self.kv_pages = state[-1]
-        if kind == "decode":
-            self.slot_ids = state[0]
+        if kind in _CARRY:
+            setattr(self, _CARRY[kind], state[0])
         return out
 
     def operands(self, kind: str) -> tuple:

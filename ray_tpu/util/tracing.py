@@ -178,8 +178,9 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
     # "block" only (a model that generates by diffusion over blocks; its
     # `rows` are (request_id, block_len, ctx_tokens) with the block
     # counted in the context, its `moe_*` as a decode's, the positions
-    # between hold None): the forward passes the program ran, the settling
-    # one counted; the tokens its real rows emitted; the block length.
+    # between hold None): the forward passes the program ran (the first is
+    # two blocks wide where it settles a row's pending block); the tokens
+    # its real rows emitted; the block length.
     # The last four are the program on the device's timeline, stamped by
     # the host with no profiler (programs run in dispatch order on one
     # stream): `enqueued_ns` when the compute seam returned (`dispatch_ns`

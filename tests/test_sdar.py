@@ -269,7 +269,7 @@ def test_rules_differ_in_the_order_and_dynamic_leaves_early(generated, rule):
         assert per_pass > 1.5 * (static["block_tokens_total"]
                                  / static["block_passes_total"])
     else:
-        # a token a row and pass, and a settling pass a block
+        # a token a row and pass, and no pass more
         assert stats["block_early_exits_total"] <= 1
         assert set(passes) == {0, 1, 2, 3}
 
@@ -440,14 +440,23 @@ def test_counters_records_and_the_first_tokens_three_parts():
     # the 3-token prompt has no whole block: two prefills end tokenless
     assert moved["prefill_tokenless_total"] == 2
     assert moved["decode_dispatches_total"] == 0
+    opened = set()
     for r in blocks:
-        assert r["block_len"] == B and r["k"] == 5
-        assert 2 <= r["block_passes"] <= 5
+        assert r["block_len"] == B and r["k"] == 4
+        assert 1 <= r["block_passes"] <= 4
         assert all(q == B and ctx % B == 0 for _, q, ctx in r["rows"])
-        # experts: every real token of every pass, 4 experts in 2 layers
+        # experts: every real token of every pass, 4 experts in 2 layers;
+        # the first pass carries the pending block of every row that has
+        # one (a request's every block but its first) beside the new one
+        pending = sum(rid in opened for rid, _, _ in r["rows"])
         assert r["moe_assignments"] == (
-            r["block_passes"] * len(r["rows"]) * B * 4 * 2)
+            r["block_passes"] * len(r["rows"]) + pending) * B * 4 * 2
+        opened |= {rid for rid, _, _ in r["rows"]}
         assert r["enqueued_ns"] <= r["device_start_ns"] <= r["device_end_ns"]
+    # 9 tokens behind tails of 3, 1 and 0: 3, 3 and 3 blocks, of which
+    # each request's last is never settled
+    assert moved["block_settles_folded_total"] == 6
+    assert moved["block_unsettled_dropped_total"] == 3
     prefills = [r for r in recs if r["kind"] == "prefill"]
     assert all(r["block_passes"] is None for r in prefills)
     assert fields.index("block_passes") == fields.index("final") + 1
@@ -488,6 +497,256 @@ def test_sampled_rows_are_seeded_and_greedy_rows_unmoved_beside_them():
         engine.close()
     assert outs[0]["g"]["tokens"] == outs[1]["g"]["tokens"] == greedy
     assert outs[0]["s"]["tokens"] == outs[1]["s"]["tokens"] != greedy
+
+
+# ------------------------------------------- a settled block's keys, folded
+def _settling_forward(engine, pool, pages, ids, start):
+    """What the program had as a pass of its own before PR 42: one forward
+    over a settled block alone, at width B, through the engine's model on
+    `pool` -> the pool with the block's final keys and values."""
+    from ray_tpu.models.llama import PagedCache
+
+    L = engine.model_cfg.num_layers
+    bt = np.zeros((1, engine.max_pages_per_seq), np.int32)
+    bt[0, :len(pages)] = pages
+    total = jnp.asarray([start + B], jnp.int32)
+    pc = PagedCache(
+        kv_pages=pool, block_tables=jnp.broadcast_to(bt, (L,) + bt.shape),
+        total_lens=jnp.broadcast_to(total, (L, 1)), block_step=True)
+    positions = jnp.asarray(start + np.arange(B, dtype=np.int32))[None]
+    (_, new_pc), _ = engine.model.apply(
+        {"params": engine.params}, jnp.asarray([ids], jnp.int32),
+        positions=positions, kv_caches=pc,
+        token_mask=positions < total[:, None], mutable=["routing"])
+    return new_pc.kv_pages
+
+
+def _block_kv(pool, pages, start, page=CFG["page_size"]):
+    """[L, Hkv, B, 2D]: the keys and values at positions start..start+B-1
+    (a block lies inside one page)."""
+    return np.asarray(pool)[:, pages[start // page], :,
+                            start % page:start % page + B]
+
+
+def _finished_with_pages(engine, rid, prompt, **sampling):
+    """Run one request to its end -> (its tokens, the pages it held: they
+    are released and not cleared)."""
+    engine.add_request(rid, prompt, SamplingParams(**sampling))
+    pages, deltas = [], []
+    while engine.has_work():
+        deltas += engine.step()
+        req = engine.requests.get(rid)
+        if req is not None and req.pages:
+            pages = list(req.pages)
+    return _by_request(deltas)[rid]["tokens"], pages
+
+
+@pytest.fixture(scope="module")
+def one_answer():
+    """A 41-token prompt (a tail of 1) and 15 tokens: four blocks."""
+    engine = _engine()
+    prompt = _prompts((41,))[0]
+    tokens, pages = _finished_with_pages(engine, "r", prompt, max_tokens=15)
+    assert tokens == _reference_generate(engine, prompt, 15)[0]
+    yield engine, prompt + tokens, pages
+    engine.close()
+
+
+@pytest.mark.parametrize("n", [0, 1, 2])
+def test_the_next_blocks_program_leaves_the_keys_a_settling_pass_writes(
+        one_answer, n):
+    """Block n is pending when its program ends; the pass that opens block
+    n + 1 writes its keys. They are what a plain width-B forward over the
+    settled block writes on the same pool."""
+    engine, seq, pages = one_answer
+    start = 40 + n * B
+    want = _settling_forward(engine, engine.kv_pages, pages,
+                             seq[start:start + B], start)
+    np.testing.assert_allclose(
+        _block_kv(engine.kv_pages, pages, start),
+        _block_kv(want, pages, start), atol=1e-5, rtol=1e-5)
+    # every earlier position as well: the settling forward changed nothing
+    # but its block
+    np.testing.assert_array_equal(
+        np.asarray(want)[:, pages[:2]], np.asarray(engine.kv_pages)[:, pages[:2]])
+
+
+def test_a_requests_last_block_is_never_settled_and_nothing_reads_it(
+        one_answer):
+    """The last block's pages hold its last denoising pass's keys (a mask
+    among the inputs), not its settled ids', and no reader exists: a prompt
+    that continues the answer reuses the PROMPT's full pages and no page of
+    the answer, and gets the reference's tokens."""
+    engine, seq, pages = one_answer
+    start = 40 + 3 * B
+    want = _settling_forward(engine, engine.kv_pages, pages,
+                             seq[start:start + B], start)
+    assert np.abs(_block_kv(engine.kv_pages, pages, start)
+                  - _block_kv(want, pages, start)).max() > 1e-3
+    hits = engine.stats()["prefix_token_hits"]
+    got, _ = _finished_with_pages(engine, "again", seq, max_tokens=5)
+    assert engine.stats()["prefix_token_hits"] - hits == 32   # of 41
+    assert got == _reference_generate(engine, seq, 5)[0]
+    st = engine.stats()
+    assert (st["block_settles_folded_total"],
+            st["block_unsettled_dropped_total"]) == (3 + 1, 1 + 1)
+
+
+@pytest.fixture(scope="module")
+def one_program():
+    """ONE block program on a pool of noise, the carry full of another
+    request's ids. Rows: 0 a first block behind 40 prompt tokens, 1 a
+    prompt of 2 tokens (its block starts the sequence), 2 a row whose
+    block is pending (the only one that may write left of its block), 3 a
+    first block whose page left of it is row 0's too (a shared prefix
+    page). -> (pool before, pool after, block tables, totals)"""
+    engine = _engine(max_model_len=64)
+    c = engine.compute
+    rng = np.random.default_rng(0)
+    before = rng.normal(size=c.kv_pages.shape).astype(np.float32)
+    c.kv_pages = jnp.asarray(before)
+    c.block_ids = jnp.asarray(rng.integers(0, 255, (4, B)), jnp.int32)
+    bt = np.zeros((4, 4), np.int32)
+    bt[0, :3], bt[1, :1], bt[2, :3], bt[3, :3] = (1, 2, 3), (4,), (5, 6, 7), (
+        1, 2, 8)
+    total = np.asarray([44, B, 36, 36], np.int32)
+    ids = np.full((4, B), 255, np.int32)
+    ids[1, :2] = (7, 9)
+    masked = ids == 255
+    out = c.run("block", engine._block_shape_key(), jnp.asarray(bt),
+                jnp.asarray(total), jnp.asarray(ids), jnp.asarray(masked),
+                jnp.asarray([False, False, True, False]),
+                np.zeros((4,), np.float32), np.zeros((4,), np.int32),
+                jnp.zeros((4, 4, 2), jnp.uint32))
+    yield before, np.asarray(c.kv_pages), bt, total, np.asarray(out)
+    engine.close()
+
+
+@pytest.mark.parametrize("row, what", [
+    (0, "a first block: the slot's carry is another request's"),
+    (1, "a prompt shorter than a block: nothing lies left of it"),
+    (3, "a first block right of a shared prefix page"),
+    (2, "a block over its pending one: both are written, no more")])
+def test_a_row_writes_its_block_and_left_of_it_only_a_pending_one(
+        one_program, row, what):
+    before, after, bt, total, _ = one_program
+    page = CFG["page_size"]
+    changed = np.abs(after - before).max(axis=(0, 2, 4)) > 0   # [P, page]
+    lo = total[row] - (2 * B if row == 2 else B)
+    want = np.zeros_like(changed)
+    for pos in range(lo, total[row]):
+        want[bt[row, pos // page], pos % page] = True
+    mine = [p for p in bt[row] if p and not (row == 3 and p in (1, 2))]
+    np.testing.assert_array_equal(changed[mine], want[mine])
+    if row == 3:
+        # the shared pages: only row 0's own block, in page 3 of its table
+        assert not changed[[1, 2]].any()
+    assert not changed[0].any()          # page 0, every unused column's
+
+
+def test_the_program_returns_denoising_steps_of_counts_and_its_carry(
+        one_program):
+    *_, out = one_program
+    S, L, E = 4, 2, 16
+    assert out.shape == (2 * S * B + 1 + 4 * L * E,)
+    assert out[2 * S * B] == 4                        # forwards run
+    counts = out[-4 * L * E:].reshape(4, L, E)
+    # 4 live blocks and 1 pending one in the first pass, 4 in the others
+    assert counts.sum(axis=(1, 2)).tolist() == [
+        n * B * 4 * L for n in (5, 4, 4, 4)]
+
+
+def test_the_head_is_computed_over_the_new_block_alone():
+    """The pass that opens a block is [S, 2B] wide; its logits [S, B, V]."""
+    engine = _engine()
+    text = engine.program_text("block", engine._block_shape_key())
+    V = engine.model_cfg.vocab_size
+    assert f"tensor<4x{B}x{V}xf32>" in text
+    assert f"tensor<4x{2 * B}x{V}xf32>" not in text
+    assert f"tensor<4x{2 * B}x64xf32>" in text        # hidden states
+    engine.close()
+
+
+def test_a_slot_that_changes_hands_in_flight_starts_from_its_own_prompt():
+    """One slot. `a` stops inside its second block while the program of
+    its third is in flight; `c` takes the slot, whose carry is `a`'s. The
+    stale program is dropped and `c` is the reference's."""
+    engine = _engine(max_batch=1)
+    pa, pc = _prompts((41, 42), seed=21)
+    stop = _reference_generate(engine, pa, 12)[0][5]
+    engine.add_request("a", pa, SamplingParams(max_tokens=12,
+                                               stop_token_ids=(stop,)))
+    engine.add_request("c", pc, SamplingParams(max_tokens=9))
+    got = _by_request(_run(engine))
+    assert got["a"]["tokens"] == _reference_generate(engine, pa, 12,
+                                                     (stop,))[0]
+    assert got["a"]["reason"] == "stop"
+    assert got["c"]["tokens"] == _reference_generate(engine, pc, 9)[0]
+    st = engine.stats()
+    blocks = sum(len(r["blocks"]) for r in got.values())
+    assert st["block_rows_total"] > blocks            # a stale row went
+    assert (st["block_settles_folded_total"]
+            + st["block_unsettled_dropped_total"]) == blocks
+    assert st["block_unsettled_dropped_total"] == 2
+    engine.close()
+
+
+def test_a_row_that_sits_a_round_out_is_settled_when_it_next_goes():
+    """`r1` is left out of the third block program (as a page shortfall
+    leaves a row out): its pending block waits in the carry, through a
+    program that is not its own, and the program that next carries it
+    settles it."""
+    engine = _engine()
+    prompts = _prompts((41, 42), seed=4)
+    for i, p in enumerate(prompts):
+        engine.add_request(f"r{i}", p, SamplingParams(max_tokens=14))
+    reserve, calls = engine._reserve_decode_pages, []
+
+    def skipping(elig, k_steps):
+        calls.append([r.request_id for r in elig])
+        kept = reserve(elig, k_steps)
+        if len(calls) == 3:
+            kept = [r for r in kept if r.request_id != "r1"]
+        return kept
+
+    engine._reserve_decode_pages = skipping
+    n0 = tracing.appended("engine.dispatch")
+    got = _by_request(_run(engine))
+    fields = tracing.FIELDS["engine.dispatch"]
+    rows = [[row[0] for row in dict(zip(fields, r))["rows"]]
+            for r in tracing.records("engine.dispatch", since=n0)
+            if r[1] == "block"]
+    assert rows[:4] == [["r0", "r1"], ["r0", "r1"], ["r0"], ["r0", "r1"]]
+    for i, p in enumerate(prompts):
+        assert got[f"r{i}"]["tokens"] == _reference_generate(
+            engine, p, 14)[0], i
+    st = engine.stats()
+    assert (st["block_settles_folded_total"]
+            + st["block_unsettled_dropped_total"]) == sum(
+                len(r["blocks"]) for r in got.values())
+    engine.close()
+
+
+@pytest.mark.parametrize("rule", RULES)
+def test_a_program_runs_denoising_steps_forwards_and_no_settling_one(
+        generated, rule):
+    got, _, stats, _ = generated[rule]
+    blocks = sum(len(r["blocks"]) for r in got.values())
+    assert (stats["block_settles_folded_total"]
+            + stats["block_unsettled_dropped_total"]) == blocks
+    assert stats["block_unsettled_dropped_total"] == len(got)
+    steps = 4
+    if rule == "low_confidence_dynamic":
+        # the confident head fixes a block in its opening pass
+        assert stats["block_passes_total"] == stats["block_dispatches_total"]
+    else:
+        # a block of 4 masks takes 4 forwards, where it took 5
+        full = stats["block_dispatches_total"] - stats[
+            "block_early_exits_total"]
+        assert full >= 7
+        assert stats["block_passes_total"] <= steps * stats[
+            "block_dispatches_total"]
+        assert stats["block_passes_total"] >= steps * full + 1
 
 
 # ---------------------------------------------------------------- the server
