@@ -87,6 +87,23 @@ class LlamaConfig:
     # RMSNorm with a learned weight over the head dim of q and of k,
     # before the rotation (Qwen3's layer)
     qk_norm: bool = False
+    # An expert layer that is TOLD which experts it holds (one chip's share
+    # of a layer whose experts are spread over many; models/kimi.py): the
+    # router scores all `n_routed_experts` (None: `num_experts`, the layer
+    # holds them all), the layer holds `num_experts` of them from
+    # `expert_first` on, and an assignment to an expert it does not hold
+    # costs it nothing and adds nothing (MoEMLP).
+    n_routed_experts: Optional[int] = None
+    expert_first: int = 0
+    # "softmax": Mixtral's rule. "sigmoid": DeepSeek-V3's: scores are
+    # sigmoid(x W_r), the k are CHOSEN by score + a learned bias a expert
+    # and WEIGHTED by the score alone, renormalised over the k chosen
+    # (`norm_topk_prob`) and scaled by `routed_scaling_factor`
+    moe_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # a gated FFN of width n x `expert_width` that every token passes,
+    # beside its routed experts
+    n_shared_experts: int = 0
     # > 0: attention is causal between blocks of this many positions,
     # counted from position 0, and bidirectional inside one (query i sees
     # key j iff j // block_causal <= i // block_causal): a model that
@@ -100,6 +117,12 @@ class LlamaConfig:
     @property
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def routed_experts(self) -> int:
+        """The router's width: `num_experts` unless the layer holds a
+        share of more."""
+        return self.n_routed_experts or self.num_experts
 
     def num_params(self) -> int:
         h, f, v, l = (self.hidden_size, self.intermediate_size,
@@ -167,7 +190,9 @@ class PagedCache:
     the layer scan the pool rides the CARRY whole, never sliced or
     re-stacked, so the loop updates the donated buffer in place; a layer
     sees that pool, its own [B, MP] / [B] slices (they ride the scan's xs)
-    and its index in `layer`.
+    and its index in `layer`. (A latent family keeps a cache object of its
+    own over a pool of one row a token for all heads, `[L, P, 1, page,
+    lanes]` = `[c_kv | k_rope | zeros]`: models/kimi.py: LatentCache.)
     """
 
     kv_pages: jax.Array      # [L, P, Hkv, page, 2*D] (K | V in lanes)
@@ -366,6 +391,24 @@ class MoEMLP(nn.Module):
     router_aux_loss_coef (see the sow call for the consumer contract);
     into "routing" `expert_counts`, the [E] int32 count of real assignments
     per expert (the serving engine's `moe_*` counters).
+
+    `moe_scoring` "sigmoid" (DeepSeek-V3's router): `sc = sigmoid(x W_r)`
+    in float32, the k CHOSEN are the largest of `sc + b` (`router_bias`, a
+    float32 [E] that enters the choice only), their weights `sc_i / (sum
+    of the chosen sc + 1e-20) * routed_scaling_factor`.
+    `n_shared_experts`: `+ Shared(x)`, a gated FFN every token passes.
+
+    A SHARE of a layer (`n_routed_experts` R > `num_experts` E): the router
+    and the choice are over all R, the weights are the whole model's
+    (normalised over all k chosen, held or not), and the layer computes
+    the chosen experts in `[expert_first, expert_first + E)` only. An
+    assignment to an expert it does not hold joins the dropless path's
+    trailing group beside the padding: no row in a tile, no expert work,
+    a zero contribution; `expert_counts` counts the HELD experts. Dropless
+    means: no assignment to a held expert is dropped, whatever the routing
+    (the row layout holds all T*k). What the absent experts would add is
+    left out: the layer returns its share of the sum (plus the shared
+    expert, which is whole).
     """
 
     config: LlamaConfig
@@ -378,6 +421,7 @@ class MoEMLP(nn.Module):
         `_stacked_experts`)."""
         cfg = self.config
         E, k = cfg.num_experts, cfg.num_experts_per_tok
+        R = cfg.routed_experts               # the router's width
         f = cfg.expert_width
         b, s, h = x.shape
         T = b * s
@@ -385,13 +429,33 @@ class MoEMLP(nn.Module):
 
         router = self.param(
             "router", A(nn.initializers.normal(0.02), ("embed", None)),
-            (h, E), jnp.float32)
+            (h, R), jnp.float32)
         # routing in fp32 (tiny matmul, numerically load-bearing)
         logits = jnp.einsum("th,he->te", xt.astype(jnp.float32), router)
-        probs = jax.nn.softmax(logits, axis=-1)              # [T,E]
-        gate, idx = jax.lax.top_k(probs, k)                  # [T,k]
-        if cfg.norm_topk_prob:
-            gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        if cfg.moe_scoring == "sigmoid":
+            probs = jax.nn.sigmoid(logits)                   # [T,R]
+            bias = self.param("router_bias", A(nn.initializers.zeros,
+                                               (None,)), (R,), jnp.float32)
+            _, idx = jax.lax.top_k(probs + bias, k)          # [T,k]
+            gate = jnp.take_along_axis(probs, idx, axis=-1)
+            if cfg.norm_topk_prob:
+                gate = gate / (gate.sum(-1, keepdims=True) + 1e-20)
+            gate = gate * cfg.routed_scaling_factor
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)          # [T,E]
+            gate, idx = jax.lax.top_k(probs, k)              # [T,k]
+            if cfg.norm_topk_prob:
+                gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        if R != E:
+            # this chip's share: the held experts by their own index, every
+            # other assignment to the trailing group E
+            idx = idx - cfg.expert_first
+            idx = jnp.where((idx >= 0) & (idx < E), idx, E)
+            # [B, S, 1, E] bool, the held experts each token chose, for a
+            # caller that asks for the "selection" collection (the
+            # benchmark's check); nobody else pays for it
+            self.sow("selection", "held", (
+                idx[..., None] == jnp.arange(E)).any(-2).reshape(b, s, 1, E))
 
         w_gu = self.param(
             "experts_gate_up",
@@ -410,6 +474,13 @@ class MoEMLP(nn.Module):
             out = self._dropless(xt, gate, idx, w_gu, w_dn, token_mask,
                                  layer)
 
+        if cfg.n_shared_experts:
+            out = out + MLP(dataclasses.replace(
+                cfg, intermediate_size=cfg.n_shared_experts * f),
+                name="shared")(xt)
+        if R != E:
+            # a share serves (serving has no balancing loss to sow)
+            return out.reshape(b, s, h)
         # Switch/GShard load-balancing aux loss
         onehot = jax.nn.one_hot(idx, E, dtype=jnp.int32)     # [T,k,E]
         frac_tokens = onehot.sum((0, 1)).astype(jnp.float32) / (T * k)
@@ -491,6 +562,10 @@ class MoEMLP(nn.Module):
         if aligned:
             row_of = row_of + shift[expert]
         y = y[row_of].reshape(T, k, h)
+        if cfg.routed_experts != E:
+            # an assignment to an absent expert reads a row past the last
+            # group, which is undefined: it adds nothing
+            y = jnp.where((expert < E).reshape(T, k, 1), y, 0)
         out = jnp.einsum("tkh,tk->th", y.astype(jnp.float32),
                          gate).astype(cfg.dtype)
         if token_mask is not None:
@@ -547,7 +622,9 @@ def moe_row_layout(tokens: int, cfg: LlamaConfig) -> tuple:
     from ..ops.grouped_matmul import aligned_rows, row_tile
 
     m, e = tokens * cfg.num_experts_per_tok, cfg.num_experts
-    tm, aligned = row_tile(m, e)
+    # the tile is chosen for the assignments a share EXPECTS (e of the
+    # routed experts' under even routing); the rows hold all of them
+    tm, aligned = row_tile(m * e // cfg.routed_experts, e)
     rows = aligned_rows(m, e, tm) if aligned else m
     if rows <= _MOE_ROWS or (not aligned and rows % _MOE_ROWS):
         return tm, aligned, rows, rows
@@ -816,7 +893,7 @@ class LlamaModel(nn.Module):
 
 # ----------------------------------------------------------------- serving
 # What serve/llm/stage.py asks of a model family's module (this one,
-# models/jamba.py and models/minicpm_sala.py): get_config, serving_model,
+# models/jamba.py, models/minicpm_sala.py, models/sdar.py, models/kimi.py): get_config, serving_model,
 # pool_spec, serving_cache, RESUMES_PREFILL, pass_cost_ratios.
 # pages are all a sequence keeps: a prefill row that starts mid-prompt
 # attends to its earlier pages (the path prefix hits use)

@@ -107,7 +107,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
+    # (the values' width: the keys' for every family but one whose values
+    # are narrower than its keys, models/kimi.py)
+    acc0 = jnp.zeros((bq, v_ref.shape[3]), jnp.float32)
 
     if causal:
         # last k block any row of this q block may attend to
@@ -170,7 +172,9 @@ def _fwd_kernel_lens(lens_ref, *refs, **static):
 
 def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
          interpret, sq, sk, lens=None, block_causal=0):
-    """q: [B,Hq,Sq_p,D]; k/v: [B,Hkv,Sk_p,D] (padded to block multiples).
+    """q: [B,Hq,Sq_p,D]; k: [B,Hkv,Sk_p,D]; v: [B,Hkv,Sk_p,Dv] (padded to
+    block multiples; Dv is D but for the forward-only path of a model whose
+    values are narrower than its keys).
 
     sq/sk are the TRUE lengths of the operands: the kernels mask kv padding
     with `k_pos < sk` and compute the causal offset from them. `lens`
@@ -180,6 +184,7 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
     """
     b, hq, sq_p, d = q.shape
     _, hkv, sk_p, _ = k.shape
+    dv = v.shape[3]
     n_rep = hq // hkv
     bq, bk = block_q, block_k
     have_segs = q_seg is not None
@@ -195,7 +200,7 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
         pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, *_: (b_, h, i, 0)),
         pl.BlockSpec((1, 1, sk_p, d),
                      lambda b_, h, i, *_: (b_, h // n_rep, 0, 0)),
-        pl.BlockSpec((1, 1, sk_p, d),
+        pl.BlockSpec((1, 1, sk_p, dv),
                      lambda b_, h, i, *_: (b_, h // n_rep, 0, 0)),
     ]
     args = [q, k, v]
@@ -210,11 +215,11 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
         args += [_dummy_arg(), _dummy_arg()]
 
     out_shape = [
-        jax.ShapeDtypeStruct((b, hq, sq_p, d), q.dtype),
+        jax.ShapeDtypeStruct((b, hq, sq_p, dv), q.dtype),
         jax.ShapeDtypeStruct((b, hq, sq_p, 1), jnp.float32),
     ]
     out_specs = [
-        pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, *_: (b_, h, i, 0)),
+        pl.BlockSpec((1, 1, bq, dv), lambda b_, h, i, *_: (b_, h, i, 0)),
         pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i, *_: (b_, h, i, 0)),
     ]
     compiler_params = dict(
@@ -470,6 +475,9 @@ def flash_attention(
     block_causal: int = 0,
 ):
     """Flash attention. q: [B,Sq,Hq,D]; k/v: [B,Sk,Hkv,D] -> [B,Sq,Hq,D].
+    With `return_lse` v may be [B,Sk,Hkv,Dv], Dv != D (latent attention's
+    materialised form: keys of 192, values of 128) -> [B,Sq,Hq,Dv]; the
+    backward kernels take one width.
 
     segment_ids: one [B,S] array (requires Sq == Sk), or a
     (q_segment_ids [B,Sq], kv_segment_ids [B,Sk]) pair for cached decode /
@@ -504,6 +512,10 @@ def flash_attention(
         raise ValueError(f"num q heads {hq} not a multiple of kv heads {hkv}")
     if causal and sk < sq:
         raise ValueError(f"causal attention needs sk >= sq, got {sq=} {sk=}")
+    if v.shape[-1] != d and not return_lse:
+        raise ValueError(
+            f"values of width {v.shape[-1]} beside keys of {d}: the "
+            f"forward-only path's (return_lse=True)")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     scale = float(scale if scale is not None else d ** -0.5)

@@ -52,6 +52,21 @@ owned end to end:
   prefix), so the kernel runs over real blocks only: a bucket's padding
   and the block table's unused width are not computed, and a row without
   a cached prefix runs none of part (2).
+- A LATENT family (models/kimi.py: multi-head latent attention) keeps ONE
+  row a token for all heads, ``[L, P, 1, page, lanes]``: ``[c_kv | k_rope |
+  zeros]``, the compressed keys-and-values and the one rotated key, after
+  the norm and the rotation, padded to whole lane tiles (``latent_lanes``:
+  576 values in 640 lanes). ``paged_write`` writes it as any page (the
+  latent as K, the pad as V). ``latent_attention_decode`` is the decode
+  kernel's third lane form under the name ``_mla_decode``: the whole row
+  is the key and its first ``v_width`` lanes the value, so one read of a
+  page serves keys, values and all 64 query heads of the absorbed form.
+  ``latent_prefill_attention`` is the materialised form: causal flash
+  among the new tokens at keys wider than values, and the context in
+  static chunks whose per-head keys and values exist one chunk at a time,
+  each a flash call (``_mla_flash``) under a ``cond`` on the rows'
+  context, merged by log-sum-exp: no gather of a whole block table and no
+  kernel that holds a whole context resident.
 - ``paged_attention_reference`` is the jnp gather path: the numerics
   oracle for kernel parity tests, the path a CPU backend runs, and the
   path tensor-parallel engines ask for by argument. A TPU backend never
@@ -67,6 +82,7 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
+LATENT_LANE_TILE = 128
 
 
 def _fori_no_unroll(lo, hi, body, init):
@@ -187,7 +203,8 @@ def _decode_kernel(lengths_ref, bt_ref, layer_ref, # SMEM scalars
                    kv_buf, work_b, work_c,         # scratch
                    sems, *,
                    page: int, chunk: int, scale: float,
-                   stream: bool = True, attend: bool = True):
+                   stream: bool = True, attend: bool = True,
+                   v_width: int = 0):
     """Single-program decode kernel (grid=()): one flattened work list of
     (sequence, page-chunk) items, double-buffered page DMAs, all kv heads
     per item. `kv_hbm` is the whole [L, P, Hkv, page, 2D] pool; only pages
@@ -205,6 +222,11 @@ def _decode_kernel(lengths_ref, bt_ref, layer_ref, # SMEM scalars
     `stream=False` starts and awaits no copy, `attend=False` skips an
     item's compute: the two halves of an item, for
     benchmarks/paged_decode_probe.py to time alone. The engine runs both.
+
+    `v_width` > 0: the pool holds ONE latent row a token for all heads
+    (`Hkv` 1; models/kimi.py): the keys are the row's every lane and the
+    values its first `v_width`, so one read of a page serves both and
+    every query head (`_mla_decode`).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -222,6 +244,9 @@ def _decode_kernel(lengths_ref, bt_ref, layer_ref, # SMEM scalars
     # at the end; for D = 64 a row is ONE lane tile, so that costs no more
     # than the sub-tile slice it avoids.
     k_lanes, v_lanes = ((0, d), (d, d2)) if q_lanes == d else ((0, d2),) * 2
+    if v_width:
+        # a third: a latent row is the key whole and the value in front
+        k_lanes, v_lanes = (0, d2), (0, v_width)
 
     # ---- build the work list: (b, chunk) for every used page-chunk
     def fill_b(b, cnt):
@@ -331,7 +356,8 @@ def _decode_kernel(lengths_ref, bt_ref, layer_ref, # SMEM scalars
         @pl.when(is_last)
         def _():
             # V's lanes of the accumulator: all of it, or its upper half
-            o_ref[b] = (acc[:, :, -d:] / l).astype(o_ref.dtype)
+            o_ref[b] = ((acc if v_width else acc[:, :, -d:]) / l).astype(
+                o_ref.dtype)
 
         m = jnp.where(is_last, jnp.full_like(m, NEG_INF), m)
         l = jnp.where(is_last, jnp.zeros_like(l), l)
@@ -354,9 +380,12 @@ def _decode_call(q, kv_pages, block_tables, lengths, layer, *,
 
 
 def _decode_pallas(kernel, q, kv_pages, block_tables, lengths, layer, *,
-                   scale: float, chunk: int, interpret: bool):
+                   scale: float, chunk: int, interpret: bool,
+                   v_width: int = 0):
     """The decode kernel's one `pallas_call`, around `kernel` (the whole
-    `_decode_kernel`, or a half of it for the probe)."""
+    `_decode_kernel`, or a half of it for the probe). `v_width` > 0: the
+    latent form; q comes at the row's whole width and `v_width` lanes come
+    back."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -364,7 +393,10 @@ def _decode_pallas(kernel, q, kv_pages, block_tables, lengths, layer, *,
     _, _, hkv, page, d2 = kv_pages.shape
     max_chunks = -(-block_tables.shape[1] // chunk)
     q = q.reshape(b, hkv, hq // hkv, d)
-    if d % 128:
+    if v_width:
+        kernel = functools.partial(kernel, v_width=v_width)
+        d = v_width
+    elif d % 128:
         # K and V are not whole lane tiles: the padded-q form
         q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, d2 - d)))
 
@@ -409,6 +441,17 @@ def decode_kernel_constraint(head_dim: int, page_size: int,
         return (f"page_size must be a multiple of {sublane} rows for "
                 f"{jnp.dtype(dtype).name}, got page_size={page_size}")
     return None
+
+
+def latent_kernel_constraint(v_width: int, page_size: int,
+                             dtype) -> Optional[str]:
+    """`decode_kernel_constraint` for a latent pool: its rows are whole
+    lane tiles by construction (`latent_lanes`); the values' slice of a
+    row must be too, and a page whole sublane tiles."""
+    if v_width % LATENT_LANE_TILE:
+        return (f"the values' width (kv_lora_rank) must be a multiple of "
+                f"{LATENT_LANE_TILE} lanes, got {v_width}")
+    return decode_kernel_constraint(LATENT_LANE_TILE, page_size, dtype)
 
 
 ITEM_BYTES = 2 << 20
@@ -473,6 +516,102 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
     return _decode_call(q, kv_pages, block_tables, lengths, layer,
                         scale=scale_f, pages_per_chunk=pages_per_chunk,
                         interpret=bool(interpret))
+
+
+# --------------------------------------------------- decode over latents
+
+def latent_lanes(width: int) -> int:
+    """Lanes of a latent pool's row: the latent's `width` (512 compressed
+    + 64 rotated at the published sizes) rounded up to whole lane tiles
+    (640). The pad is zeros and stays zeros (`paged_write` writes them),
+    so a query padded with anything multiplies nothing; it costs a ninth
+    more bytes a token than the latent itself, which a roofline counted
+    at `width` shows as a lower share. (A 576-lane row is not a whole
+    number of tiles: the chip's tiled layout pads it in memory all the
+    same, and the kernel's slices would no longer be aligned.)"""
+    return -(-width // LATENT_LANE_TILE) * LATENT_LANE_TILE
+
+
+# pages a work item of the latent kernel holds: 1024 rows of a 64-token
+# page, 1.3 MB at 640 lanes; the scores' [64, 1024] are whole lane tiles
+LATENT_ITEM_ROWS = 1024
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "pages_per_chunk",
+                                             "v_width", "interpret"))
+def _mla_decode(q, kv_pages, block_tables, lengths, layer, *, scale: float,
+                pages_per_chunk: int, v_width: int, interpret: bool):
+    """`_decode_kernel` in its latent form, under a name of its own: an
+    instruction in a trace takes the name of the innermost jit around its
+    `pallas_call`, and this kernel's time is read apart from
+    `_decode_call`'s."""
+    return _decode_pallas(_decode_kernel, q, kv_pages, block_tables, lengths,
+                          layer, scale=scale, chunk=pages_per_chunk,
+                          interpret=interpret, v_width=v_width)
+
+
+def latent_attention_reference(q: jax.Array, kv_pages: jax.Array,
+                               block_tables: jax.Array, lengths: jax.Array,
+                               *, v_width: int, scale: float,
+                               layer=None) -> jax.Array:
+    """The gather path of `latent_attention_decode`: the oracle of the
+    kernel and what a CPU backend runs. Row b attends its first
+    `lengths[b]` latents."""
+    kv_pages, layer = _layered(kv_pages, layer)
+    page, lanes = kv_pages.shape[-2:]
+    b, mp = block_tables.shape
+    rows = kv_pages[layer, block_tables].reshape(b, mp * page, lanes)
+    logits = jnp.einsum("bhd,bkd->bhk", q, rows[..., :q.shape[-1]],
+                        preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(mp * page)[None, :] < lengths[:, None]
+    logits = jnp.where(live[:, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(rows.dtype)
+    out = jnp.einsum("bhk,bkd->bhd", probs, rows[..., :v_width])
+    return jnp.where((lengths > 0)[:, None, None], out, 0)
+
+
+def latent_attention_decode(q: jax.Array, kv_pages: jax.Array,
+                            block_tables: jax.Array, lengths: jax.Array, *,
+                            v_width: int, scale: float, layer=None,
+                            pages_per_chunk: Optional[int] = None,
+                            interpret: Optional[bool] = None,
+                            force_reference: bool = False) -> jax.Array:
+    """Single-token decode attention in latent attention's ABSORBED form:
+    every query head of a row against the row's latents, one shared key
+    row a token.
+
+    q: [B, H, W] the absorbed queries (`W_UK^T q_nope | q_rope`, W the
+    latent's width); kv_pages: [L, P, 1, page, lanes] at `layer` (or
+    without the layer axis), a token's row `[c_kv | k_rope | zeros]`,
+    lanes = `latent_lanes(W)`; lengths: [B] (0 = inactive row -> zeros).
+    Returns [B, H, v_width]: the softmax-weighted sum of the rows' first
+    `v_width` lanes (`c_kv`), for the caller's `W_UV`. The kernel reads a
+    page ONCE for keys, values and all H heads; no [rows x context] array
+    and no gather of the context exists. The implementation is chosen as
+    `paged_attention_decode` chooses."""
+    b, h, w = q.shape
+    hkv, page, lanes = kv_pages.shape[-3:]
+    if hkv != 1 or lanes != latent_lanes(w):
+        raise ValueError(
+            f"a latent pool is [.., 1, page, {latent_lanes(w)}] for "
+            f"queries of {w}; got {kv_pages.shape}")
+    on_tpu = jax.default_backend() == "tpu"
+    if force_reference or (interpret is None and not on_tpu):
+        return latent_attention_reference(
+            q, kv_pages, block_tables, lengths, v_width=v_width,
+            scale=float(scale), layer=layer)
+    if not interpret:
+        why = latent_kernel_constraint(v_width, page, kv_pages.dtype)
+        if why is not None:
+            raise ValueError(f"latent decode kernel: {why}")
+    if pages_per_chunk is None:
+        pages_per_chunk = max(1, LATENT_ITEM_ROWS // page)
+    pages_per_chunk = min(pages_per_chunk, block_tables.shape[1])
+    kv_pages, layer = _layered(kv_pages, layer)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - w)))
+    return _mla_decode(q, kv_pages, block_tables, lengths, layer,
+                       scale=float(scale), pages_per_chunk=pages_per_chunk,
+                       v_width=v_width, interpret=bool(interpret))
 
 
 def paged_attention_block(q: jax.Array, kv_pages: jax.Array,
@@ -542,22 +681,27 @@ def _attn_lse(q, k, v, *, causal, scale, q_lens=None, kv_lens=None,
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o = jnp.einsum("bhrsk,bkhd->bshrd", (p / l_safe).astype(v.dtype), v)
     lse = (m + jnp.log(l_safe))[..., 0]            # [B,Hkv,rep,S]
-    return (o.reshape(b, sq, hq, d),
+    return (o.reshape(b, sq, hq, v.shape[-1]),
             lse.reshape(b, hq, sq).transpose(0, 2, 1))
 
 
 def merge_attention(o1: jax.Array, lse1: jax.Array,
-                    o2: jax.Array, lse2: jax.Array) -> jax.Array:
+                    o2: jax.Array, lse2: jax.Array,
+                    return_lse: bool = False):
     """Combine two attention partials over disjoint kv sets by their
-    log-sum-exp. o*: [B,S,H,D]; lse*: [B,S,H]."""
+    log-sum-exp. o*: [B,S,H,D]; lse*: [B,S,H]. `return_lse`: also the
+    log-sum-exp of the union, for a caller that merges a third part."""
     m = jnp.maximum(lse1, lse2)
     a1 = jnp.exp(lse1 - m)
     a2 = jnp.exp(lse2 - m)
     denom = a1 + a2
     w1 = (a1 / denom)[..., None]
     w2 = (a2 / denom)[..., None]
-    return (o1.astype(jnp.float32) * w1
-            + o2.astype(jnp.float32) * w2).astype(o1.dtype)
+    out = (o1.astype(jnp.float32) * w1
+           + o2.astype(jnp.float32) * w2).astype(o1.dtype)
+    if return_lse:
+        return out, m + jnp.log(denom)
+    return out
 
 
 def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
@@ -601,6 +745,78 @@ def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
     o2, lse2 = _attn_lse(q, k_ctx, v_ctx, causal=False, scale=scale_f,
                          q_lens=n_new, kv_lens=ctx_len, impl=impl)
     return merge_attention(o1, lse1, o2, lse2)
+
+
+# tokens of context whose keys and values a latent family's prefill
+# materialises at once: 4096 x 64 heads x (192 + 128) x 2 bytes = 0.17 GB
+# at the published sizes. The flash forward keeps a chunk's K and V
+# resident and double-buffered, keys of 192 lanes padded to 256: 6 MB at
+# 4096 rows; 8192 rows are 72 KB over the 16 MB a kernel gets unasked
+# (compiled for a described v5e: tests/test_chip_compile.py)
+LATENT_CTX_CHUNK = 4096
+
+
+def latent_ctx_chunks(ctx_pages: int, page: int,
+                      chunk_tokens: int = LATENT_CTX_CHUNK) -> Tuple:
+    """((first column, columns), ...) of the block table: the static chunks
+    `latent_prefill_attention` walks a context of `ctx_pages` columns in."""
+    n = max(1, chunk_tokens // page)
+    return tuple((c, min(n, ctx_pages - c)) for c in range(0, ctx_pages, n))
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "impl"))
+def _mla_flash(q, k, v, q_lens, kv_lens, *, causal: bool, scale: float,
+               impl: Optional[str]):
+    """`_attn_lse` under a name of its own, as `_mla_decode`: the flash
+    forward at keys wider than values, among a pass's own tokens and over
+    each materialised chunk of its context (a call inside a `cond` would
+    otherwise take the branch's name in a trace)."""
+    return _attn_lse(q, k, v, causal=causal, scale=scale, q_lens=q_lens,
+                     kv_lens=kv_lens, impl=impl)
+
+
+def latent_prefill_attention(q: jax.Array, k_new: jax.Array,
+                             v_new: jax.Array, kv_pages: jax.Array,
+                             block_tables: jax.Array, positions: jax.Array,
+                             total_lens: jax.Array, expand, *,
+                             ctx_pages: int = 0, scale: float,
+                             chunk_tokens: int = LATENT_CTX_CHUNK,
+                             impl: Optional[str] = None,
+                             layer=None) -> jax.Array:
+    """Prefill attention of a latent family in its MATERIALISED form: the
+    new tokens attend themselves causally (per-head keys `k_new` [B, S, H,
+    Dk] and values `v_new` [B, S, H, Dv], Dv <= Dk) and, where `ctx_pages`
+    > 0, the context their pages hold as LATENTS ([L, P, 1, page, lanes]).
+
+    The context is walked in static chunks of `chunk_tokens`: a chunk's
+    latent rows are gathered (5.2 MB at 4096 x 640), `expand(rows [B, T,
+    lanes]) -> (k [B, T, H, Dk], v [B, T, H, Dv])` makes its per-head keys
+    and values (one chunk's alive at a time), the queries attend the part
+    of it their row has, and the partials merge by log-sum-exp. A chunk
+    past every row's context runs nothing: neither gather, `expand` nor
+    kernel. So a context is bounded by the pool, not by what a kernel
+    keeps resident or by a [context x heads] array of the whole prompt.
+    Lengths as `paged_prefill_attention` gives them to the kernel."""
+    kv_pages, layer = _layered(kv_pages, layer)
+    page, lanes = kv_pages.shape[-2:]
+    b, s = q.shape[:2]
+    ctx_len = positions[:, 0]
+    n_new = jnp.clip(total_lens - ctx_len, 0, s)
+    o, lse = _mla_flash(q, k_new, v_new, n_new, None, causal=True,
+                        scale=scale, impl=impl)
+    for first, n in latent_ctx_chunks(ctx_pages, page, chunk_tokens):
+        have = jnp.clip(ctx_len - first * page, 0, n * page)
+
+        def attend(o, lse, first=first, n=n, have=have):
+            rows = kv_pages[layer, block_tables[:, first:first + n]]
+            k, v = expand(rows.reshape(b, n * page, lanes))
+            o2, lse2 = _mla_flash(q, k, v, n_new, have, causal=False,
+                                  scale=scale, impl=impl)
+            return merge_attention(o, lse, o2, lse2, return_lse=True)
+
+        o, lse = jax.lax.cond(jnp.any(have > 0), attend,
+                              lambda o, lse: (o, lse), o, lse)
+    return o
 
 
 def prefill_block_visits(s: int, ctx_width: int, n_new=None,

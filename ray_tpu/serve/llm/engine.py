@@ -389,6 +389,38 @@ def _refuse_for_block_diffusion(config: EngineConfig, model_cfg,
             f"where the head is")
 
 
+def _refuse_for_latent_pool(config: EngineConfig, model_cfg, mesh) -> None:
+    """A model whose pages hold one latent row a token for all heads (its
+    config has `latent_lanes`: models/kimi.py). The engine options that
+    would split a head axis the pool has not, or that were never run on
+    this family's two forms of attention, are refused here, each by the
+    mechanism that is missing."""
+    if not getattr(model_cfg, "latent_lanes", 0):
+        return
+    model = (f"model {config.model!r} keeps one latent row a token for all "
+             f"heads")
+    if config.tp > 1 or mesh is not None:
+        raise NotImplementedError(
+            f"{model}: tensor parallelism (tp={config.tp}, mesh="
+            f"{'given' if mesh is not None else None}) shards the page "
+            f"pool over its kv-head axis (ServeSharding.kv_pages_sharding)"
+            f", and a latent pool has one row for every head: splitting "
+            f"the query heads would copy the pool to every chip, and "
+            f"nothing does that yet")
+    if config.pp > 1:
+        raise NotImplementedError(
+            f"{model}: pipeline parallelism (pp={config.pp}) slices a "
+            f"uniform `layers` axis (stage_params), and this model's stack "
+            f"is a run of dense layers and a run of expert layers")
+    if config.spec_lookahead > 0:
+        raise NotImplementedError(
+            f"{model}: spec_lookahead={config.spec_lookahead} verifies a "
+            f"draft through the materialised form while decode runs the "
+            f"absorbed one, and acceptance compares their argmax bit for "
+            f"bit: no verify dispatch of this family was ever run against "
+            f"its decode")
+
+
 def _bucket(n: int, buckets) -> int:
     for b in buckets:
         if n <= b:
@@ -413,6 +445,8 @@ _PAIR_PARAMS = 375
 _STAMPS_AT = tracing.FIELDS["engine.dispatch"].index("enqueued_ns")
 # and where a block program's three fields sit, behind every family's
 _BLOCK_AT = tracing.FIELDS["engine.dispatch"].index("block_passes")
+# and a latent family's four, behind those
+_MLA_AT = tracing.FIELDS["engine.dispatch"].index("mla_layers")
 
 
 def _attn_visits(bucket: int, width: int, real=None, ctx=None) -> tuple:
@@ -536,6 +570,7 @@ class LLMEngine:
         model_cfg = serve_model_config(config)
         _refuse_for_recurrent_state(config, model_cfg, mesh)
         _refuse_for_block_diffusion(config, model_cfg, mesh)
+        _refuse_for_latent_pool(config, model_cfg, mesh)
         self._build_compute(params, mesh)
         self.max_pages_per_seq = config.max_model_len // config.page_size
 
@@ -651,7 +686,8 @@ class LLMEngine:
         # (layers, experts) of an expert model, whose programs return
         # routing counts packed behind their tokens; None for a dense one
         cfg_m = self.model_cfg
-        self._moe_LE = ((cfg_m.num_layers, cfg_m.num_experts)
+        self._moe_LE = ((getattr(cfg_m, "n_expert_layers",
+                                 cfg_m.num_layers), cfg_m.num_experts)
                         if cfg_m.num_experts else None)
         if self._moe_LE:
             self._totals.update(moe_assignments_total=0,
@@ -705,6 +741,16 @@ class LLMEngine:
                                 sparse_blocks_selected_total=0,
                                 sparse_ctx_tokens_total=0,
                                 sparse_dense_rows_total=0)
+        # a latent family (models/kimi.py): what its records say of every
+        # dispatch, and the static context chunks a resumed pass walks
+        self._mla = None
+        if getattr(cfg_m, "latent_lanes", 0):
+            self._mla = (cfg_m.num_layers, cfg_m.latent_bytes_token)
+            self._totals.update(dict.fromkeys((
+                "mla_decode_ctx_tokens_total",
+                "mla_prefill_ctx_chunks_total",
+                "mla_prefill_ctx_tokens_materialised_total",
+                "moe_assignments_routed_total"), 0))
         self._queue_wait_ns_total = 0
         # the device's timeline as the host can stamp it (_device_stamps):
         # the estimated end of the last program harvested and whether it
@@ -1364,7 +1410,8 @@ class LLMEngine:
             self._enqueue(
                 "prefill", tokens, r.start_ns, computed, computed * sb,
                 facts, group=rows,
-                tail=self._lin_sparse_facts(facts, 1, passes))
+                tail=self._lin_sparse_facts(facts, 1, passes),
+                **self._mla_prefill_facts(facts, cp))
         self._phase_ns[_DISPATCH_PREFILL] += r.ns
 
     def _enqueue(self, kind: str, toks, dispatch_ns: int, rows_padded: int,
@@ -1384,6 +1431,28 @@ class LLMEngine:
             "enqueued_ns": tracing.now_ns(),
             "rows_padded": rows_padded, "tokens_padded": tokens_padded,
             "facts": tuple(facts), "tail": (), **harvest_keys})
+
+    def _mla_prefill_facts(self, facts: List[tuple], cp: int) -> dict:
+        """A latent family's prefill record: how many of the static
+        context chunks each real row's pass materialised keys and values
+        of (a chunk is materialised WHOLE where the row's context reaches
+        into it; ops/paged_attention.py: latent_prefill_attention). Moves
+        the family's stats() totals. {} for any other model."""
+        if self._mla is None:
+            return {}
+        from ...ops.paged_attention import latent_ctx_chunks
+
+        page = self.config.page_size
+        chunks = latent_ctx_chunks(cp, page,
+                                   self.model_cfg.ctx_chunk_tokens)
+        per_row = []
+        for _, n_new, end in facts:
+            live = [n for first, n in chunks if end - n_new > first * page]
+            per_row.append(len(live))
+            self._totals["mla_prefill_ctx_chunks_total"] += len(live)
+            self._totals["mla_prefill_ctx_tokens_materialised_total"] += (
+                sum(live) * page)
+        return {"mla_ctx_chunks": tuple(per_row)}
 
     def _lin_sparse_facts(self, facts: List[tuple], k_steps: int,
                           passes=None) -> tuple:
@@ -1742,6 +1811,12 @@ class LLMEngine:
         if self._scan_layers:
             self._totals["ssm_state_updates_total"] += (
                 len(facts) * k_steps * self._scan_layers)
+        if self._mla:
+            # latents the absorbed kernel reads, a layer: a row's context
+            # at each fused step
+            self._totals["mla_decode_ctx_tokens_total"] += sum(
+                k_steps * ctx + k_steps * (k_steps - 1) // 2
+                for _, _, ctx in facts)
         self._enqueue(
             "decode", toks, dispatch_ns, S, S * k_steps, facts, k=k_steps,
             slots=chunk_slots, tail=self._lin_sparse_facts(facts, k_steps))
@@ -1883,6 +1958,14 @@ class LLMEngine:
                 rec["k"]) + moe_facts + self._ssm_fields + rec["tail"]
         if "block" in rec:
             head += (None,) * (_BLOCK_AT - len(head)) + rec["block"]
+        if self._mla:
+            routed = (sum(q for _, q, _ in rec["facts"]) * self._moe_LE[0]
+                      * self.model_cfg.num_experts_per_tok
+                      if self._moe_LE else None)
+            if routed is not None:
+                self._totals["moe_assignments_routed_total"] += routed
+            head += (None,) * (_MLA_AT - len(head)) + self._mla + (
+                rec.get("mla_ctx_chunks"), routed)
         # the stamps come last, whatever fields the model's family wrote
         tracing.record("engine.dispatch", head + (None,) * (
             _STAMPS_AT - len(head)) + stamps)
@@ -2387,6 +2470,8 @@ class LLMEngine:
             if self._lin_layers:
                 out["lin_state_pool_bytes"] = sizes["lin_state"]
                 out["sparse_index_pool_bytes"] = sizes["kc"]
+        if self._mla and self.compute:
+            out["latent_pool_bytes"] = self.compute.pool_bytes()["kv_pages"]
         if self._block and self._prefix_off:
             out["prefix_reuse_refused_why"] = self._prefix_off
         if self.sharding is not None:

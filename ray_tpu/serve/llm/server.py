@@ -158,6 +158,18 @@ _LLM_WORK_TOTALS = {
         "keys a dense layer would have attended for the same queries",
     "sparse_dense_rows_total":
         "decode (row, step)s at a position under dense_len (no selection)",
+    "mla_decode_ctx_tokens_total":
+        "latent rows the absorbed decode kernel read, a layer (live rows' "
+        "contexts over fused steps)",
+    "mla_prefill_ctx_chunks_total":
+        "context chunks whose keys and values resumed prefill passes "
+        "materialised, a layer",
+    "mla_prefill_ctx_tokens_materialised_total":
+        "context tokens in those chunks (a chunk is materialised whole)",
+    "moe_assignments_routed_total":
+        "assignments the router made for real tokens (x experts per token "
+        "x expert layers); moe_assignments_total over it is the share "
+        "this chip's held experts got",
 }
 # engine.stats() sizes published as rtpu_llm_<key> gauges
 _LLM_SIZES = {
@@ -168,6 +180,8 @@ _LLM_SIZES = {
         "bytes of the per-slot linear-attention state pool",
     "sparse_index_pool_bytes":
         "bytes of the compressed keys kept beside the pages",
+    "latent_pool_bytes":
+        "bytes of a latent family's page pool (one row a token, all heads)",
 }
 
 

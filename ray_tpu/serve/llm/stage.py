@@ -91,13 +91,17 @@ def serve_dtype(config):
 
 def model_family(name: str):
     """The module that defines the preset `name`: the one place that
-    chooses a model family from `EngineConfig.model`. Four families:
+    chooses a model family from `EngineConfig.model`. Five families:
     models/llama.py (every layer attention, dense or experts),
     models/jamba.py (state-space layers beside attention),
     models/minicpm_sala.py (linear-attention layers beside block-sparse
     attention) and models/sdar.py (llama's layers under a block mask,
     generating by diffusion over blocks: its config has `block_length`
-    and the engine steps it with the "block" program). A family's module
+    and the engine steps it with the "block" program) and models/kimi.py
+    (latent attention: a pool of ONE latent row a token for all heads,
+    absorbed in decode and materialised in prefill, and possibly one
+    chip's share of sigmoid-routed experts; its config has `latent_lanes`
+    and `n_expert_layers`). A family's module
     has `CONFIGS`, `get_config`,
     `serving_model`, `pool_spec`, `serving_cache`, and two flags:
     `RESUMES_PREFILL` (a prefill row continues from what its pages and
@@ -109,9 +113,9 @@ def model_family(name: str):
     from and the model computes the head there only). Its config answers
     `n_slot_state_layers` / `slot_state_bytes_row()` where layers keep
     state a decode slot."""
-    from ...models import jamba, llama, minicpm_sala, sdar
+    from ...models import jamba, kimi, llama, minicpm_sala, sdar
 
-    families = (llama, jamba, minicpm_sala, sdar)
+    families = (llama, jamba, minicpm_sala, sdar, kimi)
     for family in families:
         if name in family.CONFIGS:
             return family
@@ -180,16 +184,26 @@ def resolve_attention(model_cfg, config, sharding) -> Dict[str, str]:
     if jax.default_backend() != "tpu":
         impl = f"reference ({jax.default_backend()} backend)"
         return {"decode": impl, "prefill": impl}
-    from ...ops.paged_attention import decode_kernel_constraint
+    from ...ops.paged_attention import (decode_kernel_constraint,
+                                        latent_kernel_constraint)
 
-    why = decode_kernel_constraint(
-        model_cfg.head_dim_, config.page_size,
-        "bfloat16" if config.dtype == "bfloat16" else "float32")
+    dtype = "bfloat16" if config.dtype == "bfloat16" else "float32"
+    latent = bool(getattr(model_cfg, "latent_lanes", 0))
+    if latent:
+        why = latent_kernel_constraint(model_cfg.kv_lora_rank,
+                                       config.page_size, dtype)
+    else:
+        why = decode_kernel_constraint(model_cfg.head_dim_,
+                                       config.page_size, dtype)
     if why is not None:
         raise ValueError(
             f"EngineConfig(model={config.model!r}, page_size="
             f"{config.page_size}, dtype={config.dtype!r}) cannot run on "
             f"this TPU: the paged decode kernel needs {why}")
+    if latent:
+        return {"decode": "pallas latent_attention_decode (absorbed)",
+                "prefill": "pallas flash_attention over materialised "
+                           "chunks (+lse merge)"}
     return {"decode": "pallas paged_attention_decode",
             "prefill": "pallas flash_attention (+lse merge)"}
 
@@ -429,7 +443,9 @@ class StageCompute:
         ref_attn = self.sharding is not None
         # an expert model's programs return its routing counts of this
         # stage's layers, [L, E] a pass of the model
-        moe_LE = (L, cfg.num_experts) if cfg.num_experts else None
+        # (a family whose first layers are dense says how many are not)
+        moe_LE = ((getattr(cfg, "n_expert_layers", L), cfg.num_experts)
+                  if cfg.num_experts else None)
 
         def apply(params, x, positions, pc, total_lens):
             """model.apply -> (logits or hidden states, cache, routing
@@ -447,8 +463,9 @@ class StageCompute:
                 {"params": params}, x, positions=positions, kv_caches=pc,
                 token_mask=positions < total_lens[:, None],
                 mutable=["routing"])
-            return out, new_pc, sown["routing"]["layers"]["layer"][
-                "moe"]["expert_counts"]
+            # the one leaf sown: the scanned expert layers' [L, E]
+            (counts,) = jax.tree.leaves(sown["routing"])
+            return out, new_pc, counts
 
         def pack(kept, counts):
             """The program's result: a last stage's tokens are

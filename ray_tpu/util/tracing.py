@@ -181,6 +181,16 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
     # between hold None): the forward passes the program ran (the first is
     # two blocks wide where it settles a row's pending block); the tokens
     # its real rows emitted; the block length.
+    # The four behind them are written for a model with latent attention
+    # only (models/kimi.py; the positions between hold None): its layers
+    # (each keeps one latent row a token), the bytes one token's latents
+    # cost to read once over all of them as the pool stores them, for a
+    # prefill a tuple a real row of how many context chunks the pass
+    # materialised keys and values of (beside the row's `ctx_tokens`), and
+    # the assignments its router made for the record's real tokens (tokens
+    # x experts per token x expert layers). Such a model's `moe_*` count
+    # the experts it HOLDS: held / routed is 1/32 where a chip holds 12 of
+    # 384 under even routing.
     # The last four are the program on the device's timeline, stamped by
     # the host with no profiler (programs run in dispatch order on one
     # stream): `enqueued_ns` when the compute seam returned (`dispatch_ns`
@@ -203,6 +213,8 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         "lin_layers", "lin_state_bytes_row", "sparse_layers",
         "sparse_tokens_read", "sparse_kernels_scored", "pass_index",
         "final", "block_passes", "block_tokens_fixed", "block_len",
+        "mla_layers", "latent_bytes_token", "mla_ctx_chunks",
+        "moe_assignments_routed",
         "enqueued_ns", "device_start_ns", "device_end_ns", "end_exact"),
     # one per LLMEngine.step(); `fetch_blocked`: how many of the step's
     # harvests found their program unfinished (the step waited for the

@@ -696,3 +696,73 @@ def test_sdar_program_fits_and_carries_its_pool_in_place(
     aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
     assert len(aliased) >= (2 if kind == "block" else 1), header[:400]
     assert tuple(stage.kv_pages.shape) == (6, 12800, 4, 16, 256)
+
+
+# ---------------- latent attention and one chip's share of the experts
+@pytest.mark.parametrize("kind, key", [
+    ("decode", None), ("prefill", (4096, 529)), ("prefill", (4096, 0)),
+    ("prefill", (512, 529))])
+def test_kimi_program_fits_and_carries_its_pool_in_place(
+        topo, no_persistent_cache, kind, key):
+    """Kimi-K2.5 at its published widths as the cell `kimi-k2.5-longdoc`
+    runs it: one dense layer and five expert layers, each with 12 of the
+    384 routed experts beside the shared one, 20,480 rows of the
+    vocabulary, 24 slots, 8192 latent pages of 64 tokens (`[6, 8192, 1,
+    64, 640]`, 4.03 GB). The decode program's attention is the latent
+    kernel under its own name (`_mla_decode`), once a scan body; a resumed
+    `[1 x 4096]` pass behind a 33,856-token table (nine context chunks of
+    4096 tokens, each a flash call under a `cond`, beside the own-tokens
+    call) fits
+    the chip; the experts are the grouped matmul at E = 12; the pool is
+    aliased from argument to result."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    from ray_tpu.serve.llm.stage import init_params
+
+    cfg = EngineConfig(
+        model="kimi-k2.5", dtype="bfloat16", page_size=64, num_pages=64,
+        max_model_len=33856, max_batch=24,
+        prefill_buckets=(512, 1024, 2048, 4096),
+        model_overrides=dict(num_layers=6, num_experts=12,
+                             n_routed_experts=384, expert_first=0,
+                             vocab_size=20480))
+    engine = LLMEngine(cfg, params={})
+    stage = engine.compute
+    stage.params = jax.eval_shape(lambda: init_params(
+        stage.model, jnp.zeros((1, 8), jnp.int32), jax.random.PRNGKey(0)))
+    shape, dtype = stage.family.pool_spec(stage.model_cfg, 6, 8192, 64, 24)
+    assert shape == (6, 8192, 1, 64, 640)
+    stage.kv_pages = jax.ShapeDtypeStruct(shape, dtype)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(a):
+        a = a if hasattr(a, "shape") else np.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    key = (engine._decode_shape_key() if kind == "decode"
+           else (key[0], engine._wave_rb, key[1]))
+    with pytest.MonkeyPatch.context() as mp_ctx:
+        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = stage.program(kind, key).lower(*jax.tree.map(
+            sds, (*stage._state(kind), *stage.dummy_args(kind, key)),
+            is_leaf=lambda a: isinstance(a, jax.ShapeDtypeStruct))
+        ).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(kind, key, "GiB", total / 2 ** 30, "temp",
+          mem.temp_size_in_bytes / 2 ** 30)
+    # 8.35 GB of weights + 4.03 GB of latents, under the chip's 15.75 GiB
+    assert 11.4 * 2 ** 30 <= total <= HBM_GIB * 2 ** 30, total / 2 ** 30
+    kernels = set(re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
+    names = sorted({k.split(".")[0] for k in kernels})
+    if kind == "decode":
+        assert names == ["_mla_decode", "_moe_gmm"], kernels
+    else:
+        assert names == ["_mla_flash", "_moe_gmm"], kernels
+        # the own-tokens call and one a context chunk of a resumed pass,
+        # in the dense run's scan body and in the expert run's
+        assert sum(k.startswith("_mla_flash") for k in kernels) == 2 * (
+            1 + (9 if key[2] else 0)), kernels
+    header = text.split("\n", 1)[0]
+    aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliased) >= (2 if kind == "decode" else 1), header[:400]
