@@ -10,9 +10,12 @@ ABSENT").  Here the hot op is owned natively: a blocked online-softmax
   `fori_loop` of MXU matmuls with f32 accumulation. That residency bounds
   the kv length the kernel takes. Compile limits on v5e (from a compile
   for a described v5e chip, jax 0.9.0 — not from a run; guarded by
-  tests/test_chip_compile.py): forward compiles up to 16384 kv rows and
-  is refused at 32768, backward compiles up to 4096 and is refused at
-  8192, at head_dim 64 and 128 alike (`RESOURCE_EXHAUSTED ... vmem`).
+  tests/test_chip_compile.py): forward and backward compile up to 16384
+  kv rows, at head_dim 64 and 128 alike, and the forward is refused at
+  32768 (`RESOURCE_EXHAUSTED ... vmem`). The backward holds Q, dO, dq,
+  K, V, dk, dv of one query head and float32 accumulators of all three
+  gradients, 10 MB at 2048 rows and 84 MB at 16384, and asks for that
+  VMEM from its shapes (the chip has 128 MiB).
   Longer sequences need K/V tiled over the grid (ROADMAP A6),
 - GQA handled in the BlockSpec index map (q-head h reads kv-head h // n_rep),
   so no materialised `repeat_kv`,
@@ -25,8 +28,13 @@ ABSENT").  Here the hot op is owned natively: a blocked online-softmax
   query block past a row's queries runs no loop and the key loop ends with
   the row's keys, so a bucket's padding and a block table's unused width
   cost nothing; the shapes, and so the programs, stay the buckets',
-- backward pass as two Pallas kernels (dq; dk/dv) using the saved
-  log-sum-exp, flash-2 style.
+- backward pass as ONE Pallas kernel using the saved log-sum-exp: a grid
+  step is one query head against its kv head's K and V, and visits every
+  (key block, query block) tile once, computing s, p, dp and ds there
+  once for all of dv, dk and dq. dk and dv accumulate in float32 VMEM
+  over the kv head's query group and are written once, in the operands'
+  dtype: no per-query-head partials, no sum after the kernel. Tiles below
+  the causal diagonal (no segment ids, no key padding) run without a mask.
 
 Interpret mode runs the same kernels on the CPU for tests
 (tests/test_flash_attention.py checks parity with `reference_attention`
@@ -54,7 +62,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-BLOCK = 512  # default tile edge (untuned on the current chip: ROADMAP A3/A4)
+# default tile edge. On v5e at [4, 2048, 32/8, 128] (the pretrain cell's
+# step), of 256, 512 and 1024 a side: the fastest backward (2.99 ms a call;
+# 1024 x 1024 3.24, 512 x 256 3.24, 256 x 256 4.29) and within 2.3% of the
+# fastest forward (2.21 ms; 256 x 512 2.16) (benchmarks/flash_bwd_probe.py
+# --blocks, a process a pair, PR 44). Not swept at other shapes or chips.
+BLOCK = 512
 GRAN = 128   # MXU-minimal granularity: short sequences round up to this,
              # not to BLOCK, so small prefills don't pad 4-8x
 
@@ -252,111 +265,104 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
 
 
 # =============================================================== backward
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   qseg_ref, kseg_ref, dq_ref, *,
-                   sm_scale: float, causal: bool, block_k: int,
-                   sq: int, sk: int, have_segs: bool):
-    qblk = pl.program_id(2)
-    bq, d = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[0, 0]
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]      # [bq, 1]
-    delta = delta_ref[0, 0]  # [bq, 1]
-    q_pos = qblk * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
+                kseg_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                sm_scale: float, causal: bool, block_q: int, block_k: int,
+                sq: int, sk: int, have_segs: bool):
+    """One grid step: one query head against its (batch, kv head)'s K and V.
+    Every (key block, query block) tile is visited once: s, p, dp and ds are
+    computed once and feed dv, dk and dq. Scores are held transposed,
+    `[block_k, block_q]`, so that `lse` and `delta` broadcast as lane rows
+    and dv / dk are plain products; dq accumulates transposed, `[d,
+    block_q]` a query block, for the same reason. dk and dv accumulate in
+    float32 scratch across the kv head's query group (grid dimension 2,
+    over which their output block stays), dq across the key blocks of this
+    step; each is written once, in the operands' dtype."""
+    rep = pl.program_id(2)
+    bq, bk = block_q, block_k
+    nqb, nkb = q_ref.shape[2] // bq, k_ref.shape[2] // bk
     offset = sk - sq
+    # segment ids or padded keys: every tile is masked. Else only the
+    # tiles the causal diagonal crosses are
+    mask_all = have_segs or sk % bk != 0
 
-    if causal:
-        num_kb = jnp.minimum(
-            pl.cdiv((qblk + 1) * bq + offset, block_k), pl.cdiv(sk, block_k))
-    else:
-        num_kb = pl.cdiv(sk, block_k)
+    @pl.when(rep == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def body(kb, dq_acc):
-        k = k_ref[0, 0, pl.ds(kb * block_k, block_k), :]
-        v = v_ref[0, 0, pl.ds(kb * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        mask = k_pos < sk
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+    k_iota = jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+    q_iota = jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+
+    def key_block(kb, _):
+        ks = pl.ds(pl.multiple_of(kb * bk, bk), bk)
+        k = k_ref[0, 0, ks, :]
+        v = v_ref[0, 0, ks, :]
+        kt = k.T  # [d, bk]
+
+        def tile(qb, _, masked):
+            qs = pl.ds(pl.multiple_of(qb * bq, bq), bq)
+            q = q_ref[0, 0, qs, :]
+            do = do_ref[0, 0, qs, :]
+            st = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [bk, bq]
+            p = jnp.exp(st - lse_ref[0, 0, qb])
+            if masked:
+                k_pos = kb * bk + k_iota
+                mask = k_pos < sk  # kv padding
+                if causal:
+                    mask = jnp.logical_and(
+                        mask, k_pos <= qb * bq + q_iota + offset)
+                if have_segs:
+                    mask = jnp.logical_and(
+                        mask, kseg_ref[0, ks, :] == qseg_ref[0, qb])
+                # (a select, so a masked score's overflow does not matter)
+                p = jnp.where(mask, p, 0.0)
+            dv_acc[ks, :] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [bk, d]
+            dp = jax.lax.dot_general(
+                v, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [bk, bq]
+            # (scaled before it is rounded, as `_fwd_kernel`'s scores are:
+            # scaling the float32 sums where dq and dk are written instead
+            # read 0.0030 from the float32 reference where this reads
+            # 0.0021, on the chip, PERF.md section 6 PR 44)
+            ds = (p * (dp - delta_ref[0, 0, qb]) * sm_scale).astype(q.dtype)
+            dk_acc[ks, :] += jax.lax.dot_general(
+                ds, q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [bk, d]
+            dq_acc[qb] += jax.lax.dot_general(
+                kt, ds, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [d, bq]
+
+        # query blocks [first, whole) see a part of this key block, those
+        # from `whole` on all of it; none before `first` sees any
+        first, whole = 0, (nqb if mask_all else 0)
         if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos + offset)
-        if have_segs:
-            qs = qseg_ref[0]  # [bq, 1]
-            ks = kseg_ref[0, pl.ds(kb * block_k, block_k), :].reshape(
-                1, block_k)
-            mask = jnp.logical_and(mask, qs == ks)
-        p = jnp.exp(jnp.where(mask, s, NEG_INF) - lse)
-        p = jnp.where(mask, p, 0.0)
-        dp = jax.lax.dot_general(
-            do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dq_acc += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dq_acc
+            first = jnp.maximum((kb * bk - offset) // bq, 0)
+            if not mask_all:
+                whole = jnp.clip(
+                    ((kb + 1) * bk - 1 - offset + bq - 1) // bq, first, nqb)
+        if mask_all or causal:
+            jax.lax.fori_loop(
+                first, whole, functools.partial(tile, masked=True), None)
+        if not mask_all:
+            jax.lax.fori_loop(
+                whole, nqb, functools.partial(tile, masked=False), None)
 
-    dq = jax.lax.fori_loop(0, num_kb, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+    jax.lax.fori_loop(0, nkb, key_block, None)
 
+    for qb in range(nqb):
+        dq_ref[0, 0, qb * bq:(qb + 1) * bq, :] = dq_acc[qb].T.astype(
+            dq_ref.dtype)
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    qseg_ref, kseg_ref, dk_ref, dv_ref, *,
-                    sm_scale: float, causal: bool, block_q: int,
-                    sq: int, sk: int, have_segs: bool):
-    kblk = pl.program_id(2)
-    bk, d = k_ref.shape[2], k_ref.shape[3]
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
-    k_pos = kblk * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    offset = sk - sq
-    nqb = pl.cdiv(sq, block_q)
-
-    if causal:
-        # first q block whose last row can see this k block
-        qb0 = jnp.maximum((kblk * bk - offset) // block_q, 0)
-    else:
-        qb0 = 0
-
-    def body(qb, carry):
-        dk_acc, dv_acc = carry
-        q = q_ref[0, 0, pl.ds(qb * block_q, block_q), :]
-        do = do_ref[0, 0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(qb * block_q, block_q), :]      # [bq,1]
-        delta = delta_ref[0, 0, pl.ds(qb * block_q, block_q), :]  # [bq,1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        q_pos = qb * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        mask = k_pos < sk
-        if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos + offset)
-        if have_segs:
-            qs = qseg_ref[0, pl.ds(qb * block_q, block_q), :]  # [bq,1]
-            ks = kseg_ref[0].reshape(1, bk)
-            mask = jnp.logical_and(mask, qs == ks)
-        p = jnp.exp(jnp.where(mask, s, NEG_INF) - lse)
-        p = jnp.where(mask, p, 0.0)
-        dv_acc += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [bk, d]
-        dp = jax.lax.dot_general(
-            do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [bq, bk]
-        ds = p * (dp - delta) * sm_scale
-        dk_acc += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [bk, d]
-        return dk_acc, dv_acc
-
-    dk, dv = jax.lax.fori_loop(
-        qb0, nqb, body,
-        (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32)))
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    @pl.when(rep == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd(q, k, v, q_seg, kv_seg, o, lse, do, causal, sm_scale,
@@ -365,73 +371,64 @@ def _bwd(q, k, v, q_seg, kv_seg, o, lse, do, causal, sm_scale,
     _, hkv, sk_p, _ = k.shape
     n_rep = hq // hkv
     bq, bk = block_q, block_k
+    nqb = sq_p // bq
     have_segs = q_seg is not None
 
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # [B,Hq,Sq_p,1]
+    def rows(x):
+        """A per-query vector [..., Sq_p] as lane rows, one a query block."""
+        return x.reshape(*x.shape[:-1], nqb, 1, bq)
 
-    kv_spec = pl.BlockSpec((1, 1, sk_p, d),
-                           lambda b_, h, i: (b_, h // n_rep, 0, 0))
-    q_blk_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h, i: (b_, h, i, 0))
-    vec_blk_spec = pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i: (b_, h, i, 0))
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
 
+    # grid (batch, kv head, query head of its group): K, V and the dk / dv
+    # blocks keep their index over the last dimension
+    q_spec = pl.BlockSpec((1, 1, sq_p, d),
+                          lambda b_, g, r: (b_, g * n_rep + r, 0, 0))
+    kv_spec = pl.BlockSpec((1, 1, sk_p, d), lambda b_, g, r: (b_, g, 0, 0))
+    row_spec = pl.BlockSpec((1, 1, nqb, 1, bq),
+                            lambda b_, g, r: (b_, g * n_rep + r, 0, 0, 0))
     if have_segs:
-        qseg_blk = pl.BlockSpec((1, bq, 1), lambda b_, h, i: (b_, i, 0))
-        kseg_full = pl.BlockSpec((1, sk_p, 1), lambda b_, h, i: (b_, 0, 0))
-        qseg_full = pl.BlockSpec((1, sq_p, 1), lambda b_, h, i: (b_, 0, 0))
-        kseg_blk = pl.BlockSpec((1, bk, 1), lambda b_, h, i: (b_, i, 0))
-        seg_args = [q_seg, kv_seg]
+        seg_specs = [
+            pl.BlockSpec((1, nqb, 1, bq), lambda b_, g, r: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, sk_p, 1), lambda b_, g, r: (b_, 0, 0))]
+        seg_args = [rows(q_seg[..., 0]), kv_seg]
     else:
-        qseg_blk = kseg_full = qseg_full = kseg_blk = _dummy_spec()
+        seg_specs = [_dummy_spec()] * 2
         seg_args = [_dummy_arg(), _dummy_arg()]
 
-    # ---- dq: grid over q blocks
-    dq = pl.pallas_call(
+    # resident a step: q, do, dq and k, v, dk, dv blocks (double-buffered,
+    # lane-padded), the float32 accumulators, the kv segment ids (an int32
+    # column, padded to 128 lanes); beside them the [bk, bq] score tiles
+    lanes = _round_up(d, 128)
+    resident = (2 * (3 * sq_p + 4 * sk_p) * lanes * q.dtype.itemsize
+                + (sq_p + 2 * sk_p) * lanes * 4
+                + (2 * sk_p * 128 * 4 if have_segs else 0))
+    need = resident + 8 * bq * bk * 4 + _VMEM_HEADROOM
+    compiler_params = dict(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    if need > _VMEM_UNASKED:
+        compiler_params["vmem_limit_bytes"] = need
+    return pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, block_k=bk,
-            sq=sq, sk=sk, have_segs=have_segs),
-        grid=(b, hq, sq_p // bq),
-        in_specs=[q_blk_spec, kv_spec, kv_spec, q_blk_spec, vec_blk_spec,
-                  vec_blk_spec, qseg_blk, kseg_full],
-        out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h, i: (b_, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq_p, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta, *seg_args)
-
-    # ---- dk/dv: grid over k blocks; per-q-head partials, summed over groups
-    q_full_spec = pl.BlockSpec((1, 1, sq_p, d), lambda b_, h, i: (b_, h, 0, 0))
-    kv_blk_spec = pl.BlockSpec((1, 1, bk, d),
-                               lambda b_, h, i: (b_, h // n_rep, i, 0))
-    vec_full_spec = pl.BlockSpec((1, 1, sq_p, 1),
-                                 lambda b_, h, i: (b_, h, 0, 0))
-    dk_hq, dv_hq = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
-            sq=sq, sk=sk, have_segs=have_segs),
-        grid=(b, hq, sk_p // bk),
-        in_specs=[q_full_spec, kv_blk_spec, kv_blk_spec, q_full_spec,
-                  vec_full_spec, vec_full_spec, qseg_full, kseg_blk],
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h, i: (b_, h, i, 0)),
-        ],
+            _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
+            block_k=bk, sq=sq, sk=sk, have_segs=have_segs),
+        grid=(b, hkv, n_rep),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
+                  *seg_specs],
+        out_specs=[q_spec, kv_spec, kv_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sk_p, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, sk_p, d), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
+        scratch_shapes=[
+            pltpu.VMEM((nqb, d, bq), jnp.float32),
+            pltpu.VMEM((sk_p, d), jnp.float32),
+            pltpu.VMEM((sk_p, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(**compiler_params),
         interpret=interpret,
-    )(q, k, v, do, lse, delta, *seg_args)
-
-    if n_rep > 1:
-        dk = dk_hq.reshape(b, hkv, n_rep, sk_p, d).sum(axis=2)
-        dv = dv_hq.reshape(b, hkv, n_rep, sk_p, d).sum(axis=2)
-    else:
-        dk, dv = dk_hq, dv_hq
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+    )(q, k, v, do, rows(lse[..., 0]), rows(delta), *seg_args)
 
 
 # ============================================================ custom_vjp
@@ -477,7 +474,7 @@ def flash_attention(
     """Flash attention. q: [B,Sq,Hq,D]; k/v: [B,Sk,Hkv,D] -> [B,Sq,Hq,D].
     With `return_lse` v may be [B,Sk,Hkv,Dv], Dv != D (latent attention's
     materialised form: keys of 192, values of 128) -> [B,Sq,Hq,Dv]; the
-    backward kernels take one width.
+    backward kernel takes one width.
 
     segment_ids: one [B,S] array (requires Sq == Sk), or a
     (q_segment_ids [B,Sq], kv_segment_ids [B,Sk]) pair for cached decode /
@@ -554,7 +551,7 @@ def flash_attention(
     if q_lens is not None or kv_lens is not None:
         if not return_lse:
             raise ValueError("q_lens / kv_lens are the forward-only path's "
-                             "(return_lse=True): the backward kernels take "
+                             "(return_lse=True): the backward kernel takes "
                              "no lengths")
         lens = jnp.stack([
             jnp.full((b,), n, jnp.int32) if a is None

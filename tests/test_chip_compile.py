@@ -182,6 +182,10 @@ COMPILES = {
     "decode-8b-B8-D128-MP512": _paged_decode(8, 128, 512),
     "decode-7b-B32-D128-MP168": _paged_decode(32, 128, 168),
     "fwdbwd-shard_map-2x2-mesh": _flash_on_mesh,
+    # the one-pass backward asks for the VMEM its shapes need (PR 44), so it
+    # compiles as far as the forward does: these were refused at 8192
+    "fwdbwd-kv8192": _flash(_grads(_fwd), (1, 8192, 32, 8, 64)),
+    "fwdbwd-kv16384-d128": _flash(_grads(_fwd), (1, 16384, 32, 8, 128)),
     # `mixtral-chat`: a decode step's 32 slots x 2 experts, and a prompt's
     # [1 x bucket] pass at the four buckets whose tile is not decode's
     "moe-gmm-mixtral-decode-M64": _moe_gmm(64),
@@ -191,10 +195,11 @@ COMPILES = {
     "moe-gmm-mixtral-bucket2048-M4096": _moe_gmm(4096),
 }
 # The kernel's measured compile limits: K/V of one (batch, kv head) stay
-# resident in VMEM, so long kv is refused (bwd passes at 4096, fwd at
-# 16384). The PR that tiles K/V flips these knowingly.
+# resident in VMEM, so long kv is refused: forward and backward pass at
+# 16384 and the forward is refused at 32768 (the backward's kernel would
+# need 170 MB there, more than the chip's 128 MiB). The PR that tiles K/V
+# flips these knowingly.
 REFUSED = {
-    "fwdbwd-kv8192-refused": _flash(_grads(_fwd), (1, 8192, 32, 8, 64)),
     "fwd-kv32768-refused": _flash(_fwd, (1, 32768, 32, 8, 64)),
     # the decode kernel's work item: its own rule gives 32 pages (2 MiB a
     # buffer) at these widths and 64 still compile; 128 are two buffers of
@@ -218,6 +223,42 @@ def test_kernel_compiles_for_v5e(topo, no_persistent_cache, name):
         # kernel gets unasked, where the segment ids it replaces were not
         # (a raised `vmem_limit_bytes` lowers to `scoped_memory_configs`)
         assert "scoped_memory_configs" not in lowered.as_text()
+
+
+def test_the_trainers_step_runs_three_kernels_a_layer(topo):
+    """`ShardedTrainer`'s step program for a described chip, the pretrain
+    cell's form (scanned layers, remat under the `dots` policy) at tiny
+    widths: a layer body's forward, its recomputed forward and its backward
+    are ONE `tpu_custom_call` each. Before PR 44 the backward was two (dq;
+    dk/dv), four a layer: the count is the evidence that the one-pass
+    backward is the kernel that runs."""
+    import flax.linen as nn
+
+    from ray_tpu.models.llama import LlamaModel, get_config
+    from ray_tpu.parallel.train_lib import ShardedTrainer, TrainState
+
+    cfg = get_config("tiny", remat=True, remat_policy="dots")
+    assert cfg.scan_layers
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape((1,) * len(AXES)), AXES)
+    trainer = ShardedTrainer(LlamaModel(cfg), mesh)
+    ids = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    batch = {"input_ids": ids}
+
+    def init(rng):  # `ShardedTrainer.init`, which runs what it builds
+        params = nn.meta.unbox(trainer.model.init(
+            rng, jnp.zeros(ids.shape, ids.dtype))["params"])
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=trainer.tx.init(params))
+
+    state = jax.eval_shape(init, jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        state, trainer.state_shardings(batch))
+    # the ops choose kernel or reference by the backend, at trace time
+    with pytest.MonkeyPatch.context() as mp_ctx:
+        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+        text = trainer.program_text(state, batch)
+    assert text.count("tpu_custom_call") == 3, text.count("tpu_custom_call")
 
 
 # ------------------------------------------- the engine's own programs

@@ -20,11 +20,11 @@ from ray_tpu.ops.paged_attention import merge_attention
 B, D = 2, 32
 
 
-def _make(sq, sk, hq=4, hkv=2, dtype=jnp.float32, seed=0):
+def _make(sq, sk, hq=4, hkv=2, dtype=jnp.float32, seed=0, d=D):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (B, sq, hq, D), dtype)
-    k = jax.random.normal(ks[1], (B, sk, hkv, D), dtype)
-    v = jax.random.normal(ks[2], (B, sk, hkv, D), dtype)
+    q = jax.random.normal(ks[0], (B, sq, hq, d), dtype)
+    k = jax.random.normal(ks[1], (B, sk, hkv, d), dtype)
+    v = jax.random.normal(ks[2], (B, sk, hkv, d), dtype)
     return q, k, v
 
 
@@ -71,16 +71,18 @@ def test_forward_segment_ids_tuple_decode():
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("sq,sk", [(128, 128), (64, 192)])
-def test_grad_parity(sq, sk):
-    q, k, v = _make(sq, sk)
+def _assert_grads_match(q, k, v, blocks=None, **kw):
+    """Gradients of the kernels (tile edges `blocks`, or the file's) against
+    the reference's, both given `kw`."""
+    edges = {} if blocks is None else dict(block_q=blocks[0],
+                                           block_k=blocks[1])
 
     def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, causal=True, interpret=True)
+        o = flash_attention(q, k, v, interpret=True, **edges, **kw)
         return jnp.sum(jnp.sin(o))  # nontrivial cotangent
 
     def loss_ref(q, k, v):
-        return jnp.sum(jnp.sin(reference_attention(q, k, v, causal=True)))
+        return jnp.sum(jnp.sin(reference_attention(q, k, v, **kw)))
 
     g_got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g_want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -89,25 +91,60 @@ def test_grad_parity(sq, sk):
                                    err_msg=f"d{name}")
 
 
-def test_grad_parity_with_segments():
-    sq = 128
-    q, k, v = _make(sq, sq)
-    segs = jnp.tile(jnp.repeat(jnp.arange(4, dtype=jnp.int32), sq // 4)[None],
+# the backward visits a (key block, query block) tile once, the tiles the
+# diagonal crosses under a mask and those below it without one: (sq, sk,
+# q heads, kv heads, head dim, causal, tile edges or None for the file's).
+# With edges of 128 a case has several tiles of each kind.
+GRAD_CASES = {
+    "128-128": (128, 128, 4, 2, D, True, None),
+    "64-192": (64, 192, 4, 2, D, True, None),
+    "mha-d64-2x2-tiles": (256, 256, 2, 2, 64, True, (128, 128)),
+    "group-of-4-d128-3x3-tiles": (384, 384, 4, 1, 128, True, (128, 128)),
+    "sq-lt-sk-2x4-tiles": (256, 512, 4, 2, D, True, (128, 128)),
+    "sq-lt-sk-offset-inside-a-tile": (128, 320, 4, 1, D, True, (128, 128)),
+    "pads-200": (200, 200, 4, 2, D, True, (128, 128)),
+    "pads-queries-only": (200, 256, 4, 2, D, True, (128, 128)),
+    "query-tile-wider": (512, 512, 4, 2, D, True, (256, 128)),
+    "key-tile-wider": (512, 512, 4, 2, D, True, (128, 256)),
+    "not-causal-2x3-tiles": (256, 384, 4, 2, D, False, (128, 128)),
+    "not-causal-pads": (130, 200, 2, 2, D, False, (128, 128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GRAD_CASES))
+def test_grad_parity(case):
+    sq, sk, hq, hkv, d, causal, blocks = GRAD_CASES[case]
+    q, k, v = _make(sq, sk, hq=hq, hkv=hkv, d=d)
+    _assert_grads_match(q, k, v, blocks, causal=causal)
+
+
+# packed rows (every tile masked): (s, q heads, kv heads, head dim,
+# segments a row, tile edges)
+SEGMENT_GRAD_CASES = {
+    "128-four-segments": (128, 4, 2, D, 4, None),
+    "group-of-4-d64-3x3-tiles": (384, 4, 1, 64, 3, (128, 128)),
+    "mha-d128-segments-inside-tiles": (256, 2, 2, 128, 8, (128, 128)),
+    "pads-200": (200, 4, 2, D, 4, (128, 128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SEGMENT_GRAD_CASES))
+def test_grad_parity_with_segments(case):
+    s, hq, hkv, d, n_seg, blocks = SEGMENT_GRAD_CASES[case]
+    q, k, v = _make(s, s, hq=hq, hkv=hkv, d=d)
+    segs = jnp.tile((jnp.arange(s, dtype=jnp.int32) * n_seg // s)[None],
                     (B, 1))
+    _assert_grads_match(q, k, v, blocks, causal=True, segment_ids=segs)
 
-    def loss_flash(q, k, v):
-        return jnp.sum(jnp.sin(flash_attention(
-            q, k, v, causal=True, segment_ids=segs, interpret=True)))
 
-    def loss_ref(q, k, v):
-        return jnp.sum(jnp.sin(reference_attention(
-            q, k, v, causal=True, segment_ids=segs)))
-
-    g_got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for got, want, name in zip(g_got, g_want, "qkv"):
-        np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5,
-                                   err_msg=f"d{name}")
+def test_grad_parity_with_a_segment_pair_over_a_longer_kv():
+    """Chunked, packed: 128 queries at the end of 256 keys, each side with
+    its own segment ids."""
+    sq, sk = 128, 256
+    q, k, v = _make(sq, sk, hq=4, hkv=1)
+    kv_seg = jnp.tile((jnp.arange(sk, dtype=jnp.int32) // 96)[None], (B, 1))
+    _assert_grads_match(q, k, v, (128, 128), causal=True,
+                        segment_ids=(kv_seg[:, -sq:], kv_seg))
 
 
 def test_jit_and_bf16():
@@ -261,13 +298,16 @@ def _jaxpr_sha(fn, *args):
 
 
 @pytest.mark.parametrize("case, sha", [
-    ("plain", "a4fed4a4e0c528f1"), ("packed", "032ab778af83649d"),
+    ("plain", "38539c9e0e3b2c09"), ("packed", "40fe6a56360f4f41"),
     ("lse", "bdb7d7bc1c8fca77")])
 def test_the_trainers_call_traces_to_the_jaxpr_it_had(case, sha):
     """The calls that pass no lengths, forward and backward kernels at the
-    pretrain cell's shapes: the jaxpr is PR 37's to the character
-    (`train_tok_s` has a bound of 1%). The pins were read on PR 37's tree
-    and on this one; a deliberate change of the kernels reads them anew."""
+    pretrain cell's shapes: the jaxpr is pinned to the character
+    (`train_tok_s` has a bound of 1%). `lse`, the forward alone, which is
+    all that serving runs, is PR 37's; `plain` and `packed` hold the
+    backward and were read anew by PR 44, which replaced its two kernels
+    with one (a4fed4a4e0c528f1 and 032ab778af83649d until then). A
+    deliberate change of the kernels reads them anew."""
     q = jnp.zeros((2, 2048, 32, 128), jnp.bfloat16)
     k = v = jnp.zeros((2, 2048, 8, 128), jnp.bfloat16)
 
