@@ -143,19 +143,10 @@ class JambaConfig:
                 + self.n_attn_layers * (attn + mlp)
                 + self.vocab_size * h + h + head)
 
-    # what serve/llm asks of a family whose layers keep per-slot state:
-    # how the engine's refusals word it (engine.py:
-    # _refuse_for_recurrent_state), how many layers, how many bytes a row
-    SLOT_STATE = "recurrent state-space state"
-    SPLIT_BY_TP = "the scan's d_inner axis and its per-slot state"
-    LAYER_KINDS = "follow a pattern of two kinds with two kinds of state"
-
+    # what serve/llm asks of a family whose layers keep per-slot state
     @property
     def n_slot_state_layers(self) -> int:
         return self.n_mamba_layers
-
-    def slot_state_bytes_row(self) -> int:
-        return self.ssm_state_bytes_row()
 
     def ssm_state_bytes_row(self) -> int:
         """What one sequence's recurrent state costs to read or write
@@ -212,6 +203,67 @@ def serving_model(cfg: JambaConfig, n_layers=None, first=True, last=True):
             "a slice of a model with a layer pattern: pipeline stages cut "
             "a uniform `layers` axis (serve/llm/stage.py: stage_params)")
     return JambaModel(cfg)
+
+
+# (stage.py: model_family) the state cannot be rolled back, resumed
+# mid-prompt, split, cut by layer slices or handed to another engine
+CANNOT_BE_GIVEN = ("keeps recurrent state-space state", {
+    "spec_lookahead":
+        "needs a verify dispatch whose rejected draft tokens can be "
+        "rolled back, and a state advanced past them cannot be (no "
+        "state snapshot yet)",
+    "prefill_chunk_tokens":
+        "needs a prefill that resumes from a slot's state, and a "
+        "prefill row starts from zero state",
+    "tp": "would have to split the scan's d_inner axis and its per-slot "
+          "state over the mesh, and nothing does yet",
+    "pp": "slices a uniform `layers` axis (stage_params), and this "
+          "model's layers follow a pattern of two kinds with two kinds "
+          "of state",
+    "handoff": "moves KV pages only, and a request's per-slot state "
+               "would be left behind",
+})
+
+
+class ScanStateFacts:
+    """What the state-space layers' dispatches count (serve/llm/stage.py:
+    model_family). Every record says `ssm_layers` (the layers that keep
+    recurrent state a decode slot) and `ssm_state_bytes_row` (the bytes
+    one live row's state costs to read or write once over all of them), so
+    that a reader needs no knowledge of the model."""
+
+    STATS = {
+        "ssm_scan_tokens_total":
+            "real prompt tokens x state-space layers scanned (prefill)",
+        "ssm_state_updates_total":
+            "live rows x fused steps x state-space layers updated (decode)",
+        "ssm_state_pool_bytes":
+            "bytes of the per-slot recurrent state pool (state-space "
+            "layers)",
+        "ssm_slots": "decode slots of the recurrent state pool",
+    }
+
+    def __init__(self, cfg: JambaConfig, engine_config):
+        self.layers = cfg.n_mamba_layers
+        self.slots = engine_config.max_batch
+        self.constant = {"ssm_layers": self.layers,
+                         "ssm_state_bytes_row": cfg.ssm_state_bytes_row()}
+
+    def prefill(self, totals: dict, rows, passes, ctx_pages: int) -> None:
+        totals["ssm_scan_tokens_total"] += self.layers * sum(
+            q for _, q, _ in rows)
+
+    def decode(self, totals: dict, rows, k: int) -> None:
+        totals["ssm_state_updates_total"] += len(rows) * k * self.layers
+
+    def sizes(self, pool_bytes: dict) -> dict:
+        return {"ssm_state_pool_bytes": (pool_bytes["ssm_h"]
+                                         + pool_bytes["ssm_conv"]),
+                "ssm_slots": self.slots}
+
+
+def dispatch_facts(cfg: JambaConfig, engine_config) -> list:
+    return [ScanStateFacts(cfg, engine_config)]
 
 
 def pool_spec(cfg: JambaConfig, n_layers: int, num_pages: int,

@@ -51,10 +51,10 @@ import numpy as np
 from flax import struct
 
 from ..ops.paged_attention import (LATENT_CTX_CHUNK, latent_attention_decode,
-                                   latent_lanes, latent_prefill_attention,
-                                   paged_write)
+                                   latent_ctx_chunks, latent_lanes,
+                                   latent_prefill_attention, paged_write)
 from ..ops.rotary import rotate, yarn_inv_freq, yarn_mscale
-from .llama import MLP, A, LlamaConfig, MoEMLP, RMSNorm
+from .llama import MLP, A, ExpertFacts, LlamaConfig, MoEMLP, RMSNorm
 
 # the family's interface flags (serve/llm/stage.py: model_family): pages
 # are all a sequence keeps, so a prefill row resumes from them and a page
@@ -205,6 +205,95 @@ def serving_model(cfg: KimiConfig, n_layers=None, first=True, last=True):
             "run: pipeline stages cut a uniform `layers` axis "
             "(serve/llm/stage.py: stage_params)")
     return KimiModel(cfg)
+
+
+# (stage.py: model_family) the options that would split a head axis the pool
+# has not, or that were never run on this family's two forms of attention
+CANNOT_BE_GIVEN = ("keeps one latent row a token for all heads", {
+    "tp": "shards the page pool over its kv-head axis "
+          "(ServeSharding.kv_pages_sharding), and a latent pool has one "
+          "row for every head: splitting the query heads would copy the "
+          "pool to every chip, and nothing does that yet",
+    "pp": "slices a uniform `layers` axis (stage_params), and this "
+          "model's stack is a run of dense layers and a run of expert "
+          "layers",
+    "spec_lookahead":
+        "verifies a draft through the materialised form while decode "
+        "runs the absorbed one, and acceptance compares their argmax "
+        "bit for bit: no verify dispatch of this family was ever run "
+        "against its decode",
+})
+
+
+class LatentFacts:
+    """What a latent pool's dispatches count (serve/llm/stage.py:
+    model_family). Every record says `mla_layers` (each keeps one latent
+    row a token) and `latent_bytes_token` (the bytes one token's latents
+    cost to read once over all of them as the pool stores them); a
+    prefill's `mla_ctx_chunks`, a tuple a real row: how many of the static
+    context chunks the pass materialised keys and values of (WHOLE, where
+    the row's context reaches into one: ops/paged_attention.py:
+    latent_prefill_attention); a harvest adds `moe_assignments_routed`.
+    The record's `moe_*` count the experts this model HOLDS: held / routed
+    is 1/32 where a chip holds 12 of 384 under even routing."""
+
+    STATS = {
+        "mla_decode_ctx_tokens_total":
+            "latent rows the absorbed decode kernel read, a layer (live "
+            "rows' contexts over fused steps)",
+        "mla_prefill_ctx_chunks_total":
+            "context chunks whose keys and values resumed prefill passes "
+            "materialised, a layer",
+        "mla_prefill_ctx_tokens_materialised_total":
+            "context tokens in those chunks (a chunk is materialised whole)",
+        "moe_assignments_routed_total":
+            "assignments the router made for real tokens (x experts per "
+            "token x expert layers); moe_assignments_total over it is the "
+            "share this chip's held experts got",
+        "latent_pool_bytes":
+            "bytes of a latent family's page pool (one row a token, all "
+            "heads)",
+    }
+
+    def __init__(self, cfg: KimiConfig, engine_config):
+        self.page = engine_config.page_size
+        self.chunk_tokens = cfg.ctx_chunk_tokens
+        self.routed_a_token = (cfg.n_expert_layers * cfg.num_experts_per_tok
+                               if cfg.num_experts else None)
+        self.constant = {"mla_layers": cfg.num_layers,
+                         "latent_bytes_token": cfg.latent_bytes_token}
+
+    def prefill(self, totals: dict, rows, passes, ctx_pages: int) -> dict:
+        chunks = latent_ctx_chunks(ctx_pages, self.page, self.chunk_tokens)
+        per_row = []
+        for _, n_new, end in rows:
+            live = [n for first, n in chunks
+                    if end - n_new > first * self.page]
+            per_row.append(len(live))
+            totals["mla_prefill_ctx_chunks_total"] += len(live)
+            totals["mla_prefill_ctx_tokens_materialised_total"] += (
+                sum(live) * self.page)
+        return {"mla_ctx_chunks": tuple(per_row)}
+
+    def decode(self, totals: dict, rows, k: int) -> None:
+        # a row's context at each fused step
+        totals["mla_decode_ctx_tokens_total"] += sum(
+            k * ctx + k * (k - 1) // 2 for _, _, ctx in rows)
+
+    def harvest(self, totals: dict, rec: dict, packed) -> Optional[dict]:
+        if self.routed_a_token is None:
+            return None
+        routed = sum(q for _, q, _ in rec["rows"]) * self.routed_a_token
+        totals["moe_assignments_routed_total"] += routed
+        return {"moe_assignments_routed": routed}
+
+    def sizes(self, pool_bytes: dict) -> dict:
+        return {"latent_pool_bytes": pool_bytes["kv_pages"]}
+
+
+def dispatch_facts(cfg: KimiConfig, engine_config) -> list:
+    return ([ExpertFacts(cfg)] if cfg.num_experts else []) + [
+        LatentFacts(cfg, engine_config)]
 
 
 def pool_spec(cfg: KimiConfig, n_layers: int, num_pages: int,

@@ -650,6 +650,57 @@ def moe_tile_rows(counts, tokens: int, cfg: LlamaConfig) -> int:
     return tile_visits(counts, tm) * tm
 
 
+class ExpertFacts:
+    """What an expert model's dispatches count (serve/llm/stage.py:
+    model_family), written once for the families with experts. Their
+    programs return, behind the tokens, the [steps, L, E] count of real
+    assignments an expert (stage.py: pack); a harvest reads it into the
+    record's `moe_assignments` and `moe_experts_touched` (the first two
+    totals, of this record) and `moe_expert_tokens_max` (the fullest
+    expert's count)."""
+
+    STATS = {
+        "moe_assignments_total":
+            "real (token, expert) assignments of an expert model, all layers",
+        "moe_experts_touched_total":
+            "experts with at least one real token, summed over layers and "
+            "steps",
+        "moe_tile_rows_total":
+            "rows the grouped matmul multiplied (tile visits x m-tile), all "
+            "layers; moe_assignments_total over it is the fill of its tiles",
+    }
+
+    def __init__(self, cfg: LlamaConfig):
+        self.cfg = cfg
+        self.layers = getattr(cfg, "n_expert_layers", cfg.num_layers)
+
+    def harvest(self, totals: dict, rec: dict, packed) -> dict:
+        layers, experts = self.layers, self.cfg.num_experts
+        # a prefill or a verify returns ONE count over the rows it computed
+        # (an expert a wave touches in two rows counts once: the least a
+        # wave has to read)
+        counts = packed[-rec["k"] * layers * experts:].reshape(
+            -1, layers, experts)
+        assignments, touched = int(counts.sum()), int((counts > 0).sum())
+        totals["moe_assignments_total"] += assignments
+        totals["moe_experts_touched_total"] += touched
+        # what the grouped matmul multiplied to serve them: a pass of the
+        # model is the slot set (decode) or one row's length bucket; a wave
+        # of several rows is counted as if their assignments were sorted
+        # together (each row pays boundary visits of its own: a floor)
+        rows = max(rec["rows_padded"], 1)
+        per_pass = {"decode": rows, "block": rec["tokens_padded"]}.get(
+            rec["kind"], rec["tokens_padded"] // rows)
+        # the pass that opens a block is two blocks wide a row
+        opening = int(rec["kind"] == "block")
+        totals["moe_tile_rows_total"] += moe_tile_rows(
+            counts[:opening], 2 * per_pass, self.cfg) + moe_tile_rows(
+                counts[opening:], per_pass, self.cfg)
+        return {"moe_assignments": assignments,
+                "moe_experts_touched": touched,
+                "moe_expert_tokens_max": int(counts.max())}
+
+
 def _stacked_experts(module: nn.Module, cfg: LlamaConfig, kv_caches):
     """The scanned stack of expert weights, (gate_up [L, E, h, 2f], down
     [L, E, f, h]), for the paged serving path; None anywhere else.
@@ -892,9 +943,7 @@ class LlamaModel(nn.Module):
 
 
 # ----------------------------------------------------------------- serving
-# What serve/llm/stage.py asks of a model family's module (this one,
-# models/jamba.py, models/minicpm_sala.py, models/sdar.py, models/kimi.py): get_config, serving_model,
-# pool_spec, serving_cache, RESUMES_PREFILL, pass_cost_ratios.
+# What serve/llm/stage.py asks of a model family's module: `model_family`.
 # pages are all a sequence keeps: a prefill row that starts mid-prompt
 # attends to its earlier pages (the path prefix hits use)
 RESUMES_PREFILL = True
@@ -942,6 +991,11 @@ def serving_cache(cfg: LlamaConfig, pool, block_tables, total_lens=None,
         total_lens=None if total_lens is None else jnp.broadcast_to(
             total_lens, tile + total_lens.shape),
         **static)
+
+
+def dispatch_facts(cfg: LlamaConfig, engine_config) -> list:
+    """(serve/llm/stage.py: model_family)"""
+    return [ExpertFacts(cfg)] if cfg.num_experts else []
 
 
 # ---------------------------------------------------------------- registry

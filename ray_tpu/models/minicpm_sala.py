@@ -50,11 +50,13 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import struct
 
 from ..ops.lightning_attention import lightning_prefill, lightning_update
 from ..ops.paged_attention import paged_prefill_attention, paged_write
 from ..ops.sparse_attention import (SparseParams, compress_keys,
+                                    kernels_scored, keys_attended,
                                     sparse_decode, sparse_prefill)
 from .llama import MLP, A, RMSNorm, rope
 
@@ -189,13 +191,7 @@ class SalaConfig:
     def n_sparse_layers(self) -> int:
         return self._count(SPARSE)
 
-    # what serve/llm asks of a family whose layers keep per-slot state:
-    # how the engine's refusals word it, how many layers, bytes a row
-    SLOT_STATE = "per-slot linear-attention state and compressed keys"
-    SPLIT_BY_TP = ("the lightning heads' per-slot matrices and the sparse "
-                   "layers' selection a kv head")
-    LAYER_KINDS = "are a list of two kinds with three kinds of state"
-
+    # what serve/llm asks of a family whose layers keep per-slot state
     @property
     def n_slot_state_layers(self) -> int:
         return self.n_lightning_layers
@@ -270,6 +266,105 @@ def serving_model(cfg: SalaConfig, n_layers=None, first=True, last=True):
             "pipeline stages cut a uniform `layers` axis "
             "(serve/llm/stage.py: stage_params)")
     return SalaModel(cfg)
+
+
+# (stage.py: model_family) a prefill row RESUMES from its slot's state, so
+# chunked prefill is served
+CANNOT_BE_GIVEN = ("keeps per-slot linear-attention state and compressed "
+                   "keys", {
+    "spec_lookahead":
+        "needs a verify dispatch whose rejected draft tokens can be "
+        "rolled back, and a state advanced past them cannot be (no "
+        "state snapshot yet)",
+    "tp": "would have to split the lightning heads' per-slot matrices "
+          "and the sparse layers' selection a kv head over the mesh, "
+          "and nothing does yet",
+    "pp": "slices a uniform `layers` axis (stage_params), and this "
+          "model's layers are a list of two kinds with three kinds of "
+          "state",
+    "handoff": "moves KV pages only, and a request's per-slot state "
+               "would be left behind",
+})
+
+
+class LightningSparseFacts:
+    """What the linear-attention and block-sparse layers' dispatches count
+    (serve/llm/stage.py: model_family). Every record says `lin_layers`,
+    `lin_state_bytes_row` (the bytes one live row's state costs to read or
+    write once) and `sparse_layers`; and of its real rows
+    `sparse_tokens_read` (keys the sparse layers attended, summed over
+    layers, kv-head groups and fused steps) and `sparse_kernels_scored`
+    (compressed keys scored the same way); a prefill's also `pass_index`
+    and `final`, a tuple a real row: how many passes of its prompt came
+    before this one, and whether it is the last. The selection's COUNT is
+    a function of the position alone (ops/sparse_attention.py:
+    keys_attended), so nothing is fetched from the device for it."""
+
+    STATS = {
+        "lightning_prefill_tokens_total":
+            "real prompt tokens x linear-attention layers (prefill)",
+        "lightning_state_updates_total":
+            "live rows x fused steps x linear-attention layers (decode)",
+        "sparse_blocks_selected_total":
+            "blocks the sparse layers attended, over queries, layers, kv "
+            "heads",
+        "sparse_ctx_tokens_total":
+            "keys a dense layer would have attended for the same queries",
+        "sparse_dense_rows_total":
+            "decode (row, step)s at a position under dense_len (no "
+            "selection)",
+        "lin_state_pool_bytes":
+            "bytes of the per-slot linear-attention state pool",
+        "sparse_index_pool_bytes":
+            "bytes of the compressed keys kept beside the pages",
+    }
+
+    def __init__(self, cfg: SalaConfig):
+        self.lin_layers = cfg.n_lightning_layers
+        self.sparse = cfg.sparse
+        # a layer and kv-head group selects for itself
+        self.selections = cfg.n_sparse_layers * cfg.num_kv_heads
+        self.constant = {"lin_layers": self.lin_layers,
+                         "lin_state_bytes_row": cfg.slot_state_bytes_row(),
+                         "sparse_layers": cfg.n_sparse_layers}
+
+    def _attended(self, totals: dict, positions: list, decode: bool) -> dict:
+        read = scored = 0
+        if self.selections and positions:
+            sp, per = self.sparse, self.selections
+            t = np.concatenate(positions)
+            keys = keys_attended(t, sp)
+            read = per * int(keys.sum())
+            scored = per * int(kernels_scored(t, sp).sum())
+            totals["sparse_blocks_selected_total"] += per * int(
+                ((keys - t % sp.block - 1) // sp.block + 1).sum())
+            totals["sparse_ctx_tokens_total"] += per * int((t + 1).sum())
+            if decode:
+                totals["sparse_dense_rows_total"] += int(
+                    (t < sp.dense_len).sum())
+        return {"sparse_tokens_read": read, "sparse_kernels_scored": scored}
+
+    def prefill(self, totals: dict, rows, passes, ctx_pages: int) -> dict:
+        totals["lightning_prefill_tokens_total"] += self.lin_layers * sum(
+            n for _, n, _ in rows)
+        return {**self._attended(
+            totals, [np.arange(end - n, end) for _, n, end in rows], False),
+            "pass_index": tuple(p for p, _ in passes),
+            "final": tuple(f for _, f in passes)}
+
+    def decode(self, totals: dict, rows, k: int) -> dict:
+        totals["lightning_state_updates_total"] += (self.lin_layers
+                                                    * len(rows) * k)
+        return self._attended(
+            totals, [ctx - 1 + np.arange(k) for _, _, ctx in rows], True)
+
+    def sizes(self, pool_bytes: dict) -> dict:
+        return {"lin_state_pool_bytes": pool_bytes["lin_state"],
+                "sparse_index_pool_bytes": pool_bytes["kc"]}
+
+
+def dispatch_facts(cfg: SalaConfig, engine_config) -> list:
+    return [LightningSparseFacts(cfg)]
 
 
 def pool_spec(cfg: SalaConfig, n_layers: int, num_pages: int,

@@ -34,8 +34,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from .llama import (RESUMES_PREFILL, LlamaConfig, pass_cost_ratios,  # noqa: F401
-                    pool_spec, serving_cache, serving_model)
+from .llama import (RESUMES_PREFILL, ExpertFacts, LlamaConfig,  # noqa: F401
+                    pass_cost_ratios, pool_spec, serving_cache, serving_model)
 
 REMASKING = ("low_confidence_dynamic", "low_confidence_static", "sequential")
 
@@ -134,6 +134,94 @@ def denoise(logits, ids, masked, step, cfg: SdarConfig, temperature, top_k,
 
 
 # ---------------------------------------------------------------- registry
+# (stage.py: model_family) a generation step fixes a block of a row's tokens
+# in passes that rewrite the block's keys: the options built on one token a
+# step have nothing to stand on, and `max_model_len` has to hold whole blocks
+CANNOT_BE_GIVEN = ("generates by diffusion over blocks of {cfg.block_length} "
+               "tokens", {
+    "max_model_len": "must hold whole blocks (a block is written whole)",
+    "spec_lookahead":
+        "verifies a draft under a causal mask one token after the "
+        "other, and a block's tokens are fixed in the order the model's "
+        "confidence chooses (no draft-and-verify use of the block "
+        "program yet)",
+    "tp": "runs the jnp attention paths under GSPMD, and the block "
+          "step's attention (every query of a block on the row's pages) "
+          "has no sharded form that was ever run",
+    "pp": "samples on the last stage and feeds the first, and a block's "
+          "passes are one program's loop: the next pass's ids are "
+          "chosen where the head is",
+    "handoff":
+        "moves KV pages and ONE pending token, and this model's prefill "
+        "yields no token: what it would hand over is a block's state "
+        "(ids and which of them are masked), which the blob has no "
+        "place for",
+})
+
+
+class BlockFacts:
+    """What a block program's dispatches count (serve/llm/stage.py:
+    model_family; `_block_program`). Its record's `rows` are (request_id,
+    block_len, ctx_tokens) with the block counted in the context, its
+    `moe_*` as a decode's; a harvest adds `block_passes` (the forward
+    passes the program ran, packed behind its tokens; the first is two
+    blocks wide where it settles a row's pending block),
+    `block_tokens_fixed` (the tokens its real rows emitted: the engine's
+    harvest says) and `block_len`. The engine's block scheduling moves the
+    totals of a block's life itself (settled, dropped, no token)."""
+
+    STATS = {
+        "block_dispatches_total":
+            "block programs enqueued (a model that generates by diffusion "
+            "over blocks: every denoising pass of a block, the first of "
+            "them two blocks wide where it settles the slot's pending "
+            "block)",
+        "block_passes_total":
+            "forward passes the block programs ran",
+        "block_tokens_total":
+            "tokens the block programs' real rows emitted; over "
+            "block_passes_total it is what an operator trades against "
+            "quality",
+        "block_rows_total": "real rows of the block programs",
+        "block_early_exits_total":
+            "block programs that left before denoising_steps passes: every "
+            "live row's block was fixed",
+        "block_settles_folded_total":
+            "pending blocks whose final keys the next block's opening pass "
+            "wrote; over block_dispatches_total x rows it is how full the "
+            "opening passes' second half runs",
+        "block_unsettled_dropped_total":
+            "last blocks of a request in its slot, which no pass settles: "
+            "nothing reads their keys; with block_settles_folded_total, the "
+            "blocks generated",
+        "prefill_tokenless_total":
+            "prompts whose prefill ended without a token (a block model's: "
+            "its first token comes out of its first block)",
+    }
+
+    def __init__(self, cfg: SdarConfig):
+        self.block = cfg.block_length
+
+    def decode(self, totals: dict, rows, k: int) -> None:
+        totals["block_dispatches_total"] += 1
+        totals["block_rows_total"] += len(rows)
+
+    def harvest(self, totals: dict, rec: dict, packed):
+        if rec["kind"] != "block":
+            return None
+        passes = int(packed[0])
+        totals["block_passes_total"] += passes
+        totals["block_tokens_total"] += rec["emitted"]
+        totals["block_early_exits_total"] += passes < rec["k"]
+        return {"block_passes": passes, "block_tokens_fixed": rec["emitted"],
+                "block_len": self.block}
+
+
+def dispatch_facts(cfg: SdarConfig, engine_config) -> list:
+    return ([ExpertFacts(cfg)] if cfg.num_experts else []) + [
+        BlockFacts(cfg)]
+
+
 CONFIGS = {
     # SDAR-30B-A3B-Chat (huggingface.co/JetLM/SDAR-30B-A3B-Chat
     # config.json; block length, steps and rule from its generation script)

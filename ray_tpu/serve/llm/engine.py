@@ -92,21 +92,12 @@ Scheduler v2 (token-budget continuous batching), on top of the above:
   past the accepted prefix sit beyond the request's total and are
   rewritten by the next dispatch before they ever become visible.
 
-Models with recurrent (state-space) layers (models/jamba.py) keep, beside
-the attention layers' pages, a fixed-size state per DECODE SLOT. The same
-scheduler serves them: a request's slot is assigned at admission, its
-prefill row starts from ZERO state and leaves the row's final state in
-that slot (so a preempted request's re-prefill inherits nothing), and a
-decode dispatch leaves every slot it does not decode bit for bit. What
-that state cannot do yet is refused at construction, by name
-(`_refuse_for_recurrent_state`), never served wrong: a page found by
-content hash carries no state, so prefix reuse is off; state cannot be
-rolled back (speculation), resumed mid-prompt (chunked prefill), split
-(tp), cut by layer slices (pp) or handed to another engine (disaggregated
-prefill). Models with linear-attention and block-sparse layers
-(models/minicpm_sala.py) keep a matrix a head per slot and, beside each
-page, its compressed keys; their prefill rows RESUME from the slot's state,
-so of the list above chunked prefill is served and the rest refused.
+A model's FAMILY says what its dispatches count and which options it
+cannot be given (serve/llm/stage.py: `model_family`; refused at
+construction, by name). Where its layers keep state a DECODE SLOT beside
+the pages, a request's slot is assigned at admission, its prefill row
+leaves its final state there, a decode dispatch leaves every slot it does
+not decode bit for bit, and prefix reuse is off.
 
 A prompt is prefilled in the PASSES that cost least (`plan_passes`), on
 the default scheduler: full buckets, then the smallest bucket that holds
@@ -139,7 +130,7 @@ import numpy as np
 from ...util import tracing
 from .cache import OutOfPages, PageAllocator, prefix_reuse_unsound
 from .stage import (_MAX_TOP_K, StageCompute, model_family,
-                    serve_model_config, ssm_layers)
+                    serve_model_config)
 
 WAITING, RUNNING, FINISHED = "WAITING", "RUNNING", "FINISHED"
 # where a step's nanoseconds go (indices into LLMEngine._phase_ns, in the
@@ -317,108 +308,36 @@ class EngineConfig:
     pp_fetch_timeout_s: float = 60.0
 
 
-def _refuse_for_recurrent_state(config: EngineConfig, model_cfg,
-                                mesh) -> None:
-    """A model whose layers keep state a decode slot (state-space layers,
-    linear-attention layers); the engine options that would need to copy,
-    split, roll back or resume that state are refused here, each by the
-    mechanism that is missing. The family's config words what it keeps
-    (`SLOT_STATE`, `SPLIT_BY_TP`, `LAYER_KINDS`)."""
-    if not ssm_layers(model_cfg):
-        return
-    model = f"model {config.model!r} keeps {model_cfg.SLOT_STATE}"
-    if config.spec_lookahead > 0:
-        raise NotImplementedError(
-            f"{model}: spec_lookahead={config.spec_lookahead} needs a "
-            f"verify dispatch whose rejected draft tokens can be rolled "
-            f"back, and a state advanced past them cannot be (no state "
-            f"snapshot yet)")
-    if (config.prefill_chunk_tokens > 0
-            and not model_family(config.model).RESUMES_PREFILL):
-        raise NotImplementedError(
-            f"{model}: prefill_chunk_tokens={config.prefill_chunk_tokens} "
-            f"needs a prefill that resumes from a slot's state, and a "
-            f"prefill row starts from zero state")
-    if config.tp > 1 or mesh is not None:
-        raise NotImplementedError(
-            f"{model}: tensor parallelism (tp={config.tp}, mesh="
-            f"{'given' if mesh is not None else None}) would have to split "
-            f"{model_cfg.SPLIT_BY_TP} over the "
-            f"mesh, and nothing does yet")
-    if config.pp > 1:
-        raise NotImplementedError(
-            f"{model}: pipeline parallelism (pp={config.pp}) slices a "
-            f"uniform `layers` axis (stage_params), and this model's "
-            f"layers {model_cfg.LAYER_KINDS}")
-
-
-def _refuse_for_block_diffusion(config: EngineConfig, model_cfg,
-                                mesh) -> None:
-    """A model that generates by diffusion over blocks (its config has a
-    `block_length`: models/sdar.py). A generation step fixes a block of a
-    row's tokens in passes that rewrite the block's keys; the engine
-    options built on one token a step are refused here, each by the
-    mechanism that is missing."""
+def refuse(config: EngineConfig, model_cfg, mesh=None,
+           handoff: bool = False) -> None:
+    """An engine option that is set (`handoff`: the disaggregated prefill ->
+    decode hand-off is asked for), and that the model's family lists in its
+    `CANNOT_BE_GIVEN` (stage.py: model_family), is refused by name with the
+    family's reason. How an option reads when it is set is written here."""
     block = getattr(model_cfg, "block_length", 0)
-    if not block:
-        return
-    model = (f"model {config.model!r} generates by diffusion over blocks "
-             f"of {block} tokens")
-    if config.max_model_len % block:
-        raise ValueError(
-            f"{model}: max_model_len={config.max_model_len} must hold "
-            f"whole blocks (a block is written whole)")
-    if config.spec_lookahead > 0:
-        raise NotImplementedError(
-            f"{model}: spec_lookahead={config.spec_lookahead} verifies a "
-            f"draft under a causal mask one token after the other, and a "
-            f"block's tokens are fixed in the order the model's confidence "
-            f"chooses (no draft-and-verify use of the block program yet)")
-    if config.tp > 1 or mesh is not None:
-        raise NotImplementedError(
-            f"{model}: tensor parallelism (tp={config.tp}, mesh="
-            f"{'given' if mesh is not None else None}) runs the jnp "
-            f"attention paths under GSPMD, and the block step's attention "
-            f"(every query of a block on the row's pages) has no sharded "
-            f"form that was ever run")
-    if config.pp > 1:
-        raise NotImplementedError(
-            f"{model}: pipeline parallelism (pp={config.pp}) samples on "
-            f"the last stage and feeds the first, and a block's passes "
-            f"are one program's loop: the next pass's ids are chosen "
-            f"where the head is")
-
-
-def _refuse_for_latent_pool(config: EngineConfig, model_cfg, mesh) -> None:
-    """A model whose pages hold one latent row a token for all heads (its
-    config has `latent_lanes`: models/kimi.py). The engine options that
-    would split a head axis the pool has not, or that were never run on
-    this family's two forms of attention, are refused here, each by the
-    mechanism that is missing."""
-    if not getattr(model_cfg, "latent_lanes", 0):
-        return
-    model = (f"model {config.model!r} keeps one latent row a token for all "
-             f"heads")
-    if config.tp > 1 or mesh is not None:
-        raise NotImplementedError(
-            f"{model}: tensor parallelism (tp={config.tp}, mesh="
-            f"{'given' if mesh is not None else None}) shards the page "
-            f"pool over its kv-head axis (ServeSharding.kv_pages_sharding)"
-            f", and a latent pool has one row for every head: splitting "
-            f"the query heads would copy the pool to every chip, and "
-            f"nothing does that yet")
-    if config.pp > 1:
-        raise NotImplementedError(
-            f"{model}: pipeline parallelism (pp={config.pp}) slices a "
-            f"uniform `layers` axis (stage_params), and this model's stack "
-            f"is a run of dense layers and a run of expert layers")
-    if config.spec_lookahead > 0:
-        raise NotImplementedError(
-            f"{model}: spec_lookahead={config.spec_lookahead} verifies a "
-            f"draft through the materialised form while decode runs the "
-            f"absorbed one, and acceptance compares their argmax bit for "
-            f"bit: no verify dispatch of this family was ever run against "
-            f"its decode")
+    what, why = getattr(model_family(config.model), "CANNOT_BE_GIVEN",
+                        ("", {}))
+    asked = {
+        "max_model_len": block and config.max_model_len % block
+        and f"max_model_len={config.max_model_len}",
+        "spec_lookahead": config.spec_lookahead > 0
+        and f"spec_lookahead={config.spec_lookahead}",
+        "prefill_chunk_tokens": config.prefill_chunk_tokens > 0
+        and f"prefill_chunk_tokens={config.prefill_chunk_tokens}",
+        "tp": (config.tp > 1 or mesh is not None)
+        and (f"tensor parallelism (tp={config.tp}, mesh="
+             f"{'given' if mesh is not None else None})"),
+        "pp": config.pp > 1 and f"pipeline parallelism (pp={config.pp})",
+        "handoff": handoff
+        and ("the disaggregated prefill/decode hand-off (prefill_only, "
+             "extract_kv, inject_request)"),
+    }
+    for option, how in asked.items():
+        if how and option in why:
+            error = (ValueError if option == "max_model_len"
+                     else NotImplementedError)
+            raise error(f"model {config.model!r} {what.format(cfg=model_cfg)}"
+                        f": {how} {why[option]}")
 
 
 def _bucket(n: int, buckets) -> int:
@@ -444,12 +363,6 @@ _BALANCE_TOKENS = 240
 # where 3.1-3.6: a twentieth, so the constant stands (and no prompt of the
 # cells' cycles plans otherwise down to 0.7 of it).
 _PAIR_PARAMS = 375
-# where an `engine.dispatch` record's device stamps begin (_harvest)
-_STAMPS_AT = tracing.FIELDS["engine.dispatch"].index("enqueued_ns")
-# and where a block program's three fields sit, behind every family's
-_BLOCK_AT = tracing.FIELDS["engine.dispatch"].index("block_passes")
-# and a latent family's four, behind those
-_MLA_AT = tracing.FIELDS["engine.dispatch"].index("mla_layers")
 
 
 def _attn_visits(bucket: int, width: int, real=None, ctx=None) -> tuple:
@@ -570,10 +483,7 @@ class LLMEngine:
 
     def __init__(self, config: EngineConfig, params=None, mesh=None):
         self.config = config
-        model_cfg = serve_model_config(config)
-        _refuse_for_recurrent_state(config, model_cfg, mesh)
-        _refuse_for_block_diffusion(config, model_cfg, mesh)
-        _refuse_for_latent_pool(config, model_cfg, mesh)
+        refuse(config, serve_model_config(config), mesh)
         self._build_compute(params, mesh)
         self.max_pages_per_seq = config.max_model_len // config.page_size
 
@@ -639,10 +549,6 @@ class LLMEngine:
         # used by dispatch, split and warmup
         self._wave_rb: int = (config.prefill_wave_size
                               or max(1, config.max_batch // 2))
-        # decode runs ONE compile shape: the full-width block table. The
-        # Pallas decode kernel walks only the pages a sequence actually
-        # uses, so block-table width no longer costs compute (the round-3
-        # mp buckets existed to shrink the gather; the gather is gone)
         # slots: fixed decode row assignment while a request is RUNNING
         self._free_slots: List[int] = list(range(config.max_batch))
         self._slot_req: Dict[int, Request] = {}
@@ -667,46 +573,36 @@ class LLMEngine:
             "prefill_attn_blocks_total",
             "prefill_attn_blocks_skipped_total",
             "prefill_attn_blocks_masked_total"), 0)
+        cfg_m = self.model_cfg
+        family = model_family(self.config.model)
         # a model that generates by diffusion over blocks: its block
         # length (0: one token a row and step), and where a prefill pass
         # may start (a page boundary that is a block boundary too)
-        self._block = getattr(self.model_cfg, "block_length", 0)
-        self._pass_align = config.page_size
-        # why a page found by its content hash may not be reused (None:
-        # it may)
+        self._block = getattr(cfg_m, "block_length", 0)
+        self._pass_align = math.lcm(config.page_size, self._block or 1)
+        # layers keep state a decode slot: a prefill row is told its slot
+        self._slot_state = getattr(cfg_m, "n_slot_state_layers", 0) > 0
+        # why a page found by its hash may not be reused (None: it may)
         self._prefix_off = None
-        if self._block:
-            self._pass_align = math.lcm(config.page_size, self._block)
+        if self._slot_state:
+            self._prefix_off = ("a page found by its content hash carries "
+                                "no recurrent state")
+        elif self._block:
             self._prefix_off = prefix_reuse_unsound(config.page_size,
                                                     self._block)
-            self._totals.update(dict.fromkeys((
-                "block_dispatches_total", "block_passes_total",
-                "block_tokens_total", "block_rows_total",
-                "block_early_exits_total", "block_settles_folded_total",
-                "block_unsettled_dropped_total",
-                "prefill_tokenless_total"), 0))
-            if self._prefix_off:
-                self._totals["prefix_reuse_refused_total"] = 0
-        # (layers, experts) of an expert model, whose programs return
-        # routing counts packed behind their tokens; None for a dense one
-        cfg_m = self.model_cfg
-        self._moe_LE = ((getattr(cfg_m, "n_expert_layers",
-                                 cfg_m.num_layers), cfg_m.num_experts)
-                        if cfg_m.num_experts else None)
-        if self._moe_LE:
-            self._totals.update(moe_assignments_total=0,
-                                moe_experts_touched_total=0,
-                                moe_tile_rows_total=0)
-        # layers with per-slot recurrent state of any kind (0: pages are
-        # all the state there is); of them the linear-attention layers,
-        # the rest being state-space layers; and the block-sparse
-        # attention layers with their selection rule
-        self._ssm_layers = ssm_layers(cfg_m)
-        self._lin_layers = getattr(cfg_m, "n_lightning_layers", 0)
-        self._scan_layers = self._ssm_layers - self._lin_layers
-        self._sparse_layers = getattr(cfg_m, "n_sparse_layers", 0)
-        self._sparse = cfg_m.sparse if self._sparse_layers else None
-        family = model_family(self.config.model)
+        if self._prefix_off:
+            self._totals["prefix_reuse_refused_total"] = 0
+        # what the family's dispatches count (stage.py: model_family): its
+        # keys of `_totals`, and the record fields every dispatch has
+        self.family_facts = family.dispatch_facts(cfg_m, config)
+        self._rec_constant: Dict[str, Any] = {}
+        for facts in self.family_facts:
+            self._totals.update(
+                (key, 0) for key in facts.STATS if key.endswith("_total"))
+            self._rec_constant.update(getattr(facts, "constant", ()))
+        # a block-sparse family's selection rule (None: every prefill
+        # pass's attention is the flash kernel's, whose visits are counted)
+        self._sparse = getattr(cfg_m, "sparse", None)
         # a prompt is prefilled in the passes that cost least
         # (`plan_passes`), by the chip's balance and the two ratios the
         # family answers for its model
@@ -718,43 +614,6 @@ class LLMEngine:
                 floor=_BALANCE_TOKENS * weights,
                 pair=_PAIR_PARAMS * scores)
         self._head_at_gather = getattr(family, "HEAD_AT_GATHER", False)
-        # the fields of its `engine.dispatch` records behind `k`, in
-        # `tracing.FIELDS`' order, None where the model has none: the
-        # `moe_*` positions (filled per record for an expert model), then
-        # the state-space layers and what one live row's state costs to
-        # read or write once, then the same for linear-attention layers
-        # and the count of sparse layers, so that a reader needs no
-        # knowledge of the model. Nothing for any other model.
-        self._ssm_fields: tuple = ()
-        if self._ssm_layers:
-            self._prefix_off = ("a page found by its content hash carries "
-                                "no recurrent state")
-            self._totals["prefix_reuse_refused_total"] = 0
-            self._ssm_fields = (None,) * (0 if self._moe_LE else 3)
-        if self._scan_layers:
-            self._ssm_fields += (self._scan_layers,
-                                 cfg_m.slot_state_bytes_row())
-            self._totals.update(ssm_scan_tokens_total=0,
-                                ssm_state_updates_total=0)
-        if self._lin_layers or self._sparse_layers:
-            self._ssm_fields += (None,) * (0 if self._scan_layers else 2) + (
-                self._lin_layers, cfg_m.slot_state_bytes_row(),
-                self._sparse_layers)
-            self._totals.update(lightning_prefill_tokens_total=0,
-                                lightning_state_updates_total=0,
-                                sparse_blocks_selected_total=0,
-                                sparse_ctx_tokens_total=0,
-                                sparse_dense_rows_total=0)
-        # a latent family (models/kimi.py): what its records say of every
-        # dispatch, and the static context chunks a resumed pass walks
-        self._mla = None
-        if getattr(cfg_m, "latent_lanes", 0):
-            self._mla = (cfg_m.num_layers, cfg_m.latent_bytes_token)
-            self._totals.update(dict.fromkeys((
-                "mla_decode_ctx_tokens_total",
-                "mla_prefill_ctx_chunks_total",
-                "mla_prefill_ctx_tokens_materialised_total",
-                "moe_assignments_routed_total"), 0))
         self._queue_wait_ns_total = 0
         # the device's timeline as the host can stamp it (_device_stamps):
         # the estimated end of the last program harvested and whether it
@@ -1346,7 +1205,7 @@ class LLMEngine:
             bt = np.zeros((rb, self.max_pages_per_seq), np.int32)
             total = np.zeros((rb,), np.int32)
             gather = np.zeros((rb,), np.int32)
-            slots = np.zeros((rb,), np.int32) if self._ssm_layers else None
+            slots = np.zeros((rb,), np.int32) if self._slot_state else None
             rows = []
             facts = []
             passes = []
@@ -1410,103 +1269,41 @@ class LLMEngine:
                 n_new for _, n_new in group)
             self._totals["prefill_padded_tokens_total"] += computed * sb
             self._totals["prefill_passes_total"] += computed
-            if self._scan_layers:
-                self._totals["ssm_scan_tokens_total"] += self._scan_layers \
-                    * sum(n_new for _, n_new in group)
             self._enqueue(
                 "prefill", tokens, r.start_ns, computed, computed * sb,
-                facts, group=rows,
-                tail=self._lin_sparse_facts(facts, 1, passes),
-                **self._mla_prefill_facts(facts, cp))
+                facts, self._family_fields("prefill", facts, passes, cp),
+                group=rows)
         self._phase_ns[_DISPATCH_PREFILL] += r.ns
 
+    def _family_fields(self, site: str, *what) -> Dict[str, Any]:
+        """The named record fields the family's facts add at one of the
+        three places a record is made ("prefill", "decode", "harvest":
+        stage.py: model_family), each moving its own keys of `_totals`."""
+        fields: Dict[str, Any] = {}
+        for facts in self.family_facts:
+            if hasattr(facts, site):
+                fields.update(getattr(facts, site)(self._totals, *what) or ())
+        return fields
+
     def _enqueue(self, kind: str, toks, dispatch_ns: int, rows_padded: int,
-                 tokens_padded: int, facts: List[tuple], k: int = 1,
-                 **harvest_keys) -> None:
+                 tokens_padded: int, facts: List[tuple],
+                 fields: Dict[str, Any], k: int = 1, **harvest_keys) -> None:
         """Queue one enqueued program for harvest. The dict is also its
-        `engine.dispatch` flight record in the making: `facts` is one
-        (request_id, q_tokens, ctx_tokens) per real row — the tokens the
+        `engine.dispatch` flight record in the making, by the record's
+        field names: `facts` (its `rows`) is one (request_id, q_tokens,
+        ctx_tokens) per real row — the tokens the
         row computes and the tokens of KV it attends to, cached prefix
         included (for a k-step decode row: at its first step) —
-        `rows_padded`/`tokens_padded` are what the program computes.
-        _harvest adds the fetch's timestamps and writes the record."""
+        `rows_padded`/`tokens_padded` are what the program computes,
+        `fields` the family's. _harvest adds the fetch's timestamps."""
         self._dispatch_seq += 1
         self._inflight.append({
+            **self._rec_constant, **fields,
             "kind": kind, "toks": toks, "k": k, "seq": self._dispatch_seq,
-            "step": self._step_seq, "dispatch_ns": dispatch_ns,
+            "step_dispatched": self._step_seq, "dispatch_ns": dispatch_ns,
             "enqueued_ns": tracing.now_ns(),
             "rows_padded": rows_padded, "tokens_padded": tokens_padded,
-            "facts": tuple(facts), "tail": (), **harvest_keys})
-
-    def _mla_prefill_facts(self, facts: List[tuple], cp: int) -> dict:
-        """A latent family's prefill record: how many of the static
-        context chunks each real row's pass materialised keys and values
-        of (a chunk is materialised WHOLE where the row's context reaches
-        into it; ops/paged_attention.py: latent_prefill_attention). Moves
-        the family's stats() totals. {} for any other model."""
-        if self._mla is None:
-            return {}
-        from ...ops.paged_attention import latent_ctx_chunks
-
-        page = self.config.page_size
-        chunks = latent_ctx_chunks(cp, page,
-                                   self.model_cfg.ctx_chunk_tokens)
-        per_row = []
-        for _, n_new, end in facts:
-            live = [n for first, n in chunks if end - n_new > first * page]
-            per_row.append(len(live))
-            self._totals["mla_prefill_ctx_chunks_total"] += len(live)
-            self._totals["mla_prefill_ctx_tokens_materialised_total"] += (
-                sum(live) * page)
-        return {"mla_ctx_chunks": tuple(per_row)}
-
-    def _lin_sparse_facts(self, facts: List[tuple], k_steps: int,
-                          passes=None) -> tuple:
-        """The per-record fields of a model with linear-attention and
-        block-sparse layers, behind `_ssm_fields`: `sparse_tokens_read`
-        (keys the sparse layers attend for the record's real rows, summed
-        over layers, kv-head groups and fused steps), `sparse_kernels_scored`,
-        and a prefill's `pass_index` / `final` a row (`passes`; None: a
-        decode chunk of `k_steps`). `facts` are the record's (request,
-        q_tokens, ctx_tokens) rows: a prefill row's queries sit at the last
-        q_tokens positions under ctx_tokens, a decode row's at ctx_tokens
-        - 1 and the k_steps - 1 after it. The selection's COUNT is a
-        function of the position alone (ops/sparse_attention.py:
-        keys_attended), so nothing is fetched from the device for it.
-        Moves the family's stats() totals too. () for any other model."""
-        if not (self._lin_layers or self._sparse_layers):
-            return ()
-        from ...ops.sparse_attention import keys_attended, kernels_scored
-
-        if passes is None:
-            positions = [ctx - 1 + np.arange(k_steps) for _, _, ctx in facts]
-            prefill_tokens, decode_updates = 0, len(facts) * k_steps
-        else:
-            positions = [np.arange(end - n, end) for _, n, end in facts]
-            prefill_tokens, decode_updates = sum(n for _, n, _ in facts), 0
-        tot = self._totals
-        tot["lightning_prefill_tokens_total"] += (self._lin_layers
-                                                  * prefill_tokens)
-        tot["lightning_state_updates_total"] += (self._lin_layers
-                                                 * decode_updates)
-        read = scored = 0
-        if self._sparse_layers and positions:
-            sp = self._sparse
-            t = np.concatenate(positions)
-            per = self._sparse_layers * self.model_cfg.num_kv_heads
-            keys = keys_attended(t, sp)
-            read = per * int(keys.sum())
-            scored = per * int(kernels_scored(t, sp).sum())
-            tot["sparse_blocks_selected_total"] += per * int(
-                ((keys - t % sp.block - 1) // sp.block + 1).sum())
-            tot["sparse_ctx_tokens_total"] += per * int((t + 1).sum())
-            if passes is None:
-                tot["sparse_dense_rows_total"] += int(
-                    (t < sp.dense_len).sum())
-        if passes is None:
-            return (read, scored, None, None)
-        return (read, scored, tuple(p for p, _ in passes),
-                tuple(f for _, f in passes))
+            "rows": tuple(facts), **harvest_keys})
 
     @staticmethod
     def _prompt_lookup_draft(req: Request, max_len: int) -> List[int]:
@@ -1609,7 +1406,7 @@ class LLMEngine:
         toks = self._compute_verify(sb, rb, len(rows), bt, total_arr, ids,
                                     positions)
         self._enqueue("spec", toks, dispatch_ns, len(rows), len(rows) * sb,
-                      facts, rows=recs)
+                      facts, {}, drafts=recs)
         return True
 
     def _decode_eligible(self) -> List[Request]:
@@ -1734,8 +1531,8 @@ class LLMEngine:
         toks = self._compute_decode(k_steps, mp, bt, total, caps,
                                     positions, override_mask,
                                     override_ids, temp, topk, keys_steps)
-        self._enqueue_decode(toks, dispatch_ns, k_steps, facts,
-                             chunk_slots)
+        self._enqueue_decode("decode", toks, dispatch_ns, k_steps,
+                             S * k_steps, facts, chunk_slots)
         return True
 
     def _dispatch_block(self) -> bool:
@@ -1799,41 +1596,33 @@ class LLMEngine:
                 req.block_pending = True
             toks = self._compute_block(key, bt, total, ids, masked, pending,
                                        temp, topk, keys_steps)
-            self._totals["block_dispatches_total"] += 1
-            self._totals["block_rows_total"] += len(facts)
-            self._enqueue("block", toks, r.start_ns, S, S * B, facts,
-                          k=steps, slots=block_slots)
+            self._enqueue_decode("block", toks, r.start_ns, steps, S * B,
+                                 facts, block_slots)
         return True
 
-    def _enqueue_decode(self, toks, dispatch_ns: int, k_steps: int,
-                        facts: List[tuple], chunk_slots: dict) -> None:
-        """_enqueue for a decode chunk over the full slot set, with the
-        stats() totals a decode dispatch moves."""
-        S = self.config.max_batch
-        self._totals["decode_dispatches_total"] += 1
-        self._totals["decode_rows_total"] += len(facts)
-        self._totals["decode_ctx_tokens_total"] += sum(
-            ctx for _, _, ctx in facts)
-        if self._scan_layers:
-            self._totals["ssm_state_updates_total"] += (
-                len(facts) * k_steps * self._scan_layers)
-        if self._mla:
-            # latents the absorbed kernel reads, a layer: a row's context
-            # at each fused step
-            self._totals["mla_decode_ctx_tokens_total"] += sum(
-                k_steps * ctx + k_steps * (k_steps - 1) // 2
-                for _, _, ctx in facts)
+    def _enqueue_decode(self, kind: str, toks, dispatch_ns: int,
+                        k_steps: int, tokens_padded: int,
+                        facts: List[tuple], slots: dict) -> None:
+        """_enqueue for a program over the full slot set (a decode chunk
+        with the stats() totals it moves, a block program)."""
+        if kind == "decode":
+            self._totals["decode_dispatches_total"] += 1
+            self._totals["decode_rows_total"] += len(facts)
+            self._totals["decode_ctx_tokens_total"] += sum(
+                ctx for _, _, ctx in facts)
         self._enqueue(
-            "decode", toks, dispatch_ns, S, S * k_steps, facts, k=k_steps,
-            slots=chunk_slots, tail=self._lin_sparse_facts(facts, k_steps))
+            kind, toks, dispatch_ns, self.config.max_batch, tokens_padded,
+            facts, self._family_fields("decode", facts, k_steps),
+            k=k_steps, slots=slots)
 
     # ---------------------------------------------------------- harvest
 
     def _device_stamps(self, rec: dict, ready: Optional[bool],
-                       fetch_start_ns: int, fetch_end_ns: int) -> tuple:
-        """(the record's `enqueued_ns`, `device_start_ns`,
-        `device_end_ns`, `end_exact`; whether start and end are both
-        exact). Programs run in dispatch order on one stream and are
+                       fetch_start_ns: int, fetch_end_ns: int) -> bool:
+        """Writes the record's `device_start_ns`, `device_end_ns` and
+        `end_exact` (None, and `enqueued_ns` with them, where the handle
+        cannot say); returns whether start and end are both exact.
+        Programs run in dispatch order on one stream and are
         harvested in that order. A program that had NOT finished when the
         host came to fetch it (`ready` False: the rule while the host
         runs ahead of the device) ended as the fetch returned. That is
@@ -1849,7 +1638,9 @@ class LLMEngine:
         which lies past the enqueue. The gap, where the device had
         nothing enqueued, is the step's and the engine's idle time."""
         if ready is None:
-            return (None, None, None, None), False
+            rec.update(dict.fromkeys(("enqueued_ns", "device_start_ns",
+                                      "device_end_ns", "end_exact")))
+            return False
         start = rec["enqueued_ns"]
         prev = self._device_end_ns
         start_exact = True
@@ -1864,18 +1655,18 @@ class LLMEngine:
         self._device_busy_ns_total += end - start
         self._step_fetch_blocked += not ready
         self._harvests_late_total += ready
-        return ((rec["enqueued_ns"], start, end, not ready),
-                start_exact and not ready)
+        rec.update(device_start_ns=start, device_end_ns=end,
+                   end_exact=not ready)
+        return start_exact and not ready
 
     def _harvest(self, rec: dict, deltas: List[OutputDelta]) -> None:
         ready = self._handle_ready(rec["toks"])
         with tracing.region("rtpu.engine.fetch") as fetch:
             toks_np = self._fetch_tokens(rec["toks"])
-        stamps, exact = self._device_stamps(rec, ready, fetch.start_ns,
-                                            fetch.end_ns)
-        _, device_start_ns, device_end_ns, _ = stamps
+        exact = self._device_stamps(rec, ready, fetch.start_ns, fetch.end_ns)
+        device_end_ns = rec["device_end_ns"]
         with tracing.region("rtpu.engine.harvest") as r:
-            toks_np, moe_facts = self._split_counts(rec, toks_np)
+            toks_np, packed = self._split_packed(rec, toks_np)
             if rec["kind"] == "prefill":
                 for i, (rid, slot, end, final) in enumerate(rec["group"]):
                     req = self.requests.get(rid)
@@ -1884,7 +1675,7 @@ class LLMEngine:
                     if device_end_ns is not None:
                         # every row of a wave waited for the whole program
                         req.prefill_device_ns += (device_end_ns
-                                                  - device_start_ns)
+                                                  - rec["device_start_ns"])
                         req.parts_exact &= exact
                         if final:
                             req.prefill_end_ns = device_end_ns
@@ -1914,7 +1705,7 @@ class LLMEngine:
                 # draft token fed at column j was the model's own choice, so
                 # everything before the first mismatch is exactly what plain
                 # greedy decode would have produced.
-                for i, (rid, slot, start, draft) in enumerate(rec["rows"]):
+                for i, (rid, slot, start, draft) in enumerate(rec["drafts"]):
                     req = self.requests.get(rid)
                     if req is None:
                         continue
@@ -1942,8 +1733,7 @@ class LLMEngine:
                         req.planned_out = len(req.output_ids)
                         self._slot_override[req.slot] = req.output_ids[-1]
             elif rec["kind"] == "block":
-                self._harvest_block(rec, toks_np, deltas, (
-                    device_start_ns, device_end_ns, exact))
+                self._harvest_block(rec, toks_np, deltas, exact)
             else:
                 # decode chunk: toks_np is [K, S]
                 k_steps = rec["k"]
@@ -1956,39 +1746,23 @@ class LLMEngine:
                         if req.state != RUNNING:
                             break
                         self._append_token(req, int(toks_np[k, slot]), deltas)
+            rec.update(self._family_fields("harvest", rec, packed))
         self._phase_ns[_FETCH] += fetch.ns
         self._phase_ns[_HARVEST] += r.ns
-        head = (rec["seq"], rec["kind"], rec["step"], self._step_seq,
-                rec["dispatch_ns"], fetch.start_ns, fetch.end_ns,
-                rec["rows_padded"], rec["tokens_padded"], rec["facts"],
-                rec["k"]) + moe_facts + self._ssm_fields + rec["tail"]
-        if "block" in rec:
-            head += (None,) * (_BLOCK_AT - len(head)) + rec["block"]
-        if self._mla:
-            routed = (sum(q for _, q, _ in rec["facts"]) * self._moe_LE[0]
-                      * self.model_cfg.num_experts_per_tok
-                      if self._moe_LE else None)
-            if routed is not None:
-                self._totals["moe_assignments_routed_total"] += routed
-            head += (None,) * (_MLA_AT - len(head)) + self._mla + (
-                rec.get("mla_ctx_chunks"), routed)
-        # the stamps come last, whatever fields the model's family wrote
-        tracing.record("engine.dispatch", head + (None,) * (
-            _STAMPS_AT - len(head)) + stamps)
+        rec.update(step_harvested=self._step_seq,
+                   fetch_start_ns=fetch.start_ns, fetch_end_ns=fetch.end_ns)
+        tracing.record("engine.dispatch", tuple(map(
+            rec.get, tracing.FIELDS["engine.dispatch"])))
 
     def _harvest_block(self, rec: dict, fetched: np.ndarray,
-                       deltas: List[OutputDelta], stamps: tuple) -> None:
-        """A block program's result (stage.py: `_block_program`): each
-        live row's tokens go out in position order as ONE delta with the
-        pass that fixed each; the record gains its three `block_*` fields,
-        behind every other family's. A row that opened over its pending
-        block settled it: that block's keys are final now."""
-        B, S = self._block, self.config.max_batch
-        ids = fetched[:S * B].reshape(S, B)
-        fixed_at = fetched[S * B:2 * S * B].reshape(S, B)
-        passes = int(fetched[2 * S * B])
-        device_start_ns, device_end_ns, exact = stamps
-        tot = self._totals
+                       deltas: List[OutputDelta], exact: bool) -> None:
+        """A block program's tokens (stage.py: `_block_program`; [2, S, B]:
+        the ids, and the pass that fixed each): each live row's tokens go
+        out in position order as ONE delta with the pass that fixed each,
+        and the record in the making is told how many (`emitted`). A row
+        that opened over its pending block settled it: its keys are final."""
+        ids, fixed_at = fetched
+        device_end_ns = rec["device_end_ns"]
         emitted = 0
         for slot, (rid, start, known, pending) in rec["slots"].items():
             req = self.requests.get(rid)
@@ -1998,17 +1772,15 @@ class LLMEngine:
             if req.first_token_ns is None and device_end_ns is not None:
                 # the first block's program is one of the request's OWN:
                 # its device time is the first token's, not a wait
-                req.prefill_device_ns += device_end_ns - device_start_ns
+                req.prefill_device_ns += (device_end_ns
+                                          - rec["device_start_ns"])
                 req.parts_exact &= exact
                 req.prefill_end_ns = device_end_ns
-            tot["block_settles_folded_total"] += pending
+            self._totals["block_settles_folded_total"] += pending
             req.block_unsettled = True
             emitted += self._append_block(
                 req, ids[slot, known:], fixed_at[slot, known:], deltas)
-        tot["block_passes_total"] += passes
-        tot["block_tokens_total"] += emitted
-        tot["block_early_exits_total"] += passes < rec["k"]
-        rec["block"] = (passes, emitted, B)
+        rec["emitted"] = emitted
 
     def _append_block(self, req: Request, tokens, fixed_at,
                       deltas: List[OutputDelta]) -> int:
@@ -2032,40 +1804,19 @@ class LLMEngine:
             fixed_pass=[int(p) for p in fixed_at[:len(new)]]))
         return len(new)
 
-    def _split_counts(self, rec: dict, fetched: np.ndarray) -> tuple:
-        """(tokens, the record's `moe_*` fields). An expert model's
-        program returns its tokens and, behind them, the [steps, L, E]
-        count of real assignments per expert (stage.py: pack); a dense
-        model's returns the tokens and the record gains nothing."""
-        if self._moe_LE is None:
-            return fetched, ()
-        from ...models.llama import moe_tile_rows
-
-        n = rec["k"] * self._moe_LE[0] * self._moe_LE[1]
-        counts = fetched[-n:].reshape((-1,) + self._moe_LE)
-        # a prefill or a verify returns tokens for every row of the
-        # wave-sized arrays and ONE count over the rows it computed (an
-        # expert a wave touches in two rows counts once: the least a wave
-        # has to read)
-        tokens = fetched[:-n].reshape({
-            "prefill": (-1,), "spec": (self._wave_rb, -1), "block": (-1,),
-            "decode": (-1, self.config.max_batch)}[rec["kind"]])
-        assignments, touched = int(counts.sum()), int((counts > 0).sum())
-        self._totals["moe_assignments_total"] += assignments
-        self._totals["moe_experts_touched_total"] += touched
-        # what the grouped matmul multiplied to serve them: a pass of the
-        # model is the slot set (decode) or one row's length bucket; a wave
-        # of several rows is counted as if their assignments were sorted
-        # together (each row pays boundary visits of its own: a floor)
-        rows = max(rec["rows_padded"], 1)
-        per_pass = {"decode": rows, "block": rec["tokens_padded"]}.get(
-            rec["kind"], rec["tokens_padded"] // rows)
-        # the pass that opens a block is two blocks wide a row
-        opening = int(rec["kind"] == "block")
-        self._totals["moe_tile_rows_total"] += moe_tile_rows(
-            counts[:opening], 2 * per_pass, self.model_cfg) + moe_tile_rows(
-                counts[opening:], per_pass, self.model_cfg)
-        return tokens, (assignments, touched, int(counts.max()))
+    def _split_packed(self, rec: dict, fetched: np.ndarray) -> tuple:
+        """(the program's tokens in the shape its kind's harvest reads: a
+        prefill's or a verify's for every row of the wave-sized arrays; what
+        it packed behind them for the family's facts, stage.py: pack)."""
+        S, rb = self.config.max_batch, self._wave_rb
+        shape = {
+            "prefill": (rb,),
+            "spec": (rb, rec["tokens_padded"] // max(rec["rows_padded"], 1)),
+            "decode": (rec["k"], S),
+            "block": (2, S, self._block)}[rec["kind"]]
+        flat = fetched.reshape(-1)
+        n = math.prod(shape)
+        return flat[:n].reshape(shape), flat[n:]
 
     def _preempt(self, req: Request) -> None:
         """Return a running request to the waiting queue, dropping its
@@ -2244,25 +1995,9 @@ class LLMEngine:
     # ------------------------------------------- prefill/decode handoff
 
     def _refuse_handoff(self) -> None:
-        """The disaggregated prefill -> decode hand-off moves pages only
-        (`_gather_kv`, kv_transfer.py)."""
-        if self._block:
-            raise NotImplementedError(
-                f"model {self.config.model!r} generates by diffusion over "
-                f"blocks of {self._block} tokens: the disaggregated "
-                f"prefill/decode hand-off (prefill_only, extract_kv, "
-                f"inject_request) moves KV pages and ONE pending token, "
-                f"and this model's prefill yields no token: what it would "
-                f"hand over is a block's state (ids and which of them are "
-                f"masked), which the blob has no place for")
-        if self._ssm_layers:
-            raise NotImplementedError(
-                f"model {self.config.model!r} keeps "
-                f"{self.model_cfg.SLOT_STATE}: the disaggregated "
-                f"prefill/decode hand-off "
-                f"(prefill_only, extract_kv, inject_request) moves KV "
-                f"pages only, and a request's per-slot state would be "
-                f"left behind")
+        """The disaggregated prefill -> decode hand-off moves pages and one
+        pending token (`_gather_kv`, kv_transfer.py)."""
+        refuse(self.config, self.model_cfg, handoff=True)
 
     def _gather_kv(self, req: Request) -> Dict[str, Any]:
         now = time.monotonic()
@@ -2467,17 +2202,9 @@ class LLMEngine:
             "device_idle_s_total": self._device_idle_ns_total / 1e9,
             "harvests_late_total": self._harvests_late_total,
         }
-        if self._ssm_layers and self.compute:
-            sizes = self.compute.pool_bytes()
-            if self._scan_layers:
-                out["ssm_state_pool_bytes"] = (sizes["ssm_h"]
-                                               + sizes["ssm_conv"])
-                out["ssm_slots"] = self.config.max_batch
-            if self._lin_layers:
-                out["lin_state_pool_bytes"] = sizes["lin_state"]
-                out["sparse_index_pool_bytes"] = sizes["kc"]
-        if self._mla and self.compute:
-            out["latent_pool_bytes"] = self.compute.pool_bytes()["kv_pages"]
+        for facts in self.family_facts:
+            if hasattr(facts, "sizes") and self.compute:
+                out.update(facts.sizes(self.compute.pool_bytes()))
         if self._block and self._prefix_off:
             out["prefix_reuse_refused_why"] = self._prefix_off
         if self.sharding is not None:

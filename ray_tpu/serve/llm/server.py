@@ -71,8 +71,15 @@ _LLM_METRICS = None
 # a token's pace and the three parts of a first token's wait are
 # milliseconds to seconds
 _MS_BOUNDARIES = (0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 1.0, 2.0)
-# engine.stats() totals published as rtpu_llm_<key> counters
+# engine.stats() totals published as rtpu_llm_<key> counters: the engine's
+# own. What a model family counts is declared by its dispatch facts
+# (stage.py: model_family) and is registered when its engine first publishes
 _LLM_WORK_TOTALS = {
+    "preempted_total": "requests preempted for page pressure",
+    "spec_drafted_total":
+        "speculative draft tokens dispatched for verification",
+    "spec_accepted_total":
+        "speculative draft tokens accepted by verification",
     "steps_total": "engine.step() calls",
     "prefill_dispatches_total": "prefill programs enqueued",
     "decode_dispatches_total": "decode programs enqueued",
@@ -95,44 +102,9 @@ _LLM_WORK_TOTALS = {
         "programs that had finished before the host came to fetch them: "
         "the host loop was behind the device (of prefill_dispatches_total "
         "+ decode_dispatches_total)",
-    "moe_assignments_total":
-        "real (token, expert) assignments of an expert model, all layers",
-    "moe_experts_touched_total":
-        "experts with at least one real token, summed over layers and steps",
-    "moe_tile_rows_total":
-        "rows the grouped matmul multiplied (tile visits x m-tile), all "
-        "layers; moe_assignments_total over it is the fill of its tiles",
-    "ssm_scan_tokens_total":
-        "real prompt tokens x state-space layers scanned (prefill)",
-    "ssm_state_updates_total":
-        "live rows x fused steps x state-space layers updated (decode)",
     "prefix_reuse_refused_total":
         "admissions that skipped the prefix lookup (recurrent state, or "
         "pages that hold no whole number of a block model's blocks)",
-    "block_dispatches_total":
-        "block programs enqueued (a model that generates by diffusion over "
-        "blocks: every denoising pass of a block, the first of them two "
-        "blocks wide where it settles the slot's pending block)",
-    "block_passes_total":
-        "forward passes the block programs ran",
-    "block_tokens_total":
-        "tokens the block programs' real rows emitted; over "
-        "block_passes_total it is what an operator trades against quality",
-    "block_rows_total": "real rows of the block programs",
-    "block_early_exits_total":
-        "block programs that left before denoising_steps passes: every "
-        "live row's block was fixed",
-    "block_settles_folded_total":
-        "pending blocks whose final keys the next block's opening pass "
-        "wrote; over block_dispatches_total x rows it is how full the "
-        "opening passes' second half runs",
-    "block_unsettled_dropped_total":
-        "last blocks of a request in its slot, which no pass settles: "
-        "nothing reads their keys; with block_settles_folded_total, the "
-        "blocks generated",
-    "prefill_tokenless_total":
-        "prompts whose prefill ended without a token (a block model's: its "
-        "first token comes out of its first block)",
     "prefill_passes_total": "prefill rows dispatched (a prompt takes the "
                             "passes that cost least: often one)",
     "prefill_resumed_passes_total":
@@ -152,73 +124,30 @@ _LLM_WORK_TOTALS = {
         "of the visits made (total less skipped), those that build a mask: "
         "the causal diagonal's tiles and the tile that holds the end of a "
         "row's context; the flash forward runs the others bare",
-    "lightning_prefill_tokens_total":
-        "real prompt tokens x linear-attention layers (prefill)",
-    "lightning_state_updates_total":
-        "live rows x fused steps x linear-attention layers (decode)",
-    "sparse_blocks_selected_total":
-        "blocks the sparse layers attended, over queries, layers, kv heads",
-    "sparse_ctx_tokens_total":
-        "keys a dense layer would have attended for the same queries",
-    "sparse_dense_rows_total":
-        "decode (row, step)s at a position under dense_len (no selection)",
-    "mla_decode_ctx_tokens_total":
-        "latent rows the absorbed decode kernel read, a layer (live rows' "
-        "contexts over fused steps)",
-    "mla_prefill_ctx_chunks_total":
-        "context chunks whose keys and values resumed prefill passes "
-        "materialised, a layer",
-    "mla_prefill_ctx_tokens_materialised_total":
-        "context tokens in those chunks (a chunk is materialised whole)",
-    "moe_assignments_routed_total":
-        "assignments the router made for real tokens (x experts per token "
-        "x expert layers); moe_assignments_total over it is the share "
-        "this chip's held experts got",
 }
-# engine.stats() sizes published as rtpu_llm_<key> gauges
+# engine.stats() sizes published as rtpu_llm_<key> gauges, likewise
 _LLM_SIZES = {
-    "ssm_state_pool_bytes":
-        "bytes of the per-slot recurrent state pool (state-space layers)",
-    "ssm_slots": "decode slots of the recurrent state pool",
-    "lin_state_pool_bytes":
-        "bytes of the per-slot linear-attention state pool",
-    "sparse_index_pool_bytes":
-        "bytes of the compressed keys kept beside the pages",
-    "latent_pool_bytes":
-        "bytes of a latent family's page pool (one row a token, all heads)",
+    "waiting": "requests queued for engine admission",
+    "running": "requests holding a decode slot",
+    "pages_free": "free KV pages (incl. evictable cached pages)",
 }
 
 
-def _get_llm_metrics():
+def _get_llm_metrics(family_facts=()):
     """Engine-scheduler metric family (``rtpu_llm_*``), lazily
     registered so importing the module costs nothing: queue gauges +
     scheduler counters the continuous-batching bench and dashboards
     read. Counters end ``_total``, gauges do not (RTPU106); the nodelet
     ships worker-side counters with get_node_info's serve family. The
     work counters are the flight recorder's totals (``engine.stats()``),
-    the five histograms are observed from its finished
-    ``engine.request`` records (ray_tpu/util/tracing.py)."""
+    the engine's own and those `family_facts` (an engine's) declare; the
+    five histograms are observed from its finished ``engine.request``
+    records (ray_tpu/util/tracing.py)."""
     global _LLM_METRICS
-    if _LLM_METRICS is None:
-        from ...util.metrics import Counter, Gauge, Histogram
+    from ...util.metrics import Counter, Gauge, Histogram
 
+    if _LLM_METRICS is None:
         _LLM_METRICS = {
-            "waiting": Gauge("rtpu_llm_waiting",
-                             "requests queued for engine admission"),
-            "running": Gauge("rtpu_llm_running",
-                             "requests holding a decode slot"),
-            "pages_free": Gauge(
-                "rtpu_llm_pages_free",
-                "free KV pages (incl. evictable cached pages)"),
-            "preempted": Counter(
-                "rtpu_llm_preempted_total",
-                "requests preempted for page pressure"),
-            "spec_drafted": Counter(
-                "rtpu_llm_spec_drafted_total",
-                "speculative draft tokens dispatched for verification"),
-            "spec_accepted": Counter(
-                "rtpu_llm_spec_accepted_total",
-                "speculative draft tokens accepted by verification"),
             "queue_wait": Histogram(
                 "rtpu_llm_queue_wait_seconds",
                 "arrival to first prefill dispatch, per finished request"),
@@ -246,10 +175,12 @@ def _get_llm_metrics():
                 "finished request",
                 boundaries=_MS_BOUNDARIES),
         }
-        for key, what in _LLM_WORK_TOTALS.items():
-            _LLM_METRICS[key] = Counter(f"rtpu_llm_{key}", what)
-        for key, what in _LLM_SIZES.items():
-            _LLM_METRICS[key] = Gauge(f"rtpu_llm_{key}", what)
+    for table in (_LLM_WORK_TOTALS, _LLM_SIZES,
+                  *(facts.STATS for facts in family_facts)):
+        for key, what in table.items():
+            if key not in _LLM_METRICS:
+                _LLM_METRICS[key] = (Counter if key.endswith("_total")
+                                     else Gauge)(f"rtpu_llm_{key}", what)
     return _LLM_METRICS
 
 
@@ -321,22 +252,17 @@ class EngineDriverMixin:
                 return
 
     def _publish_llm_metrics(self, stats: Dict[str, Any]) -> None:
-        m = _get_llm_metrics()
-        m["waiting"].set(stats.get("waiting", 0))
-        m["running"].set(stats.get("running", 0))
-        m["pages_free"].set(stats.get("pages_free", 0))
-        for key in _LLM_SIZES:
-            if key in stats:
-                m[key].set(stats[key])
-        for key, mk in (("preempted_total", "preempted"),
-                        ("spec_drafted_total", "spec_drafted"),
-                        ("spec_accepted_total", "spec_accepted"),
-                        *((k, k) for k in _LLM_WORK_TOTALS)):
-            cur = stats.get(key, 0)
-            delta = cur - self._llm_counts.get(key, 0)
-            if delta > 0:
-                m[mk].inc(delta)
-            self._llm_counts[key] = cur
+        m = _get_llm_metrics(getattr(getattr(self, "engine", None),
+                                     "family_facts", ()))
+        for key, metric in m.items():
+            if metric.metric_type == "gauge" and key in stats:
+                metric.set(stats[key])
+            elif metric.metric_type == "counter":
+                cur = stats.get(key, 0)
+                delta = cur - self._llm_counts.get(key, 0)
+                if delta > 0:
+                    metric.inc(delta)
+                self._llm_counts[key] = cur
         # latencies of the requests that finished since the last call
         seen = tracing.appended("engine.request")
         if seen != self._llm_requests_seen:

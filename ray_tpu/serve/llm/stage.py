@@ -17,11 +17,8 @@ The operands of a program after its state (params, pool[, carry]) are
 a dict. "x" is the stage's input: ids or hidden states.
 
 "The pool" is whatever the model's family keeps on the device between
-dispatches (`model_family(...).pool_spec`): one array of pages for a
-decoder whose every layer is attention, pages AND per-slot arrays for one
-with state-space layers (models/jamba.py), pages, per-slot matrices AND
-compressed keys indexed by page id for one with linear-attention and
-block-sparse layers (models/minicpm_sala.py). Either way it is ONE donated
+dispatches (`model_family(...).pool_spec`): one array of pages, or a dict
+of pages and per-slot arrays. Either way it is ONE donated
 argument, carried whole through the row loop and the step scan, and the
 model's cache object (`serving_cache`) is the only code that looks inside.
 A model with per-slot state gets one more prefill operand, the decode
@@ -91,28 +88,40 @@ def serve_dtype(config):
 
 def model_family(name: str):
     """The module that defines the preset `name`: the one place that
-    chooses a model family from `EngineConfig.model`. Five families:
-    models/llama.py (every layer attention, dense or experts),
-    models/jamba.py (state-space layers beside attention),
-    models/minicpm_sala.py (linear-attention layers beside block-sparse
-    attention) and models/sdar.py (llama's layers under a block mask,
-    generating by diffusion over blocks: its config has `block_length`
-    and the engine steps it with the "block" program) and models/kimi.py
-    (latent attention: a pool of ONE latent row a token for all heads,
-    absorbed in decode and materialised in prefill, and possibly one
-    chip's share of sigmoid-routed experts; its config has `latent_lanes`
-    and `n_expert_layers`). A family's module
-    has `CONFIGS`, `get_config`,
-    `serving_model`, `pool_spec`, `serving_cache`, and two flags:
+    chooses a model family from `EngineConfig.model`. Five families (each
+    module says what it is): models/llama.py, jamba.py, minicpm_sala.py,
+    sdar.py (its config has `block_length` and the engine steps it with the
+    "block" program), kimi.py (its config has `latent_lanes`).
+
+    This is the one description of what a family's module provides:
+    `CONFIGS`, `get_config`, `serving_model`, `pool_spec`, `serving_cache`;
     `RESUMES_PREFILL` (a prefill row continues from what its pages and
     its slot hold, so a prompt may be prefilled in passes; such a family
     also answers `pass_cost_ratios(cfg)`: the weights a pass reads and
     the scores a (query, key) pair of its attention makes, over the
-    parameters a token multiplies) and, where set,
-    `HEAD_AT_GATHER` (`serving_cache` takes the position each row samples
-    from and the model computes the head there only). Its config answers
-    `n_slot_state_layers` / `slot_state_bytes_row()` where layers keep
-    state a decode slot."""
+    parameters a token multiplies); where set, `HEAD_AT_GATHER`
+    (`serving_cache` takes the position each row samples from and the
+    model computes the head there only); its config answers
+    `n_slot_state_layers` where layers keep state a decode slot. And:
+
+    - `dispatch_facts(model_cfg, engine_config)`: a list of small host-side
+      objects (no JAX), one a feature of the model (three families list
+      models/llama.py's `ExpertFacts`), that say what its dispatches
+      count. Each has `STATS`, name -> help of the `stats()` counters it
+      moves (`*_total`) and the pool sizes it reports (`sizes(pool_bytes)`),
+      published as `rtpu_llm_<name>`; where it has any, `constant`, the
+      `engine.dispatch` record fields every dispatch has, and three hooks
+      that move the engine's totals and return record fields BY NAME
+      (util/tracing.py: FIELDS) or None: `prefill(totals, rows, passes,
+      ctx_pages)` and `decode(totals, rows, k)` when a program is enqueued
+      (`rows` the record's, `passes` a (pass index, final) a row),
+      `harvest(totals, rec, packed)` when its tokens are fetched (the
+      record in the making, what the program packed behind them).
+    - where set, `CANNOT_BE_GIVEN`: (what sets the model apart, {option:
+      the mechanism that is missing}) over `spec_lookahead`,
+      `prefill_chunk_tokens`, `tp`, `pp`, `handoff` (the disaggregated
+      hand-off) and `max_model_len` (one that holds no whole number of
+      blocks); engine.py's `refuse` raises it by name."""
     from ...models import jamba, kimi, llama, minicpm_sala, sdar
 
     families = (llama, jamba, minicpm_sala, sdar, kimi)
@@ -130,13 +139,6 @@ def serve_model_config(config):
         config.model, scan_layers=True, remat=False, dtype=dtype,
         param_dtype=dtype, max_seq_len=config.max_model_len,
         **config.model_overrides)
-
-
-def ssm_layers(model_cfg) -> int:
-    """How many of the model's layers keep recurrent state a decode slot
-    (state-space layers, linear-attention layers: the family's config
-    says); 0 for a decoder whose only state is pages."""
-    return getattr(model_cfg, "n_slot_state_layers", 0)
 
 
 def init_params(model, example, rng):
@@ -283,7 +285,7 @@ class StageCompute:
         self.model = family.serving_model(cfg, n_layers, self.first,
                                           self.last)
         # layers of this slice that keep per-slot recurrent state
-        self.ssm_layers = ssm_layers(cfg)
+        self.ssm_layers = getattr(cfg, "n_slot_state_layers", 0)
         self.max_pages_per_seq = config.max_model_len // config.page_size
         # tensor parallelism: resolve mesh/tp BEFORE any compute so the
         # divisibility contract fails at construction, not first dispatch
@@ -471,7 +473,7 @@ class StageCompute:
             """The program's result: a last stage's tokens are
             host-bound, and an expert model's counts go behind them in
             ONE int32 array, so the harvest's single fetch brings both
-            (`LLMEngine._split_counts`); hidden states go to the next
+            (`LLMEngine._split_packed`); hidden states go to the next
             stage with the counts beside them."""
             if counts is None:
                 return kept

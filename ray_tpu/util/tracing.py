@@ -156,55 +156,20 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         "output_tokens", "preemptions", "finish_reason",
         "device_wait_ns", "prefill_device_ns", "harvest_host_ns",
         "parts_exact"),
-    # one per program enqueued, written when its tokens are harvested;
-    # `rows` is a tuple of (request_id, q_tokens, ctx_tokens) per real row.
-    # The three `moe_*` fields are written for an expert model only (a
-    # dense model's record ends at `k`): real assignments (real tokens x
-    # experts per token x layers), experts with at least one real token
-    # summed over layers and fused steps, the fullest expert's count.
-    # The two `ssm_*` fields are written for a model with state-space
-    # layers only (its `moe_*` positions hold None unless it has experts
-    # too): how many layers keep per-slot recurrent state, and the bytes
-    # one live row's state costs to read or write once over all of them.
-    # The seven fields behind them are written for a model with
-    # linear-attention and block-sparse layers only (the positions before
-    # hold None): its linear-attention layers and the bytes one live row's
-    # state costs to read or write once, its sparse layers, the keys they
-    # attended for the record's real rows (summed over layers, kv-head
-    # groups and fused steps), the compressed keys scored the same way,
-    # and for a prefill a tuple a real row of how many passes of its
-    # prompt came before this one and whether it is the last.
-    # The three `block_*` fields are written for a program of kind
-    # "block" only (a model that generates by diffusion over blocks; its
-    # `rows` are (request_id, block_len, ctx_tokens) with the block
-    # counted in the context, its `moe_*` as a decode's, the positions
-    # between hold None): the forward passes the program ran (the first is
-    # two blocks wide where it settles a row's pending block); the tokens
-    # its real rows emitted; the block length.
-    # The four behind them are written for a model with latent attention
-    # only (models/kimi.py; the positions between hold None): its layers
-    # (each keeps one latent row a token), the bytes one token's latents
-    # cost to read once over all of them as the pool stores them, for a
-    # prefill a tuple a real row of how many context chunks the pass
-    # materialised keys and values of (beside the row's `ctx_tokens`), and
-    # the assignments its router made for the record's real tokens (tokens
-    # x experts per token x expert layers). Such a model's `moe_*` count
-    # the experts it HOLDS: held / routed is 1/32 where a chip holds 12 of
-    # 384 under even routing.
+    # one per program enqueued, written when its tokens are harvested,
+    # built BY NAME: a field nobody wrote is None. Through `k` the fields
+    # are the engine's own; `rows` is a tuple of (request_id, q_tokens,
+    # ctx_tokens) per real row. From `moe_assignments` through
+    # `moe_assignments_routed` they are a model family's, documented where
+    # they are produced (its dispatch facts: serve/llm/stage.py:
+    # model_family). The ORDER stands: hand-made records of the benchmark's
+    # own tests are laid out by position.
     # The last four are the program on the device's timeline, stamped by
-    # the host with no profiler (programs run in dispatch order on one
-    # stream): `enqueued_ns` when the compute seam returned (`dispatch_ns`
-    # is taken before the host builds the program's arrays),
-    # `device_end_ns` the end of the fetch if the program had not finished
-    # when the host came to fetch it (`end_exact`: the fetch then returns
-    # as the program ends, behind it by the completion's way to the host
-    # and the copy of the tokens, the same lag for every program) and else
-    # the fetch's start, an upper bound,
-    # `device_start_ns` the later of `enqueued_ns` and the previous
-    # program's `device_end_ns`. They come LAST, behind the families'
-    # fields (None for a model without them), because hand-made records
-    # of the benchmark's own tests are laid out by position; None in all
-    # four where the handle cannot say whether it is ready (pp)
+    # the host with no profiler (serve/llm/engine.py: _device_stamps says
+    # how, and when an end is exact): `enqueued_ns` when the compute seam
+    # returned (`dispatch_ns` is taken before the host builds the program's
+    # arrays); None in all four where the handle cannot say whether it is
+    # ready (pp)
     "engine.dispatch": (
         "seq", "kind", "step_dispatched", "step_harvested", "dispatch_ns",
         "fetch_start_ns", "fetch_end_ns", "rows_padded", "tokens_padded",

@@ -837,6 +837,71 @@ t._Pending.__array__ = lambda self, dtype=None, copy=None: (
         "fetched 0", "fetched 1", "fetched 2", "an earlier handler"]
 
 
+# the `engine.dispatch` fields a model family writes (its dispatch facts:
+# serve/llm/stage.py: model_family), for its tiny preset: in every record,
+# and in the records of one kind beside those
+_MOE = {"moe_assignments", "moe_experts_touched", "moe_expert_tokens_max"}
+_SALA = {"lin_layers", "lin_state_bytes_row", "sparse_layers",
+         "sparse_tokens_read", "sparse_kernels_scored"}
+_MLA = {"mla_layers", "latent_bytes_token", "moe_assignments_routed"}
+FAMILY_FIELDS = {
+    "tiny-moe": (_MOE, {}),
+    "tiny-jamba": ({"ssm_layers", "ssm_state_bytes_row"}, {}),
+    "tiny-sala": (_SALA, {"prefill": {"pass_index", "final"}}),
+    "tiny-sdar": (_MOE, {"block": {"block_passes", "block_tokens_fixed",
+                                   "block_len"}}),
+    "tiny-kimi": (_MOE | _MLA, {"prefill": {"mla_ctx_chunks"}}),
+}
+
+
+@pytest.mark.parametrize("preset", sorted(FAMILY_FIELDS))
+def test_a_familys_records_hold_its_fields_and_no_other_familys(preset):
+    """Every `engine.dispatch` record of every family is `FIELDS` long;
+    between the engine's own fields and the stamps a family's fields are
+    set in its records and every other family's are None; and every
+    counter and pool size of `stats()` is published with a help string."""
+    from ray_tpu.serve.llm.server import EngineDriverMixin
+    from ray_tpu.util import metrics
+
+    page = 8 if preset == "tiny-jamba" else 16
+    engine = LLMEngine(EngineConfig(
+        model=preset, dtype="float32", page_size=page, num_pages=96,
+        max_model_len=256, max_batch=4, prefill_buckets=(32, 64), seed=3))
+    rng = np.random.default_rng(4)
+    # shorter than a block, one bucket, and (where a prefill resumes) more
+    # than the largest bucket holds
+    lens = (3, 41, 64) if preset == "tiny-jamba" else (3, 41, 100)
+    for i, n in enumerate(lens):
+        engine.add_request(f"f{i}", rng.integers(1, 200, n).tolist(),
+                           SamplingParams(max_tokens=6))
+    _run(engine)
+    fields = tracing.FIELDS["engine.dispatch"]
+    family = set(fields[fields.index("moe_assignments"):
+                        fields.index("enqueued_ns")])
+    every, by_kind = FAMILY_FIELDS[preset]
+    kinds = set()
+    for rec in tracing.records("engine.dispatch"):
+        assert len(rec) == len(fields)
+        r = dict(zip(fields, rec))
+        kinds.add(r["kind"])
+        assert {f for f in family if r[f] is not None} == (
+            every | by_kind.get(r["kind"], set())), r
+    assert kinds == {"prefill", "block" if preset == "tiny-sdar"
+                     else "decode"}
+    stats = engine.stats()
+    driver = EngineDriverMixin()
+    driver.engine = engine
+    driver._init_driver()
+    driver._publish_llm_metrics(stats)
+    # (`expired_total` is the admission plane's to publish, as a shed)
+    published = [k for k in stats if k != "expired_total" and (
+        k.endswith(("_total", "_pool_bytes")) or k == "ssm_slots")]
+    assert len(published) > 20
+    for key in published:
+        assert metrics._registry[f"rtpu_llm_{key}"].description, key
+    engine.close()
+
+
 def test_trainer_step_leaves_one_record_a_call():
     import jax
 

@@ -481,7 +481,8 @@ def test_counters_records_and_the_first_tokens_three_parts():
     # the span lies inside the step record's dispatch_decode
     from ray_tpu.serve.llm import server
 
-    assert "block_tokens_total" in server._LLM_WORK_TOTALS
+    assert "block_tokens_total" in server._get_llm_metrics(
+        engine.family_facts)
     engine.close()
 
 
