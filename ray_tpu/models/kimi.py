@@ -51,7 +51,7 @@ import numpy as np
 from flax import struct
 
 from ..ops.paged_attention import (LATENT_CTX_CHUNK, latent_attention_decode,
-                                   latent_ctx_chunks, latent_lanes,
+                                   ctx_chunks, latent_lanes,
                                    latent_prefill_attention, paged_write)
 from ..ops.rotary import rotate, yarn_inv_freq, yarn_mscale
 from .llama import MLP, A, ExpertFacts, LlamaConfig, MoEMLP, RMSNorm
@@ -264,7 +264,7 @@ class LatentFacts:
                          "latent_bytes_token": cfg.latent_bytes_token}
 
     def prefill(self, totals: dict, rows, passes, ctx_pages: int) -> dict:
-        chunks = latent_ctx_chunks(ctx_pages, self.page, self.chunk_tokens)
+        chunks = ctx_chunks(ctx_pages, self.page, self.chunk_tokens)
         per_row = []
         for _, n_new, end in rows:
             live = [n for first, n in chunks

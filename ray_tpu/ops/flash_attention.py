@@ -43,6 +43,12 @@ ABSENT").  Here the hot op is owned natively: a blocked online-softmax
   query block past a row's queries runs no loop and the key loop ends with
   the row's keys, so a bucket's padding and a block table's unused width
   cost nothing; the shapes, and so the programs, stay the buckets',
+- on the same path a static sliding `window` (a model whose layers mix
+  windowed and full attention, models/mellum.py): a query sees the W keys up
+  to its own place; a query block's key loop STARTS at the first tile the
+  band touches (`_band_trips`: tiles wholly behind the band are not
+  visited, not masked) and the tiles the band's lower edge crosses are edge
+  tiles as the diagonal's are. The backward has no band and refuses one,
 - backward pass as ONE Pallas kernel using the saved log-sum-exp: a grid
   step is one query head against its kv head's K and V, and visits every
   (key block, query block) tile once, computing s, p, dp and ds there
@@ -168,10 +174,33 @@ def _fwd_trips(qblk, *, bq: int, block_k: int, sq: int, sk: int,
     return xp.minimum(interior, num_kb), num_kb
 
 
+def _band_offset(causal: bool, sq: int, sk: int, kv_len):
+    """Where query 0 of a windowed call stands among its keys: a causal
+    call's queries are the LAST `sq` of `sk` positions (its diagonal's
+    offset); a call that is not causal is the context part of a resumed
+    pass, and its queries FOLLOW the row's keys (`kv_len`, or all `sk`)."""
+    if causal:
+        return sk - sq
+    return sk if kv_len is None else kv_len
+
+
+def _band_trips(qblk, *, bq: int, block_k: int, window: int, woff, xp=jnp):
+    """(first, below): under a window (query i sees key j iff j > i + woff -
+    window) query block `qblk` visits no key block before `first`, those
+    wholly behind the band of its first row, and `[first, below)` are EDGE
+    tiles: each holds a key that some row of the block does not see (the
+    band's lower edge crosses them). From `below` on the band cuts nothing
+    and `_fwd_trips` says what a tile is."""
+    first_sees = qblk * bq + woff - window + 1       # the first row's
+    last_sees = (qblk + 1) * bq + woff - window      # the last row's
+    return (xp.maximum(first_sees, 0) // block_k,
+            (xp.maximum(last_sees, 0) + block_k - 1) // block_k)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
                 sm_scale: float, causal: bool, block_k: int,
                 sq: int, sk: int, have_segs: bool, q_len=None, kv_len=None,
-                block_causal: int = 0):
+                block_causal: int = 0, window: Optional[int] = None):
     """One grid step: one query block of `g` query heads (`_fold`: 1 at a
     whole block) against their kv head's K and V, the heads side by side
     along the lanes. `q_len` / `kv_len`: this batch row's true lengths
@@ -179,7 +208,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
     `block_causal` = B > 0: the causal relation is between blocks of B
     positions and a block sees itself whole (`ops.attention.causal_mask`);
     B divides the query block and `sk - sq`, so the key blocks a query
-    block walks are causal's.
+    block walks are causal's. `window` = W: a query also sees only the W
+    keys up to its own place, `k_pos > q_pos + woff - W` (`_band_offset`);
+    the key loop starts at the first tile the band touches and the tiles
+    its lower edge crosses are edge tiles too (`_band_trips`).
 
     Scores are held transposed, `[block_k, g * block_q]`, as `_bwd_kernel`
     holds them: the running maximum `m`, the sum `l` and `lse` are lane
@@ -232,17 +264,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
                     (q_pos + offset) // block_causal + 1) * block_causal)
             elif causal:
                 mask = jnp.logical_and(mask, k_pos <= q_pos + offset)
+            if window is not None:
+                mask = jnp.logical_and(mask, k_pos > q_pos + (
+                    _band_offset(causal, sq, sk, kv_len) - window))
             if have_segs:
                 mask = jnp.logical_and(
                     mask, kseg_ref[0, ks, :] == q_seg)
             s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp2((s - m_new) * c)
-        if masked and have_segs:
+        if masked and (have_segs or window is not None):
             # a query whose every key so far is another segment's has m ==
             # NEG_INF, and its p reads exp2(0). Without segment ids key 0
             # is visible to every query of the first tile, so m is a real
             # score from there on and a masked p underflows to 0 by itself
+            # (under a window the first tile a block visits is its first
+            # row's: a later row may see none of it)
             p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp2((m - m_new) * c)
         l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
@@ -255,8 +292,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
         qblk, bq=bq, block_k=block_k, sq=sq, sk=sk, causal=causal,
         have_segs=have_segs, q_len=q_len, kv_len=kv_len,
         block_causal=block_causal)
+    carry, first = (m0, l0, acc0), 0
+    if window is not None:
+        # the band's lower edge: [first, below) masked, then as without it
+        first, below = _band_trips(
+            qblk, bq=bq, block_k=block_k, window=window,
+            woff=_band_offset(causal, sq, sk, kv_len))
+        below = jnp.clip(below, first, num_kb)
+        carry = jax.lax.fori_loop(
+            first, below, functools.partial(tile, masked=True), carry)
+        first, interior = below, jnp.clip(interior, below, num_kb)
     carry = jax.lax.fori_loop(
-        0, interior, functools.partial(tile, masked=False), (m0, l0, acc0))
+        first, interior, functools.partial(tile, masked=False), carry)
     m, l, acc = jax.lax.fori_loop(
         interior, num_kb, functools.partial(tile, masked=True), carry)
     l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -282,7 +329,7 @@ def _lane_rows(x, block_q: int):
 
 
 def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
-         interpret, sq, sk, lens=None, block_causal=0):
+         interpret, sq, sk, lens=None, block_causal=0, window=None):
     """q: [B,Hq,Sq_p,D]; k: [B,Hkv,Sk_p,D]; v: [B,Hkv,Sk_p,Dv] (padded to
     block multiples; Dv is D but for the forward-only path of a model whose
     values are narrower than its keys); q_seg [B,Sq_p] and kv_seg
@@ -309,7 +356,8 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
     kernel = functools.partial(
         _fwd_kernel if lens is None else _fwd_kernel_lens,
         sm_scale=sm_scale, causal=causal, block_k=bk,
-        sq=sq, sk=sk, have_segs=have_segs, block_causal=block_causal)
+        sq=sq, sk=sk, have_segs=have_segs, block_causal=block_causal,
+        **({} if window is None else {"window": window}))
 
     # (`*_`: the scalar-prefetched lengths, where there are any)
     in_specs = [
@@ -570,6 +618,7 @@ def flash_attention(
     q_lens: Optional[jax.Array] = None,
     kv_lens: Optional[jax.Array] = None,
     block_causal: int = 0,
+    window: Optional[int] = None,
 ):
     """Flash attention. q: [B,Sq,Hq,D]; k/v: [B,Sk,Hkv,D] -> [B,Sq,Hq,D].
     With `return_lse` v may be [B,Sk,Hkv,Dv], Dv != D (latent attention's
@@ -605,6 +654,15 @@ def flash_attention(
     blocks of B positions and whole inside one. B must divide the query
     block (128 and up) and Sk - Sq. 0: the kernel and the jaxpr are what
     they are without the argument.
+
+    window = W > 0 (static; with `return_lse` only: the backward kernel has
+    no band): a sliding window. With `causal`, query i sees key j iff i +
+    Sk - Sq - W < j <= i + Sk - Sq: itself and the W - 1 keys before it.
+    Without, the call is the CONTEXT part of a pass that resumes: its
+    queries follow the row's keys (`kv_lens`, or Sk), and query i sees key
+    j iff j > kv_len + i - W. Key blocks wholly behind the band are not
+    visited. None: the kernel and the jaxpr are what they are without the
+    argument.
     """
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
@@ -616,6 +674,12 @@ def flash_attention(
         raise ValueError(
             f"values of width {v.shape[-1]} beside keys of {d}: the "
             f"forward-only path's (return_lse=True)")
+    if window is not None and not (return_lse and window > 0
+                                   and not block_causal):
+        raise ValueError(
+            f"window={window}: a positive sliding window is the forward-"
+            f"only path's (return_lse=True, no block_causal): the backward "
+            f"kernel has no band")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     scale = float(scale if scale is not None else d ** -0.5)
@@ -666,7 +730,7 @@ def flash_attention(
     if return_lse:
         # forward-only: bypass the custom_vjp (no bwd through the merge)
         o, lse = _fwd(qt, kt, vt, q_seg, kv_seg, causal, scale, bq, bk,
-                      interpret, sq, sk, lens, block_causal)
+                      interpret, sq, sk, lens, block_causal, window)
         return (o[:, :, :sq, :].transpose(0, 2, 1, 3),
                 lse.reshape(b, hq, sq_p)[:, :, :sq].transpose(0, 2, 1))
     o = _flash(qt, kt, vt, q_seg, kv_seg, causal, scale, bq, bk, interpret,

@@ -67,6 +67,15 @@ owned end to end:
   each a flash call (``_mla_flash``) under a ``cond`` on the rows'
   context, merged by log-sum-exp: no gather of a whole block table and no
   kernel that holds a whole context resident.
+- A layer with a SLIDING WINDOW (models/mellum.py) keeps no pages under
+  the block table: its last ``window`` keys and values sit in a ring a
+  decode slot (``ring_write``), which decode reads through the same kernel
+  under the name ``_window_decode`` and a resumed prefill pass reads rolled
+  into position order beside its own banded flash call
+  (``window_prefill_attention``, ``_window_flash``); the comment above
+  ``ring_tables`` says how. A full layer's context wider than one flash
+  call holds resident is walked in chunks (``_walk_context``,
+  ``_ctx_flash``), as a latent family's is.
 - ``paged_attention_reference`` is the jnp gather path: the numerics
   oracle for kernel parity tests, the path a CPU backend runs, and the
   path tensor-parallel engines ask for by argument. A TPU backend never
@@ -478,7 +487,8 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
                            scale: Optional[float] = None,
                            pages_per_chunk: Optional[int] = None,
                            interpret: Optional[bool] = None,
-                           force_reference: bool = False) -> jax.Array:
+                           force_reference: bool = False,
+                           _call=None) -> jax.Array:
     """Single-token decode attention over paged KV (Pallas on TPU).
 
     q: [B, Hq, D] (the newest token per sequence, already written to its
@@ -514,9 +524,158 @@ def paged_attention_decode(q: jax.Array, kv_pages: jax.Array,
         pages_per_chunk = default_pages_per_chunk(kv_pages)
     pages_per_chunk = min(pages_per_chunk, block_tables.shape[1])
     kv_pages, layer = _layered(kv_pages, layer)
-    return _decode_call(q, kv_pages, block_tables, lengths, layer,
-                        scale=scale_f, pages_per_chunk=pages_per_chunk,
-                        interpret=bool(interpret))
+    # (`_call`: the same kernel under another jit's name, `_window_decode`)
+    return (_call or _decode_call)(
+        q, kv_pages, block_tables, lengths, layer, scale=scale_f,
+        pages_per_chunk=pages_per_chunk, interpret=bool(interpret))
+
+
+# ------------------------------------------------- a window's ring a slot
+# A layer with a sliding window of W tokens keeps a sequence's LAST W keys
+# and values and no others: a ring a decode slot, `win_pages` [Lw, slots *
+# W / page, Hkv, page, 2D] beside the full layers' pages in the one donated
+# pool. Slot s owns pages [s * W / page, (s + 1) * W / page) for good (no
+# allocator: a slot is the unit that is admitted), and the token at
+# position p sits at ring index p % W, so a write past W tokens overwrites
+# the token that has just left the window. The rotation is in the keys
+# already, so the order of a ring's rows means nothing to attention: decode
+# is `_decode_kernel` over the slot's pages with the length min(tokens, W),
+# under a name of its own (`_window_decode`), and it reads the window's
+# bytes whatever the context. A prefill pass attends its own tokens under
+# the band and, where it resumes, the ring as the pass before left it,
+# rolled into position order (`ring_context`), then writes its last W.
+
+def ring_tables(slots: jax.Array, ring_pages: int) -> jax.Array:
+    """[B] decode slots -> [B, ring_pages] page ids of their rings."""
+    return (slots[:, None] * ring_pages
+            + jnp.arange(ring_pages, dtype=jnp.int32)[None, :])
+
+
+def ring_write(win_pages: jax.Array, k_new: jax.Array, v_new: jax.Array,
+               slots: jax.Array, positions: jax.Array,
+               total_lens: jax.Array, layer, ring_pages: int) -> jax.Array:
+    """Put new tokens' K/V into their slots' rings of `layer`: the token at
+    position p goes to ring index p % W (W = ring_pages * page). k_new /
+    v_new [B, S, Hkv, D]; positions [B, S] contiguous from positions[:, 0];
+    total_lens [B] INCLUDING the new tokens: a position at or past it is
+    padding and writes nothing, an idle row (total 0) keeps its ring bit
+    for bit. One token a row (decode) is `paged_write`'s page scatter at
+    the ring's index; a pass of S tokens rebuilds each row's ring whole
+    (its last W real tokens over what the ring held: 2 MB a layer at W
+    1024, 4 kv heads of 128)."""
+    _, num_pages, hkv, page, d2 = win_pages.shape
+    b, s = positions.shape
+    ring = ring_pages * page
+    if s == 1:
+        at = positions % ring
+        live = positions[:, 0] < total_lens
+        return paged_write(win_pages, k_new, v_new,
+                           ring_tables(slots, ring_pages), at,
+                           jnp.where(live, at[:, 0] + 1, 0), layer)
+    kv = jnp.concatenate([k_new, v_new], axis=-1).astype(win_pages.dtype)
+    start = positions[:, :1]                                       # [B, 1]
+    end = jnp.minimum(total_lens[:, None], start + s)
+    # the newest position < end that sits at each ring index
+    idx = jnp.arange(ring, dtype=jnp.int32)[None, :]
+    newest = end - 1 - (end - 1 - idx) % ring                      # [B, W]
+    write = ((newest >= start) & (end > start)).reshape(b, ring_pages, page)
+    new = jnp.take_along_axis(
+        kv, jnp.clip(newest - start, 0, s - 1)[:, :, None, None], axis=1)
+    new = new.reshape(b, ring_pages, page, hkv, d2).transpose(0, 1, 3, 2, 4)
+    # as `paged_write`: whole pages in one scatter, a page with no new row
+    # (a padding row's, whose slot may be a real row's) to an id that drops
+    pg = jnp.where(write.any(-1), ring_tables(slots, ring_pages), num_pages)
+    old = win_pages[layer, jnp.minimum(pg, num_pages - 1)]
+    pages = jnp.where(write[:, :, None, :, None], new, old)
+    return win_pages.at[layer, pg].set(pages, mode="drop")
+
+
+def ring_context(win_pages: jax.Array, slots: jax.Array, start: jax.Array,
+                 layer, ring_pages: int):
+    """What the rings hold for rows whose next token is `start` [B], in
+    position order with the real keys first: (k, v) each [B, W, Hkv, D],
+    and kv_len [B] = min(start, W). Row c of a ring read so is position
+    max(start - W, 0) + c, which sits at ring index (that) % W. The rings
+    are gathered as whole pages (`gather_kv`: the pool is read in the
+    layout it has; a gather of single rows makes XLA copy the whole pool
+    into a layout of its liking, tests/test_chip_compile.py) and their rows
+    put in order afterwards, on 2 MB a row."""
+    ring = ring_pages * win_pages.shape[-2]
+    k, v = gather_kv(win_pages, ring_tables(slots, ring_pages), layer)
+    at = (jnp.maximum(start - ring, 0)[:, None]
+          + jnp.arange(ring, dtype=jnp.int32)[None, :]) % ring     # [B, W]
+    k, v = (jnp.take_along_axis(x, at[:, :, None, None], axis=1)
+            for x in (k, v))
+    return k, v, jnp.minimum(start, ring)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "window",
+                                             "impl"))
+def _window_flash(q, k, v, q_lens, kv_lens, *, causal: bool, scale: float,
+                  window: int, impl: Optional[str]):
+    """`_attn_lse` under a window and a name of its own (as `_mla_flash`):
+    a windowed layer's flash calls are read apart from a full layer's in a
+    trace."""
+    return _attn_lse(q, k, v, causal=causal, scale=scale, q_lens=q_lens,
+                     kv_lens=kv_lens, impl=impl, window=window)
+
+
+def window_prefill_attention(q: jax.Array, k_new: jax.Array,
+                             v_new: jax.Array, win_pages: jax.Array,
+                             slots: jax.Array, positions: jax.Array,
+                             total_lens: jax.Array, *, window: int,
+                             ring_pages: int, resumes: bool, scale: float,
+                             impl: Optional[str] = None,
+                             layer=None) -> jax.Array:
+    """Prefill attention of a layer with a sliding window: query i sees key
+    j iff i - window < j <= i, by absolute position. The new tokens attend
+    themselves under the band (no tile behind it is visited) and, where
+    the pass `resumes` (static: the program has a context part), what
+    their slot's ring holds from the passes before, the band against the
+    same absolute positions; merged by log-sum-exp. Called BEFORE the pass
+    writes its own tokens to the ring. Lengths as
+    `paged_prefill_attention` gives them to the kernel."""
+    start = positions[:, 0]
+    n_new = jnp.clip(total_lens - start, 0, q.shape[1])
+    o1, lse1 = _window_flash(q, k_new, v_new, n_new, None, causal=True,
+                             scale=scale, window=window, impl=impl)
+    if not resumes:
+        return o1
+    k_ctx, v_ctx, have = ring_context(win_pages, slots, start, layer,
+                                      ring_pages)
+    o2, lse2 = _window_flash(q, k_ctx, v_ctx, n_new, have, causal=False,
+                             scale=scale, window=window, impl=impl)
+    return merge_attention(o1, lse1, o2, lse2)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "pages_per_chunk",
+                                             "interpret"))
+def _window_decode(q, kv_pages, block_tables, lengths, layer, *,
+                   scale: float, pages_per_chunk: int, interpret: bool):
+    """`_decode_kernel` over the slots' rings, under a name of its own (as
+    `_mla_decode`): its time is read apart from `_decode_call`'s."""
+    return _decode_pallas(_decode_kernel, q, kv_pages, block_tables, lengths,
+                          layer, scale=scale, chunk=pages_per_chunk,
+                          interpret=interpret)
+
+
+def window_attention_decode(q: jax.Array, win_pages: jax.Array,
+                            total_lens: jax.Array, *, ring_pages: int,
+                            layer=None, scale: Optional[float] = None,
+                            interpret: Optional[bool] = None,
+                            force_reference: bool = False) -> jax.Array:
+    """Single-token decode over the slot set's rings: row i is slot i, its
+    newest token already written (`ring_write`). q [S, Hq, D]; total_lens
+    [S] (0 = idle -> zeros). The ring holds exactly the window, so the
+    kernel's one length a row, min(tokens, W), is the band. The
+    implementation is chosen as `paged_attention_decode` chooses."""
+    page = win_pages.shape[-2]
+    slots = jnp.arange(q.shape[0], dtype=jnp.int32)
+    return paged_attention_decode(
+        q, win_pages, ring_tables(slots, ring_pages),
+        jnp.minimum(total_lens, ring_pages * page), layer=layer, scale=scale,
+        interpret=interpret, force_reference=force_reference,
+        _call=_window_decode)
 
 
 # --------------------------------------------------- decode over latents
@@ -644,7 +803,7 @@ def paged_attention_block(q: jax.Array, kv_pages: jax.Array,
 
 # --------------------------------------------------------- prefill (+ctx)
 def _attn_lse(q, k, v, *, causal, scale, q_lens=None, kv_lens=None,
-              impl=None, block_causal=0):
+              impl=None, block_causal=0, window=None):
     """Attention returning (o [B,S,Hq,D], lse [B,S,Hq]). kv_lens [B]: the
     keys a row has (the rest are masked); q_lens [B]: its real queries
     (the flash kernel computes no block past them; the jnp path computes
@@ -654,14 +813,17 @@ def _attn_lse(q, k, v, *, causal, scale, q_lens=None, kv_lens=None,
     backend (`JAX_PLATFORMS=cpu`); "flash" forces the Pallas kernel
     (interpreter mode on a CPU backend); "reference" forces the jnp path.
     Both parts of a merged prefill go through the SAME implementation so
-    their lse scales match exactly.
+    their lse scales match exactly. `window`: `flash_attention`'s (a
+    sliding window; a call that is not causal is a resumed pass's context
+    part, its queries following the row's keys).
     """
     if impl == "flash" or (impl is None and jax.default_backend() == "tpu"):
         from .flash_attention import flash_attention
 
         return flash_attention(
             q, k, v, causal=causal, scale=scale, return_lse=True,
-            q_lens=q_lens, kv_lens=kv_lens, block_causal=block_causal)
+            q_lens=q_lens, kv_lens=kv_lens, block_causal=block_causal,
+            **({} if window is None else {"window": window}))
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
     rep = hq // hkv
@@ -676,8 +838,19 @@ def _attn_lse(q, k, v, *, causal, scale, q_lens=None, kv_lens=None,
     if kv_lens is not None:
         live = jnp.arange(sk)[None, :] < kv_lens[:, None]
         logits = jnp.where(live[:, None, None, None, :], logits, NEG_INF)
+    if window is not None:
+        # [B or 1, 1, 1]: where query 0 stands among the keys
+        woff = (jnp.full((1,), sk - sq) if causal else
+                jnp.full((1,), sk) if kv_lens is None else kv_lens)
+        band = jnp.arange(sk)[None, None, :] > (
+            jnp.arange(sq)[None, :, None] + woff[:, None, None] - window)
+        logits = jnp.where(band[:, None, None], logits, NEG_INF)
     m = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.exp(logits - m)
+    if window is not None:
+        # a query the band leaves no key (a context part's later rows):
+        # o = 0 and lse = NEG_INF, as the kernel gives
+        p = jnp.where(m > NEG_INF / 2, p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o = jnp.einsum("bhrsk,bkhd->bshrd", (p / l_safe).astype(v.dtype), v)
@@ -705,6 +878,52 @@ def merge_attention(o1: jax.Array, lse1: jax.Array,
     return out
 
 
+# VMEM one flash call over a paged context may fill with one kv head's keys
+# and values: the forward keeps them resident and double-buffered, in the
+# 16 MB a kernel gets unasked beside its query block, scores and
+# accumulators. At a head of 128 in bf16 that is 12,288 rows. A table of
+# 33,792 does not compile as one call; chunks of 16,384 compile and then
+# run out of VMEM in a program whose other operands XLA keeps there too
+# (PERF.md section 6, PR 47); a table of 8,320 is the one call it ever was
+FLASH_RESIDENT_KV_BYTES = 12 << 20
+
+
+def ctx_chunks(ctx_pages: int, page: int, chunk_tokens: int) -> Tuple:
+    """((first column, columns), ...) of the block table: the static chunks
+    of at most `chunk_tokens` a context of `ctx_pages` columns is walked
+    in."""
+    n = max(1, chunk_tokens // page)
+    return tuple((c, min(n, ctx_pages - c)) for c in range(0, ctx_pages, n))
+
+
+def _walk_context(o, lse, ctx_len, chunks, page: int, attend):
+    """Merge into (o, lse) the attention over a paged context of `ctx_len`
+    [B] tokens, a static chunk of block-table columns at a time:
+    `attend(first, n, have) -> (o, lse)` attends columns [first, first + n)
+    of which row b has `have[b]` tokens. A chunk past every row's context
+    runs nothing (a `cond`). Returns o."""
+    for first, n in chunks:
+        have = jnp.clip(ctx_len - first * page, 0, n * page)
+
+        def step(o, lse, first=first, n=n, have=have):
+            o2, lse2 = attend(first, n, have)
+            return merge_attention(o, lse, o2, lse2, return_lse=True)
+
+        o, lse = jax.lax.cond(jnp.any(have > 0), step,
+                              lambda o, lse: (o, lse), o, lse)
+    return o
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "impl"))
+def _ctx_flash(q, k, v, q_lens, kv_lens, *, scale: float,
+               impl: Optional[str]):
+    """`_attn_lse` over one chunk of a paged context, under a name of its
+    own: a call inside a `cond` would otherwise take the branch's name in a
+    trace (`_mla_flash`)."""
+    return _attn_lse(q, k, v, causal=False, scale=scale, q_lens=q_lens,
+                     kv_lens=kv_lens, impl=impl)
+
+
 def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
                             v_new: jax.Array, kv_pages: jax.Array,
                             block_tables: jax.Array,
@@ -728,6 +947,12 @@ def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
     prefix part entirely (no page reads at all). Rows whose prefix is
     shorter mask the tail; rows with no prefix mask everything. kv_pages
     is [L, P, Hkv, page, 2D] at `layer`, or [P, Hkv, page, 2D].
+
+    A table wider than one flash call may hold resident
+    (`FLASH_RESIDENT_KV_BYTES`) is walked in chunks of that size, as
+    `latent_prefill_attention` walks a latent context: each chunk a gather
+    and a flash call (`_ctx_flash`). A narrower one is one call over the
+    table's width, the jaxpr it was.
     """
     d = q.shape[-1]
     scale_f = float(scale if scale is not None else d ** -0.5)
@@ -739,6 +964,16 @@ def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
                          q_lens=n_new, impl=impl, block_causal=block_causal)
     if ctx_pages <= 0:
         return o1
+    page, d2 = kv_pages.shape[-2:]
+    chunks = ctx_chunks(ctx_pages, page, FLASH_RESIDENT_KV_BYTES // (
+        2 * d2 * kv_pages.dtype.itemsize))
+    if len(chunks) > 1:
+        def attend(first, n, have):
+            k, v = gather_kv(kv_pages, block_tables[:, first:first + n],
+                             layer)
+            return _ctx_flash(q, k, v, n_new, have, scale=scale_f, impl=impl)
+
+        return _walk_context(o1, lse1, ctx_len, chunks, page, attend)
     bt = block_tables[:, :ctx_pages]
     k_ctx, v_ctx = gather_kv(kv_pages, bt, layer)  # [B, CP*page, Hkv, D]
     # the prefix is a LENGTH of the gathered columns: a row attends the
@@ -755,14 +990,6 @@ def paged_prefill_attention(q: jax.Array, k_new: jax.Array,
 # 4096 rows; 8192 rows are 72 KB over the 16 MB a kernel gets unasked
 # (compiled for a described v5e: tests/test_chip_compile.py)
 LATENT_CTX_CHUNK = 4096
-
-
-def latent_ctx_chunks(ctx_pages: int, page: int,
-                      chunk_tokens: int = LATENT_CTX_CHUNK) -> Tuple:
-    """((first column, columns), ...) of the block table: the static chunks
-    `latent_prefill_attention` walks a context of `ctx_pages` columns in."""
-    n = max(1, chunk_tokens // page)
-    return tuple((c, min(n, ctx_pages - c)) for c in range(0, ctx_pages, n))
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "impl"))
@@ -805,23 +1032,20 @@ def latent_prefill_attention(q: jax.Array, k_new: jax.Array,
     n_new = jnp.clip(total_lens - ctx_len, 0, s)
     o, lse = _mla_flash(q, k_new, v_new, n_new, None, causal=True,
                         scale=scale, impl=impl)
-    for first, n in latent_ctx_chunks(ctx_pages, page, chunk_tokens):
-        have = jnp.clip(ctx_len - first * page, 0, n * page)
 
-        def attend(o, lse, first=first, n=n, have=have):
-            rows = kv_pages[layer, block_tables[:, first:first + n]]
-            k, v = expand(rows.reshape(b, n * page, lanes))
-            o2, lse2 = _mla_flash(q, k, v, n_new, have, causal=False,
-                                  scale=scale, impl=impl)
-            return merge_attention(o, lse, o2, lse2, return_lse=True)
+    def attend(first, n, have):
+        rows = kv_pages[layer, block_tables[:, first:first + n]]
+        k, v = expand(rows.reshape(b, n * page, lanes))
+        return _mla_flash(q, k, v, n_new, have, causal=False, scale=scale,
+                          impl=impl)
 
-        o, lse = jax.lax.cond(jnp.any(have > 0), attend,
-                              lambda o, lse: (o, lse), o, lse)
-    return o
+    return _walk_context(o, lse, ctx_len,
+                         ctx_chunks(ctx_pages, page, chunk_tokens), page,
+                         attend)
 
 
 def prefill_block_visits(s: int, ctx_width: int, n_new=None,
-                         ctx_len=None) -> Tuple[int, int, int]:
+                         ctx_len=None, window=None) -> Tuple[int, int, int]:
     """What `paged_prefill_attention`'s two flash calls visit for one row,
     a layer and head, in plain integers (the engine's counters and its
     pass cost, on the host): ((query block, key block) visits, the (query,
@@ -832,9 +1056,18 @@ def prefill_block_visits(s: int, ctx_width: int, n_new=None,
     Lengths None: what the shapes alone would make. `ctx_width` 0: the
     program without a context part. An edge visit pays the mask: a query
     block's tile on the causal diagonal, and over the context the tile
-    that holds the end of the row's keys inside it; the rest run bare."""
-    from .flash_attention import BLOCK, _fwd_trips, _pick_blocks
+    that holds the end of the row's keys inside it; the rest run bare.
+    `window`: a layer with a sliding window (`window_prefill_attention`):
+    its context part is the slot's ring (`window` columns wherever
+    `ctx_width` > 0, min(`ctx_len`, window) of them real), its key loops
+    start at the band and the tiles the band's lower edge crosses are edge
+    visits too."""
+    from .flash_attention import (BLOCK, _band_offset, _band_trips,
+                                  _fwd_trips, _pick_blocks)
 
+    if window is not None and ctx_width:
+        ctx_width = window
+        ctx_len = window if ctx_len is None else min(ctx_len, window)
     bq, bk = _pick_blocks(s, ctx_width or s, BLOCK, BLOCK)
     qblks = np.arange(-(-s // bq))
     visits = pairs = masked = 0
@@ -842,12 +1075,22 @@ def prefill_block_visits(s: int, ctx_width: int, n_new=None,
     for sk, block_k, causal, kv_len in ((s, bq, True, None),
                                         (ctx_width, bk, False, ctx_len)):
         if sk:
+            interior, every = _fwd_trips(
+                qblks, bq=bq, block_k=block_k, sq=s, sk=sk, causal=causal,
+                have_segs=False, q_len=n_new, kv_len=kv_len, block_causal=0,
+                xp=np)
+            first = 0
+            if window is not None:
+                first, below = _band_trips(
+                    qblks, bq=bq, block_k=block_k, window=window,
+                    woff=_band_offset(causal, s, sk, kv_len), xp=np)
+                first = np.minimum(first, every)
+                below = np.clip(below, first, every)
+                # the bare tiles are those between the two edges
+                interior = np.clip(interior, below, every) - below
             # (a count no query block decides comes back as one integer)
-            interior, every = (
-                int(np.broadcast_to(n, qblks.shape).sum()) for n in
-                _fwd_trips(qblks, bq=bq, block_k=block_k, sq=s, sk=sk,
-                           causal=causal, have_segs=False, q_len=n_new,
-                           kv_len=kv_len, block_causal=0, xp=np))
+            every, interior = (int(np.broadcast_to(n, qblks.shape).sum())
+                               for n in (every - first, interior))
             visits, pairs = visits + every, pairs + every * bq * block_k
             masked += every - interior
     return visits, pairs, masked

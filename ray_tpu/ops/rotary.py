@@ -52,13 +52,19 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0
 
 
-def rotate(x: jax.Array, positions: jax.Array, inv_freq) -> jax.Array:
+def rotate(x: jax.Array, positions: jax.Array, inv_freq,
+           factor: float = 1.0) -> jax.Array:
     """x [B, S, H, D] turned by `positions` [B, S] x `inv_freq` [D // 2]
-    (`models/llama.py: rope` with the frequencies given)."""
+    (`models/llama.py: rope` with the frequencies given). `factor`
+    multiplies cos and sin (YaRN's `attention_factor` where a model applies
+    it to the rotation: a score of two rotated vectors carries its
+    square)."""
     angles = positions[..., None].astype(jnp.float32) * jnp.asarray(
         inv_freq, jnp.float32)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -365,12 +365,23 @@ _BALANCE_TOKENS = 240
 _PAIR_PARAMS = 375
 
 
-def _attn_visits(bucket: int, width: int, real=None, ctx=None) -> tuple:
+# a model's kinds of attention layer, (layers, window or None) each: one
+# kind, whole contexts, unless the family says otherwise (`attention_kinds`)
+_ONE_KIND = ((1, None),)
+
+
+def _attn_visits(bucket: int, width: int, real=None, ctx=None,
+                 kinds: tuple = _ONE_KIND) -> tuple:
     """`ops.paged_attention.prefill_block_visits`: what a pass's flash
-    calls visit (imported late: this module loads without JAX)."""
+    calls visit (imported late: this module loads without JAX), summed
+    over the layers of each kind (one layer of one kind: the call
+    itself)."""
     from ...ops.paged_attention import prefill_block_visits
 
-    return prefill_block_visits(bucket, width, real, ctx)
+    return tuple(map(sum, zip(*(
+        [layers * n for n in prefill_block_visits(
+            bucket, width, real, ctx, window)]
+        for layers, window in kinds))))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -392,13 +403,19 @@ class PassCost:
       token multiplies;
     - if it RESUMES (`ctx` > 0: it starts mid-prompt or behind a cached
       prefix): one `floor` more, the bar a split has to clear (the
-      dispatch, a second weight read, the gather of the table's width)."""
+      dispatch, a second weight read, the gather of the table's width).
+
+    `kinds`: the model's kinds of attention layer as SHARES of its layers,
+    ((share, window or None), ...): a pair of a layer with a sliding window
+    is not a pair of a full one (a pass behind 16k tokens of context makes
+    a sixteenth of them there), so the pairs are each kind's own."""
     floor: float
     pair: float
+    kinds: tuple = _ONE_KIND
 
     def __call__(self, bucket: int, real: int, ctx: int) -> float:
         # (any table width that holds the context: the lengths cut the rest)
-        pairs = _attn_visits(bucket, ctx, real, ctx)[1]
+        pairs = _attn_visits(bucket, ctx, real, ctx, self.kinds)[1]
         cost = max(bucket, self.floor) + self.pair * pairs
         return cost + self.floor if ctx else cost
 
@@ -583,13 +600,18 @@ class LLMEngine:
         # layers keep state a decode slot: a prefill row is told its slot
         self._slot_state = getattr(cfg_m, "n_slot_state_layers", 0) > 0
         # why a page found by its hash may not be reused (None: it may)
+        # (in the family's own words where it names `prefix_reuse` among
+        # what it cannot be given; `stats()` then says so too)
+        named = getattr(family, "CANNOT_BE_GIVEN", ("", {}))[1].get(
+            "prefix_reuse")
         self._prefix_off = None
         if self._slot_state:
-            self._prefix_off = ("a page found by its content hash carries "
-                                "no recurrent state")
+            self._prefix_off = named or ("a page found by its content hash "
+                                         "carries no recurrent state")
         elif self._block:
             self._prefix_off = prefix_reuse_unsound(config.page_size,
                                                     self._block)
+        self._prefix_why_shown = bool(self._block or named)
         if self._prefix_off:
             self._totals["prefix_reuse_refused_total"] = 0
         # what the family's dispatches count (stage.py: model_family): its
@@ -608,11 +630,16 @@ class LLMEngine:
         # family answers for its model
         self._resumes = family.RESUMES_PREFILL
         self._pass_cost = None
+        # the kinds of attention layer whose flash visits are counted
+        self._attn_kinds = (family.attention_kinds(cfg_m) if hasattr(
+            family, "attention_kinds") else _ONE_KIND)
         if self._resumes:
             weights, scores = family.pass_cost_ratios(cfg_m)
+            layers = sum(n for n, _ in self._attn_kinds)
             self._pass_cost = PassCost(
                 floor=_BALANCE_TOKENS * weights,
-                pair=_PAIR_PARAMS * scores)
+                pair=_PAIR_PARAMS * scores,
+                kinds=tuple((n / layers, w) for n, w in self._attn_kinds))
         self._head_at_gather = getattr(family, "HEAD_AT_GATHER", False)
         self._queue_wait_ns_total = 0
         # the device's timeline as the host can stamp it (_device_stamps):
@@ -1218,7 +1245,8 @@ class LLMEngine:
             # (query block, key block) visits a row of the program's
             # shapes would make; what a row's lengths cut is counted below
             width = cp * self.config.page_size
-            shapes = _attn_visits(sb, width)[0]
+            kinds = self._attn_kinds
+            shapes = _attn_visits(sb, width, kinds=kinds)[0]
             for i, (req, n_new) in enumerate(group):
                 start = req.n_prefilled
                 if slots is not None:
@@ -1239,7 +1267,8 @@ class LLMEngine:
                 req.n_passes += 1
                 self._totals["prefill_resumed_passes_total"] += start > 0
                 if flash:
-                    visited, _, masked = _attn_visits(sb, width, n_new, start)
+                    visited, _, masked = _attn_visits(sb, width, n_new,
+                                                      start, kinds)
                     self._totals["prefill_attn_blocks_total"] += shapes
                     self._totals["prefill_attn_blocks_skipped_total"] += (
                         shapes - visited)
@@ -2205,7 +2234,7 @@ class LLMEngine:
         for facts in self.family_facts:
             if hasattr(facts, "sizes") and self.compute:
                 out.update(facts.sizes(self.compute.pool_bytes()))
-        if self._block and self._prefix_off:
+        if self._prefix_off and self._prefix_why_shown:
             out["prefix_reuse_refused_why"] = self._prefix_off
         if self.sharding is not None:
             out["sharding"] = self.sharding.page_accounting(

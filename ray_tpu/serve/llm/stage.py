@@ -88,10 +88,13 @@ def serve_dtype(config):
 
 def model_family(name: str):
     """The module that defines the preset `name`: the one place that
-    chooses a model family from `EngineConfig.model`. Five families (each
+    chooses a model family from `EngineConfig.model`. Six families (each
     module says what it is): models/llama.py, jamba.py, minicpm_sala.py,
     sdar.py (its config has `block_length` and the engine steps it with the
-    "block" program), kimi.py (its config has `latent_lanes`).
+    "block" program), kimi.py (its config has `latent_lanes`), mellum.py
+    (two kinds of attention layer: it also answers `attention_kinds(cfg)`,
+    ((layers, window or None), ...), for the engine's pass cost and its
+    counters of the flash kernel's visits).
 
     This is the one description of what a family's module provides:
     `CONFIGS`, `get_config`, `serving_model`, `pool_spec`, `serving_cache`;
@@ -121,10 +124,12 @@ def model_family(name: str):
       the mechanism that is missing}) over `spec_lookahead`,
       `prefill_chunk_tokens`, `tp`, `pp`, `handoff` (the disaggregated
       hand-off) and `max_model_len` (one that holds no whole number of
-      blocks); engine.py's `refuse` raises it by name."""
-    from ...models import jamba, kimi, llama, minicpm_sala, sdar
+      blocks); engine.py's `refuse` raises it by name. `prefix_reuse` is no
+      option: where a family names it, the engine matches no page by its
+      hash, counts what it refused and says why in `stats()`."""
+    from ...models import jamba, kimi, llama, mellum, minicpm_sala, sdar
 
-    families = (llama, jamba, minicpm_sala, sdar, kimi)
+    families = (llama, jamba, minicpm_sala, sdar, kimi, mellum)
     for family in families:
         if name in family.CONFIGS:
             return family
@@ -465,8 +470,12 @@ class StageCompute:
                 {"params": params}, x, positions=positions, kv_caches=pc,
                 token_mask=positions < total_lens[:, None],
                 mutable=["routing"])
-            # the one leaf sown: the scanned expert layers' [L, E]
-            (counts,) = jax.tree.leaves(sown["routing"])
+            # the one leaf sown: the scanned expert layers' [L, E] (a leaf
+            # a run of like layers, in the model's order, where the stack
+            # is several scans: models/mellum.py)
+            counts, *more = jax.tree.leaves(sown["routing"])
+            if more:
+                counts = jnp.concatenate([counts, *more])
             return out, new_pc, counts
 
         def pack(kept, counts):

@@ -178,6 +178,8 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         "lin_layers", "lin_state_bytes_row", "sparse_layers",
         "sparse_tokens_read", "sparse_kernels_scored", "pass_index",
         "final", "block_passes", "block_tokens_fixed", "block_len",
+        "window_layers", "full_layers", "window_tokens_read",
+        "full_tokens_read", "window_tokens_held", "full_tokens_held",
         "mla_layers", "latent_bytes_token", "mla_ctx_chunks",
         "moe_assignments_routed",
         "enqueued_ns", "device_start_ns", "device_end_ns", "end_exact"),

@@ -866,3 +866,85 @@ def test_kimi_program_fits_and_carries_its_pool_in_place(
     header = text.split("\n", 1)[0]
     aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
     assert len(aliased) >= (2 if kind == "decode" else 1), header[:400]
+
+
+# ------------- sliding-window and full attention layers, rings beside pages
+MELLUM_PAGE, MELLUM_PAGES, MELLUM_LEN = 64, 16384, 33792
+
+
+@pytest.mark.parametrize("kind, key", [
+    ("decode", None), ("prefill", (4096, MELLUM_LEN // MELLUM_PAGE))])
+def test_mellum_program_fits_and_carries_both_pools_in_place(
+        topo, no_persistent_cache, kind, key):
+    """Mellum2-12B-A2.5B-Instruct at its published widths as the cell
+    `mellum2-mixedctx` runs it: layers 0-7 (two periods of three sliding
+    layers and a full one), all 64 experts, the whole vocabulary, 64 slots:
+    the full layers' pages `[2, 16384, 4, 64, 256]` (1.05 M tokens, 4.3 GB)
+    beside the sliding layers' rings `[6, 64 x 16, 4, 64, 256]` (a window a
+    slot, 0.8 GB whatever the contexts). The decode program runs the
+    paged kernel under two names, `_decode_call` over the block table and
+    `_window_decode` over the rings; a resumed `[1 x 4096]` pass behind a
+    33,792-token table fits the chip beside 7.6 GB of weights, its sliding
+    layers' flash calls (`_window_flash`: own tokens, then the ring) apart
+    from its full layers'; both parts of the pool are aliased from argument
+    to result."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    from ray_tpu.serve.llm.stage import init_params
+
+    cfg = EngineConfig(
+        model="mellum2-12b-a2.5b", dtype="bfloat16", page_size=MELLUM_PAGE,
+        num_pages=64, max_model_len=MELLUM_LEN, max_batch=64,
+        prefill_buckets=(256, 512, 1024, 2048, 4096),
+        model_overrides=dict(num_layers=8))
+    engine = LLMEngine(cfg, params={})
+    stage = engine.compute
+    stage.params = jax.eval_shape(lambda: init_params(
+        stage.model, jnp.zeros((1, 8), jnp.int32), jax.random.PRNGKey(0)))
+    spec = stage.family.pool_spec(stage.model_cfg, 8, MELLUM_PAGES,
+                                  MELLUM_PAGE, 64)
+    assert spec["kv_pages"][0] == (2, MELLUM_PAGES, 4, MELLUM_PAGE, 256)
+    assert spec["win_pages"][0] == (6, 64 * 1024 // MELLUM_PAGE, 4,
+                                    MELLUM_PAGE, 256)
+    stage.kv_pages = {k: jax.ShapeDtypeStruct(*sd) for k, sd in spec.items()}
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(a):
+        a = a if hasattr(a, "shape") else np.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    key = (engine._decode_shape_key() if kind == "decode"
+           else (key[0], engine._wave_rb, key[1]))
+    with pytest.MonkeyPatch.context() as mp_ctx:
+        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = stage.program(kind, key).lower(*jax.tree.map(
+            sds, (*stage._state(kind), *stage.dummy_args(kind, key)),
+            is_leaf=lambda a: isinstance(a, jax.ShapeDtypeStruct))
+        ).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(kind, key, "GiB", total / 2 ** 30, "temp",
+          mem.temp_size_in_bytes / 2 ** 30)
+    # 7.59 GB of weights + 4.29 GB of pages + 0.81 GB of rings
+    assert 11.8 * 2 ** 30 <= total <= HBM_GIB * 2 ** 30, total / 2 ** 30
+    kernels = set(re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
+    names = sorted({k.split(".")[0] for k in kernels})
+    if kind == "decode":
+        assert names == ["_decode_call", "_moe_gmm", "_window_decode"], kernels
+    else:
+        assert "_window_flash" in names and "_moe_gmm" in names, kernels
+        # a sliding run's scan body: the own-tokens call, and the ring's
+        # where the pass resumes; two sliding runs
+        assert sum(k.startswith("_window_flash") for k in kernels) == 2 * (
+            2 if key[2] else 1), kernels
+    # neither part of the pool is copied whole: the rings are gathered a
+    # slot's rows and scattered back a slot's pages, as the pages are
+    whole = {sd[0] for sd in spec.values()}
+    copied = [line.strip()[:160] for line in text.splitlines()
+              if (m := re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) copy\(",
+                                line))
+              and any(dims in whole for dims, _ in _array_types(m.group(1)))]
+    assert not copied, "\n".join(copied)
+    header = text.split("\n", 1)[0]
+    aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliased) >= (3 if kind == "decode" else 2), header[:400]

@@ -559,3 +559,121 @@ def test_the_trainers_call_traces_to_the_jaxpr_it_had(case, sha):
         seg = (jnp.zeros((2, 2048), jnp.int32),) if case == "packed" else ()
         got = _jaxpr_sha(jax.grad(loss, argnums=(0, 1, 2)), q, k, v, *seg)
     assert got == sha
+
+
+# ------------------------------------------------------- a sliding window
+def _band(see, sq, sk, window, causal, kv_lens=None):
+    """`see` cut to the band of `flash_attention`'s docstring: a causal
+    call's queries are the last `sq` of `sk` positions; one that is not
+    follows its row's keys."""
+    qi, kj = np.arange(sq)[:, None], np.arange(sk)[None, :]
+    woff = (np.full((B,), sk - sq) if causal else
+            np.full((B,), sk) if kv_lens is None else np.asarray(kv_lens))
+    return see & (kj[None] > qi[None] + woff[:, None, None] - window)
+
+
+# (sq, sk, window, causal, q_lens, kv_lens, blocks): bands that start
+# inside, on and between tiles; a window under a tile and over several; the
+# context part of a resumed pass (not causal: the queries follow the keys)
+WINDOW_CASES = {
+    "inside-a-tile": (256, 256, 100, True, None, None, (128, 128)),
+    "on-a-tile": (384, 384, 128, True, None, None, (128, 128)),
+    "between-tiles": (512, 512, 200, True, None, None, (128, 128)),
+    "two-tiles-wide": (512, 512, 256, True, None, None, (128, 128)),
+    "under-a-tile": (384, 384, 32, True, None, None, (128, 128)),
+    "one-key": (256, 256, 1, True, None, None, (128, 128)),
+    "wider-than-all": (256, 256, 1000, True, None, None, (128, 128)),
+    "wide-query-tile": (512, 512, 160, True, None, None, (256, 128)),
+    "wide-key-tile": (512, 512, 160, True, None, None, (128, 256)),
+    "padded-bucket": (384, 384, 150, True, (200, 384), None, (128, 128)),
+    "offset-causal": (128, 384, 200, True, None, None, (128, 128)),
+    "context-whole-ring": (384, 256, 256, False, None, (256, 256),
+                           (128, 128)),
+    "context-short-ring": (256, 256, 256, False, (256, 100), (100, 31),
+                           (128, 128)),
+    "context-no-ring": (256, 256, 256, False, None, (0, 256), (128, 128)),
+    "context-window-under-a-tile": (256, 128, 40, False, None, (128, 77),
+                                    (128, 128)),
+    "context-window-over-the-ring": (256, 128, 300, False, None, (128, 64),
+                                     (128, 128)),
+    "default-blocks": (1024, 1024, 600, True, None, None, (512, 512)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WINDOW_CASES))
+def test_forward_parity_under_a_window(case):
+    """The kernel under `window` against plain softmax over the band's
+    pairs, `o` and `lse` of every real row (a row the band leaves no key:
+    0 and NEG_INF), and its loops' trip counts (`_fwd_trips` +
+    `_band_trips`) against a brute-force count over tiles: the loop starts
+    at the first tile a real row sees a key of, and a tile runs bare
+    exactly when every real row sees all of it."""
+    from ray_tpu.ops.flash_attention import (_band_offset, _band_trips,
+                                             _fwd_trips)
+
+    sq, sk, window, causal, q_lens, kv_lens, (bq, bk) = WINDOW_CASES[case]
+    q, k, v = _make(sq, sk, seed=7)
+    lens = {} if q_lens is None else {"q_lens": jnp.asarray(q_lens)}
+    if kv_lens is not None:
+        lens["kv_lens"] = jnp.asarray(kv_lens)
+    o, lse = flash_attention(q, k, v, causal=causal, window=window,
+                             return_lse=True, interpret=True, block_q=bq,
+                             block_k=bk, **lens)
+    see, real = _visible(sq, sk, causal=causal, q_lens=q_lens,
+                         kv_lens=kv_lens)
+    see = _band(see, sq, sk, window, causal, kv_lens)
+    _assert_real_rows_match(o, lse, q, k, v, see, real)
+    for b in range(B):
+        q_len = None if q_lens is None else q_lens[b]
+        kv_len = None if kv_lens is None else kv_lens[b]
+        for qblk in range(-(-sq // bq)):
+            rows = slice(qblk * bq, (qblk + 1) * bq)
+            live = real[b, rows]
+            visited, bare = [], []
+            for kb in range(-(-sk // bk)):
+                tile = np.zeros((live.sum(), bk), bool)
+                part = see[b, rows][live][:, kb * bk:(kb + 1) * bk]
+                tile[:, :part.shape[1]] = part
+                if tile.any():
+                    visited.append(kb)
+                if tile.size and tile.all():
+                    bare.append(kb)
+            interior, n_all = _fwd_trips(
+                qblk, bq=bq, block_k=bk, sq=sq, sk=sk, causal=causal,
+                have_segs=False, q_len=q_len, kv_len=kv_len, block_causal=0,
+                xp=np)
+            first, below = _band_trips(
+                qblk, bq=bq, block_k=bk, window=window,
+                woff=_band_offset(causal, sq, sk, kv_len), xp=np)
+            first = min(int(first), int(n_all))
+            below = int(np.clip(below, first, n_all))
+            interior = int(np.clip(interior, below, n_all))
+            # every visited tile is walked, none before the band; the bare
+            # loop runs bare tiles only (a padded row of a real block may
+            # make the kernel mask a tile the real rows see whole)
+            assert set(visited) <= set(range(first, int(n_all))), (b, qblk)
+            if visited:
+                assert first == visited[0], (b, qblk)
+            assert set(range(below, interior)) <= set(bare), (b, qblk)
+            if q_len is None:
+                assert list(range(below, interior)) == bare, (b, qblk)
+
+
+def test_a_window_none_is_the_call_it_was_and_the_backward_refuses_one():
+    """`window=None` adds nothing to the traced call (the trainer's and
+    every cell's jaxprs are pinned below and in tests/test_sdar.py,
+    test_kimi.py); a window is the forward-only path's, refused by name
+    anywhere else."""
+    q, k, v = _make(128, 128)
+    plain = _jaxpr_sha(lambda *a: flash_attention(
+        *a, return_lse=True, interpret=True), q, k, v)
+    none = _jaxpr_sha(lambda *a: flash_attention(
+        *a, return_lse=True, interpret=True, window=None), q, k, v)
+    assert plain == none
+    with pytest.raises(ValueError, match="backward kernel has no band"):
+        flash_attention(q, k, v, window=64, interpret=True)
+    with pytest.raises(ValueError, match="positive sliding window"):
+        flash_attention(q, k, v, window=0, return_lse=True, interpret=True)
+    with pytest.raises(ValueError, match="backward kernel has no band"):
+        jax.grad(lambda q: flash_attention(
+            q, k, v, window=64, interpret=True).sum())(q)

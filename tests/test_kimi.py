@@ -157,7 +157,7 @@ def test_one_pass_is_resumed_passes_over_several_context_chunks(tiny,
         one, _ = _paged(cfg, model, params, seq, (128,))
         many, _ = _paged(cfg, model, params, seq, passes)
     assert float(jnp.abs(one - many).max()) < 1e-4
-    assert len(pa.latent_ctx_chunks(MP, 16, cfg.ctx_chunk_tokens)) == 6
+    assert len(pa.ctx_chunks(MP, 16, cfg.ctx_chunk_tokens)) == 6
 
 
 def test_a_chunk_past_every_rows_context_runs_nothing(tiny):

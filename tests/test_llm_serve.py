@@ -832,3 +832,32 @@ def test_multi_step_decode_batched_prefill_concurrent():
         engine.add_request(rid, p, SamplingParams(max_tokens=6))
     conc = _collect(engine, list(prompts))
     assert conc == seq
+
+
+def test_a_family_that_names_prefix_reuse_matches_no_page_and_says_why():
+    """Two requests with the same 48-token prompt through a family whose
+    sliding-window layers keep a ring a slot (models/mellum.py): a page
+    found by its hash is a full layer's and the window's keys of the same
+    tokens are gone, so nothing is matched, each refusal is counted, the
+    reason is the family's own words, and both requests emit the same
+    tokens."""
+    from ray_tpu.models import mellum
+
+    engine = LLMEngine(EngineConfig(
+        model="tiny-mellum", dtype="float32", page_size=16, num_pages=32,
+        max_model_len=128, max_batch=2, prefill_buckets=(32, 64)))
+    prompt = list(range(1, 49))
+    out = {"a": [], "b": []}
+    for rid in out:
+        engine.add_request(rid, prompt, SamplingParams(max_tokens=4,
+                                                       temperature=0.0))
+        while engine.has_work():
+            for d in engine.step():
+                out[d.request_id].extend(d.new_token_ids)
+    st = engine.stats()
+    assert out["a"] == out["b"] and len(out["a"]) == 4
+    assert st["prefix_token_hits"] == 0 and st["cache_hits"] == 0
+    assert st["prefix_reuse_refused_total"] == 2
+    assert st["prefix_reuse_refused_why"] == mellum.CANNOT_BE_GIVEN[1][
+        "prefix_reuse"]
+    engine.close()

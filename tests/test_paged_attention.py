@@ -404,3 +404,243 @@ def test_prefill_block_visits_of_the_benchmarks_passes(s, n_new, ctx, width,
             s, width, *(() if q_len == s and kv_len == width
                         else (q_len, kv_len))) == (
             n_own + n_ctx, n_own * bq * bq + n_ctx * bq * bk, e_own + e_ctx)
+
+
+# ------------------------------------------- a window's ring a decode slot
+def _banded_dense(q, k, v, positions, window):
+    """Plain softmax attention of queries at `positions` [S] over keys
+    0..len(k)-1, each seeing the `window` keys up to its own place."""
+    rep = q.shape[1] // k.shape[1]
+    kr, vr = (jnp.repeat(x, rep, axis=1) for x in (k, v))
+    logits = jnp.einsum("qhd,khd->hqk", q, kr) * q.shape[-1] ** -0.5
+    j = jnp.arange(k.shape[0])[None, :]
+    see = (j <= positions[:, None]) & (j > positions[:, None] - window)
+    p = jax.nn.softmax(jnp.where(see[None], logits, -jnp.inf), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", p, vr)
+
+
+# passes (their lengths), then decode steps: under, across and far past the
+# window; a pass boundary inside a band; a second pass shorter than the
+# window; a pass that is several windows long
+RING_CASES = {
+    "under-the-window": ((20,), 5),
+    "one-pass-across": ((48,), 6),
+    "boundary-inside-a-band": ((16, 16, 30), 4),
+    "short-second-pass": ((40, 5), 3),
+    "far-past": ((96, 64, 33), 40),
+    "window-starts-mid-page": ((37,), 9),
+}
+
+
+@pytest.mark.parametrize("impl", [None, "flash"])
+@pytest.mark.parametrize("case", sorted(RING_CASES))
+def test_a_ring_serves_resumed_passes_and_decode_under_the_band(case, impl):
+    """A sequence prefilled in passes and then decoded through ONE slot's
+    ring of the window (two pages of 16, layer 1 of 3, slot 2 of 4) gives,
+    at every position, dense attention over the band of ALL the tokens so
+    far; the other layers and slots of the pool come through bit for bit;
+    the decode kernel (interpreted) agrees with the gather path over a
+    window that starts mid-page."""
+    from ray_tpu.ops.paged_attention import (ring_write,
+                                             window_attention_decode,
+                                             window_prefill_attention)
+
+    passes, steps = RING_CASES[case]
+    rng = np.random.default_rng(11)
+    hq, hkv, d, page, rp, slots_n, layer, slot = 4, 2, 16, 16, 2, 4, 1, 2
+    window = rp * page
+    total = sum(passes) + steps
+    q, k, v = (jnp.asarray(rng.standard_normal((total, h, d)), jnp.float32)
+               for h in (hq, hkv, hkv))
+    noise = jnp.asarray(rng.standard_normal(
+        (N_LAYERS, slots_n * rp, hkv, page, 2 * d)), jnp.float32)
+    win = noise
+    slots = jnp.asarray([slot], jnp.int32)
+    want = _banded_dense(q, k, v, jnp.arange(total), window)
+    start = 0
+    for n in passes:
+        sb = -(-n // 16) * 16 + 16              # a padded bucket
+        pad = lambda x: jnp.pad(x[start:start + n], (  # noqa: E731
+            (0, sb - n), (0, 0), (0, 0)))[None]
+        positions = (start + jnp.arange(sb))[None]
+        lens = jnp.asarray([start + n], jnp.int32)
+        got = window_prefill_attention(
+            pad(q), pad(k), pad(v), win, slots, positions, lens,
+            window=window, ring_pages=rp, resumes=start > 0, scale=d ** -0.5,
+            impl=impl, layer=layer)
+        np.testing.assert_allclose(np.asarray(got[0, :n]),
+                                   np.asarray(want[start:start + n]),
+                                   rtol=2e-5, atol=2e-5)
+        win = ring_write(win, pad(k), pad(v), slots, positions, lens, layer,
+                         rp)
+        start += n
+    # decode over the slot set: the other slots idle
+    for t in range(start, total):
+        lens = jnp.zeros((slots_n,), jnp.int32).at[slot].set(t + 1)
+        place = lambda x: jnp.zeros(  # noqa: E731
+            (slots_n, 1) + x.shape[1:], x.dtype).at[slot, 0].set(x[t])
+        win = ring_write(win, place(k), place(v), jnp.arange(slots_n),
+                         jnp.maximum(lens - 1, 0)[:, None], lens, layer, rp)
+        for interpret in (None, True):
+            got = window_attention_decode(
+                place(q)[:, 0], win, lens, ring_pages=rp, layer=layer,
+                interpret=interpret)
+            np.testing.assert_allclose(np.asarray(got[slot]),
+                                       np.asarray(want[t]), rtol=2e-5,
+                                       atol=2e-5)
+            assert not np.asarray(got)[np.arange(slots_n) != slot].any()
+    mine = np.zeros(noise.shape, bool)
+    mine[layer, slot * rp:(slot + 1) * rp] = True
+    np.testing.assert_array_equal(np.asarray(win)[~mine],
+                                  np.asarray(noise)[~mine])
+
+
+def test_a_passes_padding_and_an_idle_row_write_nothing_to_a_ring():
+    """Positions at or past a row's length, and a row with no real token,
+    leave its ring bit for bit (a masked warm-up pass, a wave's padding)."""
+    from ray_tpu.ops.paged_attention import ring_write
+
+    rng = np.random.default_rng(12)
+    noise = jnp.asarray(rng.standard_normal((2, 6, 2, 16, 32)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, 48, 2, 16)), jnp.float32)
+    positions = (64 + jnp.arange(48))[None]
+    for total in (0, 64):
+        got = ring_write(noise, k, k, jnp.asarray([1]), positions,
+                         jnp.asarray([total]), 0, 2)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(noise))
+    got = ring_write(noise, k, k, jnp.asarray([1]), positions,
+                     jnp.asarray([64 + 3]), 0, 2)
+    changed = (np.asarray(got) != np.asarray(noise)).any((2, 4))
+    # three rows of slot 1's ring, layer 0: ring index 64 % 32 = 0..2
+    assert changed.sum() == 3 and changed[0, 2, :3].all()
+
+
+@pytest.mark.parametrize("impl", [None, "flash"])
+def test_a_context_walked_in_chunks_is_the_context(impl, monkeypatch):
+    """A table wider than one flash call may hold resident (here two
+    block-table columns of float32 rows) is walked in chunks under a `cond`
+    and equals the one call over the table's width, for rows whose context
+    ends inside a chunk, on one, and a row with none."""
+    from ray_tpu.ops import paged_attention as pa
+
+    rng = np.random.default_rng(13)
+    b, hq, hkv, d, page, mp, s_new = 3, 4, 2, 16, 8, 7, 12
+    ctx_lens = (40, 16, 0)
+    kv_pages, bt, k_dense, v_dense, lens = _make_pages(
+        rng, b=b, hkv=hkv, d=d, page=page, num_pages=40, mp=mp,
+        lengths=[c + s_new for c in ctx_lens], layer=1)
+    positions = jnp.stack([jnp.arange(c, c + s_new) for c in ctx_lens])
+    q = jnp.asarray(rng.standard_normal((b, s_new, hq, d)), jnp.float32)
+    k_new, v_new = (jnp.stack([x[i, c:c + s_new] for i, c in
+                               enumerate(ctx_lens)])
+                    for x in (k_dense, v_dense))
+
+    def attend():
+        return jax.make_jaxpr(lambda *a: paged_prefill_attention(
+            *a, ctx_pages=mp, impl=impl, layer=1))(
+            q, k_new, v_new, kv_pages, bt, positions, lens)
+
+    whole = attend()
+    monkeypatch.setattr(pa, "FLASH_RESIDENT_KV_BYTES",
+                        2 * page * 2 * (2 * d * 4))
+    chunked = attend()
+    conds = [str(j).count(" cond[") for j in (whole, chunked)]
+    assert conds == [0, 4], conds            # columns 0-1, 2-3, 4-5, 6
+    args = (q, k_new, v_new, kv_pages, bt, positions, lens)
+    np.testing.assert_allclose(
+        np.asarray(jax.core.eval_jaxpr(chunked.jaxpr, chunked.consts,
+                                       *args)[0]),
+        np.asarray(jax.core.eval_jaxpr(whole.jaxpr, whole.consts, *args)[0]),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_a_wave_of_rows_writes_and_reads_each_its_own_ring():
+    """`ring_write` and `ring_context` over a batch are what they are a row
+    at a time: rows of different lengths in different slots (one several
+    windows long, one inside the window, one resuming mid-ring), and a
+    padding row with no real token that names a REAL row's slot and must
+    not touch it (one scatter: a duplicate index would be a race)."""
+    from ray_tpu.ops.paged_attention import ring_context, ring_write
+
+    rng = np.random.default_rng(14)
+    layers, slots_n, rp, hkv, page, d = 2, 4, 2, 2, 16, 8
+    noise = jnp.asarray(rng.standard_normal(
+        (layers, slots_n * rp, hkv, page, 2 * d)), jnp.float32)
+    s = 80
+    k, v = (jnp.asarray(rng.standard_normal((4, s, hkv, d)), jnp.float32)
+            for _ in range(2))
+    slots = jnp.asarray([3, 0, 2, 3], jnp.int32)
+    start = jnp.asarray([0, 32, 40, 0], jnp.int32)
+    total = jnp.asarray([80, 32 + 20, 40 + 70, 0], jnp.int32)
+    positions = start[:, None] + jnp.arange(s)[None]
+    got = ring_write(noise, k, v, slots, positions, total, 1, rp)
+    want = noise
+    for i in range(4):
+        want = ring_write(want, k[i:i + 1], v[i:i + 1], slots[i:i + 1],
+                          positions[i:i + 1], total[i:i + 1], 1, rp)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (np.asarray(got)[1, 6:8] != np.asarray(noise)[1, 6:8]).all()
+    np.testing.assert_array_equal(np.asarray(got)[1, 2:4],
+                                  np.asarray(noise)[1, 2:4])   # slot 1: idle
+    # what the rings hold, in position order: row 0's last 32 of 80
+    kc, vc, have = ring_context(got, slots[:3], total[:3], 1, rp)
+    np.testing.assert_array_equal(np.asarray(have), [32, 32, 32])
+    np.testing.assert_array_equal(np.asarray(kc[0]), np.asarray(k[0, 48:80]))
+    np.testing.assert_array_equal(np.asarray(vc[2]), np.asarray(v[2, 38:70]))
+    # row 1 resumed at 32 and wrote 20: positions 20..31 are the noise's
+    np.testing.assert_array_equal(np.asarray(kc[1, 12:]),
+                                  np.asarray(k[1, :20]))
+    for i in range(3):
+        one = ring_context(got, slots[i:i + 1], total[i:i + 1], 1, rp)
+        np.testing.assert_array_equal(np.asarray(one[0][0]),
+                                      np.asarray(kc[i]))
+
+
+@pytest.mark.parametrize("s, n_new, ctx, window", [
+    (4096, 4096, 0, 1024), (4096, 2084, 0, 1024), (4096, 4096, 8192, 1024),
+    (2048, 1842, 4100, 1024), (256, 200, 300, 1024), (512, 302, 1024, 1024),
+    (1024, 1000, 16384, 1024), (640, 513, 700, 200), (256, 256, 96, 32),
+    (128, 0, 0, 1024)])
+def test_prefill_block_visits_under_a_window(s, n_new, ctx, window):
+    """`prefill_block_visits` with `window` against a brute-force count
+    over the tiles of the two calls `window_prefill_attention` makes (own
+    tokens; the ring, min(ctx, window) of `window` columns real): visited
+    where a real row may attend a real key, an edge visit where a real row
+    may not attend some key of a visited block. At the cell's pass behind
+    8192 tokens a sliding layer visits 21 + 3 blocks where a full layer
+    visits 36 + 128."""
+    from ray_tpu.ops.paged_attention import prefill_block_visits
+
+    width = 33792 if ctx else 0
+    got = prefill_block_visits(s, width, n_new, ctx, window)
+    bq = min(512, -(-s // 128) * 128)
+    qi = np.arange(s)[:, None]
+    real = qi < n_new
+    visits = masked = pairs = 0
+    have = min(ctx, window)
+    for sk, woff, live in ((s, 0, s), (window if ctx else 0, have, have)):
+        if not sk:
+            continue
+        bk = bq if sk == s and woff == 0 and live == s else min(
+            512, -(-sk // 128) * 128)
+        kj = np.arange(sk)[None, :]
+        see = (kj > qi + woff - window) & (kj < live) & real
+        if live == s and woff == 0:
+            see &= kj <= qi
+        shape = (-(-s // bq) * bq, -(-sk // bk) * bk)
+        sees, rows = np.zeros(shape, bool), np.zeros(shape, bool)
+        sees[:s, :sk], rows[:s] = see, real
+        tiles = lambda x: x.reshape(  # noqa: E731
+            shape[0] // bq, bq, shape[1] // bk, bk)
+        visited = tiles(sees).any((1, 3))
+        # every tile from the first to the last a block's real rows see
+        span = np.maximum.accumulate(visited, 1) & np.maximum.accumulate(
+            visited[:, ::-1], 1)[:, ::-1]
+        assert (span == visited).all()
+        visits += int(visited.sum())
+        pairs += int(visited.sum()) * bq * bk
+        masked += int((tiles(rows & ~sees).any((1, 3)) & visited).sum())
+    assert got == (visits, pairs, masked)
+    if (s, n_new, ctx, window) == (4096, 4096, 8192, 1024):
+        assert got[0] == 21 + 3
+        assert prefill_block_visits(s, width, n_new, ctx)[0] == 36 + 128

@@ -460,8 +460,10 @@ def test_counters_records_and_the_first_tokens_three_parts():
     prefills = [r for r in recs if r["kind"] == "prefill"]
     assert all(r["block_passes"] is None for r in prefills)
     assert fields.index("block_passes") == fields.index("final") + 1
-    # (behind them a latent family's four, PR 43; then the stamps)
-    assert fields.index("mla_layers") == fields.index("block_len") + 1
+    # (behind them a two-kind attention family's six, PR 47, and a
+    # latent family's four, PR 43; then the stamps)
+    assert fields.index("window_layers") == fields.index("block_len") + 1
+    assert fields.index("mla_layers") == fields.index("block_len") + 7
     reqs = {r[0]: dict(zip(tracing.FIELDS["engine.request"], r))
             for r in tracing.records("engine.request",
                                      since=n0["engine.request"])}
