@@ -292,7 +292,7 @@ class LatentFacts:
 
 
 def dispatch_facts(cfg: KimiConfig, engine_config) -> list:
-    return ([ExpertFacts(cfg)] if cfg.num_experts else []) + [
+    return ([ExpertFacts(cfg, engine_config)] if cfg.num_experts else []) + [
         LatentFacts(cfg, engine_config)]
 
 

@@ -650,6 +650,25 @@ def moe_tile_rows(counts, tokens: int, cfg: LlamaConfig) -> int:
     return tile_visits(counts, tm) * tm
 
 
+def moe_tile_kn_fill_pct(passes, cfg: LlamaConfig) -> float:
+    """Of the K x N a visit of the grouped matmul multiplies, the percent
+    the weights have (`ops/grouped_matmul.py: tile_fit`; 100: `tk` and `tn`
+    divide both widths): a layer's two products weighted by their
+    operations, at the tile of a pass of each of `passes` tokens; the
+    lowest of them. From the shapes alone: it says whether the tile rule
+    fitted this model's widths, once, not what a run did."""
+    from ..ops.grouped_matmul import _tile, tile_fit
+
+    h, f = cfg.hidden_size, cfg.expert_width
+    fills = []
+    for tokens in passes:
+        tm = moe_row_layout(tokens, cfg)[0]
+        fills.append(3 * h * f / sum(
+            k * n / tile_fit(k, n, _tile(tm, k, n))
+            for k, n in ((h, 2 * f), (f, h))))
+    return round(100 * min(fills), 2)
+
+
 class ExpertFacts:
     """What an expert model's dispatches count (serve/llm/stage.py:
     model_family), written once for the families with experts. Their
@@ -668,11 +687,23 @@ class ExpertFacts:
         "moe_tile_rows_total":
             "rows the grouped matmul multiplied (tile visits x m-tile), all "
             "layers; moe_assignments_total over it is the fill of its tiles",
+        "moe_tile_kn_fill_pct":
+            "percent of the K x N a visit of the grouped matmul multiplies "
+            "that the expert weights have, at the decode step's tile and "
+            "the largest bucket's (the lower; 100: the tile divides both)",
     }
 
-    def __init__(self, cfg: LlamaConfig):
+    def __init__(self, cfg: LlamaConfig, engine_config, step_tokens: int = 1):
+        """`step_tokens`: the tokens a slot a pass of the decode program
+        computes (a block family's block)."""
         self.cfg = cfg
         self.layers = getattr(cfg, "n_expert_layers", cfg.num_layers)
+        self.kn_fill_pct = moe_tile_kn_fill_pct(
+            (engine_config.max_batch * step_tokens,
+             engine_config.prefill_buckets[-1]), cfg)
+
+    def sizes(self, pool_bytes: dict) -> dict:
+        return {"moe_tile_kn_fill_pct": self.kn_fill_pct}
 
     def harvest(self, totals: dict, rec: dict, packed) -> dict:
         layers, experts = self.layers, self.cfg.num_experts
@@ -995,7 +1026,7 @@ def serving_cache(cfg: LlamaConfig, pool, block_tables, total_lens=None,
 
 def dispatch_facts(cfg: LlamaConfig, engine_config) -> list:
     """(serve/llm/stage.py: model_family)"""
-    return [ExpertFacts(cfg)] if cfg.num_experts else []
+    return [ExpertFacts(cfg, engine_config)] if cfg.num_experts else []
 
 
 # ---------------------------------------------------------------- registry

@@ -279,7 +279,7 @@ class WindowFacts:
 
 
 def dispatch_facts(cfg: MellumConfig, engine_config) -> list:
-    return [ExpertFacts(cfg), WindowFacts(cfg)]
+    return [ExpertFacts(cfg, engine_config), WindowFacts(cfg)]
 
 
 def ring_pages(cfg: MellumConfig, page_size: int) -> int:

@@ -218,8 +218,8 @@ class BlockFacts:
 
 
 def dispatch_facts(cfg: SdarConfig, engine_config) -> list:
-    return ([ExpertFacts(cfg)] if cfg.num_experts else []) + [
-        BlockFacts(cfg)]
+    return ([ExpertFacts(cfg, engine_config, cfg.block_length)]
+            if cfg.num_experts else []) + [BlockFacts(cfg)]
 
 
 CONFIGS = {

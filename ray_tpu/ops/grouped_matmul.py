@@ -50,6 +50,9 @@ def row_tile(m: int, e: int) -> tuple:
     megablox visits every (m-tile, expert) pair that shares a row,
     multiplies the WHOLE [tm, K] x [K, N] tile at each visit whatever part
     of it is that expert's, and streams that expert's [K, N] once a visit.
+    (K x N as the weights have them: `_tile` gives `tk` and `tn` that
+    divide both, so a visit is counted, here and in `tile_visits`, on
+    tiles that multiply no column twice and none the weights lack.)
     On the chip (benchmarks/moe_gmm_probe.py, PERF.md section 6, PR 34) a
     visit costs the longer of the two: up to BALANCE_ROWS what counts is
     the number of visits, past it the rows multiplied (two visits of 256
@@ -78,27 +81,64 @@ def aligned_rows(m: int, e: int, tm: int) -> int:
     return -(-m // tm) * tm + e * tm
 
 
-def tile_for(m: int, e: int, k: int, n: int) -> tuple:
-    """The (tm, tk, tn) tile of the megablox kernel for `m` assignments on
-    `e` experts of [k, n]: a shape in, a tile out. `tm` is `row_tile`'s.
-    The rows are read again for every n-tile, which is tm / tn of the
-    weights' own traffic: `tn` is 8 * tm where the double-buffered blocks
-    and the float32 accumulator fit VMEM_BYTES, halved until they do."""
-    return _tile(row_tile(m, e)[0], k, n)
-
-
-def _tile(tm: int, k: int, n: int) -> tuple:
-    tk, tn = min(1024, k), min(8 * tm, n)
-    while tile_vmem_bytes((tm, tk, tn)) > VMEM_BYTES and tn > 1024:
-        tn //= 2
-    return tm, tk, tn
-
-
 def tile_vmem_bytes(tile: tuple, itemsize: int = 2) -> int:
     """VMEM the kernel holds at a tile: lhs, rhs and out blocks double
     buffered, and the float32 accumulator."""
     tm, tk, tn = tile
     return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def tgmm_vmem_bytes(tile: tuple, itemsize: int = 2) -> int:
+    """VMEM `tgmm` (the weights' gradient) holds at a tile: the two row
+    blocks and the [tk, tn] out block double buffered, and a float32
+    accumulator as large as the out block."""
+    tm, tk, tn = tile
+    return 2 * itemsize * (tm * tk + tm * tn + tk * tn) + 4 * tk * tn
+
+
+def tile_for(m: int, e: int, k: int, n: int) -> tuple:
+    """The (tm, tk, tn) tile of the megablox kernel for `m` assignments on
+    `e` experts of [k, n]: a shape in, a tile out. `tm` is `row_tile`'s;
+    `tk` and `tn` are `_tile`'s."""
+    return _tile(row_tile(m, e)[0], k, n)
+
+
+def _tile(tm: int, k: int, n: int, vmem=tile_vmem_bytes) -> tuple:
+    """`tk` and `tn` for an m-tile over [k, n] weights. The kernel makes
+    ceil(k / tk) x ceil(n / tn) grid steps a visit and multiplies the WHOLE
+    tile at each (the last k-tile under an iota mask over both blocks): a
+    tile that does not divide multiplies columns the weights do not have.
+    The rows are read again for every n-tile, which is tm / tn of the
+    weights' own traffic: `tn` is 8 * tm beside a `tk` of 1024, halved
+    until the double-buffered blocks and the float32 accumulator fit
+    VMEM_BYTES (`vmem`: the kernel's count, `tile_vmem_bytes` unless the
+    caller's is `tgmm`). That is the tile wherever it divides both widths
+    (PR 34 probed it there). Where it does not, and the widths are whole
+    MXU edges, the tile is the one that divides them, fits VMEM_BYTES and
+    makes the fewest steps a visit; of those, the fewest k-steps (each is
+    a pass over the accumulator, 0.8-1.0 us a visit at tm 128, where a
+    second n-tile re-reads the rows only if the m-tile changed:
+    benchmarks/moe_gmm_probe.py, PERF.md section 6, PR 48)."""
+    tk, tn = min(1024, k), min(8 * tm, n)
+    while vmem((tm, tk, tn)) > VMEM_BYTES and tn > 1024:
+        tn //= 2
+    edge = TILE_M_MIN
+    if (k % tk == 0 and n % tn == 0) or k % edge or n % edge:
+        return tm, tk, tn
+
+    def divisors(width):
+        return [t for t in range(edge, width + 1, edge) if width % t == 0]
+
+    fits = [(a, b) for a in divisors(k) for b in divisors(n)
+            if vmem((tm, a, b)) <= VMEM_BYTES]
+    return (tm, *min(fits, key=lambda t: ((k // t[0]) * (n // t[1]), -t[0])))
+
+
+def tile_fit(k: int, n: int, tile: tuple) -> float:
+    """Real K x N over the K x N a visit multiplies at `tile`: 1.0 where
+    `tk` and `tn` divide the weights."""
+    _, tk, tn = tile
+    return k * n / ((-(-k // tk) * tk) * (-(-n // tn) * tn))
 
 
 def tile_visits(group_sizes, tm: int) -> int:
@@ -176,22 +216,27 @@ def _megablox_fwd(lhs, rhs, group_sizes, tile, interpret):
 
 
 def _megablox_bwd(tile, interpret, res, grad):
-    return (*_moe_gmm_bwd(*res, grad, tile=tile, interpret=interpret),
+    return (*_moe_gmm_bwd(*res, grad, tm=tile[0], interpret=interpret),
             None)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _moe_gmm_bwd(lhs, rhs, group_sizes, grad, *, tile, interpret):
-    """d lhs = grad x rhs^T by group; d rhs[e] = lhs[group e]^T x grad."""
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def _moe_gmm_bwd(lhs, rhs, group_sizes, grad, *, tm, interpret):
+    """d lhs = grad x rhs^T by group; d rhs[e] = lhs[group e]^T x grad.
+    Each product at the tile of ITS widths: d lhs contracts over N, and
+    `tgmm` holds a whole [tk, tn] float32 accumulator beside its out
+    block, so the forward's tile is neither's."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
-    d_lhs = gmm.__wrapped__(grad, rhs, group_sizes, lhs.dtype, tile,
-                            transpose_rhs=True, interpret=interpret)
+    k, n = rhs.shape[1:]
+    d_lhs = gmm.__wrapped__(grad, rhs, group_sizes, lhs.dtype,
+                            _tile(tm, n, k), transpose_rhs=True,
+                            interpret=interpret)
     # rows in no group: the kernel left their gradient unwritten too
     d_lhs = jnp.where(
         (jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes))[:, None], d_lhs, 0)
     d_rhs = tgmm.__wrapped__(lhs.swapaxes(0, 1), grad, group_sizes,
-                             rhs.dtype, tile,
+                             rhs.dtype, _tile(tm, k, n, tgmm_vmem_bytes),
                              num_actual_groups=rhs.shape[0],
                              interpret=interpret)
     return d_lhs, d_rhs

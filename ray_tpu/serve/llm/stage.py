@@ -111,7 +111,8 @@ def model_family(name: str):
       objects (no JAX), one a feature of the model (three families list
       models/llama.py's `ExpertFacts`), that say what its dispatches
       count. Each has `STATS`, name -> help of the `stats()` counters it
-      moves (`*_total`) and the pool sizes it reports (`sizes(pool_bytes)`),
+      moves (`*_total`) and the sizes it reports (`sizes(pool_bytes)`: its
+      pools' bytes, a figure of its shapes),
       published as `rtpu_llm_<name>`; where it has any, `constant`, the
       `engine.dispatch` record fields every dispatch has, and three hooks
       that move the engine's totals and return record fields BY NAME

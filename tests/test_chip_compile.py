@@ -162,12 +162,15 @@ def _paged_decode(b, d, mp, hq=32, hkv=8, page=16, layers=4,
     return build
 
 
-def _moe_gmm(m, experts=8, h=4096, f=14336, layers=3):
+def _moe_gmm(m, experts=8, h=4096, f=14336, layers=3, grads=False):
     """The expert FFN's two grouped matmuls as `MoEMLP._dropless` calls
-    them on a TPU, for `m` assignments at Mixtral's widths in the rows
-    their layout takes (one call's: at most 4096), the weights a [layers,
-    experts, ...] stack read in place: the tile is the rule's
-    (`grouped_matmul.row_tile`), no `vmem_limit_bytes` asked."""
+    them on a TPU, for `m` assignments at Mixtral's widths (or the `h`,
+    `f` and `experts` of another model) in the rows their layout takes
+    (one call's: at most 4096), the weights a [layers, experts, ...] stack
+    read in place: the tile is the rule's (`grouped_matmul.row_tile`,
+    `_tile`), no `vmem_limit_bytes` asked. `grads`: the trainer's backward
+    through both (the gradients to the rows and to the stacks: `gmm`
+    transposed and `tgmm`, each at its own tile)."""
     def build(topo):
         from ray_tpu.ops import grouped_matmul as gm
 
@@ -185,6 +188,9 @@ def _moe_gmm(m, experts=8, h=4096, f=14336, layers=3):
                                    tm=tm, impl="megablox")
             gate, up = jnp.split(product(x, w_gu), 2, axis=-1)
             return product(jax.nn.silu(gate) * up, w_dn)
+        if grads:
+            fn = jax.grad(lambda *a, fwd=fn: fwd(*a).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))
         return fn, (sds((rows, h), BF16),
                     sds((layers, experts, h, 2 * f), BF16),
                     sds((layers, experts, f, h), BF16),
@@ -252,6 +258,20 @@ COMPILES = {
     "moe-gmm-mixtral-bucket512-M1024": _moe_gmm(1024),
     "moe-gmm-mixtral-bucket1024-M2048": _moe_gmm(2048),
     "moe-gmm-mixtral-bucket2048-M4096": _moe_gmm(4096),
+    # widths that `tk` 1024 / `tn` 8 * tm do not divide, at the tile fitted
+    # to them (PR 48). `mellum2-mixedctx`: a decode step's 64 slots x 8 on
+    # 64 experts, (128, 2304, 896) then (128, 896, 2304); one 4096-row
+    # block of a [1 x 4096] pass, the same at tm 256. `sdar-30b-a3b-chat`:
+    # a block step's 256 tokens x 8 on 128 experts, (128, 2048, 1536), 14.5
+    # of the 16 MiB by `tile_vmem_bytes`
+    "moe-gmm-mellum2-decode-M512": _moe_gmm(512, 64, 2304, 896, 8),
+    "moe-gmm-mellum2-pass4096-M32768": _moe_gmm(32768, 64, 2304, 896, 8),
+    "moe-gmm-sdar-block-M2048": _moe_gmm(2048, 128, 2048, 768, 6),
+    # the backward: refused for VMEM at tm 256 before PR 48 (tgmm held the
+    # forward's [1024, 2048] tile twice over), and at a fitted tile
+    "moe-gmm-grads-mixtral-M4096": _moe_gmm(4096, layers=1, grads=True),
+    "moe-gmm-grads-mellum2-M512": _moe_gmm(512, 64, 2304, 896, 1,
+                                           grads=True),
 }
 # The kernel's measured compile limits: K/V of one (batch, kv head) stay
 # resident in VMEM, so long kv is refused: forward and backward pass at
@@ -277,7 +297,7 @@ def test_kernel_compiles_for_v5e(topo, no_persistent_cache, name):
             lowered.compile()
     else:
         assert "tpu_custom_call" in lowered.compile().as_text()
-    if "-lens-" in name:
+    if "-lens-" in name or name.startswith("moe-gmm"):
         # K and V resident and nothing beside them: inside the VMEM a
         # kernel gets unasked, where the segment ids it replaces were not
         # (a raised `vmem_limit_bytes` lowers to `scoped_memory_configs`)

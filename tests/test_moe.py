@@ -435,6 +435,10 @@ def test_moe_dispatch_records_count_real_assignments(moe_engine_run):
                 "moe_tile_rows_total"):
         name = f"rtpu_llm_{key}"
         assert after[name] - before.get(name, 0) == stats[key], name
+    # what the tile rule made of the model's widths, once, as a gauge (a
+    # tiny model's widths are no whole MXU edges: its tile is the weights)
+    assert stats["moe_tile_kn_fill_pct"] == 100.0
+    assert after["rtpu_llm_moe_tile_kn_fill_pct"] == 100.0
 
 
 def test_moe_engine_prefill_with_groups_on_tile_boundaries():
@@ -490,29 +494,56 @@ def test_dense_engine_records_carry_no_moe_fields():
 
 # (M, E, K, N) -> the m-tile: every call `mixtral-chat`'s programs make
 # (a decode step's 32 slots x 2; a prompt's [1 x bucket] pass at the five
-# buckets; both products), a model of 64 small experts, and the short calls
+# buckets; both products), a model of 64 small experts, the short calls, and
+# the widths the other three expert cells run: Mellum2's decode step, its
+# shortest bucket and a 4096 pass on 64 experts; SDAR's block step, its
+# opening pass and a prefill wave on 128; Kimi's share of a 4096 pass on 12
 MIXTRAL_GU, MIXTRAL_DN = (4096, 28672), (14336, 4096)
+MELLUM_GU, MELLUM_DN = (2304, 1792), (896, 2304)
+SDAR_GU, SDAR_DN = (2048, 1536), (768, 2048)
+KIMI_GU, KIMI_DN = (7168, 4096), (2048, 7168)
 TILE_CASES = [
     *((m, 8, *kn, tm) for kn in (MIXTRAL_GU, MIXTRAL_DN)
       for m, tm in ((64, 128), (256, 128), (512, 128), (1024, 256),
                     (2048, 256), (4096, 256))),
     *((m, 64, 2048, 2048, tm) for m, tm in (
         (64, 128), (128, 128), (256, 128), (4096, 128), (8192, 256))),
+    *((m, 64, *kn, tm) for kn in (MELLUM_GU, MELLUM_DN)
+      for m, tm in ((512, 128), (2048, 128), (32768, 256))),
+    *((m, 128, *kn, tm) for kn in (SDAR_GU, SDAR_DN)
+      for m, tm in ((2048, 128), (4096, 128), (16384, 256))),
+    *((1024, 12, *kn, 256) for kn in (KIMI_GU, KIMI_DN)),
 ]
+# what the fit gives the widths that `tk` 1024 / `tn` 8 * tm do not divide
+FITTED = {
+    (128, *MELLUM_GU): (2304, 896), (256, *MELLUM_GU): (2304, 896),
+    (128, *MELLUM_DN): (896, 2304), (256, *MELLUM_DN): (896, 2304),
+    (128, *SDAR_GU): (2048, 1536), (256, *KIMI_DN): (2048, 1024),
+}
 
 
 @pytest.mark.parametrize("m, e, k, n, tm", TILE_CASES)
 def test_grouped_matmul_tile_follows_the_shape(m, e, k, n, tm):
-    """`tile_for`: a shape in, a tile out. M <= 256 keeps the tile every
-    shape had before PR 34 at those sizes (decode's kernel is unchanged);
-    the kernel's double-buffered blocks and accumulator fit the 16 MiB of
-    VMEM it gets unasked; whole tiles divide the weights."""
+    """`tile_for`: a shape in, a tile out. It is the tile it was (`tk` 1024,
+    `tn` 8 * tm: decode's kernel and every Mixtral call are unchanged)
+    wherever that tile divides the weights, and one that divides them
+    everywhere else (`tile_fit` 1.0: no visit multiplies columns the weights
+    do not have, no k-tile is masked); the kernel's double-buffered blocks
+    and accumulator fit the 16 MiB of VMEM it gets unasked; the backward's
+    two products have tiles of their own that divide and fit as well."""
     from ray_tpu.ops import grouped_matmul as gm
 
     tile = gm.tile_for(m, e, k, n)
     assert tile[0] == tm == gm.row_tile(m, e)[0]
+    was = (tm, min(1024, k), min(8 * tm, n))
+    if k % was[1] == 0 and n % was[2] == 0:
+        assert tile == was and (tm, k, n) not in FITTED
+    else:
+        assert gm.tile_fit(k, n, was) < 1.0
+        assert tile[1:] == FITTED[tm, k, n]
+    if (k, n) in (MIXTRAL_GU, MIXTRAL_DN):
+        assert tile == (tm, 1024, 1024 if tm == 128 else 2048)
     if m <= 256:
-        assert tile == (128, min(1024, k), min(1024, n))
         assert not gm.row_tile(m, e)[1]
     # groups start on tile boundaries where an expert's share of the call
     # is half the smallest tile or more, and the tile holds that share
@@ -520,7 +551,42 @@ def test_grouped_matmul_tile_follows_the_shape(m, e, k, n, tm):
     assert tm >= min(m // e, 256)
     assert gm.tile_vmem_bytes(tile) <= 16 * 2 ** 20
     assert k % tile[1] == 0 and n % tile[2] == 0
+    assert gm.tile_fit(k, n, tile) == 1.0
     assert all(t % 128 == 0 for t in tile)
+    # d lhs contracts over N; tgmm's accumulator is a whole [tk, tn]
+    d_lhs, d_rhs = gm._tile(tm, n, k), gm._tile(tm, k, n, gm.tgmm_vmem_bytes)
+    assert gm.tile_fit(n, k, d_lhs) == 1.0 == gm.tile_fit(k, n, d_rhs)
+    assert gm.tile_vmem_bytes(d_lhs) <= 16 * 2 ** 20
+    assert gm.tgmm_vmem_bytes(d_rhs) <= 16 * 2 ** 20
+
+
+def test_tile_fit_is_the_share_of_a_visit_the_weights_have():
+    """`tile_fit` at the tiles the rule gave Mellum2's and SDAR's widths
+    before it fitted them (the issue's table: a visit multiplied 1.52 /
+    1.33 / 1.78 times the real K x N), and what an engine says of its
+    model once (`moe_tile_kn_fill_pct`, from the shapes alone): 100 for
+    all four expert configurations."""
+    from ray_tpu.models import kimi, mellum, sdar
+    from ray_tpu.models.llama import moe_tile_kn_fill_pct
+    from ray_tpu.ops import grouped_matmul as gm
+
+    for (k, n), tile, padded in (
+            (MELLUM_GU, (128, 1024, 1024), 1.52),
+            (MELLUM_GU, (256, 1024, 1792), 1.33),
+            (MELLUM_DN, (128, 896, 1024), 1.33),
+            (MELLUM_DN, (256, 896, 2048), 1.78),
+            (SDAR_GU, (128, 1024, 1024), 1.33),
+            (KIMI_DN, (256, 1024, 2048), 1.14),
+            (MIXTRAL_GU, (256, 1024, 2048), 1.00)):
+        assert round(1 / gm.tile_fit(k, n, tile), 2) == padded
+    # (decode step's tokens, largest bucket) as the cells run them
+    for cfg, passes in (
+            (get_config("mixtral-8x7b"), (32, 2048)),
+            (mellum.get_config("mellum2-12b-a2.5b"), (64, 4096)),
+            (sdar.get_config("sdar-30b-a3b"), (64 * 4, 2048)),
+            (kimi.get_config("kimi-k2.5", num_experts=12,
+                             n_routed_experts=384), (24, 4096))):
+        assert moe_tile_kn_fill_pct(passes, cfg) == 100.0
 
 
 @pytest.mark.parametrize("seed", range(4))
@@ -601,9 +667,20 @@ def test_dropless_layer_is_the_same_with_groups_on_tile_boundaries(
         np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
 
 
-# rows of the call -> the m-tile the rule gives it; the groups: uneven, one
-# empty, ends inside tiles, and a tail of rows in no group
-KERNEL_CASES = {128: (300, [70, 0, 130, 50]), 256: (700, [300, 0, 45, 255])}
+# the m-tile the rule gives the call -> its rows and groups (uneven, one
+# empty, ends inside tiles, and a tail of rows in no group) and [K, N]; and
+# two pairs of widths the rule fits a tile to, none a power of two.
+# "mellum-gate-up": the forward's is (128, 2304, 896), two n-tiles; d lhs's
+# (128, 1792, 1152); tgmm's (128, 768, 1792), three k-steps.
+# "k-steps": (128, 2304, 896) over K 4608, two k-steps a visit; d lhs's
+# (128, 896, 2304), two n-tiles; tgmm's (128, 1536, 896)
+KERNEL_CASES = {128: (300, [70, 0, 130, 50], 256, 384),
+                256: (700, [300, 0, 45, 255], 256, 384),
+                "mellum-gate-up": (300, [70, 0, 130, 50], 2304, 1792),
+                "k-steps": (300, [70, 0, 130, 50], 4608, 896)}
+KERNEL_TILES = {
+    "mellum-gate-up": ((2304, 896), (1792, 1152), (768, 1792)),
+    "k-steps": ((2304, 896), (896, 2304), (1536, 896))}
 
 
 @pytest.mark.parametrize("stacked", [False, True], ids=["experts", "stack"])
@@ -612,15 +689,21 @@ def test_grouped_matmul_kernel_matches_plain_path_forward_and_backward(
         monkeypatch, tm, stacked):
     """The Pallas grouped matmul (interpret mode here; the compiled kernel
     on a TPU) against `jax.lax.ragged_dot`, which the CPU tests above run,
-    at every m-tile the rule returns: uneven groups that cross tile
-    boundaries, an empty one and a tail of rows in no group (undefined out
-    of the kernel, with a zero gradient); the experts alone or a whole
-    [L, E, K, N] stack read by layer; and the gradients the one-chip
-    trainer takes through the kernel's own backward (gmm and tgmm)."""
+    at every m-tile the rule returns and at widths it fits a tile to:
+    uneven groups that cross tile boundaries, an empty one and a tail of
+    rows in no group (undefined out of the kernel, with a zero gradient);
+    the experts alone or a whole [L, E, K, N] stack read by layer; and the
+    gradients the one-chip trainer takes through the kernel's own backward
+    (gmm and tgmm, each at the tile of its own widths)."""
     from ray_tpu.ops import grouped_matmul as gm
 
     rng = np.random.default_rng(8)
-    (m, sizes), kdim, n, experts = KERNEL_CASES[tm], 256, 384, 4
+    (m, sizes, kdim, n), experts = KERNEL_CASES[tm], 4
+    if tm in KERNEL_TILES:
+        tiles, tm = KERNEL_TILES[tm], 128
+        assert tiles == tuple(t[1:] for t in (
+            gm.tile_for(m, experts, kdim, n), gm._tile(tm, n, kdim),
+            gm._tile(tm, kdim, n, gm.tgmm_vmem_bytes)))
     assert gm.tile_for(m, experts, kdim, n)[0] == tm
     lhs = jnp.asarray(rng.normal(size=(m, kdim)), jnp.float32)
     stack = jnp.asarray(rng.normal(size=(2, experts, kdim, n)), jnp.float32)
@@ -641,11 +724,18 @@ def test_grouped_matmul_kernel_matches_plain_path_forward_and_backward(
         (_, out), grads = jax.value_and_grad(
             loss, argnums=(0, 1), has_aux=True)(
                 lhs, rhs, layer, "megablox_interpret")
-    np.testing.assert_allclose(out, plain, atol=1e-4, rtol=0)
+
+    def close(got, want, atol, rtol=0):
+        # float32's summation order, at the size the widths give the sums
+        # (outputs reach 70 and gradients 1e3 at K 256, three times that
+        # and more at 2304)
+        np.testing.assert_allclose(
+            got, want, rtol=rtol, atol=atol * float(np.abs(want).max()))
+
+    close(out, plain, 1.5e-6)
     assert not np.asarray(grads[0][real:]).any()
     d_rhs = grads[1][1] if stacked else grads[1]
-    # gradients reach 1e3 here: float32's summation order
-    np.testing.assert_allclose(grads[0], g_plain[0], rtol=1e-5, atol=3e-3)
-    np.testing.assert_allclose(d_rhs, g_plain[1], rtol=1e-5, atol=3e-3)
+    close(grads[0], g_plain[0], 3e-6, rtol=1e-5)
+    close(d_rhs, g_plain[1], 3e-6, rtol=1e-5)
     if stacked:                 # the other layer's experts: untouched
         assert not np.asarray(grads[1][0]).any()
