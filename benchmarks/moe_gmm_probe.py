@@ -32,6 +32,29 @@ inside the groups), which takes the boundary visits away; with `--block` a
 call is one block of that many rows out of the aligned layout (the middle
 one), as `MoEMLP._dropless` cuts a long pass. The last line names
 the tile and the layout the rule (`row_tile`, `tile_for`) gives each shape.
+
+    python benchmarks/moe_gmm_probe.py --layer [--experts E --topk K
+        --hidden H --width F --routed R] [--tokens 4096] [--real 0.92]
+        [--blocks 0,4096,16384] [--tree DIR]
+
+`--layer` times one WHOLE dropless expert layer (`models/llama.py: MoEMLP`,
+router to fold, the experts read in place from a `[3, E, ...]` stack as a
+serving program reads them) at a configuration's widths over a pass of
+`--tokens` tokens of which `--real` (its first) are no padding (Mellum2:
+`--experts 64 --topk 8 --hidden 2304 --width 896`; Kimi's share: `--experts
+12 --topk 8 --routed 384 --hidden 7168 --width 2048`), one JSON line a block
+size (`--blocks`: rows ONE grouped-matmul call of a long pass gets; 0: the
+tree's own `_MOE_ROWS`), with the blocks that makes. Then the layer BY
+PARTS at the tree's layout, each part a jitted loop that carries its
+outputs (a part called from the host times the dispatch, a loop whose
+outputs nobody carries is dead code): the layout's integer arrays
+(`dropless_layout`), the row gather over all rows at once, the two products
+with `silu * up` between them in ONE call over all rows, the pass cut into
+blocks as the layer cuts it (gathers, products and the stack of blocks: less
+the two lines above it is what cutting costs), the un-sort gather and the
+fold. `--tree DIR` imports `ray_tpu` from another checkout (a parent
+unpacked under `.scratch/`), for the whole layer only where that tree has
+no `dropless_layout`: a before and after in one call.
 Needs a TPU; nothing here is a cell's number.
 """
 
@@ -46,7 +69,8 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
 
 LAYERS = 3
 PRODUCTS = ("gate_up", "down")
@@ -109,15 +133,177 @@ def time_tile(stack, m: int, sizes_list, tile, reps: int):
     lhs = jax.random.normal(jax.random.PRNGKey(m), (m, k), jnp.bfloat16)
     out = []
     for sizes in sizes_list:
-        sizes = jnp.asarray(sizes)
-        jax.block_until_ready(loop(lhs, stack, sizes))     # compile + warm
-        times = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            jax.block_until_ready(loop(lhs, stack, sizes))
-            times.append((time.perf_counter() - t0) / (reps * LAYERS))
-        out.append(statistics.median(times))
+        out.append(timed(loop, lhs, stack, jnp.asarray(sizes))
+                   / (reps * LAYERS))
     return out
+
+
+def timed(fn, *args, reps: int = 5):
+    """Median seconds of `fn(*args)` after one call that compiles it."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return statistics.median(times)
+
+
+def timed_part(fn, args, vary: int, inner: int = 16):
+    """Median seconds of ONE `fn(*args)` among `inner` in a jitted loop (a
+    part of a layer is tens of microseconds: a call of its own from the
+    host would time the dispatch). The loop CARRIES the part's outputs, so
+    every iteration computes all of them, and the next iteration's
+    `args[vary]` (a small array) depends on them through a zero the
+    compiler cannot know, so nothing is hoisted out of the loop."""
+    import jax
+    import jax.numpy as jnp
+
+    def zero_of(out):
+        zero = jnp.int32(0)
+        for leaf in jax.tree.leaves(out):
+            corner = leaf.reshape(-1)[0]
+            zero += (jnp.minimum(corner, 0)               # none negative
+                     if jnp.issubdtype(leaf.dtype, jnp.integer)
+                     else corner != corner).astype(jnp.int32)     # no NaN
+        return zero
+
+    @jax.jit
+    def loop(*args):
+        def body(_, out):
+            moved = list(args)
+            moved[vary] = moved[vary] + zero_of(out).astype(
+                moved[vary].dtype)
+            return fn(*moved)
+        return jax.lax.fori_loop(0, inner, body, jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype),
+            jax.eval_shape(fn, *args)))
+
+    return timed(loop, *args) / inner
+
+
+def layer_probe(args, dev):
+    """`--layer`: see the module docstring."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    e, k, h, f, tokens, layers = (args.experts, args.topk, args.hidden,
+                                  args.width, args.tokens, args.layers)
+    cfg = llama.get_config(
+        "tiny-moe", dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        hidden_size=h, num_experts=e, num_experts_per_tok=k,
+        moe_intermediate_size=f, n_routed_experts=args.routed or None,
+        expert_first=0)
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 4)
+
+    def stack(key, shape):
+        # an expert at a time: a whole stack's normal draw would hold its
+        # float32 form too
+        return jax.lax.map(
+            lambda kk: jax.random.normal(kk, shape, jnp.bfloat16) * 0.02,
+            jax.random.split(key, layers * e)).reshape((layers, e) + shape)
+
+    w_gu, w_dn = stack(keys[0], (h, 2 * f)), stack(keys[1], (f, h))
+    router = jax.random.normal(keys[2], (h, cfg.routed_experts), jnp.float32)
+    x = jax.random.normal(keys[3], (1, tokens, h), jnp.bfloat16)
+    real = float(args.real.split(",")[0])      # the first, where several
+    mask = (jnp.arange(tokens) < int(round(tokens * real)))[None]
+    layer = llama.MoEMLP(cfg)
+    params = {"router": router, "experts_gate_up": w_gu[0],
+              "experts_down": w_dn[0]}
+
+    def whole(x, w_gu, w_dn):
+        # the residual stream through 2 x `--layers` layers: every token's
+        # output is the next layer's input, so nothing of a layer is dead
+        return jax.lax.fori_loop(
+            0, 2 * layers, lambda i, x: x + layer.apply(
+                {"params": params}, x, token_mask=mask,
+                stacked=(w_gu, w_dn, i % layers)), x)
+
+    row_bytes = 2 * f * 2
+    own = llama._MOE_ROWS
+    base = {"device": dev.device_kind, "experts": e, "topk": k, "hidden": h,
+            "width": f, "routed": cfg.routed_experts, "tokens": tokens,
+            "real": real, "tree": args.tree or "."}
+    for block in (int(b) for b in args.blocks.split(",")):
+        llama._MOE_ROWS = block or own
+        tm, aligned, rows, per_call = llama.moe_row_layout(tokens, cfg)
+        # a wrapper of its own a block: jit keys its traces by the function
+        secs = timed(jax.jit(lambda *a: whole(*a)), x, w_gu,
+                     w_dn) / (2 * layers)
+        print(json.dumps({
+            **base, "part": "whole layer", "tm": tm, "aligned": aligned,
+            "rows": rows, "block": per_call, "blocks": rows // per_call,
+            "call_mb": round(per_call * row_bytes / 2 ** 20, 1),
+            "ms": round(1e3 * secs, 4)}), flush=True)
+    if not hasattr(llama, "dropless_layout"):
+        return
+    llama._MOE_ROWS = own
+    tm, aligned, rows, block = llama.moe_row_layout(tokens, cfg)
+    m = tokens * k
+    xt = x[0]
+    # the router's own choice, as the layer makes it
+    _, idx = jax.lax.top_k(jax.nn.softmax(
+        xt.astype(jnp.float32) @ router, axis=-1), k)
+    if cfg.routed_experts != e:
+        idx = jnp.where(idx < e, idx, e)
+    expert = jnp.where(jnp.repeat(mask[0], k), idx.reshape(m), e)
+    layout = jax.jit(lambda ex: llama.dropless_layout(ex, e, tm, aligned,
+                                                      rows))
+    counts, ends, at, row_of = layout(expert)
+    sizes = jnp.diff(ends, prepend=0)
+    gather = jax.jit(lambda xt, at: xt[at // k])
+    rows_in = gather(xt, at)
+
+    def products(lhs, w_gu, w_dn, sizes):
+        gate_p, up_p = jnp.split(
+            grouped_matmul(lhs, w_gu, sizes, jnp.int32(1), tm), 2, axis=-1)
+        return grouped_matmul(nn.silu(gate_p) * up_p, w_dn, sizes,
+                              jnp.int32(1), tm)
+
+    def blocks(xt, at, ends, w_gu, w_dn):
+        # as `MoEMLP._dropless` cuts a pass
+        def experts_on(lo, n):
+            here = jnp.diff(jnp.clip(ends, lo, lo + n), prepend=lo)
+            return products(xt[jax.lax.dynamic_slice(at, (lo,), (n,)) // k],
+                            w_gu, w_dn, here)
+        if block == rows:
+            return experts_on(0, rows)
+        return jax.lax.map(
+            lambda lo: jax.lax.cond(
+                lo < ends[-1], lambda: experts_on(lo, block),
+                lambda: jnp.zeros((block, h), jnp.bfloat16)),
+            jnp.arange(0, rows, block)).reshape(rows, h)
+
+    y = jax.jit(blocks)(xt, at, ends, w_gu, w_dn)
+    unsort = jax.jit(lambda y, row_of: y[row_of])
+    gate = jnp.full((tokens, k), 1.0 / k, jnp.float32)
+    fold = jax.jit(lambda y, gate: jnp.einsum(
+        "tkh,tk->th", y.reshape(tokens, k, h).astype(jnp.float32),
+        gate).astype(jnp.bfloat16))
+    real_rows = int(ends[-1])
+    # (part, function, operands, the small operand that varies, bytes moved)
+    for part, fn, operands, vary, nbytes in (
+            ("layout arrays", layout, (expert,), 0, 0),
+            ("row gather, all rows", gather, (xt, at), 1, 2 * rows * h * 2),
+            ("products, one call", products, (rows_in, w_gu, w_dn, sizes), 3,
+             0),
+            ("the pass in blocks", blocks, (xt, at, ends, w_gu, w_dn), 2, 0),
+            ("un-sort gather", unsort, (y, row_of), 1, 2 * m * h * 2),
+            ("fold", fold, (unsort(y, row_of), gate), 1, 0)):
+        secs = timed_part(fn, operands, vary)
+        print(json.dumps({
+            **base, "part": part, "tm": tm, "aligned": aligned, "rows": rows,
+            "block": block, "real_rows": real_rows,
+            "ms": round(1e3 * secs, 4),
+            **({"gb_s": round(nbytes / secs / 1e9, 1)} if nbytes else {})}),
+            flush=True)
 
 
 def main():
@@ -132,7 +318,16 @@ def main():
     ap.add_argument("--experts", type=int, default=8)
     ap.add_argument("--hidden", type=int, default=4096)
     ap.add_argument("--width", type=int, default=14336)
+    ap.add_argument("--layer", action="store_true")
+    ap.add_argument("--topk", type=int, default=2)
+    ap.add_argument("--routed", type=int, default=0)
+    ap.add_argument("--tokens", type=int, default=4096)
+    ap.add_argument("--blocks", default="0")
+    ap.add_argument("--tree", default="")
+    ap.add_argument("--layers", type=int, default=LAYERS)
     args = ap.parse_args()
+    if args.tree:
+        sys.path.insert(0, os.path.join(ROOT, args.tree))
 
     import jax
     import jax.numpy as jnp
@@ -142,6 +337,8 @@ def main():
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit("moe_gmm_probe: no TPU found")
+    if args.layer:
+        return layer_probe(args, dev)
     e, h, f = args.experts, args.hidden, args.width
     widths = {"gate_up": (h, 2 * f), "down": (f, h)}
     rows = [int(r) for r in args.rows.split(",")]

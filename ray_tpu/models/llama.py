@@ -508,30 +508,12 @@ class MoEMLP(nn.Module):
             # padding joins the trailing group E, which multiplies nothing
             expert = jnp.where(jnp.repeat(token_mask.reshape(T), k),
                                expert, E)
-        order = jnp.argsort(expert, stable=True)
-        counts = jnp.zeros((E + 1,), jnp.int32).at[expert].add(1)[:E]
+        counts, ends, at, row_of = dropless_layout(expert, E, tm, aligned,
+                                                   rows)
         self.sow("routing", "expert_counts", counts,
                  reduce_fn=lambda a, b: a + b,
                  init_fn=lambda: jnp.zeros((E,), jnp.int32))
         w_gu, w_dn = w_gu.astype(cfg.dtype), w_dn.astype(cfg.dtype)
-        # `order[s]` is the assignment at place s of the packed order (by
-        # expert, padding last). Row p of what the experts multiply holds
-        # the assignment `at[p]`, expert e owns `sizes[e]` rows, and every
-        # assignment comes back `shift[its expert]` rows below its place.
-        sizes, at, shift = counts, order, None
-        if aligned:
-            # every expert's rows start on a tile of the kernel: group e
-            # moves `shift[e]` rows down and is padded to whole tiles (the
-            # padding repeats some real row: multiplied with the tile it
-            # shares either way, never read back); the model's own padding
-            # follows the last group
-            sizes = -(-counts // tm) * tm
-            shift = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                     jnp.cumsum(sizes - counts)])  # [E + 1]
-        ends = jnp.cumsum(sizes)             # where each expert's rows end
-        if aligned:
-            owner = jnp.searchsorted(ends, jnp.arange(rows), side="right")
-            at = order[jnp.clip(jnp.arange(rows) - shift[owner], 0, M - 1)]
 
         def experts_on(lo, n):
             """The expert FFN on the `n` rows from `lo` on; rows past the
@@ -558,9 +540,6 @@ class MoEMLP(nn.Module):
                 jnp.arange(0, rows, block)).reshape(rows, h)
         # back to token order; the k weighted outputs are summed in
         # float32, in the same order wherever the token sits
-        row_of = jnp.argsort(order)
-        if aligned:
-            row_of = row_of + shift[expert]
         y = y[row_of].reshape(T, k, h)
         if cfg.routed_experts != E:
             # an assignment to an absent expert reads a row past the last
@@ -607,8 +586,74 @@ class MoEMLP(nn.Module):
         return out.reshape(G * g, h)[:T]
 
 
+def _runs(a, starts, n: int):
+    """[len(starts), n]: `a[s : s + n]` for each start `s` in `starts`
+    (0 <= s <= len(a)), with `a`'s last element past its end (what a clip
+    of the index would read). No gather of scalars and no loop over the
+    starts: `a` in windows of `n`, a gather of the two whole windows a run
+    lies in, and a shift left by the run's offset in them, one select a
+    bit of it. (On a v5e, 192 runs of 256 out of 32,768: 15 us; as 192
+    dynamic slices 165, as a gather of 49,152 scalars 365: PERF.md section
+    6, PR 49.)"""
+    windows = -(-a.shape[0] // n) + 2
+    a = jnp.pad(a, (0, windows * n - a.shape[0]), mode="edge").reshape(
+        windows, n)
+    two = jnp.concatenate([a[:-1], a[1:]], 1)[starts // n]      # [runs, 2n]
+    offset, bit = starts % n, 1
+    while bit < n:
+        two = jnp.where(((offset & bit) != 0)[:, None],
+                        jnp.roll(two, -bit, 1), two)
+        bit *= 2
+    return two[:, :n]
+
+
+def dropless_layout(expert, n_experts: int, tm: int, aligned: bool,
+                    rows: int) -> tuple:
+    """The dropless layer's rows for `expert` ([M] int32: the expert each
+    assignment chose, `n_experts` for padding and for an expert a share
+    does not hold), as `moe_row_layout` gave (tm, aligned, rows):
+    (`counts` [E] real assignments an expert, `ends` [E] where each
+    expert's rows end, `at` [rows] the assignment a row holds, `row_of`
+    [M] the row an assignment's result is read from).
+
+    Assignments are sorted by expert (stable, the trailing group last).
+    Packed, row p holds the p-th of that order. Aligned, every expert's
+    rows start on a tile of the kernel: group e moves `shift[e]` rows down
+    and is padded to whole tiles (the padding repeats some real row:
+    multiplied with the tile it shares either way, never read back), and
+    the trailing group follows the last expert's.
+
+    The integer work is sums over compares that XLA fuses, on a chip where
+    a gather or a scatter of 32,768 scalars costs a third of a
+    millisecond: counts and an assignment's shift from ONE [M, E + 1]
+    compare (no scatter-add of assignments, no gather by expert), and the
+    aligned layout from a table a TILE (a tile has one owner: the groups
+    that end at or before its first row, [rows / tm, E] compares, say how
+    far its rows moved; `at` is the sorted order read in runs of `tm`
+    from there, `_runs`), not a search a row."""
+    E, M = n_experts, expert.shape[0]
+    order = jnp.argsort(expert, stable=True)
+    joined = expert[:, None] == jnp.arange(E + 1)          # [M, E + 1]
+    counts = joined.sum(0, dtype=jnp.int32)[:E]
+    row_of = jnp.argsort(order)
+    if not aligned:
+        return counts, jnp.cumsum(counts), order, row_of
+    sizes = -(-counts // tm) * tm
+    pad = sizes - counts
+    shift = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(pad)])
+    ends = jnp.cumsum(sizes)
+    first = jnp.arange(0, rows, tm)                        # of each tile
+    run = first - jnp.sum((first[:, None] >= ends) * pad, 1)
+    at = _runs(order, jnp.minimum(run, M), tm).reshape(rows)
+    return counts, ends, at, row_of + jnp.sum(joined * shift, 1)
+
+
 # sorted assignments per pass through the experts (MoEMLP._dropless): a
-# [4096, 2f] intermediate is 235 MB at Mixtral widths
+# [4096, 2f] intermediate is 235 MB at Mixtral widths. Swept in PR 49
+# (benchmarks/moe_gmm_probe.py --layer, PERF.md section 6): with the stack
+# of blocks below, 4096 to 16384 rows a block read the same to 2%
+# at Mellum2's widths; what a pass pays for being cut is the stack, not the
+# number of calls (ROADMAP A15 (c), held until its cell can measure it).
 _MOE_ROWS = 4096
 
 
@@ -631,6 +676,18 @@ def moe_row_layout(tokens: int, cfg: LlamaConfig) -> tuple:
     return tm, aligned, -(-rows // _MOE_ROWS) * _MOE_ROWS, _MOE_ROWS
 
 
+def _tile_padded(counts, tokens: int, cfg: LlamaConfig):
+    """(rows each expert's group takes in a pass of `tokens` tokens, the
+    pass's layout): `counts` ([..., E], numpy) on whole tiles where the
+    layout aligns them."""
+    import numpy as np
+
+    layout = moe_row_layout(tokens, cfg)
+    tm, aligned = layout[:2]
+    counts = np.asarray(counts)
+    return (-(-counts // tm) * tm if aligned else counts), layout
+
+
 def moe_tile_rows(counts, tokens: int, cfg: LlamaConfig) -> int:
     """Rows the grouped-matmul kernel MULTIPLIES (tile visits x m-tile) for
     passes of `tokens` tokens through dropless layers whose experts got
@@ -639,15 +696,32 @@ def moe_tile_rows(counts, tokens: int, cfg: LlamaConfig) -> int:
     The real assignments over it are the fill of the tiles. Host
     arithmetic on counts the engine fetched anyway (a pass cut into
     _MOE_ROWS blocks counts the same: blocks end on tile boundaries)."""
-    import numpy as np
-
     from ..ops.grouped_matmul import tile_visits
 
-    tm, aligned = moe_row_layout(tokens, cfg)[:2]
-    counts = np.asarray(counts)
-    if aligned:
-        counts = -(-counts // tm) * tm
-    return tile_visits(counts, tm) * tm
+    sizes, (tm, *_) = _tile_padded(counts, tokens, cfg)
+    return tile_visits(sizes, tm) * tm
+
+
+def moe_gmm_calls(counts, tokens: int, cfg: LlamaConfig,
+                  passes: int = 1) -> tuple:
+    """(grouped-matmul calls of ONE product, rows they were handed) for
+    passes of `tokens` tokens through dropless layers whose experts got
+    `counts` ([..., E] real assignments, a pass a row of E): a pass in one
+    call hands it all its rows whatever they hold; a pass in _MOE_ROWS
+    blocks makes a call for each block that holds a row of a group
+    (`MoEMLP._dropless`: the blocks past the last group's end are skipped).
+    The rows are what the layer gathers, multiplies by tiles and passes
+    through `silu * up`; the real assignments over them say how much of
+    that was padding. `passes`: the rows of a wave whose assignments a row
+    of `counts` sums (each a pass of its own: one call at least; the blocks
+    of their sum are a floor). Host arithmetic, as `moe_tile_rows`."""
+    import numpy as np
+
+    sizes, (_, _, rows, block) = _tile_padded(counts, tokens, cfg)
+    ends = sizes.sum(-1)                  # a pass's last group's end
+    blocks = -(-ends // block) if block < rows else np.ones_like(ends)
+    calls = int(np.maximum(blocks, passes).sum())
+    return calls, calls * block
 
 
 def moe_tile_kn_fill_pct(passes, cfg: LlamaConfig) -> float:
@@ -687,6 +761,14 @@ class ExpertFacts:
         "moe_tile_rows_total":
             "rows the grouped matmul multiplied (tile visits x m-tile), all "
             "layers; moe_assignments_total over it is the fill of its tiles",
+        "moe_gmm_calls_total":
+            "grouped-matmul calls of ONE product, all layers: one a decode "
+            "step or a short pass, and one for each block that held real "
+            "rows of a pass long enough to be cut into blocks",
+        "moe_layout_rows_total":
+            "rows those calls were handed (gathered, multiplied by tiles, "
+            "passed through silu * up), all layers; over moe_assignments_"
+            "total it is the rows moved a real assignment",
         "moe_tile_kn_fill_pct":
             "percent of the K x N a visit of the grouped matmul multiplies "
             "that the expert weights have, at the decode step's tile and "
@@ -718,15 +800,21 @@ class ExpertFacts:
         # what the grouped matmul multiplied to serve them: a pass of the
         # model is the slot set (decode) or one row's length bucket; a wave
         # of several rows is counted as if their assignments were sorted
-        # together (each row pays boundary visits of its own: a floor)
+        # together (each row pays boundary visits of its own: a floor); a
+        # decode step or a block program is ONE pass over its slot set
         rows = max(rec["rows_padded"], 1)
-        per_pass = {"decode": rows, "block": rec["tokens_padded"]}.get(
-            rec["kind"], rec["tokens_padded"] // rows)
+        per_pass, passes = {"decode": (rows, 1),
+                            "block": (rec["tokens_padded"], 1)}.get(
+            rec["kind"], (rec["tokens_padded"] // rows, rows))
         # the pass that opens a block is two blocks wide a row
         opening = int(rec["kind"] == "block")
-        totals["moe_tile_rows_total"] += moe_tile_rows(
-            counts[:opening], 2 * per_pass, self.cfg) + moe_tile_rows(
-                counts[opening:], per_pass, self.cfg)
+        for part, tokens in ((counts[:opening], 2 * per_pass),
+                             (counts[opening:], per_pass)):
+            totals["moe_tile_rows_total"] += moe_tile_rows(
+                part, tokens, self.cfg)
+            calls, handed = moe_gmm_calls(part, tokens, self.cfg, passes)
+            totals["moe_gmm_calls_total"] += calls
+            totals["moe_layout_rows_total"] += handed
         return {"moe_assignments": assignments,
                 "moe_experts_touched": touched,
                 "moe_expert_tokens_max": int(counts.max())}

@@ -739,3 +739,273 @@ def test_grouped_matmul_kernel_matches_plain_path_forward_and_backward(
     close(d_rhs, g_plain[1], 3e-6, rtol=1e-5)
     if stacked:                 # the other layer's experts: untouched
         assert not np.asarray(grads[1][0]).any()
+
+
+# ---- the dropless layer's row layout and its cut into calls (PR 49) ----
+
+# the four expert configurations' (E, k, R) at tiny widths
+LAYOUT_CONFIGS = {"mixtral": (8, 2, 8), "mellum": (64, 8, 64),
+                  "sdar": (128, 8, 128), "kimi-share": (12, 8, 384)}
+ROUTINGS = ("uniform", "empty-experts", "one-takes-all", "trailing-group",
+            "padded-tail")
+
+
+def _routed(routing, E, k, R, tokens, seed=0):
+    """[tokens * k] int: the group each assignment joins (E: the trailing
+    one, a share's absent experts and a `token_mask`'s padding)."""
+    rng = np.random.default_rng(seed)
+    if routing == "one-takes-all":
+        chosen = np.full((tokens, k), 3 % E)
+    elif routing == "empty-experts":
+        # every second expert gets nothing, and the last none
+        chosen = 2 * rng.integers(0, max((E - 1) // 2, 1), (tokens, k))
+    else:
+        # k distinct experts of the R routed a token, this share's first
+        chosen = np.stack([rng.permutation(R)[:k] for _ in range(tokens)])
+    expert = np.where(chosen < E, chosen, E).reshape(-1)
+    if routing == "trailing-group":
+        # most assignments to an expert the share does not hold
+        expert = np.where(rng.random(expert.shape) < 0.7, E, expert)
+    if routing == "padded-tail":
+        expert[(tokens * 5 // 8) * k:] = E
+    return expert.astype(np.int32)
+
+
+def _plain_layout(expert, E, tm, aligned, rows):
+    """The layout as the layer computed it before PR 49, in numpy: a
+    scatter-add for the counts, a search a row for its owner, a gather a
+    row, a gather an assignment for its shift."""
+    M = len(expert)
+    order = np.argsort(expert, kind="stable")
+    counts = np.zeros(E + 1, np.int64)
+    np.add.at(counts, expert, 1)
+    counts = counts[:E]
+    row_of = np.argsort(order)
+    if not aligned:
+        return (counts, np.cumsum(counts),
+                order[np.clip(np.arange(rows), 0, M - 1)], row_of)
+    sizes = -(-counts // tm) * tm
+    shift = np.concatenate([[0], np.cumsum(sizes - counts)])
+    ends = np.cumsum(sizes)
+    owner = np.searchsorted(ends, np.arange(rows), side="right")
+    at = order[np.clip(np.arange(rows) - shift[owner], 0, M - 1)]
+    return counts, ends, at, row_of + shift[expert]
+
+
+@pytest.mark.parametrize("aligned", [False, True], ids=["packed", "aligned"])
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("config", list(LAYOUT_CONFIGS))
+def test_layout_arrays_hold_what_a_search_a_row_gave(config, routing,
+                                                     aligned):
+    """`dropless_layout` (counts from a compare, the aligned rows from a
+    table a tile) against the plain formulation it replaced, array by
+    array: the four configurations' experts, top-k and share, every
+    routing a table could get wrong, rows packed and on tile boundaries,
+    in one call's rows and in the rows of a pass cut into blocks."""
+    from ray_tpu.models.llama import dropless_layout
+    from ray_tpu.ops.grouped_matmul import aligned_rows
+
+    E, k, R = LAYOUT_CONFIGS[config]
+    tokens, tm = 48, 128 if E > 8 else 256
+    expert = _routed(routing, E, k, R, tokens)
+    M = tokens * k
+    need = aligned_rows(M, E, tm) if aligned else M
+    # a pass cut into blocks has more rows than its layout needs
+    for rows in (need, -(-need // (3 * tm)) * 3 * tm)[:1 + aligned]:
+        got = jax.jit(dropless_layout, static_argnums=(1, 2, 3, 4))(
+            jnp.asarray(expert), E, tm, aligned, rows)
+        want = _plain_layout(expert, E, tm, aligned, rows)
+        for name, a, b in zip(("counts", "ends", "at", "row_of"), got,
+                              want):
+            np.testing.assert_array_equal(np.asarray(a), b, err_msg=name)
+        counts, ends, at, row_of = (np.asarray(a) for a in got)
+        # every real assignment is read back from a row that holds it
+        real = expert < E
+        assert (at[row_of[real]] == np.arange(M)[real]).all()
+        assert ends[-1] <= rows and counts.sum() == real.sum()
+
+
+def _layer_out(cfg, x, mask, params=None, seed=1):
+    """(output, counts, params) of one expert layer as a serving program
+    runs it: its experts read by index from a stack (of two layers, this
+    one the second)."""
+    from ray_tpu.models.llama import MoEMLP
+
+    layer = MoEMLP(cfg)
+    if params is None:
+        params = nn.meta.unbox(
+            layer.init(jax.random.PRNGKey(seed), x)["params"])
+
+    def stack(w):
+        return jnp.stack([jnp.zeros_like(w), w])
+
+    def apply(p, x):
+        return layer.apply(
+            {"params": p}, x, token_mask=mask, mutable=["routing"],
+            stacked=(stack(p["experts_gate_up"]), stack(p["experts_down"]),
+                     jnp.int32(1)))
+    out, sown = jax.jit(apply)(params, x)
+    return np.asarray(out), np.asarray(jax.tree.leaves(sown)[0]), params
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "megablox_interpret"])
+@pytest.mark.parametrize("case", ["empty-tail", "no-tail", "share"])
+def test_loop_over_blocks_is_one_call(monkeypatch, case, impl):
+    """A pass cut into blocks (`_MOE_ROWS` set so that its rows are 3 or
+    more calls' worth) against the same pass in ONE call: the same output
+    and counts, bit for bit on the plain path. `empty-tail`: two thirds of
+    the tokens are padding, so the last blocks hold no group and are
+    skipped; `no-tail`: every token real, every block visited; `share`: a
+    layer that holds 4 of 16 routed experts, whose rows hold every
+    assignment and whose blocks past the held experts' are skipped."""
+    from ray_tpu.models import llama
+    from ray_tpu.ops import grouped_matmul as gm
+
+    share = case == "share"
+    cfg = get_config(
+        "tiny-moe", dtype=jnp.float32, param_dtype=jnp.float32,
+        moe_intermediate_size=32, num_experts_per_tok=4 if share else 2,
+        n_routed_experts=16 if share else None, expert_first=8)
+    # `no-tail`: 1120 assignments, 257-512 an expert, fill 8 of the 9
+    # tiles the layout has for the worst routing: all 3 blocks of 3
+    tokens, tiles = {"empty-tail": (160, 1), "no-tail": (560, 3),
+                     "share": (512, 2)}[case]
+    x = jnp.asarray(np.random.default_rng(4).normal(
+        size=(1, tokens, cfg.hidden_size)), jnp.float32)
+    real = tokens if case == "no-tail" else tokens // 3
+    mask = jnp.asarray(np.arange(tokens) < real)[None]
+    monkeypatch.setattr(gm, "_impl", lambda: impl)
+    tm, aligned, rows, block = llama.moe_row_layout(tokens, cfg)
+    assert aligned and block == rows
+    whole, counts, params = _layer_out(cfg, x, mask)
+    monkeypatch.setattr(llama, "_MOE_ROWS", tiles * tm)
+    cut = llama.moe_row_layout(tokens, cfg)
+    assert cut[:2] == (tm, True) and cut[2] // cut[3] >= 3, cut
+    calls, handed = llama.moe_gmm_calls(counts, tokens, cfg)
+    assert (calls < cut[2] // cut[3]) == (case != "no-tail")
+    assert handed == calls * cut[3]
+    blocks, counts_cut, _ = _layer_out(cfg, x, mask, params)
+    np.testing.assert_array_equal(counts_cut, counts)
+    assert not blocks[0, real:].any()
+    if impl == "ragged_dot":
+        np.testing.assert_array_equal(blocks, whole)
+    else:
+        np.testing.assert_allclose(blocks, whole, atol=1e-6, rtol=1e-6)
+
+
+def _brute_calls(counts, tokens, cfg):
+    """(calls of one product, rows handed) of ONE pass, row by row: lay the
+    groups out, and count the blocks that hold a row of a group."""
+    from ray_tpu.models.llama import moe_row_layout
+
+    tm, aligned, rows, block = moe_row_layout(tokens, cfg)
+    owners = []
+    for e, c in enumerate(counts):
+        owners += [e] * int(-(-c // tm) * tm if aligned else c)
+    assert len(owners) <= rows
+    calls = 1 if block == rows else len(
+        {p // block for p in range(len(owners))})
+    return calls, calls * block
+
+
+@pytest.mark.parametrize("config", list(LAYOUT_CONFIGS))
+def test_gmm_calls_and_layout_rows_are_a_brute_force_count(monkeypatch,
+                                                           config):
+    """`moe_gmm_calls` (host arithmetic on [steps, L, E] counts) against a
+    walk over every row of every pass's layout, at each configuration's
+    experts, for passes in one call and passes cut into blocks, full and
+    mostly padding."""
+    from ray_tpu.models import llama
+
+    E, k, R = LAYOUT_CONFIGS[config]
+    cfg = get_config("tiny-moe", num_experts=E, num_experts_per_tok=k,
+                     n_routed_experts=R if R != E else None,
+                     moe_intermediate_size=32)
+    rng = np.random.default_rng(11)
+    for block in (llama._MOE_ROWS, 512):
+        monkeypatch.setattr(llama, "_MOE_ROWS", block)
+        for tokens in (4, 64, 512):
+            counts = np.stack([np.bincount(
+                _routed(routing, E, k, R, tokens, seed)[
+                    :int(tokens * k * fill)], minlength=E + 1)[:E]
+                for seed, (routing, fill) in enumerate(
+                    (r, f) for r in ROUTINGS[:4] for f in (1.0, 0.3))])
+            counts = counts.reshape(2, 4, E)            # [steps, L, E]
+            brute = [_brute_calls(c, tokens, cfg)
+                     for c in counts.reshape(-1, E)]
+            assert llama.moe_gmm_calls(counts, tokens, cfg) == (
+                sum(b[0] for b in brute), sum(b[1] for b in brute))
+    blocked = llama.moe_row_layout(512, cfg)
+    assert blocked[3] < blocked[2]          # the loop above cut a pass
+
+
+def test_moe_engine_counts_the_calls_of_a_pass_cut_into_blocks(monkeypatch):
+    """The engine with `_MOE_ROWS` set so that the 128 bucket's pass
+    (768 rows) is three calls' worth: a 100-token prompt through
+    add_request/step() gives the reference's greedy tokens through the
+    loop over blocks, and `stats()`'s `moe_gmm_calls_total` /
+    `moe_layout_rows_total` are a brute-force count over the records (the
+    reference's routing laid out row by row), exported as `rtpu_llm_*`."""
+    from chipbench.references import moe_decoder as ref
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm.engine import SamplingParams
+    from ray_tpu.serve.llm.server import EngineDriverMixin
+    from ray_tpu.util import metrics, tracing
+
+    monkeypatch.setattr(llama, "_MOE_ROWS", 256)
+    engine = _tiny_engine()
+    cfg = engine.model_cfg
+    assert llama.moe_row_layout(128, cfg) == (128, True, 768, 256)
+    k, L, E = cfg.num_experts_per_tok, cfg.num_layers, cfg.num_experts
+    seen = tracing.appended("engine.dispatch")
+    prompt = np.random.default_rng(9).integers(0, 256, 100).tolist()
+    engine.add_request("long", prompt, SamplingParams(max_tokens=4))
+    out = []
+    while engine.has_work():
+        for delta in engine.step():
+            out.extend(delta.new_token_ids)
+    weights = ref.weights_from_program_tree(engine.params)
+    seq = list(prompt)
+    for _ in range(4):
+        logits = np.asarray(ref.forward(weights, jnp.asarray([seq]),
+                                        _published(cfg)))
+        seq.append(int(logits[0, -1].argmax()))
+    assert out == seq[len(prompt):]
+    chosen = np.asarray(ref.routing(weights, jnp.asarray([seq]),
+                                    _published(cfg)))[0]      # [L, S, k]
+    calls = rows = 0
+    fields = tracing.FIELDS["engine.dispatch"]
+    records = [rec for rec in (
+        dict(zip(fields, r))
+        for r in tracing.records("engine.dispatch", seen)) if rec["rows"]]
+    for rec in records:
+        decode = rec["kind"] == "decode"
+        (_, q, ctx), = rec["rows"]
+        for j in range(rec["k"] if decode else 1):
+            at = (slice(ctx - 1 + j, ctx + j) if decode
+                  else slice(ctx - q, ctx))
+            for layer in range(L):
+                c, r = _brute_calls(
+                    np.bincount(chosen[layer, at].ravel(), minlength=E),
+                    rec["rows_padded"] if decode
+                    else rec["tokens_padded"] // rec["rows_padded"], cfg)
+                calls, rows = calls + c, rows + r
+    assert {r["kind"] for r in records} == {"prefill", "decode"}
+    stats = engine.stats()
+    assert stats["moe_gmm_calls_total"] == calls
+    assert stats["moe_layout_rows_total"] == rows
+    # the pass of 100 tokens: 200 assignments on 4 experts in tiles of 128,
+    # so two or three of its three blocks held rows, in each layer
+    prefill_calls = calls - L * sum(
+        r["k"] for r in records if r["kind"] == "decode")
+    assert 2 * L <= prefill_calls <= 3 * L
+    driver = EngineDriverMixin()
+    driver.engine = engine
+    driver._init_driver()
+    before = metrics.snapshot("rtpu_llm_")
+    driver._publish_llm_metrics(stats)
+    after = metrics.snapshot("rtpu_llm_")
+    for key in ("moe_gmm_calls_total", "moe_layout_rows_total"):
+        name = f"rtpu_llm_{key}"
+        assert after[name] - before.get(name, 0) == stats[key], name
+    engine.close()

@@ -816,14 +816,14 @@ PROGRAM_SHAS = {
     "tiny:prefill:(128, 2, 16)": "908416dc83f08d65",
     "tiny:decode:(1, 16)": "ae36d16f69da070c",
     "tiny:verify:(32, 2)": "bbfb32dae0dd51a5",
-    "tiny-moe:prefill:(32, 2, 0)": "74bbdbf02c06f17c",
-    "tiny-moe:prefill:(32, 2, 16)": "03e9b23ef88c6ee1",
-    "tiny-moe:prefill:(64, 2, 0)": "e645fc06b5f1493e",
-    "tiny-moe:prefill:(64, 2, 16)": "3503bbfef472b65e",
-    "tiny-moe:prefill:(128, 2, 0)": "7b6cd19a4e346713",
-    "tiny-moe:prefill:(128, 2, 16)": "339bc0411046ad03",
-    "tiny-moe:decode:(1, 16)": "79d32007067191b6",
-    "tiny-moe:verify:(32, 2)": "166f34b8e25449e5",
+    "tiny-moe:prefill:(32, 2, 0)": "3b2916b2027b983d",
+    "tiny-moe:prefill:(32, 2, 16)": "26ac271c85a363a1",
+    "tiny-moe:prefill:(64, 2, 0)": "bf14e78a07431c8c",
+    "tiny-moe:prefill:(64, 2, 16)": "1efae29a45fd16d1",
+    "tiny-moe:prefill:(128, 2, 0)": "e1726da4eeef3117",
+    "tiny-moe:prefill:(128, 2, 16)": "a8a911a0e6302fca",
+    "tiny-moe:decode:(1, 16)": "1a2a340eb62cc4c1",
+    "tiny-moe:verify:(32, 2)": "0aea2088f65e850a",
     "tiny-jamba:prefill:(32, 2, 0)": "1416eab2013c1e6e",
     "tiny-jamba:prefill:(64, 2, 0)": "91210e061dcc755d",
     "tiny-jamba:prefill:(128, 2, 0)": "743c37d954de7ca1",
