@@ -84,12 +84,17 @@ class KimiConfig(LlamaConfig):
     ctx_chunk_tokens: int = LATENT_CTX_CHUNK
 
     def __post_init__(self):
+        if not 0 <= self.first_k_dense_replace <= self.num_layers:
+            raise ValueError("first_k_dense_replace past the layers")
+        self._check_rotation_and_share()
+
+    def _check_rotation_and_share(self) -> None:
+        """What holds of every config with this attention and this expert
+        layer (models/gigachat.py's too, whose layers are a list)."""
         if self.rope_mscale != self.rope_mscale_all_dim:
             raise NotImplementedError(
                 "rope_scaling with mscale != mscale_all_dim scales cos and "
                 "sin; only the published case (a factor of 1) is built")
-        if not 0 <= self.first_k_dense_replace <= self.num_layers:
-            raise ValueError("first_k_dense_replace past the layers")
         held = (self.expert_first, self.expert_first + self.num_experts)
         if not 0 <= held[0] <= held[1] <= self.routed_experts:
             raise ValueError(f"held experts {held} outside the routed "
@@ -320,9 +325,14 @@ def _dense(cfg, features, axes, name):
 
 
 class MLAttention(nn.Module):
+    """`gated`: each head's output is multiplied, value by value, by
+    sigmoid(W_g,h x) of the mixer's own input before W_o (Qiu et al. 2025,
+    "Gated Attention for LLMs"; models/gigachat.py). False: no gate, and no
+    parameter for one."""
     config: KimiConfig
     ctx_pages: int
     ref_attention: bool
+    gated: bool = False
 
     @nn.compact
     def __call__(self, x, positions, kv_pages, block_tables, total_lens,
@@ -382,8 +392,12 @@ class MLAttention(nn.Module):
                 chunk_tokens=cfg.ctx_chunk_tokens,
                 impl="reference" if self.ref_attention else None,
                 layer=layer)
-        out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(
-            o.reshape(b, s, nh * dv))
+        o = o.reshape(b, s, nh * dv)
+        if self.gated:
+            gate = _dense(cfg, nh * dv, ("embed", "heads"), "gate_proj")(x)
+            o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(cfg.dtype)
+        out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(o)
         return out, kv_pages
 
 

@@ -109,6 +109,9 @@ class LlamaConfig:
     # key j iff j // block_causal <= i // block_causal): a model that
     # generates by diffusion over blocks (models/sdar.py). 0: causal.
     block_causal: int = 0
+    # a clamp on every gated FFN's two halves before their product:
+    # silu(min(gate, limit)) * clip(up, -limit, limit). None: no clamp
+    swiglu_limit: Optional[float] = None
 
     @property
     def head_dim_(self) -> int:
@@ -343,6 +346,15 @@ class Attention(nn.Module):
         return out, new_cache
 
 
+def gated_silu(gate, up, limit: Optional[float] = None):
+    """silu(gate) * up, the halves clamped first where a model has a
+    `swiglu_limit`."""
+    if limit is not None:
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
+    return nn.silu(gate) * up
+
+
 class MLP(nn.Module):
     config: LlamaConfig
 
@@ -356,7 +368,7 @@ class MLP(nn.Module):
             kernel_init=A(nn.initializers.lecun_normal(), ("embed", "mlp")),
             name="gate_up_proj")(x)
         gate, up = jnp.split(gate_up, 2, axis=-1)
-        y = nn.silu(gate) * up
+        y = gated_silu(gate, up, getattr(cfg, "swiglu_limit", None))
         return nn.DenseGeneral(
             features=cfg.hidden_size, use_bias=False, axis=-1,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -522,8 +534,9 @@ class MoEMLP(nn.Module):
             rows_at = jax.lax.dynamic_slice(at, (lo,), (n,))
             gu = grouped_matmul(xt[rows_at // k], w_gu, here, layer, tm)
             gate_p, up_p = jnp.split(gu, 2, axis=-1)
-            return grouped_matmul(nn.silu(gate_p) * up_p, w_dn, here, layer,
-                                  tm)
+            return grouped_matmul(
+                gated_silu(gate_p, up_p, cfg.swiglu_limit), w_dn, here,
+                layer, tm)
 
         # A wave's rows go through the experts `block` at a time: the
         # [block, 2f] intermediate stays small whatever the wave, and a
@@ -580,7 +593,7 @@ class MoEMLP(nn.Module):
         ex_in = jnp.einsum("Gtec,Gth->Gech", dispatch, xg)   # [G,E,C,h]
         gu = jnp.einsum("Gech,ehm->Gecm", ex_in, w_gu.astype(cfg.dtype))
         gate_p, up_p = jnp.split(gu, 2, axis=-1)
-        y = nn.silu(gate_p) * up_p
+        y = gated_silu(gate_p, up_p, cfg.swiglu_limit)
         ex_out = jnp.einsum("Gecf,efh->Gech", y, w_dn.astype(cfg.dtype))
         out = jnp.einsum("Gtec,Gech->Gth", combine, ex_out)
         return out.reshape(G * g, h)[:T]

@@ -1481,8 +1481,11 @@ class LLMEngine:
                         min(len(req.prompt_ids) + req.planned_out - 1
                             + (k_steps - 1), cap - 1))
             required = min(last_pos // page + 1, self.max_pages_per_seq)
-            while (req in self.running and req.state == RUNNING
-                   and len(req.pages) < required):
+            # (the cheap tests first: `in` walks the list of a full slot
+            # set, a dataclass comparison an entry, and a row needs a page
+            # once in `page` steps)
+            while (req.state == RUNNING and len(req.pages) < required
+                   and req in self.running):
                 try:
                     req.pages.extend(
                         self.allocator.allocate(required - len(req.pages)))
@@ -1501,8 +1504,9 @@ class LLMEngine:
                         key=lambda r: (
                             self.allocator.reclaimable_pages(r.pages),
                             r.arrival_t)))
+        running = {r.request_id for r in self.running}
         return [r for r in elig
-                if r in self.running and r.state == RUNNING]
+                if r.state == RUNNING and r.request_id in running]
 
     def _dispatch_decode_chunk(self) -> bool:
         """Launch one fused K-step decode dispatch over the full slot set,

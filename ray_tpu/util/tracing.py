@@ -182,7 +182,10 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         "full_tokens_read", "window_tokens_held", "full_tokens_held",
         "mla_layers", "latent_bytes_token", "mla_ctx_chunks",
         "moe_assignments_routed",
-        "enqueued_ns", "device_start_ns", "device_end_ns", "end_exact"),
+        "enqueued_ns", "device_start_ns", "device_end_ns", "end_exact",
+        # a family that came after the stamps (models/gigachat.py): every
+        # field before these is held to its place by hand-made records
+        "gdn_layers", "gdn_state_bytes_row"),
     # one per LLMEngine.step(); `fetch_blocked`: how many of the step's
     # harvests found their program unfinished (the step waited for the
     # device, not the device for the step), `device_idle_ns`: time the

@@ -968,3 +968,82 @@ def test_mellum_program_fits_and_carries_both_pools_in_place(
     header = text.split("\n", 1)[0]
     aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
     assert len(aliased) >= (3 if kind == "decode" else 2), header[:400]
+
+
+# -------- gated-delta-net layers beside latent attention, three kinds of pool
+GIGA_PAGES, GIGA_LEN, GIGA_SLOTS = 12288, 14400, 96
+
+
+@pytest.mark.parametrize("kind, key", [
+    ("decode", None), ("prefill", (4096, GIGA_LEN // 64))])
+def test_gigachat_program_fits_and_carries_its_three_kind_pool_in_place(
+        topo, no_persistent_cache, kind, key):
+    """GigaChat3.5-432B-A28B at its published widths as the cell
+    `gigachat3.5-reasoning` runs it: published layers 2-6 (a GDN layer with
+    the dense FFN, an MLA layer and three GDN layers with 16 of the 256
+    routed experts beside the shared one), 16,032 rows of the vocabulary, 96
+    slots: ONE layer's latent pages `[1, 12288, 1, 64, 640]` (1.01 GB)
+    beside the four GDN layers' matrices `[4, 96, 64, 128, 128]` float32
+    (1.61 GB) and conv tails `[4, 3, 96, 16384]`. The decode program runs
+    the delta-rule update as a kernel under its own name (`_gdn_update`)
+    beside the latent kernel and the grouped matmul; a resumed `[1 x 4096]`
+    pass behind a 14,400-token table (the fresh pass's program and its
+    context chunks' calls) fits the chip beside 9.46 GB of weights; no part of
+    the pool is copied whole and all three are aliased from argument to
+    result."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    from ray_tpu.serve.llm.stage import init_params
+
+    cfg = EngineConfig(
+        model="gigachat3.5-432b-a28b", dtype="bfloat16", page_size=64,
+        num_pages=64, max_model_len=GIGA_LEN, max_batch=GIGA_SLOTS,
+        prefill_buckets=(512, 1024, 2048, 4096),
+        model_overrides=dict(num_layers=5, kept_layers=(2, 3, 4, 5, 6),
+                             num_experts=16, n_routed_experts=256,
+                             expert_first=0, vocab_size=16032))
+    engine = LLMEngine(cfg, params={})
+    stage = engine.compute
+    stage.params = jax.eval_shape(lambda: init_params(
+        stage.model, jnp.zeros((1, 8), jnp.int32), jax.random.PRNGKey(0)))
+    spec = stage.family.pool_spec(stage.model_cfg, 5, GIGA_PAGES, 64,
+                                  GIGA_SLOTS)
+    assert spec["latent_pages"][0] == (1, GIGA_PAGES, 1, 64, 640)
+    assert spec["gdn_state"][0] == (4, GIGA_SLOTS, 64, 128, 128)
+    assert spec["gdn_conv"][0] == (4, 3, GIGA_SLOTS, 16384)
+    stage.kv_pages = {k: jax.ShapeDtypeStruct(*sd) for k, sd in spec.items()}
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(a):
+        a = a if hasattr(a, "shape") else np.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    key = (engine._decode_shape_key() if kind == "decode"
+           else (key[0], engine._wave_rb, key[1]))
+    with pytest.MonkeyPatch.context() as mp_ctx:
+        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = stage.program(kind, key).lower(*jax.tree.map(
+            sds, (*stage._state(kind), *stage.dummy_args(kind, key)),
+            is_leaf=lambda a: isinstance(a, jax.ShapeDtypeStruct))
+        ).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(kind, key, "GiB", total / 2 ** 30, "temp",
+          mem.temp_size_in_bytes / 2 ** 30)
+    # 9.46 GB of weights + 1.01 GB of latents + 1.65 GB of state and tails
+    assert 11.2 * 2 ** 30 <= total <= HBM_GIB * 2 ** 30, total / 2 ** 30
+    kernels = set(re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
+    names = sorted({k.split(".")[0] for k in kernels})
+    if kind == "decode":
+        assert names == ["_gdn_update", "_mla_decode", "_moe_gmm"], kernels
+    else:
+        assert names == ["_mla_flash", "_moe_gmm"], kernels
+    whole = {sd[0] for sd in spec.values()}
+    copied = [line.strip()[:160] for line in text.splitlines()
+              if (m := re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) copy\(",
+                                line))
+              and any(dims in whole for dims, _ in _array_types(m.group(1)))]
+    assert not copied, "\n".join(copied)
+    header = text.split("\n", 1)[0]
+    aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliased) >= (4 if kind == "decode" else 3), header[:400]

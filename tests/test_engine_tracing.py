@@ -851,14 +851,16 @@ FAMILY_FIELDS = {
     "tiny-sdar": (_MOE, {"block": {"block_passes", "block_tokens_fixed",
                                    "block_len"}}),
     "tiny-kimi": (_MOE | _MLA, {"prefill": {"mla_ctx_chunks"}}),
+    "tiny-gigachat": (_MOE | _MLA | {"gdn_layers", "gdn_state_bytes_row"},
+                      {"prefill": {"mla_ctx_chunks"}}),
 }
 
 
 @pytest.mark.parametrize("preset", sorted(FAMILY_FIELDS))
 def test_a_familys_records_hold_its_fields_and_no_other_familys(preset):
     """Every `engine.dispatch` record of every family is `FIELDS` long;
-    between the engine's own fields and the stamps a family's fields are
-    set in its records and every other family's are None; and every
+    between the engine's own fields and the stamps (and behind them) a
+    family's fields are set in its records and every other family's are None; and every
     counter and pool size of `stats()` is published with a help string."""
     from ray_tpu.serve.llm.server import EngineDriverMixin
     from ray_tpu.util import metrics
@@ -877,7 +879,8 @@ def test_a_familys_records_hold_its_fields_and_no_other_familys(preset):
     _run(engine)
     fields = tracing.FIELDS["engine.dispatch"]
     family = set(fields[fields.index("moe_assignments"):
-                        fields.index("enqueued_ns")])
+                        fields.index("enqueued_ns")]
+                 + fields[fields.index("end_exact") + 1:])
     every, by_kind = FAMILY_FIELDS[preset]
     kinds = set()
     for rec in tracing.records("engine.dispatch"):
