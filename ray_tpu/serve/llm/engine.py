@@ -143,8 +143,9 @@ _INTAKE, _ADMIT, _DISPATCH_PREFILL, _DISPATCH_DECODE, _FETCH, _HARVEST = \
 class SamplingParams:
     max_tokens: int = 64
     temperature: float = 0.0  # 0 => greedy
-    top_k: int = 0            # 0 => full vocab; bounded by 64 (on-device
-                              # top_k sampler width)
+    top_k: int = 0            # 0 => full vocab; bounded by 64 (stage.py:
+                              # _MAX_TOP_K, the width of the on-device
+                              # sampler's top_k; add_request refuses more)
     stop_token_ids: tuple = ()
     seed: Optional[int] = None  # None => engine-level RNG
     # disaggregation: stop after the first token and stash the request's
@@ -589,7 +590,8 @@ class LLMEngine:
             "prefill_resumed_passes_total", "prefill_split_prompts_total",
             "prefill_attn_blocks_total",
             "prefill_attn_blocks_skipped_total",
-            "prefill_attn_blocks_masked_total"), 0)
+            "prefill_attn_blocks_masked_total",
+            "drawn_dispatches_total"), 0)
         cfg_m = self.model_cfg
         family = model_family(self.config.model)
         # a model that generates by diffusion over blocks: its block
@@ -1301,7 +1303,7 @@ class LLMEngine:
             self._enqueue(
                 "prefill", tokens, r.start_ns, computed, computed * sb,
                 facts, self._family_fields("prefill", facts, passes, cp),
-                group=rows)
+                temp=temp, group=rows)
         self._phase_ns[_DISPATCH_PREFILL] += r.ns
 
     def _family_fields(self, site: str, *what) -> Dict[str, Any]:
@@ -1316,7 +1318,8 @@ class LLMEngine:
 
     def _enqueue(self, kind: str, toks, dispatch_ns: int, rows_padded: int,
                  tokens_padded: int, facts: List[tuple],
-                 fields: Dict[str, Any], k: int = 1, **harvest_keys) -> None:
+                 fields: Dict[str, Any], k: int = 1,
+                 temp: Optional[np.ndarray] = None, **harvest_keys) -> None:
         """Queue one enqueued program for harvest. The dict is also its
         `engine.dispatch` flight record in the making, by the record's
         field names: `facts` (its `rows`) is one (request_id, q_tokens,
@@ -1324,10 +1327,16 @@ class LLMEngine:
         row computes and the tokens of KV it attends to, cached prefix
         included (for a k-step decode row: at its first step) —
         `rows_padded`/`tokens_padded` are what the program computes,
-        `fields` the family's. _harvest adds the fetch's timestamps."""
+        `fields` the family's, `temp` its operand of that name (None: a
+        program with no sampler): the record's `drawn` is whether it has a
+        row above 0, the branch the program's sampler takes (stage.py:
+        _device_sample). _harvest adds the fetch's timestamps."""
         self._dispatch_seq += 1
+        drawn = None if temp is None else bool((temp > 0).any())
+        if kind in ("prefill", "decode"):
+            self._totals["drawn_dispatches_total"] += bool(drawn)
         self._inflight.append({
-            **self._rec_constant, **fields,
+            **self._rec_constant, **fields, "drawn": drawn,
             "kind": kind, "toks": toks, "k": k, "seq": self._dispatch_seq,
             "step_dispatched": self._step_seq, "dispatch_ns": dispatch_ns,
             "enqueued_ns": tracing.now_ns(),
@@ -1565,7 +1574,7 @@ class LLMEngine:
                                     positions, override_mask,
                                     override_ids, temp, topk, keys_steps)
         self._enqueue_decode("decode", toks, dispatch_ns, k_steps,
-                             S * k_steps, facts, chunk_slots)
+                             S * k_steps, facts, chunk_slots, temp)
         return True
 
     def _dispatch_block(self) -> bool:
@@ -1630,12 +1639,13 @@ class LLMEngine:
             toks = self._compute_block(key, bt, total, ids, masked, pending,
                                        temp, topk, keys_steps)
             self._enqueue_decode("block", toks, r.start_ns, steps, S * B,
-                                 facts, block_slots)
+                                 facts, block_slots, temp)
         return True
 
     def _enqueue_decode(self, kind: str, toks, dispatch_ns: int,
                         k_steps: int, tokens_padded: int,
-                        facts: List[tuple], slots: dict) -> None:
+                        facts: List[tuple], slots: dict,
+                        temp: np.ndarray) -> None:
         """_enqueue for a program over the full slot set (a decode chunk
         with the stats() totals it moves, a block program)."""
         if kind == "decode":
@@ -1646,7 +1656,7 @@ class LLMEngine:
         self._enqueue(
             kind, toks, dispatch_ns, self.config.max_batch, tokens_padded,
             facts, self._family_fields("decode", facts, k_steps),
-            k=k_steps, slots=slots)
+            k=k_steps, temp=temp, slots=slots)
 
     # ---------------------------------------------------------- harvest
 

@@ -83,6 +83,11 @@ _LLM_WORK_TOTALS = {
     "steps_total": "engine.step() calls",
     "prefill_dispatches_total": "prefill programs enqueued",
     "decode_dispatches_total": "decode programs enqueued",
+    "drawn_dispatches_total":
+        "of prefill_dispatches_total + decode_dispatches_total, the "
+        "programs whose batch had a row at a temperature above 0: their "
+        "sampler drew (scaling, top-k cut and categorical) where a greedy "
+        "batch's is an argmax",
     "prefill_tokens_total": "prompt tokens prefilled (real rows)",
     "prefill_padded_tokens_total":
         "token positions the prefill programs computed (rows x bucket)",

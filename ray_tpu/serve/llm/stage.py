@@ -12,6 +12,15 @@ embeds token ids, a later one takes the previous stage's hidden states in
 their place; a last stage samples on the device, an earlier one hands its
 hidden states on.
 
+The sampling tail of `run_prefill` and `run_decode` (`_device_sample`) is
+two branches under one `cond`: a batch whose `temp` operand has no row
+above 0 pays for an argmax; one with a drawing row pays for the scaling,
+the cut to the top-k and the categorical draw, row by row what it always
+got. The predicate is the program's own, made from the operands it already
+takes: no second program a shape, no option. (`run_verify` is an argmax;
+the block program's sampler, models/sdar.py: `_sample`, has its own
+`cond`.)
+
 The operands of a program after its state (params, pool[, carry]) are
 `OPERANDS[kind]`, in order; a pipelined frame (pp.py) is those names in
 a dict. "x" is the stage's input: ids or hidden states.
@@ -62,22 +71,38 @@ _MAX_TOP_K = 64
 
 
 def _device_sample(rows, temperature, top_k, rng_keys):
-    """Batched in-jit sampler: greedy when temperature == 0, else
-    temperature + (clamped) top-k categorical. rows: [B, V]."""
+    """Batched in-jit sampler. rows: [B, V] float32 -> [B] int32. Two
+    bodies under one `cond` on the program's own `temp` operand, does any
+    row of the batch draw:
+
+    - greedy: a batch with no drawing row is an argmax and nothing else;
+    - drawn: a row at temperature 0 gets the argmax, any other a
+      categorical draw from rows / temperature, cut to its (clamped)
+      top-k, with its own key.
+
+    A row's token is the same in whichever branch its batch lands it: the
+    argmax of the same float32 row, or a draw from the same scaled row,
+    the same cut and the same key."""
     import jax
     import jax.numpy as jnp
 
-    b = rows.shape[0]
-    greedy = jnp.argmax(rows, axis=-1)
-    scaled = rows / jnp.maximum(temperature, 1e-6)[:, None]
-    topv, _ = jax.lax.top_k(scaled, min(_MAX_TOP_K, rows.shape[-1]))
-    k_idx = jnp.clip(top_k - 1, 0, topv.shape[-1] - 1)
-    kth = topv[jnp.arange(b), k_idx]
-    masked = jnp.where((top_k[:, None] > 0) & (scaled < kth[:, None]),
-                       -jnp.inf, scaled)
-    sampled = jax.vmap(
-        lambda key, lg: jax.random.categorical(key, lg))(rng_keys, masked)
-    return jnp.where(temperature <= 0, greedy, sampled).astype(jnp.int32)
+    def greedy(_):
+        return jnp.argmax(rows, axis=-1).astype(jnp.int32)
+
+    def drawn(_):
+        b = rows.shape[0]
+        scaled = rows / jnp.maximum(temperature, 1e-6)[:, None]
+        topv, _ = jax.lax.top_k(scaled, min(_MAX_TOP_K, rows.shape[-1]))
+        k_idx = jnp.clip(top_k - 1, 0, topv.shape[-1] - 1)
+        kth = topv[jnp.arange(b), k_idx]
+        masked = jnp.where((top_k[:, None] > 0) & (scaled < kth[:, None]),
+                           -jnp.inf, scaled)
+        sampled = jax.vmap(
+            lambda key, lg: jax.random.categorical(key, lg))(rng_keys, masked)
+        return jnp.where(temperature <= 0, greedy(None),
+                         sampled).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(temperature > 0), drawn, greedy, None)
 
 
 def serve_dtype(config):

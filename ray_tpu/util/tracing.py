@@ -185,7 +185,11 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         "enqueued_ns", "device_start_ns", "device_end_ns", "end_exact",
         # a family that came after the stamps (models/gigachat.py): every
         # field before these is held to its place by hand-made records
-        "gdn_layers", "gdn_state_bytes_row"),
+        "gdn_layers", "gdn_state_bytes_row",
+        # the engine's own, LAST for the same reason: whether the program's
+        # `temp` operand had a row above 0, which is the branch its sampler
+        # took (serve/llm/stage.py: _device_sample); None: no sampler
+        "drawn"),
     # one per LLMEngine.step(); `fetch_blocked`: how many of the step's
     # harvests found their program unfinished (the step waited for the
     # device, not the device for the step), `device_idle_ns`: time the

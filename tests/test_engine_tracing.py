@@ -301,6 +301,46 @@ def test_step_phases_fit_inside_the_step(engine):
     assert steps[-1]["running"] == 0 and steps[-1]["waiting"] == 0
 
 
+def test_flightrecords_reads_a_runs_tail_from_its_records(
+        engine, tmp_path, capsys):
+    """benchmarks/flightrecords.py: `dump` keeps the rings beside the
+    benchmark's request records, `read` names the request behind the
+    `ttft_ms` tail with its parts and the programs by shape."""
+    from benchmarks import flightrecords
+
+    prompts = _prompts(3, seed=9)
+    for i, p in enumerate(prompts):
+        engine.add_request(f"f{i}", p, SamplingParams(max_tokens=4))
+    _run(engine)
+    recs = {r["request_id"]: r for r in _dicts("engine.request")}
+    t0 = min(r["arrival_ns"] for r in recs.values())
+    rel = lambda ns: (ns - t0) / 1e9  # noqa: E731
+    requests = [{"rid": rid, "due_s": rel(r["arrival_ns"]), "counted": True,
+                 "prompt_tokens": r["prompt_tokens"],
+                 "sent_s": rel(r["arrival_ns"]),
+                 "first_s": rel(r["first_token_ns"])}
+                for rid, r in recs.items()]
+    requests.append({"rid": "never", "due_s": 0.0, "counted": True,
+                     "prompt_tokens": 5, "sent_s": None, "first_s": None})
+    path = tmp_path / "run.json"
+    path.write_text(json.dumps(flightrecords.dump(requests, t0, 3600.0)))
+    flightrecords.read(str(path))
+    out = capsys.readouterr().out
+    slowest = max(recs, key=lambda rid: recs[rid]["first_token_ns"]
+                  - recs[rid]["arrival_ns"])
+    lines = out.splitlines()
+    assert "3 counted" in lines[0]
+    parts = recs[slowest]
+    assert lines[1].split()[0] == slowest
+    assert f"exact {parts['parts_exact']} due" in lines[1]
+    assert f"prefill {parts['prefill_device_ns'] / 1e6:5.1f}" in lines[1]
+    assert f"program ('decode', {ENGINE_CFG['max_batch']}, " in out
+    assert sum("program ('prefill', " in ln for ln in lines) == len(
+        {(d["rows_padded"], d["tokens_padded"])
+         for d in _dicts("engine.dispatch") if d["kind"] == "prefill"})
+    assert "thread gaps in the window (s, ms): []" in out
+
+
 def test_preemption_is_counted_and_gets_a_new_dispatch_time():
     cfg = dict(ENGINE_CFG, num_pages=12, max_model_len=64, max_batch=2,
                prefill_buckets=(16, 32, 64))
@@ -880,7 +920,8 @@ def test_a_familys_records_hold_its_fields_and_no_other_familys(preset):
     fields = tracing.FIELDS["engine.dispatch"]
     family = set(fields[fields.index("moe_assignments"):
                         fields.index("enqueued_ns")]
-                 + fields[fields.index("end_exact") + 1:])
+                 + fields[fields.index("end_exact") + 1:
+                          fields.index("drawn")])
     every, by_kind = FAMILY_FIELDS[preset]
     kinds = set()
     for rec in tracing.records("engine.dispatch"):

@@ -333,9 +333,10 @@ def test_the_engine_emits_the_references_tokens_and_its_records_say_how(
     fields = tracing.FIELDS["engine.dispatch"]
     recs = [dict(zip(fields, r)) for r in tracing.records("engine.dispatch")]
     cfg = engine.model_cfg
-    # the family's two come LAST, behind the stamps: hand-made records of
-    # four families' tests hold every earlier field to its place
-    assert fields[-2:] == ("gdn_layers", "gdn_state_bytes_row")
+    # the family's two come behind the stamps (only the engine's `drawn`
+    # behind them): hand-made records of four families' tests hold every
+    # earlier field to its place
+    assert fields[-3:] == ("gdn_layers", "gdn_state_bytes_row", "drawn")
     row_bytes = 5 * (4 * 16 * 16 * 4 + 3 * 128 * 4)      # float32 here
     for r in recs:
         assert len(r) == len(fields)
@@ -441,18 +442,20 @@ def test_a_slice_of_the_stack_is_refused():
 # serving programs of the two families whose modules this one imports from
 # and edited (an output gate that defaults to none, a clamp that defaults to
 # none), float32, CPU. (Mistral's, Mixtral's, Jamba's and MiniCPM-SALA's are
-# held by tests/test_sdar.py, SDAR's by tests/test_kimi.py.)
+# held by tests/test_sdar.py, SDAR's by tests/test_kimi.py.) (Read again on
+# PR 51's tree, which put every prefill and decode program's sampler behind
+# a `cond`.)
 PROGRAM_SHAS = {
-    "tiny-kimi:prefill:(32, 2, 0)": "b3dbd23c4e7c2143",
-    "tiny-kimi:prefill:(32, 2, 16)": "1b78b864982470ad",
-    "tiny-kimi:prefill:(64, 2, 0)": "db5d23b3a0bbbaee",
-    "tiny-kimi:prefill:(64, 2, 16)": "5464618406de9d82",
-    "tiny-kimi:decode:(1, 16)": "0416ebc85b943b71",
-    "tiny-mellum:prefill:(32, 2, 0)": "65b2df0bb99feca8",
-    "tiny-mellum:prefill:(32, 2, 16)": "f9e0d1a6e63ea366",
-    "tiny-mellum:prefill:(64, 2, 0)": "5c195b5ece4ee43c",
-    "tiny-mellum:prefill:(64, 2, 16)": "68834fa5f0e67e4a",
-    "tiny-mellum:decode:(1, 16)": "223426bb3c16b42e",
+    "tiny-kimi:prefill:(32, 2, 0)": "294666d94664b830",
+    "tiny-kimi:prefill:(32, 2, 16)": "6354526a6b850643",
+    "tiny-kimi:prefill:(64, 2, 0)": "f7bffcbcf1c7c152",
+    "tiny-kimi:prefill:(64, 2, 16)": "be045149bdeab1b4",
+    "tiny-kimi:decode:(1, 16)": "508614620678e52b",
+    "tiny-mellum:prefill:(32, 2, 0)": "d1c20523784a7a52",
+    "tiny-mellum:prefill:(32, 2, 16)": "658bc43c2bb9b85e",
+    "tiny-mellum:prefill:(64, 2, 0)": "71fe110de3ffcb23",
+    "tiny-mellum:prefill:(64, 2, 16)": "ab342b3fd88253fb",
+    "tiny-mellum:decode:(1, 16)": "7c6744cb80460bde",
 }
 
 

@@ -188,6 +188,221 @@ def test_temperature_sampling_and_stop_tokens():
     assert len(out["t"]["ids"]) == 50
 
 
+# ------------------------------------------------- the sampler's branches
+
+def _one_branch_sample(rows, temperature, top_k, rng_keys):
+    """`stage._device_sample` as it stood before it branched (PR 50's
+    tree): every row pays the scaling, the top-k over the vocabulary, the
+    mask and the draw, and a `where` picks at the end. Kept HERE, for the
+    two-branch sampler to be held to."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.serve.llm.stage import _MAX_TOP_K
+
+    b = rows.shape[0]
+    greedy = jnp.argmax(rows, axis=-1)
+    scaled = rows / jnp.maximum(temperature, 1e-6)[:, None]
+    topv, _ = jax.lax.top_k(scaled, min(_MAX_TOP_K, rows.shape[-1]))
+    k_idx = jnp.clip(top_k - 1, 0, topv.shape[-1] - 1)
+    kth = topv[jnp.arange(b), k_idx]
+    masked = jnp.where((top_k[:, None] > 0) & (scaled < kth[:, None]),
+                       -jnp.inf, scaled)
+    sampled = jax.vmap(
+        lambda key, lg: jax.random.categorical(key, lg))(rng_keys, masked)
+    return jnp.where(temperature <= 0, greedy, sampled).astype(jnp.int32)
+
+
+_SAMPLER_ROWS, _SAMPLER_V = 8, 160
+# (temperature a row, top_k a row)
+_SAMPLER_BATCHES = {
+    "all_greedy": ([0.0] * 8, [0, 5, 64, 0, 1, 0, 0, 7]),
+    "all_drawing_no_top_k": ([0.7, 1.0, 1.3, 0.2, 2.0, 1.0, 0.9, 1.1],
+                             [0] * 8),
+    "all_drawing_top_k_1": ([0.7, 1.0, 1.3, 0.2, 2.0, 1.0, 0.9, 1.1],
+                            [1] * 8),
+    "all_drawing_top_k_5": ([0.7, 1.0, 1.3, 0.2, 2.0, 1.0, 0.9, 1.1],
+                            [5] * 8),
+    "all_drawing_top_k_64": ([0.7, 1.0, 1.3, 0.2, 2.0, 1.0, 0.9, 1.1],
+                             [64] * 8),
+    # greedy rows with and without a top_k beside drawing rows with each
+    "mixed": ([0.0, 1.0, 0.0, 0.8, 1.5, 0.0, 1.0, 0.0],
+              [0, 0, 5, 5, 64, 64, 1, 0]),
+    "mixed_top_k_on_greedy_rows_only": (
+        [0.0, 1.0, 0.0, 1.2, 0.0, 0.0, 0.0, 0.0], [5, 0, 64, 0, 1, 0, 0, 0]),
+}
+
+
+def _sampler_operands(name, seed=0):
+    import jax.numpy as jnp
+
+    temperature, top_k = _SAMPLER_BATCHES[name]
+    rng = np.random.default_rng(seed)
+    rows = rng.normal(0, 3, (_SAMPLER_ROWS, _SAMPLER_V)).astype(np.float32)
+    keys = rng.integers(0, 2 ** 32, (_SAMPLER_ROWS, 2), dtype=np.uint32)
+    return (jnp.asarray(rows), jnp.asarray(temperature, jnp.float32),
+            jnp.asarray(top_k, jnp.int32), jnp.asarray(keys))
+
+
+@pytest.mark.parametrize("batch", sorted(_SAMPLER_BATCHES))
+def test_device_sample_gives_every_row_the_one_branch_samplers_token(batch):
+    import jax
+
+    from ray_tpu.serve.llm.stage import _device_sample
+
+    new, old = jax.jit(_device_sample), jax.jit(_one_branch_sample)
+    drew = set()
+    for seed in range(4):
+        operands = _sampler_operands(batch, seed)
+        got, want = np.asarray(new(*operands)), np.asarray(old(*operands))
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(got, want)
+        greedy = np.asarray(operands[0]).argmax(-1)
+        cold = np.asarray(operands[1]) <= 0
+        np.testing.assert_array_equal(got[cold], greedy[cold])
+        drew |= set(np.flatnonzero(got != greedy))
+    # (a drawing row that always returned the argmax would prove nothing)
+    if "drawing" in batch and "top_k_1" not in batch:
+        assert len(drew) >= 4, drew
+    elif batch == "all_greedy":
+        assert not drew
+
+
+def _hlo_computations(text):
+    """An HLO module's text -> ({computation: its lines}, the entry's
+    name)."""
+    import re
+
+    comps, entry, name = {}, None, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if m:
+            name = m.group(2)
+            comps[name] = []
+            entry = name if m.group(1) else entry
+        elif name is not None:
+            comps[name].append(line)
+    return comps, entry
+
+
+def _hlo_opcodes(comps, name, seen=None):
+    """The opcodes of a computation and of all it calls."""
+    import re
+
+    seen = set() if seen is None else seen
+    seen.add(name)
+    ops = set()
+    for line in comps[name]:
+        m = re.search(r" = .*?\b([a-z][\w\-]*)\(", line)
+        if m:
+            ops.add(m.group(1))
+        for callee in re.findall(r"%?([\w.\-]+)", line.partition("), ")[2]):
+            if callee in comps and callee not in seen:
+                ops |= _hlo_opcodes(comps, callee, seen)
+    return ops
+
+
+def test_device_samples_top_k_sits_inside_a_branch():
+    """The program's text. As traced: one `case` (does any row draw),
+    the top-k and the divide inside it. As compiled: the entry computation
+    is a conditional's operands and nothing heavier, and the branch a
+    greedy batch takes is an argmax (a reduce) with no top-k, divide or
+    loop of random bits under it."""
+    import re
+
+    import jax
+
+    from ray_tpu.serve.llm.stage import _device_sample
+
+    lowered = jax.jit(_device_sample).lower(*_sampler_operands("mixed"))
+    text = lowered.as_text()
+    assert text.count("chlo.top_k") == 1
+    before_case = text[:text.index("stablehlo.case")]
+    assert "chlo.top_k" not in before_case
+    assert "stablehlo.divide" not in before_case
+
+    comps, entry = _hlo_computations(lowered.compile().as_text())
+    heavy = {"custom-call", "divide", "while", "sort", "rng-bit-generator"}
+    assert heavy & _hlo_opcodes(comps, entry)       # (the check can see it)
+    own = _hlo_opcodes(comps, entry, set(comps) - {entry})
+    assert "conditional" in own and not heavy & own, own
+    call = next(l for l in comps[entry] if " conditional(" in l)
+    # branch 0 is the predicate's False: no row draws
+    greedy, drawn = re.search(
+        r"branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}",
+        call).groups()
+    assert "reduce" in _hlo_opcodes(comps, greedy)
+    assert not heavy & _hlo_opcodes(comps, greedy)
+    assert "divide" in _hlo_opcodes(comps, drawn)
+
+
+def _engine_tokens(requests):
+    engine = LLMEngine(EngineConfig(**ENGINE_CFG))
+    for rid, prompt, sampling in requests:
+        engine.add_request(rid, prompt, sampling)
+    out = _collect(engine, [r[0] for r in requests])
+    stats = engine.stats()
+    engine.close()
+    return {rid: rec["ids"] for rid, rec in out.items()}, stats
+
+
+def _dispatch_records():
+    from ray_tpu.util import tracing
+
+    fields = tracing.FIELDS["engine.dispatch"]
+    assert fields[-1] == "drawn"
+    return [dict(zip(fields, r)) for r in tracing.records("engine.dispatch")]
+
+
+_GREEDY_REQ = ("g", [1, 2, 3, 4, 5, 6, 7], SamplingParams(max_tokens=24))
+_DRAWING_REQ = ("d", [9, 8, 7, 6, 5], SamplingParams(
+    max_tokens=24, temperature=1.0, top_k=20, seed=11))
+
+
+def test_a_rows_tokens_do_not_depend_on_what_its_batch_draws():
+    """`_sampling_arrays`' promise, across the sampler's branches: a
+    greedy request's program takes the greedy branch alone and the drawn
+    one beside a drawing request, and returns the same tokens; the drawing
+    request's tokens are its own alone and in the batch."""
+    alone_g, _ = _engine_tokens([_GREEDY_REQ])
+    alone_d, _ = _engine_tokens([_DRAWING_REQ])
+    both, _ = _engine_tokens([_GREEDY_REQ, _DRAWING_REQ])
+    assert len(alone_g["g"]) == len(alone_d["d"]) == 24
+    assert both["g"] == alone_g["g"]
+    assert both["d"] == alone_d["d"]
+    # the draw is a draw: another seed, other tokens; and not the argmax's
+    other, _ = _engine_tokens([("d", _DRAWING_REQ[1], SamplingParams(
+        max_tokens=24, temperature=1.0, top_k=20, seed=12))])
+    assert other["d"] != alone_d["d"]
+    greedy_d, _ = _engine_tokens([("d", _DRAWING_REQ[1],
+                                   SamplingParams(max_tokens=24))])
+    assert greedy_d["d"] != alone_d["d"]
+
+
+def test_drawn_dispatches_are_counted_and_recorded_by_name():
+    from ray_tpu.util import tracing
+
+    tracing.reset_ring()
+    _, stats = _engine_tokens([_GREEDY_REQ])
+    assert stats["drawn_dispatches_total"] == 0
+    assert stats["prefill_dispatches_total"] >= 1
+    assert stats["decode_dispatches_total"] >= 1
+    recs = _dispatch_records()
+    assert recs and all(r["drawn"] is False for r in recs)
+
+    tracing.reset_ring()
+    _, stats = _engine_tokens([_GREEDY_REQ, _DRAWING_REQ])
+    recs = _dispatch_records()
+    assert {r["kind"] for r in recs} == {"prefill", "decode"}
+    # the record says what the program's `temp` operand held: a row of the
+    # drawing request among its rows
+    for r in recs:
+        assert r["drawn"] is ("d" in {row[0] for row in r["rows"]}), r
+    n_drawn = sum(r["drawn"] for r in recs)
+    assert 0 < n_drawn == stats["drawn_dispatches_total"] <= (
+        stats["prefill_dispatches_total"] + stats["decode_dispatches_total"])
+
+
 def test_byte_tokenizer_roundtrip():
     tok = ByteTokenizer()
     ids = tok.encode("hello, TPU!")
