@@ -67,6 +67,7 @@ from flax import struct
 
 from ..ops.gated_delta import CHUNK, gdn_prefill, gdn_update
 from ..ops.selective_scan import live_slots
+from ..util import tracing
 from .kimi import KimiConfig, LatentFacts, MLAttention, _dense
 from .llama import MLP, A, ExpertFacts, MoEMLP
 
@@ -456,13 +457,17 @@ class GatedDeltaNet(nn.Module):
             rows = []
             for i in range(b):
                 slot = i if slots is None else slots[i]
-                held = jax.lax.dynamic_slice(
-                    state, (layer, slot, 0, 0, 0), (1, 1, nv, d, d))[0, 0]
-                # a tap's row at a time: one slice of all taps makes XLA
-                # copy the pool into a layout with the taps second-minor
-                tail = jnp.stack([jax.lax.dynamic_slice(
-                    conv, (layer, j, slot, 0), (1, 1, 1, channels))[0, 0, 0]
-                    for j in range(taps - 1)])
+                with tracing.scope("rtpu.attn.cache_write"):
+                    held = jax.lax.dynamic_slice(
+                        state, (layer, slot, 0, 0, 0),
+                        (1, 1, nv, d, d))[0, 0]
+                    # a tap's row at a time: one slice of all taps makes
+                    # XLA copy the pool into a layout with the taps
+                    # second-minor
+                    tail = jnp.stack([jax.lax.dynamic_slice(
+                        conv, (layer, j, slot, 0),
+                        (1, 1, 1, channels))[0, 0, 0]
+                        for j in range(taps - 1)])
                 fresh = start[i] == 0
                 oi, s_last, t_last = gdn_prefill(
                     qkv[i], g[i], beta[i], conv_w,
@@ -472,14 +477,15 @@ class GatedDeltaNet(nn.Module):
                 # a row with no real token (a masked warm-up pass) keeps
                 # what its slot held
                 keep = n_real[i] > 0
-                state = jax.lax.dynamic_update_slice(
-                    state, jnp.where(keep, s_last, held)[None, None],
-                    (layer, slot, 0, 0, 0))
-                t_last = jnp.where(keep, t_last, tail)
-                for j in range(taps - 1):
-                    conv = jax.lax.dynamic_update_slice(
-                        conv, t_last[j][None, None, None],
-                        (layer, j, slot, 0))
+                with tracing.scope("rtpu.attn.cache_write"):
+                    state = jax.lax.dynamic_update_slice(
+                        state, jnp.where(keep, s_last, held)[None, None],
+                        (layer, slot, 0, 0, 0))
+                    t_last = jnp.where(keep, t_last, tail)
+                    for j in range(taps - 1):
+                        conv = jax.lax.dynamic_update_slice(
+                            conv, t_last[j][None, None, None],
+                            (layer, j, slot, 0))
                 rows.append(oi)
             o = jnp.stack(rows)
         w_o = self.param("o_norm", A(nn.initializers.zeros, (None,)), (d,),
@@ -594,14 +600,16 @@ class GigaChatModel(nn.Module):
             at[mixer] += n
         x, pages, state, conv = carry
 
-        x = Norm(cfg, name="final_norm")(x)
+        with tracing.scope("rtpu.head"):
+            x = Norm(cfg, name="final_norm")(x)
         # a plain leaf, not a Dense: the head runs under `lax.cond` below
         head_w = self.param(
             "lm_head", A(nn.initializers.lecun_normal(), ("embed", "vocab")),
             (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
 
         def head(a):
-            return jnp.dot(a, head_w.astype(cfg.dtype))
+            with tracing.scope("rtpu.head"):
+                return jnp.dot(a, head_w.astype(cfg.dtype))
 
         if cache.gather is None:
             logits = head(x)

@@ -61,6 +61,7 @@ from flax import struct
 from ..ops.selective_scan import _impl as _scan_impl
 from ..ops.selective_scan import (live_slots, selective_scan,
                                   selective_update, state_shape)
+from ..util import tracing
 from .llama import MLP, A, Attention, PagedCache, RMSNorm
 from .llama import serving_cache as _paged_cache
 
@@ -389,9 +390,10 @@ class MambaMixer(nn.Module):
                 x[:, 0], delta[:, 0], a_neg, bm[:, 0], cm[:, 0], d_skip,
                 ssm_h, layer, live, z[:, 0], order=state[3])
             y = y[:, None]
-            tail_new = jnp.where(live[None, :, None], window[1:], tail)
-            new_state = (ssm_h, jax.lax.dynamic_update_index_in_dim(
-                ssm_conv, tail_new, layer, 0))
+            with tracing.scope("rtpu.attn.cache_write"):
+                tail_new = jnp.where(live[None, :, None], window[1:], tail)
+                new_state = (ssm_h, jax.lax.dynamic_update_index_in_dim(
+                    ssm_conv, tail_new, layer, 0))
         else:
             n_real = (jnp.full((b,), s, jnp.int32) if mask is None
                       else mask.sum(-1).astype(jnp.int32))
@@ -410,22 +412,26 @@ class MambaMixer(nn.Module):
                 y, h_last = jax.lax.map(one_row, rows)
             if state is not None:
                 ssm_h, ssm_conv, layer, slots = state
-                # the last K-1 REAL inputs of the conv: window column
-                # n_real + j is input n_real - (K-1) + j (zeros before 0)
-                tails = jax.vmap(lambda w, nr: jax.lax.dynamic_slice_in_dim(
-                    w, nr, k - 1, 0))(window, n_real)
                 if slots is None:
                     slots = jnp.arange(b)
-                for i in range(b):
-                    ssm_h = jax.lax.dynamic_update_slice(
-                        ssm_h, h_last[i].reshape((1, 1) + ssm_h.shape[2:]),
-                        (layer, slots[i], 0, 0, 0))
-                    # a row at a time: one [K-1, 1, d] update makes the
-                    # compiler re-lay the whole array out around it
-                    for j in range(k - 1):
-                        ssm_conv = jax.lax.dynamic_update_slice(
-                            ssm_conv, tails[i][j][None, None, None],
-                            (layer, j, slots[i], 0))
+                with tracing.scope("rtpu.attn.cache_write"):
+                    # the last K-1 REAL inputs of the conv: window column
+                    # n_real + j is input n_real - (K-1) + j (zeros
+                    # before 0)
+                    tails = jax.vmap(
+                        lambda w, nr: jax.lax.dynamic_slice_in_dim(
+                            w, nr, k - 1, 0))(window, n_real)
+                    for i in range(b):
+                        ssm_h = jax.lax.dynamic_update_slice(
+                            ssm_h,
+                            h_last[i].reshape((1, 1) + ssm_h.shape[2:]),
+                            (layer, slots[i], 0, 0, 0))
+                        # a row at a time: one [K-1, 1, d] update makes
+                        # the compiler re-lay the whole array out around it
+                        for j in range(k - 1):
+                            ssm_conv = jax.lax.dynamic_update_slice(
+                                ssm_conv, tails[i][j][None, None, None],
+                                (layer, j, slots[i], 0))
                 new_state = (ssm_h, ssm_conv)
         out = dense(cfg.hidden_size, ("mlp", "embed"), "out_proj")(y)
         return out, new_state
@@ -550,15 +556,17 @@ class JambaModel(nn.Module):
                 carry, paged, (positions, mask, slots))
         x, kv_pages, ssm_h, ssm_conv = carry
 
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-        if cfg.tie_word_embeddings:
-            logits = jnp.einsum("bsh,vh->bsv", x, embed.astype(cfg.dtype))
-        else:
-            logits = nn.DenseGeneral(
-                features=cfg.vocab_size, use_bias=False, axis=-1,
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                kernel_init=A(nn.initializers.lecun_normal(),
-                              ("embed", "vocab")), name="lm_head")(x)
+        with tracing.scope("rtpu.head"):
+            x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
+            if cfg.tie_word_embeddings:
+                logits = jnp.einsum("bsh,vh->bsv", x,
+                                    embed.astype(cfg.dtype))
+            else:
+                logits = nn.DenseGeneral(
+                    features=cfg.vocab_size, use_bias=False, axis=-1,
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    kernel_init=A(nn.initializers.lecun_normal(),
+                                  ("embed", "vocab")), name="lm_head")(x)
         if cache is None:
             return logits
         return logits, cache.replace(

@@ -54,6 +54,7 @@ from ..ops.paged_attention import (LATENT_CTX_CHUNK, latent_attention_decode,
                                    ctx_chunks, latent_lanes,
                                    latent_prefill_attention, paged_write)
 from ..ops.rotary import rotate, yarn_inv_freq, yarn_mscale
+from ..util import tracing
 from .llama import MLP, A, ExpertFacts, LlamaConfig, MoEMLP, RMSNorm
 
 # the family's interface flags (serve/llm/stage.py: model_family): pages
@@ -493,14 +494,16 @@ class KimiModel(nn.Module):
             at += n
         x, kv_pages = carry
 
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
+        with tracing.scope("rtpu.head"):
+            x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
         # a plain leaf, not a Dense: the head runs under `lax.cond` below
         head_w = self.param(
             "lm_head", A(nn.initializers.lecun_normal(), ("embed", "vocab")),
             (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
 
         def head(a):
-            return jnp.dot(a, head_w.astype(cfg.dtype))
+            with tracing.scope("rtpu.head"):
+                return jnp.dot(a, head_w.astype(cfg.dtype))
 
         if cache.gather is None:
             logits = head(x)

@@ -30,6 +30,7 @@ from ..ops.attention import attention
 from ..ops.paged_attention import (paged_attention_block,
                                    paged_attention_decode,
                                    paged_prefill_attention, paged_write)
+from ..util import tracing
 
 
 def _remat_policy(name: str):
@@ -442,27 +443,31 @@ class MoEMLP(nn.Module):
         router = self.param(
             "router", A(nn.initializers.normal(0.02), ("embed", None)),
             (h, R), jnp.float32)
-        # routing in fp32 (tiny matmul, numerically load-bearing)
-        logits = jnp.einsum("th,he->te", xt.astype(jnp.float32), router)
-        if cfg.moe_scoring == "sigmoid":
-            probs = jax.nn.sigmoid(logits)                   # [T,R]
-            bias = self.param("router_bias", A(nn.initializers.zeros,
-                                               (None,)), (R,), jnp.float32)
-            _, idx = jax.lax.top_k(probs + bias, k)          # [T,k]
-            gate = jnp.take_along_axis(probs, idx, axis=-1)
-            if cfg.norm_topk_prob:
-                gate = gate / (gate.sum(-1, keepdims=True) + 1e-20)
-            gate = gate * cfg.routed_scaling_factor
-        else:
-            probs = jax.nn.softmax(logits, axis=-1)          # [T,E]
-            gate, idx = jax.lax.top_k(probs, k)              # [T,k]
-            if cfg.norm_topk_prob:
-                gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        with tracing.scope("rtpu.moe.route"):
+            # routing in fp32 (tiny matmul, numerically load-bearing)
+            logits = jnp.einsum("th,he->te", xt.astype(jnp.float32), router)
+            if cfg.moe_scoring == "sigmoid":
+                probs = jax.nn.sigmoid(logits)               # [T,R]
+                bias = self.param(
+                    "router_bias", A(nn.initializers.zeros, (None,)), (R,),
+                    jnp.float32)
+                _, idx = jax.lax.top_k(probs + bias, k)      # [T,k]
+                gate = jnp.take_along_axis(probs, idx, axis=-1)
+                if cfg.norm_topk_prob:
+                    gate = gate / (gate.sum(-1, keepdims=True) + 1e-20)
+                gate = gate * cfg.routed_scaling_factor
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)      # [T,E]
+                gate, idx = jax.lax.top_k(probs, k)          # [T,k]
+                if cfg.norm_topk_prob:
+                    gate = gate / jnp.maximum(
+                        gate.sum(-1, keepdims=True), 1e-9)
+            if R != E:
+                # this chip's share: the held experts by their own index,
+                # every other assignment to the trailing group E
+                idx = idx - cfg.expert_first
+                idx = jnp.where((idx >= 0) & (idx < E), idx, E)
         if R != E:
-            # this chip's share: the held experts by their own index, every
-            # other assignment to the trailing group E
-            idx = idx - cfg.expert_first
-            idx = jnp.where((idx >= 0) & (idx < E), idx, E)
             # [B, S, 1, E] bool, the held experts each token chose, for a
             # caller that asks for the "selection" collection (the
             # benchmark's check); nobody else pays for it
@@ -515,13 +520,15 @@ class MoEMLP(nn.Module):
         T, h = xt.shape
         M = T * k
         tm, aligned, rows, block = moe_row_layout(T, cfg)
-        expert = idx.reshape(M)              # assignment t*k+j: token t
-        if token_mask is not None:
-            # padding joins the trailing group E, which multiplies nothing
-            expert = jnp.where(jnp.repeat(token_mask.reshape(T), k),
-                               expert, E)
-        counts, ends, at, row_of = dropless_layout(expert, E, tm, aligned,
-                                                   rows)
+        with tracing.scope("rtpu.moe.layout"):
+            expert = idx.reshape(M)          # assignment t*k+j: token t
+            if token_mask is not None:
+                # padding joins the trailing group E, which multiplies
+                # nothing
+                expert = jnp.where(jnp.repeat(token_mask.reshape(T), k),
+                                   expert, E)
+            counts, ends, at, row_of = dropless_layout(expert, E, tm,
+                                                       aligned, rows)
         self.sow("routing", "expert_counts", counts,
                  reduce_fn=lambda a, b: a + b,
                  init_fn=lambda: jnp.zeros((E,), jnp.int32))
@@ -530,9 +537,12 @@ class MoEMLP(nn.Module):
         def experts_on(lo, n):
             """The expert FFN on the `n` rows from `lo` on; rows past the
             last group's come back undefined."""
-            here = jnp.diff(jnp.clip(ends, lo, lo + n), prepend=lo)
-            rows_at = jax.lax.dynamic_slice(at, (lo,), (n,))
-            gu = grouped_matmul(xt[rows_at // k], w_gu, here, layer, tm)
+            with tracing.scope("rtpu.moe.layout"):
+                here = jnp.diff(jnp.clip(ends, lo, lo + n), prepend=lo)
+                rows_at = jax.lax.dynamic_slice(at, (lo,), (n,))
+            with tracing.scope("rtpu.moe.gather"):
+                x_rows = xt[rows_at // k]
+            gu = grouped_matmul(x_rows, w_gu, here, layer, tm)
             gate_p, up_p = jnp.split(gu, 2, axis=-1)
             return grouped_matmul(
                 gated_silu(gate_p, up_p, cfg.swiglu_limit), w_dn, here,
@@ -543,25 +553,27 @@ class MoEMLP(nn.Module):
         # block past the last group (all of a wave's tail, when 15 of its
         # 16 rows are padding) is skipped, so the elementwise work between
         # the two matmuls follows the real tokens too.
-        if block == rows:
-            y = experts_on(0, rows)
-        else:
-            y = jax.lax.map(
-                lambda lo: jax.lax.cond(
-                    lo < ends[-1], lambda: experts_on(lo, block),
-                    lambda: jnp.zeros((block, h), cfg.dtype)),
-                jnp.arange(0, rows, block)).reshape(rows, h)
-        # back to token order; the k weighted outputs are summed in
-        # float32, in the same order wherever the token sits
-        y = y[row_of].reshape(T, k, h)
-        if cfg.routed_experts != E:
-            # an assignment to an absent expert reads a row past the last
-            # group, which is undefined: it adds nothing
-            y = jnp.where((expert < E).reshape(T, k, 1), y, 0)
-        out = jnp.einsum("tkh,tk->th", y.astype(jnp.float32),
-                         gate).astype(cfg.dtype)
-        if token_mask is not None:
-            out = jnp.where(token_mask.reshape(T, 1), out, 0)
+        with tracing.scope("rtpu.moe.products"):
+            if block == rows:
+                y = experts_on(0, rows)
+            else:
+                y = jax.lax.map(
+                    lambda lo: jax.lax.cond(
+                        lo < ends[-1], lambda: experts_on(lo, block),
+                        lambda: jnp.zeros((block, h), cfg.dtype)),
+                    jnp.arange(0, rows, block)).reshape(rows, h)
+        with tracing.scope("rtpu.moe.unsort"):
+            # back to token order; the k weighted outputs are summed in
+            # float32, in the same order wherever the token sits
+            y = y[row_of].reshape(T, k, h)
+            if cfg.routed_experts != E:
+                # an assignment to an absent expert reads a row past the
+                # last group, which is undefined: it adds nothing
+                y = jnp.where((expert < E).reshape(T, k, 1), y, 0)
+            out = jnp.einsum("tkh,tk->th", y.astype(jnp.float32),
+                             gate).astype(cfg.dtype)
+            if token_mask is not None:
+                out = jnp.where(token_mask.reshape(T, 1), out, 0)
         return out
 
     def _capacity_dispatch(self, xt, gate, idx, w_gu, w_dn):
@@ -1034,12 +1046,18 @@ class LlamaModel(nn.Module):
             # a denoising pass decides the row's LAST block only: a pending
             # block left of it (`_block_step`) is there for its keys
             x = x[:, -(cfg.block_causal or x.shape[1]):]
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-        head = nn.DenseGeneral(
+        with tracing.scope("rtpu.head"):
+            x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
+        head_proj = nn.DenseGeneral(
             features=cfg.vocab_size, use_bias=False, axis=-1,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             kernel_init=A(nn.initializers.lecun_normal(), ("embed", "vocab")),
             name="lm_head")
+
+        def head(x):
+            with tracing.scope("rtpu.head"):
+                return head_proj(x)
+
         if targets is not None:
             # Fused chunked cross-entropy: the [B,S,V] logits (fp32!) never
             # materialize — each sequence chunk projects + reduces inside a

@@ -55,6 +55,7 @@ from ..ops.paged_attention import (paged_attention_decode,
                                    ring_write, window_attention_decode,
                                    window_prefill_attention)
 from ..ops.rotary import rotate, yarn_inv_freq
+from ..util import tracing
 from .llama import A, ExpertFacts, LlamaConfig, MoEMLP, RMSNorm
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -487,14 +488,16 @@ class MellumModel(nn.Module):
             at[kind] += n
         x, kv_pages, win_pages = carry
 
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
+        with tracing.scope("rtpu.head"):
+            x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
         # a plain leaf, not a Dense: the head runs under `lax.cond` below
         head_w = self.param(
             "lm_head", A(nn.initializers.lecun_normal(), ("embed", "vocab")),
             (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
 
         def head(a):
-            return jnp.dot(a, head_w.astype(cfg.dtype))
+            with tracing.scope("rtpu.head"):
+                return jnp.dot(a, head_w.astype(cfg.dtype))
 
         if cache.gather is None:
             logits = head(x)

@@ -58,6 +58,7 @@ from ..ops.paged_attention import paged_prefill_attention, paged_write
 from ..ops.sparse_attention import (SparseParams, compress_keys,
                                     kernels_scored, keys_attended,
                                     sparse_decode, sparse_prefill)
+from ..util import tracing
 from .llama import MLP, A, RMSNorm, rope
 
 LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
@@ -457,16 +458,18 @@ class LightningLayer(nn.Module):
             rows = []
             for i in range(b):
                 slot = i if slots is None else slots[i]
-                held = jax.lax.dynamic_slice(
-                    lin, (idx, slot, 0, 0, 0), (1, 1, nh, d, d))[0, 0]
-                s0 = jnp.where(start[i] > 0, held, 0.0)
+                with tracing.scope("rtpu.attn.cache_write"):
+                    held = jax.lax.dynamic_slice(
+                        lin, (idx, slot, 0, 0, 0), (1, 1, nh, d, d))[0, 0]
+                    s0 = jnp.where(start[i] > 0, held, 0.0)
                 oi, s_last = lightning_prefill(
                     q[i], k[i], v[i], log_decay, s0, n_real[i], scale=scale)
                 # a row with no real token (a masked warm-up pass) keeps
                 # what its slot held
-                s_last = jnp.where(n_real[i] > 0, s_last, held)
-                lin = jax.lax.dynamic_update_slice(
-                    lin, s_last[None, None], (idx, slot, 0, 0, 0))
+                with tracing.scope("rtpu.attn.cache_write"):
+                    s_last = jnp.where(n_real[i] > 0, s_last, held)
+                    lin = jax.lax.dynamic_update_slice(
+                        lin, s_last[None, None], (idx, slot, 0, 0, 0))
                 rows.append(oi)
             o = jnp.stack(rows)
         o = _head_norm(cfg, "o_norm")(o) * jax.nn.sigmoid(gate)
@@ -584,15 +587,17 @@ class SalaModel(nn.Module):
             carry, _ = body(carry, xs, consts)
         x, kv_pages, kc, lin = carry
 
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-        x = x / (cfg.hidden_size / cfg.dim_model_base)
+        with tracing.scope("rtpu.head"):
+            x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
+            x = x / (cfg.hidden_size / cfg.dim_model_base)
         # a plain leaf, not a Dense: the head runs under `lax.cond` below
         head_w = self.param(
             "lm_head", A(nn.initializers.lecun_normal(), ("embed", "vocab")),
             (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
 
         def head(a):
-            return jnp.dot(a, head_w.astype(cfg.dtype))
+            with tracing.scope("rtpu.head"):
+                return jnp.dot(a, head_w.astype(cfg.dtype))
 
         if cache.gather is None:
             logits = head(x)

@@ -34,6 +34,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..util import tracing
 from .llama import (RESUMES_PREFILL, ExpertFacts, LlamaConfig,  # noqa: F401
                     pass_cost_ratios, pool_spec, serving_cache, serving_model)
 
@@ -126,11 +127,13 @@ def denoise(logits, ids, masked, step, cfg: SdarConfig, temperature, top_k,
     positions (each predicts its own token), ids / masked [S, B] the block
     before the pass, `step` which pass (traced), temperature / top_k [S],
     keys [S, 2] -> (ids, masked, fixed [S, B] bool) after it."""
-    x0, conf = _sample(logits.astype(jnp.float32), temperature, top_k, keys)
-    n_fix = jnp.asarray(transfer_schedule(
-        cfg.block_length, cfg.denoising_steps), jnp.int32)[step]
-    fixed = _transfer(conf, masked, n_fix, cfg)
-    return jnp.where(fixed, x0, ids), masked & ~fixed, fixed
+    with tracing.scope("rtpu.sample"):
+        x0, conf = _sample(logits.astype(jnp.float32), temperature, top_k,
+                           keys)
+        n_fix = jnp.asarray(transfer_schedule(
+            cfg.block_length, cfg.denoising_steps), jnp.int32)[step]
+        fixed = _transfer(conf, masked, n_fix, cfg)
+        return jnp.where(fixed, x0, ids), masked & ~fixed, fixed
 
 
 # ---------------------------------------------------------------- registry

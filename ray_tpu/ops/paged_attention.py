@@ -91,6 +91,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..util import tracing
+
 NEG_INF = -1e30
 LATENT_LANE_TILE = 128
 
@@ -135,6 +137,13 @@ def paged_write(kv_pages: jax.Array, k_new: jax.Array, v_new: jax.Array,
     if layer is None:
         return paged_write(kv_pages[None], k_new, v_new, block_tables,
                            positions, total_lens, 0)[0]
+    with tracing.scope("rtpu.attn.cache_write"):
+        return _paged_write(kv_pages, k_new, v_new, block_tables, positions,
+                            total_lens, layer)
+
+
+def _paged_write(kv_pages, k_new, v_new, block_tables, positions, total_lens,
+                 layer):
     _, num_pages, hkv, page, d2 = kv_pages.shape
     b, s = positions.shape
     mp = block_tables.shape[1]
@@ -165,6 +174,11 @@ def gather_kv(kv_pages: jax.Array, block_tables: jax.Array,
     """[L, P, Hkv, page, 2D] at `layer` (or [P, Hkv, page, 2D]) + [B, MP]
     -> (k, v) each [B, MP*page, Hkv, D]. One gather, no slice of a layer
     first."""
+    with tracing.scope("rtpu.attn.cache_write"):
+        return _gather_kv(kv_pages, block_tables, layer)
+
+
+def _gather_kv(kv_pages, block_tables, layer):
     kv_pages, layer = _layered(kv_pages, layer)
     hkv, page, d2 = kv_pages.shape[-3:]
     b, mp = block_tables.shape
@@ -563,15 +577,22 @@ def ring_write(win_pages: jax.Array, k_new: jax.Array, v_new: jax.Array,
     the ring's index; a pass of S tokens rebuilds each row's ring whole
     (its last W real tokens over what the ring held: 2 MB a layer at W
     1024, 4 kv heads of 128)."""
+    with tracing.scope("rtpu.attn.cache_write"):
+        return _ring_write(win_pages, k_new, v_new, slots, positions,
+                           total_lens, layer, ring_pages)
+
+
+def _ring_write(win_pages, k_new, v_new, slots, positions, total_lens, layer,
+                ring_pages: int):
     _, num_pages, hkv, page, d2 = win_pages.shape
     b, s = positions.shape
     ring = ring_pages * page
     if s == 1:
         at = positions % ring
         live = positions[:, 0] < total_lens
-        return paged_write(win_pages, k_new, v_new,
-                           ring_tables(slots, ring_pages), at,
-                           jnp.where(live, at[:, 0] + 1, 0), layer)
+        return _paged_write(win_pages, k_new, v_new,
+                            ring_tables(slots, ring_pages), at,
+                            jnp.where(live, at[:, 0] + 1, 0), layer)
     kv = jnp.concatenate([k_new, v_new], axis=-1).astype(win_pages.dtype)
     start = positions[:, :1]                                       # [B, 1]
     end = jnp.minimum(total_lens[:, None], start + s)
@@ -601,11 +622,12 @@ def ring_context(win_pages: jax.Array, slots: jax.Array, start: jax.Array,
     into a layout of its liking, tests/test_chip_compile.py) and their rows
     put in order afterwards, on 2 MB a row."""
     ring = ring_pages * win_pages.shape[-2]
-    k, v = gather_kv(win_pages, ring_tables(slots, ring_pages), layer)
-    at = (jnp.maximum(start - ring, 0)[:, None]
-          + jnp.arange(ring, dtype=jnp.int32)[None, :]) % ring     # [B, W]
-    k, v = (jnp.take_along_axis(x, at[:, :, None, None], axis=1)
-            for x in (k, v))
+    with tracing.scope("rtpu.attn.cache_write"):
+        k, v = _gather_kv(win_pages, ring_tables(slots, ring_pages), layer)
+        at = (jnp.maximum(start - ring, 0)[:, None]
+              + jnp.arange(ring, dtype=jnp.int32)[None, :]) % ring  # [B, W]
+        k, v = (jnp.take_along_axis(x, at[:, :, None, None], axis=1)
+                for x in (k, v))
     return k, v, jnp.minimum(start, ring)
 
 
@@ -1034,7 +1056,8 @@ def latent_prefill_attention(q: jax.Array, k_new: jax.Array,
                         scale=scale, impl=impl)
 
     def attend(first, n, have):
-        rows = kv_pages[layer, block_tables[:, first:first + n]]
+        with tracing.scope("rtpu.attn.cache_write"):
+            rows = kv_pages[layer, block_tables[:, first:first + n]]
         k, v = expand(rows.reshape(b, n * page, lanes))
         return _mla_flash(q, k, v, n_new, have, causal=False, scale=scale,
                           impl=impl)

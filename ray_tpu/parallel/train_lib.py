@@ -99,6 +99,10 @@ class ShardedTrainer:
         self._jit_eval = None
         self._step_seq = 0
         self._donate = donate_state
+        # the shapes and shardings of the last step's (state, batch), for
+        # `program_scopes`; its table, parsed once
+        self._step_avals = None
+        self._scopes = None
 
     # -------------------------------------------------------------- loss
     def _default_loss(self, params, batch):
@@ -194,13 +198,15 @@ class ShardedTrainer:
 
         def _step(state: TrainState, batch):
             def loss_fn(params):
-                return self.loss_fn(params, batch)
+                with tracing.scope("rtpu.loss"):
+                    return self.loss_fn(params, batch)
 
             loss, grads = jax.value_and_grad(loss_fn)(state.params)
-            updates, new_opt = self.tx.update(grads, state.opt_state,
-                                              state.params)
-            new_params = optax.apply_updates(state.params, updates)
-            gnorm = optax.global_norm(grads)
+            with tracing.scope("rtpu.optimizer"):
+                updates, new_opt = self.tx.update(grads, state.opt_state,
+                                                  state.params)
+                new_params = optax.apply_updates(state.params, updates)
+                gnorm = optax.global_norm(grads)
             return (TrainState(step=state.step + 1, params=new_params,
                                opt_state=new_opt),
                     {"loss": loss, "grad_norm": gnorm})
@@ -225,6 +231,13 @@ class ShardedTrainer:
         with tracing.region("rtpu.train.step") as r:
             batch = {k: jax.device_put(v, self._batch_sharding)
                      for k, v in batch.items()}
+            if self._step_avals is None:
+                # once: a trainer's state and batch keep their shapes (the
+                # caller gives its state away to the step, so no array)
+                self._step_avals = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype, sharding=a.sharding),
+                    (state, batch))
             with active_mesh(self.mesh):
                 out = self._jit_step(state, batch)
         tracing.record("train.step", (self._step_seq, r.start_ns, r.end_ns))
@@ -240,6 +253,23 @@ class ShardedTrainer:
             self._build_step(batch)
         with active_mesh(self.mesh):
             return self._jit_step.lower(state, batch).as_text()
+
+    def program_scopes(self) -> Optional[Dict[str, str]]:
+        """Which scope each instruction of the train step belongs to:
+        instruction name as a profiler trace's op events carry it ->
+        the `op_name` path jax wrote for it (util/tracing.py:
+        instruction_scopes, SCOPES; the backward pass is under
+        `transpose(jvp(...))`, a rematerialised forward under
+        `rematted_computation`). From the shapes and shardings of the
+        first step: the lowering that ran, so with a persistent compile
+        cache the text is the executed program's. None before a step.
+        For whoever reads a trace after the run: no step calls it."""
+        if self._scopes is None and self._step_avals is not None:
+            with active_mesh(self.mesh):
+                self._scopes = tracing.instruction_scopes(
+                    self._jit_step.lower(*self._step_avals)
+                    .compile().as_text())
+        return self._scopes
 
     def eval_loss(self, state: TrainState, batch) -> jax.Array:
         if self._jit_eval is None:

@@ -1303,7 +1303,7 @@ class LLMEngine:
             self._enqueue(
                 "prefill", tokens, r.start_ns, computed, computed * sb,
                 facts, self._family_fields("prefill", facts, passes, cp),
-                temp=temp, group=rows)
+                (sb, rb, cp), temp=temp, group=rows)
         self._phase_ns[_DISPATCH_PREFILL] += r.ns
 
     def _family_fields(self, site: str, *what) -> Dict[str, Any]:
@@ -1318,7 +1318,7 @@ class LLMEngine:
 
     def _enqueue(self, kind: str, toks, dispatch_ns: int, rows_padded: int,
                  tokens_padded: int, facts: List[tuple],
-                 fields: Dict[str, Any], k: int = 1,
+                 fields: Dict[str, Any], program_key: tuple, k: int = 1,
                  temp: Optional[np.ndarray] = None, **harvest_keys) -> None:
         """Queue one enqueued program for harvest. The dict is also its
         `engine.dispatch` flight record in the making, by the record's
@@ -1327,7 +1327,8 @@ class LLMEngine:
         row computes and the tokens of KV it attends to, cached prefix
         included (for a k-step decode row: at its first step) —
         `rows_padded`/`tokens_padded` are what the program computes,
-        `fields` the family's, `temp` its operand of that name (None: a
+        `fields` the family's, `program_key` the shape key `compute.run`
+        was given for it, `temp` its operand of that name (None: a
         program with no sampler): the record's `drawn` is whether it has a
         row above 0, the branch the program's sampler takes (stage.py:
         _device_sample). _harvest adds the fetch's timestamps."""
@@ -1337,6 +1338,7 @@ class LLMEngine:
             self._totals["drawn_dispatches_total"] += bool(drawn)
         self._inflight.append({
             **self._rec_constant, **fields, "drawn": drawn,
+            "program_key": program_key,
             "kind": kind, "toks": toks, "k": k, "seq": self._dispatch_seq,
             "step_dispatched": self._step_seq, "dispatch_ns": dispatch_ns,
             "enqueued_ns": tracing.now_ns(),
@@ -1444,7 +1446,7 @@ class LLMEngine:
         toks = self._compute_verify(sb, rb, len(rows), bt, total_arr, ids,
                                     positions)
         self._enqueue("spec", toks, dispatch_ns, len(rows), len(rows) * sb,
-                      facts, {}, drafts=recs)
+                      facts, {}, (sb, rb), drafts=recs)
         return True
 
     def _decode_eligible(self) -> List[Request]:
@@ -1573,8 +1575,8 @@ class LLMEngine:
         toks = self._compute_decode(k_steps, mp, bt, total, caps,
                                     positions, override_mask,
                                     override_ids, temp, topk, keys_steps)
-        self._enqueue_decode("decode", toks, dispatch_ns, k_steps,
-                             S * k_steps, facts, chunk_slots, temp)
+        self._enqueue_decode("decode", toks, dispatch_ns, (k_steps, mp),
+                             k_steps, S * k_steps, facts, chunk_slots, temp)
         return True
 
     def _dispatch_block(self) -> bool:
@@ -1638,12 +1640,12 @@ class LLMEngine:
                 req.block_pending = True
             toks = self._compute_block(key, bt, total, ids, masked, pending,
                                        temp, topk, keys_steps)
-            self._enqueue_decode("block", toks, r.start_ns, steps, S * B,
-                                 facts, block_slots, temp)
+            self._enqueue_decode("block", toks, r.start_ns, key, steps,
+                                 S * B, facts, block_slots, temp)
         return True
 
     def _enqueue_decode(self, kind: str, toks, dispatch_ns: int,
-                        k_steps: int, tokens_padded: int,
+                        program_key: tuple, k_steps: int, tokens_padded: int,
                         facts: List[tuple], slots: dict,
                         temp: np.ndarray) -> None:
         """_enqueue for a program over the full slot set (a decode chunk
@@ -1656,7 +1658,7 @@ class LLMEngine:
         self._enqueue(
             kind, toks, dispatch_ns, self.config.max_batch, tokens_padded,
             facts, self._family_fields("decode", facts, k_steps),
-            k=k_steps, temp=temp, slots=slots)
+            program_key, k=k_steps, temp=temp, slots=slots)
 
     # ---------------------------------------------------------- harvest
 
@@ -2168,6 +2170,17 @@ class LLMEngine:
     def program_text(self, kind: str, shape_key: tuple) -> str:
         """The lowered (StableHLO) text of one dispatch program."""
         return self.compute.program_text(kind, shape_key)
+
+    def program_scopes(self, kind: str,
+                       shape_key: tuple) -> Optional[Dict[str, str]]:
+        """Instruction name -> scope path of one dispatch program
+        (`StageCompute.program_scopes`), by a dispatch record's `kind`
+        ("spec" is the verify program) and `program_key`; None where the
+        programs are another process's (pp)."""
+        if self.compute is None:
+            return None
+        return self.compute.program_scopes(
+            "verify" if kind == "spec" else kind, shape_key)
 
     def _warmup_programs(self, prompt_buckets, include_decode) -> list:
         """(kind, shape key) of every dispatch shape traffic can hit: one

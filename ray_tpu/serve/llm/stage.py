@@ -367,6 +367,9 @@ class StageCompute:
                 (config.max_batch, cfg.block_length), jnp.int32)
         self.programs: Dict[tuple, Any] = {}
         self.programs_built = 0
+        # (kind,) + shape key -> instruction name -> op_name path, parsed
+        # on the first `program_scopes` of a key and never before
+        self._scopes: Dict[tuple, Dict[str, str]] = {}
         # the scheduler step a program is built in, for its record: the
         # engine points this at its counter, a stage worker has none
         self.step_seq = lambda: 0
@@ -590,7 +593,9 @@ class StageCompute:
                     gather_idx if head_at_gather else None)
                 # sample ON DEVICE: only B int32 tokens cross to the host
                 # per step, never the [B, V] fp32 logits
-                tokens = _device_sample(rows, temperature, top_k, rng_keys)
+                with tracing.scope("rtpu.sample"):
+                    tokens = _device_sample(rows, temperature, top_k,
+                                            rng_keys)
                 return pack(tokens, counts), kvp
 
             def run_verify(params, kv_pages, n_rows, block_tables,
@@ -605,11 +610,14 @@ class StageCompute:
                     return hidden_rows(params, kv_pages, n_rows,
                                        block_tables, total_lens, x,
                                        positions)
+                def greedy(out, i):
+                    with tracing.scope("rtpu.sample"):
+                        return jnp.argmax(out.astype(jnp.float32),
+                                          axis=-1).astype(jnp.int32)
+
                 kvp, toks, counts = row_pass(
                     params, kv_pages, n_rows, block_tables, total_lens, x,
-                    positions, jnp.zeros((rb, sb), jnp.int32),
-                    lambda out, i: jnp.argmax(
-                        out.astype(jnp.float32), axis=-1).astype(jnp.int32))
+                    positions, jnp.zeros((rb, sb), jnp.int32), greedy)
                 return pack(toks, counts), kvp
 
             return self._jit(run_prefill if kind == "prefill"
@@ -648,8 +656,10 @@ class StageCompute:
                 out, new_pc, counts = apply(params, x_k, pos,
                                             cache.step(kvp, tot), tot)
                 if last:
-                    rows = out[:, 0].astype(jnp.float32)
-                    out = _device_sample(rows, temperature, top_k, keys_k)
+                    with tracing.scope("rtpu.sample"):
+                        rows = out[:, 0].astype(jnp.float32)
+                        out = _device_sample(rows, temperature, top_k,
+                                             keys_k)
                 # caps clamp: past a slot's ceiling, positions freeze at
                 # cap-1 and totals at cap, so no block-table index runs
                 # off the allocated range. NOTE the frozen row keeps
@@ -823,6 +833,27 @@ class StageCompute:
         (`tpu_custom_call`) is really in the program a replica runs."""
         return self.program(kind, shape_key).lower(
             *self._state(kind), *self.dummy_args(kind, shape_key)).as_text()
+
+    def program_scopes(self, kind: str, shape_key: tuple) -> Dict[str, str]:
+        """Which scope each instruction of one dispatch program belongs
+        to: instruction name as a profiler trace's op events carry it
+        (`fusion.694`, `sort.3`, `_moe_gmm.26`) -> the `op_name` path jax
+        wrote for it (util/tracing.py: instruction_scopes, SCOPES). The
+        same lowering under the same options as the program that ran, so
+        with a persistent compile cache the text is the executed
+        program's, fetched and parsed once a key. For whoever reads a
+        trace AFTER the run: nothing on the serving path calls this, a key
+        that was never built is lowered without joining `programs`, and it
+        counts as no program built."""
+        key = (kind,) + tuple(shape_key)
+        table = self._scopes.get(key)
+        if table is None:
+            fn = self.programs.get(key) or self._build(kind, shape_key)
+            table = self._scopes[key] = tracing.instruction_scopes(
+                fn.lower(*self._state(kind),
+                         *self.dummy_args(kind, shape_key)
+                         ).compile().as_text())
+        return table
 
     def warmup(self, programs) -> int:
         """Build each (kind, shape key) by running it on masked dummy

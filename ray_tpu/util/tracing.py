@@ -17,6 +17,12 @@ both tiers is Unix-epoch time (spans in seconds, ring records in
 nanoseconds); jax's profiler reports the same clock counted from the start
 of its session, so one constant per session places a record on a trace.
 `chrome_trace()` renders both tiers for chrome://tracing or Perfetto.
+
+Scopes (trace time only): `scope(name)` is a `jax.named_scope` at one of
+the phases of a step listed in `SCOPES`; the name lands in the `op_name`
+of every instruction traced under it, and `instruction_scopes` reads a
+compiled program's text back as instruction name -> path, so that a
+profiler trace's device time can be summed by the program's own names.
 """
 
 from __future__ import annotations
@@ -25,6 +31,7 @@ import collections
 import contextlib
 import contextvars
 import itertools
+import re
 import threading
 import time
 import uuid
@@ -189,7 +196,12 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         # the engine's own, LAST for the same reason: whether the program's
         # `temp` operand had a row above 0, which is the branch its sampler
         # took (serve/llm/stage.py: _device_sample); None: no sampler
-        "drawn"),
+        "drawn",
+        # LAST again: the shape key the call site gave `compute.run` with
+        # the record's kind ((sb, rb, cp) a prefill, (sb, rb) a verify,
+        # (K, mp) a decode, the block key), which is the program the device
+        # ran: `LLMEngine.program_scopes(kind, program_key)` is its table
+        "program_key"),
     # one per LLMEngine.step(); `fetch_blocked`: how many of the step's
     # harvests found their program unfinished (the step waited for the
     # device, not the device for the step), `device_idle_ns`: time the
@@ -300,6 +312,69 @@ class region:
     @property
     def ns(self) -> int:
         return self.end_ns - self.start_ns
+
+
+# ---------------------------------------------------------------------------
+# Scopes: the device's time by the program's own names.
+# ---------------------------------------------------------------------------
+
+# the phases of a step that are neither a flax module nor a jit of their
+# own, each a `jax.named_scope` at trace time (nothing at run time): the
+# name lands in the `op_name` of every instruction traced under it, beside
+# the module names, the inner jits' (`jit(_sparse_prefill)`), the backward
+# pass's `transpose(jvp(...))` and a rematerialised forward's
+# `rematted_computation`, which jax writes itself. `program_scopes` (serve/
+# llm/stage.py, parallel/train_lib.py) reads them back a built program. A
+# scope is never the innermost name around a Pallas call: a kernel's
+# instruction is named after that (`_moe_gmm.<n>`, `attn.<n>`).
+SCOPES: Tuple[str, ...] = (
+    # models/llama.py: MoEMLP, every expert family's layer
+    "rtpu.moe.route",      # router logits, top-k, the gate's weights
+    "rtpu.moe.layout",     # the sort by expert and every integer array
+    "rtpu.moe.gather",     # tokens into expert order
+    "rtpu.moe.products",   # the two grouped matmuls, silu * up, the stack
+    "rtpu.moe.unsort",     # back to token order, weighted sum, the mask
+    # the final norm and the vocabulary matmul, where a family computes it
+    "rtpu.head",
+    # serve/llm/stage.py: _device_sample, models/sdar.py: denoise
+    "rtpu.sample",
+    # what an attention layer moves that is no kernel: page, ring, latent
+    # and per-slot state writes, the gathers that feed a kernel
+    "rtpu.attn.cache_write",
+    # parallel/train_lib.py: _step
+    "rtpu.loss",           # the forward under value_and_grad
+    "rtpu.optimizer",      # tx.update, apply_updates, global_norm
+)
+
+
+def scope(name: str):
+    """`with tracing.scope("rtpu.head"):` around the phase's code, inside
+    a traced function; `name` is one of SCOPES."""
+    if name not in SCOPES:
+        raise KeyError(f"{name!r} is no scope of tracing.SCOPES")
+    import jax
+
+    return jax.named_scope(name)
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?(\S+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def instruction_scopes(compiled_text: str) -> Dict[str, str]:
+    """A compiled program's text (`jit(f).lower(...).compile().as_text()`)
+    as instruction name -> the `op_name` path of its metadata ("" where
+    the compiler made the instruction and gave it none): `fusion.694` ->
+    `jit(run_prefill)/.../rtpu.moe.unsort/gather`. A fusion carries ONE
+    path, its root's. The names are the ones a profiler trace's op events
+    carry."""
+    out: Dict[str, str] = {}
+    for line in compiled_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is not None:
+            path = _OP_NAME.search(line)
+            out[m.group(1)] = path.group(1) if path else ""
+    return out
 
 
 def _dispatch_name(r: Dict[str, Any]) -> str:

@@ -336,7 +336,8 @@ def test_the_engine_emits_the_references_tokens_and_its_records_say_how(
     # the family's two come behind the stamps (only the engine's `drawn`
     # behind them): hand-made records of four families' tests hold every
     # earlier field to its place
-    assert fields[-3:] == ("gdn_layers", "gdn_state_bytes_row", "drawn")
+    assert fields[-4:] == ("gdn_layers", "gdn_state_bytes_row", "drawn",
+                           "program_key")
     row_bytes = 5 * (4 * 16 * 16 * 4 + 3 * 128 * 4)      # float32 here
     for r in recs:
         assert len(r) == len(fields)

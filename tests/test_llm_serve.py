@@ -350,7 +350,7 @@ def _dispatch_records():
     from ray_tpu.util import tracing
 
     fields = tracing.FIELDS["engine.dispatch"]
-    assert fields[-1] == "drawn"
+    assert fields[-2:] == ("drawn", "program_key")
     return [dict(zip(fields, r)) for r in tracing.records("engine.dispatch")]
 
 
