@@ -993,12 +993,16 @@ class LlamaModel(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, positions=None, segment_ids=None,
-                 kv_caches=None, targets=None, token_mask=None):
+                 kv_caches=None, targets=None, token_mask=None,
+                 apply_head: bool = True):
         """Forward pass.
 
         input_ids: [B, S] token ids; the previous stage's [B, S, h] hidden
         states when not `first`. Returns logits; hidden states when not
-        `last`.
+        `last`; with `apply_head=False` the final-normed hidden states the
+        head would multiply (a block step's: of the last block), for a
+        caller that folds the head into what it keeps of the logits
+        (models/sdar.py: decide).
 
         token_mask: [B, S] bool, False where the caller padded a row or a
         position; only the expert layer reads it (MoEMLP).
@@ -1048,6 +1052,8 @@ class LlamaModel(nn.Module):
             x = x[:, -(cfg.block_causal or x.shape[1]):]
         with tracing.scope("rtpu.head"):
             x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
+        if not apply_head:
+            return (x, new_caches) if kv_caches is not None else x
         head_proj = nn.DenseGeneral(
             features=cfg.vocab_size, use_bias=False, axis=-1,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,

@@ -19,8 +19,9 @@ prefilled and yield no token; its ragged tail opens the first block.
 
 This module holds what the method adds to the Llama family: the config's
 generation settings (they are the MODEL's, no scheduling knob), the presets,
-and one denoising pass's arithmetic on the logits (`denoise`), which the
-serving program (serve/llm/stage.py, kind "block") runs in its loop.
+and one denoising pass's arithmetic on the logits (`denoise`; `decide`, the
+same from the hidden states in front of the head, which the serving
+program, serve/llm/stage.py, kind "block", runs in its loop).
 Whether a position is masked is a FLAG beside the ids, never a comparison
 with `mask_token_id`: a prompt that holds that id is a prompt (the
 published script compares ids).
@@ -121,6 +122,16 @@ def _transfer(conf, masked, n_fix, cfg: SdarConfig):
     return jnp.where(sure.sum(-1, keepdims=True) >= n_fix, sure, best)
 
 
+def _settle(x0, conf, ids, masked, step, cfg: SdarConfig):
+    """A pass's candidates x0 and their confidences [S, B] -> (ids, masked,
+    fixed) after it: the schedule's share of the masked positions takes
+    its candidate."""
+    n_fix = jnp.asarray(transfer_schedule(
+        cfg.block_length, cfg.denoising_steps), jnp.int32)[step]
+    fixed = _transfer(conf, masked, n_fix, cfg)
+    return jnp.where(fixed, x0, ids), masked & ~fixed, fixed
+
+
 def denoise(logits, ids, masked, step, cfg: SdarConfig, temperature, top_k,
             keys):
     """One denoising pass's decision. logits [S, B, V] at the block's
@@ -130,10 +141,38 @@ def denoise(logits, ids, masked, step, cfg: SdarConfig, temperature, top_k,
     with tracing.scope("rtpu.sample"):
         x0, conf = _sample(logits.astype(jnp.float32), temperature, top_k,
                            keys)
-        n_fix = jnp.asarray(transfer_schedule(
-            cfg.block_length, cfg.denoising_steps), jnp.int32)[step]
-        fixed = _transfer(conf, masked, n_fix, cfg)
-        return jnp.where(fixed, x0, ids), masked & ~fixed, fixed
+        return _settle(x0, conf, ids, masked, step, cfg)
+
+
+def decide(hidden, head, ids, masked, step, cfg: SdarConfig, temperature,
+           top_k, keys):
+    """`denoise` from the block's final-normed hidden states [S, B, h] and
+    the head's weights [h, V] (`LlamaModel(..., apply_head=False)`), for the
+    serving program. A greedy batch keeps an argmax and a confidence of a
+    position's V logits, so its pass decides them where the head's product
+    is (ops/head_argmax.py) and writes no logits; a batch with a row that
+    draws runs the head and `denoise` as they stand. The program chooses
+    from its own `temperature` operand (stage.py: `_device_sample` does
+    the same): the engine counts the second kind as
+    `block_drawn_dispatches_total`."""
+    from ..ops.head_argmax import head_argmax
+
+    def drawn(_):
+        with tracing.scope("rtpu.head"):
+            logits = jnp.dot(hidden, head.astype(hidden.dtype))
+        return denoise(logits, ids, masked, step, cfg, temperature, top_k,
+                       keys)
+
+    def greedy(_):
+        with tracing.scope("rtpu.head"):
+            x0, top, lse = head_argmax(
+                hidden.reshape(-1, hidden.shape[-1]), head)
+        with tracing.scope("rtpu.sample"):
+            return _settle(x0.reshape(ids.shape),
+                           jnp.exp(top - lse).reshape(ids.shape), ids,
+                           masked, step, cfg)
+
+    return jax.lax.cond(jnp.any(temperature > 0), drawn, greedy, None)
 
 
 # ---------------------------------------------------------------- registry
@@ -179,6 +218,11 @@ class BlockFacts:
             "over blocks: every denoising pass of a block, the first of "
             "them two blocks wide where it settles the slot's pending "
             "block)",
+        "block_drawn_dispatches_total":
+            "of block_dispatches_total, the programs whose batch had a row "
+            "at a temperature above 0: their passes wrote the head's "
+            "logits and drew from them, where a greedy batch's decide in "
+            "one kernel over vocabulary tiles (ops/head_argmax.py)",
         "block_passes_total":
             "forward passes the block programs ran",
         "block_tokens_total":

@@ -1336,6 +1336,8 @@ class LLMEngine:
         drawn = None if temp is None else bool((temp > 0).any())
         if kind in ("prefill", "decode"):
             self._totals["drawn_dispatches_total"] += bool(drawn)
+        elif kind == "block":
+            self._totals["block_drawn_dispatches_total"] += bool(drawn)
         self._inflight.append({
             **self._rec_constant, **fields, "drawn": drawn,
             "program_key": program_key,

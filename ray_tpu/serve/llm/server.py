@@ -87,7 +87,9 @@ _LLM_WORK_TOTALS = {
         "of prefill_dispatches_total + decode_dispatches_total, the "
         "programs whose batch had a row at a temperature above 0: their "
         "sampler drew (scaling, top-k cut and categorical) where a greedy "
-        "batch's is an argmax",
+        "batch's is an argmax (a model that generates by diffusion over "
+        "blocks counts its block programs' as "
+        "block_drawn_dispatches_total)",
     "prefill_tokens_total": "prompt tokens prefilled (real rows)",
     "prefill_padded_tokens_total":
         "token positions the prefill programs computed (rows x bucket)",

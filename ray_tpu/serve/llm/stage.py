@@ -485,22 +485,23 @@ class StageCompute:
         moe_LE = ((getattr(cfg, "n_expert_layers", L), cfg.num_experts)
                   if cfg.num_experts else None)
 
-        def apply(params, x, positions, pc, total_lens):
+        def apply(params, x, positions, pc, total_lens, **how):
             """model.apply -> (logits or hidden states, cache, routing
             counts). An expert model is told which rows and positions are
             real (the padding of a wave, idle decode slots: exactly what
             `paged_write` drops) and hands back its [L, E] int32 count of
             real assignments per expert; a dense model's call is what it
-            was."""
+            was. `how`: what one program asks of its model's call beside
+            (a block program's `apply_head=False`)."""
             if moe_LE is None:
                 out, new_pc = model.apply(
                     {"params": params}, x, positions=positions,
-                    kv_caches=pc)
+                    kv_caches=pc, **how)
                 return out, new_pc, None
             (out, new_pc), sown = model.apply(
                 {"params": params}, x, positions=positions, kv_caches=pc,
                 token_mask=positions < total_lens[:, None],
-                mutable=["routing"])
+                mutable=["routing"], **how)
             # the one leaf sown: the scanned expert layers' [L, E] (a leaf
             # a run of like layers, in the model's order, where the stack
             # is several scans: models/mellum.py)
@@ -697,9 +698,14 @@ class StageCompute:
         writes the block's keys and values to its pages as the block
         stands (the positions are fixed; a later pass overwrites them) and
         attends the row's earlier tokens and the whole block
-        (`PagedCache.block_step`); `models/sdar.py: denoise` fixes some
-        masked positions from the logits. The loop leaves when no live row
-        has a masked position (at most `denoising_steps` passes in all).
+        (`PagedCache.block_step`); `models/sdar.py: decide` fixes some
+        masked positions from the new block's final-normed hidden states
+        and the head's weights: a greedy batch's pass in one kernel that
+        writes no logits, a batch with a drawing row by the head's product
+        and `denoise` (a sharded stage, where single-device kernels cannot
+        run, takes logits from the model and calls `denoise`). The loop
+        leaves when no live row has a masked position (at most
+        `denoising_steps` passes in all).
 
         A denoised block is PENDING: its pages hold the keys of its last
         pass's inputs, not of its settled ids. No pass of its own settles
@@ -722,7 +728,7 @@ class StageCompute:
         import jax
         import jax.numpy as jnp
 
-        from ...models.sdar import denoise
+        from ...models.sdar import decide, denoise
 
         cfg = self.model_cfg
         B, steps = cfg.block_length, cfg.denoising_steps
@@ -744,7 +750,7 @@ class StageCompute:
             def forward(ids, positions, kvp):
                 out, new_pc, counts = apply(
                     params, ids, positions, cache.step(kvp, total_lens),
-                    total_lens)
+                    total_lens, apply_head=ref_attn)
                 return out, new_pc.pool, counts
 
             def put(counts, c, at):
@@ -752,11 +758,13 @@ class StageCompute:
                         jax.lax.dynamic_update_slice_in_dim(
                             counts, c[None], at, 0))
 
-            def fix(carry, logits, kvp, c):
+            def fix(carry, out, kvp, c):
                 step, ids, masked, fixed_at, _, counts = carry
-                ids, masked, fixed = denoise(
-                    logits, ids, masked, step, cfg, temperature, top_k,
-                    keys_steps[step])
+                how = (ids, masked, step, cfg, temperature, top_k,
+                       keys_steps[step])
+                ids, masked, fixed = (
+                    denoise(out, *how) if ref_attn else
+                    decide(out, params["lm_head"]["kernel"], *how))
                 fixed_at = jnp.where(fixed, step, fixed_at)
                 return (step + 1, ids, masked, fixed_at, kvp,
                         put(counts, c, step))

@@ -162,6 +162,20 @@ def _paged_decode(b, d, mp, hq=32, hkv=8, page=16, layers=4,
     return build
 
 
+def _head_argmax(rows, h, v):
+    """The head of a greedy block pass (ops/head_argmax.py): `rows`
+    final-normed hidden states on the `lm_head` weights [h, v], at the
+    tile its rule gives (`vocab_tile`), no `vmem_limit_bytes` asked."""
+    def build(topo):
+        from ray_tpu.ops import head_argmax as ha
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        return (lambda x, w: ha.head_argmax(x, w, impl="pallas"),
+                (jax.ShapeDtypeStruct((rows, h), BF16, sharding=one_chip),
+                 jax.ShapeDtypeStruct((h, v), BF16, sharding=one_chip)))
+    return build
+
+
 def _moe_gmm(m, experts=8, h=4096, f=14336, layers=3, grads=False):
     """The expert FFN's two grouped matmuls as `MoEMLP._dropless` calls
     them on a TPU, for `m` assignments at Mixtral's widths (or the `h`,
@@ -267,6 +281,10 @@ COMPILES = {
     "moe-gmm-mellum2-decode-M512": _moe_gmm(512, 64, 2304, 896, 8),
     "moe-gmm-mellum2-pass4096-M32768": _moe_gmm(32768, 64, 2304, 896, 8),
     "moe-gmm-sdar-block-M2048": _moe_gmm(2048, 128, 2048, 768, 6),
+    # the same block step's head, decided in its vocabulary tiles: 64 slots
+    # x 4 positions on [2048, 151936], 148 tiles of 1024 columns and one of
+    # 384 (128 x 1187 has no larger tile that divides it)
+    "head-argmax-sdar-block-R256": _head_argmax(256, 2048, 151936),
     # the backward: refused for VMEM at tm 256 before PR 48 (tgmm held the
     # forward's [1024, 2048] tile twice over), and at a fitted tile
     "moe-gmm-grads-mixtral-M4096": _moe_gmm(4096, layers=1, grads=True),
@@ -297,7 +315,7 @@ def test_kernel_compiles_for_v5e(topo, no_persistent_cache, name):
             lowered.compile()
     else:
         assert "tpu_custom_call" in lowered.compile().as_text()
-    if "-lens-" in name or name.startswith("moe-gmm"):
+    if "-lens-" in name or name.startswith(("moe-gmm", "head-argmax")):
         # K and V resident and nothing beside them: inside the VMEM a
         # kernel gets unasked, where the segment ids it replaces were not
         # (a raised `vmem_limit_bytes` lowers to `scoped_memory_configs`)
@@ -762,7 +780,11 @@ def test_sdar_program_fits_and_carries_its_pool_in_place(
     in the loop's); a prefill's is the flash kernel under the block mask;
     the pool (and the block program's carry) is aliased from argument to
     result. The block program is 11.06 GiB (PR 42: what it was with the
-    settling pass it had until then; 0.59 GiB of it temporaries)."""
+    settling pass it had until then; 0.59 GiB of it temporaries). Since PR
+    54 a greedy batch's pass decides in `_head_argmax` and writes no
+    logits; the program reads 11.058 GiB with 0.588 of temporaries all the
+    same: the branch that draws keeps its `f32[64,4,151936]` and XLA sizes
+    a `cond` for the larger branch."""
     from ray_tpu.serve.llm import EngineConfig, LLMEngine
     from ray_tpu.serve.llm.stage import init_params
 
@@ -801,9 +823,13 @@ def test_sdar_program_fits_and_carries_its_pool_in_place(
     kernels = set(re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
     names = sorted({k.split(".")[0] for k in kernels})
     if kind == "block":
-        assert names == ["_decode_call", "_moe_gmm"], kernels
+        assert names == ["_decode_call", "_head_argmax", "_moe_gmm"], kernels
         assert sum(k.startswith("_decode_call") for k in kernels) == 3
         assert sum(k.startswith("_moe_gmm") for k in kernels) == 4
+        # the head of a greedy batch's pass, the opening one's and the
+        # loop's; the float32 logits are the drawing branch's alone
+        assert sum(k.startswith("_head_argmax") for k in kernels) == 2
+        assert text.count("f32[64,4,151936]") > 0
         # the opening pass's ids, and no head over its left half
         assert "s32[64,8]" in text and "[64,8,151936]" not in text
         assert total <= 11.3 * 2 ** 30, total / 2 ** 30

@@ -18,6 +18,7 @@ from chipbench.references import sdar_decoder as reference
 from ray_tpu.models import sdar
 from ray_tpu.ops.attention import reference_attention
 from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops import head_argmax as head_op
 from ray_tpu.ops.paged_attention import (paged_attention_block,
                                          paged_attention_reference)
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
@@ -501,6 +502,150 @@ def test_sampled_rows_are_seeded_and_greedy_rows_unmoved_beside_them():
         engine.close()
     assert outs[0]["g"]["tokens"] == outs[1]["g"]["tokens"] == greedy
     assert outs[0]["s"]["tokens"] == outs[1]["s"]["tokens"] != greedy
+
+
+def test_a_drawing_row_takes_the_program_off_the_kernel_and_is_counted():
+    """`block_drawn_dispatches_total`: the block programs whose batch had a
+    row above temperature 0 (they write the head's logits and draw); a
+    greedy batch's programs decide in the head's kernel and move nothing."""
+    prompt = _prompts((41,))[0]
+    engine = _engine()
+    assert engine.stats()["block_drawn_dispatches_total"] == 0
+    engine.add_request("g", prompt, SamplingParams(max_tokens=8))
+    _run(engine)
+    st = engine.stats()
+    assert st["block_dispatches_total"] >= 2
+    assert st["block_drawn_dispatches_total"] == 0
+    n0 = tracing.appended("engine.dispatch")
+    engine.add_request("g2", prompt, SamplingParams(max_tokens=16))
+    engine.add_request("s", prompt, SamplingParams(
+        max_tokens=3, temperature=1.0, top_k=8, seed=5))
+    _run(engine)
+    fields = tracing.FIELDS["engine.dispatch"]
+    recs = [dict(zip(fields, r)) for r in tracing.records(
+        "engine.dispatch", since=n0)]
+    drew = [r["drawn"] for r in recs if r["kind"] == "block"]
+    # `s` leaves with its first block; `g2` goes on alone, greedy again
+    assert True in drew and drew[-1] is False
+    moved = {k: engine.stats()[k] - st[k] for k in (
+        "block_dispatches_total", "block_drawn_dispatches_total")}
+    assert moved == {"block_dispatches_total": len(drew),
+                     "block_drawn_dispatches_total": sum(drew)}
+    from ray_tpu.serve.llm import server
+
+    assert "block_drawn_dispatches_total" in server._get_llm_metrics(
+        engine.family_facts)
+    engine.close()
+
+
+# --------------------------- the head of a greedy pass, decided in its tiles
+def _plain_head(x, w):
+    """What the plain head and `_sample`'s greedy body keep of a row on the
+    chip: the product in float32 (XLA does not round it to bf16 on the way
+    to the float32 reductions), reduced in float32."""
+    logits = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                     precision="highest")
+    return (jnp.argmax(logits, axis=-1), jnp.max(logits, axis=-1),
+            jax.nn.logsumexp(logits, axis=-1))
+
+
+@pytest.mark.parametrize("values", ["integers", "normal"])
+@pytest.mark.parametrize("rows, h, v", [
+    (4, 64, 1000),      # one tile, and it is partial
+    (16, 64, 2500),     # three tiles of 1024, 452 columns in the last
+    (100, 128, 2374),   # rows that are no whole sublane tile
+    (256, 64, 1187 * 2),   # the cell's rows; 128 does not divide V
+    (64, 2048, 1152)])  # the cell's hidden width: a second tile of 128
+def test_head_kernel_keeps_what_the_plain_head_keeps(rows, h, v, values):
+    """The kernel in interpret mode against argmax / max / logsumexp of
+    the float32 product. "integers": operands of -3..3, so every product
+    is exact in float32 whatever the order of its sum, and equal logits
+    are common (the lowest index must win each); "normal": the sum's
+    order moves a logit in its last bits, so near-ties may fall either
+    way."""
+    rng = np.random.default_rng(rows + v)
+    if values == "integers":
+        x = jnp.asarray(rng.integers(-3, 4, (rows, h)), jnp.bfloat16)
+        w = jnp.asarray(rng.integers(-3, 4, (h, v)), jnp.bfloat16)
+    else:
+        x = jnp.asarray(rng.normal(size=(rows, h)), jnp.bfloat16)
+        w = jnp.asarray(rng.normal(size=(h, v)) * h ** -0.5, jnp.bfloat16)
+    arg, top, lse = head_op.head_argmax(x, w, impl="pallas_interpret")
+    want_arg, want_top, want_lse = _plain_head(x, w)
+    assert arg.dtype == jnp.int32 and arg.shape == (rows,)
+    if values == "integers":
+        np.testing.assert_array_equal(arg, want_arg)
+        np.testing.assert_array_equal(top, want_top)
+        np.testing.assert_allclose(lse, want_lse, atol=2e-5, rtol=2e-6)
+        # and the jnp form every other backend takes is the same function
+        # (float32 operands: a CPU's bf16 product is not float32's rounded)
+        for got, want in zip(
+                head_op.head_argmax(x.astype(jnp.float32),
+                                    w.astype(jnp.float32), impl="jnp"),
+                (want_arg, want_top, want_lse)):
+            np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-6)
+    else:
+        np.testing.assert_allclose(top, want_top, atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(lse, want_lse, atol=2e-5, rtol=2e-6)
+        assert (arg < v).all() and (arg == want_arg).mean() > 0.98
+
+
+@pytest.mark.parametrize("at", [
+    (5, 1029),          # two tiles, the same lane
+    (1029, 5),
+    (700, 1500),        # two tiles, other lanes
+    (133, 5),           # one tile, two of its 128-column chunks
+    (1100, 2300),       # the second tile and the partial third
+    (2499, 0),          # the vocabulary's last column and its first
+    (3, 4)])            # neighbours on one chunk
+def test_head_kernel_breaks_an_exact_tie_for_the_lowest_index(at):
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.integers(-2, 3, (8, 64)), jnp.bfloat16)
+    w = rng.integers(-1, 2, (64, 2500)).astype(np.float32)
+    # both columns are a row's own direction: the largest logit, twice
+    w[:, at[0]] = w[:, at[1]] = 3 * np.asarray(x[2], np.float32)
+    w = jnp.asarray(w, jnp.bfloat16)
+    arg, top, _ = head_op.head_argmax(x, w, impl="pallas_interpret")
+    want_arg, want_top, _ = _plain_head(x, w)
+    assert int(arg[2]) == min(at) == int(want_arg[2])
+    np.testing.assert_array_equal(arg, want_arg)
+    np.testing.assert_array_equal(top, want_top)
+
+
+def test_the_vocabulary_tile_fits_the_vmem_a_kernel_gets_unasked():
+    assert head_op.vocab_tile(256, 2048) == 1024       # the cell's shape
+    for rows, h, size in [(256, 2048, 2), (256, 4096, 2), (64, 8192, 2),
+                          (256, 2048, 4), (1024, 7168, 2)]:
+        tn = head_op.vocab_tile(rows, h, size)
+        assert tn % head_op.LANES == 0 and tn >= head_op.LANES
+        held = 2 * size * h * (tn + rows) + 4 * rows * tn
+        assert held <= 16 << 20 or tn == head_op.LANES, (rows, h, tn)
+
+
+def test_a_greedy_engine_on_the_kernel_emits_the_references_tokens(
+        monkeypatch):
+    """The block program with the kernel in it (interpret mode; a CPU
+    backend's programs take the jnp form), at a vocabulary of two tiles
+    that 128 does not divide: the reference's tokens in the reference's
+    order, and the program holds no [S, B, V] product outside the branch
+    that draws."""
+    monkeypatch.setattr(head_op, "_impl", lambda: "pallas_interpret")
+    engine = LLMEngine(EngineConfig(**{**CFG, "model_overrides": {
+        "vocab_size": 1100, "mask_token_id": 1099,
+        "remasking": "low_confidence_static"}}))
+    text = engine.program_text("block", engine._block_shape_key())
+    assert text.count("tensor<4x4x1100xf32>") > 0       # the drawn branch
+    assert "tensor<16x1100xf32>" not in text            # no greedy logits
+    prompts = _prompts((3, 41, 42))
+    for i, p in enumerate(prompts):
+        engine.add_request(f"k{i}", p, SamplingParams(max_tokens=9))
+    got = _by_request(_run(engine))
+    for i, p in enumerate(prompts):
+        tokens, passes = _reference_generate(engine, p, 9)[:2]
+        assert got[f"k{i}"]["tokens"] == tokens, i
+        assert got[f"k{i}"]["passes"] == passes, i
+    assert engine.stats()["block_drawn_dispatches_total"] == 0
+    engine.close()
 
 
 # ------------------------------------------- a settled block's keys, folded
