@@ -18,6 +18,13 @@ sliding layer also sees only the `sliding_window` keys up to its own
 `MoEMLP` (softmax over all experts in float32, the k best renormalised,
 dropless).
 
+What a layer's shapes are is asked of the config A KIND, and every answer
+defaults to this family's one: `heads(kind)` query heads, `rotary_dim(kind)`
+dims of a head rotated at `theta(kind)`, `attn_gate` (a sigmoid gate a query
+head and token on the attention output), `dense_ffn(i)` (layer i's FFN is
+models/llama.py's dense `MLP`, not the expert layer). models/laguna.py
+answers them otherwise and runs this stack as it is.
+
 The stack is one scan a RUN of like layers, the runs in sequence
 (models/minicpm_sala.py; parameters `run_<ii>/...` with a leading [run]
 axis, so a run's expert weights are one stack that the grouped matmul reads
@@ -56,7 +63,7 @@ from ..ops.paged_attention import (paged_attention_decode,
                                    window_prefill_attention)
 from ..ops.rotary import rotate, yarn_inv_freq
 from ..util import tracing
-from .llama import A, ExpertFacts, LlamaConfig, MoEMLP, RMSNorm
+from .llama import A, ExpertFacts, LlamaConfig, MLP, MoEMLP, RMSNorm
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # the family's interface flags (serve/llm/stage.py: model_family): a prefill
@@ -79,6 +86,8 @@ class MellumConfig(LlamaConfig):
     rope_beta_fast: float = 32.0
     rope_beta_slow: float = 1.0
     rope_attention_factor: float = 1.2772588722239782
+    # o_h <- sigmoid(u W_g)_h o_h, W_g [hidden, heads of the kind]
+    attn_gate: bool = False
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -97,16 +106,34 @@ class MellumConfig(LlamaConfig):
     def layers(self) -> Tuple[str, ...]:
         return self.layer_types[:self.num_layers]
 
+    # ---- a layer's shapes, a KIND (this family: one answer for both)
+    def heads(self, kind: str) -> int:
+        return self.num_heads
+
+    def rotary_dim(self, kind: str) -> int:
+        return self.head_dim_
+
+    def theta(self, kind: str) -> float:
+        return self.rope_theta
+
+    def dense_ffn(self, layer: int) -> bool:
+        return False
+
     @property
     def runs(self) -> Tuple[Tuple[str, int], ...]:
-        """((kind, how many), ...): the layers as runs of like layers."""
-        out = []
-        for kind in self.layers:
-            if out and out[-1][0] == kind:
-                out[-1][1] += 1
-            else:
-                out.append([kind, 1])
-        return tuple((k, n) for k, n in out)
+        """((kind, how many), ...): the layers as runs of like attention."""
+        return _runs_of(self.layers)
+
+    @property
+    def stack_runs(self) -> Tuple[Tuple[Tuple[str, bool], int], ...]:
+        """(((kind, whether the FFN is dense), how many), ...): the layers
+        as the runs the stack scans, like in attention AND in FFN."""
+        return _runs_of((kind, self.dense_ffn(i))
+                        for i, kind in enumerate(self.layers))
+
+    @property
+    def n_expert_layers(self) -> int:
+        return sum(not self.dense_ffn(i) for i in range(self.num_layers))
 
     @property
     def n_window_layers(self) -> int:
@@ -122,12 +149,14 @@ class MellumConfig(LlamaConfig):
         return self.n_window_layers
 
     def inv_freq(self, kind: str) -> np.ndarray:
-        d = self.head_dim_
+        """[rotary_dim(kind) // 2]: the frequencies' `dim` is the ROTATED
+        dims' count."""
+        d, theta = self.rotary_dim(kind), self.theta(kind)
         if kind == FULL:
-            return yarn_inv_freq(d, self.rope_theta, self.rope_factor,
+            return yarn_inv_freq(d, theta, self.rope_factor,
                                  self.rope_original_max, self.rope_beta_fast,
                                  self.rope_beta_slow)
-        return (self.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+        return (theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
                 ).astype(np.float32)
 
     def active_params(self) -> int:
@@ -135,6 +164,16 @@ class MellumConfig(LlamaConfig):
         computes it at one position)."""
         return (super().active_params()
                 - 2 * self.vocab_size * self.hidden_size)
+
+
+def _runs_of(keys) -> tuple:
+    out = []
+    for key in keys:
+        if out and out[-1][0] == key:
+            out[-1][1] += 1
+        else:
+            out.append([key, 1])
+    return tuple((k, n) for k, n in out)
 
 
 def attention_kinds(cfg: MellumConfig) -> tuple:
@@ -223,9 +262,11 @@ class WindowFacts:
     """What the two kinds of attention layer count (serve/llm/stage.py:
     model_family), all a LAYER of the kind, from the rows' lengths alone
     (nothing is fetched for it). Every record says `window_layers` and
-    `full_layers`; and of its real rows `window_tokens_read` / `full_tokens_read`
-    (keys the kind's attention read: a decode step a full layer the row's
-    context and a sliding layer min(context, window); a prefill pass its
+    `full_layers`, and the query heads a layer of each kind has
+    (`window_heads`, `full_heads`); and of its real rows
+    `window_tokens_read` / `full_tokens_read` (keys the kind's attention
+    read: a decode step a full layer the row's context and a sliding layer
+    min(context, window); a prefill pass its
     own tokens and what it resumes behind, for a sliding layer at most the
     window) and `window_tokens_held` / `full_tokens_held` (what the row's
     rings and pages hold when the dispatch starts its last step)."""
@@ -243,7 +284,9 @@ class WindowFacts:
     def __init__(self, cfg: MellumConfig):
         self.window = cfg.sliding_window
         self.constant = {"window_layers": cfg.n_window_layers,
-                         "full_layers": cfg.n_full_layers}
+                         "full_layers": cfg.n_full_layers,
+                         "window_heads": cfg.heads(SLIDING),
+                         "full_heads": cfg.heads(FULL)}
 
     def _released(self, totals: dict, before: int, after: int) -> None:
         totals["kv_window_tokens_released_total"] += (
@@ -329,6 +372,14 @@ def _dense(cfg, features, axes, name):
         kernel_init=A(nn.initializers.lecun_normal(), axes), name=name)
 
 
+def head_gate(o, x, w_g):
+    """o [B, S, H, D] x sigmoid(x W_g) [B, S, H], one scalar a query head
+    and token, its logits and the product in float32."""
+    g = jax.nn.sigmoid(jnp.einsum("bsh,hn->bsn", x, w_g,
+                                  preferred_element_type=jnp.float32))
+    return (o.astype(jnp.float32) * g[..., None]).astype(o.dtype)
+
+
 class MixedAttention(nn.Module):
     """One attention layer of either kind; `layer` is its index among the
     layers of its KIND (into `kv_pages` or `win_pages`)."""
@@ -342,14 +393,16 @@ class MixedAttention(nn.Module):
                  total_lens, slots, layer):
         cfg = self.config
         b, s, _ = x.shape
-        nq, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+        nq, nkv, d = cfg.heads(self.kind), cfg.num_kv_heads, cfg.head_dim_
         qkv = _dense(cfg, (nq + 2 * nkv) * d, ("embed", "qkv"), "qkv_proj")(x)
         q, k, v = jnp.split(qkv, [nq * d, (nq + nkv) * d], axis=-1)
         full = self.kind == FULL
         factor = cfg.rope_attention_factor if full else 1.0
-        inv_freq = cfg.inv_freq(self.kind)
-        q = rotate(q.reshape(b, s, nq, d), positions, inv_freq, factor)
-        k = rotate(k.reshape(b, s, nkv, d), positions, inv_freq, factor)
+        inv_freq, turned = cfg.inv_freq(self.kind), cfg.rotary_dim(self.kind)
+        q = rotate(q.reshape(b, s, nq, d), positions, inv_freq, factor,
+                   turned)
+        k = rotate(k.reshape(b, s, nkv, d), positions, inv_freq, factor,
+                   turned)
         v = v.reshape(b, s, nkv, d)
         impl = "reference" if self.ref_attention else None
         if full:
@@ -380,6 +433,13 @@ class MixedAttention(nn.Module):
                     layer=layer)
                 win_pages = ring_write(win_pages, k, v, slots, positions,
                                        total_lens, layer, rp)
+        if cfg.attn_gate:
+            w_g = self.param(
+                "gate_proj", A(nn.initializers.lecun_normal(),
+                               ("embed", "heads")),
+                (cfg.hidden_size, nq), cfg.param_dtype)
+            with tracing.scope("rtpu.attn.gate"):
+                o = head_gate(o.reshape(b, s, nq, d), x, w_g.astype(cfg.dtype))
         out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(
             o.reshape(b, s, nq * d))
         return out, kv_pages, win_pages
@@ -396,6 +456,7 @@ class MellumLayer(nn.Module):
     kind: str
     ctx_pages: int
     ref_attention: bool
+    dense: bool = False
 
     @nn.compact
     def __call__(self, carry, xs, consts):
@@ -411,6 +472,9 @@ class MellumLayer(nn.Module):
             pool_idx)
         x = x + h
         normed = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="mlp_norm")(x)
+        if self.dense:
+            return (x + MLP(cfg, name="mlp")(normed), kv_pages,
+                    win_pages), None
         moe = MoEMLP(cfg, name="moe")
         h = moe(normed, token_mask,
                 None if experts is None else experts + (run_idx,))
@@ -419,9 +483,13 @@ class MellumLayer(nn.Module):
             # [B, S, 1, E] bool, the experts each token chose, for a caller
             # that asks for the "selection" collection (the benchmark's
             # check); nobody else pays for it
-            probs = jax.nn.softmax(jnp.einsum(
-                "bsh,he->bse", normed.astype(jnp.float32),
-                nn.meta.unbox(moe.variables["params"]["router"])), axis=-1)
+            held = nn.meta.unbox(moe.variables["params"])
+            logits = jnp.einsum("bsh,he->bse", normed.astype(jnp.float32),
+                                held["router"])
+            if cfg.moe_scoring == "sigmoid":
+                probs = jax.nn.sigmoid(logits) + held["router_bias"]
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)
             _, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
             self.sow("selection", "chosen", (
                 idx[..., None] == jnp.arange(cfg.num_experts)).any(-2)[
@@ -472,16 +540,17 @@ class MellumModel(nn.Module):
 
         carry = (x, cache.kv_pages, cache.win_pages)
         at = {SLIDING: 0, FULL: 0}
-        for r, (kind, n) in enumerate(cfg.runs):
+        for r, ((kind, dense), n) in enumerate(cfg.stack_runs):
             name = f"run_{r:02d}"
             experts = None
-            if kv_caches is not None and not self.is_initializing():
+            if (not dense and kv_caches is not None
+                    and not self.is_initializing()):
                 moe = nn.meta.unbox(self.get_variable("params", name))["moe"]
                 experts = (moe["experts_gate_up"].astype(cfg.dtype),
                            moe["experts_down"].astype(cfg.dtype))
             consts = (positions, cache.block_tables, cache.total_lens, slots,
                       token_mask, experts)
-            carry, _ = _run(cfg, n, name, kind=kind,
+            carry, _ = _run(cfg, n, name, kind=kind, dense=dense,
                             ctx_pages=cache.ctx_pages,
                             ref_attention=cache.ref_attention)(
                 carry, (at[kind] + jnp.arange(n), jnp.arange(n)), consts)

@@ -17,6 +17,7 @@ that layout).
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -53,18 +54,24 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 
 def rotate(x: jax.Array, positions: jax.Array, inv_freq,
-           factor: float = 1.0) -> jax.Array:
-    """x [B, S, H, D] turned by `positions` [B, S] x `inv_freq` [D // 2]
-    (`models/llama.py: rope` with the frequencies given). `factor`
-    multiplies cos and sin (YaRN's `attention_factor` where a model applies
-    it to the rotation: a score of two rotated vectors carries its
-    square)."""
+           factor: float = 1.0, rotary_dim: Optional[int] = None) -> jax.Array:
+    """x [B, S, H, D] turned by `positions` [B, S] x `inv_freq`
+    [rotary_dim // 2] (`models/llama.py: rope` with the frequencies given).
+    `factor` multiplies cos and sin (YaRN's `attention_factor` where a
+    model applies it to the rotation: a score of two rotated vectors
+    carries its square). `rotary_dim` < D: only the head's FIRST
+    `rotary_dim` dims turn (dims i and i + rotary_dim/2 together), the
+    rest pass through untouched and unscaled (a `partial_rotary_factor`)."""
     angles = positions[..., None].astype(jnp.float32) * jnp.asarray(
         inv_freq, jnp.float32)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
     if factor != 1.0:
         cos, sin = cos * factor, sin * factor
+    rest = None
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        x, rest = x[..., :rotary_dim], x[..., rotary_dim:]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    out = out.astype(x.dtype)
+    return out if rest is None else jnp.concatenate([out, rest], axis=-1)

@@ -366,9 +366,20 @@ _BALANCE_TOKENS = 240
 _PAIR_PARAMS = 375
 
 
-# a model's kinds of attention layer, (layers, window or None) each: one
+# a model's kinds of attention layer, (layers, window or None) each, and
+# (layers, window or None, query heads) where the kinds' heads differ: one
 # kind, whole contexts, unless the family says otherwise (`attention_kinds`)
 _ONE_KIND = ((1, None),)
+
+
+def _kind_shares(kinds: tuple) -> tuple:
+    """((share, window or None), ...) for `PassCost`: each kind's share of
+    the model's (layer, head) pairs, which is its share of the layers
+    where the kinds have one head count."""
+    weights = [layers * (heads[0] if heads else 1)
+               for layers, _, *heads in kinds]
+    total = sum(weights)
+    return tuple((w / total, kind[1]) for w, kind in zip(weights, kinds))
 
 
 def _attn_visits(bucket: int, width: int, real=None, ctx=None,
@@ -382,7 +393,7 @@ def _attn_visits(bucket: int, width: int, real=None, ctx=None,
     return tuple(map(sum, zip(*(
         [layers * n for n in prefill_block_visits(
             bucket, width, real, ctx, window)]
-        for layers, window in kinds))))
+        for layers, window, *_ in kinds))))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -406,10 +417,12 @@ class PassCost:
       prefix): one `floor` more, the bar a split has to clear (the
       dispatch, a second weight read, the gather of the table's width).
 
-    `kinds`: the model's kinds of attention layer as SHARES of its layers,
-    ((share, window or None), ...): a pair of a layer with a sliding window
-    is not a pair of a full one (a pass behind 16k tokens of context makes
-    a sixteenth of them there), so the pairs are each kind's own."""
+    `kinds`: the model's kinds of attention layer as SHARES of its (layer,
+    head) pairs, ((share, window or None), ...) (`_kind_shares`): a pair of
+    a layer with a sliding window is not a pair of a full one (a pass
+    behind 16k tokens of context makes a sixteenth of them there), so the
+    pairs are each kind's own, and a kind with more query heads makes more
+    of them a layer."""
     floor: float
     pair: float
     kinds: tuple = _ONE_KIND
@@ -637,11 +650,10 @@ class LLMEngine:
             family, "attention_kinds") else _ONE_KIND)
         if self._resumes:
             weights, scores = family.pass_cost_ratios(cfg_m)
-            layers = sum(n for n, _ in self._attn_kinds)
             self._pass_cost = PassCost(
                 floor=_BALANCE_TOKENS * weights,
                 pair=_PAIR_PARAMS * scores,
-                kinds=tuple((n / layers, w) for n, w in self._attn_kinds))
+                kinds=_kind_shares(self._attn_kinds))
         self._head_at_gather = getattr(family, "HEAD_AT_GATHER", False)
         self._queue_wait_ns_total = 0
         # the device's timeline as the host can stamp it (_device_stamps):

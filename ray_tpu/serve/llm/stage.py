@@ -113,14 +113,17 @@ def serve_dtype(config):
 
 def model_family(name: str):
     """The module that defines the preset `name`: the one place that
-    chooses a model family from `EngineConfig.model`. Seven families (each
+    chooses a model family from `EngineConfig.model`. Eight families (each
     module says what it is): models/llama.py, jamba.py, minicpm_sala.py,
     sdar.py (its config has `block_length` and the engine steps it with the
     "block" program), kimi.py (its config has `latent_lanes`), mellum.py
     (two kinds of attention layer: it also answers `attention_kinds(cfg)`,
     ((layers, window or None), ...), for the engine's pass cost and its
     counters of the flash kernel's visits), gigachat.py (gated-delta-net
-    layers beside latent attention: three kinds of state in its pool).
+    layers beside latent attention: three kinds of state in its pool),
+    laguna.py (Mellum's stack and cache with the shapes a kind: its
+    `attention_kinds(cfg)` is ((layers, window or None, query heads), ...),
+    so that a pass is priced by each kind's (layer, head) pairs).
 
     This is the one description of what a family's module provides:
     `CONFIGS`, `get_config`, `serving_model`, `pool_spec`, `serving_cache`;
@@ -154,10 +157,11 @@ def model_family(name: str):
       blocks); engine.py's `refuse` raises it by name. `prefix_reuse` is no
       option: where a family names it, the engine matches no page by its
       hash, counts what it refused and says why in `stats()`."""
-    from ...models import (gigachat, jamba, kimi, llama, mellum,
+    from ...models import (gigachat, jamba, kimi, laguna, llama, mellum,
                            minicpm_sala, sdar)
 
-    families = (llama, jamba, minicpm_sala, sdar, kimi, mellum, gigachat)
+    families = (llama, jamba, minicpm_sala, sdar, kimi, mellum, gigachat,
+                laguna)
     for family in families:
         if name in family.CONFIGS:
             return family

@@ -190,6 +190,10 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         "mla_layers", "latent_bytes_token", "mla_ctx_chunks",
         "moe_assignments_routed",
         "enqueued_ns", "device_start_ns", "device_end_ns", "end_exact",
+        # behind the stamps, as the next family's: the query heads a layer
+        # of each kind of a two-kind stack has (models/mellum.py:
+        # WindowFacts; they differ in models/laguna.py)
+        "window_heads", "full_heads",
         # a family that came after the stamps (models/gigachat.py): every
         # field before these is held to its place by hand-made records
         "gdn_layers", "gdn_state_bytes_row",
@@ -341,6 +345,9 @@ SCOPES: Tuple[str, ...] = (
     # what an attention layer moves that is no kernel: page, ring, latent
     # and per-slot state writes, the gathers that feed a kernel
     "rtpu.attn.cache_write",
+    # models/mellum.py: a per-head output gate's projection and its
+    # product with the heads (models/laguna.py has one)
+    "rtpu.attn.gate",
     # parallel/train_lib.py: _step
     "rtpu.loss",           # the forward under value_and_grad
     "rtpu.optimizer",      # tx.update, apply_updates, global_norm

@@ -260,6 +260,18 @@ COMPILES = {
     "decode-llama1b-B8-D64-MP32": _paged_decode(8, 64, 32),
     "decode-8b-B8-D128-MP512": _paged_decode(8, 128, 512),
     "decode-7b-B32-D128-MP168": _paged_decode(32, 128, 168),
+    # `laguna-xs2-agentturns`: a full layer's 48 query heads on 8 kv heads,
+    # a group of SIX rows (no whole 8-row sublane tile), 64 slots behind a
+    # 272-page table of 64-token pages; the same group through the flash
+    # forward, own tokens and a context, at the largest bucket and the
+    # smallest (`_fold(6, 512)` is 1: a grid step is one query head)
+    "decode-laguna-full-B64-rep6-MP272": _paged_decode(
+        64, 128, 272, hq=48, hkv=8, page=64, layers=2),
+    "fwd-lse-lens-laguna-own-4096-rep6": _ctx_lens(4096, 4096, 48, 8,
+                                                   causal=True),
+    "fwd-lse-lens-laguna-ctx-4096-rep6": _ctx_lens(4096, 8192, 48, 8),
+    "fwd-lse-lens-laguna-own-512-rep6": _ctx_lens(512, 512, 48, 8,
+                                                  causal=True),
     "fwdbwd-shard_map-2x2-mesh": _flash_on_mesh,
     # the one-pass backward asks for the VMEM its shapes need (PR 44), so it
     # compiles as far as the forward does: these were refused at 8192
@@ -281,6 +293,10 @@ COMPILES = {
     "moe-gmm-mellum2-decode-M512": _moe_gmm(512, 64, 2304, 896, 8),
     "moe-gmm-mellum2-pass4096-M32768": _moe_gmm(32768, 64, 2304, 896, 8),
     "moe-gmm-sdar-block-M2048": _moe_gmm(2048, 128, 2048, 768, 6),
+    # `laguna-xs2-agentturns`: a decode step's 64 slots x 8 on 256 experts
+    # of (2048, 512), and one 4096-row block of a pass
+    "moe-gmm-laguna-decode-M512": _moe_gmm(512, 256, 2048, 512, 4),
+    "moe-gmm-laguna-pass4096-M32768": _moe_gmm(32768, 256, 2048, 512, 4),
     # the same block step's head, decided in its vocabulary tiles: 64 slots
     # x 4 positions on [2048, 151936], 148 tiles of 1024 columns and one of
     # 384 (128 x 1187 has no larger tile that divides it)
@@ -918,6 +934,40 @@ def test_kimi_program_fits_and_carries_its_pool_in_place(
 MELLUM_PAGE, MELLUM_PAGES, MELLUM_LEN = 64, 16384, 33792
 
 
+def _compile_two_kind_program(topo, engine_cfg: dict, layers: int,
+                              pages: int, kind: str, key):
+    """A shape-only engine of a family with pages and rings (models/
+    mellum.py, models/laguna.py), its `kind` program compiled for the
+    described chip over a pool of `pages` pages -> (compiled, the pool's
+    spec, the program's key)."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    from ray_tpu.serve.llm.stage import init_params
+
+    cfg = EngineConfig(**engine_cfg)
+    engine = LLMEngine(cfg, params={})
+    stage = engine.compute
+    stage.params = jax.eval_shape(lambda: init_params(
+        stage.model, jnp.zeros((1, 8), jnp.int32), jax.random.PRNGKey(0)))
+    spec = stage.family.pool_spec(stage.model_cfg, layers, pages,
+                                  cfg.page_size, cfg.max_batch)
+    stage.kv_pages = {k: jax.ShapeDtypeStruct(*sd) for k, sd in spec.items()}
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(a):
+        a = a if hasattr(a, "shape") else np.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    key = (engine._decode_shape_key() if kind == "decode"
+           else (key[0], engine._wave_rb, key[1]))
+    with pytest.MonkeyPatch.context() as mp_ctx:
+        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = stage.program(kind, key).lower(*jax.tree.map(
+            sds, (*stage._state(kind), *stage.dummy_args(kind, key)),
+            is_leaf=lambda a: isinstance(a, jax.ShapeDtypeStruct))
+        ).compile()
+    return compiled, spec, key
+
+
 @pytest.mark.parametrize("kind, key", [
     ("decode", None), ("prefill", (4096, MELLUM_LEN // MELLUM_PAGE))])
 def test_mellum_program_fits_and_carries_both_pools_in_place(
@@ -934,38 +984,16 @@ def test_mellum_program_fits_and_carries_both_pools_in_place(
     layers' flash calls (`_window_flash`: own tokens, then the ring) apart
     from its full layers'; both parts of the pool are aliased from argument
     to result."""
-    from ray_tpu.serve.llm import EngineConfig, LLMEngine
-    from ray_tpu.serve.llm.stage import init_params
-
-    cfg = EngineConfig(
+    cfg = dict(
         model="mellum2-12b-a2.5b", dtype="bfloat16", page_size=MELLUM_PAGE,
         num_pages=64, max_model_len=MELLUM_LEN, max_batch=64,
         prefill_buckets=(256, 512, 1024, 2048, 4096),
         model_overrides=dict(num_layers=8))
-    engine = LLMEngine(cfg, params={})
-    stage = engine.compute
-    stage.params = jax.eval_shape(lambda: init_params(
-        stage.model, jnp.zeros((1, 8), jnp.int32), jax.random.PRNGKey(0)))
-    spec = stage.family.pool_spec(stage.model_cfg, 8, MELLUM_PAGES,
-                                  MELLUM_PAGE, 64)
+    compiled, spec, key = _compile_two_kind_program(
+        topo, cfg, 8, MELLUM_PAGES, kind, key)
     assert spec["kv_pages"][0] == (2, MELLUM_PAGES, 4, MELLUM_PAGE, 256)
     assert spec["win_pages"][0] == (6, 64 * 1024 // MELLUM_PAGE, 4,
                                     MELLUM_PAGE, 256)
-    stage.kv_pages = {k: jax.ShapeDtypeStruct(*sd) for k, sd in spec.items()}
-    one_chip = SingleDeviceSharding(topo.devices[0])
-
-    def sds(a):
-        a = a if hasattr(a, "shape") else np.asarray(a)
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-
-    key = (engine._decode_shape_key() if kind == "decode"
-           else (key[0], engine._wave_rb, key[1]))
-    with pytest.MonkeyPatch.context() as mp_ctx:
-        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
-        compiled = stage.program(kind, key).lower(*jax.tree.map(
-            sds, (*stage._state(kind), *stage.dummy_args(kind, key)),
-            is_leaf=lambda a: isinstance(a, jax.ShapeDtypeStruct))
-        ).compile()
     mem, text = compiled.memory_analysis(), compiled.as_text()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
@@ -985,6 +1013,67 @@ def test_mellum_program_fits_and_carries_both_pools_in_place(
             2 if key[2] else 1), kernels
     # neither part of the pool is copied whole: the rings are gathered a
     # slot's rows and scattered back a slot's pages, as the pages are
+    whole = {sd[0] for sd in spec.values()}
+    copied = [line.strip()[:160] for line in text.splitlines()
+              if (m := re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) copy\(",
+                                line))
+              and any(dims in whole for dims, _ in _array_types(m.group(1)))]
+    assert not copied, "\n".join(copied)
+    header = text.split("\n", 1)[0]
+    aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliased) >= (3 if kind == "decode" else 2), header[:400]
+
+
+# ------ the same stack with the shapes a kind: 48 and 64 heads, a dense layer
+LAGUNA_PAGE, LAGUNA_PAGES, LAGUNA_LEN = 64, 8192, 17408
+
+
+@pytest.mark.parametrize("kind, key", [
+    ("decode", None), ("prefill", (4096, LAGUNA_LEN // LAGUNA_PAGE)),
+    ("prefill", (512, 0))])
+def test_laguna_program_fits_and_carries_both_pools_in_place(
+        topo, no_persistent_cache, kind, key):
+    """Laguna-XS.2 at its published widths as the cell
+    `laguna-xs2-agentturns` runs it: layers 0-4 (full attention + the dense
+    FFN, three sliding layers, a full one; 48 and 64 query heads on 8 kv
+    heads), all 256 experts beside the shared one, the whole vocabulary,
+    64 slots: the two full layers' pages `[2, 8192, 8, 64, 256]` (524k
+    tokens, 4.29 GB) beside the three sliding layers' rings `[3, 64 x 8, 8,
+    64, 256]` (0.40 GB whatever the contexts). The decode program runs the
+    paged kernel at a group of 6 (`_decode_call`) and of 8
+    (`_window_decode`); a resumed `[1 x 4096]` pass behind a 17,408-token
+    table fits the chip beside 7.74 GB of weights; both parts of the pool
+    are aliased from argument to result and neither is copied whole."""
+    cfg = dict(
+        model="laguna-xs.2", dtype="bfloat16", page_size=LAGUNA_PAGE,
+        num_pages=64, max_model_len=LAGUNA_LEN, max_batch=64,
+        prefill_buckets=(512, 1024, 2048, 4096),
+        model_overrides=dict(num_layers=5))
+    compiled, spec, key = _compile_two_kind_program(
+        topo, cfg, 5, LAGUNA_PAGES, kind, key)
+    assert spec["kv_pages"][0] == (2, LAGUNA_PAGES, 8, LAGUNA_PAGE, 256)
+    assert spec["win_pages"][0] == (3, 64 * 512 // LAGUNA_PAGE, 8,
+                                    LAGUNA_PAGE, 256)
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(kind, key, "GiB", total / 2 ** 30, "temp",
+          mem.temp_size_in_bytes / 2 ** 30)
+    # 7.74 GB of weights + 4.29 GB of pages + 0.40 GB of rings
+    assert 11.5 * 2 ** 30 <= total <= HBM_GIB * 2 ** 30, total / 2 ** 30
+    kernels = set(re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
+    names = sorted({k.split(".")[0] for k in kernels})
+    if kind == "decode":
+        assert names == ["_decode_call", "_moe_gmm", "_window_decode"], kernels
+    else:
+        assert "_window_flash" in names and "_moe_gmm" in names, kernels
+        # ONE sliding run's scan body: the own-tokens call, and the ring's
+        # where the pass resumes
+        assert sum(k.startswith("_window_flash") for k in kernels) == (
+            2 if key[2] else 1), kernels
+    # the gate's product is the program's own name for it
+    scopes = set(re.findall(r'op_name="[^"]*?(rtpu\.[\w.]+)', text))
+    assert "rtpu.attn.gate" in scopes, sorted(scopes)
     whole = {sd[0] for sd in spec.values()}
     copied = [line.strip()[:160] for line in text.splitlines()
               if (m := re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) copy\(",

@@ -893,6 +893,10 @@ FAMILY_FIELDS = {
     "tiny-kimi": (_MOE | _MLA, {"prefill": {"mla_ctx_chunks"}}),
     "tiny-gigachat": (_MOE | _MLA | {"gdn_layers", "gdn_state_bytes_row"},
                       {"prefill": {"mla_ctx_chunks"}}),
+    "tiny-laguna": (_MOE | {"window_layers", "full_layers", "window_heads",
+                            "full_heads", "window_tokens_read",
+                            "full_tokens_read", "window_tokens_held",
+                            "full_tokens_held"}, {}),
 }
 
 

@@ -257,6 +257,10 @@ FOLD_CASES = {
     "4-of-8-at-128": (8, 1, 128, 256, 128, 4),
     "3-of-3-at-128": (6, 2, 128, 256, 128, 3),
     "1-of-5-at-128": (5, 1, 128, 256, 128, 1),
+    # a group of SIX (models/laguna.py's full layers): divisors 1, 2, 3, 6
+    "3-of-6-at-128": (12, 2, 128, 256, 128, 3),
+    "2-of-6-at-256": (12, 2, 256, 384, 256, 2),
+    "1-of-6-at-512": (12, 2, 512, 512, 512, 1),
     "1-of-4-at-384": (8, 2, 384, 384, 384, 1),
     "1-of-4-at-512": (8, 2, 512, 512, 512, 1),
     "mha-at-128": (2, 2, 128, 256, 128, 1),

@@ -202,6 +202,50 @@ def test_decode_kernel_matches_reference(hq, hkv, pages_per_chunk, layer,
     _assert_rows_match(got, want, lengths, dtype)
 
 
+@pytest.mark.parametrize("ring", [False, True], ids=["pages", "ring"])
+@pytest.mark.parametrize("hq,hkv", [(6, 2), (12, 2), (48, 8)],
+                         ids=["rep3", "rep6", "rep6-of-8-kv"])
+@pytest.mark.parametrize("form", ["d128-page16-f32", "d128-page16-bf16"])
+def test_decode_kernel_at_a_group_that_is_no_sublane_tile(form, hq, hkv,
+                                                          ring):
+    """Three and six query heads a kv head (models/laguna.py's full layers
+    are 48 on 8): the kernel's state is `[Hkv, rep, .]` and `rep` rows are
+    no whole 8-row tile; through the block table, and through a slot's ring
+    under the window's name and length rule."""
+    from ray_tpu.ops.paged_attention import (ring_tables,
+                                             window_attention_decode)
+
+    rng = np.random.default_rng(hq)
+    d, page, dtype = FORMS[form]
+    b, mp, layer = 4, 4, 1
+    lengths = [1, 2 * page + 3, 0, mp * page]
+    q = jnp.asarray(rng.standard_normal((b, hq, d)), dtype)
+    if ring:
+        # slot i's ring is pages [i * mp, (i + 1) * mp): a window of mp
+        # pages, read at min(length, window)
+        pool = jnp.asarray(rng.standard_normal(
+            (N_LAYERS, b * mp, hkv, page, 2 * d)), dtype)
+        lens = jnp.asarray([1, 2 * page + 3, 0, 9 * page], jnp.int32)
+        got = window_attention_decode(q, pool, lens, ring_pages=mp,
+                                      layer=layer, interpret=True)
+        held = jnp.minimum(lens, mp * page)
+        want = paged_attention_reference(
+            q[:, None], pool[layer], ring_tables(jnp.arange(b), mp),
+            jnp.maximum(held - 1, 0)[:, None])[:, 0]
+        _assert_rows_match(got, want, np.asarray(held), dtype)
+        return
+    kv_pages, bt, _, _, lens = _make_pages(
+        rng, b=b, hkv=hkv, d=d, page=page, num_pages=40, mp=mp,
+        lengths=lengths, layer=layer)
+    kv_pages = kv_pages.astype(dtype)
+    got = paged_attention_decode(q, kv_pages, bt, lens, layer=layer,
+                                 pages_per_chunk=3, interpret=True)
+    want = paged_attention_reference(
+        q[:, None], kv_pages[layer], bt,
+        jnp.maximum(lens - 1, 0)[:, None])[:, 0]
+    _assert_rows_match(got, want, lengths, dtype)
+
+
 @pytest.mark.parametrize("pages_per_chunk", [2, 4])
 @pytest.mark.parametrize("form", [f for f in FORMS if "page16" in f])
 def test_decode_kernel_never_reads_past_a_length(form, pages_per_chunk):
