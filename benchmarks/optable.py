@@ -1,5 +1,6 @@
 """One traced run of a benchmark cell (needs a chip), then the op table of
-the median decode, prefill and block program of its span: self time by op
+the median decode, prefill and block program of its span (of the pretrain
+cell's: the median train step): self time by op
 name, count, in order of first start, each op with the scope the program
 gives its instruction (`LLMEngine.program_scopes`, through
 `chipbench/scoped.py`), and the program's roll-up by scope above it. What
@@ -32,6 +33,10 @@ try:
     from chipbench import scoped
 except ImportError:
     scoped = None
+
+# the kinds of program a span can hold (`chipbench/scoped.py`); the
+# trainer's step has no `jit_run_train(` module event: by scope only
+KINDS = ("decode", "prefill", "block", "train")
 
 # the runner, for its engine's tables (as benchmarks/flightrecords.py
 # reaches it)
@@ -77,7 +82,7 @@ def median_by_scope(kind):
 
 os.makedirs(os.path.dirname(out_path), exist_ok=True)
 with open(out_path, "w") as f:
-    for kind in ("decode", "prefill", "block"):
+    for kind in KINDS:
         got = median_by_scope(kind) or median_by_module(kind)
         if got is None:
             continue
@@ -114,7 +119,7 @@ with open(out_path, "w") as f:
                     f"{first / 1e6:8.3f} ms  {op}{where}\n")
     if scoped is not None:
         # every whole program of a kind, as the per-layer readers sum them
-        for kind in ("decode", "prefill", "block"):
+        for kind in KINDS:
             t = scoped.table(ctx, [kind])
             if t is not None:
                 f.write("\n".join(scoped.rollup_lines(t, kind)) + "\n")

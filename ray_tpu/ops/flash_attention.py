@@ -79,6 +79,7 @@ from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -588,10 +589,21 @@ def _flash(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
     return o
 
 
+# The forward kernel's outputs, by the names a remat policy can list
+# (`jax.checkpoint_policies.save_only_these_names`): a policy that lists
+# them runs the forward kernel once a step and not again inside the
+# backward (models/llama.py: `_remat_policy`); under one that does not, a
+# name is the identity. The names sit on the kernel's own outputs, inside
+# the forward rule: naming the caller's `o` would keep `o` and still run
+# the kernel again for `lse`.
+SAVED_OUTPUTS = ("flash_o", "flash_lse")
+
+
 def _flash_fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
                interpret, sq, sk):
-    o, lse = _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
-                  interpret, sq, sk)
+    o, lse = map(checkpoint_name, _fwd(
+        q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
+        interpret, sq, sk), SAVED_OUTPUTS)
     return o, (q, k, v, q_seg, kv_seg, o, lse)
 
 

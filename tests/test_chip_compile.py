@@ -338,19 +338,26 @@ def test_kernel_compiles_for_v5e(topo, no_persistent_cache, name):
         assert "scoped_memory_configs" not in lowered.as_text()
 
 
-def test_the_trainers_step_runs_three_kernels_a_layer(topo):
+@pytest.mark.parametrize("policy, kernels", [("dots", 2), ("nothing", 3)])
+def test_the_trainers_step_runs_two_kernels_a_layer(topo, policy, kernels):
     """`ShardedTrainer`'s step program for a described chip, the pretrain
     cell's form (scanned layers, remat under the `dots` policy) at tiny
-    widths: a layer body's forward, its recomputed forward and its backward
-    are ONE `tpu_custom_call` each. Before PR 44 the backward was two (dq;
-    dk/dv), four a layer: the count is the evidence that the one-pass
-    backward is the kernel that runs."""
+    widths: a layer body's forward and its backward are ONE
+    `tpu_custom_call` each. Until PR 57 there was a third, the forward
+    recomputed inside the backward scan: `dots` saved products only, and
+    the kernel's `o` and `lse` are none. The forward rule now names them
+    (`ops/flash_attention.py: SAVED_OUTPUTS`) and `"dots"` lists the names
+    (`models/llama.py: _remat_policy`), so the backward reads what the
+    forward left and the recomputed call is dead: the count is the evidence
+    that the saved outputs are the ones the backward reads. `"nothing"`
+    lists no name and still runs three. Before PR 44 the backward was two
+    kernels (dq; dk/dv), four a layer."""
     import flax.linen as nn
 
     from ray_tpu.models.llama import LlamaModel, get_config
     from ray_tpu.parallel.train_lib import ShardedTrainer, TrainState
 
-    cfg = get_config("tiny", remat=True, remat_policy="dots")
+    cfg = get_config("tiny", remat=True, remat_policy=policy)
     assert cfg.scan_layers
     mesh = Mesh(np.asarray(topo.devices[:1]).reshape((1,) * len(AXES)), AXES)
     trainer = ShardedTrainer(LlamaModel(cfg), mesh)
@@ -371,7 +378,8 @@ def test_the_trainers_step_runs_three_kernels_a_layer(topo):
     with pytest.MonkeyPatch.context() as mp_ctx:
         mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
         text = trainer.program_text(state, batch)
-    assert text.count("tpu_custom_call") == 3, text.count("tpu_custom_call")
+    assert text.count("tpu_custom_call") == kernels, \
+        text.count("tpu_custom_call")
 
 
 # ------------------------------------------- the engine's own programs

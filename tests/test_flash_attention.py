@@ -532,7 +532,7 @@ def _jaxpr_sha(fn, *args):
 
 
 @pytest.mark.parametrize("case, sha", [
-    ("plain", "77c1c24c3392e9c0"), ("packed", "185f042aeedeb177"),
+    ("plain", "13ebb5a15f4d6fa9"), ("packed", "c43460414616763e"),
     ("lse", "afaa56b993c2c7a3")])
 def test_the_trainers_call_traces_to_the_jaxpr_it_had(case, sha):
     """The calls that pass no lengths, forward and backward kernels at the
@@ -547,8 +547,12 @@ def test_the_trainers_call_traces_to_the_jaxpr_it_had(case, sha):
     Within PR 45 they stood at 0c165bf59ecf9aa4 / 1051b2069a70a24d /
     102e820fa94da2f4 before the review's round: the trip counts were Python
     integers where nothing traced decided them, and a grid step had no
-    head dimension to fold (`_fold`: 1 at these shapes). A deliberate change
-    of the kernels reads them anew."""
+    head dimension to fold (`_fold`: 1 at these shapes). PR 57 read `plain`
+    and `packed` anew (77c1c24c3392e9c0 and 185f042aeedeb177 until then):
+    the forward rule names `o` and `lse` for a remat policy to list (two
+    `name` equations; the kernels' calls are to the character what they
+    were), and `lse`, which never meets the forward rule, did not move. A
+    deliberate change of the kernels reads them anew."""
     q = jnp.zeros((2, 2048, 32, 128), jnp.bfloat16)
     k = v = jnp.zeros((2, 2048, 8, 128), jnp.bfloat16)
 
@@ -681,3 +685,48 @@ def test_a_window_none_is_the_call_it_was_and_the_backward_refuses_one():
     with pytest.raises(ValueError, match="backward kernel has no band"):
         jax.grad(lambda q: flash_attention(
             q, k, v, window=64, interpret=True).sum())(q)
+
+
+# ------------------------------------------- what a remat policy keeps
+@pytest.mark.parametrize("policy, forwards", [("dots", 1), ("nothing", 2)])
+def test_a_rematted_layer_runs_the_forward_kernel_as_its_policy_says(
+        policy, forwards):
+    """The gradient of the trainer's loss through scanned, rematerialised
+    layers with the flash kernels (interpret mode): under `"dots"` the
+    backward reads the `o` and `lse` the forward call named
+    (`SAVED_OUTPUTS`), so a layer holds ONE forward kernel and one
+    backward; under `"nothing"` the backward scan runs the forward again:
+    two and one. Either way the gradient is the un-rematerialised one to
+    the bit: the values saved are the values recomputed."""
+    import flax.linen as nn
+    from jax.sharding import Mesh
+
+    from ray_tpu.models.llama import LlamaModel, get_config
+    from ray_tpu.parallel.mesh import AXES, active_mesh
+    from ray_tpu.parallel.train_lib import ShardedTrainer
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape((1,) * len(AXES)),
+                AXES)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 256), 0, 256)
+
+    def grads_of(**remat):
+        # float32: the CPU backend has no bf16 x bf16 = f32 product
+        cfg = get_config("tiny", attention_impl="flash", dtype=jnp.float32,
+                         **remat)
+        assert cfg.scan_layers
+        trainer = ShardedTrainer(LlamaModel(cfg), mesh)
+        params = nn.meta.unbox(trainer.model.init(
+            jax.random.PRNGKey(0), ids)["params"])
+        grad = jax.grad(lambda p: trainer.loss_fn(p, {"input_ids": ids}))
+        with active_mesh(mesh):
+            # a scan's body counts once, a layer; the forward kernel gives
+            # (o, lse), the backward (dq, dk, dv)
+            return jax.jit(grad)(params), sorted(
+                len(call.outvars) for call in _kernel_calls(grad, params))
+
+    got, kernels = grads_of(remat=True, remat_policy=policy)
+    want, plain = grads_of(remat=False)
+    assert plain == [2, 3]
+    assert kernels == [2] * forwards + [3]
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
