@@ -440,11 +440,16 @@ class MoEMLP(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, token_mask=None, stacked=None):
+    def __call__(self, x, token_mask=None, stacked=None, choice=None):
         """stacked: None, or (gate_up [L, E, h, 2f], down [L, E, f, h],
         layer): the whole scanned stack of expert weights and this layer's
         index in it, for the dropless path's kernel to read in place (see
-        `_stacked_experts`)."""
+        `_stacked_experts`). choice: None, or what a router OUTSIDE the
+        layer decided (models/zaya.py: an MLP whose state runs down the
+        stack), (probs [T, R] float32, gate [T, k] float32 the chosen
+        experts' weights as they are applied, idx [T, k] int32): the layer
+        then has no `router` of its own and serves that choice on the same
+        paths."""
         cfg = self.config
         E, k = cfg.num_experts, cfg.num_experts_per_tok
         R = cfg.routed_experts               # the router's width
@@ -453,13 +458,18 @@ class MoEMLP(nn.Module):
         T = b * s
         xt = x.reshape(T, h)
 
-        router = self.param(
-            "router", A(nn.initializers.normal(0.02), ("embed", None)),
-            (h, R), jnp.float32)
+        if choice is None:
+            router = self.param(
+                "router", A(nn.initializers.normal(0.02), ("embed", None)),
+                (h, R), jnp.float32)
         with tracing.scope("rtpu.moe.route"):
-            # routing in fp32 (tiny matmul, numerically load-bearing)
-            logits = jnp.einsum("th,he->te", xt.astype(jnp.float32), router)
-            if cfg.moe_scoring == "sigmoid":
+            if choice is None:
+                # routing in fp32 (tiny matmul, numerically load-bearing)
+                logits = jnp.einsum("th,he->te", xt.astype(jnp.float32),
+                                    router)
+            if choice is not None:
+                probs, gate, idx = choice
+            elif cfg.moe_scoring == "sigmoid":
                 probs = jax.nn.sigmoid(logits)               # [T,R]
                 bias = self.param(
                     "router_bias", A(nn.initializers.zeros, (None,)), (R,),

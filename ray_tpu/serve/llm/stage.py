@@ -113,7 +113,7 @@ def serve_dtype(config):
 
 def model_family(name: str):
     """The module that defines the preset `name`: the one place that
-    chooses a model family from `EngineConfig.model`. Eight families (each
+    chooses a model family from `EngineConfig.model`. Nine families (each
     module says what it is): models/llama.py, jamba.py, minicpm_sala.py,
     sdar.py (its config has `block_length` and the engine steps it with the
     "block" program), kimi.py (its config has `latent_lanes`), mellum.py
@@ -123,7 +123,12 @@ def model_family(name: str):
     layers beside latent attention: three kinds of state in its pool),
     laguna.py (Mellum's stack and cache with the shapes a kind: its
     `attention_kinds(cfg)` is ((layers, window or None, query heads), ...),
-    so that a pass is priced by each kind's (layer, head) pairs).
+    so that a pass is priced by each kind's (layer, head) pairs), zaya.py
+    (attention inside a compressed latent with a tail of its last inputs a
+    decode slot beside EVERY layer's pages, `cca_tail`; top-1 experts
+    chosen by an MLP router whose state runs down the stack as a second
+    stream of the layer scan's carry, fed to llama.py's `MoEMLP` through
+    its `choice` seam; a tied head).
 
     This is the one description of what a family's module provides:
     `CONFIGS`, `get_config`, `serving_model`, `pool_spec`, `serving_cache`;
@@ -158,10 +163,10 @@ def model_family(name: str):
       option: where a family names it, the engine matches no page by its
       hash, counts what it refused and says why in `stats()`."""
     from ...models import (gigachat, jamba, kimi, laguna, llama, mellum,
-                           minicpm_sala, sdar)
+                           minicpm_sala, sdar, zaya)
 
     families = (llama, jamba, minicpm_sala, sdar, kimi, mellum, gigachat,
-                laguna)
+                laguna, zaya)
     for family in families:
         if name in family.CONFIGS:
             return family

@@ -197,6 +197,8 @@ FIELDS: Dict[str, Tuple[str, ...]] = {
         # a family that came after the stamps (models/gigachat.py): every
         # field before these is held to its place by hand-made records
         "gdn_layers", "gdn_state_bytes_row",
+        # the next family's (models/zaya.py: CcaFacts), behind them
+        "cca_layers", "cca_tail_bytes_row",
         # the engine's own, LAST for the same reason: whether the program's
         # `temp` operand had a row above 0, which is the branch its sampler
         # took (serve/llm/stage.py: _device_sample); None: no sampler
@@ -348,6 +350,10 @@ SCOPES: Tuple[str, ...] = (
     # models/mellum.py: a per-head output gate's projection and its
     # product with the heads (models/laguna.py has one)
     "rtpu.attn.gate",
+    # models/zaya.py: everything between a CCA layer's projection and its
+    # attention kernel that is no cache write: the value shift, the two
+    # convolutions, the q-k mean, the norm, the temperature, the rotation
+    "rtpu.attn.cca",
     # parallel/train_lib.py: _step
     "rtpu.loss",           # the forward under value_and_grad
     "rtpu.optimizer",      # tx.update, apply_updates, global_norm

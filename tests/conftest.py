@@ -99,6 +99,19 @@ def shared_cluster():
     yield ray_tpu
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _release_compiled_programs():
+    """After a test module, drop what JAX compiled for it. Every compiled
+    CPU program holds memory maps until its jit cache lets go (seven engine
+    tests of one file: 6,000 maps; `jax.clear_caches()`: 700 again), a
+    worker runs dozens of files, and past the kernel's 65,530 a process's
+    next compile dies of a segmentation fault inside LLVM: with the tests of
+    PR 58 added, the worker that ran `tests/test_gigachat.py` last did, in
+    two whole runs of two, while the file passes alone."""
+    yield
+    jax.clear_caches()
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _shutdown_at_exit():
     yield

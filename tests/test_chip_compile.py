@@ -1170,3 +1170,79 @@ def test_gigachat_program_fits_and_carries_its_three_kind_pool_in_place(
     header = text.split("\n", 1)[0]
     aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
     assert len(aliased) >= (4 if kind == "decode" else 3), header[:400]
+
+
+# ------- attention inside a compressed latent, a tail a slot beside its pages
+ZAYA_PAGES, ZAYA_LEN, ZAYA_SLOTS, ZAYA_LAYERS = 3456, 14400, 64, 20
+
+
+@pytest.mark.parametrize("kind, key", [
+    ("decode", None), ("prefill", (4096, 0)),
+    ("prefill", (4096, ZAYA_LEN // 64))])
+def test_zaya_program_fits_and_carries_both_pools_in_place(
+        topo, no_persistent_cache, kind, key):
+    """ZAYA1-8B at its published widths as the cell `zaya1-8b-reasoning`
+    runs it: published layers 0-19 with all 16 experts of 2048 and the
+    whole 262,272-row tied vocabulary, 64 slots: pages of the latent's 2 kv
+    heads `[20, P, 2, 64, 256]` beside a tail a slot `[20, 64, 2688]`. The
+    decode program's attention is the paged-decode kernel at 8 query heads
+    on 2 kv heads (a group of 4, Mistral's), once a scan body, beside the
+    grouped matmul at E = 16; a `[1 x 4096]` pass, fresh and resumed behind
+    a 14,400-token table, is the flash forward and fits the chip beside
+    9.38 GB of weights; no part of the pool is copied whole and both are
+    aliased from argument to result."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    from ray_tpu.serve.llm.stage import init_params
+
+    cfg = EngineConfig(
+        model="zaya1-8b", dtype="bfloat16", page_size=64, num_pages=64,
+        max_model_len=ZAYA_LEN, max_batch=ZAYA_SLOTS,
+        prefill_buckets=(512, 1024, 2048, 4096),
+        model_overrides=dict(num_layers=ZAYA_LAYERS))
+    engine = LLMEngine(cfg, params={})
+    stage = engine.compute
+    stage.params = jax.eval_shape(lambda: init_params(
+        stage.model, jnp.zeros((1, 8), jnp.int32), jax.random.PRNGKey(0)))
+    spec = stage.family.pool_spec(stage.model_cfg, ZAYA_LAYERS, ZAYA_PAGES,
+                                  64, ZAYA_SLOTS)
+    assert spec["kv_pages"][0] == (ZAYA_LAYERS, ZAYA_PAGES, 2, 64, 256)
+    assert spec["cca_tail"][0] == (ZAYA_LAYERS, ZAYA_SLOTS, 2688)
+    stage.kv_pages = {k: jax.ShapeDtypeStruct(*sd) for k, sd in spec.items()}
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(a):
+        a = a if hasattr(a, "shape") else np.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    key = (engine._decode_shape_key() if kind == "decode"
+           else (key[0], engine._wave_rb, key[1]))
+    assert stage.operands("prefill")[-1] == "slots"
+    with pytest.MonkeyPatch.context() as mp_ctx:
+        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = stage.program(kind, key).lower(*jax.tree.map(
+            sds, (*stage._state(kind), *stage.dummy_args(kind, key)),
+            is_leaf=lambda a: isinstance(a, jax.ShapeDtypeStruct))
+        ).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(kind, key, "GiB", total / 2 ** 30, "temp",
+          mem.temp_size_in_bytes / 2 ** 30)
+    # 9.38 GB of weights + 4.53 GB of pages
+    assert 12.9 * 2 ** 30 <= total <= HBM_GIB * 2 ** 30, total / 2 ** 30
+    kernels = set(re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call", text))
+    names = sorted({k.split(".")[0] for k in kernels})
+    if kind == "decode":
+        assert names == ["_decode_call", "_moe_gmm"], kernels
+    else:
+        assert names == (["_ctx_flash", "_moe_gmm", "attn"] if key[2]
+                         else ["_moe_gmm", "attn"]), kernels
+    whole = {sd[0] for sd in spec.values()}
+    copied = [line.strip()[:160] for line in text.splitlines()
+              if (m := re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) copy\(",
+                                line))
+              and any(dims in whole for dims, _ in _array_types(m.group(1)))]
+    assert not copied, "\n".join(copied)
+    header = text.split("\n", 1)[0]
+    aliased = re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliased) >= (3 if kind == "decode" else 2), header[:400]

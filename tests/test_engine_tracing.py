@@ -897,6 +897,7 @@ FAMILY_FIELDS = {
                             "full_heads", "window_tokens_read",
                             "full_tokens_read", "window_tokens_held",
                             "full_tokens_held"}, {}),
+    "tiny-zaya": (_MOE | {"cca_layers", "cca_tail_bytes_row"}, {}),
 }
 
 

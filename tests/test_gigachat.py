@@ -333,11 +333,11 @@ def test_the_engine_emits_the_references_tokens_and_its_records_say_how(
     fields = tracing.FIELDS["engine.dispatch"]
     recs = [dict(zip(fields, r)) for r in tracing.records("engine.dispatch")]
     cfg = engine.model_cfg
-    # the family's two come behind the stamps (only the engine's `drawn`
-    # behind them): hand-made records of four families' tests hold every
-    # earlier field to its place
-    assert fields[-4:] == ("gdn_layers", "gdn_state_bytes_row", "drawn",
-                           "program_key")
+    # the family's two come behind the stamps (the next family's two and
+    # the engine's own behind them): hand-made records of four families'
+    # tests hold every earlier field to its place
+    assert fields[-6:] == ("gdn_layers", "gdn_state_bytes_row", "cca_layers",
+                           "cca_tail_bytes_row", "drawn", "program_key")
     row_bytes = 5 * (4 * 16 * 16 * 4 + 3 * 128 * 4)      # float32 here
     for r in recs:
         assert len(r) == len(fields)

@@ -34,12 +34,15 @@ FAMILIES = {
     # the one family with a per-head output gate (models/laguna.py)
     "laguna": ("tiny-laguna", 16, "decode",
                SERVING | MOE | {"rtpu.attn.gate"}),
+    # the one family whose q, k and v come out of convolutions in time
+    # (models/zaya.py); its MLP router is under `rtpu.moe.route`
+    "zaya": ("tiny-zaya", 16, "decode", SERVING | MOE | {"rtpu.attn.cca"}),
 }
 # the jitted wrappers a kernel's instruction is named after, by family:
 # each must be in some path, and no scope may follow it there
 WRAPPERS = {
     "experts": ("_moe_gmm",), "sdar": ("_moe_gmm",),
-    "laguna": ("_moe_gmm",),
+    "laguna": ("_moe_gmm",), "zaya": ("_moe_gmm",),
     "minicpm-sala": ("_sparse_prefill", "_sparse_select",
                      "_sparse_compress", "_lightning_prefill",
                      "_lightning_update"),
@@ -210,7 +213,7 @@ ENTRY %main.3 (a: f32[8]) -> f32[8] {
 
 
 def test_a_scope_is_one_of_the_vocabulary():
-    assert len(set(tracing.SCOPES)) == len(tracing.SCOPES) == 11
+    assert len(set(tracing.SCOPES)) == len(tracing.SCOPES) == 12
     assert all(s.startswith("rtpu.") for s in tracing.SCOPES)
     with pytest.raises(KeyError, match="rtpu.moe.sort"):
         tracing.scope("rtpu.moe.sort")

@@ -257,15 +257,19 @@ class _FileScanner:
         self.source = source
         self.tree = tree
         self.facts = _FileFacts(path, in_package)
+        # every node once, in `ast.walk`'s order: the passes below and the
+        # scans read this list instead of walking the tree again (the walk
+        # is most of the pass's time over 230 files)
+        self.nodes: List[ast.AST] = list(ast.walk(tree))
         self.parents: Dict[ast.AST, ast.AST] = {}
-        for node in ast.walk(tree):
+        for node in self.nodes:
             for child in ast.iter_child_nodes(node):
                 self.parents[child] = node
         # every def in the file by name (for handler-signature and
         # handler-dict-argument resolution)
         self.func_defs: Dict[str, List[ast.AST]] = {}
         self.docstring_nodes: Set[ast.AST] = set()
-        for node in ast.walk(tree):
+        for node in self.nodes:
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 self.func_defs.setdefault(node.name, []).append(node)
             if isinstance(node, (ast.Module, ast.ClassDef, ast.FunctionDef,
@@ -279,7 +283,7 @@ class _FileScanner:
         # defines it or imports it from a *config module — serve/llm code
         # imports an unrelated model-config get_config from models.llama
         self.runtime_config_file = "get_config" in self.func_defs
-        for node in ast.walk(tree):
+        for node in self.nodes:
             if isinstance(node, ast.ImportFrom) and node.module and \
                     node.module.split(".")[-1] == "config" and \
                     any(a.name == "get_config" for a in node.names):
@@ -379,7 +383,7 @@ class _FileScanner:
     # -------------------------------------------------------------- scan
     def scan(self) -> _FileFacts:
         self.facts.pragmas = _parse_pragmas(self.source, self.path, [])
-        for node in ast.walk(self.tree):
+        for node in self.nodes:
             if isinstance(node, ast.Dict):
                 self._scan_dict(node)
             elif isinstance(node, ast.Call):
@@ -426,7 +430,7 @@ class _FileScanner:
 
     def _scan_subscript_regs(self):
         # handlers["method"] = fn
-        for node in ast.walk(self.tree):
+        for node in self.nodes:
             if not isinstance(node, ast.Assign) or len(node.targets) != 1:
                 continue
             t = node.targets[0]
@@ -572,7 +576,7 @@ class _FileScanner:
         Attribute-target aliases (`self._cfg = get_config()`) apply
         file-wide since the attribute outlives the assigning method."""
         attr_aliases: Set[str] = set()
-        for node in ast.walk(self.tree):
+        for node in self.nodes:
             if isinstance(node, ast.Assign) and \
                     self._is_config_call(node.value):
                 for t in node.targets:
@@ -581,13 +585,14 @@ class _FileScanner:
 
         def visit_frame(frame: ast.AST, inherited: Set[str]):
             names = set(inherited)
-            for sub in self._frame_walk(frame):
+            subs = list(self._frame_walk(frame))     # walked once a frame
+            for sub in subs:
                 if isinstance(sub, ast.Assign) and \
                         self._is_config_call(sub.value):
                     for t in sub.targets:
                         if isinstance(t, ast.Name):
                             names.add(t.id)
-            for sub in self._frame_walk(frame):
+            for sub in subs:
                 # getattr(cfg, "field"[, default])
                 if isinstance(sub, ast.Call) and \
                         isinstance(sub.func, ast.Name) and \
@@ -607,7 +612,7 @@ class _FileScanner:
                     if recv in names or recv in attr_aliases:
                         self.facts.config_reads.append(
                             (sub.attr, sub.lineno, True))
-            for sub in self._frame_walk(frame):
+            for sub in subs:
                 if isinstance(sub, (ast.FunctionDef, ast.AsyncFunctionDef,
                                     ast.Lambda)):
                     visit_frame(sub, names)
