@@ -4,12 +4,18 @@ the float32 reference's.
 
     python benchmarks/flash_bwd_probe.py [--shape pretrain|llama1b|d128-4k|all]
         [--packed] [--blocks QxK,...] [--iters N] [--seed N]
+        [--layout bshd|bhsd]
     python benchmarks/flash_bwd_probe.py --fwd-shapes [--blocks QxK,...]
-        [--only NAME,...] [--all-edge] [--no-fold]
+        [--only NAME,...] [--all-edge] [--no-fold] [--layout bshd|bhsd]
 
 One jitted call each of `_fwd` and `_bwd` (the backward's whole device work:
-`delta`, the kernel, nothing of the forward) on random bf16 operands,
-`[B, H, S, D]` as the kernels take them, timed over `--iters` calls; beside
+`delta`, the kernel, nothing of the forward) on random bf16 operands as the
+kernels take them, timed over `--iters` calls: `--layout bshd` (the
+default) `[B, S, H, D]`, read as rows of `[B, S, H x D]` where a head is
+whole lane tiles and `[B, H, S, D]` by the module's own rule where not;
+`--layout bhsd` `[B, H, S, D]` at every width (PR 60: the kernels in the
+layout they had, for a before and after in one tree; patches the module's
+rule for the probe's process only); beside
 each the share of the bf16 peak on the REAL causal pairs, counted as
 `chipbench/flops.py` counts them (forward two products a pair, backward
 four: the score tile's recomputation is not counted). `--packed` gives every
@@ -55,7 +61,28 @@ FWD_SHAPES = {
     "mla-4k": (1, 4096, 4096, 64, 64, 192, 128),
     "bucket128-ctx": (1, 128, 2560, 32, 8, 128, 128),
     "bucket256-ctx": (1, 256, 2560, 32, 8, 128, 128),
+    # a sliding layer of models/laguna.py: an eighth name, the window
+    "laguna-w512": (1, 4096, 4096, 64, 8, 128, 128, 512),
 }
+
+
+def _operands(fa, shapes, keys):
+    """Random bf16 operands `[B, H, S, D]` for `shapes` [(B, H, S, D), ...],
+    the two functions that give the kernels their layout of them and take
+    an output (rows `[B, S, H x D]` or `[B, S, H, D]` or `[B, H, S, D]`)
+    back to `[B, H, S, D]`, and the layout's name."""
+    import jax
+    import jax.numpy as jnp
+
+    xs = [jax.random.normal(key, shape, jnp.bfloat16)
+          for key, shape in zip(keys, shapes)]
+    if not fa._heads_on_lanes(shapes[0][3], shapes[-1][3]):
+        return xs, (lambda x: x), (lambda x, h: x), "bhsd"
+
+    def back(x, h):
+        return x.reshape(*x.shape[:2], h, -1).transpose(0, 2, 1, 3)
+
+    return xs, (lambda x: x.transpose(0, 2, 1, 3)), back, "bshd"
 
 
 def probe(name: str, packed: bool, iters: int, seed: int,
@@ -67,11 +94,9 @@ def probe(name: str, packed: bool, iters: int, seed: int,
     from ray_tpu.ops.attention import reference_attention
 
     b, s, hq, hkv, d = SHAPES[name]
-    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    q, do = (jax.random.normal(key, (b, hq, s, d), jnp.bfloat16)
-             for key in ks[:2])
-    k, v = (jax.random.normal(key, (b, hkv, s, d), jnp.bfloat16)
-            for key in ks[2:])
+    (q, do, k, v), lay, back, layout = _operands(
+        fa, [(b, hq, s, d)] * 2 + [(b, hkv, s, d)] * 2,
+        jax.random.split(jax.random.PRNGKey(seed), 4))
     seg = kv_seg = None
     if packed:
         seg = jnp.tile(jnp.repeat(jnp.arange(4, dtype=jnp.int32), s // 4),
@@ -84,9 +109,10 @@ def probe(name: str, packed: bool, iters: int, seed: int,
     bwd = jax.jit(lambda q, k, v, o, lse, do: fa._bwd(
         q, k, v, seg, kv_seg, o, lse, do, *static))
 
-    o, lse = fwd(q, k, v)
-    t_fwd = _timed(fwd, iters, q, k, v)
-    t_bwd = _timed(bwd, iters, q, k, v, o, lse, do)
+    args = tuple(lay(x) for x in (q, k, v))
+    o, lse = fwd(*args)
+    t_fwd = _timed(fwd, iters, *args)
+    t_bwd = _timed(bwd, iters, *args, o, lse, lay(do))
 
     # the gradients against the float32 reference's on the first batch row
     # (float32 operands, so that its gradients are not rounded to bf16: two
@@ -100,12 +126,14 @@ def probe(name: str, packed: bool, iters: int, seed: int,
 
     want = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(
         *(x[:1].astype(jnp.float32) for x in (q, k, v)))
-    got = bwd(q, k, v, o, lse, do)
+    got = (back(g, h) for g, h in zip(bwd(*args, o, lse, lay(do)),
+                                      (hq, hkv, hkv)))
     err = {n: float(jnp.linalg.norm(g[:1].astype(jnp.float32) - w)
                     / jnp.linalg.norm(w))
            for n, g, w in zip(("dq", "dk", "dv"), got, want)}
     pairs = s * (s + 1) // 2 * hq * b
     return {"shape": name, "packed": packed, "blocks": [bq, bk],
+            "layout": layout,
             "fwd_ms": t_fwd * 1e3, "bwd_ms": t_bwd * 1e3,
             "fwd_peak_pct": 100 * 2 * 2 * d * pairs / PEAK_FLOPS / t_fwd,
             "bwd_peak_pct": 100 * 4 * 2 * d * pairs / PEAK_FLOPS / t_bwd,
@@ -135,19 +163,20 @@ def probe_fwd(name: str, causal: bool, lens: bool, iters: int, seed: int,
 
     from ray_tpu.ops import flash_attention as fa
 
-    b, sq, sk, hq, hkv, d, dv = FWD_SHAPES[name]
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (b, hq, sq, d), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (b, hkv, sk, d), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, hkv, sk, dv), jnp.bfloat16)
+    b, sq, sk, hq, hkv, d, dv, *window = FWD_SHAPES[name]
+    window = window[0] if window else None
+    (q, k, v), lay, back, layout = _operands(
+        fa, [(b, hq, sq, d), (b, hkv, sk, d), (b, hkv, sk, dv)],
+        jax.random.split(jax.random.PRNGKey(seed), 3))
     bq, bk = blocks or fa._pick_blocks(sq, sk, fa.BLOCK, fa.BLOCK)
     short = min(300, sq // 2) if lens else 0
     nq, nk = sq - short, sk - short
     row_lens = jnp.tile(jnp.asarray([[nq], [nk]], jnp.int32), (1, b))
     fwd = jax.jit(lambda q, k, v, n: fa._fwd(
         q, k, v, None, None, causal, d ** -0.5, bq, bk, False, sq, sk,
-        n if lens else None))
-    t = _timed(fwd, iters, q, k, v, row_lens)
+        n if lens else None, 0, window))
+    args = tuple(lay(x) for x in (q, k, v))
+    t = _timed(fwd, iters, *args, row_lens)
     # the first kv head's query group against the float32 reference over
     # the row's real queries and keys
     from ray_tpu.ops.attention import reference_attention
@@ -155,15 +184,22 @@ def probe_fwd(name: str, causal: bool, lens: bool, iters: int, seed: int,
     rep = hq // hkv
     tr = lambda x, n: x[:1, :, :n].astype(  # noqa: E731
         jnp.float32).transpose(0, 2, 1, 3)
-    want = reference_attention(tr(q[:, :rep], nq), tr(k[:, :1], nk),
-                               tr(v[:, :1], nk), causal=causal)
-    got = tr(fwd(q, k, v, row_lens)[0][:, :rep], nq)
-    err = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
-    # query i of nq sees keys [0, i + sk - sq] of nk if causal, else all nk
+    err = None
+    if window is None:
+        want = reference_attention(tr(q[:, :rep], nq), tr(k[:, :1], nk),
+                                   tr(v[:, :1], nk), causal=causal)
+        got = tr(back(fwd(*args, row_lens)[0], hq)[:, :rep], nq)
+        err = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    # query i of nq sees keys [0, i + sk - sq] of nk if causal, else all
+    # nk; under a window the last `window` of them (the context part of a
+    # pass that resumes: the `window` keys before the row's end + i)
     off = sk - sq
-    pairs = (sum(min(nk, i + off + 1) for i in range(nq)) if causal
-             else nq * nk) * hq * b
+    seen = [min(nk, i + off + 1) if causal else nk for i in range(nq)]
+    if window is not None:
+        seen = [min(n, window) for n in seen]
+    pairs = sum(seen) * hq * b
     return {"fwd_shape": name, "causal": causal, "lens": lens,
+            "layout": layout,
             "blocks": [bq, bk], "fwd_ms": t * 1e3,
             "fwd_peak_pct": 100 * 2 * (d + dv) * pairs / PEAK_FLOPS / t,
             "o_rel_err": err}
@@ -187,6 +223,9 @@ def main() -> int:
                     help="every tile takes the forward's masked body")
     ap.add_argument("--no-fold", action="store_true",
                     help="a grid step of the forward holds one query head")
+    ap.add_argument("--layout", default="bshd", choices=["bshd", "bhsd"],
+                    help="bhsd: [B, H, S, D] operands at every width, the "
+                         "kernels' layout before PR 60")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
@@ -199,12 +238,14 @@ def main() -> int:
         return 1
     tries = [tuple(int(e) for e in t.split("x"))
              for t in args.blocks.split(",") if t] or [None]
-    if args.all_edge or args.no_fold:
+    if args.all_edge or args.no_fold or args.layout == "bhsd":
         import jax.numpy as jnp
 
         from ray_tpu.ops import flash_attention as fa
 
         trips = fa._fwd_trips
+        if args.layout == "bhsd":
+            fa._heads_on_lanes = lambda d, dv: False
         if args.all_edge:
             fa._fwd_trips = lambda *a, **kw: (jnp.int32(0),
                                               trips(*a, **kw)[1])

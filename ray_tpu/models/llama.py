@@ -26,11 +26,12 @@ from flax import struct
 
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops.attention import attention
+from ..ops.attention import attention, flash_on_one_device
 from ..ops.flash_attention import SAVED_OUTPUTS
 from ..ops.paged_attention import (paged_attention_block,
                                    paged_attention_decode,
                                    paged_prefill_attention, paged_write)
+from ..ops.rotary import rotate_rows, rows_rotatable
 from ..util import tracing
 
 
@@ -183,11 +184,18 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: [B, S, H, D], positions: [B, S]."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         rows: bool = False) -> jax.Array:
+    """Rotary embedding. x: [B, S, H, D], positions: [B, S]. `rows`: the
+    caller's attention is the flash kernel on this one device, which reads
+    x as rows of [B, S, H x D]; where the shapes allow, the rotation is then
+    a kernel over those rows too (ops/rotary.py: `rotate_rows`), the same
+    arithmetic with no change of layout under it."""
     d = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,D/2]
+    if rows and rows_rotatable(x):
+        return rotate_rows(x, jnp.cos(angles), jnp.sin(angles))
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -299,8 +307,15 @@ class Attention(nn.Module):
             k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, (None,),
                         name="k_norm")(k)
         if cfg.rope_theta is not None:
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+            # the rotation reads rows where the attention's kernel will
+            if isinstance(kv_cache, PagedCache):
+                rows = flash_on_one_device(
+                    "reference" if kv_cache.ref_attention else None)
+            else:   # (any other cache is a dense decode step's)
+                rows = kv_cache is None and flash_on_one_device(
+                    cfg.attention_impl)
+            q = rope(q, positions, cfg.rope_theta, rows)
+            k = rope(k, positions, cfg.rope_theta, rows)
         if isinstance(kv_cache, PagedCache):
             # Serving path: write new K/V into this layer's pages of the
             # pool, then attend. Decode (S == 1) streams only the used

@@ -166,6 +166,16 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                segment_ids=segment_ids, scale=scale)
 
 
+def flash_on_one_device(impl: Optional[str] = None) -> bool:
+    """Whether `attention(..., impl=impl)` is the Pallas kernel called on
+    this one device: not a reference, not a sequence-parallel form, not
+    wrapped in a shard_map over a mesh. What stands beside such a call may
+    be a one-device kernel too (models/llama.py: `rope`'s rows)."""
+    if impl is None:
+        impl = "flash" if jax.default_backend() == "tpu" else "reference"
+    return impl == "flash" and _mesh_to_shard_over() is None
+
+
 def _mesh_to_shard_over():
     """The active mesh when the flash kernel has to be wrapped in a
     shard_map: more than one device, and not already inside a manual

@@ -19,6 +19,20 @@ ABSENT").  Here the hot op is owned natively: a blocked online-softmax
   Longer sequences need K/V tiled over the grid (ROADMAP A6),
 - GQA handled in the BlockSpec index map (q-head h reads kv-head h // n_rep),
   so no materialised `repeat_kv`,
+- operands where the projections left them: the caller's `[B, S, H, D]` is
+  `[B, S, H x D]` by a reshape, and where a head is whole lane tiles (D and
+  Dv multiples of 128: `_heads_on_lanes`) a head is a LANE BLOCK of that
+  row: the forward's q and `o` blocks are `(1, block_q, g x D)` at lane
+  block h, K and V `(1, Sk, D)` at lane block h // n_rep, the backward's q,
+  do, dq `(1, Sq, D)` and k, v, dk, dv `(1, Sk, D)` likewise. Nothing is
+  transposed around a call, `o` is kept once (`SAVED_OUTPUTS`) in the
+  layout both its readers take (the o projection; `delta`, a product with
+  the heads' lanes), and only `lse` and `delta`, one float32 a (query,
+  head), are heads-first. K and V of a head are then strided rows that XLA
+  cannot hold in VMEM whole, so the forward asks for its VMEM from 12,288
+  rows on. Any other width (a latent family's keys of 192, a tiny
+  configuration's heads of 16) keeps `[B, H, S, D]` operands, the
+  transposes that make them and the text it lowered to,
 - causal masking is relative to the *end* of the kv sequence (tril with
   offset sk - sq), which makes the same kernel correct for training
   (sq == sk), chunked prefill and multi-token decode (sq < sk),
@@ -107,10 +121,29 @@ GRAN = 128   # MXU-minimal granularity: short sequences round up to this,
 # q / o / lse blocks and its [bk, bq] score tiles take beside K and V
 _VMEM_UNASKED = 16 << 20
 _VMEM_HEADROOM = 8 << 20
+# what they take where there are no segment ids (2.6 MB at blocks of 512)
+_VMEM_BESIDE = 4 << 20
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def _heads_on_lanes(d: int, dv: int) -> bool:
+    """Whether the kernels take `[B, S, H, D]` operands as the projections
+    leave them: a head is then a lane block of a `[B, S, H x D]` row, which
+    it can be only where the keys' and the values' widths are whole lane
+    tiles. Any other width (the latent families' keys of 192, a tiny
+    configuration's heads of 16) keeps `[B, H, S, D]` operands, and the
+    transposes around the call that make them."""
+    return d % 128 == 0 and dv % 128 == 0
+
+
+def _dims(q, k, on_lanes: bool):
+    """(B, Hq, Hkv, Sq_p, Sk_p) of the kernels' operands in either form:
+    `[B, S, H, D]` where a head is a lane block, `[B, H, S, D]` where not."""
+    (b, s1, s2, _), (_, k1, k2, _) = q.shape, k.shape
+    return (b, s2, k2, s1, k1) if on_lanes else (b, s1, k1, s2, k2)
 
 
 def _pick_blocks(sq: int, sk: int, block_q: int, block_k: int):
@@ -225,9 +258,21 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
     log2(e)`: one multiply a tile element where `exp` of scaled scores
     paid two."""
     qblk = pl.program_id(2)
-    _, g, bq, d = q_ref.shape
-    lanes = g * bq
-    qt = q_ref[0].reshape(lanes, d).T  # [d, lanes]
+    # (a block's leading ones: [1, 1, S, D] of `[B, H, S, D]` operands, [1,
+    # S, D] where a head is a lane block of `[B, S, H x D]`)
+    lead = (0,) * (k_ref.ndim - 2)
+    if q_ref.ndim == 4:
+        _, g, bq, d = q_ref.shape
+        lanes = g * bq
+        qt = q_ref[0].reshape(lanes, d).T  # [d, lanes]
+    else:
+        # the g heads are g * d contiguous lanes of a row: each a static
+        # lane slice, stacked on sublanes in VMEM
+        bq, d = q_ref.shape[1], k_ref.shape[2]
+        g = q_ref.shape[2] // d
+        lanes = g * bq
+        qt = jnp.concatenate(
+            [q_ref[0, :, r * d:(r + 1) * d] for r in range(g)], axis=0).T
     offset = sk - sq
     c = sm_scale * _LOG2E
 
@@ -239,7 +284,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
     l0 = jnp.zeros((1, lanes), jnp.float32)
     # (the values' width: the keys' for every family but one whose values
     # are narrower than its keys, models/kimi.py)
-    acc0 = jnp.zeros((v_ref.shape[3], lanes), jnp.float32)
+    acc0 = jnp.zeros((v_ref.shape[-1], lanes), jnp.float32)
     k_iota = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
     q_pos = per_head(
         qblk * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1))
@@ -249,8 +294,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
     def tile(kb, carry, masked):
         m, l, acc = carry
         ks = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
-        k = k_ref[0, 0, ks, :]
-        v = v_ref[0, 0, ks, :]
+        k = k_ref[(*lead, ks, slice(None))]
+        v = v_ref[(*lead, ks, slice(None))]
         s = jax.lax.dot_general(
             k, qt, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)  # [bk, lanes], raw
@@ -308,7 +353,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref, *,
     m, l, acc = jax.lax.fori_loop(
         interior, num_kb, functools.partial(tile, masked=True), carry)
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe).T.reshape(g, bq, -1).astype(o_ref.dtype)
+    o = (acc / l_safe).T  # [lanes, dv]
+    if o_ref.ndim == 4:
+        o_ref[0] = o.reshape(g, bq, -1).astype(o_ref.dtype)
+    else:
+        dv = o.shape[1]
+        for r in range(g):
+            o_ref[0, :, r * dv:(r + 1) * dv] = o[r * bq:(r + 1) * bq].astype(
+                o_ref.dtype)
     # (a query that saw no key: NEG_INF itself, not NEG_INF * sm_scale)
     lse = jnp.where(l == 0.0, NEG_INF, m * sm_scale + jnp.log(l_safe))
     for r in range(g):
@@ -333,19 +385,22 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
          interpret, sq, sk, lens=None, block_causal=0, window=None):
     """q: [B,Hq,Sq_p,D]; k: [B,Hkv,Sk_p,D]; v: [B,Hkv,Sk_p,Dv] (padded to
     block multiples; Dv is D but for the forward-only path of a model whose
-    values are narrower than its keys); q_seg [B,Sq_p] and kv_seg
-    [B,Sk_p,1] int32, or None.
+    values are narrower than its keys); or, where a head is whole lane
+    tiles (`_heads_on_lanes`), [B,Sq_p,Hq,D] / [B,Sk_p,Hkv,D|Dv], read as
+    rows of [B, S, H x D]; q_seg [B,Sq_p] and kv_seg [B,Sk_p,1] int32, or
+    None.
 
     sq/sk are the TRUE lengths of the operands: the kernels mask kv padding
     with `k_pos < sk` and compute the causal offset from them. `lens`
     ([2, B] int32, or None) is each batch row's own (queries, keys) within
-    them, as data. Returns o [B,Hq,Sq_p,Dv] and lse as lane rows
+    them, as data. Returns o [B,Hq,Sq_p,Dv] (rows [B,Sq_p,Hq x Dv] where
+    the operands were) and lse as lane rows
     [B,Hq,Sq_p/block_q,1,block_q] (`_lane_rows`: the layout the kernel
     writes and `_bwd` reads, so the trainer's step relays nothing out).
     """
-    b, hq, sq_p, d = q.shape
-    _, hkv, sk_p, _ = k.shape
-    dv = v.shape[3]
+    d, dv = q.shape[3], v.shape[3]
+    on_lanes = _heads_on_lanes(d, dv)
+    b, hq, hkv, sq_p, sk_p = _dims(q, k, on_lanes)
     n_rep = hq // hkv
     bq, bk = block_q, block_k
     nqb = sq_p // bq
@@ -361,13 +416,31 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
         **({} if window is None else {"window": window}))
 
     # (`*_`: the scalar-prefetched lengths, where there are any)
-    in_specs = [
-        pl.BlockSpec((1, g, bq, d), lambda b_, h, i, *_: (b_, h, i, 0)),
-        pl.BlockSpec((1, 1, sk_p, d),
-                     lambda b_, h, i, *_: (b_, h * g // n_rep, 0, 0)),
-        pl.BlockSpec((1, 1, sk_p, dv),
-                     lambda b_, h, i, *_: (b_, h * g // n_rep, 0, 0)),
-    ]
+    if on_lanes:
+        # a head is a lane block of [B, S, H x D] (a reshape, free): the g
+        # folded heads of a step are g * d contiguous lanes of a row
+        def heads(width):
+            return pl.BlockSpec((1, bq, g * width),
+                                lambda b_, h, i, *_: (b_, i, h))
+
+        def kv_heads(width):
+            return pl.BlockSpec(
+                (1, sk_p, width), lambda b_, h, i, *_: (b_, 0, h * g // n_rep))
+
+        q, k, v = (x.reshape(*x.shape[:2], -1) for x in (q, k, v))
+        o_shape = (b, sq_p, hq * dv)
+    else:
+        def heads(width):
+            return pl.BlockSpec((1, g, bq, width),
+                                lambda b_, h, i, *_: (b_, h, i, 0))
+
+        def kv_heads(width):
+            return pl.BlockSpec(
+                (1, 1, sk_p, width),
+                lambda b_, h, i, *_: (b_, h * g // n_rep, 0, 0))
+
+        o_shape = (b, hq, sq_p, dv)
+    in_specs = [heads(d), kv_heads(d), kv_heads(dv)]
     args = [q, k, v]
     if have_segs:
         in_specs += [
@@ -380,25 +453,31 @@ def _fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
         args += [_dummy_arg(), _dummy_arg()]
 
     out_shape = [
-        jax.ShapeDtypeStruct((b, hq, sq_p, dv), q.dtype),
+        jax.ShapeDtypeStruct(o_shape, q.dtype),
         jax.ShapeDtypeStruct((b, hq, nqb, 1, bq), jnp.float32),
     ]
     out_specs = [
-        pl.BlockSpec((1, g, bq, dv), lambda b_, h, i, *_: (b_, h, i, 0)),
+        heads(dv),
         pl.BlockSpec((1, g, 1, 1, bq),
                      lambda b_, h, i, *_: (b_, h, i, 0, 0)),
     ]
     compiler_params = dict(
         dimension_semantics=("parallel", "parallel", "parallel"))
-    if have_segs:
+    if have_segs or on_lanes:
         # K, V and the kv segment ids of one (batch, kv head) stay
         # resident, double-buffered, and an int32 [sk, 1] column is padded
         # to 128 lanes: at kv 8k and D 128 that is 18 MB, over the 16 MB a
         # kernel gets unasked. Such a call compiles only while XLA chooses
         # to hold the ids in VMEM itself (the engine's [4 x 4096] prefix
         # prefill did, until the program around it changed), so ask.
-        resident = 2 * sk_p * (2 * d * k.dtype.itemsize + 128 * 4)
-        if resident + _VMEM_HEADROOM > _VMEM_UNASKED:
+        # Without ids K and V alone pass it from 12,288 rows on (the
+        # engine's calls hold no more: `FLASH_RESIDENT_KV_BYTES`; the
+        # trainer's may). A lane block of a row is no operand XLA can hold
+        # in VMEM whole, as it did a head of `[B, H, S, D]`.
+        resident = 2 * sk_p * ((d + dv) * k.dtype.itemsize
+                               + (128 * 4 if have_segs else 0))
+        beside = _VMEM_HEADROOM if have_segs else _VMEM_BESIDE
+        if resident + beside > _VMEM_UNASKED:
             compiler_params["vmem_limit_bytes"] = resident + _VMEM_HEADROOM
     if lens is None:
         grid_spec = dict(grid=grid, in_specs=in_specs, out_specs=out_specs)
@@ -433,7 +512,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
     step; each is written once, in the operands' dtype."""
     rep = pl.program_id(2)
     bq, bk = block_q, block_k
-    nqb, nkb = q_ref.shape[2] // bq, k_ref.shape[2] // bk
+    lead = (0,) * (q_ref.ndim - 2)   # as in `_fwd_kernel`
+    nqb, nkb = q_ref.shape[-2] // bq, k_ref.shape[-2] // bk
     offset = sk - sq
     # segment ids or padded keys: every tile is masked. Else only the
     # tiles the causal diagonal crosses are
@@ -450,14 +530,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
 
     def key_block(kb, _):
         ks = pl.ds(pl.multiple_of(kb * bk, bk), bk)
-        k = k_ref[0, 0, ks, :]
-        v = v_ref[0, 0, ks, :]
+        k = k_ref[(*lead, ks, slice(None))]
+        v = v_ref[(*lead, ks, slice(None))]
         kt = k.T  # [d, bk]
 
         def tile(qb, _, masked):
             qs = pl.ds(pl.multiple_of(qb * bq, bq), bq)
-            q = q_ref[0, 0, qs, :]
-            do = do_ref[0, 0, qs, :]
+            q = q_ref[(*lead, qs, slice(None))]
+            do = do_ref[(*lead, qs, slice(None))]
             st = jax.lax.dot_general(
                 k, q, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale  # [bk, bq]
@@ -509,31 +589,49 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
     jax.lax.fori_loop(0, nkb, key_block, None)
 
     for qb in range(nqb):
-        dq_ref[0, 0, qb * bq:(qb + 1) * bq, :] = dq_acc[qb].T.astype(
-            dq_ref.dtype)
+        dq_ref[(*lead, slice(qb * bq, (qb + 1) * bq), slice(None))] = (
+            dq_acc[qb].T.astype(dq_ref.dtype))
 
     @pl.when(rep == pl.num_programs(2) - 1)
     def _():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[lead] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[lead] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd(q, k, v, q_seg, kv_seg, o, lse, do, causal, sm_scale,
          block_q, block_k, interpret, sq, sk):
-    b, hq, sq_p, d = q.shape
-    _, hkv, sk_p, _ = k.shape
+    d = q.shape[3]
+    on_lanes = _heads_on_lanes(d, d)
+    b, hq, hkv, sq_p, sk_p = _dims(q, k, on_lanes)
     n_rep = hq // hkv
     bq, bk = block_q, block_k
     nqb = sq_p // bq
     have_segs = q_seg is not None
 
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-
     # grid (batch, kv head, query head of its group): K, V and the dk / dv
     # blocks keep their index over the last dimension
-    q_spec = pl.BlockSpec((1, 1, sq_p, d),
-                          lambda b_, g, r: (b_, g * n_rep + r, 0, 0))
-    kv_spec = pl.BlockSpec((1, 1, sk_p, d), lambda b_, g, r: (b_, g, 0, 0))
+    if on_lanes:
+        shapes = [q.shape, k.shape, v.shape]
+        q, k, v, do = (x.reshape(*x.shape[:2], -1) for x in (q, k, v, do))
+        # `o` is read as the o projection reads it, rows of [B, S, H x D]:
+        # a head's sum is the row's product with that head's lanes set
+        # (float32 passes of the MXU), and only delta, one float32 a
+        # (query, head), changes its layout
+        head_lanes = (jnp.arange(hq * d)[:, None] // d
+                      == jnp.arange(hq)[None]).astype(jnp.float32)
+        delta = jnp.einsum(
+            "bsl,lh->bhs", do.astype(jnp.float32) * o.astype(jnp.float32),
+            head_lanes, precision=jax.lax.Precision.HIGHEST)
+        q_spec = pl.BlockSpec((1, sq_p, d),
+                              lambda b_, g, r: (b_, 0, g * n_rep + r))
+        kv_spec = pl.BlockSpec((1, sk_p, d), lambda b_, g, r: (b_, 0, g))
+    else:
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1)
+        q_spec = pl.BlockSpec((1, 1, sq_p, d),
+                              lambda b_, g, r: (b_, g * n_rep + r, 0, 0))
+        kv_spec = pl.BlockSpec((1, 1, sk_p, d),
+                               lambda b_, g, r: (b_, g, 0, 0))
     row_spec = pl.BlockSpec((1, 1, nqb, 1, bq),
                             lambda b_, g, r: (b_, g * n_rep + r, 0, 0, 0))
     if have_segs:
@@ -557,7 +655,7 @@ def _bwd(q, k, v, q_seg, kv_seg, o, lse, do, causal, sm_scale,
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     if need > _VMEM_UNASKED:
         compiler_params["vmem_limit_bytes"] = need
-    return pl.pallas_call(
+    grads = pl.pallas_call(
         functools.partial(
             _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
             block_k=bk, sq=sq, sk=sk, have_segs=have_segs),
@@ -578,6 +676,9 @@ def _bwd(q, k, v, q_seg, kv_seg, o, lse, do, causal, sm_scale,
         compiler_params=pltpu.CompilerParams(**compiler_params),
         interpret=interpret,
     )(q, k, v, do, lse, _lane_rows(delta, bq), *seg_args)
+    if on_lanes:
+        grads = [x.reshape(s) for x, s in zip(grads, shapes)]
+    return grads
 
 
 # ============================================================ custom_vjp
@@ -721,10 +822,27 @@ def flash_attention(
         widths[axis] = (0, pad_n)
         return jnp.pad(x, widths)
 
-    # [B,S,H,D] -> [B,H,S,D] for MXU-friendly blocking
-    qt = pad(q.transpose(0, 2, 1, 3), sq_p, 2)
-    kt = pad(k.transpose(0, 2, 1, 3), sk_p, 2)
-    vt = pad(v.transpose(0, 2, 1, 3), sk_p, 2)
+    if _heads_on_lanes(d, v.shape[-1]):
+        # the kernels' blocks index the operands where they are
+        seq_axis = 1
+
+        def heads_first(x):
+            return x
+
+        def seq_first(o):
+            return o[:, :sq].reshape(b, sq, hq, -1)
+    else:
+        # [B,S,H,D] -> [B,H,S,D], and `o` back
+        seq_axis = 2
+
+        def heads_first(x):
+            return x.transpose(0, 2, 1, 3)
+
+        def seq_first(o):
+            return o[:, :, :sq, :].transpose(0, 2, 1, 3)
+    qt = pad(heads_first(q), sq_p, seq_axis)
+    kt = pad(heads_first(k), sk_p, seq_axis)
+    vt = pad(heads_first(v), sk_p, seq_axis)
     if q_seg is not None:
         q_seg = pad(q_seg.astype(jnp.int32), sq_p, 1)
         kv_seg = pad(kv_seg.astype(jnp.int32), sk_p, 1)[..., None]
@@ -743,11 +861,11 @@ def flash_attention(
         # forward-only: bypass the custom_vjp (no bwd through the merge)
         o, lse = _fwd(qt, kt, vt, q_seg, kv_seg, causal, scale, bq, bk,
                       interpret, sq, sk, lens, block_causal, window)
-        return (o[:, :, :sq, :].transpose(0, 2, 1, 3),
+        return (seq_first(o),
                 lse.reshape(b, hq, sq_p)[:, :, :sq].transpose(0, 2, 1))
     o = _flash(qt, kt, vt, q_seg, kv_seg, causal, scale, bq, bk, interpret,
                sq, sk)
-    return o[:, :, :sq, :].transpose(0, 2, 1, 3)
+    return seq_first(o)
 
 
 def flash_attention_sharded(

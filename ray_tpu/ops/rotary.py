@@ -16,12 +16,15 @@ that layout).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def yarn_inv_freq(dim: int, theta: float, factor: float,
@@ -75,3 +78,89 @@ def rotate(x: jax.Array, positions: jax.Array, inv_freq,
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     out = out.astype(x.dtype)
     return out if rest is None else jnp.concatenate([out, rest], axis=-1)
+
+
+# ------------------------------------------------- the rotation, on rows
+# `[B, S, H, D]` is `[B, S, H x D]` as a projection writes it and as the
+# flash kernels' blocks index it (ops/flash_attention.py: `_heads_on_lanes`),
+# but XLA's own layout of the four-dimensional shape keeps H on the
+# sublanes: written in jax.numpy, the rotation costs a copy of the array on
+# its way in and another on its way out, and the split of a head's lanes at
+# d/2 two passes more (compiled for a described v5e: five passes over q
+# where this is one). The kernel reads a row's block, turns each head's
+# lanes against the tile's other half (`pltpu.roll`) and writes it back.
+_ROW_LANES = 1024   # lanes of a block: whole heads, 512 rows x 1024 x 2 B
+
+
+def _rows_kernel(x_ref, cos_ref, sin_ref, o_ref):
+    cos, sin = cos_ref[0], sin_ref[0]      # [rows, d]: (cos | cos), (-sin | sin)
+    d = cos.shape[1]
+    for head in range(x_ref.shape[2] // d):
+        lanes = slice(head * d, (head + 1) * d)
+        x = x_ref[0, :, lanes].astype(jnp.float32)
+        o_ref[0, :, lanes] = (x * cos + pltpu.roll(x, d // 2, 1) * sin
+                              ).astype(o_ref.dtype)
+
+
+def _rows_call(x, cos, sin, interpret):
+    b, s, h, d = x.shape
+    rows = next(r for r in (512, 256, 128) if s % r == 0)
+    heads = max(g for g in range(1, h + 1)
+                if h % g == 0 and g * d <= max(_ROW_LANES, d))
+    block = pl.BlockSpec((1, rows, heads * d), lambda b_, i, j: (b_, i, j))
+    table = pl.BlockSpec((1, rows, d), lambda b_, i, j: (b_, i, 0))
+    return pl.pallas_call(
+        _rows_kernel, grid=(b, s // rows, h // heads),
+        in_specs=[block, table, table], out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((b, s, h * d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3),
+        interpret=interpret,
+    )(x.reshape(b, s, h * d), cos, sin).reshape(x.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _turn_rows(x, cos, sin, interpret):
+    return _rows_call(x, cos, sin, interpret)
+
+
+def _turn_rows_fwd(x, cos, sin, interpret):
+    return _rows_call(x, cos, sin, interpret), (cos, sin)
+
+
+def _turn_rows_bwd(interpret, tables, g):
+    # the transpose of a rotation is the rotation back
+    cos, sin = tables
+    return (_rows_call(g, cos, -sin, interpret), jnp.zeros_like(cos),
+            jnp.zeros_like(sin))
+
+
+_turn_rows.defvjp(_turn_rows_fwd, _turn_rows_bwd)
+
+
+def rows_rotatable(x: jax.Array) -> bool:
+    """Whether `rotate_rows` takes `x` [B, S, H, D]: a head is whole lane
+    tiles and the sequence whole blocks of 128 rows (the flash kernels'
+    granule; a decode step's one token is not)."""
+    return x.shape[-1] % 128 == 0 and x.shape[1] % 128 == 0
+
+
+def rotate_rows(x: jax.Array, cos: jax.Array, sin: jax.Array, *,
+                interpret: Optional[bool] = None) -> jax.Array:
+    """`x1 cos - x2 sin | x2 cos + x1 sin` of every head of x [B, S, H, D]
+    (dims i and i + D/2 together, float32 arithmetic, x's dtype out) for
+    cos, sin [B, S, D/2]: `models/llama.py: rope`'s arithmetic as ONE pass
+    of a Pallas kernel over rows of `[B, S, H x D]`, with a backward that
+    is the same pass turned back. A one-device program, as the flash
+    kernels are (`interpret` as theirs: None is by the backend); under a
+    name of its own in a trace (`_rotate_rows`)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _rotate_rows(x, cos, sin, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _rotate_rows(x, cos, sin, *, interpret: bool):
+    cos = jnp.concatenate([cos, cos], axis=-1).astype(jnp.float32)
+    sin = jnp.concatenate([-sin, sin], axis=-1).astype(jnp.float32)
+    return _turn_rows(x, cos, sin, interpret)

@@ -243,16 +243,20 @@ class ShardedTrainer:
         tracing.record("train.step", (self._step_seq, r.start_ns, r.end_ns))
         return out
 
-    def program_text(self, state: TrainState, batch) -> str:
+    def program_text(self, state: TrainState, batch,
+                     compiled: bool = False) -> str:
         """The lowered (StableHLO) text of the train step for this state
         and batch — what chip_smoke.py reads to show that the Pallas
-        flash kernel (`tpu_custom_call`) is in the program."""
+        flash kernel (`tpu_custom_call`) is in the program. `compiled`:
+        the compiler's own text of it instead (the copies and layouts XLA
+        chose: tests/test_chip_compile.py reads them)."""
         if not isinstance(batch, dict):
             batch = {"input_ids": batch}
         if self._jit_step is None:
             self._build_step(batch)
         with active_mesh(self.mesh):
-            return self._jit_step.lower(state, batch).as_text()
+            lowered = self._jit_step.lower(state, batch)
+            return (lowered.compile() if compiled else lowered).as_text()
 
     def program_scopes(self) -> Optional[Dict[str, str]]:
         """Which scope each instruction of the train step belongs to:

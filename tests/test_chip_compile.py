@@ -162,6 +162,25 @@ def _paged_decode(b, d, mp, hq=32, hkv=8, page=16, layers=4,
     return build
 
 
+def _rope_rows(b, s, h, d=128):
+    """The rotation over rows of `[B, S, H x D]` (`ops/rotary.py:
+    rotate_rows`), forward and turned back: `pltpu.roll` by half a head on
+    a head's lanes, a block of whole heads."""
+    def build(topo):
+        from ray_tpu.ops.rotary import rotate_rows
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def fn(x, cos, sin):
+            return jax.value_and_grad(lambda x: rotate_rows(
+                x, cos, sin, interpret=False).astype(jnp.float32).sum())(x)
+        return fn, (
+            jax.ShapeDtypeStruct((b, s, h, d), BF16, sharding=one_chip),
+            *(jax.ShapeDtypeStruct((b, s, d // 2), jnp.float32,
+                                   sharding=one_chip),) * 2)
+    return build
+
+
 def _head_argmax(rows, h, v):
     """The head of a greedy block pass (ops/head_argmax.py): `rows`
     final-normed hidden states on the `lm_head` weights [h, v], at the
@@ -273,6 +292,12 @@ COMPILES = {
     "fwd-lse-lens-laguna-own-512-rep6": _ctx_lens(512, 512, 48, 8,
                                                   causal=True),
     "fwdbwd-shard_map-2x2-mesh": _flash_on_mesh,
+    # the rotation beside them (PR 60): the pretrain step's q and k, a
+    # [1 x 128] bucket of 48 heads, a wave of 20 heads on one kv head
+    "rope-rows-pretrain-q": _rope_rows(4, 2048, 32),
+    "rope-rows-pretrain-k": _rope_rows(4, 2048, 8),
+    "rope-rows-bucket128-48-heads": _rope_rows(1, 128, 48),
+    "rope-rows-wave-16x2048-20-heads": _rope_rows(16, 2048, 20),
     # the one-pass backward asks for the VMEM its shapes need (PR 44), so it
     # compiles as far as the forward does: these were refused at 8192
     "fwdbwd-kv8192": _flash(_grads(_fwd), (1, 8192, 32, 8, 64)),
@@ -380,6 +405,72 @@ def test_the_trainers_step_runs_two_kernels_a_layer(topo, policy, kernels):
         text = trainer.program_text(state, batch)
     assert text.count("tpu_custom_call") == kernels, \
         text.count("tpu_custom_call")
+
+
+def test_the_trainers_step_moves_no_head_around_its_attention(
+        topo, no_persistent_cache):
+    """The same step at heads of 128, COMPILED: q, k, v, `o` and their
+    gradients are read and written as rows of `[B, S, H x D]`, where the
+    projections leave them. Until PR 60 the flash kernels blocked `[B, H,
+    S, D]`, and XLA joined the transposes to and from it with `rope`'s
+    float32 arithmetic: eleven transposing copies a step at the pretrain
+    cell's shapes, and three copies a layer of the kept `o`, whose two
+    readers (the o projection, `delta`) wanted two layouts. Now a head is a
+    lane block of the kernels' operands (`ops/flash_attention.py:
+    _heads_on_lanes`) and the rotation a kernel over the same rows
+    (`ops/rotary.py: rotate_rows`; XLA's own layout of `[B, S, H, D]` keeps
+    H on the sublanes, so the rotation written in jax.numpy is a copy in
+    and a copy out). So the compiled step holds no `copy` and no
+    `transpose` of an array of q's or k's size, in the forward scan's body
+    or the backward's; a layer is two `attn` calls and six rotations (q and
+    k: forward, recomputed, turned back for dq and dk); and the kept `o`
+    is ONE stacked array of rows."""
+    import flax.linen as nn
+
+    from ray_tpu.models.llama import LlamaModel, get_config
+    from ray_tpu.parallel.train_lib import ShardedTrainer, TrainState
+
+    b, s, hq, hkv, d, layers = 2, 256, 4, 1, 128, 2
+    # (widths at which no other array has q's or k's number of elements)
+    cfg = get_config("tiny", remat=True, remat_policy="dots", head_dim=d,
+                     num_heads=hq, num_kv_heads=hkv, hidden_size=192,
+                     intermediate_size=320, vocab_size=384, num_layers=layers)
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape((1,) * len(AXES)), AXES)
+    trainer = ShardedTrainer(LlamaModel(cfg), mesh)
+    ids = jax.ShapeDtypeStruct((b, s), jnp.int32)
+    batch = {"input_ids": ids}
+
+    def init(rng):
+        params = nn.meta.unbox(trainer.model.init(
+            rng, jnp.zeros(ids.shape, ids.dtype))["params"])
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=trainer.tx.init(params))
+
+    state = jax.eval_shape(init, jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        state, trainer.state_shardings(batch))
+    with pytest.MonkeyPatch.context() as mp_ctx:
+        mp_ctx.setattr(jax, "default_backend", lambda: "tpu")
+        text = trainer.program_text(state, batch, compiled=True)
+
+    def elements(type_text):
+        return int(np.prod([int(n) for n in type_text.split(",") if n]))
+
+    moved = [line.strip()[:160] for line in text.splitlines()
+             for m in [re.search(
+                 r" = \w+\[([\d,]*)\]\S* (copy|transpose)\(", line)]
+             if m and elements(m.group(1)) in (b * s * hq * d, b * s * hkv * d,
+                                               layers * b * s * hq * d)]
+    assert not moved, moved
+    calls = re.findall(r"%([\w.-]+?)\.\d+ = [^=]*custom-call\([^)]*\), "
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert sorted(calls) == ["_rotate_rows"] * 6 + ["attn"] * 2, calls
+    # what the layer scan keeps of q's size a layer: `o`, once, as rows
+    kept = {m.group(0) for m in re.finditer(
+        r"bf16\[%d,[\d,]+\]" % layers, text)
+        if elements(m.group(0)[5:-1]) == layers * b * s * hq * d}
+    assert kept == {f"bf16[{layers},{b},{s},{hq * d}]"}, kept
 
 
 # ------------------------------------------- the engine's own programs
@@ -858,7 +949,10 @@ def test_sdar_program_fits_and_carries_its_pool_in_place(
         assert "s32[64,8]" in text and "[64,8,151936]" not in text
         assert total <= 11.3 * 2 ** 30, total / 2 ** 30
     else:
-        assert names == ["_moe_gmm", "attn"], kernels
+        # (since PR 60 q's and k's rotation is a kernel over the rows the
+        # flash kernel reads: models/llama.py: rope, ops/rotary.py)
+        assert names == ["_moe_gmm", "_rotate_rows", "attn"], kernels
+        assert sum(k.startswith("_rotate_rows") for k in kernels) == 2
         # a resumed pass: the own-tokens part and the part over its pages
         assert sum(k.startswith("attn") for k in kernels) == (
             2 if key[2] else 1)

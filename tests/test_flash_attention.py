@@ -146,6 +146,26 @@ TILE_CASES = {
     "values-narrower-than-keys": (256, 384, dict(causal=True, dv=16)),
     "values-narrower-not-causal-kv-lens": (
         128, 384, dict(causal=False, dv=16, kv_lens=(384, 129))),
+    # heads of 128 (`heads`: query heads on kv heads): q, k, v are read and
+    # `o` written as rows of [B, S, H x D], a head a lane block (PR 60)
+    "rows-mha-diagonal-2x2": (256, 256, dict(causal=True, heads=(2, 2))),
+    "rows-4-to-1-offset-diagonal": (
+        128, 384, dict(causal=True, heads=(4, 1))),
+    "rows-6-to-1-a-sequence-that-pads": (
+        200, 200, dict(causal=True, heads=(6, 1))),
+    "rows-8-to-1-not-causal-key-tail": (
+        128, 300, dict(causal=False, heads=(8, 1))),
+    "rows-20-to-1-q-and-kv-lens": (
+        256, 384, dict(causal=False, heads=(20, 1), q_lens=(256, 100),
+                       kv_lens=(384, 257))),
+    "rows-4-to-2-segment-ids": (
+        256, 256, dict(causal=True, heads=(4, 2), segs=(100, 100))),
+    "rows-4-to-2-segment-pair-over-a-longer-kv": (
+        128, 384, dict(causal=True, heads=(4, 2), segs=(0, 300))),
+    "rows-4-to-2-block-causal-4": (
+        256, 384, dict(causal=True, heads=(4, 2), block_causal=4)),
+    "rows-values-of-256-beside-keys-of-128": (
+        128, 256, dict(causal=True, heads=(2, 1), dv=256)),
 }
 
 
@@ -160,9 +180,14 @@ def test_forward_parity_by_tile_class(case):
     means nothing."""
     sq, sk, kw = TILE_CASES[case]
     kw = dict(kw)
-    dv, segs = kw.pop("dv", D), kw.pop("segs", None)
-    q, k, v = _make(sq, sk, seed=len(case))
-    v = v[..., :dv]
+    heads = kw.pop("heads", None)
+    if heads is None:
+        heads, d = (4, 2), D
+    else:
+        d = 128
+    dv, segs = kw.pop("dv", d), kw.pop("segs", None)
+    q, k, v = _make(sq, sk, *heads, seed=len(case), d=max(d, dv))
+    q, k, v = q[..., :d], k[..., :d], v[..., :dv]
     lens = {n: jnp.asarray(kw.pop(n), jnp.int32)
             for n in ("q_lens", "kv_lens") if n in kw}
     if segs is not None:
@@ -171,7 +196,8 @@ def test_forward_parity_by_tile_class(case):
     o, lse = flash_attention(
         q, k, v, interpret=True, return_lse=True, block_q=128, block_k=128,
         segment_ids=segs, **lens, **kw)
-    assert o.shape == (B, sq, 4, dv) and lse.shape == (B, sq, 4)
+    assert o.shape == (B, sq, heads[0], dv)
+    assert lse.shape == (B, sq, heads[0])
     _assert_real_rows_match(o, lse, q, k, v, *_visible(
         sq, sk, segs=segs, **lens, **kw))
 
@@ -264,6 +290,11 @@ FOLD_CASES = {
     "1-of-4-at-384": (8, 2, 384, 384, 384, 1),
     "1-of-4-at-512": (8, 2, 512, 512, 512, 1),
     "mha-at-128": (2, 2, 128, 256, 128, 1),
+    # heads of 128: the folded heads are g * 128 contiguous lanes of a row,
+    # stacked on sublanes in VMEM (PR 60)
+    "rows-4-of-4-at-128": (8, 2, 128, 256, 128, 4, 128),
+    "rows-2-of-6-at-256": (6, 1, 256, 256, 256, 2, 128),
+    "rows-4-of-20-at-128": (20, 1, 128, 256, 128, 4, 128),
 }
 
 
@@ -277,9 +308,9 @@ def test_a_short_query_block_folds_its_kv_heads_group_along_the_lanes(case):
     diagonal, lengths and segment ids alike."""
     from ray_tpu.ops.flash_attention import _fold
 
-    hq, hkv, sq, sk, bq, g = FOLD_CASES[case]
+    hq, hkv, sq, sk, bq, g, *d = FOLD_CASES[case]
     assert _fold(hq // hkv, bq) == g
-    q, k, v = _make(sq, sk, hq=hq, hkv=hkv, seed=len(case))
+    q, k, v = _make(sq, sk, hq=hq, hkv=hkv, seed=len(case), d=(d or [D])[0])
     lens = dict(q_lens=jnp.asarray([sq - 30, sq], jnp.int32),
                 kv_lens=jnp.asarray([sk, sk - 100], jnp.int32))
     segs = (_two_segments(sk, 100)[:, sk - sq:], _two_segments(sk, 100))
@@ -342,6 +373,14 @@ GRAD_CASES = {
     "key-tile-wider": (512, 512, 4, 2, D, True, (128, 256)),
     "not-causal-2x3-tiles": (256, 384, 4, 2, D, False, (128, 128)),
     "not-causal-pads": (130, 200, 2, 2, D, False, (128, 128)),
+    # heads of 128: q, do, dq a lane block of [B, S, Hq x D], k, v, dk, dv
+    # of [B, S, Hkv x D], `delta` from `o` as the o projection reads it
+    "rows-mha-2x2-tiles": (256, 256, 2, 2, 128, True, (128, 128)),
+    "rows-4-to-1-sq-lt-sk": (128, 384, 4, 1, 128, True, (128, 128)),
+    "rows-6-to-1-pads-200": (200, 200, 6, 1, 128, True, (128, 128)),
+    "rows-8-to-2-one-tile": (128, 128, 8, 2, 128, True, None),
+    "rows-20-to-1-not-causal": (128, 256, 20, 1, 128, False, (128, 128)),
+    "rows-d256-2-to-1": (128, 128, 2, 1, 256, True, None),
 }
 
 
@@ -359,6 +398,7 @@ SEGMENT_GRAD_CASES = {
     "group-of-4-d64-3x3-tiles": (384, 4, 1, 64, 3, (128, 128)),
     "mha-d128-segments-inside-tiles": (256, 2, 2, 128, 8, (128, 128)),
     "pads-200": (200, 4, 2, D, 4, (128, 128)),
+    "rows-4-to-2-d128-pads-200": (200, 4, 2, 128, 4, (128, 128)),
 }
 
 
@@ -532,8 +572,8 @@ def _jaxpr_sha(fn, *args):
 
 
 @pytest.mark.parametrize("case, sha", [
-    ("plain", "13ebb5a15f4d6fa9"), ("packed", "c43460414616763e"),
-    ("lse", "afaa56b993c2c7a3")])
+    ("plain", "dad2d0203e442b0a"), ("packed", "2fafad410f79a346"),
+    ("lse", "b7e701b10c4a1f24")])
 def test_the_trainers_call_traces_to_the_jaxpr_it_had(case, sha):
     """The calls that pass no lengths, forward and backward kernels at the
     pretrain cell's shapes: the jaxpr is pinned to the character
@@ -551,8 +591,15 @@ def test_the_trainers_call_traces_to_the_jaxpr_it_had(case, sha):
     and `packed` anew (77c1c24c3392e9c0 and 185f042aeedeb177 until then):
     the forward rule names `o` and `lse` for a remat policy to list (two
     `name` equations; the kernels' calls are to the character what they
-    were), and `lse`, which never meets the forward rule, did not move. A
-    deliberate change of the kernels reads them anew."""
+    were), and `lse`, which never meets the forward rule, did not move.
+    PR 60 read all three anew (13ebb5a15f4d6fa9, c43460414616763e and
+    afaa56b993c2c7a3 until then): these shapes, heads of 128, fall under
+    its rule (`_heads_on_lanes`), so the calls' operands are rows of `[B,
+    S, H x D]` with a head a lane block, the four transposes around a call
+    are gone, and the backward's `delta` is a product of `do * o` with the
+    heads' lanes; the shapes the rule leaves alone are pinned to the
+    parent's text below. A deliberate change of the kernels reads them
+    anew."""
     q = jnp.zeros((2, 2048, 32, 128), jnp.bfloat16)
     k = v = jnp.zeros((2, 2048, 8, 128), jnp.bfloat16)
 
@@ -561,12 +608,60 @@ def test_the_trainers_call_traces_to_the_jaxpr_it_had(case, sha):
                                interpret=False).astype(jnp.float32).sum()
 
     if case == "lse":
-        got = _jaxpr_sha(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, interpret=False, return_lse=True), q, k, v)
+        fn, args = (lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=False, return_lse=True)), ()
     else:
-        seg = (jnp.zeros((2, 2048), jnp.int32),) if case == "packed" else ()
-        got = _jaxpr_sha(jax.grad(loss, argnums=(0, 1, 2)), q, k, v, *seg)
-    assert got == sha
+        args = (jnp.zeros((2, 2048), jnp.int32),) if case == "packed" else ()
+        fn = jax.grad(loss, argnums=(0, 1, 2))
+    assert _jaxpr_sha(fn, q, k, v, *args) == sha
+    # every call's q, k, v (and the backward's do, dq, dk, dv) are rows
+    for call in _kernel_calls(fn, q, k, v, *args):
+        rows = [x.aval.shape for x in (*call.invars, *call.outvars)
+                if x.aval.dtype == jnp.bfloat16]
+        assert set(rows) == {(2, 2048, 32 * 128), (2, 2048, 8 * 128)}
+
+
+@pytest.mark.parametrize("case, sha", [
+    ("a-head-of-16", "f0f033bcb8c431b4"),
+    ("a-head-of-16-lens", "c10c7fc466c90dde"),
+    ("keys-of-192-values-of-128", "2d8041e64d353148")])
+def test_a_head_that_is_no_lane_tile_keeps_the_operands_it_had(case, sha):
+    """The rule is the operands' shape (`_heads_on_lanes`): a head is a
+    lane block of a row only where the keys' and the values' widths are
+    whole lane tiles. A tiny configuration's head of 16 (forward and
+    backward; the forward with lengths) and a latent family's keys of 192
+    beside values of 128 (`_mla_flash`: forward only) keep `[B, H, S, D]`
+    operands and the transposes that make them, and trace to the jaxpr the
+    tree had before PR 60, to the character: the hashes were read on the
+    parent commit (798d9e5) with this file's `_jaxpr_sha`."""
+    bf16 = jnp.bfloat16
+    if case == "keys-of-192-values-of-128":
+        q = k = jnp.zeros((1, 512, 4, 192), bf16)
+        v = jnp.zeros((1, 512, 4, 128), bf16)
+
+        def fn(q, k, v):
+            return flash_attention(
+                q, k, v, causal=True, interpret=False, return_lse=True,
+                kv_lens=jnp.asarray([300], jnp.int32))
+    else:
+        q = jnp.zeros((2, 256, 4, 16), bf16)
+        k = v = jnp.zeros((2, 256, 2, 16), bf16)
+
+        def fn(q, k, v):
+            return flash_attention(
+                q, k, v, causal=True, interpret=False, return_lse=True,
+                q_lens=jnp.asarray([3, 200], jnp.int32))
+        if case == "a-head-of-16":
+            fn = jax.grad(lambda q, k, v: flash_attention(
+                q, k, v, causal=True, interpret=False).astype(
+                    jnp.float32).sum(), argnums=(0, 1, 2))
+    assert _jaxpr_sha(fn, q, k, v) == sha
+    b, s, h, d = q.shape
+    for call in _kernel_calls(fn, q, k, v):
+        heads_first = [x.aval.shape for x in call.invars
+                       if x.aval.dtype == bf16]
+        assert heads_first[0] == (b, h, s, d)
+        assert all(len(shape) == 4 for shape in heads_first)
 
 
 # ------------------------------------------------------- a sliding window
@@ -605,6 +700,11 @@ WINDOW_CASES = {
     "context-window-over-the-ring": (256, 128, 300, False, None, (128, 64),
                                      (128, 128)),
     "default-blocks": (1024, 1024, 600, True, None, None, (512, 512)),
+    # heads of 128, 8 on 1 (a sliding layer of models/laguna.py): rows
+    "rows-8-to-1-between-tiles": (384, 384, 200, True, None, None,
+                                  (128, 128), (8, 1, 128)),
+    "rows-8-to-1-context-short-ring": (256, 256, 256, False, (256, 100),
+                                       (100, 31), (128, 128), (8, 1, 128)),
 }
 
 
@@ -619,8 +719,10 @@ def test_forward_parity_under_a_window(case):
     from ray_tpu.ops.flash_attention import (_band_offset, _band_trips,
                                              _fwd_trips)
 
-    sq, sk, window, causal, q_lens, kv_lens, (bq, bk) = WINDOW_CASES[case]
-    q, k, v = _make(sq, sk, seed=7)
+    sq, sk, window, causal, q_lens, kv_lens, (bq, bk), *heads = (
+        WINDOW_CASES[case])
+    hq, hkv, d = heads[0] if heads else (4, 2, D)
+    q, k, v = _make(sq, sk, hq, hkv, seed=7, d=d)
     lens = {} if q_lens is None else {"q_lens": jnp.asarray(q_lens)}
     if kv_lens is not None:
         lens["kv_lens"] = jnp.asarray(kv_lens)
