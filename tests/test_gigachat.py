@@ -7,7 +7,6 @@ tokens, wherever a number can be compared.
 """
 
 import functools
-import hashlib
 
 import flax.linen as nn
 import jax
@@ -436,43 +435,3 @@ def test_a_slice_of_the_stack_is_refused():
     with pytest.raises(NotImplementedError, match="two mixers"):
         gigachat.serving_model(gigachat.get_config("tiny-gigachat"), 1, True,
                                False)
-
-
-# --------------------- (e) every other family's programs keep their text
-# read on the parent commit (PR 49's tree): the lowered text of the tiny
-# serving programs of the two families whose modules this one imports from
-# and edited (an output gate that defaults to none, a clamp that defaults to
-# none), float32, CPU. (Mistral's, Mixtral's, Jamba's and MiniCPM-SALA's are
-# held by tests/test_sdar.py, SDAR's by tests/test_kimi.py.) (Read again on
-# PR 51's tree, which put every prefill and decode program's sampler behind
-# a `cond`.)
-PROGRAM_SHAS = {
-    "tiny-kimi:prefill:(32, 2, 0)": "294666d94664b830",
-    "tiny-kimi:prefill:(32, 2, 16)": "6354526a6b850643",
-    "tiny-kimi:prefill:(64, 2, 0)": "f7bffcbcf1c7c152",
-    "tiny-kimi:prefill:(64, 2, 16)": "be045149bdeab1b4",
-    "tiny-kimi:decode:(1, 16)": "508614620678e52b",
-    "tiny-mellum:prefill:(32, 2, 0)": "d1c20523784a7a52",
-    "tiny-mellum:prefill:(32, 2, 16)": "658bc43c2bb9b85e",
-    "tiny-mellum:prefill:(64, 2, 0)": "71fe110de3ffcb23",
-    "tiny-mellum:prefill:(64, 2, 16)": "ab342b3fd88253fb",
-    "tiny-mellum:decode:(1, 16)": "7c6744cb80460bde",
-}
-
-
-@pytest.fixture(scope="module")
-def program_shas():
-    out = {}
-    for preset in ("tiny-kimi", "tiny-mellum"):
-        eng = LLMEngine(EngineConfig(**{**CFG, "model": preset}))
-        for kind, key in eng._warmup_programs(None, True):
-            out[f"{preset}:{kind}:{key}"] = hashlib.sha256(
-                eng.program_text(kind, key).encode()).hexdigest()[:16]
-        eng.close()
-    return out
-
-
-@pytest.mark.parametrize("program", sorted(PROGRAM_SHAS))
-def test_the_families_imported_from_keep_their_lowered_text(program_shas,
-                                                            program):
-    assert program_shas[program] == PROGRAM_SHAS[program]

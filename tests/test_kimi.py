@@ -7,7 +7,6 @@ wherever a number can be compared.
 """
 
 import functools
-import hashlib
 import math
 
 import flax.linen as nn
@@ -553,38 +552,3 @@ def test_what_a_latent_pool_cannot_be_given_is_refused_by_name(over, what):
 def test_a_slice_of_the_stack_is_refused():
     with pytest.raises(NotImplementedError, match="a dense run"):
         kimi.serving_model(kimi.get_config("tiny-kimi"), 1, True, False)
-
-
-# --------------------- (j) every other family's programs keep their text
-# read on the parent commit (PR 42's tree): the lowered text of SDAR's tiny
-# serving programs, float32, CPU. (Mistral's, Mixtral's, Jamba's and
-# MiniCPM-SALA's are held by tests/test_sdar.py.) (The prefill programs'
-# were read again on PR 51's tree, which put their sampler behind a `cond`;
-# the block program, with its own sampler, kept PR 42's text until PR 54,
-# which is a change to that program: the head and the decision of a pass
-# behind one `cond`, read again on PR 54's tree.)
-SDAR_SHAS = {
-    "tiny-sdar:prefill:(32, 2, 0)": "d754dafa5b91be7b",
-    "tiny-sdar:prefill:(32, 2, 16)": "1515272d01e5cdf9",
-    "tiny-sdar:prefill:(64, 2, 0)": "8e6d20790b1d2513",
-    "tiny-sdar:prefill:(64, 2, 16)": "4caef83d460e013a",
-    "tiny-sdar:prefill:(128, 2, 0)": "8af33472245683d3",
-    "tiny-sdar:prefill:(128, 2, 16)": "3b68f1965e23582d",
-    "tiny-sdar:block:(4, 4, 16)": "779b5505889ed6ce",
-}
-
-
-@pytest.fixture(scope="module")
-def sdar_shas():
-    eng = LLMEngine(EngineConfig(**{**CFG, "model": "tiny-sdar",
-                                    "prefill_buckets": (32, 64, 128)}))
-    out = {f"tiny-sdar:{kind}:{key}": hashlib.sha256(
-        eng.program_text(kind, key).encode()).hexdigest()[:16]
-        for kind, key in eng._warmup_programs(None, True)}
-    eng.close()
-    return out
-
-
-@pytest.mark.parametrize("program", sorted(SDAR_SHAS))
-def test_sdars_programs_keep_their_lowered_text(sdar_shas, program):
-    assert sdar_shas[program] == SDAR_SHAS[program]

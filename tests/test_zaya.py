@@ -333,10 +333,9 @@ def _moe(k=2, **over):
 
 def test_a_choice_from_outside_is_served_as_the_layers_own():
     """The seam unused, the layer has its router and is what it was (the
-    sha256 tables of tests/test_sdar.py, test_kimi.py, test_gigachat.py and
-    test_laguna.py hold every expert family's lowered text); given the
-    choice its own router would make, it has no router and returns the
-    same values."""
+    sha256 table of tests/test_program_pins.py holds every family's lowered
+    text); given the choice its own router would make, it has no router
+    and returns the same values."""
     cfg, layer, params, x = _moe()
     own = layer.apply({"params": params}, x)
     probs = jax.nn.softmax(jnp.einsum("th,he->te", x.reshape(-1, 32),
