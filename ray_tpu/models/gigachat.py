@@ -68,7 +68,10 @@ from flax import struct
 from ..ops.gated_delta import CHUNK, gdn_prefill, gdn_update
 from ..ops.selective_scan import live_slots
 from ..util import tracing
-from .kimi import KimiConfig, LatentFacts, MLAttention, _dense
+from ._stack import (default_positions, dense as _dense, embed_tokens,
+                     head_at_gather, own_cache, scan_run, stacked_experts,
+                     whole_model_only)
+from .kimi import KimiConfig, LatentFacts, MLAttention
 from .llama import MLP, A, ExpertFacts, MoEMLP
 
 GDN, MLA = "gated-delta", "latent"
@@ -273,12 +276,9 @@ class GdnLatentCache:
 
 # ----------------------------------------------------------------- serving
 def serving_model(cfg: GigaChatConfig, n_layers=None, first=True, last=True):
-    if not (first and last):
-        raise NotImplementedError(
-            "a slice of a model whose layers are a list of two mixers and "
-            "two FFNs: pipeline stages cut a uniform `layers` axis "
-            "(serve/llm/stage.py: stage_params)")
-    return GigaChatModel(cfg)
+    return whole_model_only(
+        GigaChatModel, cfg, first, last,
+        "whose layers are a list of two mixers and two FFNs")
 
 
 # (stage.py: model_family) a prefill row RESUMES from its slot and its
@@ -539,43 +539,23 @@ class GigaChatLayer(nn.Module):
                 conv), None
 
 
-def _run(cfg: GigaChatConfig, length: int, name: str, **attrs):
-    return nn.scan(
-        GigaChatLayer, variable_axes={"params": 0, "routing": 0,
-                                      "selection": 0},
-        split_rngs={"params": True}, length=length,
-        in_axes=(0, nn.broadcast),
-        metadata_params={nn.PARTITION_NAME: "layers"})(cfg, name=name,
-                                                       **attrs)
-
-
 class GigaChatModel(nn.Module):
     config: GigaChatConfig
 
     @nn.compact
     def __call__(self, input_ids, positions=None, kv_caches=None,
                  token_mask=None):
-        """input_ids [B, S] -> logits [B, S, V]; with `kv_caches` (a
-        GdnLatentCache) -> (logits, the cache with its pools updated): S
-        == 1 is a decode step over the slot set, S > 1 a prefill pass that
-        resumes from the rows' slots and pages; with `gather` the logits
-        are [B, 1, V], at that position of each row. Without a cache the
-        same paged path runs over a pool of its own (one page set and one
-        slot a row), from zero state. `token_mask` [B, S] bool marks
-        padding (the expert layers give it no expert)."""
+        """THE CALL of models/_stack.py, `kv_caches` a GdnLatentCache: a
+        prefill pass resumes from the rows' slots (state and tail) and
+        latent pages."""
         cfg = self.config
-        b, s = input_ids.shape
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        cache = kv_caches
-        if cache is None:
-            cache = self._own_cache(b, s, token_mask)
+        s = input_ids.shape[1]
+        positions = default_positions(input_ids, positions)
+        cache = kv_caches if kv_caches is not None else own_cache(
+            pool_spec, serving_cache, cfg, *input_ids.shape, token_mask)
         if token_mask is None:
             token_mask = positions < cache.total_lens[:, None]
-        embed = self.param(
-            "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = embed[input_ids].astype(cfg.dtype)
+        _, x = embed_tokens(self, cfg, input_ids)
 
         start = positions[:, 0]
         n_real = jnp.clip(cache.total_lens - start, 0, s)
@@ -585,57 +565,24 @@ class GigaChatModel(nn.Module):
         at = {GDN: 0, MLA: 0}
         for r, ((mixer, dense), n) in enumerate(cfg.runs):
             name = f"run_{r:02d}"
-            experts = None
-            if (not dense and kv_caches is not None
-                    and not self.is_initializing()):
-                moe = nn.meta.unbox(self.get_variable("params", name))["moe"]
-                experts = (moe["experts_gate_up"].astype(cfg.dtype),
-                           moe["experts_down"].astype(cfg.dtype))
+            experts = (stacked_experts(self, cfg, (name, "moe"))
+                       if not dense and kv_caches is not None else None)
             consts = (positions, cache.block_tables, cache.total_lens, start,
                       n_real, cache.slots, order, token_mask, experts)
-            carry, _ = _run(cfg, n, name, mixer=mixer, dense=dense,
-                            ctx_pages=cache.ctx_pages,
-                            ref_attention=cache.ref_attention)(
+            carry, _ = scan_run(GigaChatLayer, n, name, cfg, mixer=mixer,
+                                dense=dense, ctx_pages=cache.ctx_pages,
+                                ref_attention=cache.ref_attention)(
                 carry, (at[mixer] + jnp.arange(n), jnp.arange(n)), consts)
             at[mixer] += n
         x, pages, state, conv = carry
 
         with tracing.scope("rtpu.head"):
             x = Norm(cfg, name="final_norm")(x)
-        # a plain leaf, not a Dense: the head runs under `lax.cond` below
-        head_w = self.param(
-            "lm_head", A(nn.initializers.lecun_normal(), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
-
-        def head(a):
-            with tracing.scope("rtpu.head"):
-                return jnp.dot(a, head_w.astype(cfg.dtype))
-
-        if cache.gather is None:
-            logits = head(x)
-        else:
-            at_gather = jnp.take_along_axis(
-                x, jnp.maximum(cache.gather, 0)[:, None, None], axis=1)
-            logits = jax.lax.cond(
-                jnp.any(cache.gather >= 0), head,
-                lambda a: jnp.zeros(a.shape[:2] + (cfg.vocab_size,),
-                                    cfg.dtype), at_gather)
+        logits = head_at_gather(self, cfg, x, cache.gather)
         if kv_caches is None:
             return logits
         return logits, cache.replace(latent_pages=pages, gdn_state=state,
                                      gdn_conv=conv)
-
-    def _own_cache(self, b: int, s: int, token_mask) -> GdnLatentCache:
-        cfg = self.config
-        page = 16
-        mp = -(-s // page) + 1
-        pool = {k: jnp.zeros(*sd) for k, sd in pool_spec(
-            cfg, cfg.num_layers, 1 + b * mp, page, b).items()}
-        total = (jnp.full((b,), s, jnp.int32) if token_mask is None
-                 else token_mask.sum(-1).astype(jnp.int32))
-        return serving_cache(
-            cfg, pool, 1 + jnp.arange(b * mp, dtype=jnp.int32
-                                      ).reshape(b, mp), total)
 
 
 # ---------------------------------------------------------------- registry

@@ -62,6 +62,8 @@ from ..ops.selective_scan import _impl as _scan_impl
 from ..ops.selective_scan import (live_slots, selective_scan,
                                   selective_update, state_shape)
 from ..util import tracing
+from ._stack import (default_positions, dense, embed_tokens, scan_run,
+                     whole_model_only)
 from .llama import MLP, A, Attention, PagedCache, RMSNorm
 from .llama import serving_cache as _paged_cache
 
@@ -199,11 +201,8 @@ RESUMES_PREFILL = False
 
 
 def serving_model(cfg: JambaConfig, n_layers=None, first=True, last=True):
-    if not (first and last):
-        raise NotImplementedError(
-            "a slice of a model with a layer pattern: pipeline stages cut "
-            "a uniform `layers` axis (serve/llm/stage.py: stage_params)")
-    return JambaModel(cfg)
+    return whole_model_only(JambaModel, cfg, first, last,
+                            "with a layer pattern")
 
 
 # (stage.py: model_family) the state cannot be rolled back, resumed
@@ -336,14 +335,7 @@ class MambaMixer(nn.Module):
         b, s, _ = u.shape
         f32 = jnp.float32
 
-        def dense(features, axes, name, use_bias=False):
-            return nn.DenseGeneral(
-                features=features, use_bias=use_bias, axis=-1,
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                kernel_init=A(nn.initializers.lecun_normal(), axes),
-                name=name)
-
-        xz = dense(2 * d, ("embed", "mlp"), "in_proj")(u)
+        xz = dense(cfg, 2 * d, ("embed", "mlp"), "in_proj")(u)
         x, z = jnp.split(xz, 2, axis=-1)
         conv_w = self.param("conv_kernel", A(_conv_init(k), (None, "mlp")),
                             (k, d), cfg.param_dtype).astype(cfg.dtype)
@@ -372,13 +364,13 @@ class MambaMixer(nn.Module):
                     for j in range(k)) + conv_b
         x = nn.silu(x)
 
-        dbc = dense(r + 2 * n, ("mlp", None), "x_proj")(x)
+        dbc = dense(cfg, r + 2 * n, ("mlp", None), "x_proj")(x)
         dt, bm, cm = jnp.split(dbc, [r, r + n], axis=-1)
         dt = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="dt_norm")(dt)
         bm = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="b_norm")(bm)
         cm = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="c_norm")(cm)
         delta = jax.nn.softplus(
-            dense(d, (None, "mlp"), "dt_proj")(dt).astype(f32) + dt_bias)
+            dense(cfg, d, (None, "mlp"), "dt_proj")(dt).astype(f32) + dt_bias)
         if mask is not None:
             delta = jnp.where(mask[..., None], delta, 0.0)
         a_neg = -jnp.exp(a_log)
@@ -433,7 +425,7 @@ class MambaMixer(nn.Module):
                                 ssm_conv, tails[i][j][None, None, None],
                                 (layer, j, slots[i], 0))
                 new_state = (ssm_h, ssm_conv)
-        out = dense(cfg.hidden_size, ("mlp", "embed"), "out_proj")(y)
+        out = dense(cfg, cfg.hidden_size, ("mlp", "embed"), "out_proj")(y)
         return out, new_state
 
 
@@ -474,14 +466,6 @@ class AttentionLayer(nn.Module):
         return x, new_cache
 
 
-def _mamba_run(cfg: JambaConfig, length: int, name: str):
-    return nn.scan(
-        MambaLayer, variable_axes={"params": 0},
-        split_rngs={"params": True}, length=length,
-        in_axes=(0, nn.broadcast),
-        metadata_params={nn.PARTITION_NAME: "layers"})(cfg, name=name)
-
-
 class Period(nn.Module):
     """Period `index` of the layer pattern: a run of Mamba layers, the
     attention layer, a run of Mamba layers."""
@@ -498,7 +482,7 @@ class Period(nn.Module):
         first = self.index * (pre + post)  # this period's first Mamba layer
         run = (x, ssm_h, ssm_conv)
         if pre:
-            run, _ = _mamba_run(cfg, pre, "pre")(
+            run, _ = scan_run(MambaLayer, pre, "pre", cfg)(
                 run, first + jnp.arange(pre), (mask, slots))
         x, ssm_h, ssm_conv = run
         if paged is not None:
@@ -512,7 +496,7 @@ class Period(nn.Module):
             kv_pages = new_paged.kv_pages
         run = (x, ssm_h, ssm_conv)
         if post:
-            run, _ = _mamba_run(cfg, post, "post")(
+            run, _ = scan_run(MambaLayer, post, "post", cfg)(
                 run, first + pre + jnp.arange(post), (mask, slots))
         x, ssm_h, ssm_conv = run
         return x, kv_pages, ssm_h, ssm_conv
@@ -530,13 +514,8 @@ class JambaModel(nn.Module):
         zero state. `token_mask` [B, S] bool marks padding where there is
         no cache to say it (with one: positions < total_lens)."""
         cfg = self.config
-        if positions is None:
-            positions = jnp.broadcast_to(
-                jnp.arange(input_ids.shape[1]), input_ids.shape[:2])
-        embed = self.param(
-            "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = embed[input_ids].astype(cfg.dtype)
+        positions = default_positions(input_ids, positions)
+        embed, x = embed_tokens(self, cfg, input_ids)
 
         cache = kv_caches
         kv_pages = ssm_h = ssm_conv = paged = slots = None
@@ -562,11 +541,8 @@ class JambaModel(nn.Module):
                 logits = jnp.einsum("bsh,vh->bsv", x,
                                     embed.astype(cfg.dtype))
             else:
-                logits = nn.DenseGeneral(
-                    features=cfg.vocab_size, use_bias=False, axis=-1,
-                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                    kernel_init=A(nn.initializers.lecun_normal(),
-                                  ("embed", "vocab")), name="lm_head")(x)
+                logits = dense(cfg, cfg.vocab_size, ("embed", "vocab"),
+                               "lm_head")(x)
         if cache is None:
             return logits
         return logits, cache.replace(

@@ -55,6 +55,9 @@ from ..ops.paged_attention import (LATENT_CTX_CHUNK, latent_attention_decode,
                                    latent_prefill_attention, paged_write)
 from ..ops.rotary import rotate, yarn_inv_freq, yarn_mscale
 from ..util import tracing
+from ._stack import (default_positions, dense as _dense, embed_tokens,
+                     head_at_gather, own_cache, scan_run, stacked_experts,
+                     whole_model_only)
 from .llama import MLP, A, ExpertFacts, LlamaConfig, MoEMLP, RMSNorm
 
 # the family's interface flags (serve/llm/stage.py: model_family): pages
@@ -205,12 +208,8 @@ class LatentCache:
 
 # ----------------------------------------------------------------- serving
 def serving_model(cfg: KimiConfig, n_layers=None, first=True, last=True):
-    if not (first and last):
-        raise NotImplementedError(
-            "a slice of a model whose stack is a dense run and an expert "
-            "run: pipeline stages cut a uniform `layers` axis "
-            "(serve/llm/stage.py: stage_params)")
-    return KimiModel(cfg)
+    return whole_model_only(KimiModel, cfg, first, last,
+                            "whose stack is a dense run and an expert run")
 
 
 # (stage.py: model_family) the options that would split a head axis the pool
@@ -318,13 +317,6 @@ def serving_cache(cfg: KimiConfig, pool, block_tables, total_lens=None,
 
 
 # ------------------------------------------------------------------ layers
-def _dense(cfg, features, axes, name):
-    return nn.DenseGeneral(
-        features=features, use_bias=False, axis=-1, dtype=cfg.dtype,
-        param_dtype=cfg.param_dtype,
-        kernel_init=A(nn.initializers.lecun_normal(), axes), name=name)
-
-
 class MLAttention(nn.Module):
     """`gated`: each head's output is multiplied, value by value, by
     sigmoid(W_g,h x) of the mixer's own input before W_o (Qiu et al. 2025,
@@ -435,49 +427,24 @@ class KimiLayer(nn.Module):
         return (x + h, kv_pages), None
 
 
-def _run(cfg: KimiConfig, length: int, name: str, **attrs):
-    return nn.scan(
-        KimiLayer, variable_axes={"params": 0, "routing": 0,
-                                  "selection": 0},
-        split_rngs={"params": True}, length=length,
-        in_axes=(0, nn.broadcast),
-        metadata_params={nn.PARTITION_NAME: "layers"})(cfg, name=name,
-                                                       **attrs)
-
-
 class KimiModel(nn.Module):
     config: KimiConfig
 
     @nn.compact
     def __call__(self, input_ids, positions=None, kv_caches=None,
                  token_mask=None):
-        """input_ids [B, S] -> logits [B, S, V]; with `kv_caches` (a
-        LatentCache) -> (logits, the cache with its pool updated): S == 1
-        is a decode step in the absorbed form, S > 1 a prefill pass in the
-        materialised form that resumes from the rows' pages; with `gather`
-        the logits are [B, 1, V], at that position of each row. Without a
-        cache the same paged path runs over a pool of its own. `token_mask`
-        [B, S] bool marks padding (the expert layers give it no expert)."""
+        """THE CALL of models/_stack.py, `kv_caches` a LatentCache: a decode
+        step runs the absorbed form, a prefill pass the materialised one."""
         cfg = self.config
-        b, s = input_ids.shape
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        cache = kv_caches
-        if cache is None:
-            cache = self._own_cache(b, s, token_mask)
+        positions = default_positions(input_ids, positions)
+        cache = kv_caches if kv_caches is not None else own_cache(
+            pool_spec, serving_cache, cfg, *input_ids.shape, token_mask)
         if token_mask is None:
             token_mask = positions < cache.total_lens[:, None]
-        embed = self.param(
-            "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = embed[input_ids].astype(cfg.dtype)
+        _, x = embed_tokens(self, cfg, input_ids)
 
-        experts = None
-        if (cfg.n_expert_layers and kv_caches is not None
-                and not self.is_initializing()):
-            moe = nn.meta.unbox(self.get_variable("params", "layers"))["moe"]
-            experts = (moe["experts_gate_up"].astype(cfg.dtype),
-                       moe["experts_down"].astype(cfg.dtype))
+        experts = (stacked_experts(self, cfg, ("layers", "moe"))
+                   if cfg.n_expert_layers and kv_caches is not None else None)
         carry = (x, cache.kv_pages)
         at = 0
         for name, dense, n in (
@@ -487,47 +454,19 @@ class KimiModel(nn.Module):
                 continue
             consts = (positions, cache.block_tables, cache.total_lens,
                       token_mask, None if dense else experts)
-            carry, _ = _run(cfg, n, name, dense=dense,
-                            ctx_pages=cache.ctx_pages,
-                            ref_attention=cache.ref_attention)(
+            carry, _ = scan_run(KimiLayer, n, name, cfg, dense=dense,
+                                ctx_pages=cache.ctx_pages,
+                                ref_attention=cache.ref_attention)(
                 carry, (at + jnp.arange(n), jnp.arange(n)), consts)
             at += n
         x, kv_pages = carry
 
         with tracing.scope("rtpu.head"):
             x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-        # a plain leaf, not a Dense: the head runs under `lax.cond` below
-        head_w = self.param(
-            "lm_head", A(nn.initializers.lecun_normal(), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
-
-        def head(a):
-            with tracing.scope("rtpu.head"):
-                return jnp.dot(a, head_w.astype(cfg.dtype))
-
-        if cache.gather is None:
-            logits = head(x)
-        else:
-            at_gather = jnp.take_along_axis(
-                x, jnp.maximum(cache.gather, 0)[:, None, None], axis=1)
-            logits = jax.lax.cond(
-                jnp.any(cache.gather >= 0), head,
-                lambda a: jnp.zeros(a.shape[:2] + (cfg.vocab_size,),
-                                    cfg.dtype), at_gather)
+        logits = head_at_gather(self, cfg, x, cache.gather)
         if kv_caches is None:
             return logits
         return logits, cache.replace(kv_pages=kv_pages)
-
-    def _own_cache(self, b: int, s: int, token_mask) -> LatentCache:
-        cfg = self.config
-        page = 16
-        mp = -(-s // page) + 1
-        shape, dtype = pool_spec(cfg, cfg.num_layers, 1 + b * mp, page, b)
-        total = (jnp.full((b,), s, jnp.int32) if token_mask is None
-                 else token_mask.sum(-1).astype(jnp.int32))
-        return serving_cache(
-            cfg, jnp.zeros(shape, dtype),
-            1 + jnp.arange(b * mp, dtype=jnp.int32).reshape(b, mp), total)
 
 
 # ---------------------------------------------------------------- registry

@@ -33,6 +33,8 @@ from ..ops.paged_attention import (paged_attention_block,
                                    paged_prefill_attention, paged_write)
 from ..ops.rotary import rotate_rows, rows_rotatable
 from ..util import tracing
+from ._stack import (A, default_positions, dense, embed_tokens, scan_run,
+                     stacked_experts)
 
 
 def _remat_policy(name: str):
@@ -53,8 +55,6 @@ def _remat_policy(name: str):
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             jax.checkpoint_policies.save_only_these_names(*SAVED_OUTPUTS))
     return jax.checkpoint_policies.nothing_saveable
-
-A = nn.with_logical_partitioning  # annotate param init with logical axes
 
 
 @dataclass(frozen=True)
@@ -291,11 +291,7 @@ class Attention(nn.Module):
         hd = cfg.head_dim_
         nq, nkv = cfg.num_heads, cfg.num_kv_heads
         # fused QKV: one [h, (nq+2*nkv)*hd] matmul feeds the MXU better than 3
-        qkv = nn.DenseGeneral(
-            features=(nq + 2 * nkv) * hd, use_bias=False, axis=-1,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            kernel_init=A(nn.initializers.lecun_normal(), ("embed", "qkv")),
-            name="qkv_proj")(x)
+        qkv = dense(cfg, (nq + 2 * nkv) * hd, ("embed", "qkv"), "qkv_proj")(x)
         q, k, v = jnp.split(qkv, [nq * hd, (nq + nkv) * hd], axis=-1)
         b, s = x.shape[:2]
         q = q.reshape(b, s, nq, hd)
@@ -367,11 +363,7 @@ class Attention(nn.Module):
                             impl=impl, block_causal=cfg.block_causal)
             new_cache = (k, v) if kv_cache is not None else None
         out = out.reshape(b, s, nq * hd)
-        out = nn.DenseGeneral(
-            features=cfg.hidden_size, use_bias=False, axis=-1,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            kernel_init=A(nn.initializers.lecun_normal(), ("heads", "embed")),
-            name="o_proj")(out)
+        out = dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(out)
         return out, new_cache
 
 
@@ -391,18 +383,11 @@ class MLP(nn.Module):
     def __call__(self, x):
         cfg = self.config
         # fused gate+up projection
-        gate_up = nn.DenseGeneral(
-            features=2 * cfg.intermediate_size, use_bias=False, axis=-1,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            kernel_init=A(nn.initializers.lecun_normal(), ("embed", "mlp")),
-            name="gate_up_proj")(x)
+        gate_up = dense(cfg, 2 * cfg.intermediate_size, ("embed", "mlp"),
+                        "gate_up_proj")(x)
         gate, up = jnp.split(gate_up, 2, axis=-1)
         y = gated_silu(gate, up, getattr(cfg, "swiglu_limit", None))
-        return nn.DenseGeneral(
-            features=cfg.hidden_size, use_bias=False, axis=-1,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            kernel_init=A(nn.initializers.lecun_normal(), ("mlp", "embed")),
-            name="down_proj")(y)
+        return dense(cfg, cfg.hidden_size, ("mlp", "embed"), "down_proj")(y)
 
 
 class MoEMLP(nn.Module):
@@ -900,13 +885,9 @@ def _stacked_experts(module: nn.Module, cfg: LlamaConfig, kv_caches):
     no PagedCache) keeps the sliced weights: a carried stack would make
     every layer's backward add a stack-sized cotangent."""
     if not (cfg.num_experts and isinstance(kv_caches, PagedCache)
-            and not module.is_initializing() and not _experts_sharded()):
+            and not _experts_sharded()):
         return None
-    moe = nn.meta.unbox(module.get_variable("params", "layers"))[
-        "layer"]["moe"]
-    # cast once, outside the scan (nothing where param_dtype is dtype)
-    return (moe["experts_gate_up"].astype(cfg.dtype),
-            moe["experts_down"].astype(cfg.dtype))
+    return stacked_experts(module, cfg, ("layers", "layer", "moe"))
 
 
 def _experts_sharded() -> bool:
@@ -947,12 +928,15 @@ class ScannedLayer(nn.Module):
     None leaf adds nothing to the program, and the same goes for the expert
     layer's `token_mask` and stacked `experts`); `kv_cache` is this layer's
     slice of the scan's xs: a PagedCache without its pool, or a dense
-    (k, v) pair, whose grown copy goes out through the ys.
+    (k, v) pair, whose grown copy goes out through the ys. `consts` is
+    `scan_run`'s broadcast argument and None here: what a pass holds fixed
+    rides the carry, where the trainer's pinned program has it
+    (tests/test_program_pins.py: `tiny:train_step`).
     """
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, carry, kv_cache):
+    def __call__(self, carry, kv_cache, consts=None):
         x, positions, segment_ids, kv_pages, token_mask, experts = carry
         if kv_pages is not None:
             kv_cache = kv_cache.replace(kv_pages=kv_pages)
@@ -967,8 +951,8 @@ class ScannedLayer(nn.Module):
 def _apply_layers(cfg: LlamaConfig, length: int, x, positions, segment_ids,
                   kv_caches, token_mask=None, experts=None):
     """Run `length` scanned layers named "layers" under the calling
-    module; returns (x, new_caches). Shared by LlamaModel and LayerStack:
-    ONE definition of the scan axes/metadata so every consumer
+    module; returns (x, new_caches). Shared by LlamaModel and LayerStack,
+    and the scan is every family's (`_stack.scan_run`), so every consumer
     produces the identical "layers" param collection (leaves stacked with
     a leading [length] axis under PARTITION_NAME "layers").
 
@@ -979,21 +963,14 @@ def _apply_layers(cfg: LlamaConfig, length: int, x, positions, segment_ids,
     if cfg.remat:
         layer_cls = nn.remat(ScannedLayer, prevent_cse=False,
                              policy=_remat_policy(cfg.remat_policy))
-    layers = nn.scan(
-        layer_cls,
-        variable_axes={"params": 0, "losses": 0, "routing": 0,
-                       "intermediates": 0},
-        split_rngs={"params": True},
-        length=length,
-        metadata_params={nn.PARTITION_NAME: "layers"},
-    )(cfg, name="layers")
+    layers = scan_run(layer_cls, length, "layers", cfg)
     paged = isinstance(kv_caches, PagedCache)
     kv_pages, xs = None, kv_caches
     if paged:
         kv_pages = kv_caches.kv_pages
         xs = kv_caches.replace(kv_pages=None, layer=jnp.arange(length))
     (x, _, _, kv_pages, _, _), ys = layers(
-        (x, positions, segment_ids, kv_pages, token_mask, experts), xs)
+        (x, positions, segment_ids, kv_pages, token_mask, experts), xs, None)
     return x, kv_caches.replace(kv_pages=kv_pages) if paged else ys
 
 
@@ -1055,15 +1032,10 @@ class LlamaModel(nn.Module):
         """
         cfg = self.config
         n_layers = cfg.num_layers if self.n_layers is None else self.n_layers
-        if positions is None:
-            positions = jnp.broadcast_to(
-                jnp.arange(input_ids.shape[1]), input_ids.shape[:2])
+        positions = default_positions(input_ids, positions)
         x = input_ids
         if self.first:
-            embed = self.param(
-                "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
-                (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-            x = embed[input_ids].astype(cfg.dtype)
+            _, x = embed_tokens(self, cfg, input_ids)
 
         if cfg.scan_layers:
             x, new_caches = _apply_layers(
@@ -1092,11 +1064,7 @@ class LlamaModel(nn.Module):
             x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
         if not apply_head:
             return (x, new_caches) if kv_caches is not None else x
-        head_proj = nn.DenseGeneral(
-            features=cfg.vocab_size, use_bias=False, axis=-1,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            kernel_init=A(nn.initializers.lecun_normal(), ("embed", "vocab")),
-            name="lm_head")
+        head_proj = dense(cfg, cfg.vocab_size, ("embed", "vocab"), "lm_head")
 
         def head(x):
             with tracing.scope("rtpu.head"):

@@ -63,6 +63,9 @@ from ..ops.paged_attention import (paged_attention_decode,
                                    window_prefill_attention)
 from ..ops.rotary import rotate, yarn_inv_freq
 from ..util import tracing
+from ._stack import (default_positions, dense as _dense, embed_tokens,
+                     head_at_gather, own_cache, scan_run, stacked_experts,
+                     whole_model_only)
 from .llama import A, ExpertFacts, LlamaConfig, MLP, MoEMLP, RMSNorm
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -227,12 +230,8 @@ class WindowCache:
 
 # ----------------------------------------------------------------- serving
 def serving_model(cfg: MellumConfig, n_layers=None, first=True, last=True):
-    if not (first and last):
-        raise NotImplementedError(
-            "a slice of a model whose layers are a list of two kinds: "
-            "pipeline stages cut a uniform `layers` axis "
-            "(serve/llm/stage.py: stage_params)")
-    return MellumModel(cfg)
+    return whole_model_only(MellumModel, cfg, first, last,
+                            "whose layers are a list of two kinds")
 
 
 # (stage.py: model_family) what rests on a block table that holds every
@@ -365,13 +364,6 @@ def serving_cache(cfg: MellumConfig, pool: dict, block_tables,
 
 
 # ------------------------------------------------------------------ layers
-def _dense(cfg, features, axes, name):
-    return nn.DenseGeneral(
-        features=features, use_bias=False, axis=-1, dtype=cfg.dtype,
-        param_dtype=cfg.param_dtype,
-        kernel_init=A(nn.initializers.lecun_normal(), axes), name=name)
-
-
 def head_gate(o, x, w_g):
     """o [B, S, H, D] x sigmoid(x W_g) [B, S, H], one scalar a query head
     and token, its logits and the product in float32."""
@@ -497,101 +489,46 @@ class MellumLayer(nn.Module):
         return (x + h, kv_pages, win_pages), None
 
 
-def _run(cfg: MellumConfig, length: int, name: str, **attrs):
-    return nn.scan(
-        MellumLayer, variable_axes={"params": 0, "routing": 0,
-                                    "selection": 0},
-        split_rngs={"params": True}, length=length,
-        in_axes=(0, nn.broadcast),
-        metadata_params={nn.PARTITION_NAME: "layers"})(cfg, name=name,
-                                                       **attrs)
-
-
 class MellumModel(nn.Module):
     config: MellumConfig
 
     @nn.compact
     def __call__(self, input_ids, positions=None, kv_caches=None,
                  token_mask=None):
-        """input_ids [B, S] -> logits [B, S, V]; with `kv_caches` (a
-        WindowCache) -> (logits, the cache with its pool updated): S == 1
-        is a decode step over the slot set, S > 1 a prefill pass that
-        resumes from the rows' pages and rings; with `gather` the logits
-        are [B, 1, V], at that position of each row. Without a cache the
-        same paged path runs over a pool of its own (a page set and a slot
-        a row). `token_mask` [B, S] bool marks padding (the expert layers
-        give it no expert)."""
+        """THE CALL of models/_stack.py, `kv_caches` a WindowCache: a
+        prefill pass resumes from the rows' pages and their slots' rings."""
         cfg = self.config
-        b, s = input_ids.shape
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        cache = kv_caches
-        if cache is None:
-            cache = self._own_cache(b, s, token_mask)
+        positions = default_positions(input_ids, positions)
+        cache = kv_caches if kv_caches is not None else own_cache(
+            pool_spec, serving_cache, cfg, *input_ids.shape, token_mask)
         if token_mask is None:
             token_mask = positions < cache.total_lens[:, None]
         slots = cache.slots
         if slots is None:
-            slots = jnp.arange(b, dtype=jnp.int32)
-        embed = self.param(
-            "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = embed[input_ids].astype(cfg.dtype)
+            slots = jnp.arange(input_ids.shape[0], dtype=jnp.int32)
+        _, x = embed_tokens(self, cfg, input_ids)
 
         carry = (x, cache.kv_pages, cache.win_pages)
         at = {SLIDING: 0, FULL: 0}
         for r, ((kind, dense), n) in enumerate(cfg.stack_runs):
             name = f"run_{r:02d}"
-            experts = None
-            if (not dense and kv_caches is not None
-                    and not self.is_initializing()):
-                moe = nn.meta.unbox(self.get_variable("params", name))["moe"]
-                experts = (moe["experts_gate_up"].astype(cfg.dtype),
-                           moe["experts_down"].astype(cfg.dtype))
+            experts = (stacked_experts(self, cfg, (name, "moe"))
+                       if not dense and kv_caches is not None else None)
             consts = (positions, cache.block_tables, cache.total_lens, slots,
                       token_mask, experts)
-            carry, _ = _run(cfg, n, name, kind=kind, dense=dense,
-                            ctx_pages=cache.ctx_pages,
-                            ref_attention=cache.ref_attention)(
+            carry, _ = scan_run(MellumLayer, n, name, cfg, kind=kind,
+                                dense=dense, ctx_pages=cache.ctx_pages,
+                                ref_attention=cache.ref_attention)(
                 carry, (at[kind] + jnp.arange(n), jnp.arange(n)), consts)
             at[kind] += n
         x, kv_pages, win_pages = carry
 
         with tracing.scope("rtpu.head"):
             x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-        # a plain leaf, not a Dense: the head runs under `lax.cond` below
-        head_w = self.param(
-            "lm_head", A(nn.initializers.lecun_normal(), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
-
-        def head(a):
-            with tracing.scope("rtpu.head"):
-                return jnp.dot(a, head_w.astype(cfg.dtype))
-
-        if cache.gather is None:
-            logits = head(x)
-        else:
-            at_gather = jnp.take_along_axis(
-                x, jnp.maximum(cache.gather, 0)[:, None, None], axis=1)
-            logits = jax.lax.cond(
-                jnp.any(cache.gather >= 0), head,
-                lambda a: jnp.zeros(a.shape[:2] + (cfg.vocab_size,),
-                                    cfg.dtype), at_gather)
+        logits = head_at_gather(self, cfg, x, cache.gather)
         if kv_caches is None:
             return logits
         return logits, cache.replace(kv_pages=kv_pages, win_pages=win_pages)
-
-    def _own_cache(self, b: int, s: int, token_mask) -> WindowCache:
-        cfg = self.config
-        page = 16
-        mp = -(-s // page) + 1
-        pool = {k: jnp.zeros(*sd) for k, sd in pool_spec(
-            cfg, cfg.num_layers, 1 + b * mp, page, b).items()}
-        total = (jnp.full((b,), s, jnp.int32) if token_mask is None
-                 else token_mask.sum(-1).astype(jnp.int32))
-        return serving_cache(
-            cfg, pool, 1 + jnp.arange(b * mp, dtype=jnp.int32
-                                      ).reshape(b, mp), total)
 
 
 # ---------------------------------------------------------------- registry

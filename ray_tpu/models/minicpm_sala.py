@@ -59,7 +59,9 @@ from ..ops.sparse_attention import (SparseParams, compress_keys,
                                     kernels_scored, keys_attended,
                                     sparse_decode, sparse_prefill)
 from ..util import tracing
-from .llama import MLP, A, RMSNorm, rope
+from ._stack import (default_positions, dense, embed_table, head_at_gather,
+                     own_cache, scan_run, whole_model_only)
+from .llama import MLP, RMSNorm, rope
 
 LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
 # the family's interface flags (serve/llm/stage.py: model_family): a
@@ -261,12 +263,8 @@ class SalaCache:
 
 # ----------------------------------------------------------------- serving
 def serving_model(cfg: SalaConfig, n_layers=None, first=True, last=True):
-    if not (first and last):
-        raise NotImplementedError(
-            "a slice of a model whose layers are a list of two kinds: "
-            "pipeline stages cut a uniform `layers` axis "
-            "(serve/llm/stage.py: stage_params)")
-    return SalaModel(cfg)
+    return whole_model_only(SalaModel, cfg, first, last,
+                            "whose layers are a list of two kinds")
 
 
 # (stage.py: model_family) a prefill row RESUMES from its slot's state, so
@@ -407,13 +405,6 @@ def _head_norm(cfg, name):
     return RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
 
 
-def _dense(cfg, features, axes, name):
-    return nn.DenseGeneral(
-        features=features, use_bias=False, axis=-1, dtype=cfg.dtype,
-        param_dtype=cfg.param_dtype,
-        kernel_init=A(nn.initializers.lecun_normal(), axes), name=name)
-
-
 def _finish(cfg, x, mixed):
     x = x + cfg.residual_scale * mixed
     return x + cfg.residual_scale * MLP(cfg, name="mlp")(
@@ -443,7 +434,7 @@ class LightningLayer(nn.Module):
         b, s, _ = x.shape
         nh, d = cfg.lightning_nh, cfg.lightning_head_dim
         xn = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_norm")(x)
-        qkvg = _dense(cfg, 4 * nh * d, ("embed", "qkv"), "qkvg_proj")(xn)
+        qkvg = dense(cfg, 4 * nh * d, ("embed", "qkv"), "qkvg_proj")(xn)
         q, k, v, gate = (a.reshape(b, s, nh, d)
                          for a in jnp.split(qkvg, 4, axis=-1))
         q = rope(_head_norm(cfg, "q_norm")(q), positions, cfg.rope_theta)
@@ -473,7 +464,7 @@ class LightningLayer(nn.Module):
                 rows.append(oi)
             o = jnp.stack(rows)
         o = _head_norm(cfg, "o_norm")(o) * jax.nn.sigmoid(gate)
-        out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(
+        out = dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(
             o.reshape(b, s, nh * d))
         return (_finish(cfg, x, out), kv_pages, kc, lin), None
 
@@ -495,8 +486,8 @@ class SparseLayer(nn.Module):
         nq, g, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         sp, scale = cfg.sparse, d ** -0.5
         xn = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_norm")(x)
-        qkvg = _dense(cfg, (2 * nq + 2 * g) * d, ("embed", "qkv"),
-                      "qkvg_proj")(xn)
+        qkvg = dense(cfg, (2 * nq + 2 * g) * d, ("embed", "qkv"),
+                     "qkvg_proj")(xn)
         q, k, v, gate = jnp.split(
             qkvg, [nq * d, (nq + g) * d, (nq + 2 * g) * d], axis=-1)
         q = _head_norm(cfg, "q_norm")(q.reshape(b, s, nq, d))
@@ -529,17 +520,8 @@ class SparseLayer(nn.Module):
             # pays for it
             self.sow("selection", "blocks", picked)
         o = o.reshape(b, s, nq * d) * jax.nn.sigmoid(gate)
-        out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(o)
+        out = dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(o)
         return (_finish(cfg, x, out), kv_pages, kc, lin), None
-
-
-def _run(body, cfg: SalaConfig, length: int, name: str, **attrs):
-    return nn.scan(
-        body, variable_axes={"params": 0, "selection": 0},
-        split_rngs={"params": True},
-        length=length, in_axes=(0, nn.broadcast),
-        metadata_params={nn.PARTITION_NAME: "layers"})(cfg, name=name,
-                                                       **attrs)
 
 
 class SalaModel(nn.Module):
@@ -548,25 +530,16 @@ class SalaModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, kv_caches=None,
                  token_mask=None):
-        """input_ids [B, S] -> logits [B, S, V]; with `kv_caches` (a
-        SalaCache) -> (logits, the cache with its pools updated): S == 1
-        is a decode step over the slot set, S > 1 a prefill pass that
-        resumes from the rows' slots and pages; with `gather` the logits
-        are [B, 1, V], at that position of each row. Without a cache the
-        same paged path runs over a pool of its own (one page set and one
-        slot a row), from zero state. `token_mask` [B, S] bool marks
-        padding where there is no cache to say it."""
+        """THE CALL of models/_stack.py, `kv_caches` a SalaCache;
+        `token_mask` only sizes the call's own cache (no layer reads it)."""
         cfg = self.config
-        b, s = input_ids.shape
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        cache = kv_caches
-        if cache is None:
-            cache = self._own_cache(b, s, token_mask)
-        embed = self.param(
-            "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = (embed[input_ids] * cfg.scale_emb).astype(cfg.dtype)
+        s = input_ids.shape[1]
+        positions = default_positions(input_ids, positions)
+        cache = kv_caches if kv_caches is not None else own_cache(
+            pool_spec, serving_cache, cfg, *input_ids.shape, token_mask,
+            page=cfg.sparse.block)
+        x = (embed_table(self, cfg)[input_ids] * cfg.scale_emb).astype(
+            cfg.dtype)
 
         start = positions[:, 0]
         n_real = jnp.clip(cache.total_lens - start, 0, s)
@@ -579,51 +552,22 @@ class SalaModel(nn.Module):
             xs = (at[kind] + jnp.arange(n), jnp.asarray(published))
             at[kind] += n
             if kind == LIGHTNING:
-                body = _run(LightningLayer, cfg, n, f"run_{r}")
+                body = scan_run(LightningLayer, n, f"run_{r}", cfg)
             else:
-                body = _run(SparseLayer, cfg, n, f"run_{r}",
-                            ctx_pages=cache.ctx_pages,
-                            ref_attention=cache.ref_attention)
+                body = scan_run(SparseLayer, n, f"run_{r}", cfg,
+                                ctx_pages=cache.ctx_pages,
+                                ref_attention=cache.ref_attention)
             carry, _ = body(carry, xs, consts)
         x, kv_pages, kc, lin = carry
 
         with tracing.scope("rtpu.head"):
             x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
             x = x / (cfg.hidden_size / cfg.dim_model_base)
-        # a plain leaf, not a Dense: the head runs under `lax.cond` below
-        head_w = self.param(
-            "lm_head", A(nn.initializers.lecun_normal(), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
-
-        def head(a):
-            with tracing.scope("rtpu.head"):
-                return jnp.dot(a, head_w.astype(cfg.dtype))
-
-        if cache.gather is None:
-            logits = head(x)
-        else:
-            at_gather = jnp.take_along_axis(
-                x, jnp.maximum(cache.gather, 0)[:, None, None], axis=1)
-            logits = jax.lax.cond(
-                jnp.any(cache.gather >= 0), head,
-                lambda a: jnp.zeros(a.shape[:2] + (cfg.vocab_size,),
-                                    cfg.dtype), at_gather)
+        logits = head_at_gather(self, cfg, x, cache.gather)
         if kv_caches is None:
             return logits
         return logits, cache.replace(kv_pages=kv_pages, kc=kc,
                                      lin_state=lin)
-
-    def _own_cache(self, b: int, s: int, token_mask) -> SalaCache:
-        cfg = self.config
-        page = cfg.sparse.block
-        mp = -(-s // page) + 1
-        pool = {k: jnp.zeros(*sd) for k, sd in pool_spec(
-            cfg, cfg.num_layers, 1 + b * mp, page, b).items()}
-        total = (jnp.full((b,), s, jnp.int32) if token_mask is None
-                 else token_mask.sum(-1).astype(jnp.int32))
-        return serving_cache(
-            cfg, pool, 1 + jnp.arange(b * mp, dtype=jnp.int32
-                                      ).reshape(b, mp), total)
 
 
 # ---------------------------------------------------------------- registry

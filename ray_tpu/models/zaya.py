@@ -76,6 +76,8 @@ from ..ops.paged_attention import (paged_attention_decode,
                                    paged_prefill_attention, paged_write)
 from ..ops.rotary import rotate
 from ..util import tracing
+from ._stack import (default_positions, dense, embed_tokens, head_at_gather,
+                     own_cache, scan_run, stacked_experts)
 from .llama import A, ExpertFacts, LlamaConfig, MoEMLP, RMSNorm
 
 # the family's interface flags (serve/llm/stage.py: model_family): a
@@ -406,11 +408,7 @@ class CCAttention(nn.Module):
         hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
         chan, taps1 = cfg.cca_channels, cfg.cca_time1
         # [q~ | k~ | h W_v1 | h W_v2] in one product
-        qkv = nn.DenseGeneral(
-            features=chan + 2 * d, use_bias=False, axis=-1, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            kernel_init=A(nn.initializers.lecun_normal(), ("embed", "qkv")),
-            name="qkv_proj")(x)
+        qkv = dense(cfg, chan + 2 * d, ("embed", "qkv"), "qkv_proj")(x)
         a = self.param(
             "conv0", A(nn.initializers.uniform(cfg.cca_time0 ** -0.5),
                        (None, "qkv")), (cfg.cca_time0, chan), cfg.param_dtype)
@@ -461,11 +459,8 @@ class CCAttention(nn.Module):
                 ctx_pages=self.ctx_pages, scale=1.0,
                 impl="reference" if self.ref_attention else None,
                 layer=layer)
-        out = nn.DenseGeneral(
-            features=cfg.hidden_size, use_bias=False, axis=-1,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            kernel_init=A(nn.initializers.lecun_normal(), ("heads", "embed")),
-            name="o_proj")(out.reshape(b, s, hq * d))
+        out = dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(
+            out.reshape(b, s, hq * d))
         return out, pages, tail
 
 
@@ -543,43 +538,24 @@ class ZayaModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, kv_caches=None,
                  token_mask=None):
-        """input_ids [B, S] -> logits [B, S, V]; with `kv_caches` (a
-        CcaCache) -> (logits, the cache with its pools updated): S == 1 is
-        a decode step over the slot set, S > 1 a prefill pass that resumes
-        from the rows' slots and pages; with `gather` the logits are [B,
-        1, V], at that position of each row. Without a cache the same paged
-        path runs over a pool of its own (one page set and one slot a row),
-        from zero tails. `token_mask` [B, S] bool marks padding (the expert
-        layers give it no expert)."""
+        """THE CALL of models/_stack.py, `kv_caches` a CcaCache: a prefill
+        pass resumes from the rows' pages and their slots' tails."""
         cfg = self.config
         b, s = input_ids.shape
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        cache = kv_caches
-        if cache is None:
-            cache = self._own_cache(b, s, token_mask)
+        positions = default_positions(input_ids, positions)
+        cache = kv_caches if kv_caches is not None else own_cache(
+            pool_spec, serving_cache, cfg, b, s, token_mask)
         if token_mask is None:
             token_mask = positions < cache.total_lens[:, None]
-        embed = self.param(
-            "embed", A(nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = embed[input_ids].astype(cfg.dtype)
+        embed, x = embed_tokens(self, cfg, input_ids)
 
         n_real = jnp.clip(cache.total_lens - positions[:, 0], 0, s)
-        experts = None
-        if kv_caches is not None and not self.is_initializing():
-            moe = nn.meta.unbox(self.get_variable("params", "layers"))["moe"]
-            experts = (moe["experts_gate_up"].astype(cfg.dtype),
-                       moe["experts_down"].astype(cfg.dtype))
+        experts = (stacked_experts(self, cfg, ("layers", "moe"))
+                   if kv_caches is not None else None)
         consts = (positions, cache.block_tables, cache.total_lens, n_real,
                   cache.slots, token_mask, experts)
-        layers = nn.scan(
-            ZayaLayer, variable_axes={"params": 0, "losses": 0, "routing": 0,
-                                      "selection": 0, "intermediates": 0},
-            split_rngs={"params": True}, length=cfg.num_layers,
-            in_axes=(0, nn.broadcast),
-            metadata_params={nn.PARTITION_NAME: "layers"})(
-            cfg, cache.ctx_pages, cache.ref_attention, name="layers")
+        layers = scan_run(ZayaLayer, cfg.num_layers, "layers", cfg,
+                          cache.ctx_pages, cache.ref_attention)
         # the router's state before layer 0 is zero
         r0 = jnp.zeros((b, s, cfg.router_hidden_size), jnp.float32)
         (x, _, pages, tail), _ = layers(
@@ -588,35 +564,10 @@ class ZayaModel(nn.Module):
 
         with tracing.scope("rtpu.head"):
             x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-
-        def head(a):
-            with tracing.scope("rtpu.head"):
-                return jnp.einsum("bsh,vh->bsv", a, embed.astype(cfg.dtype))
-
-        if cache.gather is None:
-            logits = head(x)
-        else:
-            at_gather = jnp.take_along_axis(
-                x, jnp.maximum(cache.gather, 0)[:, None, None], axis=1)
-            logits = jax.lax.cond(
-                jnp.any(cache.gather >= 0), head,
-                lambda a: jnp.zeros(a.shape[:2] + (cfg.vocab_size,),
-                                    cfg.dtype), at_gather)
+        logits = head_at_gather(self, cfg, x, cache.gather, weight=embed)
         if kv_caches is None:
             return logits
         return logits, cache.replace(kv_pages=pages, cca_tail=tail)
-
-    def _own_cache(self, b: int, s: int, token_mask) -> CcaCache:
-        cfg = self.config
-        page = 16
-        mp = -(-s // page) + 1
-        pool = {k: jnp.zeros(*sd) for k, sd in pool_spec(
-            cfg, cfg.num_layers, 1 + b * mp, page, b).items()}
-        total = (jnp.full((b,), s, jnp.int32) if token_mask is None
-                 else token_mask.sum(-1).astype(jnp.int32))
-        return serving_cache(
-            cfg, pool, 1 + jnp.arange(b * mp, dtype=jnp.int32
-                                      ).reshape(b, mp), total)
 
 
 # ---------------------------------------------------------------- registry

@@ -139,7 +139,10 @@ def model_family(name: str):
     parameters a token multiplies); where set, `HEAD_AT_GATHER`
     (`serving_cache` takes the position each row samples from and the
     model computes the head there only); its config answers
-    `n_slot_state_layers` where layers keep state a decode slot. And:
+    `n_slot_state_layers` where layers keep state a decode slot. A family's
+    model calls models/_stack.py for the scan over a run of like layers, the
+    embedding, the head and its own pool, and writes its layers, its cache
+    and its walk over its runs. And:
 
     - `dispatch_facts(model_cfg, engine_config)`: a list of small host-side
       objects (no JAX), one a feature of the model (three families list

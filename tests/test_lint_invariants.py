@@ -183,11 +183,13 @@ def test_proto_rule_fixture(rule):
 def test_proto_gate_whole_program_clean():
     """The acceptance gate: zero unsuppressed RTPU101-106 findings over
     the package, with tests/ and benchmarks/ as auxiliary evidence, and
-    a <10s perf guard on the whole pass (it parses ~180 modules once)."""
+    a guard on the pass's cost A FILE (it parses ~240 modules once)."""
     pkg = os.path.join(REPO, "ray_tpu")
-    # CPU time, not wall: the guard is about analyzer complexity (the
-    # pass is single-process and compute-bound), and wall time on the
-    # shared box swings with ambient load.
+    # CPU time, not wall, and a file, not the whole pass: the guard is
+    # about analyzer complexity (the pass is single-process and
+    # compute-bound; 12-15 ms a file on an idle box, 21 in the driver's run of
+    # PR 60 under six workers), and a bound on seconds failed two trees for
+    # the box's load alone while every test file a PR adds is parsed here.
     t0 = time.process_time()
     findings, n_files = run_proto([pkg], aux_paths=default_aux_paths(pkg))
     elapsed = time.process_time() - t0
@@ -197,7 +199,8 @@ def test_proto_gate_whole_program_clean():
         f"{f.path}:{f.line}: {f.rule} {f.message}" for f in unsuppressed)
     for f in findings:
         assert f.reason and f.reason.strip(), f"{f.path}:{f.line}"
-    assert elapsed < 10.0, f"proto pass took {elapsed:.1f}s"
+    assert elapsed / n_files < 0.060, (
+        f"proto pass took {elapsed:.1f}s over {n_files} files")
 
 
 def test_proto_rpc_graph_ground_truth():
