@@ -9,6 +9,9 @@ without a real cluster the same way, via cluster_utils.Cluster).
 """
 
 import os
+import signal
+import traceback
+from contextlib import contextmanager
 
 # Force the CPU backend with 8 virtual devices, before any test initializes a
 # backend. The driver also exports JAX_PLATFORMS=cpu; setting the config here
@@ -23,6 +26,75 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest  # noqa: E402
+
+from _engines import close_all  # noqa: E402
+
+# A test that waits fails by name. The dearest case of a whole loaded run
+# is 75 s (PR 62); a run of the suite is cut at 1470 s, and a cut run has
+# no exit code of its own and counts only the lines it finished: so a
+# stall has to end well inside that, as ONE failure that says where it was.
+TEST_TIME_LIMIT_S = 300.0
+
+
+@contextmanager
+def time_limit(seconds: float, what: str = "the test"):
+    """Fail what runs inside once it has run `seconds`, from the line it
+    is waiting at (the alarm arrives in the main thread, between two
+    bytecodes or out of an interruptible wait; it repeats every 5 s, for
+    code that swallows the first). Nests: leaving restores the limit that
+    was running."""
+    def expire(signum, frame):
+        pytest.fail(f"{what} ran past its limit of {seconds:g} s; it was "
+                    "waiting at:\n" + "".join(traceback.format_stack(frame)))
+
+    was = signal.signal(signal.SIGALRM, expire)
+    left, again = signal.setitimer(signal.ITIMER_REAL, seconds, 5.0)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, left, again)
+        signal.signal(signal.SIGALRM, was)
+
+
+def _limited(item, phase):
+    with time_limit(TEST_TIME_LIMIT_S, f"{item.nodeid} ({phase})"):
+        return (yield)
+
+
+# The dear files, dearest first (seconds of a whole loaded run, PR 62;
+# `tools/t1_times.py` says when the order is stale). `--dist loadfile` hands
+# files out in collection order, which is the alphabet's: its last big file
+# (`test_zaya.py`, 140-230 s) then runs while five workers have nothing left.
+# With these in front the run ends on files of a few seconds.
+DEAR_FIRST = (
+    "test_flash_attention.py", "test_paged_attention.py",
+    "test_engine_tracing.py", "test_chip_compile_engines.py",
+    "test_program_pins.py", "test_sdar.py", "test_laguna.py",
+    "test_minicpm_sala.py", "test_moe.py", "test_prefill_plan.py",
+    "test_zaya.py", "test_chip_compile_families.py", "test_gigachat.py",
+    "test_program_scopes.py", "test_jamba.py", "test_kimi.py",
+    "test_mellum.py", "test_chip_compile.py", "test_llm_serve.py",
+    "test_gated_delta.py", "test_rotary.py")
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(DEAR_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    return (yield from _limited(item, "setup"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    return (yield from _limited(item, "call"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    return (yield from _limited(item, "teardown"))
 
 
 def pytest_configure(config):
@@ -107,8 +179,10 @@ def _release_compiled_programs():
     worker runs dozens of files, and past the kernel's 65,530 a process's
     next compile dies of a segmentation fault inside LLVM: with the tests of
     PR 58 added, the worker that ran `tests/test_gigachat.py` last did, in
-    two whole runs of two, while the file passes alone."""
+    two whole runs of two, while the file passes alone. The module's shared
+    engines (tests/_engines.py) are left first: they hold the programs."""
     yield
+    close_all()
     jax.clear_caches()
 
 
@@ -131,6 +205,62 @@ def fresh_cluster():
     session = ray_tpu.init(num_cpus=4)
     yield session
     ray_tpu.shutdown()
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A `v5e:2x2` that is described, not attached, for the files that
+    compile for it (tests/test_chip_compile*.py). Described inside a
+    fixture, never at import: only one process may load the TPU library,
+    and under xdist every worker imports every test file."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip (the next one warns):
+    keep the cache off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def shape_engine():
+    """EngineConfig -> an `LLMEngine` whose parameters are shapes only,
+    one a configuration: its stage's `program` builds the real programs,
+    nothing runs."""
+    import jax.numpy as jnp
+
+    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.serve.llm.stage import init_params
+
+    done = {}
+
+    def get(cfg):
+        engine = done.get(repr(cfg))
+        if engine is None:
+            engine = done[repr(cfg)] = LLMEngine(cfg, params={})
+            stage = engine.compute
+            stage.params = jax.eval_shape(lambda: init_params(
+                stage.model, jnp.zeros((1, 8), jnp.int32),
+                jax.random.PRNGKey(0)))
+        return engine
+
+    return get
 
 
 @pytest.fixture(scope="session")

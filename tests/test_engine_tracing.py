@@ -18,6 +18,8 @@ from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
 from ray_tpu.serve.llm.engine import PassCost
 from ray_tpu.util import tracing
 
+from _engines import new_engine, renewed, scarce, tiny_engine
+
 ENGINE_CFG = dict(
     model="tiny", page_size=8, num_pages=64, max_model_len=128,
     max_batch=4, prefill_buckets=(16, 32, 64, 128), dtype="float32",
@@ -47,7 +49,7 @@ def _prompts(n, lo=9, step=7, seed=0):
 def engine():
     """One engine for the cases that only read what it records (its
     programs compile once); every case starts from an empty ring."""
-    return LLMEngine(EngineConfig(**ENGINE_CFG))
+    return tiny_engine(**ENGINE_CFG)
 
 
 @pytest.fixture(autouse=True)
@@ -141,15 +143,16 @@ def _waves(n, engine, tag, max_tokens=3, seed=11):
 
 @pytest.fixture(scope="module")
 def wide():
-    """max_batch 8: waves of at most 4 rows."""
-    return LLMEngine(EngineConfig(**WIDE_CFG))
+    """max_batch 8: waves of at most 4 rows. (An engine of its own: the
+    cases read which programs their traffic built.)"""
+    return new_engine(**WIDE_CFG)
 
 
 @pytest.fixture(scope="module")
 def wide_spec():
     """`wide` with speculative decoding on and a draft for every slot
     (two tokens the model will not have chosen: all are rejected)."""
-    engine = LLMEngine(EngineConfig(**WIDE_CFG, spec_lookahead=3))
+    engine = new_engine(**WIDE_CFG, spec_lookahead=3)
     engine._prompt_lookup_draft = lambda req, max_len: [511, 510][:max_len]
     return engine
 
@@ -190,7 +193,7 @@ def test_a_prefill_wave_computes_its_requests_and_no_padding_row(
     if spec:
         assert ("verify", 16, 4) in wide.compute.programs
         assert after["spec_drafted_total"] > after["spec_accepted_total"] == 0
-        plain = LLMEngine(EngineConfig(**WIDE_CFG))
+        plain = tiny_engine(**WIDE_CFG)
         assert _waves(n, plain, f"w{n}", seed=100 + n)[1] == out
 
 
@@ -198,13 +201,13 @@ def test_a_prefill_wave_computes_its_requests_and_no_padding_row(
 def test_greedy_tokens_are_the_same_alone_and_inside_a_full_wave(chunk):
     """Both schedulers pass through the row loop: a request's tokens do
     not depend on how many rows shared its wave."""
-    engine = LLMEngine(EngineConfig(**WIDE_CFG, prefill_chunk_tokens=chunk))
+    engine = tiny_engine(**WIDE_CFG, prefill_chunk_tokens=chunk)
     rows, full = _waves(4, engine, "g", max_tokens=6)
     # whole prompts go four to a wave; a budget of 16 tokens a step is
     # shared by up to three rows
     assert max(real for _, real in rows) == (3 if chunk else 4)
     # alone, on a fresh engine (nothing cached): one row every dispatch
-    engine = LLMEngine(EngineConfig(**WIDE_CFG, prefill_chunk_tokens=chunk))
+    engine = tiny_engine(**WIDE_CFG, prefill_chunk_tokens=chunk)
     rows, alone = _waves(1, engine, "g", max_tokens=6)
     assert set(rows) == {(1, 1)}
     assert full["g-0"] == alone["g-0"] and len(alone["g-0"]) == 6
@@ -213,10 +216,11 @@ def test_greedy_tokens_are_the_same_alone_and_inside_a_full_wave(chunk):
 @pytest.fixture(scope="module")
 def warmed():
     """A warmed engine, the number warmup() returned, and a count of
-    the programs JAX itself builds from then on."""
+    the programs JAX itself builds from then on. (An engine of its own:
+    what a first use builds is the claim.)"""
     import jax.monitoring
 
-    engine = LLMEngine(EngineConfig(**WIDE_CFG))
+    engine = new_engine(**WIDE_CFG)
     n = engine.warmup()
     compiles = [0]
 
@@ -342,27 +346,25 @@ def test_flightrecords_reads_a_runs_tail_from_its_records(
 
 
 def test_preemption_is_counted_and_gets_a_new_dispatch_time():
-    cfg = dict(ENGINE_CFG, num_pages=12, max_model_len=64, max_batch=2,
-               prefill_buckets=(16, 32, 64))
-    eng = LLMEngine(EngineConfig(**cfg))
-    first_dispatch = {}
-    for i, p in enumerate(_prompts(2, lo=17, step=0, seed=8)):
-        eng.add_request(f"p{i}", p, SamplingParams(max_tokens=40))
-    for _ in range(900):
-        if not eng.has_work():
-            break
-        eng.step()
-        for req in eng.running:
-            first_dispatch.setdefault(req.request_id, req.dispatched_ns)
-    assert eng.stats()["preempted_total"] >= 1
-    recs = {r["request_id"]: r for r in _dicts("engine.request")}
-    assert sum(r["preemptions"] for r in recs.values()) \
-        == eng.stats()["preempted_total"]
-    for rid, r in recs.items():
-        # folded output tokens are not prompt tokens
-        assert r["prompt_tokens"] == 17 and r["output_tokens"] == 40
-        if r["preemptions"]:
-            assert r["dispatched_ns"] > first_dispatch[rid]
+    with scarce(tiny_engine(**ENGINE_CFG), 11) as eng:
+        first_dispatch = {}
+        for i, p in enumerate(_prompts(2, lo=17, step=0, seed=8)):
+            eng.add_request(f"p{i}", p, SamplingParams(max_tokens=40))
+        for _ in range(900):
+            if not eng.has_work():
+                break
+            eng.step()
+            for req in eng.running:
+                first_dispatch.setdefault(req.request_id, req.dispatched_ns)
+        assert eng.stats()["preempted_total"] >= 1
+        recs = {r["request_id"]: r for r in _dicts("engine.request")}
+        assert sum(r["preemptions"] for r in recs.values()) \
+            == eng.stats()["preempted_total"]
+        for rid, r in recs.items():
+            # folded output tokens are not prompt tokens
+            assert r["prompt_tokens"] == 17 and r["output_tokens"] == 40
+            if r["preemptions"]:
+                assert r["dispatched_ns"] > first_dispatch[rid]
 
 
 def test_ring_holds_its_capacity_and_counts_what_it_drops():
@@ -901,8 +903,81 @@ FAMILY_FIELDS = {
 }
 
 
-@pytest.mark.parametrize("preset", sorted(FAMILY_FIELDS))
-def test_a_familys_records_hold_its_fields_and_no_other_familys(preset):
+# every tiny preset that a test file renews an engine of
+PRESETS = ("tiny", "tiny-mellum", *sorted(FAMILY_FIELDS))
+# stats() that are read off a clock, and the one that counts what the
+# programs cost to build: a renewed engine has them built, which is the point
+NOT_THE_TRAFFICS = {"queue_wait_s_total", "device_busy_s_total",
+                    "device_idle_s_total", "harvests_late_total",
+                    "programs_built_total"}
+
+
+@pytest.fixture(scope="module")
+def preset_engine(request):
+    """A new engine of one tiny preset for the two cases that preset has
+    below (pytest runs a module-scoped parameter's cases together, in the
+    order they are written: the first finds the engine as it was built)."""
+    preset = request.param
+    engine = new_engine(
+        preset, dtype="float32", page_size=8 if preset == "tiny-jamba" else 16,
+        num_pages=96, max_model_len=256, max_batch=4, prefill_buckets=(64,),
+        seed=3)
+    yield engine
+    engine.close()
+
+
+def _served(engine, seed, lens=(5, 41, 64), max_tokens=6):
+    """The same traffic every time it is asked with one seed (a prompt that
+    fills the largest bucket, a repeated prompt for the prefix cache, a row
+    that draws) -> (tokens by request, the pool, what stats() says of it)."""
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(1, 200, n).tolist() for n in lens]
+    prompts.append(prompts[1])
+    out = {}
+    for i, prompt in enumerate(prompts):
+        engine.add_request(f"r{i}", prompt, SamplingParams(
+            max_tokens=max_tokens, temperature=1.0 if i == 2 else 0.0,
+            top_k=8, seed=17))
+    for _ in range(600):
+        if not engine.has_work():
+            break
+        for d in engine.step():
+            out.setdefault(d.request_id, []).extend(d.new_token_ids)
+    assert not engine.has_work()
+    pool = engine.kv_pages
+    pool = pool if isinstance(pool, dict) else {"kv_pages": pool}
+    return (out, {k: np.asarray(v) for k, v in pool.items()},
+            {k: v for k, v in engine.stats().items()
+             if k not in NOT_THE_TRAFFICS})
+
+
+@pytest.mark.parametrize("preset_engine", PRESETS, indirect=True)
+def test_a_renewed_engine_serves_what_a_new_one_serves(preset_engine):
+    """What the test files stand on (tests/_engines.py): an engine built
+    here serves a traffic; then another traffic leaves it pages in the
+    prefix cache, carries in its slots and counters; it is renewed and
+    serves the first again: the same tokens (a seeded draw among them), the
+    same pool to the bit, the same stats(). One with work is refused."""
+    engine = preset_engine
+    assert engine.stats()["programs_built_total"] == 0       # new
+    tokens, pool, stats = _served(engine, seed=1)
+    assert all(len(t) == 6 for t in tokens.values()) and len(tokens) == 4
+    _served(engine, seed=2, lens=(9, 64, 33), max_tokens=9)
+    engine.add_request("open", [1, 2, 3], SamplingParams(max_tokens=4))
+    with pytest.raises(RuntimeError, match="only an idle engine"):
+        renewed(engine)
+    _run(engine)
+    again = _served(renewed(engine), seed=1)
+    assert again[0] == tokens
+    assert again[2] == stats
+    for part, pages in pool.items():
+        np.testing.assert_array_equal(again[1][part], pages, err_msg=part)
+
+
+@pytest.mark.parametrize("preset_engine", sorted(FAMILY_FIELDS),
+                         indirect=True)
+def test_a_familys_records_hold_its_fields_and_no_other_familys(
+        preset_engine):
     """Every `engine.dispatch` record of every family is `FIELDS` long;
     between the engine's own fields and the stamps (and behind them) a
     family's fields are set in its records and every other family's are None; and every
@@ -910,10 +985,8 @@ def test_a_familys_records_hold_its_fields_and_no_other_familys(preset):
     from ray_tpu.serve.llm.server import EngineDriverMixin
     from ray_tpu.util import metrics
 
-    page = 8 if preset == "tiny-jamba" else 16
-    engine = LLMEngine(EngineConfig(
-        model=preset, dtype="float32", page_size=page, num_pages=96,
-        max_model_len=256, max_batch=4, prefill_buckets=(32, 64), seed=3))
+    engine = renewed(preset_engine)
+    preset = engine.config.model
     rng = np.random.default_rng(4)
     # shorter than a block, one bucket, and (where a prefill resumes) more
     # than the largest bucket holds
@@ -948,7 +1021,6 @@ def test_a_familys_records_hold_its_fields_and_no_other_familys(preset):
     assert len(published) > 20
     for key in published:
         assert metrics._registry[f"rtpu_llm_{key}"].description, key
-    engine.close()
 
 
 def test_trainer_step_leaves_one_record_a_call():

@@ -16,6 +16,11 @@ from ray_tpu.ops.attention import reference_attention
 from ray_tpu.ops.flash_attention import NEG_INF, flash_attention
 from ray_tpu.ops.paged_attention import merge_attention
 
+from _engines import jitted
+
+# (`flash_traced`: the call itself, for the tests that read its jaxpr)
+flash_traced = flash_attention
+
 # tiny-but-unaligned shapes exercise the padding paths; interpret mode is slow
 B, D = 2, 32
 
@@ -92,6 +97,7 @@ def _visible(sq, sk, *, causal, block_causal=0, q_lens=None, kv_lens=None,
     return see, real
 
 
+@jitted
 def _masked_reference(q, k, v, see):
     """(o, lse) of plain softmax attention over the pairs `see` admits,
     float32 throughout; a row that sees nothing gives 0 / NEG_INF."""
@@ -316,13 +322,13 @@ def test_a_short_query_block_folds_its_kv_heads_group_along_the_lanes(case):
     segs = (_two_segments(sk, 100)[:, sk - sq:], _two_segments(sk, 100))
     for kw in (dict(causal=True, **lens), dict(causal=False, **lens),
                dict(causal=True, segment_ids=segs)):
-        fn = functools.partial(flash_attention, return_lse=True, block_q=bq,
-                               block_k=128, **kw)
-        o, lse = fn(q, k, v, interpret=True)
-        see = dict(kw)
+        kw.update(return_lse=True, block_q=bq, block_k=128)
+        o, lse = flash_attention(q, k, v, interpret=True, **kw)
+        see = {k: kw[k] for k in kw if k in ("causal", "q_lens", "kv_lens")}
         _assert_real_rows_match(o, lse, q, k, v, *_visible(
-            sq, sk, segs=see.pop("segment_ids", None), **see))
-        call, = _kernel_calls(functools.partial(fn, interpret=False), q, k, v)
+            sq, sk, segs=kw.get("segment_ids"), **see))
+        call, = _kernel_calls(functools.partial(
+            flash_traced, interpret=False, **kw), q, k, v)
         assert call.params["grid_mapping"].grid == (B, hq // g, sq // bq)
 
 
@@ -349,8 +355,8 @@ def _assert_grads_match(q, k, v, blocks=None, **kw):
     def loss_ref(q, k, v):
         return jnp.sum(jnp.sin(reference_attention(q, k, v, **kw)))
 
-    g_got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_got = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_want = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for got, want, name in zip(g_got, g_want, "qkv"):
         np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5,
                                    err_msg=f"d{name}")
@@ -548,11 +554,11 @@ def test_without_lengths_the_call_is_what_it_was():
     q, k, v = _make(128, 256)
     for return_lse in (True, False):
         call, = _kernel_calls(functools.partial(
-            flash_attention, causal=False, interpret=False,
+            flash_traced, causal=False, interpret=False,
             return_lse=return_lse), q, k, v)
         assert len(call.invars) == 5
         assert call.params["grid_mapping"].num_index_operands == 0
-    call, = _kernel_calls(lambda q, k, v, n: flash_attention(
+    call, = _kernel_calls(lambda q, k, v, n: flash_traced(
         q, k, v, causal=False, interpret=False, return_lse=True, kv_lens=n),
         q, k, v, jnp.asarray([1, 2], jnp.int32))
     assert len(call.invars) == 6
@@ -604,11 +610,11 @@ def test_the_trainers_call_traces_to_the_jaxpr_it_had(case, sha):
     k = v = jnp.zeros((2, 2048, 8, 128), jnp.bfloat16)
 
     def loss(q, k, v, seg=None):
-        return flash_attention(q, k, v, causal=True, segment_ids=seg,
+        return flash_traced(q, k, v, causal=True, segment_ids=seg,
                                interpret=False).astype(jnp.float32).sum()
 
     if case == "lse":
-        fn, args = (lambda q, k, v: flash_attention(
+        fn, args = (lambda q, k, v: flash_traced(
             q, k, v, causal=True, interpret=False, return_lse=True)), ()
     else:
         args = (jnp.zeros((2, 2048), jnp.int32),) if case == "packed" else ()
@@ -640,7 +646,7 @@ def test_a_head_that_is_no_lane_tile_keeps_the_operands_it_had(case, sha):
         v = jnp.zeros((1, 512, 4, 128), bf16)
 
         def fn(q, k, v):
-            return flash_attention(
+            return flash_traced(
                 q, k, v, causal=True, interpret=False, return_lse=True,
                 kv_lens=jnp.asarray([300], jnp.int32))
     else:
@@ -648,11 +654,11 @@ def test_a_head_that_is_no_lane_tile_keeps_the_operands_it_had(case, sha):
         k = v = jnp.zeros((2, 256, 2, 16), bf16)
 
         def fn(q, k, v):
-            return flash_attention(
+            return flash_traced(
                 q, k, v, causal=True, interpret=False, return_lse=True,
                 q_lens=jnp.asarray([3, 200], jnp.int32))
         if case == "a-head-of-16":
-            fn = jax.grad(lambda q, k, v: flash_attention(
+            fn = jax.grad(lambda q, k, v: flash_traced(
                 q, k, v, causal=True, interpret=False).astype(
                     jnp.float32).sum(), argnums=(0, 1, 2))
     assert _jaxpr_sha(fn, q, k, v) == sha
@@ -775,17 +781,17 @@ def test_a_window_none_is_the_call_it_was_and_the_backward_refuses_one():
     test_kimi.py); a window is the forward-only path's, refused by name
     anywhere else."""
     q, k, v = _make(128, 128)
-    plain = _jaxpr_sha(lambda *a: flash_attention(
+    plain = _jaxpr_sha(lambda *a: flash_traced(
         *a, return_lse=True, interpret=True), q, k, v)
-    none = _jaxpr_sha(lambda *a: flash_attention(
+    none = _jaxpr_sha(lambda *a: flash_traced(
         *a, return_lse=True, interpret=True, window=None), q, k, v)
     assert plain == none
     with pytest.raises(ValueError, match="backward kernel has no band"):
-        flash_attention(q, k, v, window=64, interpret=True)
+        flash_traced(q, k, v, window=64, interpret=True)
     with pytest.raises(ValueError, match="positive sliding window"):
-        flash_attention(q, k, v, window=0, return_lse=True, interpret=True)
+        flash_traced(q, k, v, window=0, return_lse=True, interpret=True)
     with pytest.raises(ValueError, match="backward kernel has no band"):
-        jax.grad(lambda q: flash_attention(
+        jax.grad(lambda q: flash_traced(
             q, k, v, window=64, interpret=True).sum())(q)
 
 

@@ -18,8 +18,11 @@ from chipbench.references import gdn_mla_moe_decoder as ref
 from ray_tpu.models import gigachat
 from ray_tpu.models.llama import LlamaConfig, MoEMLP
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
-from ray_tpu.serve.llm.stage import init_params, model_family
+from ray_tpu.serve.llm.stage import model_family
 from ray_tpu.util import tracing
+
+from _engines import (applied, fresh_params, jitted, new_engine, scarce,
+                      tiny_engine)
 
 F32 = dict(dtype=jnp.float32, param_dtype=jnp.float32)
 CFG = dict(model="tiny-gigachat", dtype="float32", page_size=16,
@@ -71,8 +74,7 @@ def _seeded(params, seed=2):
 def tiny():
     cfg = gigachat.get_config("tiny-gigachat", **F32)
     model = gigachat.GigaChatModel(cfg)
-    params = _seeded(init_params(model, jnp.zeros((1, 8), jnp.int32),
-                                 jax.random.PRNGKey(1)))
+    params = fresh_params(model, 1, _seeded)
     return cfg, model, params
 
 
@@ -80,6 +82,7 @@ def _ids(shape, seed=3):
     return jax.random.randint(jax.random.PRNGKey(seed), shape, 0, 256)
 
 
+@jitted
 def _reference(params, ids):
     return ref.forward(ref.weights_from_program_tree(params), ids, PUB)
 
@@ -154,7 +157,7 @@ def test_the_full_forward_is_the_references(tiny):
     _, model, params = tiny
     ids = _ids((2, 100))
     with jax.default_matmul_precision("highest"):
-        got = model.apply({"params": params}, ids)
+        got = applied(model, params, ids)
     want = _reference(params, ids)
     assert float(jnp.abs(want).max()) > 0.5
     assert float(jnp.abs(got - want).max()) < 2e-4
@@ -168,7 +171,7 @@ def test_the_clamp_and_the_gates_are_live_in_the_comparison(tiny):
     _, model, params = tiny
     ids = _ids((1, 64))
     with jax.default_matmul_precision("highest"):
-        got = model.apply({"params": params}, ids)
+        got = applied(model, params, ids)
     w = ref.weights_from_program_tree(params)
     for over in (dict(swiglu_limit=None), dict(layernorm_gating_weight=1.0)):
         other = ref.forward(w, ids, {**PUB, **over})
@@ -198,8 +201,8 @@ def test_an_idle_slot_keeps_all_three_kinds_bit_for_bit(tiny):
     bt = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
     cache = gigachat.serving_cache(cfg, pool, bt,
                                    jnp.asarray([20, 0], jnp.int32))
-    _, new = model.apply({"params": params}, _ids((2, 1)),
-                         positions=jnp.asarray([[19], [0]]), kv_caches=cache)
+    _, new = applied(model, params, _ids((2, 1)),
+                     positions=jnp.asarray([[19], [0]]), kv_caches=cache)
     for name, axis in (("gdn_state", 1), ("gdn_conv", 2)):
         was, now = (jnp.moveaxis(p[name], axis, 0) for p in (pool, new.pool))
         assert bool((now[1] == was[1]).all()), name
@@ -237,7 +240,7 @@ def test_the_shares_of_sixteen_chips_add_up_to_the_uncut_layer():
 
     want, _ = ref._expert_layer(m, ref_w(0, 32), dict(cfg, expert_first=0),
                                 "float32")
-    assert float(jnp.abs(whole.apply({"params": wp}, x).reshape(-1, 32)
+    assert float(jnp.abs(applied(whole, wp, x).reshape(-1, 32)
                          - want).max()) < 1e-5
     # the clamp bites: the same layer without it is another layer
     loose, _ = ref._expert_layer(m, ref_w(0, 32), dict(
@@ -269,8 +272,8 @@ def test_a_vocabulary_slice_is_a_smaller_vocabulary(tiny):
                   lm_head=params["lm_head"][:, :128])
     ids = _ids((1, 40)) % 128
     with jax.default_matmul_precision("highest"):
-        got = cut.apply({"params": sliced}, ids)
-        whole = model.apply({"params": params}, ids)
+        got = applied(cut, sliced, ids)
+        whole = applied(model, params, ids)
     assert got.shape[-1] == 128
     assert float(jnp.abs(got - whole[..., :128]).max()) < 1e-5
     assert float(jnp.abs(got - _reference(sliced, ids)).max()) < 2e-4
@@ -301,18 +304,15 @@ def _judge(engine, prompt, tokens, tie=1e-3):
 
 
 def _engine(**over):
-    eng = LLMEngine(EngineConfig(**{**CFG, **over}))
-    # a fresh engine's norms, gates and decays sit at their centres: move
-    # them, as `tiny` does
-    eng.compute.params = jax.tree.map(jnp.asarray, _seeded(eng.params))
-    return eng
+    """The module's engine of this configuration, renewed. (A fresh
+    engine's norms, gates and decays sit at their centres: `_seeded` moves
+    them, as `tiny` does.)"""
+    return tiny_engine(**{**CFG, **over}, params=_seeded)
 
 
 @pytest.fixture(scope="module")
 def engine():
-    eng = _engine()
-    yield eng
-    eng.close()
+    return _engine()
 
 
 def _prompts(lens, seed):
@@ -380,20 +380,20 @@ def test_a_preemption_that_refills_keeps_the_tokens():
     """Out of pages mid-decode: the victim's slot and pages go, and it is
     refilled by prefilling prompt + tokens so far again (B-M6 (a): no
     state is saved)."""
-    eng = _engine(num_pages=9, max_model_len=128, max_batch=2)
-    prompts = _prompts((30, 33), 5)
-    for i, p in enumerate(prompts):
-        eng.add_request(f"q{i}", p, SamplingParams(max_tokens=50))
-    got = _run(eng)
-    assert eng.stats()["preempted_total"] >= 1
-    for i, p in enumerate(prompts):
-        assert len(got[f"q{i}"]) == 50
-        assert _judge(eng, p, got[f"q{i}"]) >= 35
-    eng.close()
+    with scarce(_engine(), 8) as eng:
+        prompts = _prompts((30, 33), 5)
+        for i, p in enumerate(prompts):
+            eng.add_request(f"q{i}", p, SamplingParams(max_tokens=50))
+        got = _run(eng)
+        assert eng.stats()["preempted_total"] >= 1
+        for i, p in enumerate(prompts):
+            assert len(got[f"q{i}"]) == 50
+            assert _judge(eng, p, got[f"q{i}"]) >= 35
 
 
 def test_no_program_is_built_under_traffic_after_warmup():
-    eng = LLMEngine(EngineConfig(**CFG))
+    """(An engine of its own: what a first use builds is the claim.)"""
+    eng = new_engine(**CFG)
     n = eng.warmup()
     assert n == 2 * 2 + 1
     tracing.reset_ring()

@@ -16,8 +16,10 @@ from chipbench.runners import engine_ssm
 from ray_tpu.models import jamba, llama
 from ray_tpu.ops import selective_scan as ss
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
-from ray_tpu.serve.llm.stage import init_params, model_family
+from ray_tpu.serve.llm.stage import model_family
 from ray_tpu.util import tracing
+
+from _engines import applied, fresh_params, jitted, scarce, tiny_engine
 
 F32 = jnp.float32
 CFG = jamba.get_config("tiny-jamba", dtype=F32, param_dtype=F32)
@@ -29,11 +31,19 @@ PUB = dict(num_hidden_layers=8, attn_layer_period=4, attn_layer_offset=1,
 VOCAB = CFG.vocab_size
 
 
+BASE = dict(model="tiny-jamba", dtype="float32", num_pages=64,
+            page_size=8, max_model_len=128, max_batch=4,
+            prefill_buckets=(16, 32, 64, 128), seed=3)
+
+
 def _engine_config(**over):
-    base = dict(model="tiny-jamba", dtype="float32", num_pages=64,
-                page_size=8, max_model_len=128, max_batch=4,
-                prefill_buckets=(16, 32, 64, 128), seed=3)
-    return EngineConfig(**{**base, **over})
+    return EngineConfig(**{**BASE, **over})
+
+
+def _engine(params, **over):
+    """The module's engine of this configuration over the seeded weights,
+    renewed."""
+    return tiny_engine(**{**BASE, **over}, params=params)
 
 
 @pytest.fixture(scope="module")
@@ -43,8 +53,7 @@ def model():
 
 @pytest.fixture(scope="module")
 def params(model):
-    return init_params(model, jnp.zeros((1, 8), jnp.int32),
-                       jax.random.PRNGKey(11))
+    return fresh_params(model, 11)
 
 
 @pytest.fixture(scope="module")
@@ -53,7 +62,7 @@ def ref_weights(params):
 
 
 def _ref_logits(ref_weights, seq):
-    return np.asarray(reference.forward(
+    return np.asarray(jitted(reference.forward)(
         ref_weights, jnp.asarray([seq], jnp.int32), PUB)[0])
 
 
@@ -108,7 +117,7 @@ def test_family_is_chosen_in_one_place():
 def test_full_forward_matches_the_reference(model, params, ref_weights,
                                             length):
     ids = jnp.asarray([_prompt(length, length)], jnp.int32)
-    got = np.asarray(model.apply({"params": params}, ids)[0])
+    got = np.asarray(applied(model, params, ids)[0])
     want = _ref_logits(ref_weights, ids[0].tolist())
     np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -127,7 +136,7 @@ def test_prefill_then_decode_through_pages_and_state(ref_weights, params):
     """Prefill, then token by token through the cache (the benchmark's own
     logits path: its pages and per-slot state), against the reference's
     full forward at every position."""
-    engine = LLMEngine(_engine_config(), params=params)
+    engine = _engine(params)
     prompts = [_prompt(s, n) for s, n in ((1, 5), (2, 21), (3, 40))]
     rows, fed = engine_ssm._paged_logits(engine, prompts, 6)
     for prompt, logits, toks in zip(prompts, rows, fed):
@@ -178,14 +187,14 @@ def test_a_rows_logits_do_not_depend_on_slot_padding_or_neighbours(
             ids[i, :len(r)] = r
         cache = _cache(pool, [len(r) for r in rows],
                        jnp.arange(len(rows)))
-        logits, cache = model.apply({"params": params}, jnp.asarray(ids),
-                                    kv_caches=cache)
+        logits, cache = applied(model, params, jnp.asarray(ids),
+                                kv_caches=cache)
         return np.asarray(logits), cache.pool
 
     def decode(pool, lens, tokens):
         pos = jnp.maximum(jnp.asarray(lens)[:, None] - 1, 0)
-        logits, cache = model.apply(
-            {"params": params}, jnp.asarray(tokens, jnp.int32)[:, None],
+        logits, cache = applied(
+            model, params, jnp.asarray(tokens, jnp.int32)[:, None],
             positions=pos, kv_caches=_cache(pool, lens, None))
         return np.asarray(logits[:, 0]), cache.pool
 
@@ -221,12 +230,12 @@ def test_an_idle_slot_of_a_poisoned_pool_stays_poisoned_bit_for_bit(
     bt = jnp.asarray([[5, 6, 7, 8]], jnp.int32)
     cache = jamba.serving_cache(CFG, pool, bt, jnp.asarray([7]),
                                 jnp.asarray([1]))
-    _, cache = model.apply({"params": params}, ids, kv_caches=cache)
+    _, cache = applied(model, params, ids, kv_caches=cache)
     before = cache.pool
     bt4 = jnp.arange(1, 17, dtype=jnp.int32).reshape(4, 4)
     cache = jamba.serving_cache(CFG, before, bt4, jnp.asarray([0, 8, 0, 0]))
-    logits, cache = model.apply(
-        {"params": params}, jnp.asarray([[1], [2], [3], [4]], jnp.int32),
+    logits, cache = applied(
+        model, params, jnp.asarray([[1], [2], [3], [4]], jnp.int32),
         positions=jnp.asarray([[0], [7], [0], [0]]), kv_caches=cache)
     assert np.isfinite(np.asarray(logits[1])).all()
     for name in ("ssm_h", "ssm_conv"):
@@ -240,7 +249,7 @@ def test_an_idle_slot_of_a_poisoned_pool_stays_poisoned_bit_for_bit(
 # ----------------------------------------------------------------- engine
 def test_engine_serves_greedy_tokens_the_reference_agrees_with(
         params, ref_weights):
-    engine = LLMEngine(_engine_config(), params=params)
+    engine = _engine(params)
     prompts = [_prompt(s, n) for s, n in ((1, 5), (2, 20), (3, 33), (4, 60))]
     out = _generate(engine, prompts, 10)
     for i, p in enumerate(prompts):
@@ -252,7 +261,7 @@ def test_a_repeated_prompt_is_prefilled_again_not_reused(params,
                                                          ref_weights):
     """Prefix reuse is off: the second request of the same prompt finds no
     page, registers none, and gets the same tokens from its own state."""
-    engine = LLMEngine(_engine_config(), params=params)
+    engine = _engine(params)
     prompt = _prompt(21, 40)               # five full pages
     first = _generate(engine, [("a", prompt)], 8)["a"]
     second = _generate(engine, [("b", prompt), ("c", prompt)], 8)
@@ -269,33 +278,31 @@ def test_engine_is_right_through_a_preemption(params, ref_weights):
     """11 usable pages cannot hold three 7-page sequences: a request is
     preempted, its output folded into its prompt, and prefilled again from
     ZERO state into whatever slot it then gets."""
-    engine = LLMEngine(_engine_config(num_pages=12, max_model_len=64,
-                                      prefill_buckets=(32, 64)),
-                       params=params)
-    prompts = [_prompt(s, 20) for s in (31, 32, 33)]
-    out = _generate(engine, prompts, 30)
-    assert engine.stats()["preempted_total"] >= 1
-    for i, p in enumerate(prompts):
-        assert len(out[f"r{i}"]) == 30
-        _assert_greedy(ref_weights, p, out[f"r{i}"])
+    with scarce(_engine(params), 11) as engine:
+        prompts = [_prompt(s, 20) for s in (31, 32, 33)]
+        out = _generate(engine, prompts, 30)
+        assert engine.stats()["preempted_total"] >= 1
+        for i, p in enumerate(prompts):
+            assert len(out[f"r{i}"]) == 30
+            _assert_greedy(ref_weights, p, out[f"r{i}"])
 
 
 def test_a_poisoned_state_pool_gives_the_same_tokens(params):
     """NaN in every slot of the state pool before any request: a prefill
     row starts from zero and never reads what its slot held."""
     prompts = [_prompt(s, n) for s, n in ((41, 9), (42, 30))]
-    clean = _generate(LLMEngine(_engine_config(), params=params), prompts, 8)
-    engine = LLMEngine(_engine_config(), params=params)
+    clean = _generate(_engine(params), prompts, 8)
+    engine = _engine(params)              # the same engine, renewed
     engine.compute.kv_pages = _poisoned_state(engine.compute.kv_pages)
     assert _generate(engine, prompts, 8) == clean
 
 
 def test_warm_up_builds_no_cached_prefix_program(params):
-    engine = LLMEngine(_engine_config(), params=params)
+    engine = _engine(params)
     programs = engine._warmup_programs(None, True)
     assert [key[2] for kind, key in programs if kind == "prefill"] == [0] * 4
     assert ("decode", engine._decode_shape_key()) in programs
-    dense = LLMEngine(EngineConfig(model="tiny", dtype="float32"))
+    dense = tiny_engine("tiny")
     assert len(dense._warmup_programs(None, False)) == 2 * len(
         dense.config.prefill_buckets)
 
@@ -323,7 +330,7 @@ def test_engine_options_that_need_the_state_moved_are_refused(option):
 
 
 def test_the_disaggregated_hand_off_is_refused(params):
-    engine = LLMEngine(_engine_config(), params=params)
+    engine = _engine(params)
     for call in (
             lambda: engine.add_request("p", [1, 2, 3], SamplingParams(
                 max_tokens=4, prefill_only=True)),
@@ -333,14 +340,14 @@ def test_the_disaggregated_hand_off_is_refused(params):
         with pytest.raises(NotImplementedError, match="hand-off"):
             call()
     # a dense engine's hand-off is what it was
-    LLMEngine(EngineConfig(model="tiny", dtype="float32"))._refuse_handoff()
+    tiny_engine("tiny")._refuse_handoff()
 
 
 # ------------------------------------------------------ spans and counters
 def test_records_and_stats_say_what_the_state_costs(params):
     tracing.reset_ring()
-    engine = LLMEngine(_engine_config(decode_steps_per_dispatch=2),
-                       params=params)
+    # (two steps a dispatch: the updates are counted by a record's `k`)
+    engine = _engine(params, decode_steps_per_dispatch=2)
     st = engine.stats()
     assert st["ssm_slots"] == 4
     assert st["ssm_state_pool_bytes"] == 4 * CFG.ssm_state_bytes_row()
@@ -362,7 +369,7 @@ def test_records_and_stats_say_what_the_state_costs(params):
 def test_dense_and_expert_engines_carry_none_of_it():
     for preset in ("tiny", "tiny-moe"):
         tracing.reset_ring()
-        engine = LLMEngine(EngineConfig(model=preset, dtype="float32"))
+        engine = tiny_engine(preset)
         _generate(engine, [[1, 2, 3, 4, 5]], 3)
         assert not [k for k in engine.stats()
                     if k.startswith(("ssm_", "prefix_reuse"))]

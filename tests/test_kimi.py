@@ -21,8 +21,10 @@ from ray_tpu.models.llama import LlamaConfig, MoEMLP
 from ray_tpu.ops import paged_attention as pa
 from ray_tpu.ops.rotary import yarn_inv_freq, yarn_mscale
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
-from ray_tpu.serve.llm.stage import init_params
 from ray_tpu.util import tracing
+
+from _engines import (applied, fresh_params, jitted, new_engine, scarce,
+                      tiny_engine)
 
 F32 = dict(dtype=jnp.float32, param_dtype=jnp.float32)
 CFG = dict(model="tiny-kimi", dtype="float32", page_size=16, num_pages=64,
@@ -60,8 +62,7 @@ def _seeded(params, seed=2):
 def tiny():
     cfg = kimi.get_config("tiny-kimi", **F32)
     model = kimi.KimiModel(cfg)
-    params = _seeded(init_params(model, jnp.zeros((1, 8), jnp.int32),
-                                 jax.random.PRNGKey(1)))
+    params = fresh_params(model, 1, _seeded)
     return cfg, model, params
 
 
@@ -69,6 +70,7 @@ def _ids(shape, seed=3):
     return jax.random.randint(jax.random.PRNGKey(seed), shape, 0, 256)
 
 
+@jitted
 def _reference(params, ids):
     return ref.forward(ref.weights_from_program_tree(params), ids, PUB)
 
@@ -114,7 +116,7 @@ def test_the_full_forward_is_the_references(tiny):
     _, model, params = tiny
     ids = _ids((2, 100))
     with jax.default_matmul_precision("highest"):
-        got = model.apply({"params": params}, ids)
+        got = applied(model, params, ids)
     want = _reference(params, ids)
     assert float(jnp.abs(got - want).max()) < 1e-4
     assert float(jnp.sqrt((want ** 2).mean())) > 0.3
@@ -173,9 +175,9 @@ def test_a_chunk_past_every_rows_context_runs_nothing(tiny):
     def resumed(pool):
         cache = kimi.serving_cache(cfg, pool, bt, jnp.asarray([80]),
                                    ctx_pages=MP)
-        return model.apply({"params": params}, jnp.asarray(seq[64:])[None],
-                           positions=(64 + jnp.arange(16))[None],
-                           kv_caches=cache)[0]
+        return applied(model, params, jnp.asarray(seq[64:])[None],
+                       positions=(64 + jnp.arange(16))[None],
+                       kv_caches=cache)[0]
 
     assert bool(jnp.isfinite(resumed(dirty)).all())
     assert float(jnp.abs(resumed(dirty) - resumed(pool)).max()) == 0.0
@@ -201,7 +203,7 @@ def test_the_shares_of_four_chips_add_up_to_the_uncut_layer():
     wp["router"] = wp["router"] * 30
     wp["router_bias"] = 0.05 * np.asarray(
         jax.random.normal(jax.random.PRNGKey(2), (16,)))
-    want = whole.apply({"params": wp}, x)
+    want = applied(whole, wp, x)
     shared = jnp.asarray(
         jax.nn.silu(x @ wp["shared"]["gate_up_proj"]["kernel"][:, :16])
         * (x @ wp["shared"]["gate_up_proj"]["kernel"][:, 16:])
@@ -271,16 +273,16 @@ def test_an_absent_expert_costs_no_row_and_padding_costs_none():
     _, chosen = ref.route(x.reshape(-1, 32), p["router"], p["router_bias"],
                           PUB)
     held = np.asarray(chosen)[:, 4:8]
-    _, sown = layer.apply({"params": p}, x, mutable=["routing"])
+    _, sown = applied(layer, p, x, mutable=["routing"])
     counts = np.asarray(jax.tree.leaves(sown["routing"])[0])
     assert counts.tolist() == held.sum(0).tolist()
     assert counts.sum() < 40 * 4          # the absent ones are not rows
     mask = jnp.arange(40)[None] < 25
-    out, sown = layer.apply({"params": p}, x, mask, mutable=["routing"])
+    out, sown = applied(layer, p, x, mask, mutable=["routing"])
     counts = np.asarray(jax.tree.leaves(sown["routing"])[0])
     assert counts.tolist() == held[:25].sum(0).tolist()
     assert float(jnp.abs(out[0, 25:]).max()) == 0.0
-    full = layer.apply({"params": p}, x)
+    full = applied(layer, p, x)
     assert float(jnp.abs(out[0, :25] - full[0, :25]).max()) < 1e-6
 
 
@@ -409,9 +411,7 @@ def _judge(engine, prompt, tokens, tie=1e-3):
 
 @pytest.fixture(scope="module")
 def engine():
-    eng = LLMEngine(EngineConfig(**CFG))
-    yield eng
-    eng.close()
+    return tiny_engine(**CFG)
 
 
 def _prompts(lens, seed):
@@ -471,39 +471,38 @@ def test_the_engine_emits_the_references_tokens_and_its_records_say_how(
 
 
 def test_a_prefix_hit_and_a_preemption_that_refills_keep_the_tokens():
-    eng = LLMEngine(EngineConfig(**{**CFG, "num_pages": 9,
-                                    "max_model_len": 128, "max_batch": 2}))
-    shared = _prompts((48,), 11)[0]
-    tails = _prompts((5, 7), 12)
-    for i, tail in enumerate(tails):
-        eng.add_request(f"p{i}", shared + tail, SamplingParams(max_tokens=6))
-        got = _run(eng)[f"p{i}"]
-        assert _judge(eng, shared + tail, got) >= 4
-    assert eng.stats()["prefix_token_hits"] == 48      # three pages of 16
-    assert "prefix_reuse_refused_total" not in eng.stats()
-    prompts = _prompts((30, 33), 5)
-    for i, p in enumerate(prompts):
-        eng.add_request(f"q{i}", p, SamplingParams(max_tokens=50))
-    got = _run(eng)
-    assert eng.stats()["preempted_total"] >= 1
-    for i, p in enumerate(prompts):
-        assert len(got[f"q{i}"]) == 50
-        assert _judge(eng, p, got[f"q{i}"]) >= 35
-    eng.close()
+    with scarce(tiny_engine(**CFG), 8) as eng:
+        shared = _prompts((48,), 11)[0]
+        tails = _prompts((5, 7), 12)
+        for i, tail in enumerate(tails):
+            eng.add_request(f"p{i}", shared + tail,
+                            SamplingParams(max_tokens=6))
+            got = _run(eng)[f"p{i}"]
+            assert _judge(eng, shared + tail, got) >= 4
+        assert eng.stats()["prefix_token_hits"] == 48      # three pages of 16
+        assert "prefix_reuse_refused_total" not in eng.stats()
+        prompts = _prompts((30, 33), 5)
+        for i, p in enumerate(prompts):
+            eng.add_request(f"q{i}", p, SamplingParams(max_tokens=50))
+        got = _run(eng)
+        assert eng.stats()["preempted_total"] >= 1
+        for i, p in enumerate(prompts):
+            assert len(got[f"q{i}"]) == 50
+            assert _judge(eng, p, got[f"q{i}"]) >= 35
 
 
 def test_chunked_prefill_is_resumed_passes_too():
-    eng = LLMEngine(EngineConfig(**{**CFG, "prefill_chunk_tokens": 32}))
+    eng = tiny_engine(**{**CFG, "prefill_chunk_tokens": 32})
     prompt = _prompts((100,), 9)[0]
     eng.add_request("c", prompt, SamplingParams(max_tokens=6))
     got = _run(eng)["c"]
     assert eng.stats()["prefill_resumed_passes_total"] >= 2
     assert _judge(eng, prompt, got) >= 4
-    eng.close()
 
 
 def test_no_program_is_built_under_traffic_after_warmup():
-    eng = LLMEngine(EngineConfig(**CFG))
+    """(An engine of its own: what a first use builds is the claim.)"""
+    eng = new_engine(**CFG)
     n = eng.warmup()
     assert n == 2 * 2 + 1
     assert set(eng.compute.programs) == {

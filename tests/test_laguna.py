@@ -21,11 +21,13 @@ import pytest
 from chipbench.references import laguna_decoder as ref
 from ray_tpu.models import laguna, mellum
 from ray_tpu.ops import paged_attention as pa
-from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.serve.llm import EngineConfig, SamplingParams
 from ray_tpu.serve.llm.engine import (PassCost, _attn_visits, _kind_shares,
                                       plan_passes, refuse)
 from ray_tpu.serve.llm.stage import init_params, model_family
 from ray_tpu.util import tracing
+
+from _engines import applied, fresh_params, jitted, scarce, tiny_engine
 
 F32 = dict(dtype=jnp.float32, param_dtype=jnp.float32)
 WINDOW = 32
@@ -84,8 +86,7 @@ def contexts_walked_in_chunks():
 def tiny():
     cfg = laguna.get_config("tiny-laguna", **F32)
     model = laguna.serving_model(cfg)
-    params = _seeded(init_params(model, jnp.zeros((1, 8), jnp.int32),
-                                 jax.random.PRNGKey(1)))
+    params = fresh_params(model, 1, _seeded)
     return cfg, model, params
 
 
@@ -124,6 +125,7 @@ PARTS = ("gate", "half-rotation", "theta-a-kind", "bias", "scale",
          "shared-expert", "one-key-more", "one-key-fewer")
 
 
+@jitted
 def _reference(params, ids, part=None):
     w = ref.weights_from_program_tree(params)
     cfg = PUB
@@ -235,7 +237,7 @@ def forwards(tiny):
     for n in (24, 100, 640):
         ids = _ids((1, n), seed=n)
         with jax.default_matmul_precision("highest"):
-            out[n] = (ids, model.apply({"params": params}, ids),
+            out[n] = (ids, applied(model, params, ids),
                       _reference(params, ids))
     return out
 
@@ -296,7 +298,7 @@ def test_resumed_passes_then_decode_through_the_cache_are_the_references(
 def test_the_selection_sown_is_the_references_experts(tiny):
     cfg, model, params = tiny
     ids = _ids((1, 80), seed=9)
-    _, sown = model.apply({"params": params}, ids, mutable=["selection"])
+    _, sown = applied(model, params, ids, mutable=["selection"])
     got = jnp.concatenate([v["chosen"][0][:, 0] for _, v in sorted(
         sown["selection"].items())])                     # [L, S, 1, E]
     w = ref.weights_from_program_tree(params)
@@ -313,13 +315,16 @@ def test_the_selection_sown_is_the_references_experts(tiny):
 
 
 # ----------------------------------------------- (b) the engine's normal path
-def _engine(**more):
-    """An engine on the seeded weights (a selection bias off zero)."""
+@functools.cache
+def _engine_params():
     cfg = laguna.get_config("tiny-laguna", **F32)
-    params = _seeded(init_params(
-        laguna.serving_model(cfg), jnp.zeros((1, 8), jnp.int32),
-        jax.random.PRNGKey(1)))
-    return LLMEngine(EngineConfig(**{**CFG, **more}), params=params)
+    return fresh_params(laguna.serving_model(cfg), 1, _seeded)
+
+
+def _engine(**more):
+    """The module's engine of this configuration on the seeded weights (a
+    selection bias off zero), renewed."""
+    return tiny_engine(**{**CFG, **more}, params=_engine_params())
 
 
 @pytest.fixture(scope="module")
@@ -432,14 +437,13 @@ def test_a_preempted_request_refills_and_agrees():
     """Two pages short: the decode step preempts a request, which refills
     its pages AND its slot's rings from its tokens; every token of both is
     still the reference's."""
-    engine = _engine(num_pages=22, max_model_len=256, max_batch=2)
-    rng = np.random.default_rng(7)
-    prompts = [rng.integers(0, 256, n).tolist() for n in (150, 140)]
-    emitted = _generate(engine, prompts, 40)
-    assert engine.stats()["preempted_total"] >= 1
-    assert [len(e) for e in emitted] == [40, 40]
-    assert _worst_by_the_reference(engine, prompts, emitted) < 1e-3
-    engine.close()
+    with scarce(_engine(), 21) as engine:
+        rng = np.random.default_rng(7)
+        prompts = [rng.integers(0, 256, n).tolist() for n in (150, 140)]
+        emitted = _generate(engine, prompts, 40)
+        assert engine.stats()["preempted_total"] >= 1
+        assert [len(e) for e in emitted] == [40, 40]
+        assert _worst_by_the_reference(engine, prompts, emitted) < 1e-3
 
 
 def test_a_pass_is_priced_by_each_kinds_pairs_and_heads():

@@ -13,6 +13,8 @@ from ray_tpu.serve.llm import (ByteTokenizer, EngineConfig, LLMEngine,
                                PageAllocator, SamplingParams)
 from ray_tpu.serve.llm.cache import OutOfPages
 
+from _engines import scarce, tiny_engine
+
 ENGINE_CFG = dict(
     model="tiny", page_size=8, num_pages=64, max_model_len=128,
     max_batch=4, prefill_buckets=(16, 32, 64, 128), dtype="float32",
@@ -116,7 +118,7 @@ def test_continuous_batching_matches_solo_runs():
 
 
 def test_prefix_cache_reuse_identical_output():
-    engine = LLMEngine(EngineConfig(**ENGINE_CFG))
+    engine = tiny_engine(**ENGINE_CFG)
     shared = list(np.random.default_rng(2).integers(0, 500, 24))
     engine.add_request("a", shared + [7], SamplingParams(max_tokens=4))
     out_a = _collect(engine, ["a"])["a"]["ids"]
@@ -133,53 +135,47 @@ def test_engine_matches_dense_greedy_through_prefix_hit_and_preemption(
     prefill, 32 decode steps, a preemption and its re-prefill, and a
     prefix-cache hit (the ctx_pages > 0 program). Each request's greedy
     tokens are those of the same model run densely with no cache."""
-    cfg = dict(ENGINE_CFG)
-    cfg.update(num_pages=12, max_model_len=64, max_batch=2,
-               prefill_buckets=(16, 32, 64))
-    engine = LLMEngine(EngineConfig(**cfg))
-    rng = np.random.default_rng(4)
-    prompts = {f"p{i}": list(rng.integers(0, 500, 17)) for i in range(2)}
-    # two full pages of p0's prompt, then a tail of its own
-    prompts["hit"] = prompts["p0"][:16] + list(rng.integers(0, 500, 5))
-    n = 32
-    # 2 x (17 + 32) tokens need 14 pages and 11 are free: one is preempted
-    for rid in ("p0", "p1"):
-        engine.add_request(rid, prompts[rid], SamplingParams(max_tokens=n))
-    out = _collect(engine, ["p0", "p1"], max_steps=900)
-    assert engine.stats()["preempted_total"] >= 1
-    hits = engine.allocator.stats["cache_hits"]
-    engine.add_request("hit", prompts["hit"], SamplingParams(max_tokens=n))
-    out.update(_collect(engine, ["hit"], max_steps=900))
-    assert engine.allocator.stats["cache_hits"] > hits
-    want = dense_greedy(engine.model, engine.params,
-                        list(prompts.values()), n)
-    for rid, tokens in zip(prompts, want):
-        assert out[rid]["ids"] == tokens, rid
+    with scarce(tiny_engine(**ENGINE_CFG), 11) as engine:
+        rng = np.random.default_rng(4)
+        prompts = {f"p{i}": list(rng.integers(0, 500, 17)) for i in range(2)}
+        # two full pages of p0's prompt, then a tail of its own
+        prompts["hit"] = prompts["p0"][:16] + list(rng.integers(0, 500, 5))
+        n = 32
+        # 2 x (17 + 32) tokens need 14 pages and 11 are free: one is preempted
+        for rid in ("p0", "p1"):
+            engine.add_request(rid, prompts[rid], SamplingParams(max_tokens=n))
+        out = _collect(engine, ["p0", "p1"], max_steps=900)
+        assert engine.stats()["preempted_total"] >= 1
+        hits = engine.allocator.stats["cache_hits"]
+        engine.add_request("hit", prompts["hit"], SamplingParams(max_tokens=n))
+        out.update(_collect(engine, ["hit"], max_steps=900))
+        assert engine.allocator.stats["cache_hits"] > hits
+        want = dense_greedy(engine.model, engine.params,
+                            list(prompts.values()), n)
+        for rid, tokens in zip(prompts, want):
+            assert out[rid]["ids"] == tokens, rid
 
 
 def test_page_pressure_queues_and_completes():
     """More requests than the page pool supports at once: engine must queue
     and still complete everything."""
-    cfg = dict(ENGINE_CFG)
-    cfg.update(num_pages=12, max_model_len=64,
-               prefill_buckets=(16, 32, 64))
-    engine = LLMEngine(EngineConfig(**cfg))
-    rng = np.random.default_rng(3)
-    ids = []
-    for i in range(5):
-        rid = f"p{i}"
-        ids.append(rid)
-        engine.add_request(rid, list(rng.integers(0, 500, 17)),
-                           SamplingParams(max_tokens=8))
-    out = _collect(engine, ids)
-    for rid in ids:
-        assert out[rid]["fin"] in ("length", "stop"), out[rid]
-        assert len(out[rid]["ids"]) == 8
-    assert engine.allocator.num_free() > 0
+    with scarce(tiny_engine(**ENGINE_CFG), 11) as engine:
+        rng = np.random.default_rng(3)
+        ids = []
+        for i in range(5):
+            rid = f"p{i}"
+            ids.append(rid)
+            engine.add_request(rid, list(rng.integers(0, 500, 17)),
+                               SamplingParams(max_tokens=8))
+        out = _collect(engine, ids)
+        for rid in ids:
+            assert out[rid]["fin"] in ("length", "stop"), out[rid]
+            assert len(out[rid]["ids"]) == 8
+        assert engine.allocator.num_free() > 0
 
 
 def test_temperature_sampling_and_stop_tokens():
-    engine = LLMEngine(EngineConfig(**ENGINE_CFG))
+    engine = tiny_engine(**ENGINE_CFG)
     prompt = [1, 2, 3, 4, 5]
     engine.add_request("t", prompt,
                        SamplingParams(max_tokens=50, temperature=1.0,
@@ -337,7 +333,7 @@ def test_device_samples_top_k_sits_inside_a_branch():
 
 
 def _engine_tokens(requests):
-    engine = LLMEngine(EngineConfig(**ENGINE_CFG))
+    engine = tiny_engine(**ENGINE_CFG)
     for rid, prompt, sampling in requests:
         engine.add_request(rid, prompt, sampling)
     out = _collect(engine, [r[0] for r in requests])
@@ -488,15 +484,14 @@ def test_pd_handoff_matches_single_engine():
     """Prefill→extract_kv→inject→decode must reproduce the single-engine
     greedy output token for token (ref: prefill_decode_disagg.py — the
     reference delegates KV movement to vLLM; here it is native)."""
-    cfg = EngineConfig(**ENGINE_CFG, seed=0)
     prompt = list(range(1, 40))
 
-    ref = LLMEngine(cfg)
+    ref = tiny_engine(**ENGINE_CFG)
     ref.add_request("ref", prompt, SamplingParams(max_tokens=12))
     ref_out = _collect(ref, ["ref"])["ref"]["ids"]
 
-    prefill = LLMEngine(cfg)
-    decode = LLMEngine(cfg)
+    prefill = tiny_engine(**ENGINE_CFG)        # `ref`, renewed
+    decode = tiny_engine(**ENGINE_CFG, twin="decode")
     prefill.add_request("r", prompt, SamplingParams(max_tokens=12))
     first = []
     while not first:
@@ -576,15 +571,14 @@ def test_pd_concurrent_requests_one_replica(shared_cluster):
 def test_pd_prefill_respects_stop_on_first_token():
     """A request whose first token terminates (max_tokens=1 / EOS) must
     finish at the prefill tier with the real reason — never hand off."""
-    cfg = EngineConfig(**ENGINE_CFG, seed=0)
-    engine = LLMEngine(cfg)
+    engine = tiny_engine(**ENGINE_CFG)
     sampling = SamplingParams(max_tokens=1, prefill_only=True)
     engine.add_request("r", [1, 2, 3, 4, 5], sampling)
     out = _collect(engine, ["r"])
     assert out["r"]["fin"] == "length"  # not prefill_done
     assert "r" not in engine.extracted
     # pages released (nothing leaked for a finished request)
-    assert engine.allocator.num_free() == cfg.num_pages - 1
+    assert engine.allocator.num_free() == ENGINE_CFG["num_pages"] - 1
 
 
 # ----------------------------------------------------- tensor parallel
@@ -689,7 +683,7 @@ def test_tp_bundles_and_page_budget():
     with pytest.raises(ValueError, match="cannot span hosts"):
         tp_bundles(8)
     # per-shard accounting: a fixed per-chip budget affords tp x pages
-    engine = LLMEngine(EngineConfig(**ENGINE_CFG))
+    engine = tiny_engine(**ENGINE_CFG)
     mcfg = engine.model_cfg
     base = pages_for_budget(1 << 20, 8, mcfg, dtype_bytes=4, tp=1)
     assert pages_for_budget(1 << 20, 8, mcfg, dtype_bytes=4, tp=2) \
@@ -816,7 +810,7 @@ def test_prefix_aware_coadmission_skips_blocked_head():
     cfg = dict(ENGINE_CFG)
     cfg.update(num_pages=12, max_model_len=128, max_batch=3,
                prefill_buckets=(16, 32, 64, 128))
-    engine = LLMEngine(EngineConfig(**cfg, prefill_chunk_tokens=16))
+    engine = tiny_engine(**cfg, prefill_chunk_tokens=16)
     shared = list(np.random.default_rng(6).integers(0, 500, 16))
 
     # warm the prefix cache with `shared` (2 full pages), then release
@@ -911,13 +905,16 @@ def test_prompt_lookup_draft_unit():
 
 def test_running_request_expires_mid_decode():
     """A RUNNING slot whose propagated deadline passes is pruned at step
-    start: typed 'expired' delta, slot + pages freed, dead work stops."""
+    start: typed 'expired' delta, slot + pages freed, dead work stops.
+    (The deadline is an hour off when the request comes and is moved into
+    the past once tokens have come: 0.4 s from the start passed mid-decode
+    only while the engine's programs were being built.)"""
     import time as _time
 
-    engine = LLMEngine(EngineConfig(**ENGINE_CFG))
+    engine = tiny_engine(**ENGINE_CFG)
     engine.add_request("d", [1, 2, 3, 4, 5],
                        SamplingParams(max_tokens=500),
-                       deadline=_time.time() + 0.4)
+                       deadline=_time.time() + 3600)
     fin = None
     got = 0
     for _ in range(2000):
@@ -927,6 +924,10 @@ def test_running_request_expires_mid_decode():
                 fin = d.finish_reason
         if fin:
             break
+        if got >= 3:
+            req = engine.requests["d"]
+            assert 3000 < req.deadline_mono - _time.monotonic() <= 3600
+            req.deadline_mono = _time.monotonic() - 1.0
     assert fin == "expired"
     assert 0 < got < 500  # partial progress, then pruned mid-decode
     assert engine.stats()["expired_total"] == 1
@@ -1022,7 +1023,7 @@ def test_multi_step_decode_matches_single_step():
 
     outs = {}
     for k in (1, 4):
-        engine = LLMEngine(EngineConfig(**base, decode_steps_per_dispatch=k))
+        engine = tiny_engine(**base, decode_steps_per_dispatch=k)
         engine.add_request("m", prompt, SamplingParams(max_tokens=9))
         outs[k] = _collect(engine, ["m"])["m"]
     assert outs[1] == outs[4], (outs[1], outs[4])
@@ -1058,9 +1059,9 @@ def test_a_family_that_names_prefix_reuse_matches_no_page_and_says_why():
     tokens."""
     from ray_tpu.models import mellum
 
-    engine = LLMEngine(EngineConfig(
-        model="tiny-mellum", dtype="float32", page_size=16, num_pages=32,
-        max_model_len=128, max_batch=2, prefill_buckets=(32, 64)))
+    engine = tiny_engine(
+        "tiny-mellum", dtype="float32", page_size=16, num_pages=32,
+        max_model_len=128, max_batch=2, prefill_buckets=(32, 64))
     prompt = list(range(1, 49))
     out = {"a": [], "b": []}
     for rid in out:

@@ -18,10 +18,12 @@ import pytest
 from chipbench.references import window_moe_decoder as ref
 from ray_tpu.models import mellum
 from ray_tpu.ops import paged_attention as pa
-from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.serve.llm import EngineConfig, SamplingParams
 from ray_tpu.serve.llm.engine import PassCost, plan_passes, refuse
-from ray_tpu.serve.llm.stage import init_params, model_family
+from ray_tpu.serve.llm.stage import model_family
 from ray_tpu.util import tracing
+
+from _engines import applied, fresh_params, jitted, scarce, tiny_engine
 
 F32 = dict(dtype=jnp.float32, param_dtype=jnp.float32)
 WINDOW = 32
@@ -77,8 +79,7 @@ def contexts_walked_in_chunks():
 def tiny():
     cfg = mellum.get_config("tiny-mellum", **F32)
     model = mellum.MellumModel(cfg)
-    params = _seeded(init_params(model, jnp.zeros((1, 8), jnp.int32),
-                                 jax.random.PRNGKey(1)))
+    params = fresh_params(model, 1, _seeded)
     return cfg, model, params
 
 
@@ -86,6 +87,7 @@ def _ids(shape, seed=3):
     return jax.random.randint(jax.random.PRNGKey(seed), shape, 0, 256)
 
 
+@jitted
 def _reference(params, ids, **cfg):
     return ref.forward(ref.weights_from_program_tree(params), ids,
                        {**PUB, **cfg})
@@ -152,7 +154,7 @@ def test_the_full_forward_is_the_references_and_not_the_all_full_ones(tiny):
     for n in (24, 100, 640):
         ids = _ids((1, n), seed=n)
         with jax.default_matmul_precision("highest"):
-            got = model.apply({"params": params}, ids)
+            got = applied(model, params, ids)
         want = _reference(params, ids)
         rms = float(jnp.sqrt((want ** 2).mean()))
         assert rms > 0.3
@@ -204,7 +206,7 @@ def test_resumed_passes_then_decode_through_the_cache_are_the_references(
 def test_the_selection_sown_is_the_references_experts(tiny):
     cfg, model, params = tiny
     ids = _ids((1, 80), seed=9)
-    _, sown = model.apply({"params": params}, ids, mutable=["selection"])
+    _, sown = applied(model, params, ids, mutable=["selection"])
     got = jnp.concatenate([v["chosen"][0][:, 0] for _, v in sorted(
         sown["selection"].items())])                     # [L, S, 1, E]
     with jax.default_matmul_precision("highest"):
@@ -217,13 +219,13 @@ def test_the_selection_sown_is_the_references_experts(tiny):
 
 # ----------------------------------------------- (b) the engine's normal path
 def _engine(**more):
-    return LLMEngine(EngineConfig(**{**CFG, **more}))
+    """The module's engine of this configuration, renewed."""
+    return tiny_engine(**{**CFG, **more})
 
 
 @pytest.fixture(scope="module")
 def engine():
-    """One engine for the tests that only read it: its programs are built
-    once."""
+    """One engine for the tests that only read it."""
     return _engine()
 
 
@@ -323,13 +325,13 @@ def test_a_preempted_request_refills_and_agrees():
     """Two pages short: the decode step preempts a request, which refills
     its pages AND its slot's rings from its tokens; every token of both is
     still the reference's."""
-    engine = _engine(num_pages=22, max_model_len=256, max_batch=2)
-    rng = np.random.default_rng(7)
-    prompts = [rng.integers(0, 256, n).tolist() for n in (150, 140)]
-    emitted = _generate(engine, prompts, 40)
-    assert engine.stats()["preempted_total"] >= 1
-    assert [len(e) for e in emitted] == [40, 40]
-    _assert_greedy_by_the_reference(engine, prompts, emitted)
+    with scarce(_engine(), 21) as engine:
+        rng = np.random.default_rng(7)
+        prompts = [rng.integers(0, 256, n).tolist() for n in (150, 140)]
+        emitted = _generate(engine, prompts, 40)
+        assert engine.stats()["preempted_total"] >= 1
+        assert [len(e) for e in emitted] == [40, 40]
+        _assert_greedy_by_the_reference(engine, prompts, emitted)
 
 
 def test_a_pass_is_priced_by_the_pairs_each_kind_makes():

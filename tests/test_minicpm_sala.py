@@ -20,8 +20,10 @@ from ray_tpu.ops.paged_attention import (paged_attention_decode,
                                          paged_write)
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
 from ray_tpu.serve.llm.engine import PassCost
-from ray_tpu.serve.llm.stage import init_params, model_family
+from ray_tpu.serve.llm.stage import model_family
 from ray_tpu.util import tracing
+
+from _engines import applied, fresh_params, jitted, scarce, tiny_engine
 
 F32 = jnp.float32
 CFG = sala.get_config("tiny-sala", dtype=F32, param_dtype=F32)
@@ -38,11 +40,18 @@ PUB = dict(mixer_types=list(CFG.mixer_types), kept_layers=None,
 VOCAB, PAGE, MP = CFG.vocab_size, 16, 16
 
 
+BASE = dict(model="tiny-sala", dtype="float32", num_pages=64,
+            page_size=PAGE, max_model_len=256, max_batch=4,
+            prefill_buckets=(32, 64), seed=3)
+
+
 def _engine_config(**over):
-    base = dict(model="tiny-sala", dtype="float32", num_pages=64,
-                page_size=PAGE, max_model_len=256, max_batch=4,
-                prefill_buckets=(32, 64), seed=3)
-    return EngineConfig(**{**base, **over})
+    return EngineConfig(**{**BASE, **over})
+
+
+def _engine(**over):
+    """The module's engine of this configuration, renewed."""
+    return tiny_engine(**{**BASE, **over})
 
 
 @pytest.fixture(scope="module")
@@ -52,8 +61,7 @@ def model():
 
 @pytest.fixture(scope="module")
 def params(model):
-    return init_params(model, jnp.zeros((1, 8), jnp.int32),
-                       jax.random.PRNGKey(11))
+    return fresh_params(model, 11)
 
 
 @pytest.fixture(scope="module")
@@ -62,7 +70,7 @@ def ref_weights(params):
 
 
 def _ref_logits(ref_weights, seq):
-    return np.asarray(reference.forward(
+    return np.asarray(jitted(reference.forward)(
         ref_weights, jnp.asarray([seq], jnp.int32), PUB)[0])
 
 
@@ -91,8 +99,8 @@ BT = jnp.arange(1, 1 + MP, dtype=jnp.int32)[None]
 
 def _apply(model, params, pool, bt, total, ids, positions, slots, ctx):
     cache = sala.serving_cache(CFG, pool, bt, total, slots, ctx_pages=ctx)
-    logits, cache = model.apply({"params": params}, ids, positions=positions,
-                                kv_caches=cache)
+    logits, cache = applied(model, params, ids, positions=positions,
+                            kv_caches=cache)
     return logits, cache.pool
 
 
@@ -196,7 +204,7 @@ def test_prefill_then_decode_through_the_pool_is_the_references_forward(
 
 def test_the_models_own_forward_is_the_paged_path(model, params, ref_weights):
     seq = _prompt(5, 100)
-    got = model.apply({"params": params}, jnp.asarray([seq, seq[::-1]]))
+    got = applied(model, params, jnp.asarray([seq, seq[::-1]]))
     np.testing.assert_allclose(got[0], _ref_logits(ref_weights, seq),
                                atol=3e-4)
     np.testing.assert_allclose(got[1], _ref_logits(ref_weights, seq[::-1]),
@@ -262,18 +270,15 @@ def test_a_prompt_past_the_largest_bucket_is_served_in_passes(preset, over):
     prompts = [_prompt(21, 150, vocab), _prompt(22, 40, vocab),
                _prompt(23, 100, vocab)]
     def engines():
-        whole = LLMEngine(_engine_config(
-            model=preset, **{**over, "prefill_buckets": (64, 160),
-                             "prefill_chunk_tokens": 0}), params=weights)
+        whole = _engine(model=preset, **{
+            **over, "prefill_buckets": (64, 160), "prefill_chunk_tokens": 0})
         # whole whatever the plan: a tiny model's attention is dear beside
         # its products, and 100 tokens would go as 64 + 64
         whole._pass_cost = PassCost(float("inf"), 0.0)
-        return whole, LLMEngine(_engine_config(model=preset, **over),
-                                params=whole.params)
+        # (the same seed: the same weights)
+        return whole, _engine(model=preset, **over)
 
-    weights = None
     whole, passes = engines()
-    weights = whole.params
     assert _generate(passes, prompts, 12) == _generate(whole, prompts, 12)
     st = passes.stats()
     assert st["prefill_resumed_passes_total"] >= 3
@@ -298,12 +303,13 @@ def test_a_preempted_request_whose_folded_prompt_outgrows_the_buckets_finishes(
     prompts = [_prompt(31, 30), _prompt(32, 30)]
     over = dict(model=preset, page_size=page, prefill_buckets=(32,),
                 max_model_len=128, max_batch=2)
-    roomy = LLMEngine(_engine_config(**over))
-    want = _generate(roomy, prompts, 40)
-    tight = LLMEngine(_engine_config(num_pages=112 // page, **over),
-                      params=roomy.params)
-    got = _generate(tight, prompts, 40)
-    st = tight.stats()
+    # (its own sizes: one bucket of 32 that the folded prompt outgrows;
+    # then the same engine, renewed, with the pages that two rows of 70
+    # tokens cannot both keep)
+    want = _generate(_engine(**over), prompts, 40)
+    with scarce(_engine(**over), 112 // page - 1) as tight:
+        got = _generate(tight, prompts, 40)
+        st = tight.stats()
     assert st["preempted_total"] >= 1
     assert st["prefill_resumed_passes_total"] >= 1
     assert got == want
@@ -325,7 +331,7 @@ def test_engine_options_that_need_the_state_moved_are_refused(option):
 
 
 def test_prefix_reuse_and_the_hand_off_stay_off(params):
-    engine = LLMEngine(_engine_config(), params=params)
+    engine = _engine(params=params, twin="params")
     with pytest.raises(NotImplementedError, match="hand-off"):
         engine.add_request("p", [1, 2, 3], SamplingParams(
             max_tokens=4, prefill_only=True))
@@ -346,7 +352,7 @@ def test_records_and_stats_on_a_known_schedule(params):
     one), then 6 decode steps: the counters against the selection rule
     counted independently (chipbench/sala_work.py)."""
     tracing.reset_ring()
-    engine = LLMEngine(_engine_config(), params=params)
+    engine = _engine(params=params, twin="params")
     st = engine.stats()
     assert st["lin_state_pool_bytes"] == 4 * CFG.slot_state_bytes_row()
     assert st["sparse_index_pool_bytes"] == 3 * 64 * 2 * 4 * 16 * 4
@@ -394,8 +400,8 @@ def test_other_families_carry_none_of_it():
     fields = tracing.FIELDS["engine.dispatch"]
     for preset, n in (("tiny", 11), ("tiny-jamba", 16)):
         tracing.reset_ring()
-        engine = LLMEngine(EngineConfig(model=preset, dtype="float32",
-                                        page_size=8))
+        engine = _engine(model=preset, page_size=8,
+                         prefill_buckets=(16, 32))
         _generate(engine, [[1, 2, 3, 4, 5]], 3)
         assert not [k for k in engine.stats()
                     if k.startswith(("lightning_", "sparse_", "lin_"))]

@@ -19,6 +19,8 @@ import pytest
 
 from ray_tpu.models.llama import LlamaModel, get_config
 
+from _engines import applied, jitted, tiny_engine
+
 
 @pytest.fixture(scope="module")
 def tiny_moe():
@@ -27,7 +29,7 @@ def tiny_moe():
     ids = jnp.asarray(np.random.default_rng(0).integers(
         0, cfg.vocab_size, (2, 32), dtype=np.int32))
     params = nn.meta.unbox(
-        model.init(jax.random.PRNGKey(0), ids)["params"])
+        jitted(model.init)(jax.random.PRNGKey(0), ids)["params"])
     return cfg, model, params, ids
 
 
@@ -178,7 +180,7 @@ def moe_f32():
 
     cfg = get_config("tiny-moe", dtype=jnp.float32)
     model = LlamaModel(cfg)
-    params = nn.meta.unbox(model.init(
+    params = nn.meta.unbox(jitted(model.init)(
         jax.random.PRNGKey(3), jnp.zeros((1, 8), jnp.int32))["params"])
     return (cfg, model, params, moe_decoder,
             moe_decoder.weights_from_program_tree(params), _published(cfg))
@@ -198,14 +200,13 @@ def test_moe_forward_matches_reference(moe_f32, shape):
     np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
 
 
-def _tiny_engine(**over):
-    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
-
+def _tiny_engine(twin=None, **over):
+    """The module's engine of this configuration, renewed."""
     cfg = dict(model="tiny-moe", dtype="float32", page_size=8, num_pages=64,
                max_model_len=128, max_batch=4,
                prefill_buckets=(16, 32, 64, 128), seed=3)
     cfg.update(over)
-    return LLMEngine(EngineConfig(**cfg))
+    return tiny_engine(**cfg, twin=twin)
 
 
 def test_moe_paged_prefill_and_decode_match_reference():
@@ -287,8 +288,8 @@ def test_moe_row_is_independent_of_slot_and_padding(moe_f32, slot,
                 (cfg.num_layers, b, mp)),
             total_lens=jnp.broadcast_to(lens, (cfg.num_layers, b)))
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        logits, _ = model.apply(
-            {"params": params}, jnp.asarray(ids), positions=positions,
+        logits, _ = applied(
+            model, params, jnp.asarray(ids), positions=positions,
             kv_caches=pc,
             token_mask=None if mask is None else positions < lens[:, None])
         return np.asarray(logits)
@@ -644,7 +645,8 @@ def test_dropless_layer_is_the_same_with_groups_on_tile_boundaries(
                     jnp.float32)
     mask = jnp.asarray(np.arange(tokens) < 107)[None]
     layer = MoEMLP(cfg)
-    params = nn.meta.unbox(layer.init(jax.random.PRNGKey(1), x)["params"])
+    params = nn.meta.unbox(
+        jitted(layer.init)(jax.random.PRNGKey(1), x)["params"])
     monkeypatch.setattr(gm, "_impl", lambda: impl)
 
     def loss(params, x):
@@ -653,8 +655,8 @@ def test_dropless_layer_is_the_same_with_groups_on_tile_boundaries(
 
     def run():
         with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
-                params, x)
+            return jitted(jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True))(params, x)
 
     (_, out), grads = run()
     monkeypatch.setattr(gm, "row_tile", lambda m, e: (128, False))
@@ -718,12 +720,9 @@ def test_grouped_matmul_kernel_matches_plain_path_forward_and_backward(
 
     rhs, layer = (stack, jnp.int32(1)) if stacked else (stack[1], None)
     with jax.default_matmul_precision("highest"):
-        (_, plain), g_plain = jax.value_and_grad(
-            loss, argnums=(0, 1), has_aux=True)(lhs, stack[1], None,
-                                                "ragged_dot")
-        (_, out), grads = jax.value_and_grad(
-            loss, argnums=(0, 1), has_aux=True)(
-                lhs, rhs, layer, "megablox_interpret")
+        both = jitted(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
+        (_, plain), g_plain = both(lhs, stack[1], None, "ragged_dot")
+        (_, out), grads = both(lhs, rhs, layer, "megablox_interpret")
 
     def close(got, want, atol, rtol=0):
         # float32's summation order, at the size the widths give the sums
@@ -834,7 +833,7 @@ def _layer_out(cfg, x, mask, params=None, seed=1):
     layer = MoEMLP(cfg)
     if params is None:
         params = nn.meta.unbox(
-            layer.init(jax.random.PRNGKey(seed), x)["params"])
+            jitted(layer.init)(jax.random.PRNGKey(seed), x)["params"])
 
     def stack(w):
         return jnp.stack([jnp.zeros_like(w), w])
@@ -953,7 +952,7 @@ def test_moe_engine_counts_the_calls_of_a_pass_cut_into_blocks(monkeypatch):
     from ray_tpu.util import metrics, tracing
 
     monkeypatch.setattr(llama, "_MOE_ROWS", 256)
-    engine = _tiny_engine()
+    engine = _tiny_engine(twin="_MOE_ROWS=256")   # traced under the patch
     cfg = engine.model_cfg
     assert llama.moe_row_layout(128, cfg) == (128, True, 768, 256)
     k, L, E = cfg.num_experts_per_tok, cfg.num_layers, cfg.num_experts

@@ -13,9 +13,10 @@ import pytest
 from chipbench import generator
 from ray_tpu.models import jamba, llama, minicpm_sala
 from ray_tpu.serve.llm.engine import (_BALANCE_TOKENS, _PAIR_PARAMS, _bucket,
-                                      EngineConfig, LLMEngine, PassCost,
-                                      SamplingParams, plan_passes)
+                                      PassCost, SamplingParams, plan_passes)
 from ray_tpu.util import tracing
+
+from _engines import new_engine, scarce, tiny_engine
 
 CHIPBENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "chipbench")
@@ -221,11 +222,9 @@ def test_three_passes_only_where_they_pay():
 
 
 # ------------------------------------------------------------ the engine
-def _config(**over):
-    base = dict(model="tiny", dtype="float32", num_pages=64, page_size=8,
-                max_model_len=256, max_batch=4,
-                prefill_buckets=(16, 32, 64, 128), seed=5)
-    return EngineConfig(**{**base, **over})
+BASE = dict(model="tiny", dtype="float32", num_pages=64, page_size=8,
+            max_model_len=256, max_batch=4,
+            prefill_buckets=(16, 32, 64, 128), seed=5)
 
 
 def _prompt(seed, n, vocab=256):
@@ -243,14 +242,24 @@ def _generate(engine, prompts, max_tokens):
     return out
 
 
-def _pair(floor=4, **over):
-    """(an engine that prefills every prompt whole, the same engine with a
-    floor at which the tiny buckets split)."""
-    whole = LLMEngine(_config(**over))
-    split = LLMEngine(_config(**over), params=whole.params)
+def _whole(**over):
+    """The module's engine of this configuration, renewed: it prefills
+    every prompt whole."""
+    whole = tiny_engine(**{**BASE, **over})
     assert whole._pass_cost.floor >= 240   # the tiny buckets never pay
+    return whole
+
+
+def _split(floor=4, **over):
+    """Its twin (the same seed: the same weights), with a floor at which
+    the tiny buckets split."""
+    split = tiny_engine(**{**BASE, **over}, twin="split")
     split._pass_cost = _bare(floor)
-    return whole, split
+    return split
+
+
+def _pair(**over):
+    return _whole(**over), _split(**over)
 
 
 def _pools(engine):
@@ -311,17 +320,16 @@ def test_a_preempted_split_request_finishes_with_the_roomy_engines_tokens():
     """Two split prompts (40 = 32 + 16) that cannot both keep their
     pages: one is preempted, and its folded prompt is planned anew from
     what the prefix cache still holds of it (one resumed pass more)."""
-    over = dict(max_model_len=128, max_batch=2)
     prompts = [_prompt(31, 40), _prompt(32, 40)]
-    roomy, _ = _pair(**over)
+    roomy = _whole()
     want = _generate(roomy, prompts, 40)
-    tight = LLMEngine(_config(num_pages=17, **over), params=roomy.params)
-    tight._pass_cost = _bare(4)
-    assert _generate(tight, prompts, 40) == want
-    st = tight.stats()
-    assert st["preempted_total"] >= 1
-    assert st["prefill_split_prompts_total"] >= 2
-    assert st["prefill_resumed_passes_total"] >= 3
+    # (16 pages of 8 are what two rows of 80 tokens cannot both keep)
+    with scarce(_split(), 16) as tight:
+        assert _generate(tight, prompts, 40) == want
+        st = tight.stats()
+        assert st["preempted_total"] >= 1
+        assert st["prefill_split_prompts_total"] >= 2
+        assert st["prefill_resumed_passes_total"] >= 3
 
 
 def test_a_last_pass_whose_padding_runs_past_max_model_len():
@@ -337,7 +345,7 @@ def test_a_last_pass_whose_padding_runs_past_max_model_len():
 
 
 def test_a_family_that_cannot_resume_never_splits():
-    engine = LLMEngine(_config(model="tiny-jamba"))
+    engine = tiny_engine(**{**BASE, "model": "tiny-jamba"})
     assert engine._pass_cost is None and not engine._resumes
     engine._pass_cost = _bare(4)       # even so: the plan is never asked
     _generate(engine, [_prompt(3, 70), _prompt(4, 90)], 3)
@@ -352,9 +360,10 @@ def test_a_family_that_cannot_resume_never_splits():
 def test_the_plan_adds_no_program(preset, parts):
     """What warm-up builds is what it built before prompts were split:
     every bucket with and without a context part for a family that
-    resumes, one decode program; no key the plan could add."""
-    engine = LLMEngine(_config(
-        model=preset, page_size=16 if preset == "tiny-sala" else 8))
+    resumes, one decode program; no key the plan could add. (An engine of
+    its own: the module's has the programs of every case before.)"""
+    engine = new_engine(**{**BASE, "model": preset,
+                           "page_size": 16 if preset == "tiny-sala" else 8})
     rb = engine._wave_rb
     assert engine._warmup_programs(None, True) == [
         ("prefill", (sb, rb, cp)) for sb in (16, 32, 64, 128)
@@ -408,8 +417,8 @@ def test_no_more_visits_pay_the_mask_than_are_made():
     """A mixed batch (fresh rows of several buckets beside split ones,
     contexts that end inside a key block and on its edge): every visit
     that builds a mask is a visit made."""
-    _, split = _pair(max_model_len=1024, num_pages=400,
-                     prefill_buckets=(128, 256, 1024))
+    split = _split(max_model_len=1024, num_pages=400,
+                   prefill_buckets=(128, 256, 1024))
     _generate(split, [_prompt(i, n) for i, n in enumerate(
         (500, 700, 30, 129, 256, 1000, 385))], 2)
     st = split.stats()
@@ -426,7 +435,7 @@ def test_no_more_visits_pay_the_mask_than_are_made():
 def test_a_sparse_familys_resumed_pass_counts_no_flash_blocks():
     """MiniCPM-SALA attends a context through ops/sparse_attention.py: only
     its fresh pass under the dense length is the flash kernel's."""
-    whole, split = _pair(model="tiny-sala", page_size=16)
+    split = _split(model="tiny-sala", page_size=16)
     _generate(split, [_prompt(3, 70)], 2)
     st = split.stats()
     assert st["prefill_resumed_passes_total"] == 1

@@ -17,8 +17,10 @@ import re
 import numpy as np
 import pytest
 
-from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
+from ray_tpu.serve.llm import LLMEngine, SamplingParams
 from ray_tpu.util import tracing
+
+from _engines import new_engine, tiny_engine
 
 MOE = {s for s in tracing.SCOPES if s.startswith("rtpu.moe.")}
 SERVING = {"rtpu.head", "rtpu.sample", "rtpu.attn.cache_write"}
@@ -55,12 +57,14 @@ def _dicts(kind):
             for rec in tracing.records(kind)]
 
 
-def _engine(family, **more):
+def _engine(family, build=new_engine, **more):
+    """An engine of its own (most cases here read what an engine built, or
+    trace it under a patch); `build=tiny_engine`: the module's."""
     preset, page, _, _ = FAMILIES[family]
-    return LLMEngine(EngineConfig(
-        model=preset, dtype="float32", page_size=page, num_pages=96,
+    return build(
+        preset, dtype="float32", page_size=page, num_pages=96,
         max_model_len=256, max_batch=2, prefill_buckets=(32, 64), seed=3,
-        **more))
+        **more)
 
 
 def _generate(engine, lens=(41,), max_tokens=5, seed=4):
@@ -86,7 +90,7 @@ def tables():
     def get(family):
         if family not in done:
             tracing.reset_ring()
-            engine = _engine(family)
+            engine = _engine(family, tiny_engine)
             # a prompt over the largest bucket: a resumed pass beside the
             # fresh one, where the family resumes
             _generate(engine, lens=(41, 64 if family == "jamba" else 100))
@@ -96,7 +100,6 @@ def tables():
                     for rec in _dicts("engine.dispatch")}
             done[family] = {kind: (key, engine.program_scopes(kind, key))
                             for kind, key in keys.items()}
-            engine.close()
         return done[family]
 
     return get
@@ -301,7 +304,8 @@ def test_greedy_tokens_are_bit_equal_with_and_without_the_scopes(
         family, monkeypatch):
     """A scope is a name at trace time: the programs compute what they
     computed without one."""
-    with_scopes = _generate(_engine(family), lens=(41, 23), max_tokens=8)
+    with_scopes = _generate(_engine(family, tiny_engine), lens=(41, 23),
+                            max_tokens=8)
     monkeypatch.setattr(tracing, "scope",
                         lambda name: contextlib.nullcontext())
     bare_engine = _engine(family)

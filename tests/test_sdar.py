@@ -5,6 +5,7 @@ float32, tiny sizes, on the CPU. The tiny preset has 16 experts of which 4
 a token, an expert width that is not the dense one's, head-dim norms on q
 and k, blocks of 4."""
 
+import functools
 import json
 
 import jax
@@ -24,6 +25,8 @@ from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
 from ray_tpu.serve.llm.stage import init_params, model_family
 from ray_tpu.util import tracing
 
+from _engines import new_engine, scarce, tiny_engine
+
 B = 4
 CFG = dict(model="tiny-sdar", page_size=16, num_pages=64, max_model_len=256,
            max_batch=4, prefill_buckets=(32, 64, 128), dtype="float32")
@@ -37,6 +40,7 @@ def _model_cfg(**over):
                            dtype=jnp.float32, param_dtype=jnp.float32, **over)
 
 
+@functools.cache
 def _params(seed=7, head_gain=1.0):
     """The benchmark's seeded weights in the tiny preset's tree. The head
     times `head_gain`: at 200 every position's softmax is peaked (a
@@ -63,12 +67,19 @@ def _ref_cfg(model_cfg):
         mask_token_id=c.mask_token_id)
 
 
-def _engine(rule="low_confidence_static", head_gain=1.0, **over):
+def _engine(rule="low_confidence_static", head_gain=1.0, own=False,
+            **over):
+    """The module's engine of this configuration and head, renewed; or one
+    of the caller's `own`, that no other case renews or has used: a
+    fixture's, whose pool its tests read, or one whose first builds are
+    counted."""
     cfg = {**CFG, **over}
     cfg["model_overrides"] = {"remasking": rule,
                               **over.get("model_overrides", {})}
-    return LLMEngine(EngineConfig(**cfg), params=_params(
-        head_gain=head_gain))
+    if own:
+        return new_engine(**cfg, params=_params(head_gain=head_gain))
+    return tiny_engine(**cfg, params=_params(head_gain=head_gain),
+                       twin=head_gain)
 
 
 def _run(engine, max_steps=2000):
@@ -216,7 +227,7 @@ def generated():
     out = {}
     for rule in RULES:
         gain = 200.0 if rule == "low_confidence_dynamic" else 1.0
-        engine = _engine(rule, head_gain=gain)
+        engine = _engine(rule, head_gain=gain, own=True)
         engine.warmup()
         built = engine.stats()["programs_built_total"]
         for i, p in enumerate(_prompts()):
@@ -327,20 +338,19 @@ def test_a_preempted_request_refills_and_goes_on_to_the_same_tokens():
     """Pages for two 40-token answers do not fit: one request is preempted
     mid-answer, its output folded into its prompt (whole blocks from
     position 0), and ends with the tokens it would have had alone."""
-    engine = _engine(num_pages=8, max_model_len=96, max_batch=2,
-                     prefill_buckets=(32, 64))
-    prompts = _prompts((30, 33), seed=5)
-    for i, p in enumerate(prompts):
-        engine.add_request(f"r{i}", p, SamplingParams(max_tokens=40))
-    got = _by_request(_run(engine))
-    assert engine.stats()["preempted_total"] >= 1
-    for i, p in enumerate(prompts):
-        assert got[f"r{i}"]["tokens"] == _reference_generate(
-            engine, p, 40)[0], i
-    reqs = [dict(zip(tracing.FIELDS["engine.request"], r))
-            for r in tracing.records("engine.request")[-2:]]
-    assert sorted(r["output_tokens"] for r in reqs) == [40, 40]
-    engine.close()
+    with scarce(_engine(), 7) as engine:
+        prompts = _prompts((30, 33), seed=5)
+        for i, p in enumerate(prompts):
+            engine.add_request(f"r{i}", p, SamplingParams(max_tokens=40))
+        got = _by_request(_run(engine))
+        assert engine.stats()["preempted_total"] >= 1
+        for i, p in enumerate(prompts):
+            assert got[f"r{i}"]["tokens"] == _reference_generate(
+                engine, p, 40)[0], i
+        reqs = [dict(zip(tracing.FIELDS["engine.request"], r))
+                for r in tracing.records("engine.request")[-2:]]
+        assert sorted(r["output_tokens"] for r in reqs) == [40, 40]
+        engine.close()
 
 
 def test_prefix_pages_are_reused_where_a_page_holds_whole_blocks():
@@ -629,9 +639,9 @@ def test_a_greedy_engine_on_the_kernel_emits_the_references_tokens(
     order, and the program holds no [S, B, V] product outside the branch
     that draws."""
     monkeypatch.setattr(head_op, "_impl", lambda: "pallas_interpret")
-    engine = LLMEngine(EngineConfig(**{**CFG, "model_overrides": {
+    engine = new_engine(**{**CFG, "model_overrides": {
         "vocab_size": 1100, "mask_token_id": 1099,
-        "remasking": "low_confidence_static"}}))
+        "remasking": "low_confidence_static"}})
     text = engine.program_text("block", engine._block_shape_key())
     assert text.count("tensor<4x4x1100xf32>") > 0       # the drawn branch
     assert "tensor<16x1100xf32>" not in text            # no greedy logits
@@ -692,7 +702,7 @@ def _finished_with_pages(engine, rid, prompt, **sampling):
 @pytest.fixture(scope="module")
 def one_answer():
     """A 41-token prompt (a tail of 1) and 15 tokens: four blocks."""
-    engine = _engine()
+    engine = _engine(own=True)
     prompt = _prompts((41,))[0]
     tokens, pages = _finished_with_pages(engine, "r", prompt, max_tokens=15)
     assert tokens == _reference_generate(engine, prompt, 15)[0]
@@ -857,7 +867,7 @@ def test_a_row_that_sits_a_round_out_is_settled_when_it_next_goes():
             kept = [r for r in kept if r.request_id != "r1"]
         return kept
 
-    engine._reserve_decode_pages = skipping
+    engine._reserve_decode_pages = skipping     # (`renewed` takes it off)
     n0 = tracing.appended("engine.dispatch")
     got = _by_request(_run(engine))
     fields = tracing.FIELDS["engine.dispatch"]

@@ -27,6 +27,9 @@ from ray_tpu.serve.llm import EngineConfig, LLMEngine, SamplingParams
 from ray_tpu.serve.llm.stage import init_params, model_family
 from ray_tpu.util import tracing
 
+from _engines import (applied, fresh_params, jitted, new_engine,
+                      tiny_engine)
+
 F32 = dict(dtype=jnp.float32, param_dtype=jnp.float32)
 CFG = dict(model="tiny-zaya", dtype="float32", page_size=16, num_pages=64,
            max_model_len=256, max_batch=4, prefill_buckets=(32, 64))
@@ -67,8 +70,7 @@ def _seeded(params, seed=2):
 def tiny():
     cfg = zaya.get_config("tiny-zaya", **F32)
     model = zaya.ZayaModel(cfg)
-    params = _seeded(init_params(model, jnp.zeros((1, 8), jnp.int32),
-                                 jax.random.PRNGKey(1)))
+    params = fresh_params(model, 1, _seeded)
     return cfg, model, params
 
 
@@ -76,6 +78,7 @@ def _ids(shape, seed=3):
     return jax.random.randint(jax.random.PRNGKey(seed), shape, 0, 512)
 
 
+@jitted
 def _reference(params, ids, **over):
     return ref.forward(ref.weights_from_program_tree(params), ids,
                        {**PUB, **over})
@@ -148,7 +151,7 @@ def test_the_full_forward_is_the_references(tiny):
     _, model, params = tiny
     ids = _ids((2, 100))
     with jax.default_matmul_precision("highest"):
-        got = model.apply({"params": params}, ids)
+        got = applied(model, params, ids)
     want = _reference(params, ids)
     assert float(jnp.abs(want).max()) > 0.3
     assert float(jnp.abs(got - want).max()) < TOL
@@ -163,7 +166,7 @@ def test_every_term_is_live_in_the_comparison(tiny, reading, monkeypatch):
     _, model, params = tiny
     ids = _ids((1, 64))
     with jax.default_matmul_precision("highest"):
-        got = model.apply({"params": params}, ids)
+        got = applied(model, params, ids)
     w = ref.weights_from_program_tree(params)
     layers, over = dict(w["layers"]), {}
     if reading == "value_shift":
@@ -251,8 +254,8 @@ def test_an_idle_slot_keeps_its_tail_and_pages_bit_for_bit(tiny):
     pool = _fresh_pool(cfg, fill=0)
     bt = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
     cache = zaya.serving_cache(cfg, pool, bt, jnp.asarray([20, 0], jnp.int32))
-    _, new = model.apply({"params": params}, _ids((2, 1)),
-                         positions=jnp.asarray([[19], [0]]), kv_caches=cache)
+    _, new = applied(model, params, _ids((2, 1)),
+                     positions=jnp.asarray([[19], [0]]), kv_caches=cache)
     was, now = pool["cca_tail"], new.pool["cca_tail"]
     assert bool((now[:, 1] == was[:, 1]).all())
     assert not bool((now[:, 0] == was[:, 0]).any(-1).all())
@@ -307,13 +310,13 @@ def test_the_bias_moves_the_choice_and_not_the_weight(tiny):
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 12, 64))
     r_prev = jax.random.normal(jax.random.PRNGKey(1), (1, 12, 16))
     p = jax.tree.map(lambda a: a[1], params["layers"]["router"])
-    (probs, gate, idx), _ = router.apply({"params": p}, x, r_prev)
+    (probs, gate, idx), _ = applied(router, p, x, r_prev)
     # the weight is the chosen expert's probability as it is: not 1.0
     assert bool((gate == jnp.take_along_axis(probs, idx, -1)).all())
     assert float(gate.max()) < 0.9 and abs(float(probs.sum(-1).mean()) - 1
                                            ) < 1e-6
     pushed = {**p, "bias": p["bias"].at[3].add(10.0)}
-    (probs_b, gate_b, idx_b), _ = router.apply({"params": pushed}, x, r_prev)
+    (probs_b, gate_b, idx_b), _ = applied(router, pushed, x, r_prev)
     assert bool((idx_b == 3).all()) and not bool((idx == 3).all())
     assert bool((probs_b == probs).all())
     assert bool((gate_b[:, 0] == probs[:, 3]).all())
@@ -337,7 +340,7 @@ def test_a_choice_from_outside_is_served_as_the_layers_own():
     text); given the choice its own router would make, it has no router
     and returns the same values."""
     cfg, layer, params, x = _moe()
-    own = layer.apply({"params": params}, x)
+    own = applied(layer, params, x)
     probs = jax.nn.softmax(jnp.einsum("th,he->te", x.reshape(-1, 32),
                                       params["router"]), axis=-1)
     gate, idx = jax.lax.top_k(probs, 2)
@@ -345,7 +348,7 @@ def test_a_choice_from_outside_is_served_as_the_layers_own():
     outside = {k: v for k, v in params.items() if k != "router"}
     assert "router" not in nn.meta.unbox(layer.init(
         jax.random.PRNGKey(1), x, choice=(probs, gate, idx))["params"])
-    given = layer.apply({"params": outside}, x, choice=(probs, gate, idx))
+    given = applied(layer, outside, x, choice=(probs, gate, idx))
     assert bool((given == own).all())
 
 
@@ -357,14 +360,14 @@ def test_a_padded_token_reaches_no_expert_and_one_weight_is_not_one():
     gate = jnp.take_along_axis(probs, idx, -1)
     mask = jnp.arange(12)[None, :] < jnp.asarray([[12], [5]])
     outside = {k: v for k, v in params.items() if k != "router"}
-    out, sown = layer.apply({"params": outside}, x, mask,
-                            choice=(probs, gate, idx), mutable=["routing"])
+    out, sown = applied(layer, outside, x, mask,
+                        choice=(probs, gate, idx), mutable=["routing"])
     counts, = jax.tree.leaves(sown["routing"])
     assert int(counts.sum()) == 17
     assert bool((out[1, 5:] == 0).all()) and bool((out[1, :5] != 0).any())
     # un-normalised: doubling the weight doubles the output
-    twice = layer.apply({"params": outside}, x, mask,
-                        choice=(probs, 2 * gate, idx))
+    twice = applied(layer, outside, x, mask,
+                    choice=(probs, 2 * gate, idx))
     assert float(jnp.abs(twice - 2 * out).max()) < 1e-5
 
 
@@ -416,16 +419,14 @@ def _judge(engine, prompt, tokens, tie=1e-3):
 
 
 def _engine(**over):
-    eng = LLMEngine(EngineConfig(**{**CFG, **over}))
-    eng.compute.params = jax.tree.map(jnp.asarray, _seeded(eng.params))
-    return eng
+    """The module's engine of this configuration on the seeded weights,
+    renewed."""
+    return tiny_engine(**{**CFG, **over}, params=_seeded)
 
 
 @pytest.fixture(scope="module")
 def engine():
-    eng = _engine()
-    yield eng
-    eng.close()
+    return _engine()
 
 
 def _prompts(lens, seed):
@@ -479,7 +480,6 @@ def test_a_slot_given_to_a_new_request_leaks_no_tail():
         eng.add_request(name, prompt, SamplingParams(max_tokens=12))
         assert _judge(eng, prompt, _run(eng)[name]) >= 8
     assert eng.stats()["cca_tail_resets_total"] == 2
-    eng.close()
 
 
 def test_a_prompt_seen_before_is_prefilled_again_not_matched(engine):
@@ -493,7 +493,8 @@ def test_a_prompt_seen_before_is_prefilled_again_not_matched(engine):
 
 
 def test_no_program_is_built_under_traffic_after_warmup():
-    eng = LLMEngine(EngineConfig(**CFG))
+    """(An engine of its own: what a first use builds is the claim.)"""
+    eng = new_engine(**CFG)
     n = eng.warmup()
     assert n == 2 * 2 + 1
     tracing.reset_ring()
